@@ -1,24 +1,41 @@
-"""ctypes loaders for the native libraries.
+"""ctypes loaders — and the build — for the native libraries.
 
 The codec library accelerates the framed-IPC hot path (shuffle/spill
-compression); the host-bridge library is the embedding surface for
-non-Python host engines.  Both degrade gracefully: pure-Python zstd when
-the codec .so is absent, in-process python calls when the bridge is.
+compression); the partition and agg kernels are host fast paths; the
+host-bridge library is the embedding surface for non-Python host
+engines.  A missing library selects a slower pure-Python / numpy /
+pyarrow path with identical results; `loaded_libraries()` says which
+were found, so a run can state what it used instead of degrading in
+silence.
+
+`build_native_libs()` builds them from native/src + native/CMakeLists.txt
+into native/build (git-ignored): a checkout holds no .so, and a copied
+tree resets file times, so freshness is decided by a CONTENT stamp of the
+sources, never by mtimes.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
-from typing import Optional
+import shutil
+import subprocess
+from typing import Dict, Optional
 
 _HERE = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+_NATIVE = os.path.join(_HERE, "native")
+_BUILD = os.path.join(_NATIVE, "build")
 _SEARCH = [
-    os.path.join(_HERE, "native", "build"),
-    os.path.join(_HERE, "native", "lib"),
+    _BUILD,
+    os.path.join(_NATIVE, "lib"),
     os.environ.get("BLAZE_TPU_NATIVE_DIR", ""),
 ]
+_LIBS = ("libblaze_ipc_codec.so", "libblaze_host_bridge.so",
+         "libblaze_jni_bridge.so", "libblaze_agg_kernel.so",
+         "libblaze_partition_kernel.so")
+_STAMP = os.path.join(_BUILD, ".source_sha256")
 
 
 def _find(name: str) -> Optional[str]:
@@ -29,6 +46,69 @@ def _find(name: str) -> Optional[str]:
         if os.path.exists(p):
             return p
     return None
+
+
+class NativeBuildError(RuntimeError):
+    """The native libraries could not be built (toolchain missing or a
+    compile error); callers run on the pure-Python paths and say so."""
+
+
+def _source_digest() -> str:
+    h = hashlib.sha256()
+    files = [os.path.join(_NATIVE, "CMakeLists.txt")]
+    for sub in ("src", "include"):
+        d = os.path.join(_NATIVE, sub)
+        files += sorted(os.path.join(d, f) for f in os.listdir(d))
+    for path in files:
+        h.update(os.path.relpath(path, _NATIVE).encode())
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def build_native_libs() -> str:
+    """Make native/build hold libraries built from the sources as they
+    are now.  Returns "current" when the content stamp matches and every
+    library is present, "built" after a from-scratch build; raises
+    NativeBuildError when cmake/ninja/the compiler is missing or fails."""
+    global _codec_checked
+    digest = _source_digest()
+    if all(os.path.exists(os.path.join(_BUILD, lib)) for lib in _LIBS):
+        try:
+            with open(_STAMP) as f:
+                if f.read().strip() == digest:
+                    return "current"
+        except FileNotFoundError:
+            pass
+    # from scratch: a build directory configured at another path (a
+    # copied tree) carries a CMakeCache that cmake refuses to reuse
+    shutil.rmtree(_BUILD, ignore_errors=True)
+    try:
+        subprocess.run(["cmake", "-S", _NATIVE, "-B", _BUILD, "-G", "Ninja"],
+                       check=True, capture_output=True, timeout=300)
+        subprocess.run(["cmake", "--build", _BUILD], check=True,
+                       capture_output=True, timeout=600)
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        raise NativeBuildError(
+            f"native build failed: {e} "
+            f"{detail.decode(errors='replace')[-500:]}") from e
+    with open(_STAMP, "w") as f:
+        f.write(digest)
+    # forget earlier misses so this process loads what it just built
+    _codec_checked = False
+    _kernels.clear()
+    return "built"
+
+
+def loaded_libraries() -> Dict[str, bool]:
+    """Which native libraries this process resolves; False names a
+    pure-Python fallback in use (zstd codec in Python, numpy murmur3
+    partition ids, pyarrow group-by in the host agg lane)."""
+    return {"ipc_codec": get_codec() is not None,
+            "partition_kernel": get_partition_kernel() is not None,
+            "agg_kernel": get_agg_kernel() is not None,
+            "host_bridge": _find("libblaze_host_bridge.so") is not None}
 
 
 class _Codec:

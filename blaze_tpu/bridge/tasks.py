@@ -2,8 +2,8 @@
 
 A task thread wedged inside backend init/compile must convert to a
 TimeoutError for the caller instead of hanging ThreadPoolExecutor
-forever (the failure mode of BENCH_r02: rc=124 with threads stuck in
-`jax.devices()`).  shutdown(wait=False) leaves any stuck thread behind;
+forever (threads stuck in `jax.devices()` once turned a bench run into
+rc=124).  shutdown(wait=False) leaves any stuck thread behind;
 callers that must exit promptly despite one should use os._exit after
 reporting (bench.py child does).
 

@@ -25,6 +25,7 @@ the router's liveness deadline can classify it down).
 from __future__ import annotations
 
 import json
+import logging
 import os
 import signal
 import socket
@@ -36,6 +37,8 @@ from typing import Any, Dict, Optional
 from blaze_tpu import faults
 from blaze_tpu.fleet import wire
 from blaze_tpu.shuffle.ipc import FrameTransportClosed
+
+log = logging.getLogger("blaze_tpu.fleet")
 
 
 class ReplicaServer:
@@ -283,19 +286,26 @@ class ReplicaServer:
 
 def spawn_replica(replica_id: str, conf: Optional[Dict[str, Any]] = None,
                   env: Optional[Dict[str, str]] = None,
-                  startup_timeout_s: float = 60.0):
+                  startup_timeout_s: float = 60.0,
+                  platform: str = "cpu"):
     """Spawn one replica as a real process; returns (Popen, (host,
     port)).  The child prints a single `listening` JSON line once its
     socket is bound — the hello-before-dispatch contract at process
-    granularity."""
+    granularity.  `platform` is the replica's JAX_PLATFORMS, stated by
+    the spawner (host replicas by default); the child reports it back
+    in its `listening` line and it is logged here.  A replica that
+    would open the chip this process holds is refused."""
     import subprocess
+
+    from blaze_tpu.bridge.placement import refuse_chip_contention
     cmd = [sys.executable, "-m", "blaze_tpu.fleet.replica",
            "--replica-id", replica_id, "--port", "0"]
     for k, v in (conf or {}).items():
         cmd += ["--conf", f"{k}={v}"]
     child_env = dict(os.environ)
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
+    child_env["JAX_PLATFORMS"] = platform
     child_env.update(env or {})
+    refuse_chip_contention(child_env, f"replica {replica_id}")
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                             stderr=subprocess.DEVNULL, text=True,
                             env=child_env)
@@ -314,6 +324,9 @@ def spawn_replica(replica_id: str, conf: Optional[Dict[str, Any]] = None,
     if info.get("kind") != "listening":
         raise RuntimeError(
             f"replica {replica_id}: unexpected startup line {line!r}")
+    log.info("replica %s (pid %s) listening on %s:%s, platform %s",
+             replica_id, info.get("pid"), info["host"], info["port"],
+             info.get("platform"))
     return proc, (info["host"], int(info["port"]))
 
 
@@ -332,10 +345,6 @@ def replica_main(argv=None) -> int:
     ap.add_argument("--mem-bytes", type=int, default=4 << 30)
     args = ap.parse_args(argv)
 
-    if os.environ.get("BLAZE_BENCH_PLATFORM"):
-        import jax
-        jax.config.update("jax_platforms",
-                          os.environ["BLAZE_BENCH_PLATFORM"])
     from blaze_tpu import config
     from blaze_tpu.memory import MemManager
     for item in args.conf:
@@ -353,9 +362,11 @@ def replica_main(argv=None) -> int:
                          name="blaze-fleet-drain", daemon=True).start()
 
     signal.signal(signal.SIGTERM, _sigterm)
+    import jax
     print(json.dumps({"kind": "listening", "host": server.host,
                       "port": server.port, "pid": os.getpid(),
-                      "replica_id": args.replica_id}))
+                      "replica_id": args.replica_id,
+                      "platform": jax.default_backend()}))
     sys.stdout.flush()
     done.wait()
     return 0

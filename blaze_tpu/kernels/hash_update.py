@@ -37,6 +37,12 @@ SQL null grouping falls out of zeroing data limbs where the key is
 invalid and carrying the validity bit as one more limb.  All kernel
 arithmetic is int32 (Mosaic rejects i64 scalars; traced under an
 x64-off scope like mxu_agg).
+
+STATUS (PR 21, jax 0.9.0 / libtpu 0.0.34, v5e): the kernel runs in
+interpret mode only.  Mosaic refuses the per-row walk — scalar loads and
+stores at data-dependent lane indices of VMEM refs — with "Cannot store
+scalars to VMEM", so kernels/lane.py keeps it out of `auto` on TPU
+(MOSAIC_REFUSED) until it is redesigned as a vector walk (ROADMAP S4).
 """
 
 from __future__ import annotations
@@ -46,12 +52,6 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax._src.config import enable_x64 as _x64_scope
-except Exception:  # pragma: no cover - private API fallback
-    import contextlib
-    _x64_scope = lambda _v: contextlib.nullcontext()  # noqa: E731
 
 
 # ---------------------------------------------------------------------------
@@ -157,11 +157,22 @@ def _make_kernel(n: int, S: int, L: int):
     return kernel
 
 
+def _sublane_pad(rows: int) -> int:
+    return -(-rows // 8) * 8
+
+
 def vmem_estimate(n: int, S: int, L: int) -> int:
-    """Bytes of VMEM the placement kernel keeps live: inputs + outputs +
-    scratch, all i32 and all grid-resident (constant index maps)."""
-    return 4 * (2 * (L + 1) * S      # tab0 + tab scratch, used0 + used
-                + (L + 4) * n)       # h, limbs, pend0/pend, placed, wslot
+    """Bytes of VMEM the placement kernel occupies as Mosaic lays it
+    out: every (r, c) int32 block pads r up to 8 sublanes (a (1, n) row
+    costs 8n words), pipelined inputs and outputs are double-buffered,
+    scratch is single-buffered."""
+    Lp = _sublane_pad(L)
+    n_rows = (2 * (8 + Lp + 8)   # h, limbs, pend0 (inputs)
+              + 2 * (8 + 8)      # placed, wslot (outputs)
+              + 8)               # pend (scratch)
+    s_rows = (2 * (8 + Lp)       # used0, tab0 (inputs)
+              + 8 + Lp)          # used, tab (scratch)
+    return 4 * (n_rows * n + s_rows * S)
 
 
 def placement(h, limbs, pend0, npend, used0, tab0, probe_rounds: int,
@@ -178,7 +189,7 @@ def placement(h, limbs, pend0, npend, used0, tab0, probe_rounds: int,
     L, S = tab0.shape
     kernel = _make_kernel(n, S, L)
     const = lambda *_: (0, 0)  # noqa: E731
-    with _x64_scope(False):
+    with jax.enable_x64(False):
         placed, wslot = pl.pallas_call(
             kernel,
             grid=(probe_rounds,),

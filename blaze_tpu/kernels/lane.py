@@ -15,9 +15,18 @@ radix partitioning — run through one of three lanes:
                     and the parity oracle for tests.
 
 One knob drives the choice (`auron.tpu.kernels.pallas` = auto/on/off):
-`auto` takes the Pallas lane only where Mosaic compiles it (TPU);
-`on` forces the kernel layer everywhere (interpret off-TPU — tests,
-benches, parity sweeps); `off` pins the scatter formulation.
+`auto` takes the Pallas lane only where Mosaic compiles it; `on` forces
+the kernel layer (interpret off-TPU — tests, benches, parity sweeps —
+and an ERROR on a TPU whose compiler refuses the kernel, never a silent
+degrade); `off` pins the scatter formulation.
+
+`MOSAIC_REFUSED` is the record of what the compiler said on the chip.
+Both kernels walk rows serially with scalar loads/stores at
+data-dependent lane indices of VMEM refs, which Mosaic does not lower;
+moving the walk state to SMEM compiles but overflows the 1 MiB SMEM at
+65,536-row batches and ran 3x slower than XLA's stable argsort at 32,768
+(CHANGES.md PR 21).  Until the kernels are redesigned (ROADMAP S4) the
+default path on TPU runs the scatter formulation BY DECISION.
 
 Lane resolution happens HOST-SIDE (at program build / dispatch time,
 never inside a traced computation) so the resolved lane can key every
@@ -31,6 +40,16 @@ bit-identity contract — the chaos suite proves it).
 from __future__ import annotations
 
 _VALID = ("auto", "on", "off")
+
+# kind -> the compiler's message, verbatim (jax 0.9.0 / libtpu 0.0.34,
+# TPU v5e).  A kind listed here is out of the TPU lane set.  The tier-1
+# test lowers both kernels for the TPU platform and fails when this
+# record goes stale, so a redesigned kernel re-enters `auto` by deleting
+# its entry.
+MOSAIC_REFUSED = {
+    "hash": "Cannot store scalars to VMEM",
+    "partition": "Cannot store scalars to VMEM",
+}
 
 
 def knob() -> str:
@@ -54,10 +73,15 @@ def resolve(kind: str) -> str:
     else:
         import jax
         on_tpu = jax.default_backend() == "tpu"
+        refused = MOSAIC_REFUSED.get(kind) if on_tpu else None
         if mode == "on":
+            if refused:
+                raise RuntimeError(
+                    f"auron.tpu.kernels.pallas=on but Mosaic refuses the "
+                    f"{kind!r} kernel on this TPU: {refused}")
             lane = "pallas" if on_tpu else "interpret"
         else:  # auto: Mosaic where it compiles, scatter elsewhere
-            lane = "pallas" if on_tpu else "scatter"
+            lane = "pallas" if on_tpu and not refused else "scatter"
     if lane != "scatter":
         try:
             faults.maybe_fail("pallas-kernel", kind=kind)

@@ -16,8 +16,10 @@ accumulation `(one_hot_hi)^T @ (w * one_hot_lo)` — one dot_general per
 row-chunk, executed on the MXU.  One-hot operands are generated on the
 VPU inside the kernel (they never touch HBM), and the output table stays
 resident in VMEM across the whole grid (constant out index_map).
-Measured on v5e: ~300M rows/s for count+2-limb sums — ~4x the best
-scatter formulation and ~30x the r4 production kernel.
+On a v5e (jax 0.9.0 / libtpu 0.0.34) the kernel compiles under Mosaic at
+every layout `plan_layout` admits and is bit-identical to the scatter
+table (chip_smoke.py); its rows/s on the directly attached chip: not
+measured.
 
 Exactness without f64 (TPU v5e emulates all 64-bit types, ~10x slower):
 values are aggregated as 8-bit LIMBS of a non-negative integer
@@ -52,6 +54,13 @@ _LIMB_BITS = 8
 _LIMB_MASK = (1 << _LIMB_BITS) - 1
 _CHUNK = 2048          # rows per sublane-row; 8 * _CHUNK rows per grid step
 _ROWS_PER_STEP = 8 * _CHUNK
+# Scoped-VMEM ceiling handed to Mosaic.  `_chunk_for` sizes the one-hot
+# operands only; the compiler also keeps the iota/compare temporaries
+# and the concatenated f32 partials live, so the real footprint is a few
+# times that estimate (22.6 MB measured at sh=256, sl=256, 6 blocks,
+# chunk=4096 — over the 16 MB default limit, well under a v5e core's
+# 128 MiB of VMEM).
+_VMEM_LIMIT_BYTES = 64 << 20
 
 
 class MxuAggLayout(NamedTuple):
@@ -174,11 +183,7 @@ def _make_kernel(layout: MxuAggLayout, chunk: int):
 def _pallas_window_table(gid, arrays, layout: MxuAggLayout,
                          interpret: bool = False):
     from jax.experimental import pallas as pl
-    try:
-        from jax._src.config import enable_x64 as _x64_scope
-    except Exception:  # pragma: no cover - private API fallback
-        import contextlib
-        _x64_scope = lambda _v: contextlib.nullcontext()  # noqa: E731
+    from jax.experimental.pallas import tpu as pltpu
 
     chunk = _chunk_for(layout)
     rows_per_step = 8 * chunk
@@ -196,7 +201,7 @@ def _pallas_window_table(gid, arrays, layout: MxuAggLayout,
     # Mosaic lowering rejects i64-typed scalars; the kernel is pure
     # i32/bf16/f32, so trace it with x64 semantics scoped off (the global
     # x64 flag exists for Arrow i64/f64 columns, not for kernel innards).
-    with _x64_scope(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             kernel,
             grid=(nblk,),
@@ -206,6 +211,8 @@ def _pallas_window_table(gid, arrays, layout: MxuAggLayout,
                                    lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((layout.sh, layout.sl * nb),
                                            jnp.int32),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(gid3, *arrs3)
 

@@ -20,7 +20,14 @@ to the stable argsort — the parity tests assert both.  Rows with
 pid >= num_partitions (parked/invalid) route to (num_partitions,
 capacity), out of every buffer's range, matching the legacy drop path;
 rank >= capacity routes the same way and the caller derives overflow
-from the counts (sum of max(0, count - capacity))."""
+from the counts (sum of max(0, count - capacity)).
+
+STATUS (PR 21, jax 0.9.0 / libtpu 0.0.34, v5e): interpret mode only.
+Mosaic refuses pass 2's scalar stores into VMEM ("Cannot store scalars
+to VMEM"); with all walk state in SMEM the kernel compiled and matched
+the stable argsort up to 32,768 rows, ran out of the 1 MiB SMEM at
+65,536 and was 3x slower than XLA's argsort.  kernels/lane.py keeps it
+out of `auto` on TPU (MOSAIC_REFUSED) until it is redesigned."""
 
 from __future__ import annotations
 
@@ -30,11 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax._src.config import enable_x64 as _x64_scope
-except Exception:  # pragma: no cover - private API fallback
-    import contextlib
-    _x64_scope = lambda _v: contextlib.nullcontext()  # noqa: E731
 
 _CHUNK = 2048  # histogram rows per vectorized compare block
 
@@ -96,10 +98,14 @@ def _make_kernel(n: int, P: int, Pp: int, capacity: int, chunk: int):
 
 
 def vmem_estimate(n: int, num_partitions: int) -> int:
+    """Bytes of VMEM as Mosaic lays the kernel out: each (1, c) int32
+    row pads to 8 sublanes, the pipelined pid input and the four outputs
+    are double-buffered, the cursor scratch is single-buffered, and the
+    histogram holds one iota and one compare block."""
     Pp = -(-(num_partitions + 1) // 128) * 128
-    # pid + part + slot + order, the histogram compare block, 4 cursor
-    # rows (counts/starts/cur + iota)
-    return 4 * (4 * n + _CHUNK * Pp + 4 * Pp)
+    return 4 * (2 * 8 * 4 * n          # pid + part + slot + order
+                + 2 * _CHUNK * Pp      # histogram iota + one-hot
+                + (2 * 8 + 2 * 8) * Pp)  # counts (out) + starts/cur
 
 
 @functools.lru_cache(maxsize=64)
@@ -118,7 +124,7 @@ def _ranks_call(n: int, num_partitions: int, capacity: int,
     def call(pid):
         pid = jnp.clip(pid, 0, P).astype(jnp.int32)
         pid = jnp.pad(pid, (0, npad - n), constant_values=P)
-        with _x64_scope(False):
+        with jax.enable_x64(False):
             part, slot, order, counts = pl.pallas_call(
                 kernel,
                 grid=(1,),

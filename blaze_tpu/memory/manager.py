@@ -334,13 +334,13 @@ def default_budget_bytes() -> int:
     fraction formula of the reference, NativeHelper.scala:51-73)."""
     import jax
     frac = config.MEMORY_FRACTION.get()
-    try:
-        stats = jax.devices()[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"] * frac)
-    except Exception:
-        pass
-    # CPU fallback: host memory bounded by the process-RSS fraction
+    # memory_stats() is None on the CPU backend and a dict with
+    # bytes_limit on a TPU (16.9e9 on a v5e); an accelerator that cannot
+    # report it is an error, not a 4 GiB host budget in silence
+    stats = jax.devices()[0].memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"] * frac)
+    # CPU backend: host memory bounded by the process-RSS fraction
     # (ref auron.process.vmrss.memoryFraction), nominally capped at 4 GiB
     try:
         phys = os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")

@@ -781,7 +781,7 @@ def _device_to_arrow(data: jax.Array, valid: jax.Array, n: int) -> pa.Array:
 class _ArrowSink:
     """Collects output columns, deferring device arrays so ALL of them come
     back in ONE batched device_get — per-column syncs each cost a full
-    round trip on a tunneled device."""
+    dispatch round trip (~1 ms on a directly attached v5e)."""
 
     def __init__(self):
         self._items: List = []  # pa.Array | ("dev", data, valid, n)

@@ -21,19 +21,8 @@ from blaze_tpu.bridge.xla_stats import meter_jit
 
 DP_AXIS = "dp"
 
-
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions.  Newer jax exposes it at the
-    top level with `check_vma`; 0.4.x only has
-    jax.experimental.shard_map with the older `check_rep` flag.  Both
-    checks are disabled for the same reason: the collective programs
-    here intentionally mix per-device and replicated intermediates."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+# Every shard_map here passes check_vma=False: the collective programs
+# intentionally mix per-device and replicated intermediates.
 
 
 def make_mesh(num_devices: Optional[int] = None,
@@ -108,7 +97,8 @@ def distributed_grouped_agg(mesh: Mesh, key_specs, agg_specs,
         # (1,)-axis so out_specs P('dp') stacks per-device counts
         return final._replace(num_groups=final.num_groups.reshape(1))
 
-    sharded = shard_map_compat(stage, mesh, P(DP_AXIS), P(DP_AXIS))
+    sharded = jax.shard_map(stage, mesh=mesh, in_specs=P(DP_AXIS),
+                            out_specs=P(DP_AXIS), check_vma=False)
     return meter_jit(sharded, name="mesh.grouped_agg")
 
 
@@ -196,7 +186,8 @@ def distributed_sort(mesh: Mesh, num_payloads: int, capacity: int,
         return tuple([out_keys, out_valid] + out_payloads +
                      [overflow.reshape(1)])
 
-    sharded = shard_map_compat(stage, mesh, P(DP_AXIS), P(DP_AXIS))
+    sharded = jax.shard_map(stage, mesh=mesh, in_specs=P(DP_AXIS),
+                            out_specs=P(DP_AXIS), check_vma=False)
     return meter_jit(sharded, name="mesh.sort")
 
 
@@ -283,7 +274,8 @@ def distributed_hash_join(mesh: Mesh, num_build_payloads: int,
         return tuple([jkeys, pair_valid] + out_b + out_p +
                      [counts.reshape(3)])
 
-    sharded = shard_map_compat(stage, mesh, P(DP_AXIS), P(DP_AXIS))
+    sharded = jax.shard_map(stage, mesh=mesh, in_specs=P(DP_AXIS),
+                            out_specs=P(DP_AXIS), check_vma=False)
     return meter_jit(sharded, name="mesh.hash_join")
 
 
@@ -319,7 +311,8 @@ def distributed_broadcast_join_agg(mesh: Mesh, build_capacity: int):
         return (jax.lax.psum(sums, DP_AXIS),
                 jax.lax.psum(counts, DP_AXIS))
 
-    sharded = shard_map_compat(stage, mesh,
-                               (P(), P(DP_AXIS), P(DP_AXIS), P(DP_AXIS)),
-                               (P(), P()))
+    sharded = jax.shard_map(
+        stage, mesh=mesh,
+        in_specs=(P(), P(DP_AXIS), P(DP_AXIS), P(DP_AXIS)),
+        out_specs=(P(), P()), check_vma=False)
     return meter_jit(sharded, name="mesh.broadcast_join_agg")

@@ -533,7 +533,7 @@ def _exchange_program(mesh, n_out: int, capacity: int,
     from blaze_tpu.bridge.xla_stats import meter_jit
     from blaze_tpu.parallel.collective import (all_to_all_rows,
                                                partition_ids_for_keys)
-    from blaze_tpu.parallel.mesh import DP_AXIS, shard_map_compat
+    from blaze_tpu.parallel.mesh import DP_AXIS
 
     n_dev = mesh.shape[DP_AXIS]
     ncols = len(dtypes)
@@ -552,7 +552,8 @@ def _exchange_program(mesh, n_out: int, capacity: int,
             row_valid, dev, DP_AXIS, n_dev, capacity, lane=lane)
         return tuple(out_cols) + (out_valid, overflow.reshape(1))
 
-    sharded = shard_map_compat(stage, mesh, PS(DP_AXIS), PS(DP_AXIS))
+    sharded = jax.shard_map(stage, mesh=mesh, in_specs=PS(DP_AXIS),
+                            out_specs=PS(DP_AXIS), check_vma=False)
     return meter_jit(sharded, name="mesh.exchange_rows")
 
 

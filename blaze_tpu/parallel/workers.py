@@ -184,6 +184,7 @@ class _Slot:
         self.inbox: "queue.Queue" = queue.Queue()
         self.write_lock = threading.Lock()
         self.device_spec: Optional[Dict[str, Any]] = None  # hello frame
+        self.platform: Optional[str] = None  # hello frame: JAX_PLATFORMS
         self.cpu_ns = 0            # child CPU (user+sys) across tasks
 
     def pid(self) -> Optional[int]:
@@ -249,8 +250,12 @@ class WorkerPool:
         t.start()
 
     @staticmethod
-    def _child_env(slot: _Slot) -> Optional[Dict[str, str]]:
-        """Spawn env for one child; None inherits the parent env as-is.
+    def _child_env(slot: _Slot) -> Dict[str, str]:
+        """Spawn env for one child.  Its platform is always decided here
+        and stated: a child inherits the parent's JAX_PLATFORMS, and a
+        child that would open the accelerator its parent holds is
+        refused (bridge/placement.py refuse_chip_contention) — the pool then
+        fails to start and tasks run in-process.
         With workers.pinDevices each child is pinned to exactly ONE
         emulated device (`JAX_PLATFORMS=cpu`,
         `--xla_force_host_platform_device_count=1`) — the
@@ -260,16 +265,17 @@ class WorkerPool:
         device-count flag inherited from a multichip parent is stripped
         first (the parent emulates N devices; its children must not)."""
         from blaze_tpu import config
-        if not config.WORKERS_PIN_DEVICES.get():
-            return None
+        from blaze_tpu.bridge.placement import refuse_chip_contention
         env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                       env.get("XLA_FLAGS", "")).strip()
-        env["XLA_FLAGS"] = (flags +
-                            " --xla_force_host_platform_device_count=1"
-                            ).strip()
-        env["BLAZE_WORKER_DEVICE_SLOT"] = str(slot.id)
+        if config.WORKERS_PIN_DEVICES.get():
+            env["JAX_PLATFORMS"] = "cpu"
+            flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
+                           "", env.get("XLA_FLAGS", "")).strip()
+            env["XLA_FLAGS"] = (flags +
+                                " --xla_force_host_platform_device_count=1"
+                                ).strip()
+            env["BLAZE_WORKER_DEVICE_SLOT"] = str(slot.id)
+        refuse_chip_contention(env, f"worker {slot.id}")
         return env
 
     def _reader(self, slot: _Slot, proc: subprocess.Popen,
@@ -284,9 +290,12 @@ class WorkerPool:
                     break
                 kind = msg.get("kind")
                 if kind == "hello":
+                    log.info("worker %d (pid %s) up on platform %s",
+                             slot.id, msg.get("pid"), msg.get("platform"))
                     with self._cond:
                         if slot.proc is proc and slot.state == _STARTING:
                             slot.state = _IDLE
+                            slot.platform = msg.get("platform")
                             slot.device_spec = msg.get("device_spec")
                             slot.last_heartbeat = time.monotonic()
                             self._cond.notify_all()
@@ -752,6 +761,7 @@ class WorkerPool:
             return [{"worker": s.id, "pid": s.pid(), "state": s.state,
                      "crashes": s.crashes, "tasks_done": s.tasks_done,
                      "incarnation": s.incarnation,
+                     "platform": s.platform,
                      "device_spec": s.device_spec,
                      "cpu_s": s.cpu_ns / 1e9,
                      "heartbeat_age_ms": int((now - s.last_heartbeat) * 1e3)
@@ -1067,7 +1077,12 @@ def child_main() -> int:
     sys.stdout = sys.stderr
     out_lock = threading.Lock()
     signal.signal(signal.SIGTERM, lambda *_: os._exit(143))
-    hello: Dict[str, Any] = {"kind": "hello", "pid": os.getpid()}
+    # the platform is stated from the spawn env alone: importing jax in
+    # the frame loop would initialize a backend before the first task's
+    # conf snapshot lands
+    hello: Dict[str, Any] = {
+        "kind": "hello", "pid": os.getpid(),
+        "platform": os.environ.get("JAX_PLATFORMS") or "default"}
     spec = _child_device_spec()
     if spec is not None:
         hello["device_spec"] = spec

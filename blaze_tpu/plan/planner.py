@@ -446,9 +446,9 @@ def exchange_device_spec(partitioning: Optional[Dict[str, Any]],
     placement) AND more than one device in the mesh — or the stage
     loop is forced on (auron.tpu.stage.deviceLoop.enable=on), whose
     device-resident map output should stay D2D.  Host-pinned
-    placement (CPU tests, tunneled backends) keeps the file path: there
-    the collective is emulation-only overhead, and a 1-device
-    collective never beats the local fast path.
+    placement (CPU tests, auron.tpu.placement=host) keeps the file
+    path: there the collective is emulation-only overhead, and a
+    1-device collective never beats the local fast path.
     """
     from blaze_tpu import config
 

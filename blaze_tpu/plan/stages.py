@@ -39,7 +39,7 @@ import pyarrow as pa
 
 from blaze_tpu.bridge.metrics import MetricNode
 from blaze_tpu.bridge.resource import put_resource, remove_resource
-from blaze_tpu.faults import FetchFailedError
+from blaze_tpu.faults import FetchFailedError, InjectedFault
 
 log = logging.getLogger("blaze_tpu.stages")
 
@@ -819,6 +819,8 @@ class DagScheduler:
                 if self._is_cancellation(e):
                     raise
                 from blaze_tpu.bridge import tracing, xla_stats
+                self._note_undeclared_fallback("device_shuffle", e,
+                                               stage=stage.sid)
                 xla_stats.note_device_shuffle_fallback()
                 tracing.instant("device_shuffle_fallback",
                                 stage=stage.sid, error=type(e).__name__)
@@ -839,6 +841,28 @@ class DagScheduler:
                                 error=type(e).__name__)
         self._run_producer_file(stage)
         self._maybe_store_subplan(stage)
+
+    @staticmethod
+    def _note_undeclared_fallback(site: str, e: Exception,
+                                  **where) -> None:
+        """The device tiers fall back on ANY failure, but only the
+        engine's own declared degradations (typed ineligibility,
+        capacity overflow, scripted chaos) may do so quietly.  Anything
+        else — a lowering or compile error above all — is logged at
+        ERROR with its traceback and kept in xla_stats, so it cannot
+        pass unseen with tracing off while the reference path returns
+        identical results."""
+        from blaze_tpu.parallel.stage import DeviceExchangeError
+        from blaze_tpu.plan.stage_compiler import StageLoopIneligible
+        from blaze_tpu.runtime.loop import StageLoopFallback
+        if isinstance(e, (DeviceExchangeError, StageLoopFallback,
+                          StageLoopIneligible, InjectedFault)):
+            return
+        from blaze_tpu.bridge import xla_stats
+        log.error("%s fell back on an undeclared error (%s)", site,
+                  ", ".join(f"{k}={v}" for k, v in where.items()),
+                  exc_info=e)
+        xla_stats.note_unexpected_fallback(site, e, **where)
 
     @staticmethod
     def _rss_root() -> Optional[str]:
@@ -905,7 +929,9 @@ class DagScheduler:
         except Exception as e:
             if self._is_cancellation(e):
                 raise
-            xla_stats.note_stage_loop_fallback()
+            self._note_undeclared_fallback("stage_loop", e,
+                                           stage=stage.sid, task=m)
+            xla_stats.note_stage_loop_fallback(str(e))
             tracing.instant("stage_loop_fallback", stage=stage.sid,
                             task=m, reason=str(e))
             return None

@@ -1,8 +1,9 @@
 """Persistent device loop for compiled stage programs.
 
 The staged executor dispatches one XLA program per batch (the fused
-chain step) plus a host sync for the overflow scalar — at BENCH_SF100's
-~100ms dispatch RTT the engine is dispatch-bound, not compute-bound.
+chain step) plus a host sync for the overflow scalar, so at a
+millisecond per dispatch round trip (1.0 ms on a directly attached v5e,
+CHANGES.md PR 21) the engine is dispatch-bound, not compute-bound.
 This loop folds a CHUNK of bucket-padded batches per dispatch:
 `lax.fori_loop` runs chain + probe-insert + accumulate for every batch
 of the chunk inside ONE program, carrying the agg hash table across
@@ -241,6 +242,7 @@ def run_partition(program, partition: int, ctx: str = "",
     xla_stats.note_stage_loop_task(
         chunks=fold_calls, batches=batches, rows=rows, regrows=regrows,
         dispatches_avoided=max(0, batches - fold_calls))
+    program.agg._note_lane(batches)
     return carry
 
 

@@ -2,10 +2,10 @@
 
 The engine's per-batch glue (padding, masks, promotions, null plumbing)
 historically ran as *eager* jax ops.  Each eager dispatch costs ~0.1-1 ms
-of XLA program-launch overhead; a SF1 query issues hundreds of them, so
-fixed cost — not kernels — dominated the wall clock (BENCH_r03:
-vs_baseline 0.297 with roofline_frac 2.6e-05).  The reference has no such
-boundary tax: its glue is plain Rust (ref
+of XLA program-launch overhead (a directly attached v5e measured 1.0 ms
+per dispatch+readback, CHANGES.md PR 21); a SF1 query issues hundreds of
+them, so fixed cost — not kernels — dominates the wall clock.  The
+reference has no such boundary tax: its glue is plain Rust (ref
 datafusion-ext-plans/src/common/cached_exprs_evaluator.rs).
 
 The fix mirrors the reference's split between scalar glue and vectorized

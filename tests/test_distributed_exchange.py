@@ -17,7 +17,6 @@ import pytest
 from blaze_tpu.parallel import (DP_AXIS, all_to_all_rows,
                                 distributed_hash_join, distributed_sort,
                                 make_mesh, shard_rows)
-from blaze_tpu.parallel.mesh import shard_map_compat
 from jax.sharding import PartitionSpec as P
 
 NDEV = 8
@@ -45,7 +44,8 @@ def test_all_to_all_rows_roundtrip(mesh):
                                              NDEV, cap)
         return cols[0], cols[1], valid_r, ovf.reshape(1)
 
-    fn = jax.jit(shard_map_compat(stage, mesh, P(DP_AXIS), P(DP_AXIS)))
+    fn = jax.jit(jax.shard_map(stage, mesh=mesh, in_specs=P(DP_AXIS),
+                               out_specs=P(DP_AXIS), check_vma=False))
     k, v, ok, p = shard_rows(mesh, jnp.asarray(keys), jnp.asarray(vals),
                              jnp.asarray(valid), jnp.asarray(pid))
     rk, rv, rvalid, ovf = fn(k, v, ok, p)
@@ -80,7 +80,8 @@ def test_all_to_all_rows_overflow_detected(mesh):
                                              NDEV, cap)
         return cols[0], valid_r, ovf.reshape(1)
 
-    fn = jax.jit(shard_map_compat(stage, mesh, P(DP_AXIS), P(DP_AXIS)))
+    fn = jax.jit(jax.shard_map(stage, mesh=mesh, in_specs=P(DP_AXIS),
+                               out_specs=P(DP_AXIS), check_vma=False))
     k, ok, p = shard_rows(mesh, jnp.asarray(keys),
                           jnp.asarray(valid), jnp.asarray(pid))
     rk, rvalid, ovf = fn(k, ok, p)

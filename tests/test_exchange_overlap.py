@@ -4,6 +4,7 @@ the cached shard_map collective, the staged scheduler's overlap path
 compile per ladder rung), the process-per-device worker pinning with
 real child CPU accounting, and the compressed worker/RSS wire frames."""
 
+import os
 import threading
 
 import numpy as np
@@ -229,7 +230,8 @@ def test_child_env_pins_exactly_one_device(monkeypatch):
     from blaze_tpu.parallel.workers import (_child_device_spec, _Slot,
                                             WorkerPool)
     slot = _Slot(3)
-    assert WorkerPool._child_env(slot) is None  # knob off: inherit parent
+    # knob off: the parent's environment, handed over explicitly
+    assert WorkerPool._child_env(slot) == dict(os.environ)
     monkeypatch.setenv("XLA_FLAGS",
                        "--xla_force_host_platform_device_count=8")
     config.conf.set(config.WORKERS_PIN_DEVICES.key, True)

@@ -80,10 +80,10 @@ def test_q01_spills_under_pressure(tmp_path):
 @pytest.mark.slow
 def test_wire_query_on_real_accelerator():
     """Device-placement wire path on REAL accelerator hardware: q52
-    through DagScheduler with auron.tpu.placement=device.  Skips on
-    CPU-only environments (the itest/CI tier pins jax to cpu); run
-    without JAX_PLATFORMS to exercise the actual chip (see
-    DEVICE_WIRE_r04.json for a recorded run)."""
+    through DagScheduler with auron.tpu.placement=device.  conftest.py
+    pins pytest to the CPU platform, so this skips everywhere pytest
+    runs; the chip's proof of the same path is `python chip_smoke.py`
+    (q01 SF10 and q06 SF1 through DagScheduler, CHANGES.md PR 21)."""
     import jax
 
     from blaze_tpu import config

@@ -4,6 +4,7 @@ falls back WHOLESALE on injected faults and degraded queries (never a
 divergent result, never a burned retry), and tears down within one
 chunk of a cancellation with a clean leak report."""
 
+import logging
 import threading
 
 import numpy as np
@@ -173,8 +174,76 @@ def test_injected_fault_falls_back_wholesale(tmp_path, staged_path,
     assert d["stage_loop_tasks"] == 0  # no loop task reached the drain
     # a fallback is an in-attempt re-run, NOT a task retry
     assert d["task_retries"] == 0
+    # scripted chaos is a DECLARED degradation: counted, not an error
+    assert d["unexpected_fallbacks"] == 0
+    assert any("injected fault" in r
+               for r in xla_stats.stage_loop_fallback_reasons())
     comp = {p["compute"] for p in sched.stage_placement.values()}
     assert "device-loop" not in comp, sched.stage_placement
+
+
+def _clean_result(tmp_path, plan):
+    config.conf.set(config.STAGE_DEVICE_LOOP_ENABLE.key, "off")
+    clean = _sorted_df(DagScheduler(
+        work_dir=str(tmp_path / "dag-clean")).run_collect(plan))
+    config.conf.set(config.STAGE_DEVICE_LOOP_ENABLE.key, "on")
+    return clean
+
+
+def _assert_loud(caplog, d, site, text):
+    """An UNDECLARED device-tier failure: still a fallback, but an ERROR
+    log with its traceback and its text kept in xla_stats — with tracing
+    off, the default, where tracing.instant is a no-op."""
+    from blaze_tpu.bridge import tracing
+    assert not tracing.enabled()
+    assert d["unexpected_fallbacks"] >= 1
+    kept = [e for e in xla_stats.fallback_errors() if e["site"] == site]
+    assert kept and text in kept[-1]["message"], xla_stats.fallback_errors()
+    assert kept[-1]["error"] == "ValueError"
+    logged = [r for r in caplog.records
+              if r.levelno == logging.ERROR and r.exc_info
+              and r.name == "blaze_tpu.stages"]
+    assert logged, [r.getMessage() for r in caplog.records]
+    assert text in caplog.text and "Traceback" in caplog.text
+
+
+def test_undeclared_stage_loop_error_is_logged_and_kept(
+        tmp_path, staged_path, loop_on, monkeypatch, caplog):
+    plan = _two_stage_plan(tmp_path, tag="und")
+    clean = _clean_result(tmp_path, plan)
+    from blaze_tpu.runtime import loop as device_loop
+
+    def refuse(*_a, **_k):  # what a Mosaic lowering error looks like
+        raise ValueError("Cannot store scalars to VMEM")
+
+    monkeypatch.setattr(device_loop, "run_partition", refuse)
+    before = xla_stats.snapshot()
+    with caplog.at_level(logging.ERROR):
+        got = _sorted_df(DagScheduler(
+            work_dir=str(tmp_path / "dag-und")).run_collect(plan))
+    assert got.equals(clean)  # the staged re-run still answers
+    d = xla_stats.delta(before)
+    assert d["stage_loop_fallbacks"] >= 1
+    _assert_loud(caplog, d, "stage_loop", "Cannot store scalars to VMEM")
+
+
+def test_undeclared_device_producer_error_is_logged_and_kept(
+        tmp_path, staged_path, loop_on, monkeypatch, caplog):
+    plan = _two_stage_plan(tmp_path, tag="undp")
+    clean = _clean_result(tmp_path, plan)
+
+    def refuse(self, *_a, **_k):
+        raise ValueError("RESOURCE_EXHAUSTED: vmem")
+
+    monkeypatch.setattr(DagScheduler, "_exchange_sync", refuse)
+    before = xla_stats.snapshot()
+    with caplog.at_level(logging.ERROR):
+        got = _sorted_df(DagScheduler(
+            work_dir=str(tmp_path / "dag-undp")).run_collect(plan))
+    assert got.equals(clean)  # the file shuffle still answers
+    d = xla_stats.delta(before)
+    assert d["shuffle_device_fallbacks"] >= 1
+    _assert_loud(caplog, d, "device_shuffle", "RESOURCE_EXHAUSTED: vmem")
 
 
 def test_degraded_query_declines_loop(tmp_path, staged_path, loop_on):
