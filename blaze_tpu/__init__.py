@@ -65,3 +65,8 @@ __all__ = [
     "COMPILE_CACHE_DIR",
     "__version__",
 ]
+
+# count what JAX really compiles (eager glue included) from the first
+# program on: bridge/xla_stats.py `backend_compiles`
+from blaze_tpu.bridge import xla_stats as _xla_stats  # noqa: E402
+_xla_stats.listen_backend_compiles()

@@ -35,7 +35,7 @@ import pyarrow as pa
 
 from blaze_tpu import config
 from blaze_tpu.schema import DataType, Field, Schema, TypeId
-from blaze_tpu.xputil import asnp, xp_of
+from blaze_tpu.xputil import asnp, to_device, to_host, xp_of
 
 LANE = 128  # TPU lane width; device buffers are padded to a multiple of this
 
@@ -188,9 +188,8 @@ class DeviceColumn:
         v[:n] = True if valid is None else valid
         if stage_host or _host_resident():
             return DeviceColumn(dtype, data, v)
-        from blaze_tpu.bridge import xla_stats
-        xla_stats.note_h2d(data.nbytes + v.nbytes)
-        return DeviceColumn(dtype, jnp.asarray(data), jnp.asarray(v))
+        data, v = to_device((data, v))
+        return DeviceColumn(dtype, data, v)
 
     @staticmethod
     def from_arrow(arr: pa.Array, dtype: DataType, capacity: int,
@@ -276,10 +275,8 @@ class DictColumn(DeviceColumn):
         v[:n] = True if valid is None else valid
         if stage_host or _host_resident():
             return DictColumn(dtype, data, v, dictionary=dictionary)
-        from blaze_tpu.bridge import xla_stats
-        xla_stats.note_h2d(data.nbytes + v.nbytes)
-        return DictColumn(dtype, jnp.asarray(data), jnp.asarray(v),
-                          dictionary=dictionary)
+        data, v = to_device((data, v))
+        return DictColumn(dtype, data, v, dictionary=dictionary)
 
     @staticmethod
     def from_arrow_dict(arr: pa.DictionaryArray, dtype: DataType,
@@ -449,9 +446,8 @@ class ColumnBatch:
         skip rows a filter already deselected — filters only set
         `selection` without compacting, so expression evaluators still
         see deselected rows' values (see Cast._ansi_check_device)."""
-        import numpy as _np
         n = self.num_rows if n is None else n
-        return _np.asarray(self.row_mask())[:n]
+        return asnp(self.row_mask())[:n]
 
     def is_selected(self, row: int) -> bool:
         """Row-level selection probe for raise-gating paths (ANSI casts,
@@ -470,7 +466,7 @@ class ColumnBatch:
             return self.num_rows
         c = getattr(self, "_sel_count", None)
         if c is None:
-            c = int(self._xp().sum(self.row_mask()))
+            c = int(to_host(self._xp().sum(self.row_mask())))
             self._sel_count = c  # dataclasses.replace drops the cache
         return c
 
@@ -492,9 +488,7 @@ class ColumnBatch:
         for i in idx:
             bufs.append(self.columns[i].data)
             bufs.append(np.asarray(self.columns[i].validity))
-        placed = jax.device_put(bufs)
-        from blaze_tpu.bridge import xla_stats
-        xla_stats.note_h2d(sum(b.nbytes for b in bufs))
+        placed = to_device(bufs)
         cols = list(self.columns)
         for j, i in enumerate(idx):
             # replace() preserves the column subclass (DictColumn keeps
@@ -560,12 +554,7 @@ class ColumnBatch:
         if to_fetch and all(isinstance(x, np.ndarray) for x in to_fetch):
             fetched = to_fetch  # host-resident: nothing to sync
         else:
-            fetched = jax.device_get(to_fetch) if to_fetch else []
-            if to_fetch:
-                from blaze_tpu.bridge import xla_stats
-                xla_stats.note_d2h(sum(
-                    x.nbytes for x, src in zip(fetched, to_fetch)
-                    if not isinstance(src, np.ndarray)))
+            fetched = to_host(to_fetch) if to_fetch else []
         pos = 0
         sel = None
         if self.selection is not None:

@@ -15,7 +15,8 @@ Categories (docs/observability.md keeps the table):
 - ``retry_backoff``   lineage-recovery backoff sleeps (backoff_wait)
 - ``exchange_wire``   device/rss/shuffle exchange spans — data motion
 - ``device_compute``  stage-loop device chunks + XLA compiles
-- ``scan_decode``     operator:*Scan* decode time
+- ``scan_decode``     produce:parquet_scan — decode + placement of one
+                      scan batch on the prefetch worker (a real interval)
 - ``host_compute``    any other covered time (task bodies, host ops)
 - ``barrier_idle``    uncovered time immediately before an exchange
                       segment — the map→exchange barrier
@@ -51,8 +52,8 @@ def _category(name: str) -> Optional[str]:
         return "exchange_wire"
     if name in ("stage_loop_chunk", "xla_compile"):
         return "device_compute"
-    if name.startswith("operator:"):
-        return "scan_decode" if "Scan" in name else "host_compute"
+    if name == "produce:parquet_scan":
+        return "scan_decode"
     if name in ("task", "task_attempt", "worker_task", "stream_epoch",
                 "stage_recovery", "explain_analyze"):
         return "host_compute"

@@ -44,7 +44,10 @@ class PlacementInfo:
 def _measure_rtt_ms() -> float:
     import jax
     import jax.numpy as jnp
-    f = jax.jit(lambda a: (a + 1).sum())
+    def placement_rtt_probe(a):
+        return (a + 1).sum()
+
+    f = jax.jit(placement_rtt_probe)
     x = jnp.ones(8)
     float(f(x))  # compile + warm
     samples = []

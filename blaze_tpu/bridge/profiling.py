@@ -166,6 +166,7 @@ def prometheus_text() -> str:
         "cache": "cross-query work sharing",
         "stats": "statistics feedback plane",
         "fleet": "replicated serving fleet",
+        "backend": "jax backend compile",
     }
     families = xla_stats.counter_families()
     for fam in sorted(families):

@@ -27,7 +27,12 @@ check — operators call `span(...)` unconditionally.
 Every span name the runtime can emit is registered in SPAN_NAMES
 (enforced by tests/test_span_names.py: undocumented or dead names fail
 conformance).  Names with a trailing `*` are prefix families — the
-suffix is dynamic (operator class names).
+suffix is dynamic (prefetcher names).
+
+While tracing is on, `span()` also enters a `jax.profiler`
+`TraceAnnotation` of the same name, so a profiler trace taken through
+`/trace/start` shows the host spans on the timeline of the device
+programs.  Without a profiler session the annotation records nothing.
 """
 
 from __future__ import annotations
@@ -47,6 +52,7 @@ _MAX_SPANS = 100_000
 _sink = None  # open JSONL file, when exporting
 _tls = threading.local()
 _ids = itertools.count(1)
+_annotation = None  # jax.profiler.TraceAnnotation, imported on first use
 
 # Worker-child mode: spans are buffered locally and shipped back to the
 # parent in heartbeat/result frames instead of accumulating here.
@@ -87,9 +93,27 @@ SPAN_NAMES: Dict[str, str] = {
                     "window/watermark -> sink attempt -> checkpoint "
                     "commit (streaming/executor.py; attrs epoch/rows)",
     "explain_analyze": "whole-query profiled execution (plan/explain.py)",
-    "operator:*": "per-operator stream total accumulated across next() "
-                  "calls; suffix is the ExecutionPlan class name "
-                  "(ops/base.py stream meter)",
+    "d2h": "one blocking device-to-host readback: the caller waits for "
+           "the value and the programs that produce it (xputil.to_host; "
+           "attrs bytes)",
+    "h2d": "one host-to-device placement; device_put returns before the "
+           "copy lands, so this is host staging and dispatch time "
+           "(xputil.to_device; attrs bytes)",
+    "prefetch_wait": "the consumer blocked on a prefetch queue: the "
+                     "producer thread is behind (ops/base.py "
+                     "PrefetchIterator.__next__; attrs source)",
+    "produce:*": "one item produced on a prefetch worker thread, "
+                 "next(source) plus transform; suffix is the prefetcher "
+                 "name, e.g. produce:parquet_scan = decode, dictionary "
+                 "encoding, from_arrow and the nested h2d (ops/base.py "
+                 "PrefetchIterator._work; attrs rows)",
+    "join_build": "a join's build side collected (step=collect) or "
+                  "hash-indexed (step=index) (ops/joins/exec.py)",
+    "join_probe": "one probe batch from hashed keys to joined batch, or "
+                  "one Arrow-lane join over the collected probe side "
+                  "(ops/joins/exec.py; attrs rows)",
+    "agg_drain": "an aggregation table read back and turned into an "
+                 "Arrow batch (plan/fused.py _emit_*; attrs table)",
     # -- instants (dur_ns == 0) ---------------------------------------
     "task_retry": "a failed attempt was classified retryable and will "
                   "back off and retry (bridge/tasks.py)",
@@ -225,12 +249,44 @@ def execution_context(**fields):
         stack.pop()
 
 
+def capture() -> tuple:
+    """(execution context, enclosing span id) of this thread, for a
+    helper thread to `adopt()`: thread-locals do not follow the work."""
+    stack = getattr(_tls, "span_stack", None)
+    return current_context(), (stack[-1] if stack else None)
+
+
+@contextmanager
+def adopt(captured: tuple):
+    """Helper-thread side of `capture()`: spans emitted in the body carry
+    the capturing thread's query/stage/partition and parent under its
+    enclosing span (what remote_task_scope does across processes)."""
+    ctx, parent = captured
+    stack = _span_stack()
+    if parent is not None:
+        stack.append(parent)
+    try:
+        with execution_context(**ctx):
+            yield
+    finally:
+        if parent is not None:
+            stack.pop()
+
+
+def _profiler_annotation(name: str):
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)
+
+
 @contextmanager
 def span(name: str, **attrs):
     """Emit one span covering the `with` body.  No-op when disabled."""
     if not _enabled:
         if _conf_probed or not enabled():
-            yield
+            yield attrs
             return
     _check_name(name)
     sid = next(_ids)
@@ -239,7 +295,8 @@ def span(name: str, **attrs):
     stack.append(sid)
     t0 = time.perf_counter_ns()
     try:
-        yield
+        with _profiler_annotation(name):
+            yield attrs  # the body may add what it learns (rows, bytes)
     finally:
         t1 = time.perf_counter_ns()
         stack.pop()

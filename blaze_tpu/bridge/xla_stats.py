@@ -10,8 +10,10 @@ native/JVM and host/device boundary costs):
   dispatch is classified compile vs cache-hit, compile time accumulates
   per kernel, and shape churn (many distinct signatures on one kernel)
   is flagged.
-* transfer volume — H2D on batch placement, D2H on Arrow export /
-  host fetches.  `note_h2d`/`note_d2h` are called from the batch layer.
+* transfer volume and time — every crossing of the host/device boundary
+  goes through `xputil.to_host` / `xputil.to_device`, which call
+  `note_d2h`/`note_h2d` with the bytes moved and the nanoseconds the
+  calling thread spent there.
 
 Compile detection is portable across jax versions: the traced Python
 function only RUNS when XLA is actually tracing (i.e. compiling) the
@@ -21,6 +23,7 @@ call; a cache hit never re-enters it.
 from __future__ import annotations
 
 import functools
+import re
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -29,8 +32,11 @@ _lock = threading.Lock()
 
 # kernel name -> stats dict
 _kernels: Dict[str, Dict[str, Any]] = {}
-_transfers = {"h2d_bytes": 0, "h2d_transfers": 0,
-              "d2h_bytes": 0, "d2h_transfers": 0}
+# h2d_ns is host staging + dispatch time only: device_put returns before
+# the copy lands.  d2h_wait_ns is the time the caller was blocked, which
+# includes waiting for the programs that produce the value.
+_transfers = {"h2d_bytes": 0, "h2d_transfers": 0, "h2d_ns": 0,
+              "d2h_bytes": 0, "d2h_transfers": 0, "d2h_wait_ns": 0}
 # batch-shaping + IO-pipeline counters (batch.bucket_capacity /
 # ops.base.PrefetchIterator): how many capacity requests were quantized
 # onto the bucket ladder (and the padding that cost), and how often the
@@ -249,6 +255,16 @@ _SAMPLE_CAP = 8192
 HISTOGRAM_BUCKETS_S = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
                        1.0, 2.5, 5.0, 10.0, 30.0)
 
+# What JAX itself asked its backend for (jax.monitoring events): every
+# program, the eager glue ones included, that `meter_jit` never sees.
+# backend_compiles counts compile requests (a persistent-cache hit is
+# also a request, answered by compile_cache_hits).
+_backend = {"backend_compiles": 0, "backend_compile_ns": 0,
+            "compile_cache_hits": 0}
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_backend_listening = False
+
 # Distinct signatures beyond this on one kernel = shape churn (the
 # recompilation-storm smell: unpadded dynamic shapes hitting jit).
 SHAPE_CHURN_THRESHOLD = 8
@@ -279,6 +295,51 @@ def _signature(args, kwargs) -> tuple:
             tuple(sorted((k, one(v)) for k, v in kwargs.items())))
 
 
+_NOT_WORD = re.compile(r"[^A-Za-z0-9_]")
+
+
+def program_name(fun_name: str, kernel: str) -> str:
+    """The `__name__` handed to `jax.jit` for a metered kernel:
+    `<function>__<kernel>`, so the profiler's `XLA Modules` events read
+    `jit_<function>__<kernel>` and join `compile_report()["kernels"]` on
+    the kernel name.  The function name stays in front: trace readers
+    match on it (`^jit_fold_impl`).  `<lambda>` reads `_lambda`."""
+    return (f"{_NOT_WORD.sub('_', fun_name).rstrip('_') or 'jit_fn'}"
+            f"__{_NOT_WORD.sub('_', kernel)}")
+
+
+def _on_backend_compile(event: str, secs: float, **_kw) -> None:
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    ns = int(secs * 1e9)
+    with _lock:
+        _backend["backend_compiles"] += 1
+        _backend["backend_compile_ns"] += ns
+    from blaze_tpu.bridge import tracing
+    tracing.instant("xla_compile", ns=ns, source="backend")
+
+
+def _on_cache_hit(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        with _lock:
+            _backend["compile_cache_hits"] += 1
+
+
+def listen_backend_compiles() -> None:
+    """Register the two jax.monitoring listeners once per process
+    (called from `import blaze_tpu`; listeners cannot be removed, so
+    `reset()` zeroes the counters and leaves them registered)."""
+    global _backend_listening
+    with _lock:
+        if _backend_listening:
+            return
+        _backend_listening = True
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_backend_compile)
+    jax.monitoring.register_event_listener(_on_cache_hit)
+
+
 def meter_jit(fun: Callable, *, name: Optional[str] = None,
               **jit_kwargs) -> Callable:
     """`jax.jit` with compile/cache-hit accounting.
@@ -286,6 +347,7 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
     Drop-in for `jax.jit(fun, **kwargs)` — supports static_argnums /
     static_argnames / donate_argnums.  Each call is timed; a call during
     which the traced body executed is a compile, otherwise a cache hit.
+    The device program is named after the kernel (`program_name`).
     """
     import jax
 
@@ -297,6 +359,9 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
         traced.hit = True
         return fun(*args, **kwargs)
 
+    _noting.__name__ = program_name(
+        getattr(fun, "__name__", "jit_fn"), kname)
+    _noting.__qualname__ = _noting.__name__
     jitted = jax.jit(_noting, **jit_kwargs)
 
     @functools.wraps(fun)
@@ -325,23 +390,26 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
         return out
 
     wrapper._blaze_metered_jit = kname  # introspection / tests
+    wrapper._blaze_jitted = jitted      # .lower() for the naming test
     return wrapper
 
 
-def note_h2d(nbytes: int) -> None:
+def note_h2d(nbytes: int, ns: int = 0) -> None:
     if nbytes <= 0:
         return
     with _lock:
         _transfers["h2d_bytes"] += int(nbytes)
         _transfers["h2d_transfers"] += 1
+        _transfers["h2d_ns"] += int(ns)
 
 
-def note_d2h(nbytes: int) -> None:
+def note_d2h(nbytes: int, wait_ns: int = 0) -> None:
     if nbytes <= 0:
         return
     with _lock:
         _transfers["d2h_bytes"] += int(nbytes)
         _transfers["d2h_transfers"] += 1
+        _transfers["d2h_wait_ns"] += int(wait_ns)
 
 
 def note_bucket(capacity: int, pad_rows: int) -> None:
@@ -916,7 +984,10 @@ def pipeline_stats() -> dict:
 
 
 def compile_report() -> dict:
-    """Per-kernel compile stats + totals, JSON-ready."""
+    """Per-kernel compile stats + totals, JSON-ready.  Covers the
+    kernels wrapped by `meter_jit` only: `total_compiles` in
+    `snapshot()` is their sum.  What JAX compiled in all, eager glue
+    programs included, is `backend_compiles` (`backend_stats()`)."""
     with _lock:
         kernels = {}
         totals = {"calls": 0, "compiles": 0, "cache_hits": 0,
@@ -939,6 +1010,11 @@ def compile_report() -> dict:
 def transfer_stats() -> dict:
     with _lock:
         return dict(_transfers)
+
+
+def backend_stats() -> dict:
+    with _lock:
+        return dict(_backend)
 
 
 def counter_families() -> Dict[str, Dict[str, int]]:
@@ -967,15 +1043,14 @@ def counter_families() -> Dict[str, Dict[str, int]]:
             "aqe": dict(_aqe),
             "encoding": dict(_encoding),
             "fleet": dict(_fleet),
+            "backend": dict(_backend),
         }
 
 
 def snapshot() -> dict:
     """Flat counter snapshot for before/after deltas (explain_analyze)."""
     rep = compile_report()
-    flat = {"h2d_bytes": 0, "d2h_bytes": 0,
-            "h2d_transfers": 0, "d2h_transfers": 0}
-    flat.update(transfer_stats())
+    flat = transfer_stats()
     ps = pipeline_stats()
     ps.pop("bucket_capacities", None)  # list: not delta-able
     flat.update(ps)
@@ -996,6 +1071,7 @@ def snapshot() -> dict:
     flat.update(aqe_stats())
     flat.update(encoding_stats())
     flat.update(fleet_stats())
+    flat.update(backend_stats())
     flat.update({f"total_{k}": v for k, v in rep["totals"].items()})
     return flat
 
@@ -1043,6 +1119,8 @@ def reset() -> None:
             _encoding[k] = 0
         for k in _fleet:
             _fleet[k] = 0
+        for k in _backend:
+            _backend[k] = 0
         _fallback_errors.clear()
         _stage_loop_fallback_reasons.clear()
         _task_duration_ns.clear()

@@ -15,12 +15,12 @@ from dataclasses import dataclass
 from typing import Any, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
 from blaze_tpu.batch import ColumnBatch, DeviceColumn, HostColumn
 from blaze_tpu.schema import DataType, Schema, TypeId
+from blaze_tpu import xputil
 from blaze_tpu.xputil import xp_of
 
 
@@ -105,7 +105,7 @@ class ColVal:
         padded[:len(np_mask)] = np_mask
         if batch._xp() is np:
             return padded
-        return jnp.asarray(padded)
+        return xputil.to_device(padded)
 
 
 class PhysicalExpr:

@@ -45,6 +45,7 @@ def build_runs(sorted_hashes: np.ndarray
 
 
 from blaze_tpu.bridge.xla_stats import meter_jit
+from blaze_tpu.xputil import to_host
 
 
 @functools.partial(meter_jit, name="join.probe_counts")
@@ -111,7 +112,7 @@ def probe_expand_device(unique_hashes, run_start, run_count, sorted_idx,
     per bucket)."""
     start, count = probe_counts(unique_hashes, run_start, run_count,
                                 probe_hashes, probe_null)
-    total = int(jnp.sum(count))
+    total = int(to_host(jnp.sum(count)))
     if total == 0:
         z = np.zeros(0, dtype=np.int64)
         return z, z
@@ -123,7 +124,7 @@ def probe_expand_device(unique_hashes, run_start, run_count, sorted_idx,
     assert p.dtype == want and sorted_pos.dtype == want, (
         f"join pair arrays widened: {p.dtype}/{sorted_pos.dtype}, "
         f"expected {want} at cap={cap}")
-    p_np, sp_np, v_np = jax.device_get((p, sorted_pos, valid))
+    p_np, sp_np, v_np = to_host((p, sorted_pos, valid))
     p_np = p_np[v_np[: len(p_np)]][:total]
     sp_np = sp_np[v_np[: len(sp_np)]][:total]
     b_np = np.asarray(sorted_idx)[sp_np]

@@ -38,7 +38,7 @@ from blaze_tpu.ops.agg.functions import AggFunction
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
 from blaze_tpu.ops.sort import merge_sorted_batches
 from blaze_tpu.schema import DataType, Field, INT64, Schema, TypeId
-from blaze_tpu.xputil import xp_of
+from blaze_tpu.xputil import to_host, xp_of
 
 
 class AggMode(enum.Enum):
@@ -799,7 +799,7 @@ class _ArrowSink:
                            isinstance(v, np.ndarray) for d, v in pending):
             fetched = pending  # host-resident: no sync needed
         else:
-            fetched = jax.device_get(pending) if pending else []
+            fetched = to_host(pending) if pending else []
         out: List[pa.Array] = []
         j = 0
         for it in self._items:

@@ -22,6 +22,7 @@ from typing import Callable, Iterator, List, Optional, Sequence
 
 from blaze_tpu import config
 from blaze_tpu.batch import ColumnBatch, bucket_capacity
+from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.metrics import BASELINE_METRICS, MetricNode
 from blaze_tpu.schema import Schema
@@ -56,17 +57,12 @@ class _MeteredIter:
     incrementally so a downstream early break (LimitExec) still records
     the partial work."""
 
-    __slots__ = ("_it", "_plan", "_key", "_partition", "_kind",
-                 "_total_ns", "_done")
+    __slots__ = ("_it", "_plan", "_key")
 
-    def __init__(self, it, plan, key, partition, kind, setup_ns):
+    def __init__(self, it, plan, key):
         self._it = iter(it)
         self._plan = plan
         self._key = key
-        self._partition = partition
-        self._kind = kind
-        self._total_ns = setup_ns
-        self._done = False
 
     def __iter__(self):
         return self
@@ -84,13 +80,9 @@ class _MeteredIter:
         t0 = time.perf_counter_ns()
         try:
             item = next(self._it)
-        except StopIteration:
-            self._finish()
-            raise
         finally:
-            dt = time.perf_counter_ns() - t0
-            self._plan.metrics.add("elapsed_compute_ns", dt)
-            self._total_ns += dt
+            self._plan.metrics.add("elapsed_compute_ns",
+                                   time.perf_counter_ns() - t0)
             if not reenter:
                 active.discard(self._key)
         m = self._plan.metrics
@@ -98,20 +90,8 @@ class _MeteredIter:
         m.add("output_rows", _batch_rows(item))
         return item
 
-    def _finish(self):
-        if self._done:
-            return
-        self._done = True
-        from blaze_tpu.bridge import tracing
-        if tracing.enabled():
-            tracing.emit_span(
-                f"operator:{type(self._plan).__name__}",
-                self._total_ns, partition=self._partition,
-                kind=self._kind,
-                rows=self._plan.metrics.get("output_rows"))
 
-
-def _meter_stream(fn, kind: str):
+def _meter_stream(fn):
     """Wrap a subclass execute/arrow_batches with the standard meter."""
 
     @functools.wraps(fn)
@@ -120,7 +100,6 @@ def _meter_stream(fn, kind: str):
         key = id(self)
         if key in active:  # inner self-call (execute <-> arrow_batches)
             return fn(self, *args, **kwargs)
-        partition = args[0] if args else kwargs.get("partition", 0)
         active.add(key)
         t0 = time.perf_counter_ns()
         try:
@@ -131,7 +110,7 @@ def _meter_stream(fn, kind: str):
             setup_ns = time.perf_counter_ns() - t0
             active.discard(key)
         self.metrics.add("elapsed_compute_ns", setup_ns)
-        return _MeteredIter(it, self, key, partition, kind, setup_ns)
+        return _MeteredIter(it, self, key)
 
     wrapper._blaze_metered = True
     wrapper._blaze_wraps = fn
@@ -177,6 +156,7 @@ class PrefetchIterator:
                      if config.IO_PREFETCH_ENABLE.get() else 0)
         self._source = iter(source)
         self._transform = transform
+        self._name = name
         self._done = False
         if depth <= 0:
             self._queue = None
@@ -185,6 +165,9 @@ class PrefetchIterator:
         # the worker re-enters the consumer's TaskContext: cancellation
         # checks and task-scoped state are thread-local
         self._ctx = current_task()
+        # ... and so is the tracer's context: the worker's spans carry
+        # the consumer's query/stage/partition and enclosing span
+        self._trace_ctx = tracing.capture()
         self._queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._thread = threading.Thread(
@@ -195,10 +178,17 @@ class PrefetchIterator:
     def _work(self):
         from blaze_tpu.bridge.context import task_scope
         try:
-            with task_scope(self._ctx):
-                for item in self._source:
-                    if self._transform is not None:
-                        item = self._transform(item)
+            with task_scope(self._ctx), tracing.adopt(self._trace_ctx):
+                while True:
+                    with tracing.span(f"produce:{self._name}",
+                                      rows=0) as attrs:
+                        try:
+                            item = next(self._source)
+                        except StopIteration:
+                            break
+                        if self._transform is not None:
+                            item = self._transform(item)
+                        attrs["rows"] = getattr(item, "num_rows", 0)
                     if not self._put(item):
                         return  # closed under us
             self._put(_DONE)
@@ -232,9 +222,9 @@ class PrefetchIterator:
                     else item)
         if self._done:
             raise StopIteration
-        from blaze_tpu.bridge import xla_stats
         t0 = time.perf_counter_ns()
-        item = self._queue.get()
+        with tracing.span("prefetch_wait", source=self._name):
+            item = self._queue.get()
         xla_stats.note_prefetch(wait_ns=time.perf_counter_ns() - t0)
         if item is _DONE:
             self._done = True
@@ -295,7 +285,7 @@ class ExecutionPlan:
             fn = cls.__dict__.get(attr)
             if fn is not None and callable(fn) and \
                     not getattr(fn, "_blaze_metered", False):
-                setattr(cls, attr, _meter_stream(fn, attr))
+                setattr(cls, attr, _meter_stream(fn))
 
     def __init__(self, children: Sequence["ExecutionPlan"] = ()):
         self._children: List[ExecutionPlan] = list(children)

@@ -21,7 +21,6 @@ import enum
 import itertools
 from typing import Iterator, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
@@ -29,7 +28,8 @@ import pyarrow.compute as pc
 
 from blaze_tpu import config
 from blaze_tpu.batch import ColumnBatch
-from blaze_tpu.xputil import asnp
+from blaze_tpu.bridge import tracing
+from blaze_tpu.xputil import asnp, to_device, to_host
 from blaze_tpu.bridge.resource import get_or_create
 from blaze_tpu.exprs import PhysicalExpr
 from blaze_tpu.kernels import hashing as H
@@ -113,9 +113,8 @@ def _device_hash_keys(batch: ColumnBatch, key_exprs: Sequence[PhysicalExpr]
             if on_host:
                 flat_cols.append(((full, full_len), _pad(valid, cap)))
             else:
-                flat_cols.append(((jnp.asarray(full),
-                                   jnp.asarray(full_len)),
-                                  jnp.asarray(_pad(valid, cap))))
+                flat_cols.append(to_device(((full, full_len),
+                                            _pad(valid, cap))))
             tids.append("utf8")
     if on_host:
         flat_cols = _norm_float_keys(flat_cols, tids, np)
@@ -127,7 +126,7 @@ def _device_hash_keys(batch: ColumnBatch, key_exprs: Sequence[PhysicalExpr]
             anyn_np |= ~np.asarray(val)
         return h_np[:n], anyn_np[:n], key_arrays
     h, anyn = _hash_valid_jit(tuple(tids))(flat_cols)
-    h_np, anyn_np = jax.device_get((h, anyn))
+    h_np, anyn_np = to_host((h, anyn))
     return h_np[:n], anyn_np[:n].copy(), key_arrays
 
 
@@ -198,6 +197,12 @@ class JoinMap:
         touch the hash index at all."""
         if self._built:
             return
+        with tracing.span("join_build", rows=self.table.num_rows,
+                          step="index"):
+            self._build_index()
+        self._built = True
+
+    def _build_index(self) -> None:
         from blaze_tpu.kernels.join import build_runs
         n = self.table.num_rows
         if n:
@@ -222,7 +227,6 @@ class JoinMap:
             self.ustart = np.zeros(0, dtype=np.int32)
             self.ucount = np.zeros(0, dtype=np.int32)
             self.key_arrays = []
-        self._built = True
 
     @property
     def num_rows(self) -> int:
@@ -247,11 +251,11 @@ class JoinMap:
                                                      probe_null)
         else:
             from blaze_tpu.kernels.join import probe_expand_device
-            import jax.numpy as _j
+            uh, ustart, ucount, ph, pn = to_device(
+                (self.uh, self.ustart, self.ucount, probe_hashes,
+                 probe_null))
             probe_idx, build_idx = probe_expand_device(
-                _j.asarray(self.uh), _j.asarray(self.ustart),
-                _j.asarray(self.ucount), self.sorted_idx,
-                _j.asarray(probe_hashes), _j.asarray(probe_null))
+                uh, ustart, ucount, self.sorted_idx, ph, pn)
         if not len(probe_idx):
             return (np.zeros(0, dtype=np.int64),) * 2
         # drop null-key build rows, then verify true equality per key
@@ -368,7 +372,9 @@ class BaseJoinExec(ExecutionPlan):
         child = self.children[build]
         stream = (b.compact().to_arrow() for b in child.execute(partition))
         keys = self.right_keys if build == 1 else self.left_keys
-        return build_join_map(stream, child.schema, keys)
+        with tracing.span("join_build", partition=partition,
+                          step="collect"):
+            return build_join_map(stream, child.schema, keys)
 
     # -- execution ----------------------------------------------------------
     def execute(self, partition: int) -> BatchIterator:
@@ -777,6 +783,18 @@ class BaseJoinExec(ExecutionPlan):
                       probe_is_left: bool,
                       skip_filter_keys: frozenset = frozenset()
                       ) -> Iterator[ColumnBatch]:
+        with tracing.span("join_probe", lane="arrow",
+                          rows=sum(c.num_rows for c in probe_chunks)):
+            rb = self._pa_join_table(build_tbl, probe_chunks, probe_keys,
+                                     probe_is_left, skip_filter_keys)
+        bs = config.BATCH_SIZE.get()
+        for off in range(0, rb.num_rows, bs):
+            yield ColumnBatch.from_arrow(
+                rb.slice(off, min(bs, rb.num_rows - off)))
+
+    def _pa_join_table(self, build_tbl, probe_chunks, probe_keys,
+                       probe_is_left: bool, skip_filter_keys: frozenset
+                       ) -> pa.RecordBatch:
         probe_schema = self.children[0 if probe_is_left else 1].schema
         pprefix = "l" if probe_is_left else "r"
         if probe_chunks:
@@ -820,16 +838,21 @@ class BaseJoinExec(ExecutionPlan):
             if not col.type.equals(f.type):
                 col = col.cast(f.type, safe=False)
             arrays.append(col)
-        rb = pa.RecordBatch.from_arrays(arrays, schema=out_arrow)
-        bs = config.BATCH_SIZE.get()
-        for off in range(0, rb.num_rows, bs):
-            yield ColumnBatch.from_arrow(
-                rb.slice(off, min(bs, rb.num_rows - off)))
+        return pa.RecordBatch.from_arrays(arrays, schema=out_arrow)
 
     # -- probe one batch ----------------------------------------------------
     def _probe_batch(self, jmap: JoinMap, batch: ColumnBatch,
                      probe_keys: Sequence[PhysicalExpr], probe_is_left: bool
-                     ) -> Iterator[ColumnBatch]:
+                     ) -> List[ColumnBatch]:
+        """One probe batch from hashed keys to joined batch (0 or 1
+        output batches)."""
+        with tracing.span("join_probe", rows=batch.num_rows):
+            return list(self._probe_batch_rows(jmap, batch, probe_keys,
+                                               probe_is_left))
+
+    def _probe_batch_rows(self, jmap: JoinMap, batch: ColumnBatch,
+                          probe_keys: Sequence[PhysicalExpr],
+                          probe_is_left: bool) -> Iterator[ColumnBatch]:
         n = batch.num_rows
         hashes, any_null, key_arrays = _device_hash_keys(batch, probe_keys)
         p_idx, b_idx = jmap.lookup(hashes, any_null, key_arrays)
@@ -897,7 +920,7 @@ class BaseJoinExec(ExecutionPlan):
         joined = self._joined_batch(probe_rb, jmap, p_idx, b_idx,
                                     probe_is_left, allow_missing=False)
         v = self.join_filter.evaluate(joined)
-        return np.asarray(v.as_mask(joined))[:joined.num_rows]
+        return asnp(v.as_mask(joined))[:joined.num_rows]
 
     def _joined_batch(self, probe_rb, jmap, p_idx, b_idx, probe_is_left,
                       allow_missing=True) -> ColumnBatch:
@@ -1199,10 +1222,12 @@ class BroadcastJoinExec(BaseJoinExec):
         def factory():
             keys = self.right_keys if build == 1 else self.left_keys
             batches = []
-            for p in range(child.num_partitions):
-                batches.extend(b.compact().to_arrow()
-                               for b in child.execute(p))
-            return build_join_map(iter(batches), child.schema, keys)
+            with tracing.span("join_build", partition=partition,
+                              step="collect"):
+                for p in range(child.num_partitions):
+                    batches.extend(b.compact().to_arrow()
+                                   for b in child.execute(p))
+                return build_join_map(iter(batches), child.schema, keys)
         # the cache key folds the build-side output schema: plan rewrites
         # (column pruning) may narrow the build columns per consumer, and
         # two plans sharing one broadcast_id must not serve each other

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from blaze_tpu.kernels import compare
+from blaze_tpu.xputil import to_host
 
 
 class AggTable(NamedTuple):
@@ -720,7 +721,7 @@ class DeviceExchange:
         out = ticket.out
         result = None
         while True:
-            overflow = int(np.sum(np.asarray(out[-1])))
+            overflow = int(np.sum(to_host(out[-1])))
             if overflow == 0:
                 result = out
                 break
@@ -745,11 +746,11 @@ class DeviceExchange:
         xla_stats.note_device_exchange(ticket.n, ticket.moved_bytes,
                                        ticket.collectives)
 
-        out_cols = [np.asarray(a) for a in result[:ncols]]
-        out_vals = [np.asarray(a).astype(bool)
-                    for a in result[ncols:2 * ncols]]
-        pid_r = np.asarray(result[2 * ncols])
-        valid_r = np.asarray(result[2 * ncols + 1]).astype(bool)
+        result = to_host(list(result[:2 * ncols + 2]))
+        out_cols = result[:ncols]
+        out_vals = [a.astype(bool) for a in result[ncols:2 * ncols]]
+        pid_r = result[2 * ncols]
+        valid_r = result[2 * ncols + 1].astype(bool)
 
         # received layout is already (dest device, source device, slot)
         # deterministic; a stable sort by pid keeps it reproducible

@@ -931,12 +931,12 @@ def _task_device_shard(rows: int, groups: int, reps: int = 1,
     vals = jnp.asarray(rng.random(rows))
 
     @jax.jit
-    def agg(k, v):
+    def worker_microbench_agg(k, v):
         return jax.ops.segment_sum(v, k, num_segments=groups)
 
     out = None
     for _ in range(max(1, int(reps))):
-        out = agg(keys, vals)
+        out = worker_microbench_agg(keys, vals)
     out.block_until_ready()
     cpu1 = os.times()
     return {"wall_s": time.perf_counter() - t_wall,

@@ -40,7 +40,7 @@ import pyarrow as pa
 
 from blaze_tpu import config
 from blaze_tpu.batch import ColumnBatch
-from blaze_tpu.bridge import xla_stats
+from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.exprs import BoundReference, PhysicalExpr
 from blaze_tpu.ops.agg.exec import AggExec, AggMode
@@ -54,6 +54,7 @@ from blaze_tpu.parallel.stage import (hash_agg_step, init_accumulators,
                                       rehash_carry, scatter_accumulate,
                                       unpack_dense_keys)
 from blaze_tpu.schema import Field, Schema, TypeId
+from blaze_tpu.xputil import asnp, to_device, to_host
 
 
 def fuse_plan(plan: ExecutionPlan) -> ExecutionPlan:
@@ -349,10 +350,10 @@ def _memory_bounds(scan: MemoryScanExec, col_index: int,
     for part in scan._partitions:
         for cb in part:
             col = cb.columns[col_index]
-            data = np.asarray(col.data)[:cb.num_rows]
-            valid = np.asarray(col.validity)[:cb.num_rows]
+            data = asnp(col.data)[:cb.num_rows]
+            valid = asnp(col.validity)[:cb.num_rows]
             if cb.selection is not None:
-                valid = valid & np.asarray(cb.selection)[:cb.num_rows]
+                valid = valid & asnp(cb.selection)[:cb.num_rows]
             if not valid.any():
                 continue
             if np.issubdtype(data.dtype, np.floating) and not float_ok:
@@ -1541,7 +1542,7 @@ class FusedPartialAggExec(ExecutionPlan):
             nonlocal carry, bound
             if carry is None:
                 return
-            table, mm, ok = jax.device_get(carry)
+            table, mm, ok = to_host(carry)
             carry = None
             bound = 0
             if not bool(ok):
@@ -1658,7 +1659,7 @@ class FusedPartialAggExec(ExecutionPlan):
         None when the table is empty.  Shared by the dense and
         dict-device emit paths."""
         accs, avalid, occupied = carry
-        count = int(jnp.sum(occupied))
+        count = int(to_host(jnp.sum(occupied)))
         if count == 0:
             return None
         padded = _bucket(count, num_slots)
@@ -1668,18 +1669,21 @@ class FusedPartialAggExec(ExecutionPlan):
         fetch = ([jnp.take(a, slots_dev) for a in accs],
                  [jnp.take(v, slots_dev) for v in avalid],
                  slots_dev)
-        host_accs, host_avalid, slots = jax.device_get(fetch)
+        host_accs, host_avalid, slots = to_host(fetch)
         return ([a[:count] for a in host_accs],
                 [v[:count] for v in host_avalid], slots[:count])
 
     def _emit_dense(self, carry, num_slots: int) -> BatchIterator:
-        drained = self._drain_table(carry, num_slots)
-        if drained is None:
-            return
-        host_accs, host_avalid, slots = drained
-        # slot -> key decode host-side (shared stride logic, no round trip)
-        host_keys = unpack_dense_keys(slots, self._ranges, xp=np)
-        yield from self._emit_rows(host_keys, host_accs, host_avalid)
+        with tracing.span("agg_drain", table="dense"):
+            drained = self._drain_table(carry, num_slots)
+            if drained is None:
+                return
+            host_accs, host_avalid, slots = drained
+            # slot -> key decode host-side (shared stride logic, no round
+            # trip)
+            host_keys = unpack_dense_keys(slots, self._ranges, xp=np)
+            rb = self._rows_to_arrow(host_keys, host_accs, host_avalid)
+        yield from self._emit_chunks(rb)
 
     # -- var-width keys on device: dictionary-code dense strategy ----------
     # (VERDICT r4 #8 / SURVEY §7 hard-part #1: keep string group keys as
@@ -1758,12 +1762,18 @@ class FusedPartialAggExec(ExecutionPlan):
         yield from self._emit_dict(carry, caps, dicts)
 
     def _emit_dict(self, carry, caps, dicts) -> BatchIterator:
+        with tracing.span("agg_drain", table="dict"):
+            rb = self._dict_table_to_arrow(carry, caps, dicts)
+        if rb is not None:
+            yield from self._emit_chunks(rb)
+
+    def _dict_table_to_arrow(self, carry, caps, dicts):
         num_slots = 1
         for c in caps:
             num_slots *= (c + 1)
         drained = self._drain_table(carry, num_slots)
         if drained is None:
-            return
+            return None
         host_accs, host_avalid, slots = drained
         count = len(slots)
         ranges = [(0, c - 1) for c in caps]
@@ -1785,11 +1795,7 @@ class FusedPartialAggExec(ExecutionPlan):
             else:
                 arrays.append(_to_arrow(a[:count], v[:count], f.type))
             i += 1
-        rb = pa.RecordBatch.from_arrays(arrays, schema=out_arrow)
-        bs = config.BATCH_SIZE.get()
-        for off in range(0, rb.num_rows, bs):
-            chunk = rb.slice(off, min(bs, rb.num_rows - off))
-            yield ColumnBatch.from_arrow(chunk)
+        return pa.RecordBatch.from_arrays(arrays, schema=out_arrow)
 
     # -- unbounded keys: device open-addressing hash table -----------------
     # (ref agg_hash_map.rs; replaces the earlier sort-based table — a
@@ -1834,7 +1840,7 @@ class FusedPartialAggExec(ExecutionPlan):
                 carry = init_hash_carry(key_dtypes, kinds,
                                         self._acc_dtypes(), slots)
             new_carry, overflow, _ng = step(carry, batch)
-            while int(overflow) > 0:
+            while int(to_host(overflow)) > 0:
                 if not self._grow:
                     new_carry = None
                     break
@@ -1843,7 +1849,7 @@ class FusedPartialAggExec(ExecutionPlan):
                 slots *= 2
                 self.metrics.add("table_grown", 1)
                 bigger, re_ovf, _ = _rehash_jit(kinds, slots, lane)(carry)
-                if int(re_ovf) > 0:
+                if int(to_host(re_ovf)) > 0:
                     continue  # rare probe clustering: double again
                 carry = bigger
                 new_carry, overflow, _ng = step(carry, batch)
@@ -1870,27 +1876,29 @@ class FusedPartialAggExec(ExecutionPlan):
             local = init_hash_carry(key_dtypes, kinds,
                                     self._acc_dtypes(), slots)
             out, overflow, _ng = step(local, batch)
-            if int(overflow) == 0:
+            if int(to_host(overflow)) == 0:
                 return out
             slots *= 2
 
     def _emit_hash(self, carry, key_dicts=None) -> BatchIterator:
-        count = int(jnp.sum(carry.used))
-        if count == 0:
-            return
-        padded = _bucket(count, carry.used.shape[0])
-        sel = jnp.nonzero(carry.used, size=padded, fill_value=0)[0]
-        keys_h, kvalid_h, accs_h, avalid_h = jax.device_get(
-            ([jnp.take(k, sel) for k in carry.keys],
-             [jnp.take(v, sel) for v in carry.key_valid],
-             [jnp.take(a, sel) for a in carry.accs],
-             [jnp.take(v, sel) for v in carry.acc_valid]))
-        keys = [(kd[:count], kv[:count])
-                for kd, kv in zip(keys_h, kvalid_h)]
-        accs = [a[:count] for a in accs_h]
-        avalid = [v[:count] for v in avalid_h]
-        yield from self._emit_rows(keys, accs, avalid,
-                                   key_dicts=key_dicts)
+        with tracing.span("agg_drain", table="hash"):
+            count = int(to_host(jnp.sum(carry.used)))
+            if count == 0:
+                return
+            padded = _bucket(count, carry.used.shape[0])
+            sel = jnp.nonzero(carry.used, size=padded, fill_value=0)[0]
+            keys_h, kvalid_h, accs_h, avalid_h = to_host(
+                ([jnp.take(k, sel) for k in carry.keys],
+                 [jnp.take(v, sel) for v in carry.key_valid],
+                 [jnp.take(a, sel) for a in carry.accs],
+                 [jnp.take(v, sel) for v in carry.acc_valid]))
+            keys = [(kd[:count], kv[:count])
+                    for kd, kv in zip(keys_h, kvalid_h)]
+            accs = [a[:count] for a in accs_h]
+            avalid = [v[:count] for v in avalid_h]
+            rb = self._rows_to_arrow(keys, accs, avalid,
+                                     key_dicts=key_dicts)
+        yield from self._emit_chunks(rb)
 
     # -- shared emission ----------------------------------------------------
     def _device_inputs(self, batch: ColumnBatch):
@@ -1912,8 +1920,17 @@ class FusedPartialAggExec(ExecutionPlan):
         return (tuple(kd), tuple(kv), tuple(ad), tuple(av),
                 _pad_lane(batch.row_mask()))
 
-    def _emit_rows(self, keys, accs, avalid,
-                   key_dicts=None) -> BatchIterator:
+    def _emit_rows(self, keys, accs, avalid) -> BatchIterator:
+        with tracing.span("agg_drain", table="host"):
+            rb = self._rows_to_arrow(keys, accs, avalid)
+        yield from self._emit_chunks(rb)
+
+    def _emit_chunks(self, rb) -> BatchIterator:
+        for chunk in self._emit_batches(rb):
+            yield ColumnBatch.from_arrow(chunk)
+
+    def _rows_to_arrow(self, keys, accs, avalid,
+                       key_dicts=None) -> pa.RecordBatch:
         n = len(accs[0]) if accs else len(keys[0][0])
         arrays: List[pa.Array] = []
         out_arrow = self._out_schema.to_arrow()
@@ -1938,11 +1955,7 @@ class FusedPartialAggExec(ExecutionPlan):
             else:
                 arrays.append(_to_arrow(a, v, f.type))
             i += 1
-        rb = pa.RecordBatch.from_arrays(arrays, schema=out_arrow)
-        bs = config.BATCH_SIZE.get()
-        for off in range(0, rb.num_rows, bs):
-            chunk = rb.slice(off, min(bs, rb.num_rows - off))
-            yield ColumnBatch.from_arrow(chunk)
+        return pa.RecordBatch.from_arrays(arrays, schema=out_arrow)
 
 
 import functools
@@ -2334,7 +2347,7 @@ def _relayout_dict_table(carry, kinds, acc_dtypes, old_caps, new_caps):
     growth: decode occupied slots to per-key codes (pure stride math,
     host-side), recompute slot ids under the new strides, scatter accs
     1:1 (codes are unique per slot, no merging)."""
-    accs, avalid, occupied = jax.device_get(carry)
+    accs, avalid, occupied = to_host(carry)
     occ = np.nonzero(occupied)[0]
     old_ranges = [(0, c - 1) for c in old_caps]
     decoded = unpack_dense_keys(occ, old_ranges, xp=np)
@@ -2352,16 +2365,16 @@ def _relayout_dict_table(carry, kinds, acc_dtypes, old_caps, new_caps):
     fresh_accs, fresh_avalid = init_accumulators(kinds, acc_dtypes,
                                                  new_total)
     for fa, a in zip(fresh_accs, accs):
-        na = np.asarray(fa).copy()
+        na = asnp(fa).copy()
         na[new_slot] = a[occ]
-        n_accs.append(jnp.asarray(na))
+        n_accs.append(na)
     for fv, v in zip(fresh_avalid, avalid):
-        nv = np.asarray(fv).copy()
+        nv = asnp(fv).copy()
         nv[new_slot] = v[occ]
-        n_avalid.append(jnp.asarray(nv))
+        n_avalid.append(nv)
     n_occ = np.zeros(new_total, dtype=bool)
     n_occ[new_slot] = True
-    return (tuple(n_accs), tuple(n_avalid), jnp.asarray(n_occ))
+    return to_device((tuple(n_accs), tuple(n_avalid), n_occ))
 
 
 @functools.lru_cache(maxsize=64)

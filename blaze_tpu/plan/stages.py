@@ -950,6 +950,7 @@ class DagScheduler:
         exchange shards them without a host round trip); any staged
         batches force the host concat path."""
         import numpy as np
+        from blaze_tpu.xputil import asnp
         if col_tasks and not batches:
             import jax.numpy as jnp
             ncols = len(col_tasks[0][0])
@@ -962,9 +963,9 @@ class DagScheduler:
         for datas, vls, _n in col_tasks:
             for i, (d, v) in enumerate(zip(datas, vls)):
                 cols[i] = np.concatenate(
-                    [cols[i], np.asarray(d).astype(cols[i].dtype)])
+                    [cols[i], asnp(d).astype(cols[i].dtype)])
                 valids[i] = np.concatenate(
-                    [valids[i], np.asarray(v).astype(bool)])
+                    [valids[i], asnp(v).astype(bool)])
         return cols, valids
 
     def _exchange_sync(self, stage: Stage, spec, n_out: int, schema):

@@ -34,13 +34,13 @@ from contextlib import contextmanager
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from blaze_tpu import config, faults
 from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.parallel.stage import hash_agg_step, init_hash_carry
+from blaze_tpu.xputil import to_host
 
 # hard ceiling on grow-on-overflow table size: past this the partition
 # is cheaper to re-run staged (which streams) than to hold on device
@@ -202,7 +202,7 @@ def run_partition(program, partition: int, ctx: str = "",
             with tracing.span("stage_loop_chunk", stage=ctx,
                               partition=partition, chunk=ci,
                               batches=count):
-                rows += int(np.asarray(jnp.sum(masks)))
+                rows += int(to_host(jnp.sum(masks)))
                 cols_stacked, masks = _pad_chunk(cols_stacked, masks,
                                                  chunk)
                 start = 0
@@ -211,6 +211,8 @@ def run_partition(program, partition: int, ctx: str = "",
                         carry, cols_stacked, masks,
                         jnp.asarray(start, jnp.int32))
                     fold_calls += 1
+                    # the host waits for the fold here
+                    ovf_seen, first_ovf = to_host((ovf_seen, first_ovf))
                     if not bool(ovf_seen):
                         break
                     if not program.grow:
@@ -227,7 +229,7 @@ def run_partition(program, partition: int, ctx: str = "",
                     slots *= 2
                     bigger, re_ovf, _ = _rehash_jit(program.kinds,
                                                     slots, lane)(carry)
-                    if int(re_ovf) > 0:
+                    if int(to_host(re_ovf)) > 0:
                         continue  # rare probe clustering: double again
                     carry = bigger
                     regrows += 1
@@ -303,7 +305,7 @@ def drain_device(program, carry):
         # into an exchange if that ever changes
         raise StageLoopFallback("dict-encoded keys cannot drain D2D")
     used = carry.used
-    count = int(jax.device_get(jnp.sum(used)))
+    count = int(to_host(jnp.sum(used)))
     if count == 0:
         return [], [], 0
     padded = _bucket(count, used.shape[0])

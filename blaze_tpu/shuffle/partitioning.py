@@ -121,7 +121,7 @@ class HashPartitioning(Partitioning):
 
     def partition_ids(self, batch: ColumnBatch) -> np.ndarray:
         from blaze_tpu.bridge.placement import host_resident
-        from blaze_tpu.xputil import asnp
+        from blaze_tpu.xputil import asnp, to_device
         n = batch.num_rows
         if self.num_partitions == 1:
             # pmod(h, 1) == 0 for every row: skip the hash chain
@@ -159,9 +159,8 @@ class HashPartitioning(Partitioning):
                 if on_host:
                     flat_cols.append(((full, full_len), pad_valid))
                 else:
-                    flat_cols.append(((jnp.asarray(full),
-                                       jnp.asarray(full_len)),
-                                      jnp.asarray(pad_valid)))
+                    flat_cols.append(to_device(((full, full_len),
+                                                pad_valid)))
                 tids.append("utf8")
         if on_host:
             # the native kernel hashes raw bit views, so it needs the
@@ -175,7 +174,7 @@ class HashPartitioning(Partitioning):
                                          self.num_partitions, xp=np)
             return np.asarray(pids)[:n].astype(np.int32)
         pids = _hash_pmod_jit(tuple(tids), self.num_partitions)(flat_cols)
-        return np.asarray(pids)[:n].astype(np.int32)
+        return asnp(pids)[:n].astype(np.int32)
 
 
 class RoundRobinPartitioning(Partitioning):

@@ -20,6 +20,8 @@ jnp — so the same expression code traces unchanged.
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 _jnp = None
@@ -49,12 +51,51 @@ def xp_of(*arrays):
     return np
 
 
+def to_host(tree):
+    """Device -> host: `jax.device_get` of an array or pytree, and the
+    one place in `blaze_tpu/` where the host blocks on the device.
+    Counts the bytes of the device leaves (`d2h_bytes`, one
+    `d2h_transfers`) and the time the caller was blocked
+    (`d2h_wait_ns`, which includes waiting for the programs that
+    produce the value), under a `d2h` span.  A tree with no device leaf
+    is returned as device_get returns it, uncounted."""
+    if is_np(tree):
+        return tree
+    import jax
+    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)
+                 if isinstance(x, jax.Array))
+    if not nbytes:
+        return jax.device_get(tree)
+    from blaze_tpu.bridge import tracing, xla_stats
+    t0 = time.perf_counter_ns()
+    with tracing.span("d2h", bytes=nbytes):
+        out = jax.device_get(tree)
+    xla_stats.note_d2h(nbytes, time.perf_counter_ns() - t0)
+    return out
+
+
+def to_device(tree):
+    """Host -> device: one `jax.device_put` over an array or pytree of
+    numpy buffers.  Counts their bytes (`h2d_bytes`, one
+    `h2d_transfers`) and the time spent here (`h2d_ns`), under an `h2d`
+    span.  device_put returns before the copy lands, so the time is host
+    staging and dispatch, not the transfer."""
+    import jax
+    nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)
+                 if isinstance(x, np.ndarray))
+    from blaze_tpu.bridge import tracing, xla_stats
+    t0 = time.perf_counter_ns()
+    with tracing.span("h2d", bytes=nbytes):
+        out = jax.device_put(tree)
+    xla_stats.note_h2d(nbytes, time.perf_counter_ns() - t0)
+    return out
+
+
 def asnp(a) -> np.ndarray:
     """Pull an array to host numpy (zero-copy for numpy and for CPU-backend
-    jax arrays).  Device pulls are accounted as D2H transfer volume."""
+    jax arrays).  Device pulls go through `to_host`."""
     if isinstance(a, np.ndarray):
         return a
-    out = np.asarray(a)
-    from blaze_tpu.bridge import xla_stats
-    xla_stats.note_d2h(out.nbytes)
-    return out
+    if is_np(a) or isinstance(a, (list, tuple)):
+        return np.asarray(a)
+    return np.asarray(to_host(a))

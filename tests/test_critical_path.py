@@ -29,7 +29,7 @@ def test_categories_sum_to_wall_exactly():
         _span("task", 50, 300, sid=2),
         _span("shuffle_exchange", 100, 80, sid=3),   # inside the task
         _span("stage_loop_chunk", 200, 60, sid=4),   # inside the task
-        _span("operator:ParquetScanExec", 260, 30, sid=5),
+        _span("produce:parquet_scan", 260, 30, sid=5),
         # 350..400 uncovered, then a final exchange
         _span("device_exchange", 400, 100, sid=6),
     ]
@@ -89,12 +89,12 @@ def test_report_shape_and_dominant():
 def test_critical_path_descends_longest_children():
     spans = [
         _span("task", 0, 300, sid=1),
-        _span("operator:AggExec", 0, 100, sid=2, parent=1),
-        _span("operator:ParquetScanExec", 100, 180, sid=3, parent=1),
+        _span("agg_drain", 0, 100, sid=2, parent=1),
+        _span("produce:parquet_scan", 100, 180, sid=3, parent=1),
     ]
     path = critical_path.critical_path(spans)
     assert [e["name"] for e in path] == \
-        ["task", "operator:ParquetScanExec"]
+        ["task", "produce:parquet_scan"]
     assert path[1]["category"] == "scan_decode"
 
 

@@ -115,10 +115,10 @@ def test_unknown_span_name_rejected_when_enabled():
             pass
     with pytest.raises(ValueError, match="unregistered span"):
         tracing.instant("also-not-registered")
-    # wildcard names pass: operator spans are per-operator dynamic
-    with tracing.span("operator:hash_agg", rows=1):
+    # wildcard names pass: produce spans carry the prefetcher's name
+    with tracing.span("produce:parquet_scan", rows=1):
         pass
-    assert _by_name(tracing.spans(), "operator:hash_agg")
+    assert _by_name(tracing.spans(), "produce:parquet_scan")
 
 
 # -- wire roundtrip ---------------------------------------------------------
@@ -458,7 +458,9 @@ def test_span_registry_pin():
         "task", "task_attempt", "backoff_wait", "admission_wait",
         "worker_task", "device_exchange", "rss_exchange",
         "shuffle_exchange", "stage_recovery", "stage_loop_chunk",
-        "stream_epoch", "explain_analyze", "operator:*",
+        "stream_epoch", "explain_analyze",
+        "d2h", "h2d", "prefetch_wait", "produce:*", "join_build",
+        "join_probe", "agg_drain",
         "task_retry", "fault_injected", "xla_compile",
         "device_shuffle_fallback", "rss_shuffle_fallback",
         "stage_loop_fallback", "quota_breach", "mem_spill",
