@@ -106,6 +106,8 @@ _stage_loop = {"stage_loop_programs_built": 0,
                "stage_loop_calls": 0, "stage_loop_chunks": 0,
                "stage_loop_batches": 0, "stage_loop_rows": 0,
                "stage_loop_tasks": 0, "stage_loop_regrows": 0,
+               "stage_loop_reserves": 0, "stage_loop_rehash_lanes": 0,
+               "stage_loop_max_slots": 0,
                "stage_loop_fallbacks": 0,
                "stage_loop_staged_dispatches_avoided": 0}
 
@@ -803,11 +805,17 @@ def note_stage_program(cache_hit: bool) -> None:
 
 
 def note_stage_loop_task(chunks: int, batches: int, rows: int,
-                         regrows: int, dispatches_avoided: int) -> None:
+                         regrows: int, reserves: int, rehash_lanes: int,
+                         slots: int, dispatches_avoided: int) -> None:
     """One map task completed through the device-resident stage loop:
-    `chunks` loop program calls folded `batches` batches / `rows` rows,
-    growing the agg table `regrows` times; the staged per-batch path
-    would have issued `dispatches_avoided` extra Python dispatches."""
+    `chunks` loop program calls folded `batches` batches / `rows` rows.
+    The agg table's capacity was raised at `reserves` chunk boundaries
+    before the fold and `regrows` times after an overflow, pushing
+    `rehash_lanes` old-table slots through the rehash, and ended at
+    `slots` (`stage_loop_max_slots` is the high-water mark since
+    reset(), so its delta says how far it rose).  The staged per-batch
+    path would have issued `dispatches_avoided` extra Python
+    dispatches."""
     with _lock:
         _stage_loop["stage_loop_tasks"] += 1
         _stage_loop["stage_loop_calls"] += int(chunks)
@@ -815,6 +823,10 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int,
         _stage_loop["stage_loop_batches"] += int(batches)
         _stage_loop["stage_loop_rows"] += int(rows)
         _stage_loop["stage_loop_regrows"] += int(regrows)
+        _stage_loop["stage_loop_reserves"] += int(reserves)
+        _stage_loop["stage_loop_rehash_lanes"] += int(rehash_lanes)
+        _stage_loop["stage_loop_max_slots"] = max(
+            _stage_loop["stage_loop_max_slots"], int(slots))
         _stage_loop["stage_loop_staged_dispatches_avoided"] += \
             int(dispatches_avoided)
 

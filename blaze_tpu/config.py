@@ -303,8 +303,13 @@ IO_PREFETCH_DEPTH = int_conf(
     "synchronous passthrough (same as disabling the kill-switch).")
 ON_DEVICE_AGG_CAPACITY = int_conf(
     "auron.tpu.agg.table.capacity", 1 << 18,
-    "Static group slots for the fused sorted-table aggregation stage; "
-    "overflow degrades to pass-through partials (plan/fused.py).")
+    "Group slots the device hash aggregation table starts from.  The "
+    "stage loop (runtime/loop.py) treats it as a floor: at each chunk "
+    "boundary it sizes the table, in every agg mode, for the groups held "
+    "plus the rows about to arrive (powers of two up to 2^24 slots).  "
+    "The staged per-batch path (plan/fused.py) starts here too; there "
+    "overflow doubles the table in exact modes and degrades to "
+    "pass-through partials in partial mode.")
 FUSED_STAGE_ENABLE = bool_conf(
     "auron.tpu.fused.stage.enable", True,
     "Rewrite eligible scan->filter->partial-agg subtrees into single-XLA-"

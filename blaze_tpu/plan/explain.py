@@ -347,6 +347,7 @@ class QueryProfile:
                 f"rows={x.get('stage_loop_rows', 0)} "
                 f"dispatches_avoided="
                 f"{x.get('stage_loop_staged_dispatches_avoided', 0)} "
+                f"reserves={x.get('stage_loop_reserves', 0)} "
                 f"regrows={x.get('stage_loop_regrows', 0)} "
                 f"fallbacks={x.get('stage_loop_fallbacks', 0)}")
         if x.get("stream_epochs"):

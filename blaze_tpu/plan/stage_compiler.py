@@ -22,7 +22,7 @@ Eligibility (anything else stays on the staged per-batch executor):
     its final drain).
 
 One `StageProgram` fingerprint = (chain cache key, reduce kinds, key
-dtypes, acc dtypes, grow mode).  The loop's fold program is cached per
+dtypes, acc dtypes).  The loop's fold program is cached per
 fingerprint; capacity rungs and chunk widths become jit signatures
 inside that one program, so steady state sees zero recompiles
 (stage_loop_programs_built / stage_loop_program_cache_hits account the
@@ -77,7 +77,6 @@ class StageProgram:
     kinds: Tuple[str, ...]         # reduce kinds per agg spec
     key_dtypes: Tuple[Any, ...]    # jnp dtypes of the group keys
     acc_dtypes: Tuple[Any, ...]    # jnp dtypes of the accumulators
-    grow: bool                     # exact modes grow the table on overflow
     fingerprint: Tuple             # process-wide program identity
     # per-group-key SOURCE column index when the key is dict-encoded
     # utf8 (codes fold as int32; the loop captures each stream's last
@@ -152,8 +151,7 @@ def compile_fused_agg(agg) -> StageProgram:
     acc_dtypes = tuple(agg._acc_dtypes())
     fingerprint = (agg._prepare_key, kinds,
                    tuple(str(d) for d in key_dtypes),
-                   tuple(str(d) for d in acc_dtypes), bool(agg._grow),
-                   dict_keys)
+                   tuple(str(d) for d in acc_dtypes), dict_keys)
     hit = fingerprint in _SEEN_FINGERPRINTS
     xla_stats.note_stage_program(cache_hit=hit)
     if not hit:
@@ -163,8 +161,7 @@ def compile_fused_agg(agg) -> StageProgram:
     return StageProgram(agg=agg, prepare=agg._prepare,
                         prepare_key=agg._prepare_key, kinds=kinds,
                         key_dtypes=key_dtypes, acc_dtypes=acc_dtypes,
-                        grow=bool(agg._grow), fingerprint=fingerprint,
-                        dict_keys=dict_keys)
+                        fingerprint=fingerprint, dict_keys=dict_keys)
 
 
 def try_compile(agg) -> Optional[StageProgram]:

@@ -10,15 +10,24 @@ of the chunk inside ONE program, carrying the agg hash table across
 iterations with buffer donation, so Python-side dispatches per
 partition drop from O(batches x operators) to O(chunks).
 
+Capacity is reserved BEFORE a chunk is folded, in every agg mode: at
+the chunk boundary the host already holds the chunk's row counts and
+the table's group count (they ride the overflow scalars' round trip),
+so the table is sized for `groups + rows about to arrive` — a plain
+allocation while it is empty, one rehash otherwise — and overflow is a
+rare backstop, not the growth policy.  Partial mode grows like the
+exact modes: the loop emits one fully aggregated table per task, and
+the skip semantics stay with the staged path (`_execute_sorted`), which
+takes the partition only past `_MAX_SLOTS`.
+
 Discipline inherited from the staged path, kept intact:
 
   * ATOMIC overflow (hash_agg_step): the first batch that overflows
     leaves the carry unchanged and masks every later batch of the chunk
-    to a no-op; the host doubles + rehashes (exact modes) and resumes
-    the SAME chunk at the overflow batch — bit-identical to the staged
-    grow schedule.  Partial mode keeps its skip semantics by falling
-    back wholesale instead of growing (the loop emits nothing until its
-    final drain, so the staged re-run is lossless).
+    to a no-op; the host re-sizes + rehashes and resumes the SAME chunk
+    at the overflow batch.  Past `_MAX_SLOTS` every mode falls back
+    wholesale (the loop emits nothing until its final drain, so the
+    staged re-run is lossless).
   * Cancellation/deadline (PR 7): the query token is checked between
     chunks (and per source batch by the metered stream), so teardown
     latency is bounded by one chunk.
@@ -29,6 +38,7 @@ Discipline inherited from the staged path, kept intact:
 
 from __future__ import annotations
 
+import math
 import threading
 from contextlib import contextmanager
 
@@ -42,9 +52,18 @@ from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.parallel.stage import hash_agg_step, init_hash_carry
 from blaze_tpu.xputil import to_host
 
-# hard ceiling on grow-on-overflow table size: past this the partition
-# is cheaper to re-run staged (which streams) than to hold on device
+# hard ceiling on the table size: past this the partition is cheaper to
+# re-run staged (which streams and skips) than to hold on device
 _MAX_SLOTS = 1 << 24
+
+# Table sizing.  The table is re-sized when the groups it holds plus the
+# rows about to arrive pass _TRIGGER_LOAD of its slots, to the power of
+# two that puts them at _TARGET_LOAD.  The two are 2x apart so that a
+# table never re-sizes at successive chunks of equal cardinality.
+# Chosen from the fold's per-batch device time against slots and load
+# on a TPU v5e (PERF.md section 6, PR 25).
+_TRIGGER_LOAD = 0.25
+_TARGET_LOAD = 0.125
 
 
 class StageLoopFallback(RuntimeError):
@@ -123,7 +142,12 @@ def _fold_factory(program, donate: bool, lane: str = "scatter"):
             return (new_c, jnp.logical_or(ovf_seen, hit), first_ovf)
 
         init = (carry, jnp.asarray(False), jnp.asarray(0, jnp.int32))
-        return jax.lax.fori_loop(start, masks.shape[0], body, init)
+        carry, ovf_seen, first_ovf = jax.lax.fori_loop(
+            start, masks.shape[0], body, init)
+        # the table's group count rides the overflow scalars' round
+        # trip: the host sizes the next chunk's table from it
+        groups = jnp.sum(carry.used, dtype=jnp.int32)
+        return carry, ovf_seen, first_ovf, groups
 
     kwargs = {"donate_argnums": (0,)} if donate else {}
     fold = meter_jit(fold_impl, name="runtime.stage_loop", **kwargs)
@@ -168,13 +192,24 @@ def loop_chunk_batches() -> int:
     return chunk
 
 
+def _slots_for(need: int, floor: int) -> int:
+    """The power of two that holds `need` groups at _TARGET_LOAD, within
+    [floor, _MAX_SLOTS]."""
+    from blaze_tpu.plan.fused import _pow2
+    return min(_MAX_SLOTS, max(floor, _pow2(math.ceil(need / _TARGET_LOAD))))
+
+
 def run_partition(program, partition: int, ctx: str = "",
                   source_stream=None):
     """Fold one partition through the stage program; returns the final
     HashAggCarry.  Raises StageLoopFallback on any ineligibility or
     failure — nothing has been emitted at that point, so the caller's
     staged re-run is lossless.  Cancellation (QueryCancelled /
-    TaskKilledError / deadline) propagates untranslated."""
+    TaskKilledError / deadline) propagates untranslated.
+
+    The table's capacity sequence is a function of the input alone (rows
+    per batch, groups so far), never of timing: a repeat of the same
+    partition walks the same sizes and loads no new program."""
     from blaze_tpu.plan.fused import _batch_windows, _pow2, _rehash_jit
     task = current_task()
     q = task.query
@@ -187,53 +222,78 @@ def run_partition(program, partition: int, ctx: str = "",
     from blaze_tpu.kernels import lane as lane_mod
     lane = lane_mod.resolve("hash")
     fold = _fold_factory(program, _donate_active(), lane)
-    slots = _pow2(config.ON_DEVICE_AGG_CAPACITY.get())
-    carry = init_hash_carry(list(program.key_dtypes), program.kinds,
-                            list(program.acc_dtypes), slots)
+    floor = _pow2(config.ON_DEVICE_AGG_CAPACITY.get())
     stream = (source_stream if source_stream is not None
               else program.source.execute(partition))
-    batches = rows = fold_calls = regrows = ci = 0
+    batches = rows = fold_calls = regrows = reserves = rehash_lanes = 0
+    ci = groups = 0
+    slots, carry = floor, None  # allocated at the first chunk, for it
+
+    def fresh(n):
+        return init_hash_carry(list(program.key_dtypes), program.kinds,
+                               list(program.acc_dtypes), n)
+
+    def resized(want):
+        """The table at `want` slots or more: a plain allocation while
+        it holds nothing, one rehash otherwise."""
+        nonlocal rehash_lanes
+        while want <= _MAX_SLOTS:
+            if carry is None or groups == 0:
+                return fresh(want), want
+            _run_fences()  # drain in-flight overlapped exchanges
+            rehash_lanes += slots
+            bigger, re_ovf, _ = _rehash_jit(program.kinds, want,
+                                            lane)(carry)
+            if int(to_host(re_ovf)) == 0:
+                return bigger, want
+            want *= 2  # rare probe clustering: double again
+        raise StageLoopFallback(f"table would exceed {_MAX_SLOTS} slots")
+
     try:
         for cols_stacked, masks, count in _batch_windows(stream, chunk):
             # chunk boundary: the ONLY host sync points of the loop —
-            # cooperative cancel, fault site, overflow scalar
+            # cooperative cancel, fault site, row counts, and the
+            # overflow scalars with the table's group count
             task.check_running()
             faults.maybe_fail("device-loop", stage=ctx, chunk=ci)
             with tracing.span("stage_loop_chunk", stage=ctx,
                               partition=partition, chunk=ci,
                               batches=count):
-                rows += int(to_host(jnp.sum(masks)))
+                # selected lanes per batch: before the chain's filter,
+                # so an upper bound on the rows the fold will insert
+                batch_rows = to_host(jnp.sum(masks, axis=1)).tolist()
+                chunk_rows = sum(batch_rows)
+                rows += chunk_rows
+                # reserve before fold
+                need = groups + chunk_rows
+                if carry is None or need > slots * _TRIGGER_LOAD:
+                    want = _slots_for(need, floor)
+                    if want > slots:
+                        reserves += 1
+                    if carry is None or want > slots:
+                        carry, slots = resized(want)
                 cols_stacked, masks = _pad_chunk(cols_stacked, masks,
                                                  chunk)
                 start = 0
                 while True:
-                    carry, ovf_seen, first_ovf = fold(
+                    carry, ovf_seen, first_ovf, ngroups = fold(
                         carry, cols_stacked, masks,
                         jnp.asarray(start, jnp.int32))
                     fold_calls += 1
                     # the host waits for the fold here
-                    ovf_seen, first_ovf = to_host((ovf_seen, first_ovf))
+                    ovf_seen, first_ovf, ngroups = to_host(
+                        (ovf_seen, first_ovf, ngroups))
+                    groups = int(ngroups)
                     if not bool(ovf_seen):
                         break
-                    if not program.grow:
-                        # PARTIAL mode: skip semantics (batch-local
-                        # dedup pass-through) belong to the staged path;
-                        # growing here would diverge from its bit
-                        # pattern
-                        raise StageLoopFallback(
-                            "hash table overflow in partial mode")
-                    if slots * 2 > _MAX_SLOTS:
-                        raise StageLoopFallback(
-                            f"table would exceed {_MAX_SLOTS} slots")
-                    _run_fences()  # drain in-flight overlapped exchanges
-                    slots *= 2
-                    bigger, re_ovf, _ = _rehash_jit(program.kinds,
-                                                    slots, lane)(carry)
-                    if int(to_host(re_ovf)) > 0:
-                        continue  # rare probe clustering: double again
-                    carry = bigger
-                    regrows += 1
+                    # residual overflow (probe clustering below the
+                    # trigger load): size for what is left of the chunk,
+                    # at least double, and resume at the overflow batch
                     start = int(first_ovf)
+                    need = groups + sum(batch_rows[start:])
+                    carry, slots = resized(
+                        max(slots * 2, _slots_for(need, floor)))
+                    regrows += 1
             ci += 1
             batches += count
             task.loop_chunks = ci
@@ -241,8 +301,11 @@ def run_partition(program, partition: int, ctx: str = "",
         # scripted chaos at the device-loop site: wholesale fallback,
         # not a task retry — the chaos soak asserts THIS path converges
         raise StageLoopFallback(f"injected fault: {e}") from e
+    if carry is None:
+        carry = fresh(slots)  # empty partition
     xla_stats.note_stage_loop_task(
         chunks=fold_calls, batches=batches, rows=rows, regrows=regrows,
+        reserves=reserves, rehash_lanes=rehash_lanes, slots=slots,
         dispatches_avoided=max(0, batches - fold_calls))
     program.agg._note_lane(batches)
     return carry
@@ -296,20 +359,19 @@ def execute_loop(program, partition: int, ctx: str = ""):
 def drain_device(program, carry):
     """D2D drain: compact the carry's used slots ON DEVICE and cast to
     the stage out-schema storage dtypes, so the partitioned output feeds
-    DeviceExchange without a host round trip.  Returns (datas, valids,
-    n) — lists of length-n device arrays in output column order."""
-    from blaze_tpu.plan.fused import _bucket
+    DeviceExchange without the rows leaving the device (only the slot
+    mask and the indices cross, fused._used_slots).  Returns (datas,
+    valids, n) — lists of length-n device arrays in output column
+    order."""
+    from blaze_tpu.plan.fused import _used_slots
     if any(s is not None for s in getattr(program, "dict_keys", ())):
         # dict-key stages never reach here (utf8 output columns exclude
         # the boundary from DeviceExchange), but raw codes must not leak
         # into an exchange if that ever changes
         raise StageLoopFallback("dict-encoded keys cannot drain D2D")
-    used = carry.used
-    count = int(to_host(jnp.sum(used)))
+    sel, count = _used_slots(carry.used)
     if count == 0:
         return [], [], 0
-    padded = _bucket(count, used.shape[0])
-    sel = jnp.nonzero(used, size=padded, fill_value=0)[0]
     fields = list(program.out_schema)
     datas, valids = [], []
     i = 0

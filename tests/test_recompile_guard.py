@@ -105,12 +105,12 @@ def loop_on():
 
 
 def _loop_agg_plan(tmp_path, tag="a", n=4000, mode="partial",
-                   value="float64", seed=5):
+                   value="float64", seed=5, groups=200):
     """hash_agg over a 2-partition parquet scan.  Keys are WIDE int64
     (compact 0..199 ranges take the dense lane, which the stage compiler
     rejects — the loop is the hash lane's fold)."""
     rng = np.random.default_rng(seed)
-    k = rng.integers(0, 200, n) * 1000003 + 17
+    k = rng.integers(0, groups, n) * 1000003 + 17
     if value == "int64":
         v = pa.array(rng.integers(0, 1000, n), type=pa.int64())
     else:
@@ -187,49 +187,55 @@ def pallas_on():
         config.conf.unset(config.KERNELS_PALLAS.key)
 
 
+@pytest.fixture
+def rung_ladder():
+    """A floor of 16 slots, 256-row batches folded one a chunk, and
+    groups that keep arriving: the loop reserves at the first chunk and
+    re-sizes a table that holds groups at a later one."""
+    config.conf.set(config.ON_DEVICE_AGG_CAPACITY.key, 16)
+    config.conf.set(config.BATCH_SIZE.key, 256)
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, 1)
+    try:
+        yield
+    finally:
+        config.conf.unset(config.ON_DEVICE_AGG_CAPACITY.key)
+        config.conf.unset(config.BATCH_SIZE.key)
+        config.conf.unset(config.STAGE_DEVICE_LOOP_CHUNK.key)
+
+
+def _climbs_once_compiled(tmp_path, tag):
+    def plan():
+        return _fused(_loop_agg_plan(tmp_path, tag=tag, mode="final",
+                                     groups=4000))
+    assert list(plan().execute(0))
+    before = xla_stats.snapshot()
+    assert list(plan().execute(0))
+    d = xla_stats.delta(before)
+    assert d["total_compiles"] == 0, \
+        f"capacity-rung recompiles: {d['total_compiles']}"
+    # the ladder actually climbed, by reservation: the first chunk's
+    # allocation, then a rehash of a table that held groups
+    assert d["stage_loop_reserves"] > 1
+    assert d["stage_loop_rehash_lanes"] > 0
+    assert d["stage_loop_fallbacks"] == 0
+    return d
+
+
 @pytest.mark.pallas
 def test_pallas_lane_capacity_rungs_compile_once(tmp_path, loop_on,
-                                                 pallas_on):
+                                                 pallas_on, rung_ladder):
     # the rung ladder with the kernel lane forced on: the warm run
     # compiles one placement kernel per capacity rung (the lane rides
     # the fold/rehash cache keys); the repeat run climbs the same
     # ladder with ZERO new compiles and zero fallbacks
-    config.conf.set(config.ON_DEVICE_AGG_CAPACITY.key, 16)
-    try:
-        plan = _fused(_loop_agg_plan(tmp_path, tag="prung", mode="final"))
-        assert list(plan.execute(0))
-        before = xla_stats.snapshot()
-        again = _fused(_loop_agg_plan(tmp_path, tag="prung",
-                                      mode="final"))
-        assert list(again.execute(0))
-        d = xla_stats.delta(before)
-        assert d["total_compiles"] == 0, \
-            f"pallas-lane rung recompiles: {d['total_compiles']}"
-        assert d["stage_loop_regrows"] > 0
-        assert d["stage_loop_fallbacks"] == 0
-        # the kernel lane actually resolved (interpret on a CPU session)
-        assert (d["scatter_lane_hash_interpret"]
-                + d["scatter_lane_hash_pallas"]) > 0
-    finally:
-        config.conf.unset(config.ON_DEVICE_AGG_CAPACITY.key)
+    d = _climbs_once_compiled(tmp_path, "prung")
+    # the kernel lane actually resolved (interpret on a CPU session)
+    assert (d["scatter_lane_hash_interpret"]
+            + d["scatter_lane_hash_pallas"]) > 0
 
 
-def test_stage_loop_capacity_rungs_compile_once(tmp_path, loop_on):
-    # exact (final) mode grows the table on overflow: capacity 16 with
-    # ~200 groups forces the rung ladder.  The warm run compiles every
-    # rung's rehash + the one fold program; the repeat run climbs the
-    # same ladder with ZERO new compiles.
-    config.conf.set(config.ON_DEVICE_AGG_CAPACITY.key, 16)
-    try:
-        plan = _fused(_loop_agg_plan(tmp_path, tag="rung", mode="final"))
-        assert list(plan.execute(0))
-        before = xla_stats.snapshot()
-        again = _fused(_loop_agg_plan(tmp_path, tag="rung", mode="final"))
-        assert list(again.execute(0))
-        d = xla_stats.delta(before)
-        assert d["total_compiles"] == 0, \
-            f"capacity-rung recompiles: {d['total_compiles']}"
-        assert d["stage_loop_regrows"] > 0  # the ladder actually climbed
-        assert d["stage_loop_fallbacks"] == 0
-    finally:
-        config.conf.unset(config.ON_DEVICE_AGG_CAPACITY.key)
+def test_stage_loop_capacity_rungs_compile_once(tmp_path, loop_on,
+                                                rung_ladder):
+    # the warm run compiles every rung's rehash + the fold at every
+    # rung; the repeat run climbs the same ladder with ZERO new compiles
+    _climbs_once_compiled(tmp_path, "rung")

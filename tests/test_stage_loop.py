@@ -320,3 +320,276 @@ def test_cancelled_query_leaves_no_leaks(tmp_path, staged_path, loop_on):
         timer.cancel()
     report = sched.leak_report()
     assert all(v == [] for v in report.values()), report
+
+
+# -- table sizing: reserve before fold, in every mode (ISSUE 25) -------------
+
+@pytest.fixture
+def small_tables():
+    """Floor of 16 slots, 512-row batches, 2 batches a chunk, and the
+    device hash table on the staged lane too (host placement would take
+    Arrow's aggregation, which never builds it)."""
+    config.conf.set(config.ON_DEVICE_AGG_CAPACITY.key, 16)
+    config.conf.set(config.BATCH_SIZE.key, 512)
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, 2)
+    config.conf.set(config.FUSED_HOST_VECTORIZED_ENABLE.key, False)
+    try:
+        yield
+    finally:
+        config.conf.unset(config.ON_DEVICE_AGG_CAPACITY.key)
+        config.conf.unset(config.BATCH_SIZE.key)
+        config.conf.unset(config.STAGE_DEVICE_LOOP_CHUNK.key)
+        config.conf.unset(config.FUSED_HOST_VECTORIZED_ENABLE.key)
+
+
+@pytest.fixture
+def capacities(monkeypatch):
+    """The table's slot count at every fold call, in order."""
+    from blaze_tpu.runtime import loop as device_loop
+    seen = []
+    real = device_loop._fold_factory
+
+    def factory(*a, **k):
+        fold = real(*a, **k)
+
+        def spy(carry, *rest):
+            seen.append(int(carry.used.shape[0]))
+            return fold(carry, *rest)
+        return spy
+
+    monkeypatch.setattr(device_loop, "_fold_factory", factory)
+    return seen
+
+
+def _wide(k):
+    # WIDE int64 keys: a compact range would take the dense lane
+    return np.asarray(k, dtype=np.int64) * 1000003 + 17
+
+
+def _sum_agg(tmp_path, keys, mode, tag):
+    """sum(v) group by k over one parquet file, fused (loop-eligible)."""
+    from blaze_tpu.plan.column_pruning import prune_columns
+    from blaze_tpu.plan.fused import fuse_plan
+    from blaze_tpu.plan.planner import collapse_filter_project, create_plan
+    vals = np.random.default_rng(11).random(len(keys))
+    t = pa.table({"k": pa.array(keys, type=pa.int64()),
+                  "v": pa.array(vals)})
+    p = str(tmp_path / f"{tag}.parquet")
+    pq.write_table(t, p, row_group_size=512)
+    schema = {"fields": [
+        {"name": "k", "type": {"id": "int64"}, "nullable": True},
+        {"name": "v", "type": {"id": "float64"}, "nullable": True}]}
+    plan = {"kind": "hash_agg",
+            "groupings": [{"expr": {"kind": "column", "index": 0},
+                           "name": "k"}],
+            "aggs": [{"fn": "sum", "mode": mode, "name": "s",
+                      "args": [{"kind": "column", "index": 1}]}],
+            "input": {"kind": "parquet_scan", "schema": schema,
+                      "file_groups": [[p]]}}
+    fused = fuse_plan(prune_columns(collapse_filter_project(
+        create_plan(plan))))
+    want = t.to_pandas().groupby("k", as_index=False).agg(s=("v", "sum"))
+    return fused, want.sort_values("k").reset_index(drop=True)
+
+
+def _emitted(plan):
+    out = [b.compact().to_arrow() for b in plan.execute(0)]
+    df = pa.Table.from_batches([b for b in out if b.num_rows]).to_pandas()
+    df.columns = ["k", "s"]  # partial mode names its sum `s.sum`
+    return df
+
+
+def _reserved_for(rows):
+    from blaze_tpu.runtime import loop as device_loop
+    return device_loop._slots_for(rows, 16)
+
+
+def _merged(df):
+    """What a final aggregation makes of partial output."""
+    return df.groupby("k", as_index=False).agg(s=("s", "sum")) \
+        .sort_values("k").reset_index(drop=True)
+
+
+def _assert_sums(got, want):
+    assert got.k.tolist() == want.k.tolist()
+    np.testing.assert_allclose(got.s.to_numpy(), want.s.to_numpy(),
+                               rtol=1e-12)
+
+
+def _colliding(slots, n, skip=()):
+    """`n` wide keys whose home slot in a table of `slots` is slot 0:
+    they need `n` probe rounds however empty the table is."""
+    from blaze_tpu.kernels import hashing as H
+    cand = _wide(np.arange(1, 400 * slots))
+    h = H.hash_columns([(cand, np.ones(len(cand), bool), "int64")],
+                       seed=42, xp=np, algo="xxhash64")
+    hit = cand[(h & (slots - 1)) == 0]
+    hit = hit[~np.isin(hit, skip)]
+    assert len(hit) >= n
+    return hit[:n]
+
+
+def test_partial_mode_grows_past_the_floor_in_the_loop(
+        tmp_path, loop_on, small_tables):
+    # 3000 groups against a floor of 16: partial mode used to give up at
+    # the first overflow and re-run staged
+    keys = _wide(np.random.default_rng(5).integers(0, 3000, 6000))
+    plan, want = _sum_agg(tmp_path, keys, "partial", "pgrow")
+    before = xla_stats.snapshot()
+    got = _emitted(plan)
+    d = xla_stats.delta(before)
+    assert d["stage_loop_tasks"] == 1
+    assert d["stage_loop_fallbacks"] == 0
+    assert d["partial_agg_skip_events"] == 0
+    assert len(got) == len(want)  # ONE fully aggregated table
+    _assert_sums(_merged(got), want)
+    config.conf.set(config.STAGE_DEVICE_LOOP_ENABLE.key, "off")
+    staged, _ = _sum_agg(tmp_path, keys, "partial", "pgrow-off")
+    before = xla_stats.snapshot()
+    staged_out = _emitted(staged)
+    # the staged lane keeps its skip semantics: pass-through partials
+    assert xla_stats.delta(before)["partial_agg_skip_events"] == 1
+    assert len(staged_out) > len(want)
+    _assert_sums(_merged(staged_out), _merged(got))
+
+
+def test_one_chunk_partition_sizes_once(tmp_path, loop_on, small_tables,
+                                        capacities):
+    keys = _wide(np.random.default_rng(6).integers(0, 700, 1000))
+    plan, want = _sum_agg(tmp_path, keys, "final", "once")
+    before = xla_stats.snapshot()
+    got = _merged(_emitted(plan))
+    d = xla_stats.delta(before)
+    _assert_sums(got, want)
+    assert capacities == [_reserved_for(1000)] and capacities[0] >= 4000
+    assert d["stage_loop_reserves"] == 1
+    assert d["stage_loop_rehash_lanes"] == 0
+    assert d["stage_loop_regrows"] == 0
+    assert d["stage_loop_fallbacks"] == 0
+    assert (xla_stats.stage_loop_stats()["stage_loop_max_slots"]
+            >= capacities[0])
+
+
+def test_growing_cardinality_resizes_with_hysteresis(
+        tmp_path, loop_on, small_tables, capacities):
+    # every row a new group, 1024 rows a chunk, 16 chunks
+    plan, want = _sum_agg(tmp_path, _wide(np.arange(16384)), "final",
+                          "grow")
+    before = xla_stats.snapshot()
+    got = _merged(_emitted(plan))
+    d = xla_stats.delta(before)
+    _assert_sums(got, want)
+    assert d["stage_loop_regrows"] == 0 and d["stage_loop_fallbacks"] == 0
+    assert len(capacities) == 16
+    assert capacities == sorted(capacities)
+    steps = [i for i in range(1, 16) if capacities[i] > capacities[i - 1]]
+    assert 1 <= len(steps) <= np.log2(capacities[-1] / capacities[0])
+    # never at two successive chunks of equal cardinality
+    assert all(b - a > 1 for a, b in zip(steps, steps[1:])) and steps[0] > 1
+    assert d["stage_loop_reserves"] == 1 + len(steps)
+    assert d["stage_loop_rehash_lanes"] == sum(
+        capacities[i - 1] for i in steps)
+    # the groups held and the rows about to arrive never pass the
+    # trigger load
+    from blaze_tpu.runtime import loop as device_loop
+    assert all(1024 * (i + 1) <= c * device_loop._TRIGGER_LOAD
+               for i, c in enumerate(capacities))
+
+
+@pytest.mark.parametrize("mode", ["partial", "final"])
+def test_past_max_slots_every_mode_falls_back(tmp_path, loop_on,
+                                              small_tables, monkeypatch,
+                                              mode):
+    from blaze_tpu.runtime import loop as device_loop
+    monkeypatch.setattr(device_loop, "_MAX_SLOTS", 64)
+    keys = _wide(np.random.default_rng(8).integers(0, 3000, 6000))
+    plan, want = _sum_agg(tmp_path, keys, mode, f"max-{mode}")
+    before = xla_stats.snapshot()
+    got = _merged(_emitted(plan))
+    d = xla_stats.delta(before)
+    _assert_sums(got, want)  # the staged lane answers, losslessly
+    assert d["stage_loop_fallbacks"] == 1 and d["stage_loop_tasks"] == 0
+    assert xla_stats.stage_loop_fallback_reasons().get(
+        "table would exceed 64 slots", 0) >= 1
+
+
+def test_low_cardinality_never_rehashes(tmp_path, loop_on, small_tables,
+                                        capacities):
+    keys = _wide(np.random.default_rng(9).integers(0, 12, 8192))
+    plan, want = _sum_agg(tmp_path, keys, "partial", "low")
+    before = xla_stats.snapshot()
+    got = _merged(_emitted(plan))
+    d = xla_stats.delta(before)
+    _assert_sums(got, want)
+    assert len(capacities) == 8 and len(set(capacities)) == 1
+    assert d["stage_loop_reserves"] == 1
+    assert d["stage_loop_rehash_lanes"] == 0
+    assert d["stage_loop_regrows"] == 0
+
+
+def test_capacity_sequence_is_deterministic(tmp_path, loop_on,
+                                            small_tables, capacities):
+    keys = _wide(np.arange(8192))
+    plan, _ = _sum_agg(tmp_path, keys, "partial", "det")
+    _emitted(plan)
+    first = list(capacities)
+    del capacities[:]
+    again, want = _sum_agg(tmp_path, keys, "partial", "det")
+    before = xla_stats.snapshot()
+    got = _merged(_emitted(again))
+    d = xla_stats.delta(before)
+    _assert_sums(got, want)
+    assert capacities == first and len(set(first)) > 1
+    assert d["stage_loop_programs_built"] == 0
+    assert d["backend_compiles"] == 0 and d["total_compiles"] == 0
+
+
+def _overflowing_keys():
+    """Two 512-row batches, one chunk: 256 plain groups, then 40 keys
+    that share a home slot in the table reserved for the chunk and so
+    need 40 probe rounds of the 16 there are."""
+    plain = _wide(np.arange(256))
+    bad = _colliding(_reserved_for(1024), 40, skip=plain)
+    return np.concatenate([np.repeat(plain, 2), np.resize(bad, 512)])
+
+
+def test_residual_overflow_regrows_and_resumes(tmp_path, loop_on,
+                                               small_tables, capacities):
+    plan, want = _sum_agg(tmp_path, _overflowing_keys(), "partial", "ovf")
+    before = xla_stats.snapshot()
+    got = _emitted(plan)
+    d = xla_stats.delta(before)
+    assert len(got) == len(want) == 296
+    _assert_sums(_merged(got), want)
+    assert d["stage_loop_regrows"] >= 1 and d["stage_loop_reserves"] == 1
+    assert d["stage_loop_fallbacks"] == 0
+    assert capacities[0] == _reserved_for(1024)
+    assert capacities[1] >= 2 * capacities[0]
+    assert d["stage_loop_rehash_lanes"] >= capacities[0]
+
+
+@pytest.mark.parametrize("how", ["reserved", "reactive"])
+def test_exchange_fence_runs_before_every_rehash(tmp_path, loop_on,
+                                                 small_tables, monkeypatch,
+                                                 how):
+    from blaze_tpu.plan import fused
+    from blaze_tpu.runtime import loop as device_loop
+    events = []
+    real = fused._rehash_jit
+
+    def rehash(*a, **k):
+        events.append("rehash")
+        return real(*a, **k)
+
+    monkeypatch.setattr(fused, "_rehash_jit", rehash)
+    keys = (_wide(np.arange(8192)) if how == "reserved"
+            else _overflowing_keys())
+    plan, want = _sum_agg(tmp_path, keys, "final", f"fence-{how}")
+    before = xla_stats.snapshot()
+    with device_loop.exchange_fence(lambda: events.append("fence")):
+        got = _merged(_emitted(plan))
+    d = xla_stats.delta(before)
+    _assert_sums(got, want)
+    assert (d["stage_loop_regrows"] > 0) == (how == "reactive")
+    assert "rehash" in events
+    assert events == ["fence", "rehash"] * (len(events) // 2)
