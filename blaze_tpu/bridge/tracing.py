@@ -112,6 +112,12 @@ SPAN_NAMES: Dict[str, str] = {
     "join_probe": "one probe batch from hashed keys to joined batch, or "
                   "one Arrow-lane join over the collected probe side "
                   "(ops/joins/exec.py; attrs rows)",
+    "sort_device": "a sort's permutation taken on the device, one pass "
+                   "per 32-bit digit of the order keys, under a merge join "
+                   "or not (ops/sort.py; attrs rows, passes)",
+    "smj_merge": "one partition's sort-merge join as device programs: "
+                 "bounds, expansion, gather (ops/joins/merge.py; attrs "
+                 "rows of both sides, pairs)",
     "agg_drain": "an aggregation table read back and turned into an "
                  "Arrow batch (plan/fused.py _emit_*; attrs table)",
     # -- instants (dur_ns == 0) ---------------------------------------

@@ -119,6 +119,14 @@ _agg = {"partial_agg_skip_events": 0, "partial_agg_skipped_rows": 0,
         "partial_agg_probe_rows": 0, "partial_agg_probe_groups": 0,
         "partial_agg_switch_rows": 0, "partial_agg_spill_switches": 0}
 
+# Sort and sort-merge join on the device (ops/sort.py, ops/joins/exec.py):
+# rows whose sort permutation came from the device, rows of both sides and
+# pairs written by the device merge join, and equal-key runs the Python
+# run cursor walked instead (ops/joins/smj.py: a partition the memory manager
+# shed, or a join shape the device path states it does not take).
+_sortmerge = {"sort_device_rows": 0, "smj_device_rows": 0,
+              "smj_device_pairs": 0, "smj_streamed_runs": 0}
+
 # Pallas scatter/hash lane resolutions (kernels/lane.py): which lane
 # each hash-update / radix-partition dispatch took, plus envelope
 # declines and fault-injected fallbacks.  Surfaced in the
@@ -885,6 +893,18 @@ def agg_stats() -> dict:
         return dict(_agg)
 
 
+def note_sortmerge(**deltas: int) -> None:
+    """kwargs name `_sortmerge` keys; all are counters."""
+    with _lock:
+        for k, v in deltas.items():
+            _sortmerge[k] += int(v)
+
+
+def sortmerge_stats() -> dict:
+    with _lock:
+        return dict(_sortmerge)
+
+
 def note_scatter_lane(kind: str, lane: str) -> None:
     """One kernel-lane resolution: kind in hash/partition, lane in
     pallas/interpret/scatter (kernels/lane.py resolve)."""
@@ -1074,6 +1094,7 @@ def snapshot() -> dict:
     flat.update(shuffle_stats())
     flat.update(stage_loop_stats())
     flat.update(scatter_lane_stats())
+    flat.update(sortmerge_stats())
     flat.update(stream_stats())
     flat.update(worker_stats())
     flat.update(speculation_stats())
@@ -1113,6 +1134,8 @@ def reset() -> None:
             _stage_loop[k] = 0
         for k in _scatter_lane:
             _scatter_lane[k] = 0
+        for k in _sortmerge:
+            _sortmerge[k] = 0
         for k in _stream:
             _stream[k] = 0
         for k in _workers:

@@ -1062,13 +1062,6 @@ PARTIAL_AGG_SKIPPING_PROBE_ROWS = int_conf(
     "decision errs toward keeping the aggregation.",
     category="operator",
     alt_keys=("auron.tpu.partialAggSkipping.probeRows",))
-SMJ_ACERO_ENABLE = bool_conf(
-    "auron.tpu.smj.acero.enable", True,
-    "Sort-merge joins whose sides fit the host collect budget run "
-    "through Arrow's C++ hash join with the output re-sorted by the "
-    "join keys (preserving SMJ's ordering contract); larger inputs "
-    "keep the spillable streaming merge.",
-    category="operator")
 PARTIAL_AGG_SKIPPING_ON_SPILL = bool_conf(
     "auron.tpu.partialAgg.skipping.onSpill", False,
     "Under memory pressure, switch an eligible partial agg to pass-through "

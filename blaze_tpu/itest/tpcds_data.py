@@ -104,6 +104,12 @@ SALES_DATE_DAYS = 1826  # TPC-DS facts span ~5 years (1998-2002), not the
 
 
 def gen_store_returns(scale: float, seed: int = 14) -> pa.Table:
+    """These returns match no sale: `sr_ticket_number` counts up and
+    `sr_item_sk` is drawn on its own, so a return meets a `store_sales`
+    row on (ticket, item) with probability 1/18,000 (about 16 pairs at
+    SF1).  The itest's fact-to-fact joins (q17, q25, q29, q93) therefore
+    carry next to no rows on any backend; `benchmark/data/tpcds_returns.py`
+    draws returns from sales, as dsdgen does."""
     n = _rows("store_returns", scale)
     rng = np.random.default_rng(seed)
     date_n = min(_rows("date_dim", scale), SALES_DATE_DAYS)

@@ -19,6 +19,13 @@ two jit'd programs:
 
 Hash collisions are verified by the caller against the real key columns,
 so a colliding pair can never produce a wrong join row.
+
+The sort-merge join's device path (ops/joins/merge.py) uses the same shape
+over real keys instead of hashes: the searched side is key-SORTED already,
+so `merge_bounds` is `probe_counts` with a lexicographic comparison over
+the order keys of kernels/compare.py, and the pairs come out of the same
+`expand_pairs` (metered apart, so the trace tells the two joins' time
+apart).
 """
 
 from __future__ import annotations
@@ -129,3 +136,90 @@ def probe_expand_device(unique_hashes, run_start, run_count, sorted_idx,
     sp_np = sp_np[v_np[: len(sp_np)]][:total]
     b_np = np.asarray(sorted_idx)[sp_np]
     return p_np, b_np
+
+
+# ---------------------------------------------------------------------------
+# sort-merge join: the same probe over key-sorted sides
+# ---------------------------------------------------------------------------
+
+def _order_operands(cols, dtypes):
+    """Flat (bucket, key) operands of kernels/compare.order_key, ascending
+    and NULLs first: how both children of a merge join are sorted."""
+    from blaze_tpu.kernels import compare
+    ops = []
+    for (data, valid), dt in zip(cols, dtypes):
+        ops.extend(compare.order_key(data, valid, dt))
+    return ops
+
+
+def _lex_less(a_ops, b_ops):
+    """a < b, lexicographically over operand lists, elementwise."""
+    less = jnp.zeros(a_ops[0].shape, dtype=bool)
+    for a, b in reversed(list(zip(a_ops, b_ops))):
+        less = (a < b) | ((a == b) & less)
+    return less
+
+
+def _merge_bounds(probe_cols, build_cols, probe_rows, build_rows, dtypes):
+    """Per probe row, its equal-key run in the key-sorted build side.
+
+    probe_cols / build_cols: ((data, validity), ...) of the join keys, both
+    sides of one type per key (`promote_join_key_exprs`), the build side
+    sorted ascending, NULLs first, over its first `build_rows` rows.
+    Returns (lo, count, total): `lo` is the lower bound of the probe row's
+    key in the build side (where its run starts, or would), `count` the
+    run's length, 0 for a probe row past `probe_rows`, one with a NULL key
+    (SQL: NULL joins nothing; NaN joins NaN, as order_key encodes it) or
+    one without a partner; `total` their int64 sum.
+
+    One vectorised binary search (log2(capacity) rounds of one gather per
+    operand), then the run's end from the build side's own run boundaries:
+    no second search for the upper bound."""
+    p_ops = _order_operands(probe_cols, dtypes)
+    b_ops = _order_operands(build_cols, dtypes)
+    cap_p, cap_b = p_ops[0].shape[0], b_ops[0].shape[0]
+    idt = jnp.int32
+    n_b = jnp.asarray(build_rows, idt)
+
+    def step(_, lo_hi):
+        lo, hi = lo_hi
+        mid = (lo + hi) >> 1
+        at = [jnp.take(o, mid, mode="clip") for o in b_ops]
+        go_right = (lo < hi) & _lex_less(at, p_ops)
+        return (jnp.where(go_right, mid + 1, lo),
+                jnp.where((lo < hi) & ~go_right, mid, hi))
+
+    lo, _hi = jax.lax.fori_loop(
+        0, max(1, cap_b.bit_length()), step,
+        (jnp.zeros(cap_p, idt), jnp.full(cap_p, n_b, idt)))
+    # where each build row's run ends: the next row that differs from its
+    # predecessor, found by a reverse running minimum
+    pos_b = jnp.arange(cap_b, dtype=idt)
+    differs = jnp.zeros(cap_b, dtype=bool)
+    for o in b_ops:
+        differs = differs | jnp.concatenate(
+            [jnp.ones(1, bool), o[1:] != o[:-1]])
+    starts = jnp.where(differs & (pos_b < n_b), pos_b, n_b)
+    run_end = jnp.concatenate([jax.lax.cummin(starts, reverse=True)[1:],
+                               n_b[None]])
+    at = [jnp.take(o, lo, mode="clip") for o in b_ops]
+    hit = lo < n_b
+    for a, p in zip(at, p_ops):
+        hit = hit & (a == p)
+    for _data, valid in probe_cols:
+        hit = hit & valid
+    hit = hit & (jnp.arange(cap_p, dtype=idt) < probe_rows)
+    count = jnp.where(hit, jnp.take(run_end, lo, mode="clip") - lo, 0)
+    return lo, count.astype(idt), jnp.sum(count.astype(jnp.int64))
+
+
+merge_bounds = meter_jit(_merge_bounds, name="smj.bounds",
+                         static_argnames=("dtypes",))
+# The same expansion as a program of the merge join's own name,
+# `jit_expand_pairs__smj_expand_pairs`.  A program has one name, and the
+# trace is read by it: under `join.expand_pairs` the merge join's device
+# time (`__smj_`) would leave this part of its work out, and its share of
+# the roofline read too high; q01 runs both joins in one query.
+merge_expand_pairs = meter_jit(expand_pairs.__wrapped__,
+                               name="smj.expand_pairs",
+                               static_argnames=("cap",))

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.kernels import compare
 from blaze_tpu.schema import DataType
 from blaze_tpu.xputil import xp_of
@@ -61,6 +62,20 @@ def sort_indices(columns: Sequence[Tuple[jax.Array, Optional[jax.Array], DataTyp
     """
     keys = compare.order_keys(columns, descending, nulls_first)
     return compare.lexsort_indices(keys, valid_mask)
+
+
+def lsd_pass(digit: jax.Array, perm: jax.Array) -> jax.Array:
+    """One pass of a least-significant-digit-first sort: `perm` reordered,
+    stably, by `digit[perm]`.  Two operands of 32 bits whatever the keys:
+    the TPU compiler's time for a sort grows with the operands and their
+    width (2 x 32 bits: half a minute; 6 operands with 64-bit keys: seven
+    minutes, at any length), so a sort by many wide keys is many runs of
+    this one program rather than one program of its own."""
+    return jax.lax.sort((jnp.take(digit, perm), perm), num_keys=1,
+                        is_stable=True)[1]
+
+
+sort_pass = meter_jit(lsd_pass, name="sort.pass")
 
 
 def group_ids_from_sorted(keys: Sequence[jax.Array], valid_mask: jax.Array

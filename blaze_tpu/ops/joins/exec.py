@@ -1022,65 +1022,38 @@ class SortMergeJoinExec(BaseJoinExec):
                 return child
         return SortExec(child, [(k, False, True) for k in keys])
 
-    def _acero_sorted(self, partition: int):
-        """Materialized host path: both sides within the collect budget
-        join through Arrow's C++ hash join, and the OUTPUT re-sorts by
-        the join keys (ascending, nulls first) to preserve SMJ's
-        output-ordering contract for downstream consumers.  Returns None
-        — falling back to the streaming run-cursor merge — when a side
-        overflows the budget (the spillable path exists precisely for
-        that), keys are computed expressions, or Acero lacks the join
-        type.  A run-cursor merge over N one-row key runs is O(N)
-        Python; this path replaces it with two vectorized passes (the
-        q97 distinct-pair FULL OUTER was 200x slower streaming)."""
-        from blaze_tpu.bridge.placement import host_resident
+    def _acero_eligible(self) -> bool:
+        """Arrow's hash join takes the join type, and the keys are plain
+        columns (EXISTENCE is already outside _PA_JOIN_TYPES)."""
         from blaze_tpu.exprs.base import BoundReference
-        if (not host_resident() or not self._pa_join_eligible()
-                or not config.SMJ_ACERO_ENABLE.get()):
-            return None  # EXISTENCE is already outside _PA_JOIN_TYPES
-        if not all(isinstance(k, BoundReference)
-                   for k in self.left_keys + self.right_keys):
-            return None
-        limit = config.FUSED_HOST_COLLECT_ROWS.get()
-        sides = []
-        for i in (0, 1):
-            chunks, rows = [], 0
-            stream = self.children[i].arrow_batches(partition)
-            for rb in stream:
-                if rb.num_rows == 0:
-                    continue
-                chunks.append(rb)
-                rows += rb.num_rows
-                if rows > limit:
-                    # hand everything consumed so far back to execute():
-                    # when child output is already key-sorted, the
-                    # streaming merge resumes from these chunks without
-                    # re-reading the input
-                    return ("overflow", i, sides, chunks, stream)
-            sides.append(chunks)
+        return self._pa_join_eligible() and all(
+            isinstance(k, BoundReference)
+            for k in self.left_keys + self.right_keys)
+
+    def _acero_join(self, left: List[pa.RecordBatch],
+                    right: List[pa.RecordBatch]) -> Iterator[ColumnBatch]:
+        """Both collected sides through Arrow's C++ hash join, the OUTPUT
+        re-sorted by the join keys (ascending, nulls first) to preserve
+        SMJ's output-ordering contract for downstream consumers.  A
+        run-cursor merge over N one-row key runs is O(N) Python; this
+        replaces it with two vectorized passes (the q97 distinct-pair FULL
+        OUTER was 200x slower streaming)."""
         build_tbl = self._join_key_table(
             self.children[1].schema,
-            (pa.Table.from_batches(sides[1]) if sides[1]
-             else pa.Table.from_batches(
-                 [], schema=self.children[1].schema.to_arrow())),
+            pa.Table.from_batches(
+                right, schema=self.children[1].schema.to_arrow()),
             self.right_keys, "r")
-
-        def gen():
-            out = list(self._pa_join_once(build_tbl, sides[0],
-                                          self.left_keys, True))
-            if not out:
-                return
-            tbl = pa.Table.from_batches(
-                [cb.compact().to_arrow() for cb in out])
-            order = self._smj_output_order(tbl)
-            if order is not None:
-                tbl = tbl.take(order)
-            bs = config.BATCH_SIZE.get()
-            for off in range(0, tbl.num_rows, bs):
-                yield ColumnBatch.from_arrow(
-                    tbl.slice(off, min(bs, tbl.num_rows - off))
-                    .combine_chunks())
-        return gen()
+        out = list(self._pa_join_once(build_tbl, left, self.left_keys, True))
+        if not out:
+            return
+        tbl = pa.Table.from_batches([cb.compact().to_arrow() for cb in out])
+        order = self._smj_output_order(tbl)
+        if order is not None:
+            tbl = tbl.take(order)
+        bs = config.BATCH_SIZE.get()
+        for off in range(0, tbl.num_rows, bs):
+            yield ColumnBatch.from_arrow(
+                tbl.slice(off, min(bs, tbl.num_rows - off)).combine_chunks())
 
     def _smj_output_order(self, tbl):
         """Sort indices restoring key order (nulls first).  Key columns
@@ -1107,42 +1080,91 @@ class SortMergeJoinExec(BaseJoinExec):
             null_placement="at_start")
 
     def execute(self, partition: int) -> BatchIterator:
-        from blaze_tpu.ops.joins.smj import MergeJoiner, _RunCursor
-        acero = self._acero_sorted(partition)
-        l_stream = r_stream = None
-        if isinstance(acero, tuple):
-            # collect-budget overflow on side i.  If every side whose
-            # data was already consumed is ALREADY key-sorted (children
-            # are SortExecs in translated plans), resume the streaming
-            # merge from the buffered chunks — no re-read; otherwise
-            # fall through to full re-execution (a fresh SortExec would
-            # have to see all rows anyway).
-            _tag, i, done, part_chunks, rest = acero
-            consumed = list(range(i + 1))
-            if all(self._sorted_child(j) is self.children[j]
-                   for j in consumed):
-                chained = itertools.chain(part_chunks, rest)
-                if i == 0:
-                    l_stream = chained
-                else:
-                    l_stream = iter(done[0])
-                    r_stream = chained
-            acero = None
-        if acero is not None:
+        from blaze_tpu.bridge.placement import host_resident
+        if host_resident():
+            return self._merge_host(partition)
+        return iter(CoalesceStream(self._merge_device(partition),
+                                   metrics=self.metrics))
+
+    def _merge_host(self, partition: int) -> Iterator[ColumnBatch]:
+        """Host placement: both sorted sides within the collect budget
+        (`auron.tpu.fused.hostCollectRows`, per side) join through Arrow
+        (`_acero_join`).  A side over it (the spillable sorts exist
+        precisely for that), computed keys, or a join type Arrow lacks go
+        through the run cursor, resumed from what was collected."""
+        streams = [_arrow_stream(self._sorted_child(i).execute(partition))
+                   for i in (0, 1)]
+        held: List[List[pa.RecordBatch]] = [[], []]
+        limit = config.FUSED_HOST_COLLECT_ROWS.get()
+
+        def sides_fit() -> bool:
+            for side, stream in zip(held, streams):
+                rows = 0
+                for rb in stream:
+                    side.append(rb)
+                    rows += rb.num_rows
+                    if rows > limit:
+                        return False
+            return True
+
+        if self._acero_eligible() and sides_fit():
             # output_rows is counted inside _pa_join_once already
-            return iter(acero)
+            yield from self._acero_join(*held)
+            return
+        yield from CoalesceStream(
+            self._merge_streaming(*(itertools.chain(side, stream)
+                                    for side, stream in zip(held, streams))),
+            metrics=self.metrics)
 
-        def arrow_stream(plan):
-            for b in plan.execute(partition):
-                rb = b.compact().to_arrow()
-                if rb.num_rows:
-                    yield rb
+    def _merge_device(self, partition: int) -> Iterator[ColumnBatch]:
+        """The partition through ops/joins/merge.py: both sorted sides
+        collected on the device, their bytes and the pairs' declared to the
+        memory manager (`merge.Hold`), joined by device programs.  A
+        partition the manager sheds, and a join shape `merge.declines`
+        names, go through the run cursor instead, resumed from what was
+        collected (both children are sorted)."""
+        from blaze_tpu.memory import MemManager
+        from blaze_tpu.ops.joins import merge
+        streams = [self._sorted_child(i).execute(partition) for i in (0, 1)]
+        held: List[List[ColumnBatch]] = [[], []]
+        hold = merge.Hold(self.metrics)
+        hold.set_spillable(MemManager.get())
 
-        if l_stream is None:
-            l_stream = arrow_stream(self._sorted_child(0))
-        if r_stream is None:
-            r_stream = arrow_stream(self._sorted_child(1))
+        def sides_fit() -> bool:
+            for side, stream in zip(held, streams):
+                for b in stream:
+                    b = b.compact()
+                    if b.num_rows:
+                        side.append(b)
+                        if not hold.reserve(b.nbytes_device()):
+                            return False
+            return True
 
+        try:
+            if merge.declines(self) is None and sides_fit():
+                try:
+                    out = merge.join(self, held[0], held[1], hold)
+                except merge.Over:
+                    pass  # the pairs were denied their bytes
+                else:
+                    if out is not None:
+                        yield out
+                    return
+            self.metrics.add("smj_streamed", 1)
+            # down a tier before anything else is held: the run cursor
+            # reads Arrow
+            rest = [itertools.chain([b.to_arrow() for b in side],
+                                    _arrow_stream(stream))
+                    for side, stream in zip(held, streams)]
+            del held[:]
+            hold.update_mem_used(0)
+            yield from self._merge_streaming(*rest)
+        finally:
+            hold.unregister()
+
+    def _merge_streaming(self, l_stream, r_stream) -> Iterator[ColumnBatch]:
+        """The run-cursor merge over two key-sorted Arrow streams."""
+        from blaze_tpu.ops.joins.smj import MergeJoiner, _RunCursor
         joiner = MergeJoiner(self.children[0].schema,
                              self.children[1].schema, self.schema,
                              self.join_type, self.join_filter,
@@ -1151,11 +1173,15 @@ class SortMergeJoinExec(BaseJoinExec):
                           self.children[0].schema)
         rcur = _RunCursor(r_stream, self.right_keys,
                           self.children[1].schema)
+        for rb in joiner.join(lcur, rcur):
+            yield ColumnBatch.from_arrow(rb)
 
-        def gen():
-            for rb in joiner.join(lcur, rcur):
-                yield ColumnBatch.from_arrow(rb)
-        return iter(CoalesceStream(gen(), metrics=self.metrics))
+
+def _arrow_stream(batches) -> Iterator[pa.RecordBatch]:
+    for b in batches:
+        rb = b.compact().to_arrow()
+        if rb.num_rows:
+            yield rb
 
 
 class ShuffledHashJoinExec(BaseJoinExec):

@@ -84,6 +84,7 @@ class _RunCursor:
         self._pending: List[Tuple[Tuple, pa.Table]] = []  # complete runs
         self._tail: Optional[Tuple[Tuple, pa.Table]] = None
         self._done = False
+        self.runs = 0  # runs handed out: what `smj_streamed_runs` counts
 
     def _keys_of(self, rb: pa.RecordBatch) -> List[pa.Array]:
         cb = ColumnBatch.from_arrow(rb)
@@ -139,6 +140,7 @@ class _RunCursor:
             self._ingest()
         if self._pending:
             key, tbl = self._pending.pop(0)
+            self.runs += 1
             return _Run(key, tbl)
         return None
 
@@ -225,6 +227,15 @@ class MergeJoiner:
     # -- the merge ----------------------------------------------------------
     def join(self, lcur: _RunCursor, rcur: _RunCursor
              ) -> Iterator[pa.RecordBatch]:
+        from blaze_tpu.bridge import xla_stats
+        try:
+            yield from self._join(lcur, rcur)
+        finally:
+            xla_stats.note_sortmerge(
+                smj_streamed_runs=lcur.runs + rcur.runs)
+
+    def _join(self, lcur: _RunCursor, rcur: _RunCursor
+              ) -> Iterator[pa.RecordBatch]:
         JT = self.JT
         jt = self.join_type
         left_outer = jt in (JT.LEFT, JT.FULL)

@@ -29,7 +29,7 @@ import numpy as np
 import pyarrow as pa
 
 from blaze_tpu import config
-from blaze_tpu.batch import ColumnBatch, DeviceColumn, bucket_capacity
+from blaze_tpu.batch import ColumnBatch, bucket_capacity
 from blaze_tpu.exprs import PhysicalExpr
 from blaze_tpu.memory import MemConsumer, MemManager, Spill, try_new_spill
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
@@ -132,6 +132,45 @@ def host_sort_keys(rb: pa.RecordBatch, key_cols: Sequence[int],
 def lexsort_host(keys: List[np.ndarray]) -> np.ndarray:
     # np.lexsort sorts by the LAST key first
     return np.lexsort(tuple(reversed(keys)))
+
+
+def _digits(keys: List[np.ndarray]) -> List[np.ndarray]:
+    """The order keys as 32-bit digits, most significant first, without the
+    digits every row agrees on (the bucket of a column without NULLs, the
+    high half of a small surrogate key): those cannot move a row."""
+    out = []
+    for k in keys:
+        halves = ([k.astype(np.uint32)] if k.dtype.itemsize <= 4 else
+                  [(k >> np.uint64(32)).astype(np.uint32),
+                   k.astype(np.uint32)])
+        out.extend(h for h in halves if len(h) and h.min() != h.max())
+    return out
+
+
+def _device_permutation(keys: List[np.ndarray], n: int) -> np.ndarray:
+    """The stable sort permutation of host order keys (unsigned columns
+    whose joint lexicographic order is the SQL order), taken on the device:
+    one `sort_pass` (kernels/sort.py) per 32-bit digit, least significant
+    first.  Padding rows carry the largest digit everywhere and every pass is
+    stable, so they end behind every row."""
+    from blaze_tpu.bridge import tracing, xla_stats
+    from blaze_tpu.kernels.sort import sort_pass
+    from blaze_tpu.xputil import to_device, to_host
+    digits = _digits(keys)
+    if not digits:
+        return np.arange(n)
+    cap = bucket_capacity(n)
+    with tracing.span("sort_device", rows=n, passes=len(digits)):
+        padded = np.full((len(digits), cap), np.uint32(0xFFFFFFFF))
+        for i, d in enumerate(digits):
+            padded[i, :n] = d
+        *placed, perm = to_device(
+            list(padded) + [np.arange(cap, dtype=np.int32)])
+        for d in reversed(placed):
+            perm = sort_pass(d, perm)
+        out = to_host(perm)[:n]
+    xla_stats.note_sortmerge(sort_device_rows=n)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -255,24 +294,12 @@ class _SortState(MemConsumer):
         key_cols = list(range(self._num_keys))
         desc = [d for _, d, _ in self._specs]
         nf = [f for _, _, f in self._specs]
-        fixed = all(_is_fixed(rb.column(i).type) for i in key_cols)
-        if fixed and rb.num_rows >= 1024:
-            # device path: order keys + fused lax.sort
-            import jax.numpy as jnp
-            from blaze_tpu.kernels import compare
-            from blaze_tpu.schema import DataType
-            cols = []
-            for i in key_cols:
-                dc = DeviceColumn.from_arrow(
-                    rb.column(i), DataType.from_arrow(rb.column(i).type),
-                    bucket_capacity(rb.num_rows))
-                cols.append((dc.data, dc.validity, dc.dtype))
-            keys = compare.order_keys(cols, desc, nf)
-            valid = jnp.arange(cols[0][0].shape[0]) < rb.num_rows
-            perm = compare.lexsort_indices(keys, valid)
-            return np.asarray(perm)[:rb.num_rows]
         keys = host_sort_keys(rb, key_cols, desc, nf)
-        return lexsort_host(keys)
+        from blaze_tpu.bridge.placement import host_resident
+        if host_resident() or rb.num_rows < 1024 \
+                or any(k.dtype == object for k in keys):
+            return lexsort_host(keys)
+        return _device_permutation(keys, rb.num_rows)
 
     # -- merged output ------------------------------------------------------
     def merged_output(self) -> Iterator[pa.RecordBatch]:
@@ -367,11 +394,6 @@ def merge_sorted_batches(runs: List[Iterator[pa.RecordBatch]],
         chunk = max(1, min(bs, mem_target // row_bytes))
         for off in range(0, out.num_rows, chunk):
             yield out.slice(off, min(chunk, out.num_rows - off))
-
-
-def _is_fixed(t: pa.DataType) -> bool:
-    return not (pa.types.is_string(t) or pa.types.is_large_string(t) or
-                pa.types.is_binary(t) or pa.types.is_nested(t))
 
 
 def _key_tuple(keys: List[np.ndarray], row: int) -> tuple:

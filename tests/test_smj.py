@@ -50,10 +50,12 @@ def _sorted_frames(df):
 @pytest.fixture(params=["acero", "streaming"])
 def smj_path(request):
     """Both SMJ host paths stay covered: the Acero materialized join
-    and the streaming run-cursor merge it falls back to."""
-    key = config.SMJ_ACERO_ENABLE.key
-    old = config.SMJ_ACERO_ENABLE.get()
-    config.conf.set(key, request.param == "acero")
+    and the streaming run-cursor merge it falls back to when a side passes
+    the collect budget (here: at its first row)."""
+    key = config.FUSED_HOST_COLLECT_ROWS.key
+    old = config.FUSED_HOST_COLLECT_ROWS.get()
+    if request.param == "streaming":
+        config.conf.set(key, 0)
     yield request.param
     config.conf.set(key, old)
 
