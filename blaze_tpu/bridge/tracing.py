@@ -120,6 +120,11 @@ SPAN_NAMES: Dict[str, str] = {
                  "rows of both sides, pairs)",
     "agg_drain": "an aggregation table read back and turned into an "
                  "Arrow batch (plan/fused.py _emit_*; attrs table)",
+    "partial_passthrough": "one chunk of a partial aggregation that "
+                           "stopped grouping: the chain as one program, "
+                           "the live rows read back in accumulator form "
+                           "(runtime/loop.py; attrs stage, partition, "
+                           "chunk, batches)",
     # -- instants (dur_ns == 0) ---------------------------------------
     "task_retry": "a failed attempt was classified retryable and will "
                   "back off and retry (bridge/tasks.py)",

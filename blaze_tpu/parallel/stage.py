@@ -270,6 +270,24 @@ def init_hash_carry(key_dtypes: Sequence, acc_kinds: Sequence[str],
                         jnp.zeros(num_slots, dtype=bool))
 
 
+def normalize_float_keys(key_cols):
+    """Grouping normalizes -0.0 to 0.0 AND NaN to one canonical bit
+    pattern BEFORE hashing (Spark's NormalizeFloatingNumbers does both
+    upstream of the hash, so the raw-bits hash kernel itself stays
+    bit-exact with Spark).  Without the NaN leg, differently-encoded
+    NaNs hash to different slots while the slot-match treats any
+    NaN == NaN — keys could land in two groups.  The table stores the
+    normalized keys, so rows that leave a partial aggregation without
+    entering one (runtime/loop.py's pass-through) take the same form."""
+    def _norm(d):
+        d = jnp.where(d == 0, jnp.abs(d), d)
+        return jnp.where(jnp.isnan(d), jnp.array(jnp.nan, dtype=d.dtype), d)
+
+    return [(_norm(d), v)
+            if jnp.issubdtype(d.dtype, jnp.floating) else (d, v)
+            for d, v in key_cols]
+
+
 def hash_agg_step(carry: HashAggCarry,
                   key_cols: Sequence[Tuple[jax.Array, jax.Array]],
                   agg_specs: Sequence[Tuple[str, Optional[jax.Array],
@@ -295,19 +313,7 @@ def hash_agg_step(carry: HashAggCarry,
     n = mask.shape[0]
     row_idx = jnp.arange(n, dtype=jnp.int64)
 
-    # grouping normalizes -0.0 to 0.0 AND NaN to one canonical bit
-    # pattern BEFORE hashing (Spark's NormalizeFloatingNumbers does both
-    # upstream of the hash, so the raw-bits hash kernel itself stays
-    # bit-exact with Spark).  Without the NaN leg, differently-encoded
-    # NaNs hash to different slots while the slot-match treats any
-    # NaN == NaN — keys could land in two groups.
-    def _norm(d):
-        d = jnp.where(d == 0, jnp.abs(d), d)
-        return jnp.where(jnp.isnan(d), jnp.array(jnp.nan, dtype=d.dtype), d)
-
-    key_cols = [(_norm(d), v)
-                if jnp.issubdtype(d.dtype, jnp.floating) else (d, v)
-                for d, v in key_cols]
+    key_cols = normalize_float_keys(key_cols)
 
     cols = [(d, v, _dtype_of(d).id.value) for d, v in key_cols]
     h = H.hash_columns(cols, seed=42, xp=jnp, algo="xxhash64")
@@ -410,6 +416,26 @@ def _hash_step_tail(carry, key_cols, agg_specs, mask, placed, tkeys,
     return sel, overflow, num_groups
 
 
+def row_contribution(kind: str, vd: Optional[jax.Array],
+                     vv: Optional[jax.Array], mask: jax.Array, dtype):
+    """(value, valid) one row brings to its group's accumulator of
+    `kind`: the count of its valid argument (of the row, for count(*)),
+    its value, or the identity where the argument is null or the row is
+    masked.  It is also the accumulator of a group that holds this ONE
+    row, which is how rows leave a partial aggregation that stopped
+    grouping (runtime/loop.py).  Null semantics live here alone."""
+    cv = (vv if vv is not None else jnp.ones_like(mask)) & mask
+    if kind == "count":
+        return cv.astype(dtype), cv
+    if kind == "sum":
+        return jnp.where(cv, vd.astype(dtype), 0), cv
+    if kind == "min":
+        return jnp.where(cv, vd.astype(dtype), _identity(dtype, False)), cv
+    if kind == "max":
+        return jnp.where(cv, vd.astype(dtype), _identity(dtype, True)), cv
+    raise ValueError(f"unsupported agg kind {kind}")
+
+
 def scatter_accumulate(g: jax.Array,
                        agg_specs: Sequence[Tuple[str, Optional[jax.Array],
                                                  Optional[jax.Array]]],
@@ -420,25 +446,15 @@ def scatter_accumulate(g: jax.Array,
     place so null/identity semantics cannot diverge between paths."""
     new_accs, new_avalid = [], []
     for (kind, vd, vv), a, av in zip(agg_specs, accs, avalid):
-        cv = (vv if vv is not None else jnp.ones_like(mask)) & mask
-        if kind == "count":
-            a = a.at[g].add(cv.astype(a.dtype), mode="drop")
-        elif kind == "sum":
-            a = a.at[g].add(jnp.where(cv, vd.astype(a.dtype), 0),
-                            mode="drop")
-            av = av.at[g].max(cv, mode="drop")
-        elif kind == "min":
-            big = _identity(a.dtype, False)
-            a = a.at[g].min(jnp.where(cv, vd.astype(a.dtype), big),
-                            mode="drop")
-            av = av.at[g].max(cv, mode="drop")
+        val, cv = row_contribution(kind, vd, vv, mask, a.dtype)
+        if kind == "min":
+            a = a.at[g].min(val, mode="drop")
         elif kind == "max":
-            small = _identity(a.dtype, True)
-            a = a.at[g].max(jnp.where(cv, vd.astype(a.dtype), small),
-                            mode="drop")
-            av = av.at[g].max(cv, mode="drop")
+            a = a.at[g].max(val, mode="drop")
         else:
-            raise ValueError(f"unsupported agg kind {kind}")
+            a = a.at[g].add(val, mode="drop")
+        if kind != "count":
+            av = av.at[g].max(cv, mode="drop")
         new_accs.append(a)
         new_avalid.append(av)
     return new_accs, new_avalid

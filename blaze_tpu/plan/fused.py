@@ -579,9 +579,13 @@ class FusedPartialAggExec(ExecutionPlan):
         if prog is not None:
             # device-resident stage loop (runtime/loop.py): ONE jit'd
             # program folds a chunk of batches, amortizing dispatch per
-            # chunk instead of per batch.  The loop emits only at its
-            # final drain, so StageLoopFallback here is lossless and the
-            # partition re-runs through the staged lanes below.
+            # chunk instead of per batch.  The loop raises
+            # StageLoopFallback only while it has emitted nothing (it
+            # emits at its final drain, or from the point at which a
+            # partial aggregation switches to pass-through, after which
+            # an error fails the task instead), so the fallback is
+            # lossless and the partition re-runs through the staged
+            # lanes below.
             from blaze_tpu.runtime.loop import (StageLoopFallback,
                                                 execute_loop)
             try:
@@ -1885,18 +1889,25 @@ class FusedPartialAggExec(ExecutionPlan):
             sel, count = _used_slots(carry.used)
             if count == 0:
                 return
-            keys_h, kvalid_h, accs_h, avalid_h = to_host(
-                ([jnp.take(k, sel) for k in carry.keys],
-                 [jnp.take(v, sel) for v in carry.key_valid],
-                 [jnp.take(a, sel) for a in carry.accs],
-                 [jnp.take(v, sel) for v in carry.acc_valid]))
-            keys = [(kd[:count], kv[:count])
-                    for kd, kv in zip(keys_h, kvalid_h)]
-            accs = [a[:count] for a in accs_h]
-            avalid = [v[:count] for v in avalid_h]
-            rb = self._rows_to_arrow(keys, accs, avalid,
-                                     key_dicts=key_dicts)
+            rb = self._take_to_arrow(sel, count, carry.keys,
+                                     carry.key_valid, carry.accs,
+                                     carry.acc_valid, key_dicts=key_dicts)
         yield from self._emit_chunks(rb)
+
+    def _take_to_arrow(self, sel, count: int, keys, key_valid, accs,
+                       acc_valid, key_dicts=None) -> pa.RecordBatch:
+        """Rows `sel[:count]` of device columns in accumulator form (a
+        hash table's used slots, a pass-through window's live rows),
+        read back and laid out in the out-schema."""
+        keys_h, kvalid_h, accs_h, avalid_h = to_host(
+            ([jnp.take(k, sel) for k in keys],
+             [jnp.take(v, sel) for v in key_valid],
+             [jnp.take(a, sel) for a in accs],
+             [jnp.take(v, sel) for v in acc_valid]))
+        return self._rows_to_arrow(
+            [(kd[:count], kv[:count]) for kd, kv in zip(keys_h, kvalid_h)],
+            [a[:count] for a in accs_h], [v[:count] for v in avalid_h],
+            key_dicts=key_dicts)
 
     # -- shared emission ----------------------------------------------------
     def _device_inputs(self, batch: ColumnBatch):

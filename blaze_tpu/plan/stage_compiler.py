@@ -18,8 +18,8 @@ Eligibility (anything else stays on the staged per-batch executor):
   * every group key is fixed-width (utf8 keys belong to the Arrow host
     lane), and there is at least one group key;
   * the source plan is re-executable, so a wholesale fallback can re-run
-    the partition from scratch losslessly (the loop emits nothing until
-    its final drain).
+    the partition from scratch losslessly (the loop falls back only
+    while it has emitted nothing: runtime/loop.py).
 
 One `StageProgram` fingerprint = (chain cache key, reduce kinds, key
 dtypes, acc dtypes).  The loop's fold program is cached per

@@ -5,7 +5,7 @@ chain step) plus a host sync for the overflow scalar, so at a
 millisecond per dispatch round trip (1.0 ms on a directly attached v5e,
 CHANGES.md PR 21) the engine is dispatch-bound, not compute-bound.
 This loop folds a CHUNK of bucket-padded batches per dispatch:
-`lax.fori_loop` runs chain + probe-insert + accumulate for every batch
+`lax.while_loop` runs chain + probe-insert + accumulate for every batch
 of the chunk inside ONE program, carrying the agg hash table across
 iterations with buffer donation, so Python-side dispatches per
 partition drop from O(batches x operators) to O(chunks).
@@ -15,10 +15,25 @@ the chunk boundary the host already holds the chunk's row counts and
 the table's group count (they ride the overflow scalars' round trip),
 so the table is sized for `groups + rows about to arrive` — a plain
 allocation while it is empty, one rehash otherwise — and overflow is a
-rare backstop, not the growth policy.  Partial mode grows like the
-exact modes: the loop emits one fully aggregated table per task, and
-the skip semantics stay with the staged path (`_execute_sorted`), which
-takes the partition only past `_MAX_SLOTS`.
+rare backstop, not the growth policy.
+
+Partial-aggregation skipping (the AGG_TRIGGER_PARTIAL_SKIPPING analog,
+ref agg_table.rs:108-122; the three `auron.tpu.partialAgg.skipping.*`
+keys).  A PARTIAL program driven by `execute_loop` measures groups per
+LIVE row: the fold returns the rows it inserted (after the chain's
+filter, which the host cannot count beforehand) beside the table's
+group count.  While fewer than `minRows` live rows are in, the fold
+stops at the first batch boundary that reaches them and the host
+resumes the SAME chunk there, as it does after an overflow; from then
+on the cumulative ratio is looked at after every fold.  Once
+`groups / live rows > ratio` the loop SWITCHES: it drains what the
+table holds, releases it, and passes the rest of the partition through
+un-aggregated in accumulator form (`_pass_through`), one elementwise
+program per chunk over the same stacked window.  The FINAL aggregation
+downstream merges raw rows exactly as it merges partial groups.  FINAL,
+merge and complete programs never switch (nothing merges after them),
+nor do programs with dictionary-encoded keys, nor `run_partition`'s
+direct caller (the device-to-device exchange wants ONE carry).
 
 Discipline inherited from the staged path, kept intact:
 
@@ -26,14 +41,21 @@ Discipline inherited from the staged path, kept intact:
     leaves the carry unchanged and masks every later batch of the chunk
     to a no-op; the host re-sizes + rehashes and resumes the SAME chunk
     at the overflow batch.  Past `_MAX_SLOTS` every mode falls back
-    wholesale (the loop emits nothing until its final drain, so the
-    staged re-run is lossless).
+    wholesale.
+  * Fallback only before the first emission.  Until a partition
+    switches (and for ever, if it never does) the loop has emitted
+    nothing, so `StageLoopFallback` and the staged re-run are lossless.
+    A switched partition HAS emitted: from then on nothing is turned
+    into a fallback, and a fault or error is the task's failure (the
+    retry re-runs the partition whole, staged; first-wins commit keeps
+    one output).
   * Cancellation/deadline (PR 7): the query token is checked between
     chunks (and per source batch by the metered stream), so teardown
-    latency is bounded by one chunk.
-  * Fault injection (PR 4): the `device-loop` site fires at chunk
-    boundaries; an injected fault becomes a wholesale fallback, never a
-    divergent result.
+    latency is bounded by one chunk, folded or passed through.
+  * Fault injection (PR 4): the `device-loop` site fires at every chunk
+    boundary; before the switch an injected fault becomes a wholesale
+    fallback, after it a retryable task failure, never a divergent
+    result.
 """
 
 from __future__ import annotations
@@ -41,6 +63,7 @@ from __future__ import annotations
 import math
 import threading
 from contextlib import contextmanager
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +72,9 @@ from blaze_tpu import config, faults
 from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.xla_stats import meter_jit
-from blaze_tpu.parallel.stage import hash_agg_step, init_hash_carry
+from blaze_tpu.parallel.stage import (hash_agg_step, init_hash_carry,
+                                      normalize_float_keys,
+                                      row_contribution)
 from blaze_tpu.xputil import to_host
 
 # hard ceiling on the table size: past this the partition is cheaper to
@@ -65,6 +90,10 @@ _MAX_SLOTS = 1 << 24
 _TRIGGER_LOAD = 0.25
 _TARGET_LOAD = 0.125
 
+# the fold's `look` argument when no first look is pending: more live
+# rows than a chunk can hold, so the fold never stops for it
+_NO_LOOK = (1 << 31) - 1
+
 
 class StageLoopFallback(RuntimeError):
     """The loop declined or failed BEFORE emitting anything; the caller
@@ -73,7 +102,8 @@ class StageLoopFallback(RuntimeError):
     new failure mode."""
 
 
-# fingerprint -> jit'd chunk fold; bounded FIFO like fused's step caches
+# fingerprint -> jit'd chunk fold or pass-through; bounded FIFO like
+# fused's step caches
 _FOLD_CACHE: dict = {}
 _FOLD_LIMIT = 128
 
@@ -112,23 +142,28 @@ def _run_fences() -> None:
         fn()
 
 
+def _cached(skey, build):
+    fn = _FOLD_CACHE.get(skey)
+    if fn is None:
+        if len(_FOLD_CACHE) >= _FOLD_LIMIT:
+            _FOLD_CACHE.pop(next(iter(_FOLD_CACHE)))
+        fn = _FOLD_CACHE[skey] = build()
+    return fn
+
+
+def _batch_of(cols_stacked, b):
+    return tuple(None if col is None else (col[0][b], col[1][b])
+                 for col in cols_stacked)
+
+
 def _fold_factory(program, donate: bool, lane: str = "scatter"):
-    skey = (program.fingerprint, bool(donate), lane)
-    fold = _FOLD_CACHE.get(skey)
-    if fold is not None:
-        return fold
-    if len(_FOLD_CACHE) >= _FOLD_LIMIT:
-        _FOLD_CACHE.pop(next(iter(_FOLD_CACHE)))
     prepare = program.prepare
     kinds = program.kinds
 
-    def fold_impl(carry, cols_stacked, masks, start):
-        def body(b, state):
-            c, ovf_seen, first_ovf = state
-            cols_b = tuple(
-                None if col is None else (col[0][b], col[1][b])
-                for col in cols_stacked)
-            kd, kv, ad, av, m = prepare(cols_b, masks[b])
+    def fold_impl(carry, cols_stacked, masks, start, look):
+        def body(state):
+            b, c, ovf_seen, first_ovf, folded = state
+            kd, kv, ad, av, m = prepare(_batch_of(cols_stacked, b), masks[b])
             # once a batch overflows, later batches fold as no-ops: the
             # carry stays exactly at the pre-overflow state (hash_agg_step
             # is atomic), so the host can regrow and resume mid-chunk
@@ -137,22 +172,65 @@ def _fold_factory(program, donate: bool, lane: str = "scatter"):
             new_c, ovf, _ng = hash_agg_step(c, list(zip(kd, kv)), specs,
                                             live, lane=lane)
             hit = ovf > 0
-            first_ovf = jnp.where(hit & ~ovf_seen,
-                                  jnp.asarray(b, jnp.int32), first_ovf)
-            return (new_c, jnp.logical_or(ovf_seen, hit), first_ovf)
+            first_ovf = jnp.where(hit & ~ovf_seen, b, first_ovf)
+            # the rows of an overflowing batch are not in the table
+            folded += jnp.where(hit, 0, jnp.sum(live, dtype=jnp.int32))
+            return (b + 1, new_c, jnp.logical_or(ovf_seen, hit), first_ovf,
+                    folded)
 
-        init = (carry, jnp.asarray(False), jnp.asarray(0, jnp.int32))
-        carry, ovf_seen, first_ovf = jax.lax.fori_loop(
-            start, masks.shape[0], body, init)
-        # the table's group count rides the overflow scalars' round
-        # trip: the host sizes the next chunk's table from it
+        def more(state):
+            b, _c, _ovf_seen, _first_ovf, folded = state
+            # `look` live rows are in: stop at this batch boundary, so
+            # the host can take its first look at groups per live row
+            return (b < masks.shape[0]) & (folded < look)
+
+        zero = jnp.asarray(0, jnp.int32)
+        b, carry, ovf_seen, first_ovf, folded = jax.lax.while_loop(
+            more, body, (start, carry, jnp.asarray(False), zero, zero))
+        # the table's group count and the live rows this call inserted
+        # ride the overflow scalars' round trip: the host sizes the next
+        # chunk's table from the one and judges the partial-skip ratio
+        # from both.  `resume` is the batch to go on from: the one that
+        # overflowed, else the first one not folded (the chunk's width
+        # when nothing stopped the fold)
         groups = jnp.sum(carry.used, dtype=jnp.int32)
-        return carry, ovf_seen, first_ovf, groups
+        resume = jnp.where(ovf_seen, first_ovf, b)
+        return carry, ovf_seen, resume, groups, folded
 
     kwargs = {"donate_argnums": (0,)} if donate else {}
-    fold = meter_jit(fold_impl, name="runtime.stage_loop", **kwargs)
-    _FOLD_CACHE[skey] = fold
-    return fold
+    return _cached(
+        (program.fingerprint, bool(donate), lane),
+        lambda: meter_jit(fold_impl, name="runtime.stage_loop", **kwargs))
+
+
+def _passthrough_factory(program):
+    """The chain alone over one stacked window, for a partition that
+    stopped grouping: every batch from `start` on leaves as flat device
+    columns in the partial output's accumulator form, with the mask of
+    its live rows."""
+    prepare = program.prepare
+    kinds = program.kinds
+    key_dtypes = program.key_dtypes
+    acc_dtypes = program.acc_dtypes
+
+    def passthrough_impl(cols_stacked, masks, start):
+        def one(b):
+            kd, kv, ad, av, m = prepare(_batch_of(cols_stacked, b), masks[b])
+            live = jnp.logical_and(m, b >= start)
+            kd, kv = zip(*normalize_float_keys(
+                [(d.astype(dt), v) for d, v, dt in zip(kd, kv, key_dtypes)]))
+            accs, acc_valid = zip(*(
+                row_contribution(k, d, v, live, dt)
+                for k, d, v, dt in zip(kinds, ad, av, acc_dtypes)))
+            return kd, kv, accs, acc_valid, live
+
+        out = jax.lax.map(one, jnp.arange(masks.shape[0], dtype=jnp.int32))
+        return jax.tree_util.tree_map(lambda a: a.reshape(-1), out)
+
+    return _cached(
+        ("passthrough", program.fingerprint),
+        lambda: meter_jit(passthrough_impl,
+                          name="runtime.stage_loop_passthrough"))
 
 
 def _donate_active() -> bool:
@@ -199,17 +277,50 @@ def _slots_for(need: int, floor: int) -> int:
     return min(_MAX_SLOTS, max(floor, _pow2(math.ceil(need / _TARGET_LOAD))))
 
 
+class _Unfolded(NamedTuple):
+    """What a partition that switched to pass-through has not folded:
+    the batches of the current (padded) window from `start` on, and the
+    windows still to come."""
+    cols_stacked: tuple
+    masks: jax.Array
+    count: int      # real batches of the window, before its padding
+    start: int
+    chunk: int      # the window's index in the partition
+    windows: object
+
+
+def _may_switch(program) -> bool:
+    """Only a PARTIAL aggregation has a merge downstream that makes
+    groups of passed-through rows."""
+    agg = program.agg
+    return (not agg._complete and not agg._grow
+            and config.PARTIAL_AGG_SKIPPING_ENABLE.get())
+
+
 def run_partition(program, partition: int, ctx: str = "",
                   source_stream=None):
     """Fold one partition through the stage program; returns the final
-    HashAggCarry.  Raises StageLoopFallback on any ineligibility or
-    failure — nothing has been emitted at that point, so the caller's
-    staged re-run is lossless.  Cancellation (QueryCancelled /
-    TaskKilledError / deadline) propagates untranslated.
+    HashAggCarry, which holds every row: this entry never switches to
+    pass-through (its caller, the device-to-device exchange, drains ONE
+    carry).  Raises StageLoopFallback on any ineligibility or failure —
+    nothing has been emitted at that point, so the caller's staged
+    re-run is lossless.  Cancellation (QueryCancelled / TaskKilledError
+    / deadline) propagates untranslated.
 
     The table's capacity sequence is a function of the input alone (rows
     per batch, groups so far), never of timing: a repeat of the same
     partition walks the same sizes and loads no new program."""
+    carry, _rest = _fold_partition(program, partition, ctx, source_stream,
+                                   may_switch=False)
+    return carry
+
+
+def _fold_partition(program, partition: int, ctx: str, source_stream,
+                    may_switch: bool):
+    """(carry, None), or (carry, _Unfolded) when `may_switch` and the
+    table made more than `ratio` groups a live row: the carry holds what
+    was folded until then and the rest is the caller's to pass through.
+    Emits nothing either way, so StageLoopFallback is lossless here."""
     from blaze_tpu.plan.fused import _batch_windows, _pow2, _rehash_jit
     task = current_task()
     q = task.query
@@ -223,11 +334,16 @@ def run_partition(program, partition: int, ctx: str = "",
     lane = lane_mod.resolve("hash")
     fold = _fold_factory(program, _donate_active(), lane)
     floor = _pow2(config.ON_DEVICE_AGG_CAPACITY.get())
+    min_rows = min(_NO_LOOK,
+                   max(1, config.PARTIAL_AGG_SKIPPING_MIN_ROWS.get()))
+    ratio = config.PARTIAL_AGG_SKIPPING_RATIO.get()
     stream = (source_stream if source_stream is not None
               else program.source.execute(partition))
+    windows = _batch_windows(stream, chunk)
     batches = rows = fold_calls = regrows = reserves = rehash_lanes = 0
-    ci = groups = 0
+    ci = groups = live_folded = 0
     slots, carry = floor, None  # allocated at the first chunk, for it
+    rest = None
 
     def fresh(n):
         return init_hash_carry(list(program.key_dtypes), program.kinds,
@@ -250,10 +366,11 @@ def run_partition(program, partition: int, ctx: str = "",
         raise StageLoopFallback(f"table would exceed {_MAX_SLOTS} slots")
 
     try:
-        for cols_stacked, masks, count in _batch_windows(stream, chunk):
-            # chunk boundary: the ONLY host sync points of the loop —
-            # cooperative cancel, fault site, row counts, and the
-            # overflow scalars with the table's group count
+        for cols_stacked, masks, count in windows:
+            # chunk boundary: cooperative cancel, fault site, row counts.
+            # The loop's host syncs are here and after each fold (the
+            # overflow scalars with the table's group count and the
+            # live rows inserted)
             task.check_running()
             faults.maybe_fail("device-loop", stage=ctx, chunk=ci)
             with tracing.span("stage_loop_chunk", stage=ctx,
@@ -262,10 +379,8 @@ def run_partition(program, partition: int, ctx: str = "",
                 # selected lanes per batch: before the chain's filter,
                 # so an upper bound on the rows the fold will insert
                 batch_rows = to_host(jnp.sum(masks, axis=1)).tolist()
-                chunk_rows = sum(batch_rows)
-                rows += chunk_rows
                 # reserve before fold
-                need = groups + chunk_rows
+                need = groups + sum(batch_rows)
                 if carry is None or need > slots * _TRIGGER_LOAD:
                     want = _slots_for(need, floor)
                     if want > slots:
@@ -275,27 +390,45 @@ def run_partition(program, partition: int, ctx: str = "",
                 cols_stacked, masks = _pad_chunk(cols_stacked, masks,
                                                  chunk)
                 start = 0
-                while True:
-                    carry, ovf_seen, first_ovf, ngroups = fold(
+                while start < count:
+                    # the first look: stop the fold once `min_rows` live
+                    # rows are in, not at the chunk's end
+                    look = (min_rows - live_folded
+                            if may_switch and live_folded < min_rows
+                            else _NO_LOOK)
+                    carry, ovf_seen, resume, ngroups, nlive = fold(
                         carry, cols_stacked, masks,
-                        jnp.asarray(start, jnp.int32))
+                        jnp.asarray(start, jnp.int32),
+                        jnp.asarray(look, jnp.int32))
                     fold_calls += 1
                     # the host waits for the fold here
-                    ovf_seen, first_ovf, ngroups = to_host(
-                        (ovf_seen, first_ovf, ngroups))
+                    ovf_seen, resume, ngroups, nlive = to_host(
+                        (ovf_seen, resume, ngroups, nlive))
                     groups = int(ngroups)
-                    if not bool(ovf_seen):
-                        break
-                    # residual overflow (probe clustering below the
-                    # trigger load): size for what is left of the chunk,
-                    # at least double, and resume at the overflow batch
-                    start = int(first_ovf)
-                    need = groups + sum(batch_rows[start:])
-                    carry, slots = resized(
-                        max(slots * 2, _slots_for(need, floor)))
-                    regrows += 1
+                    live_folded += int(nlive)
+                    start = int(resume)
+                    if bool(ovf_seen):
+                        # residual overflow (probe clustering below the
+                        # trigger load): size for what is left of the
+                        # chunk, at least double, and resume at the
+                        # overflow batch
+                        need = groups + sum(batch_rows[start:])
+                        carry, slots = resized(
+                            max(slots * 2, _slots_for(need, floor)))
+                        regrows += 1
+                    elif may_switch and live_folded >= min_rows:
+                        xla_stats.note_partial_agg_probe(live_folded,
+                                                         groups)
+                        if groups > ratio * live_folded:
+                            rest = _Unfolded(cols_stacked, masks, count,
+                                             start, ci, windows)
+                            break
+                # rows handed to the fold, and no others
+                rows += sum(batch_rows[:start])
+                batches += min(start, count)
+            if rest is not None:
+                break
             ci += 1
-            batches += count
             task.loop_chunks = ci
     except faults.InjectedFault as e:
         # scripted chaos at the device-loop site: wholesale fallback,
@@ -303,12 +436,55 @@ def run_partition(program, partition: int, ctx: str = "",
         raise StageLoopFallback(f"injected fault: {e}") from e
     if carry is None:
         carry = fresh(slots)  # empty partition
+    if rest is not None:
+        program.agg.metrics.add("partial_skipped", 1)
+        xla_stats.note_partial_agg_skip(live_folded)
     xla_stats.note_stage_loop_task(
         chunks=fold_calls, batches=batches, rows=rows, regrows=regrows,
         reserves=reserves, rehash_lanes=rehash_lanes, slots=slots,
         dispatches_avoided=max(0, batches - fold_calls))
     program.agg._note_lane(batches)
-    return carry
+    return carry, rest
+
+
+def _pass_through(program, rest: _Unfolded, partition: int, ctx: str):
+    """The rest of a switched partition, un-aggregated: per chunk ONE
+    program runs the chain and lays each live row out as the group it
+    would have been alone, and the live rows leave through the fused
+    node's emission.  No table is held.  The partition has emitted by
+    now, so nothing raised here may become a StageLoopFallback."""
+    from blaze_tpu.plan.fused import _used_slots
+    task = current_task()
+    agg = program.agg
+    passthrough = _passthrough_factory(program)
+    chunk = int(rest.masks.shape[0])
+
+    def chunks():
+        # the boundary checks of the window the switch fell in ran
+        # before its fold; after its last batch nothing of it is left
+        if rest.start < rest.count:
+            yield (rest.chunk, rest.cols_stacked, rest.masks, rest.count,
+                   rest.start)
+        for ci, (cols_stacked, masks, count) in enumerate(rest.windows,
+                                                          rest.chunk + 1):
+            task.check_running()
+            faults.maybe_fail("device-loop", stage=ctx, chunk=ci)
+            yield (ci, *_pad_chunk(cols_stacked, masks, chunk), count, 0)
+
+    for ci, cols_stacked, masks, count, start in chunks():
+        with tracing.span("partial_passthrough", stage=ctx,
+                          partition=partition, chunk=ci,
+                          batches=count - start):
+            keys, key_valid, accs, acc_valid, live = passthrough(
+                cols_stacked, masks, jnp.asarray(start, jnp.int32))
+            sel, n = _used_slots(live)
+            rb = (agg._take_to_arrow(sel, n, keys, key_valid, accs,
+                                     acc_valid) if n else None)
+        xla_stats.note_partial_agg_rows(n)
+        agg._note_lane(count - start)
+        task.loop_chunks = ci + 1
+        if rb is not None:
+            yield from agg._emit_chunks(rb)
 
 
 def _dict_stream_guard(stream, utf8_cols, key_srcs, captured):
@@ -335,8 +511,12 @@ def _dict_stream_guard(stream, utf8_cols, key_srcs, captured):
 
 def execute_loop(program, partition: int, ctx: str = ""):
     """Generator form for FusedPartialAggExec.execute: fold, then drain
-    through the shared emission path (ColumnBatch chunks).  Guaranteed
-    to raise StageLoopFallback only BEFORE the first yield."""
+    through the shared emission path (ColumnBatch chunks); a PARTIAL
+    program whose table made more than `ratio` groups a live row drains
+    early and passes the rest of the partition through un-aggregated
+    (module docstring).  Raises StageLoopFallback only BEFORE the first
+    yield; after it, whatever goes wrong is raised as it is and fails
+    the task."""
     dict_keys = getattr(program, "dict_keys", ())
     if any(s is not None for s in dict_keys):
         from blaze_tpu.schema import TypeId
@@ -346,14 +526,21 @@ def execute_loop(program, partition: int, ctx: str = ""):
         captured: dict = {}
         stream = _dict_stream_guard(program.source.execute(partition),
                                     utf8_cols, key_srcs, captured)
+        # no switch: codes decode through the stream's LAST dictionary,
+        # and the guard may decline the partition at any batch, which
+        # is lossless only while nothing has been emitted
         carry = run_partition(program, partition, ctx=ctx,
                               source_stream=stream)
         key_dicts = [captured.get(s) if s is not None else None
                      for s in dict_keys]
         yield from program.agg._emit_hash(carry, key_dicts=key_dicts)
         return
-    carry = run_partition(program, partition, ctx=ctx)
+    carry, rest = _fold_partition(program, partition, ctx, None,
+                                  may_switch=_may_switch(program))
     yield from program.agg._emit_hash(carry)
+    if rest is not None:
+        del carry  # the table is released before the rest is read
+        yield from _pass_through(program, rest, partition, ctx)
 
 
 def drain_device(program, carry):

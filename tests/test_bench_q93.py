@@ -228,9 +228,9 @@ def test_idle_inside_the_sort_and_the_merge_join_is_read_from_their_spans(
                                        f"{n}.json"))
              for n in ("idle_smj_merge_s", "idle_sort_device_s")}
     manifest = load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    for entry in manifest["per_layer"][-2:]:
-        assert entry["name"] in specs and entry["workloads"] == [
-            "sf1_q93_x1", "sf10_q01_x1"]
+    entries = {e["name"]: e for e in manifest["per_layer"]}
+    for name in specs:
+        assert entries[name]["workloads"] == ["sf1_q93_x1", "sf10_q01_x1"]
 
     def span(name, a, b, thread="task-0"):
         return {"name": name, "t0_ns": a, "t1_ns": b, "dur_ns": b - a,

@@ -142,7 +142,11 @@ def test_overlap_fault_falls_back_wholesale(tmp_path, device_mesh,
     with faults.scoped(("device-collective", dict(at=(1,)))):
         got = _sorted_df(sched.run_collect(plan))
 
-    assert got.equals(clean)
+    # two shuffle paths add a group's partial sums in different orders:
+    # the keys are equal, the float sums to their last digits
+    assert got.k.tolist() == clean.k.tolist()
+    np.testing.assert_allclose(got.s.to_numpy(), clean.s.to_numpy(),
+                               rtol=1e-12)
     assert xla_stats.shuffle_stats()["shuffle_device_fallbacks"] >= 1
     assert all(v == [] for v in sched.leak_report().values())
 

@@ -593,3 +593,415 @@ def test_exchange_fence_runs_before_every_rehash(tmp_path, loop_on,
     assert (d["stage_loop_regrows"] > 0) == (how == "reactive")
     assert "rehash" in events
     assert events == ["fence", "rehash"] * (len(events) // 2)
+
+
+# -- partial-aggregation skipping inside the loop (ISSUE 27) -----------------
+
+@pytest.fixture
+def skip_conf(small_tables):
+    """A first look after 1,000 live rows (the second batch of a chunk
+    of four 512-row batches), a switch past 0.9 groups a live row, and
+    the exchange through shuffle files: the device-to-device exchange
+    (the 8 virtual devices would take it) folds ONE carry, no switch."""
+    config.conf.set(config.PARTIAL_AGG_SKIPPING_MIN_ROWS.key, 1000)
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, 4)
+    config.conf.set(config.SHUFFLE_DEVICE.key, "off")
+    try:
+        yield
+    finally:
+        config.conf.unset(config.PARTIAL_AGG_SKIPPING_MIN_ROWS.key)
+        config.conf.unset(config.PARTIAL_AGG_SKIPPING_ENABLE.key)
+        config.conf.unset(config.SHUFFLE_DEVICE.key)
+
+
+_AGGS = {
+    # case -> [(fn, argument column or None, name)]
+    "sum_float": [("sum", "v", "s")],
+    "int_sum_counts": [("sum", "i", "s"), ("count", None, "n"),
+                       ("count", "i", "c")],
+    "min_max": [("min", "v", "lo"), ("max", "v", "hi"), ("min", "i", "ilo"),
+                ("max", "i", "ihi")],
+    "avg_as_sum_and_count": [("sum", "v", "s"), ("count", "v", "c")],
+}
+
+
+def _skip_table(n=8192, null_keys=False, null_args=False, seed=13,
+                keys=None):
+    """Nearly one group a row on two wide keys; `f` drives the filter."""
+    rng = np.random.default_rng(seed)
+    k = _wide(rng.integers(0, 16 * n, n)) if keys is None else keys
+    k2 = rng.integers(0, 3, n) * 1000003 + 5
+    v = rng.random(n) * 100
+    i = rng.integers(-1000, 1000, n)
+    drop = rng.random(n) < 0.1
+
+    def nullable(a, t, on, share=1.0):
+        # every NULL key lands in one of three groups: keep them few
+        return pa.array(a, type=t,
+                        mask=drop & (rng.random(n) < share) if on else None)
+
+    return pa.table({"k": nullable(k, pa.int64(), null_keys, 0.2),
+                     "k2": pa.array(k2, type=pa.int64()),
+                     "v": nullable(v, pa.float64(), null_args),
+                     "i": nullable(i, pa.int64(), null_args),
+                     "f": pa.array(rng.integers(0, 10, n))})
+
+
+def _skip_agg(table, aggs, mode="partial", filtered=False):
+    """The fused aggregation by (k, k2) over a memory scan, under a
+    filter that keeps 70% of the rows where `filtered`."""
+    from blaze_tpu.exprs import BinaryExpr, col, lit
+    from blaze_tpu.ops import (AggExec, AggMode, FilterExec, MemoryScanExec,
+                               make_agg)
+    from blaze_tpu.plan.fused import FusedPartialAggExec, fuse_plan
+    names = table.schema.names
+    node = MemoryScanExec.from_arrow(table, batch_rows=512)
+    if filtered:
+        node = FilterExec(node, [BinaryExpr(">=", col(names.index("f"), "f"),
+                                            lit(3))])
+    m = {"partial": AggMode.PARTIAL, "complete": AggMode.COMPLETE,
+         "merge": AggMode.PARTIAL_MERGE, "final": AggMode.FINAL}[mode]
+    plan = fuse_plan(AggExec(
+        node, [(col(0, "k"), "k"), (col(1, "k2"), "k2")],
+        [(make_agg(fn, [col(names.index(a), a)] if a else []), m, name)
+         for fn, a, name in aggs]))
+    assert isinstance(plan, FusedPartialAggExec)
+    assert plan.fused_mode == "sorted"  # the hash lane: the loop's
+    return plan
+
+
+def _partial_rows(plan):
+    out = [b.compact().to_arrow() for b in plan.execute(0)]
+    return pa.Table.from_batches([b for b in out if b.num_rows],
+                                 schema=plan.schema.to_arrow())
+
+
+def _final_merge(partial, aggs):
+    """The FINAL aggregation over partial output, on the eager engine
+    (independent of the loop), sorted by key."""
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops import AggExec, AggMode, MemoryScanExec, make_agg
+    merged = AggExec(
+        MemoryScanExec.from_arrow(partial),
+        [(col(0, "k"), "k"), (col(1, "k2"), "k2")],
+        [(make_agg(fn, [col(2 + j)]), AggMode.PARTIAL_MERGE, name)
+         for j, (fn, _a, name) in enumerate(aggs)]).execute_collect()
+    df = merged.to_arrow().to_pandas()
+    df.columns = ["k", "k2"] + [name for _f, _a, name in aggs]
+    return df.sort_values(["k", "k2"], na_position="first") \
+        .reset_index(drop=True)
+
+
+def _assert_same_answer(got, want):
+    """Keys, counts and integer sums exactly; float sums to 1e-12."""
+    assert len(got) == len(want)
+    for c in want.columns:
+        g, w = got[c], want[c]
+        assert g.isna().tolist() == w.isna().tolist(), c
+        if w.dtype.kind == "f" and c not in ("k", "k2"):
+            np.testing.assert_allclose(g.to_numpy(), w.to_numpy(),
+                                       rtol=1e-12, err_msg=c)
+        else:
+            assert g.dropna().tolist() == w.dropna().tolist(), c
+
+
+def _run_skipping(plan_of, enable=True):
+    """(partial output, counters) of one run in the loop."""
+    config.conf.set(config.PARTIAL_AGG_SKIPPING_ENABLE.key, enable)
+    before = xla_stats.snapshot()
+    out = _partial_rows(plan_of())
+    return out, xla_stats.delta(before)
+
+
+@pytest.mark.parametrize("shape", ["plain", "filtered", "null_keys",
+                                   "null_args", "filtered_nulls"])
+@pytest.mark.parametrize("case", sorted(_AGGS))
+def test_high_cardinality_partial_switches_and_merges_to_the_same_answer(
+        loop_on, skip_conf, case, shape):
+    aggs = _AGGS[case]
+    t = _skip_table(null_keys=shape in ("null_keys", "filtered_nulls"),
+                    null_args=shape in ("null_args", "filtered_nulls"))
+
+    def plan():
+        return _skip_agg(t, aggs, filtered=shape.startswith("filtered"))
+
+    skipped, d = _run_skipping(plan)
+    assert d["stage_loop_tasks"] == 1 and d["stage_loop_fallbacks"] == 0
+    assert d["partial_agg_skip_events"] == 1
+    assert d["partial_agg_skipped_rows"] > 0
+    live = 8192 if not shape.startswith("filtered") else int(
+        np.sum(t["f"].to_numpy() >= 3))
+    # the first look came at the batch boundary that reached minRows
+    assert 1000 <= d["partial_agg_switch_rows"] < 1000 + 512
+    assert d["partial_agg_skipped_rows"] == \
+        live - d["partial_agg_switch_rows"]
+    # folded rows are counted as folded, passed rows as passed
+    assert d["stage_loop_rows"] == 512 * d["stage_loop_batches"] < 8192
+    assert skipped.num_rows > 0.9 * live
+
+    grouped, d_off = _run_skipping(plan, enable=False)
+    assert d_off["partial_agg_skip_events"] == 0
+    assert d_off["partial_agg_skipped_rows"] == 0
+    assert d_off["stage_loop_rows"] == 8192
+    assert grouped.schema == skipped.schema
+    want = _final_merge(grouped, aggs)
+    _assert_same_answer(_final_merge(skipped, aggs), want)
+
+    # the staged lane's pass-through (its table overflows at 16 slots
+    # and it passes batch-local groups on) merges to the same
+    config.conf.set(config.STAGE_DEVICE_LOOP_ENABLE.key, "off")
+    staged, d_st = _run_skipping(plan)
+    assert d_st["partial_agg_skip_events"] == 1 and d_st[
+        "stage_loop_tasks"] == 0
+    _assert_same_answer(_final_merge(staged, aggs), want)
+
+
+@pytest.mark.parametrize("exchange", ["files", "device"])
+def test_scheduler_map_tasks_switch_and_the_final_stage_answers(
+        tmp_path, staged_path, loop_on, skip_conf, exchange):
+    """Through DagScheduler: every map task of a high-cardinality partial
+    stage switches, and the FINAL stage makes the same groups and sums of
+    passed-through rows as of partial groups.  The device-to-device
+    exchange drains `run_partition`'s ONE carry: it never switches, and
+    its carry lacks no row."""
+    if exchange == "device":
+        config.conf.set(config.SHUFFLE_DEVICE.key, "on")
+    plan = _two_stage_plan(tmp_path, tag="skip")
+    rng = np.random.default_rng(21)
+    t = pa.table({"k": pa.array(_wide(rng.integers(0, 1 << 20, 8000))),
+                  "v": pa.array(rng.random(8000))})
+    for i, group in enumerate(
+            plan["input"]["input"]["input"]["file_groups"]):
+        pq.write_table(t.slice(i * 4000, 4000), group[0],
+                       row_group_size=512)
+    config.conf.set(config.PARTIAL_AGG_SKIPPING_ENABLE.key, False)
+    before = xla_stats.snapshot()
+    want = _sorted_df(DagScheduler(
+        work_dir=str(tmp_path / "dag-noskip")).run_collect(plan))
+    assert xla_stats.delta(before)["partial_agg_skip_events"] == 0
+    config.conf.set(config.PARTIAL_AGG_SKIPPING_ENABLE.key, True)
+    before = xla_stats.snapshot()
+    got = _sorted_df(DagScheduler(
+        work_dir=str(tmp_path / "dag-skip")).run_collect(plan))
+    d = xla_stats.delta(before)
+    assert d["stage_loop_fallbacks"] == 0 and d["task_retries"] == 0
+    if exchange == "files":
+        assert d["partial_agg_skip_events"] == 2  # both map tasks
+        assert d["partial_agg_skipped_rows"] == 2 * (4000 - 1024)
+    else:
+        assert d["shuffle_device_exchanges"] >= 1
+        assert d["partial_agg_skip_events"] == 0
+        assert d["partial_agg_skipped_rows"] == 0
+    _assert_sums(got, want)
+
+
+@pytest.mark.parametrize("case", ["twelve_groups", "under_min_rows",
+                                  "final", "complete", "merge"])
+def test_these_never_switch(loop_on, skip_conf, case):
+    n = 800 if case == "under_min_rows" else 8192
+    keys = (_wide(np.random.default_rng(2).integers(0, 12, n))
+            if case == "twelve_groups" else None)
+    mode = case if case in ("final", "complete", "merge") else "partial"
+    t = _skip_table(n=n, keys=keys)
+    before = xla_stats.snapshot()
+    out = _partial_rows(_skip_agg(t, _AGGS["sum_float"], mode=mode))
+    d = xla_stats.delta(before)
+    assert d["stage_loop_tasks"] == 1 and d["stage_loop_fallbacks"] == 0
+    assert d["partial_agg_skip_events"] == 0
+    assert d["partial_agg_skipped_rows"] == 0
+    assert d["stage_loop_rows"] == n
+    want = t.to_pandas().groupby(["k", "k2"]).ngroups
+    assert out.num_rows == want  # ONE fully aggregated table
+    chunks = -(-n // 2048)
+    if case == "twelve_groups":
+        # looked at after the first look's stop and after every fold
+        assert d["partial_agg_probe_rows"] > 0
+        assert d["stage_loop_calls"] == chunks + 1
+    else:
+        # nothing was evaluated, and no fold stopped for a look
+        assert d["partial_agg_probe_rows"] == 0
+        assert d["stage_loop_calls"] == chunks
+
+
+def test_low_cardinality_head_high_cardinality_tail_switches_later(
+        loop_on, skip_conf):
+    # one chunk of 12 groups, then every row a group: the cumulative
+    # ratio passes 0.9 at the end of the tenth chunk of 2,048 rows
+    head = np.random.default_rng(4).integers(0, 12, 2048)
+    keys = _wide(np.concatenate([head, 100 + np.arange(30720)]))
+    t = _skip_table(n=len(keys), keys=keys)
+
+    def plan():
+        return _skip_agg(t, _AGGS["int_sum_counts"])
+
+    skipped, d = _run_skipping(plan)
+    assert d["partial_agg_skip_events"] == 1
+    assert d["partial_agg_switch_rows"] == 10 * 2048
+    assert d["partial_agg_skipped_rows"] == \
+        len(keys) - d["partial_agg_switch_rows"]
+    grouped, _d = _run_skipping(plan, enable=False)
+    _assert_same_answer(_final_merge(skipped, _AGGS["int_sum_counts"]),
+                        _final_merge(grouped, _AGGS["int_sum_counts"]))
+
+
+def test_fault_after_the_switch_fails_the_task_not_the_rows(
+        tmp_path, staged_path, loop_on, skip_conf):
+    """A switched partition has emitted: a `device-loop` fault at a later
+    chunk boundary is the task's failure (one retry, staged), never a
+    staged re-run appended to what was emitted."""
+    plan = _two_stage_plan(tmp_path, tag="flt-skip")
+    scan = plan["input"]["input"]["input"]
+    rng = np.random.default_rng(22)
+    t = pa.table({"k": pa.array(_wide(rng.integers(0, 1 << 20, 8000))),
+                  "v": pa.array(rng.random(8000))})
+    pq.write_table(t, scan["file_groups"][0][0], row_group_size=512)
+    scan["file_groups"] = scan["file_groups"][:1]  # ONE map task
+    clean = _sorted_df(DagScheduler(
+        work_dir=str(tmp_path / "dag-clean")).run_collect(plan))
+    assert len(clean) == t.to_pandas().k.nunique()
+
+    before = xla_stats.snapshot()
+    # the switch comes inside the first chunk, at 1,024 live rows: the
+    # third chunk boundary is past it
+    with faults.scoped(("device-loop", dict(at=(3,)))):
+        got = _sorted_df(DagScheduler(
+            work_dir=str(tmp_path / "dag-chaos")).run_collect(plan))
+    d = xla_stats.delta(before)
+    assert d["partial_agg_skip_events"] >= 1  # it had switched
+    assert d["stage_loop_fallbacks"] == 0     # and did not fall back
+    assert d["task_retries"] == 1
+    _assert_sums(got, clean)  # no row twice, no row lost
+
+
+def test_fault_after_the_switch_propagates_from_execute(loop_on,
+                                                        skip_conf):
+    plan = _skip_agg(_skip_table(), _AGGS["sum_float"])
+    before = xla_stats.snapshot()
+    emitted = 0
+    with faults.scoped(("device-loop", dict(at=(3,)))):
+        with pytest.raises(faults.InjectedFault):
+            for b in plan.execute(0):
+                emitted += b.selected_count()
+    d = xla_stats.delta(before)
+    assert emitted > 0 and d["partial_agg_skip_events"] == 1
+    assert d["stage_loop_fallbacks"] == 0
+
+
+def test_skipping_disabled_is_the_loop_without_it(loop_on, skip_conf):
+    """`enable=false`: one fold call a chunk and the same carry as the
+    entry that never switches; and a first look that does not switch
+    (ratio 1.0 cannot be passed) leaves that same carry too, through the
+    same fold program."""
+    from blaze_tpu.plan import stage_compiler
+    from blaze_tpu.runtime import loop as device_loop
+    t = _skip_table()
+
+    def carry_of(**conf):
+        prog = stage_compiler.compile_task_plan(
+            _skip_agg(t, _AGGS["int_sum_counts"], filtered=True))
+        before = xla_stats.snapshot()
+        with config.scoped(**conf), task_scope(TaskContext()):
+            carry, rest = device_loop._fold_partition(
+                prog, 0, "t", None, may_switch=device_loop._may_switch(prog))
+            plain = device_loop.run_partition(prog, 0)
+        assert rest is None
+        return carry, plain, xla_stats.delta(before)
+
+    def same(a, b):
+        import jax
+        return all(np.array_equal(np.asarray(x), np.asarray(y),
+                                  equal_nan=True)
+                   for x, y in zip(jax.tree_util.tree_leaves(a),
+                                   jax.tree_util.tree_leaves(b)))
+
+    off, plain, d = carry_of(
+        **{config.PARTIAL_AGG_SKIPPING_ENABLE.key: False})
+    assert same(off, plain)
+    assert d["stage_loop_calls"] == 2 * 4  # two runs, a call a chunk
+    assert d["partial_agg_probe_rows"] == 0
+    looked, plain, d = carry_of(
+        **{config.PARTIAL_AGG_SKIPPING_RATIO.key: 1.0})
+    assert same(looked, off) and same(plain, off)
+    assert d["stage_loop_calls"] == 2 * 4 + 1  # the first look's stop
+    assert d["partial_agg_probe_rows"] > 0
+    # the early stop and the resumed chunk are the jit signature the
+    # whole chunk has: one program per window, nothing traced anew
+    assert d["total_compiles"] == 0 and d["backend_compiles"] == 0
+
+
+def test_passed_through_chunks_are_spans_and_a_program_of_their_own(
+        loop_on, skip_conf):
+    """Each pass-through chunk is a `partial_passthrough` span (the idle
+    gaps inside it can be named), and its program is not a `fold_impl`:
+    `fold_device_s` and `fold_roofline` read `^jit_fold_impl`."""
+    from blaze_tpu.bridge import tracing
+    tracing.start_tracing()
+    try:
+        _partial_rows(_skip_agg(_skip_table(), _AGGS["sum_float"]))
+        spans = [s for s in tracing.spans()
+                 if s["name"] == "partial_passthrough"]
+        folds = [s for s in tracing.spans()
+                 if s["name"] == "stage_loop_chunk"]
+    finally:
+        tracing.stop_tracing()
+        tracing.reset_conf_probe()
+    # the switch fell in the first of four chunks: its last two batches
+    # and the three chunks after it were passed through
+    assert [s["attrs"]["batches"] for s in spans] == [2, 4, 4, 4]
+    assert [s["attrs"]["chunk"] for s in spans] == [0, 1, 2, 3]
+    assert len(folds) == 1
+    kernels = xla_stats.compile_report()["kernels"]
+    assert kernels["runtime.stage_loop_passthrough"]["calls"] >= 4
+    assert xla_stats.program_name(
+        "passthrough_impl", "runtime.stage_loop_passthrough"
+    ) == "passthrough_impl__runtime_stage_loop_passthrough"
+
+
+@pytest.mark.parametrize("kind", ["count", "count_star", "sum", "min",
+                                  "max"])
+def test_a_rows_accumulator_form_is_the_table_of_that_one_row(kind):
+    """`row_contribution` is what `scatter_accumulate` leaves in a slot
+    that holds ONE row, NULL argument and masked row included."""
+    import jax.numpy as jnp
+    from blaze_tpu.parallel.stage import (init_accumulators,
+                                          row_contribution,
+                                          scatter_accumulate)
+    vd = jnp.asarray([3.5, -1.0, 7.25, 0.0, 9.0])
+    vv = jnp.asarray([True, False, True, True, False])
+    mask = jnp.asarray([True, True, False, True, True])
+    k = "count" if kind == "count_star" else kind
+    arg = (None, None) if kind == "count_star" else (vd, vv)
+    accs, valid = init_accumulators([k], [jnp.float64], 5)
+    accs, valid = scatter_accumulate(jnp.arange(5), [(k, *arg)], mask,
+                                     accs, valid)
+    val, cv = row_contribution(k, *arg, mask, accs[0].dtype)
+    assert val.dtype == accs[0].dtype
+    np.testing.assert_array_equal(np.asarray(val), np.asarray(accs[0]))
+    if k != "count":  # a count is never NULL
+        np.testing.assert_array_equal(np.asarray(cv), np.asarray(valid[0]))
+
+
+def test_a_switch_at_a_windows_last_batch_passes_the_next_window_on(
+        loop_on, skip_conf):
+    """minRows 2,000 is reached at the fourth batch of the first window
+    of four: nothing of that window is left to pass through."""
+    config.conf.set(config.PARTIAL_AGG_SKIPPING_MIN_ROWS.key, 2000)
+    from blaze_tpu.bridge import tracing
+    t = _skip_table()
+    tracing.start_tracing()
+    try:
+        skipped, d = _run_skipping(
+            lambda: _skip_agg(t, _AGGS["int_sum_counts"]))
+        spans = [s["attrs"] for s in tracing.spans()
+                 if s["name"] == "partial_passthrough"]
+    finally:
+        tracing.stop_tracing()
+        tracing.reset_conf_probe()
+    assert d["partial_agg_switch_rows"] == 2048
+    assert d["partial_agg_skipped_rows"] == 8192 - 2048
+    assert [(a["chunk"], a["batches"]) for a in spans] == [
+        (1, 4), (2, 4), (3, 4)]
+    grouped, _d = _run_skipping(
+        lambda: _skip_agg(t, _AGGS["int_sum_counts"]), enable=False)
+    _assert_same_answer(_final_merge(skipped, _AGGS["int_sum_counts"]),
+                        _final_merge(grouped, _AGGS["int_sum_counts"]))
