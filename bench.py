@@ -3423,251 +3423,6 @@ def serve_bench_main() -> int:
 
 
 # ===========================================================================
-# --scatterlane: Pallas hash/radix lanes vs scatter formulations (ISSUE 9)
-# ===========================================================================
-
-def _scatterlane_parity() -> dict:
-    """Interpret-kernel vs scatter-formulation bitwise parity on hostile
-    shapes: NaN bit patterns, -0.0, null keys/values, masked rows, and a
-    forced overflow-at-capacity trial.  The carry tuples must match BIT
-    FOR BIT — this is the oracle behind `bit_identical`."""
-    import jax
-    import numpy as np
-    import jax.numpy as jnp
-
-    from blaze_tpu.kernels import radix
-    from blaze_tpu.parallel.stage import hash_agg_step, init_hash_carry
-
-    def bits_equal(a, b):
-        a, b = np.asarray(a), np.asarray(b)
-        return (a.shape == b.shape and a.dtype == b.dtype
-                and a.tobytes() == b.tobytes())
-
-    trials, failures = 0, 0
-    for seed, S in ((0, 1 << 12), (1, 1 << 12), (2, 64)):  # 64 = overflow
-        rng = np.random.default_rng(seed)
-        n = 2048
-        keys = rng.integers(0, 500, n).astype(np.float64)
-        keys[rng.random(n) < 0.05] = -0.0
-        keys[rng.random(n) < 0.05] = np.nan
-        kv = rng.random(n) > 0.1
-        vals = rng.random(n)
-        av = rng.random(n) > 0.1
-        mask = rng.random(n) > 0.2
-        outs = {}
-        for lane in ("interpret", "scatter"):
-            c = init_hash_carry([jnp.float64], ["sum", "min"],
-                                (jnp.float64, jnp.float64), S)
-            outs[lane] = hash_agg_step(
-                c, [(jnp.asarray(keys), jnp.asarray(kv))],
-                [("sum", jnp.asarray(vals), jnp.asarray(av)),
-                 ("min", jnp.asarray(vals), jnp.asarray(av))],
-                jnp.asarray(mask), lane=lane)
-        (ca, oa, ga), (cb, ob, gb) = outs["interpret"], outs["scatter"]
-        same = int(oa) == int(ob) and int(ga) == int(gb) and all(
-            bits_equal(a, b) for a, b in
-            zip(jax.tree_util.tree_leaves(ca),
-                jax.tree_util.tree_leaves(cb)))
-        trials += 1
-        failures += 0 if same else 1
-
-    # radix lane vs the stable-argsort grouping it replaces
-    rng = np.random.default_rng(7)
-    pids = rng.integers(0, 13, 9000).astype(np.int64)
-    order, starts, ends = radix.partition_order(pids, 13, interpret=True)
-    ref = np.argsort(pids, kind="stable")
-    trials += 1
-    if not (np.array_equal(order, ref)
-            and np.array_equal(
-                starts, np.searchsorted(pids[ref], np.arange(13), "left"))
-            and np.array_equal(
-                ends, np.searchsorted(pids[ref], np.arange(13), "right"))):
-        failures += 1
-    return {"trials": trials, "bit_identical": failures == 0}
-
-
-def _scatterlane_queries() -> dict:
-    """q01/q06/q95 with the kernel lane forced ON vs forced OFF through
-    the staged scheduler; compare_frames is the divergence oracle and
-    the scatter-lane counters prove the ON leg actually took the kernel
-    (or its verified fallback) path."""
-    import tempfile
-
-    from blaze_tpu import config
-    from blaze_tpu.bridge import xla_stats
-    from blaze_tpu.itest import generate
-    from blaze_tpu.itest.queries import QUERIES
-    from blaze_tpu.itest.runner import compare_frames
-    from blaze_tpu.itest.tpcds_data import write_parquet_splits
-    from blaze_tpu.memory import MemManager
-    from blaze_tpu.plan.stages import DagScheduler
-
-    names = os.environ.get("BLAZE_BENCH_SCATTER_QUERIES",
-                           "q01,q06,q95").split(",")
-    scale = float(os.environ.get("BLAZE_BENCH_SCATTER_SCALE", "0.1"))
-    MemManager.init(4 << 30)
-    knobs = {config.DAG_SINGLE_TASK_BYTES.key: 0,
-             config.TASK_RETRY_BACKOFF_MS.key: 5,
-             # the Arrow host lane would swallow the aggs whole — force
-             # the jax hash lane both legs so the kernel actually runs
-             config.FUSED_HOST_VECTORIZED_ENABLE.key: False,
-             config.BATCH_SIZE.key: 8192}
-    for k, v in knobs.items():
-        config.conf.set(k, v)
-
-    def frame(tbl):
-        import pandas as pd
-        return tbl.to_pandas() if tbl.num_rows else pd.DataFrame(
-            {c: [] for c in tbl.schema.names})
-
-    queries, diverged = [], 0
-    lane_delta = {}
-    try:
-        for qname in names:
-            qname = qname.strip()
-            builder, table_names = QUERIES[qname]
-            tables = generate(table_names, scale=scale)
-            with tempfile.TemporaryDirectory(prefix="scatterlane-") as d:
-                paths = write_parquet_splits(tables, d, 2)
-                plan_dict, _oracle = builder(paths, tables, 2)
-
-                config.conf.set(config.KERNELS_PALLAS.key, "off")
-                base = DagScheduler(work_dir=os.path.join(d, "dag0")) \
-                    .run_collect(plan_dict)
-
-                config.conf.set(config.KERNELS_PALLAS.key, "on")
-                before = xla_stats.snapshot()
-                try:
-                    got = DagScheduler(work_dir=os.path.join(d, "dag1")) \
-                        .run_collect(plan_dict)
-                finally:
-                    config.conf.unset(config.KERNELS_PALLAS.key)
-                ds = xla_stats.delta(before)
-                for key, v in ds.items():
-                    if key.startswith("scatter_lane_"):
-                        lane_delta[key] = lane_delta.get(key, 0) + int(v)
-
-                err = compare_frames(frame(got), frame(base))
-                if err is not None:
-                    diverged += 1
-                queries.append({"query": qname, "divergence": err})
-    finally:
-        config.conf.unset(config.KERNELS_PALLAS.key)
-        for k in knobs:
-            config.conf.unset(k)
-    return {"queries": queries, "divergent_queries": diverged,
-            "scale": scale, "lane_counters": lane_delta}
-
-
-def scatterlane_bench_main() -> int:
-    """Scatter-lane leg (`--scatterlane`): the VMEM hash-update kernel
-    against the dense scatter formulation (the
-    `device_scatter_rows_per_sec` shape) at HIGH cardinality — a sparse
-    int64 key domain far wider than the live group count, where the
-    dense table's slot traffic dominates.  Also records the interpret
-    bitwise-parity oracle and the q01/q06/q95 lane-on/lane-off
-    divergence legs.  Writes BENCH_SCATTER.json, prints one JSON line.
-
-    On a CPU session the Mosaic lane cannot lower, so the throughput leg
-    tags `lane_strategy: "hash-ref"` (the scatter hash walk, the same
-    placement contract) and the >=4x gate applies only to the real
-    `pallas` strategy — XLA:CPU scatters are vectorized, so the CPU
-    ratio says nothing about the TPU lane this kernel exists for."""
-    import jax
-    import numpy as np
-    import jax.numpy as jnp
-
-    from blaze_tpu.parallel.stage import (hash_agg_step, init_hash_carry,
-                                          pack_dense_keys)
-    from blaze_tpu.plan import fused as F
-
-    backend = jax.default_backend()
-    n = int(os.environ.get("BLAZE_BENCH_SCATTER_ROWS", str(1 << 16)))
-    reps = int(os.environ.get("BLAZE_BENCH_SCATTER_REPS", "3"))
-    folds = 8  # batches folded per dispatch in both legs
-    domain = 1 << 21  # sparse key domain >> live groups: high cardinality
-    S = 1 << 18
-
-    rng = np.random.default_rng(3)
-    keys = jnp.asarray(rng.integers(0, domain, n).astype(np.int64))
-    vals = jnp.asarray(rng.random(n))
-    valid = jnp.ones(n, dtype=bool)
-    num_slots = domain + 2
-
-    @jax.jit
-    def dense_fold(carry, kd, kv, ad, av):
-        def body(_i, c):
-            # carry-dependent always-true bit: hoist-proofing, as in the
-            # --sf100 device legs (sums stay finite by construction)
-            p = c[0][0].reshape(-1)[0] > -1e300
-            gid, _t = pack_dense_keys([(kd, kv)], [(0, domain - 1)])
-            return F._scatter_into_carry(c, gid, ["sum"], [ad], [av],
-                                         kv & p, num_slots)
-        return jax.lax.fori_loop(0, folds, body, carry)
-
-    lane = "pallas" if backend == "tpu" else "scatter"
-    lane_strategy = "pallas" if backend == "tpu" else "hash-ref"
-
-    @jax.jit
-    def hash_fold(carry, kd, kv, ad, av):
-        def body(_i, c):
-            p = c.accs[0].reshape(-1)[0] > -1e300
-            return hash_agg_step(c, [(kd, kv)], [("sum", ad, av)],
-                                 kv & p, lane=lane)[0]
-        return jax.lax.fori_loop(0, folds, body, carry)
-
-    def time_leg(fn, fresh, read):
-        out = fn(fresh(), keys, valid, vals, valid)  # compile + warmup
-        jax.block_until_ready(read(out))
-        walls = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            out = fn(fresh(), keys, valid, vals, valid)
-            jax.block_until_ready(read(out))
-            walls.append(time.perf_counter() - t0)
-        return min(walls) / folds
-
-    dense_wall = time_leg(
-        dense_fold,
-        lambda: F._init_carry(["sum"], (jnp.float64,), num_slots),
-        lambda o: o[0][0])
-    hash_wall = time_leg(
-        hash_fold,
-        lambda: init_hash_carry([jnp.int64], ["sum"], (jnp.float64,), S),
-        lambda o: o.accs[0])
-    dense_rps = int(n / dense_wall)
-    hash_rps = int(n / hash_wall)
-
-    parity = _scatterlane_parity()
-    itest = _scatterlane_queries()
-
-    rec = {
-        "metric": "scatter_lane_hash_update_speedup",
-        "value": round(hash_rps / dense_rps, 3),
-        "unit": "x vs dense-scatter formulation",
-        "lane_strategy": lane_strategy,
-        "backend": backend,
-        "rows": n, "key_domain": domain, "hash_slots": S,
-        "scatter_formulation_rows_per_sec": dense_rps,
-        "hash_update_rows_per_sec": hash_rps,
-        "bit_identical": parity["bit_identical"],
-        "parity_trials": parity["trials"],
-        "divergent_queries": itest["divergent_queries"],
-        "itest": itest,
-    }
-    path = os.environ.get(
-        "BLAZE_BENCH_SCATTER_PATH",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     "BENCH_SCATTER.json"))
-    _write_bench(path, rec)
-    print(json.dumps(rec))
-    sys.stdout.flush()
-    ok = (rec["bit_identical"] and rec["divergent_queries"] == 0
-          and (rec["value"] >= 4 if lane_strategy == "pallas" else True))
-    return 0 if ok else 1
-
-
-# ===========================================================================
 # --stream: streaming soak — epochs, mid-soak chaos, exactly-once gate
 # ===========================================================================
 
@@ -4900,8 +4655,6 @@ def main():
         sys.exit(aggskip_bench_main())
     if "--deviceloop" in sys.argv:
         sys.exit(deviceloop_bench_main())
-    if "--scatterlane" in sys.argv:
-        sys.exit(scatterlane_bench_main())
     if "--stream" in sys.argv:
         sys.exit(stream_bench_main())
     if "--obs" in sys.argv:

@@ -158,7 +158,6 @@ def prometheus_text() -> str:
         "shuffle": "exchange transport",
         "stage_loop": "device-resident stage loop",
         "agg": "adaptive partial aggregation",
-        "scatter_lane": "pallas kernel-lane resolution",
         "stream": "streaming runtime",
         "workers": "worker pool supervision",
         "speculation": "speculative execution",

@@ -127,19 +127,6 @@ _agg = {"partial_agg_skip_events": 0, "partial_agg_skipped_rows": 0,
 _sortmerge = {"sort_device_rows": 0, "smj_device_rows": 0,
               "smj_device_pairs": 0, "smj_streamed_runs": 0}
 
-# Pallas scatter/hash lane resolutions (kernels/lane.py): which lane
-# each hash-update / radix-partition dispatch took, plus envelope
-# declines and fault-injected fallbacks.  Surfaced in the
-# explain_analyze footer.
-_scatter_lane = {"scatter_lane_hash_pallas": 0,
-                 "scatter_lane_hash_interpret": 0,
-                 "scatter_lane_hash_scatter": 0,
-                 "scatter_lane_partition_pallas": 0,
-                 "scatter_lane_partition_interpret": 0,
-                 "scatter_lane_partition_scatter": 0,
-                 "scatter_lane_declines": 0,
-                 "scatter_lane_fault_fallbacks": 0}
-
 # Streaming-runtime accounting (streaming/executor.py StreamExecutor):
 # committed epochs and their wall time, rows/records through the
 # pipeline, late-record routing, checkpoint commits, recovery rounds
@@ -905,33 +892,6 @@ def sortmerge_stats() -> dict:
         return dict(_sortmerge)
 
 
-def note_scatter_lane(kind: str, lane: str) -> None:
-    """One kernel-lane resolution: kind in hash/partition, lane in
-    pallas/interpret/scatter (kernels/lane.py resolve)."""
-    key = f"scatter_lane_{kind}_{lane}"
-    with _lock:
-        if key in _scatter_lane:
-            _scatter_lane[key] += 1
-
-
-def note_scatter_lane_decline() -> None:
-    """A kernel-lane dispatch fell outside the kernel envelope (VMEM
-    footprint) and degraded to the scatter formulation."""
-    with _lock:
-        _scatter_lane["scatter_lane_declines"] += 1
-
-
-def note_scatter_lane_fault() -> None:
-    """An injected pallas-kernel fault forced the scatter fallback."""
-    with _lock:
-        _scatter_lane["scatter_lane_fault_fallbacks"] += 1
-
-
-def scatter_lane_stats() -> dict:
-    with _lock:
-        return dict(_scatter_lane)
-
-
 def note_stream_epoch(wall_ns: int, rows: int = 0,
                       records: int = 0) -> None:
     """One committed micro-batch epoch: wall time, sink rows emitted,
@@ -1065,7 +1025,6 @@ def counter_families() -> Dict[str, Dict[str, int]]:
             "shuffle": dict(_shuffle),
             "stage_loop": dict(_stage_loop),
             "agg": dict(_agg),
-            "scatter_lane": dict(_scatter_lane),
             "stream": dict(_stream),
             "workers": dict(_workers),
             "speculation": dict(_speculation),
@@ -1093,7 +1052,6 @@ def snapshot() -> dict:
     flat.update(agg_stats())
     flat.update(shuffle_stats())
     flat.update(stage_loop_stats())
-    flat.update(scatter_lane_stats())
     flat.update(sortmerge_stats())
     flat.update(stream_stats())
     flat.update(worker_stats())
@@ -1132,8 +1090,6 @@ def reset() -> None:
             _shuffle[k] = 0
         for k in _stage_loop:
             _stage_loop[k] = 0
-        for k in _scatter_lane:
-            _scatter_lane[k] = 0
         for k in _sortmerge:
             _sortmerge[k] = 0
         for k in _stream:

@@ -323,24 +323,6 @@ FUSED_STAGE_CAPACITY = int_conf(
     "auron.tpu.fused.stage.capacity", 1 << 24,
     "Max dense group-table slots (product of key ranges) for the fused "
     "dense-group-id path before falling back to the sorted table.")
-KERNELS_PALLAS = str_conf(
-    "auron.tpu.kernels.pallas", "auto",
-    "Lane strategy for the scatter-shaped Pallas kernels (open-"
-    "addressing hash-table update, radix partitioning): 'auto' takes a "
-    "Mosaic kernel only where the compiler accepts it (today neither "
-    "kernel lowers on TPU — kernels/lane.py MOSAIC_REFUSED — so 'auto' "
-    "runs the verified scatter formulation everywhere); 'on' forces the "
-    "kernel layer (interpret mode off-TPU — bit-identical, used by CI "
-    "and parity benches; raises on a TPU that refuses the kernel); "
-    "'off' pins the scatter formulation.  Every resolution is counted "
-    "in xla_stats (scatter_lane_*) and shown in the explain_analyze "
-    "footer.", category="kernels")
-KERNELS_PALLAS_VMEM_BUDGET = int_conf(
-    "auron.tpu.kernels.pallas.vmemBudget", 12 << 20,
-    "VMEM bytes the hash-update kernel may keep grid-resident (table "
-    "limbs + probe state).  Dispatches whose estimated footprint "
-    "exceeds it decline to the scatter formulation "
-    "(scatter_lane_declines counts them).", category="kernels")
 AGG_MXU_ENABLE = bool_conf(
     "auron.tpu.mxuAgg.enable", True,
     "Aggregate compact dense group tables as MXU one-hot matmuls "
@@ -529,8 +511,8 @@ FAULTS_RULES = str_conf(
     "optional `:corrupt` action suffix (flip a frame byte instead of "
     "raising).  Sites: task-start, shuffle-write, shuffle-read, "
     "ipc-decode, mem-pressure, device-collective, device-loop, admit, "
-    "cancel-race, quota-breach, pallas-kernel, stream-epoch, "
-    "checkpoint-commit, worker-crash, worker-hang, worker-slow, "
+    "cancel-race, quota-breach, stream-epoch, checkpoint-commit, "
+    "worker-crash, worker-hang, worker-slow, "
     "speculation-loser-commit-race.  Site names are validated at parse "
     "time (faults.register_site declares dynamic sites).",
     category="fault-tolerance")

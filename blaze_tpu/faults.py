@@ -29,9 +29,6 @@ Sites (the code points that call in here):
                    cancel-vs-completion race window
     quota-breach   memory/manager.py, per quota evaluation (forces a
                    per-query quota breach → degradation rung)
-    pallas-kernel  kernels/lane.py, per lane-kernel invocation (forces
-                   the interpret/scatter fallback path; the engine must
-                   degrade, not diverge)
     stream-epoch   streaming/executor.py, at each micro-batch epoch
                    boundary (kills the epoch mid-flight; the stream
                    replays from the last committed checkpoint)
@@ -96,8 +93,8 @@ from typing import Any, Dict, Iterable, Optional, Tuple
 
 SITES = ("task-start", "shuffle-write", "shuffle-read", "ipc-decode",
          "mem-pressure", "device-collective", "device-loop", "admit",
-         "cancel-race", "quota-breach", "pallas-kernel", "stream-epoch",
-         "checkpoint-commit", "worker-crash", "worker-hang", "worker-slow",
+         "cancel-race", "quota-breach", "stream-epoch", "checkpoint-commit",
+         "worker-crash", "worker-hang", "worker-slow",
          "speculation-loser-commit-race", "replica-crash", "replica-hang",
          "socket-torn-frame")
 

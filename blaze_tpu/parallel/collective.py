@@ -39,8 +39,7 @@ def partition_ids_for_keys(keys: Sequence[Tuple[jax.Array, jax.Array]],
     return H.spark_partition_ids(flat_cols, tids, num_partitions, xp=jnp)
 
 
-def _dest_slots(pid: jax.Array, num_partitions: int, capacity: int,
-                lane: str = "scatter"):
+def _dest_slots(pid: jax.Array, num_partitions: int, capacity: int):
     """Dense within-destination slot assignment for per-destination
     buffers of `capacity` rows.
 
@@ -48,20 +47,8 @@ def _dest_slots(pid: jax.Array, num_partitions: int, capacity: int,
     `dest` = (partition, slot) per sorted row, routed OUT of bounds for
     rows with pid >= num_partitions or past capacity, so scatters with
     mode="drop" discard them instead of clobbering a live slot;
-    `overflow` counts in-range rows dropped by the capacity limit.
-
-    lane 'pallas'/'interpret' takes the radix partition kernel
-    (kernels/radix.py) instead of the stable argsort: there `order` is
-    None and `dest` is per ORIGINAL row (callers skip the take) — the
-    scattered buffers and the overflow count are bit-identical."""
+    `overflow` counts in-range rows dropped by the capacity limit."""
     R = pid.shape[0]
-    if lane in ("pallas", "interpret"):
-        from blaze_tpu.kernels import lane as lane_mod
-        from blaze_tpu.kernels import radix
-        if radix.vmem_estimate(R, num_partitions) <= lane_mod.vmem_budget():
-            return radix.dest_slots(pid, num_partitions, capacity,
-                                    interpret=(lane == "interpret"))
-        lane_mod.decline("partition", "vmem")
     order = jnp.argsort(pid, stable=True)
     sorted_pid = jnp.take(pid, order)
     counts = jnp.bincount(jnp.clip(pid, 0, num_partitions),
@@ -79,8 +66,7 @@ def _dest_slots(pid: jax.Array, num_partitions: int, capacity: int,
 
 
 def all_to_all_regroup(table: AggTable, axis_name: str,
-                       num_partitions: int, out_slots: int,
-                       lane: str = "scatter") -> AggTable:
+                       num_partitions: int, out_slots: int) -> AggTable:
     """Exchange group-table slots so equal keys land on one device, then
     merge — the on-ICI shuffle+final-agg.  Callable only inside shard_map
     over `axis_name`."""
@@ -90,15 +76,15 @@ def all_to_all_regroup(table: AggTable, axis_name: str,
     pid = jnp.where(table.slot_valid, pid, num_partitions)  # park empties
 
     # per-destination capacity G: a device's slots can never overflow it
-    order, dest, _overflow = _dest_slots(pid, num_partitions, G, lane)
+    order, dest, _overflow = _dest_slots(pid, num_partitions, G)
 
     def scatter(col):
-        sc = jnp.take(col, order) if order is not None else col
+        sc = jnp.take(col, order)
         buf = jnp.zeros((num_partitions, G), dtype=col.dtype)
         return buf.at[dest].set(sc, mode="drop")
 
     def scatter_valid(col):
-        sc = jnp.take(col, order) if order is not None else col
+        sc = jnp.take(col, order)
         buf = jnp.zeros((num_partitions, G), dtype=bool)
         return buf.at[dest].set(sc, mode="drop")
 
@@ -127,7 +113,7 @@ def all_to_all_regroup(table: AggTable, axis_name: str,
 
 def all_to_all_rows(columns: Sequence[jax.Array], valid: jax.Array,
                     pid: jax.Array, axis_name: str, num_partitions: int,
-                    capacity: int, lane: str = "scatter"):
+                    capacity: int):
     """Operator-agnostic raw-row exchange over ICI.
 
     The reference's repartitioner moves arbitrary operator output rows
@@ -148,8 +134,7 @@ def all_to_all_rows(columns: Sequence[jax.Array], valid: jax.Array,
       with a bigger bucket when nonzero — the same bounded-overflow
       discipline as the fused agg table)."""
     pid = jnp.where(valid, pid, num_partitions)  # park unsent rows
-    order, dest, overflow = _dest_slots(pid, num_partitions, capacity,
-                                        lane)
+    order, dest, overflow = _dest_slots(pid, num_partitions, capacity)
 
     def exchange(buf):
         return jax.lax.all_to_all(buf, axis_name, split_axis=0,
@@ -157,7 +142,7 @@ def all_to_all_rows(columns: Sequence[jax.Array], valid: jax.Array,
 
     out_cols = []
     for col in columns:
-        sc = jnp.take(col, order) if order is not None else col
+        sc = jnp.take(col, order)
         buf = jnp.zeros((num_partitions, capacity), dtype=col.dtype)
         buf = buf.at[dest].set(sc, mode="drop")
         out_cols.append(exchange(buf).reshape(num_partitions * capacity))

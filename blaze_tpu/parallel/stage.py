@@ -292,23 +292,12 @@ def hash_agg_step(carry: HashAggCarry,
                   key_cols: Sequence[Tuple[jax.Array, jax.Array]],
                   agg_specs: Sequence[Tuple[str, Optional[jax.Array],
                                             Optional[jax.Array]]],
-                  mask: jax.Array, probe_rounds: int = 16,
-                  lane: Optional[str] = None):
+                  mask: jax.Array, probe_rounds: int = 16):
     """Insert one batch into the table.  Returns (new_carry, overflow,
     num_groups); ATOMIC: when any row fails to place within probe_rounds,
     the ORIGINAL carry is returned unchanged (overflow > 0) so the host
-    can grow/degrade and retry the whole batch losslessly.
-
-    `lane` picks the probe/claim formulation: 'scatter' (whole-batch
-    rounds, the reference), 'pallas'/'interpret' (the VMEM-resident
-    placement kernel, kernels/hash_update.py — bit-identical carry by
-    construction).  None resolves via kernels/lane.py at trace time;
-    jit'd callers resolve it themselves and key their caches with it so
-    a knob flip retraces instead of reusing a stale program."""
+    can grow/degrade and retry the whole batch losslessly."""
     from blaze_tpu.kernels import hashing as H
-    if lane is None:
-        from blaze_tpu.kernels import lane as lane_mod
-        lane = lane_mod.resolve("hash")
     S = carry.used.shape[0]
     n = mask.shape[0]
     row_idx = jnp.arange(n, dtype=jnp.int64)
@@ -323,30 +312,6 @@ def hash_agg_step(carry: HashAggCarry,
     tkeys0 = tuple(carry.keys)
     tkvalid0 = tuple(carry.key_valid)
     placed0 = jnp.full(n, S, dtype=jnp.int64)  # S == unplaced sentinel
-
-    kern = None
-    if lane in ("pallas", "interpret"):
-        from blaze_tpu.kernels import hash_update as HU
-        from blaze_tpu.kernels import lane as lane_mod
-        kern = HU.place_rows(h, key_cols, mask, carry, probe_rounds,
-                             interpret=(lane == "interpret"))
-        if kern is None:  # outside the VMEM envelope -> scatter
-            lane_mod.decline("hash", "vmem")
-
-    if kern is not None:
-        # placement-only kernel: replay the EXACT legacy tail (key
-        # scatters via the claimed slots, used-flag update) so the carry
-        # is bit-identical to the scatter formulation's
-        placed, wslot = kern
-        tkeys = [tk.at[wslot].set(kd, mode="drop")
-                 for tk, (kd, _kv) in zip(tkeys0, key_cols)]
-        tkvalid = [tv.at[wslot].set(kv, mode="drop")
-                   for tv, (_kd, kv) in zip(tkvalid0, key_cols)]
-        used = used0.at[wslot].set(True, mode="drop")
-        unplaced = mask & (placed == S)
-        overflow = jnp.sum(unplaced.astype(jnp.int32))
-        return _hash_step_tail(carry, key_cols, agg_specs, mask, placed,
-                               tkeys, tkvalid, used, overflow)
 
     def round_body(state):
         r, used, tkeys, tkvalid, placed, unplaced = state
@@ -389,25 +354,13 @@ def hash_agg_step(carry: HashAggCarry,
     _r, used, tkeys, tkvalid, placed, unplaced = jax.lax.while_loop(
         round_cond, round_body,
         (jnp.int32(0), used0, tkeys0, tkvalid0, placed0, mask))
-    tkeys = list(tkeys)
-    tkvalid = list(tkvalid)
     overflow = jnp.sum(unplaced.astype(jnp.int32))
-    return _hash_step_tail(carry, key_cols, agg_specs, mask, placed,
-                           tkeys, tkvalid, used, overflow)
 
-
-def _hash_step_tail(carry, key_cols, agg_specs, mask, placed, tkeys,
-                    tkvalid, used, overflow):
-    """Shared accumulate + atomic-select tail of hash_agg_step: ONE CODE
-    PATH for every lane, so accumulator math, null semantics and the
-    overflow contract cannot diverge between the scatter formulation and
-    the Pallas placement kernel."""
-    g = placed  # S sentinel drops out of every scatter below
+    # the S sentinel of an unplaced row drops out of every scatter below
     new_accs, new_avalid = scatter_accumulate(
-        g, [(k, d, v) for k, d, v in agg_specs], mask,
-        carry.accs, carry.acc_valid)
+        placed, agg_specs, mask, carry.accs, carry.acc_valid)
 
-    new_carry = HashAggCarry(tuple(tkeys), tuple(tkvalid),
+    new_carry = HashAggCarry(tkeys, tkvalid,
                              tuple(new_accs), tuple(new_avalid), used)
     keep_new = overflow == 0
     sel = jax.tree_util.tree_map(
@@ -480,8 +433,7 @@ def init_accumulators(kinds: Sequence[str], acc_dtypes: Sequence,
 
 
 def rehash_carry(old: HashAggCarry, kinds: Sequence[str],
-                 new_slots: int, probe_rounds: int = 16,
-                 lane: Optional[str] = None):
+                 new_slots: int, probe_rounds: int = 16):
     """Re-insert an existing table into a larger one (the grow path).
     `kinds` are the ORIGINAL accumulator kinds; stored accumulators
     re-merge with merge semantics (count -> sum of counts)."""
@@ -491,7 +443,7 @@ def rehash_carry(old: HashAggCarry, kinds: Sequence[str],
     specs = [("sum" if k == "count" else k, a, av)
              for k, a, av in zip(kinds, old.accs, old.acc_valid)]
     return hash_agg_step(fresh, list(zip(old.keys, old.key_valid)), specs,
-                         old.used, probe_rounds, lane=lane)
+                         old.used, probe_rounds)
 
 
 def merge_agg_tables(table: AggTable,
@@ -536,14 +488,12 @@ class DeviceExchangeError(RuntimeError):
 
 @functools.lru_cache(maxsize=64)
 def _exchange_program(mesh, n_out: int, capacity: int,
-                      key_idx: Tuple[int, ...], dtypes: Tuple[str, ...],
-                      lane: str = "scatter"):
+                      key_idx: Tuple[int, ...], dtypes: Tuple[str, ...]):
     """Build + cache the jit'd shard_map exchange for one static shape.
 
     Cache key = (mesh, reduce partition count, bucket-ladder rung, key
-    column positions, column dtype signature, partition lane): the
-    collective compiles once per rung and is reused by every batch that
-    lands on it; the lane rides the key so a knob flip retraces.
+    column positions, column dtype signature): the collective compiles
+    once per rung and is reused by every batch that lands on it.
     """
     from jax.sharding import PartitionSpec as PS
 
@@ -566,7 +516,7 @@ def _exchange_program(mesh, n_out: int, capacity: int,
         dev = pid % n_dev
         out_cols, out_valid, overflow = all_to_all_rows(
             list(datas) + list(valids) + [pid],
-            row_valid, dev, DP_AXIS, n_dev, capacity, lane=lane)
+            row_valid, dev, DP_AXIS, n_dev, capacity)
         return tuple(out_cols) + (out_valid, overflow.reshape(1))
 
     sharded = jax.shard_map(stage, mesh=mesh, in_specs=PS(DP_AXIS),
@@ -599,7 +549,7 @@ class ExchangeTicket:
     routing are free to run while the host folds the next chunk."""
 
     __slots__ = ("out", "rungs", "row_valid", "datas", "vbufs",
-                 "key_idx", "dtypes", "lane", "n", "ncols", "n_out",
+                 "key_idx", "dtypes", "n", "ncols", "n_out",
                  "n_dev", "rows_per_dev", "ctx", "moved_bytes",
                  "collectives", "dispatch_ns", "parts")
 
@@ -700,8 +650,6 @@ class DeviceExchange:
 
         key_idx = tuple(int(i) for i in key_indices)
         dtypes = tuple(np.dtype(c.dtype).name for c in columns)
-        from blaze_tpu.kernels import lane as lane_mod
-        lane = lane_mod.resolve("partition")
 
         cap = rungs[0]
         # the scripted mid-collective kill: one decision per shard
@@ -709,13 +657,13 @@ class DeviceExchange:
         for d in range(n_dev):
             faults.maybe_fail("device-collective", shard=d, stage=ctx)
         fn = _exchange_program(self.mesh, int(n_out), int(cap),
-                               key_idx, dtypes, lane)
+                               key_idx, dtypes)
         out = fn(*shard_rows(self.mesh, row_valid, *datas, *vbufs))
         moved_bytes, collectives = exchange_wire_cost(n_dev, cap, dtypes)
         return ExchangeTicket(
             out=out, rungs=list(rungs[1:]), row_valid=row_valid,
             datas=datas, vbufs=vbufs, key_idx=key_idx, dtypes=dtypes,
-            lane=lane, n=n, ncols=ncols, n_out=int(n_out), n_dev=n_dev,
+            n=n, ncols=ncols, n_out=int(n_out), n_dev=n_dev,
             rows_per_dev=rows_per_dev, ctx=ctx, moved_bytes=moved_bytes,
             collectives=collectives,
             dispatch_ns=_time.perf_counter_ns())
@@ -748,8 +696,7 @@ class DeviceExchange:
                 faults.maybe_fail("device-collective", shard=d,
                                   stage=ticket.ctx)
             fn = _exchange_program(self.mesh, n_out, int(cap),
-                                   ticket.key_idx, ticket.dtypes,
-                                   ticket.lane)
+                                   ticket.key_idx, ticket.dtypes)
             out = fn(*shard_rows(self.mesh, ticket.row_valid,
                                  *ticket.datas, *ticket.vbufs))
             mb, cc = exchange_wire_cost(ticket.n_dev, cap, ticket.dtypes)

@@ -366,25 +366,6 @@ class QueryProfile:
                 f"recoveries={x.get('stream_recoveries', 0)} "
                 f"sink_commits={x.get('stream_sink_commits', 0)} "
                 f"dup_skips={x.get('stream_sink_dup_skips', 0)}")
-        lane_keys = ("scatter_lane_hash_pallas",
-                     "scatter_lane_hash_interpret",
-                     "scatter_lane_hash_scatter",
-                     "scatter_lane_partition_pallas",
-                     "scatter_lane_partition_interpret",
-                     "scatter_lane_partition_scatter")
-        if any(x.get(k) for k in lane_keys):
-            lines.append(
-                "scatter lanes: hash="
-                f"{x.get('scatter_lane_hash_pallas', 0)}p/"
-                f"{x.get('scatter_lane_hash_interpret', 0)}i/"
-                f"{x.get('scatter_lane_hash_scatter', 0)}s "
-                "partition="
-                f"{x.get('scatter_lane_partition_pallas', 0)}p/"
-                f"{x.get('scatter_lane_partition_interpret', 0)}i/"
-                f"{x.get('scatter_lane_partition_scatter', 0)}s "
-                f"declines={x.get('scatter_lane_declines', 0)} "
-                f"fault_fallbacks="
-                f"{x.get('scatter_lane_fault_fallbacks', 0)}")
         bn_line = format_bottleneck_footer(self.bottleneck)
         if bn_line is not None:
             lines.append(bn_line)

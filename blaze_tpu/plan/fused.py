@@ -1815,14 +1815,12 @@ class FusedPartialAggExec(ExecutionPlan):
             # (a second program would pay another dispatch and
             # materialize kd/kv/ad/av between programs)
             stream = self._source.execute(partition)
-            lane = _hash_lane()
             raw_step = _hash_chain_step_factory(self._prepare_key,
-                                                self._prepare, kinds, lane)
+                                                self._prepare, kinds)
             step = lambda c, b: raw_step(c, *_source_inputs(b))  # noqa: E731
         else:
             stream = self.children[0].execute(partition)
-            lane = _hash_lane()
-            raw_step = _hash_step_jit(kinds, lane)
+            raw_step = _hash_step_jit(kinds)
             step = lambda c, b: raw_step(  # noqa: E731
                 c, *self._device_inputs(b))
         key_dtypes = [e.data_type(self._in_schema).jnp_dtype()
@@ -1852,7 +1850,7 @@ class FusedPartialAggExec(ExecutionPlan):
                 # the step is atomic, so carry is intact and lossless
                 slots *= 2
                 self.metrics.add("table_grown", 1)
-                bigger, re_ovf, _ = _rehash_jit(kinds, slots, lane)(carry)
+                bigger, re_ovf, _ = _rehash_jit(kinds, slots)(carry)
                 if int(to_host(re_ovf)) > 0:
                     continue  # rare probe clustering: double again
                 carry = bigger
@@ -2454,36 +2452,25 @@ def _pow2(n: int) -> int:
     return max(16, 1 << (int(n) - 1).bit_length())
 
 
-def _hash_lane() -> str:
-    """Resolve the probe/claim lane ONCE per dispatch site (host-side,
-    kernels/lane.py) — it then rides every cache key below so flipping
-    `auron.tpu.kernels.pallas` retraces instead of reusing a stale
-    program."""
-    from blaze_tpu.kernels import lane as lane_mod
-    return lane_mod.resolve("hash")
-
-
 @functools.lru_cache(maxsize=128)
-def _hash_step_jit(kinds, lane: str = "scatter"):
+def _hash_step_jit(kinds):
     """One compiled program per batch: probe-insert + scatter-accumulate
     into the device hash table (kernels in parallel/stage.py)."""
     def f(carry, kd, kv, ad, av, mask):
         specs = [(k, d, v) for k, d, v in zip(kinds, ad, av)]
-        return hash_agg_step(carry, list(zip(kd, kv)), specs, mask,
-                             lane=lane)
+        return hash_agg_step(carry, list(zip(kd, kv)), specs, mask)
     return meter_jit(f, name="fused.hash_step")
 
 
 @functools.lru_cache(maxsize=128)
-def _rehash_jit(kinds, new_slots: int, lane: str = "scatter"):
-    return meter_jit(lambda c: rehash_carry(c, list(kinds), new_slots,
-                                            lane=lane),
+def _rehash_jit(kinds, new_slots: int):
+    return meter_jit(lambda c: rehash_carry(c, list(kinds), new_slots),
                      name="fused.rehash")
 
 
-def _hash_chain_step_factory(key, prepare, kinds, lane: str = "scatter"):
+def _hash_chain_step_factory(key, prepare, kinds):
     """Chain + probe-insert + accumulate as ONE compiled program."""
-    skey = ("hash", key, kinds, lane)
+    skey = ("hash", key, kinds)
     step = _DENSE_STEP_CACHE.get(skey)
     if step is not None:
         return step
@@ -2493,8 +2480,7 @@ def _hash_chain_step_factory(key, prepare, kinds, lane: str = "scatter"):
     def step(carry, cols_flat, mask):
         kd, kv, ad, av, m = prepare(cols_flat, mask)
         specs = [(k, d, v) for k, d, v in zip(kinds, ad, av)]
-        return hash_agg_step(carry, list(zip(kd, kv)), specs, m,
-                             lane=lane)
+        return hash_agg_step(carry, list(zip(kd, kv)), specs, m)
 
     _DENSE_STEP_CACHE[skey] = step
     return step

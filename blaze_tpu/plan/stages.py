@@ -1612,7 +1612,6 @@ class DagScheduler:
             # same caveat as the history attribution)
             reasons = {}
             for key, reason in (("stage_loop_fallbacks", "stage_loop"),
-                                ("scatter_lane_declines", "scatter_lane"),
                                 ("expr_eager_batches", "expr_eager"),
                                 # per-column causes (ISSUE 20): WHY the
                                 # stage left the device lane, not just

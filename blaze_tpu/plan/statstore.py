@@ -55,7 +55,7 @@ INGEST_COUNTERS = (
     "expr_programs_built", "expr_program_cache_hits",
     "expr_fused_batches", "expr_eager_batches",
     "stage_loop_programs_built", "stage_loop_program_cache_hits",
-    "stage_loop_fallbacks", "scatter_lane_declines",
+    "stage_loop_fallbacks",
     "shuffle_device_bytes", "shuffle_host_bytes",
     "shuffle_barrier_idle_ns", "shuffle_device_overlap_exchanges",
     "aqe_rewrites", "aqe_bytes_saved", "aqe_history_seeds",

@@ -156,7 +156,7 @@ def _batch_of(cols_stacked, b):
                  for col in cols_stacked)
 
 
-def _fold_factory(program, donate: bool, lane: str = "scatter"):
+def _fold_factory(program, donate: bool):
     prepare = program.prepare
     kinds = program.kinds
 
@@ -170,7 +170,7 @@ def _fold_factory(program, donate: bool, lane: str = "scatter"):
             live = jnp.logical_and(m, jnp.logical_not(ovf_seen))
             specs = [(k, d, v) for k, d, v in zip(kinds, ad, av)]
             new_c, ovf, _ng = hash_agg_step(c, list(zip(kd, kv)), specs,
-                                            live, lane=lane)
+                                            live)
             hit = ovf > 0
             first_ovf = jnp.where(hit & ~ovf_seen, b, first_ovf)
             # the rows of an overflowing batch are not in the table
@@ -199,7 +199,7 @@ def _fold_factory(program, donate: bool, lane: str = "scatter"):
 
     kwargs = {"donate_argnums": (0,)} if donate else {}
     return _cached(
-        (program.fingerprint, bool(donate), lane),
+        (program.fingerprint, bool(donate)),
         lambda: meter_jit(fold_impl, name="runtime.stage_loop", **kwargs))
 
 
@@ -330,9 +330,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
     if q is not None and q.force_agg_passthrough:
         raise StageLoopFallback("query degraded to agg pass-through")
     chunk = loop_chunk_batches()
-    from blaze_tpu.kernels import lane as lane_mod
-    lane = lane_mod.resolve("hash")
-    fold = _fold_factory(program, _donate_active(), lane)
+    fold = _fold_factory(program, _donate_active())
     floor = _pow2(config.ON_DEVICE_AGG_CAPACITY.get())
     min_rows = min(_NO_LOOK,
                    max(1, config.PARTIAL_AGG_SKIPPING_MIN_ROWS.get()))
@@ -358,8 +356,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                 return fresh(want), want
             _run_fences()  # drain in-flight overlapped exchanges
             rehash_lanes += slots
-            bigger, re_ovf, _ = _rehash_jit(program.kinds, want,
-                                            lane)(carry)
+            bigger, re_ovf, _ = _rehash_jit(program.kinds, want)(carry)
             if int(to_host(re_ovf)) == 0:
                 return bigger, want
             want *= 2  # rare probe clustering: double again

@@ -307,20 +307,7 @@ class ShuffleRepartitioner(MemConsumer):
         tbl = pa.Table.from_batches(self._staged).combine_chunks()
         rb = tbl.to_batches()[0]
         pids = np.asarray(rb.column(0))
-        from blaze_tpu.kernels import lane as lane_mod
-        from blaze_tpu.kernels import radix
-        lane = lane_mod.resolve("partition")
-        if lane in ("pallas", "interpret") and \
-                radix.vmem_estimate(len(pids), n_parts) \
-                > lane_mod.vmem_budget():
-            lane_mod.decline("partition", "vmem")
-            lane = "scatter"
-        if lane in ("pallas", "interpret"):
-            # radix kernel lane: rank walk in row order — bit-identical
-            # to the stable argsort grouping below
-            order, starts, ends = radix.partition_order(
-                pids, n_parts, interpret=(lane == "interpret"))
-        elif n_parts <= 32:
+        if n_parts <= 32:
             # counting sort: one flatnonzero sweep per partition beats a
             # generic argsort ~5x at small reducer counts (pids are a
             # handful of distinct values, the classic radix-1 case);

@@ -8,10 +8,8 @@ defaults are the thing under test.  Data is made from --seed.  Phases:
   kernels  the aggregation/partition kernels alone, compiled (not
            interpreted) at production shapes and compared with their
            references: mxu_agg.window_table bit for bit against the
-           scatter table; the hash and partition lanes `kernels/lane.py`
-           resolves under `auto`, against a numpy group-by / stable
-           argsort.  Each kernel Mosaic refused (lane.MOSAIC_REFUSED) is
-           lowered once more and must still give the recorded message.
+           scatter table; hash_agg_step against a numpy group-by; the
+           exchange's partition order against a stable argsort.
   pair     the q01 stage pair bench.py builds (scan -> filter -> partial
            hash-agg -> shuffle_writer -> .data/.index -> ipc_reader ->
            final agg) as TaskDefinition bytes through
@@ -103,10 +101,6 @@ def lane_evidence(trees) -> dict:
 
 EVIDENCE_KEYS = (
     "total_compiles", "h2d_bytes", "d2h_bytes",
-    "scatter_lane_hash_pallas", "scatter_lane_hash_interpret",
-    "scatter_lane_hash_scatter", "scatter_lane_partition_pallas",
-    "scatter_lane_partition_interpret", "scatter_lane_partition_scatter",
-    "scatter_lane_declines", "scatter_lane_fault_fallbacks",
     "stage_loop_tasks", "stage_loop_batches", "stage_loop_fallbacks",
     "stage_loop_regrows", "stage_loop_reserves", "stage_loop_rehash_lanes",
     "shuffle_device_exchanges",
@@ -149,10 +143,7 @@ def check_leg(name: str, ev: dict, lanes: dict, reasons: dict) -> None:
     check(ev["unexpected_fallbacks"] == 0,
           f"{name}: a device tier fell back on an undeclared error: "
           f"{xla_stats.fallback_errors()}")
-    for k in ("scatter_lane_hash_interpret",
-              "scatter_lane_partition_interpret",
-              "scatter_lane_fault_fallbacks", "shuffle_device_fallbacks",
-              "mxu_verify_fallback"):
+    for k in ("shuffle_device_fallbacks", "mxu_verify_fallback"):
         check(ev[k] == 0, f"{name}: {k} = {ev[k]}")
     # the loop sizes its table for the rows about to reach it, in every
     # mode: at these sizes no task has a reason to leave it
@@ -204,7 +195,7 @@ def phase_kernels(seed: int) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from blaze_tpu.kernels import hash_update, lane, mxu_agg, radix
+    from blaze_tpu.kernels import mxu_agg
     from blaze_tpu.parallel.collective import _dest_slots
     from blaze_tpu.parallel.stage import hash_agg_step, init_hash_carry
     rng = np.random.default_rng(seed)
@@ -230,14 +221,7 @@ def phase_kernels(seed: int) -> None:
             f"{took:.2f}s bit-identical {same}")
         check(same, f"mxu_agg differs from its reference at {n}x{slots}")
 
-    hash_lane = lane.resolve("hash")
-    part_lane = lane.resolve("partition")
-    say(f"-- lanes under auto: hash={hash_lane} partition={part_lane}; "
-        f"refused by Mosaic: {json.dumps(lane.MOSAIC_REFUSED)}")
-    check(hash_lane != "interpret" and part_lane != "interpret",
-          "auto resolved an interpret lane on the chip")
-
-    say(f"-- hash_agg_step(lane={hash_lane!r}) vs numpy group-by")
+    say("-- hash_agg_step vs numpy group-by")
     # distinct keys <= S/6: the probe walk is bounded at 16 rounds and a
     # fuller table overflows by design (the engine then grows it)
     for n, S, kdt in ((32768, 1 << 16, (np.int64,)),
@@ -252,8 +236,8 @@ def phase_kernels(seed: int) -> None:
         keys = [(jnp.asarray(k), jnp.ones(n, bool)) for k in kd]
         carry = init_hash_carry([jnp.dtype(dt) for dt in kdt], ["sum"],
                                 (jnp.float64,), S)
-        step = jax.jit(lambda c, k, v, m, _ln=hash_lane: hash_agg_step(
-            c, k, [("sum", v, None)], m, lane=_ln))
+        step = jax.jit(lambda c, k, v, m: hash_agg_step(
+            c, k, [("sum", v, None)], m))
         t0 = time.perf_counter()
         out, overflow, groups = jax.block_until_ready(
             step(carry, keys, jnp.asarray(vals), jnp.asarray(mask)))
@@ -272,9 +256,9 @@ def phase_kernels(seed: int) -> None:
             f"groups {int(groups)} overflow {int(overflow)} "
             f"equal {got == want}")
         check(int(overflow) == 0 and got == want,
-              f"hash lane differs from numpy at {n}x{S}x{kinds}")
+              f"hash_agg_step differs from numpy at {n}x{S}x{kinds}")
 
-    say(f"-- partition lane {part_lane!r} vs numpy stable argsort")
+    say("-- _dest_slots vs numpy stable argsort")
     # its compile time follows the row count, not the partition count
     # (a stable argsort: 17-20 s per shape on a v5e), so two shapes cover
     # both batch sizes and both fan-outs
@@ -282,7 +266,7 @@ def phase_kernels(seed: int) -> None:
         pid = rng.integers(0, parts + 1, n).astype(np.int32)
         t0 = time.perf_counter()
         order, dest, overflow = jax.block_until_ready(jax.jit(
-            lambda p, _P=parts, _n=n: _dest_slots(p, _P, _n, part_lane))(
+            lambda p, _P=parts, _n=n: _dest_slots(p, _P, _n))(
                 jnp.asarray(pid)))
         took = time.perf_counter() - t0
         ref = np.argsort(pid, kind="stable")
@@ -290,7 +274,7 @@ def phase_kernels(seed: int) -> None:
             and int(overflow) == 0
         say(f"  rows {n} partitions {parts}: compile+run {took:.2f}s "
             f"order==stable-argsort {same}")
-        check(same, f"partition lane differs at {n}x{parts}")
+        check(same, f"_dest_slots differs at {n}x{parts}")
 
     say("-- the compiler's answer for what it refused, today")
     # f64 on this chip is a float32 pair, not IEEE double: its bits
@@ -307,26 +291,6 @@ def phase_kernels(seed: int) -> None:
     check(said is not None and "X64 element types" in said,
           f"the f64 bitcast record is stale: the compiler now says "
           f"{said!r}")
-    attempts = {
-        "hash": lambda: jax.jit(lambda h, l, p, u, t: hash_update.placement(
-            h, l, p, jnp.int32(8), u, t, 4)).lower(
-                jnp.zeros(1024, jnp.int32), jnp.zeros((3, 1024), jnp.int32),
-                jnp.zeros(1024, jnp.int32), jnp.zeros(2048, jnp.int32),
-                jnp.zeros((3, 2048), jnp.int32)),
-        "partition": lambda: jax.jit(lambda p: radix.partition_ranks(
-            p, 4, 1024)).lower(jnp.zeros(1024, jnp.int32)),
-    }
-    for kind, recorded in lane.MOSAIC_REFUSED.items():
-        try:
-            attempts[kind]()
-        except ValueError as e:  # the expected outcome, asserted below
-            said = str(e)
-        else:
-            said = None
-        say(f"  {kind}: {said!r}")
-        check(said is not None and recorded in said,
-              f"lane.MOSAIC_REFUSED[{kind!r}] is stale: the compiler now "
-              f"says {said!r}")
 
 
 def phase_pair(seed: int, work: str) -> None:

@@ -81,14 +81,14 @@ def _lower_hash_valid():
 
 def _lower_rehash():
     from blaze_tpu.plan.fused import _rehash_jit
-    return _module_name(_rehash_jit(("sum",), 128, "scatter"),
+    return _module_name(_rehash_jit(("sum",), 128),
                         _hash_carry())
 
 
 def _lower_hash_step():
     from blaze_tpu.plan.fused import _hash_step_jit
     return _module_name(
-        _hash_step_jit(("sum",), "scatter"), _hash_carry(),
+        _hash_step_jit(("sum",)), _hash_carry(),
         (jnp.zeros(8, jnp.int64),), (jnp.ones(8, bool),),
         (jnp.zeros(8, jnp.float64),), (jnp.ones(8, bool),),
         jnp.ones(8, bool))

@@ -1,7 +1,6 @@
 """Chip bring-up contracts that a CPU host can hold the code to (ISSUE 21):
 where the compile cache lives, that a forced device placement without a
-device raises, that chip_smoke.py refuses to run without a chip, and that
-the lane resolver never hands out a kernel the compiler refused."""
+device raises, and that chip_smoke.py refuses to run without a chip."""
 
 import os
 import subprocess
@@ -10,11 +9,9 @@ import sys
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from blaze_tpu import config
 from blaze_tpu.bridge import placement as P
-from blaze_tpu.kernels import hash_update, lane, radix
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PRINT_CACHE = ("import jax, blaze_tpu; "
@@ -119,51 +116,3 @@ def test_chip_smoke_exits_nonzero_without_a_chip(tmp_path):
     # named the missing chip BEFORE any data: no phase line, no result
     assert r.stdout == ""
     assert not os.listdir(tmp_path)
-
-
-# -- (e) the resolver and the compiler's record ------------------------------
-
-def _refusal(lower):
-    with pytest.raises(ValueError) as e:
-        lower()
-    return str(e.value)
-
-
-def test_mosaic_refusals_are_current_and_recorded():
-    """lane.MOSAIC_REFUSED is what Mosaic says TODAY (lowering for the
-    TPU platform needs no chip) and CHANGES.md carries it verbatim."""
-    i32 = jnp.int32
-    said = {
-        "hash": _refusal(lambda: jax.jit(
-            lambda h, l, p, u, t: hash_update.placement(
-                h, l, p, i32(8), u, t, 4)).trace(
-            jnp.zeros(1024, i32), jnp.zeros((3, 1024), i32),
-            jnp.zeros(1024, i32), jnp.zeros(2048, i32),
-            jnp.zeros((3, 2048), i32)).lower(
-                lowering_platforms=("tpu",))),
-        "partition": _refusal(lambda: jax.jit(
-            lambda p: radix.partition_ranks(p, 4, 1024)).trace(
-            jnp.zeros(1024, i32)).lower(lowering_platforms=("tpu",))),
-    }
-    assert set(said) == set(lane.MOSAIC_REFUSED)
-    with open(os.path.join(REPO, "CHANGES.md")) as f:
-        pr21 = [ln for ln in f if ln.startswith("PR 21 (bring_up)")]
-    assert len(pr21) == 1
-    for kind, message in lane.MOSAIC_REFUSED.items():
-        assert message in said[kind]
-        assert message in pr21[0]
-
-
-@pytest.mark.parametrize("kind", ["hash", "partition"])
-def test_resolve_never_returns_a_refused_lane_on_tpu(kind, monkeypatch):
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert kind in lane.MOSAIC_REFUSED
-    try:
-        for knob in ("auto", "off"):
-            config.conf.set(config.KERNELS_PALLAS.key, knob)
-            assert lane.resolve(kind) == "scatter"
-        config.conf.set(config.KERNELS_PALLAS.key, "on")
-        with pytest.raises(RuntimeError, match="Mosaic refuses"):
-            lane.resolve(kind)  # raises; never degrades, never 'pallas'
-    finally:
-        config.conf.unset(config.KERNELS_PALLAS.key)

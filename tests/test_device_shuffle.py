@@ -108,6 +108,34 @@ def test_partition_ids_match_hash_partitioning_lane():
     assert np.asarray(ids)[:len(data)].tolist() == want.tolist()
 
 
+# -- per-destination staging ------------------------------------------------
+
+@pytest.mark.parametrize("parts,cap", [(4, 512), (7, 64), (16, 128)])
+def test_dest_slots_buffers_match_stable_argsort(parts, cap):
+    """The per-destination buffers the all-to-all ships, against numpy:
+    rows keep their order within a destination, parked pids (>= parts)
+    go nowhere, and rows past `cap` are counted, not written."""
+    from blaze_tpu.parallel.collective import _dest_slots
+    rng = np.random.default_rng(parts)
+    n = 2000
+    pid = rng.integers(0, parts + 2, n).astype(np.int64)  # some parked
+    col_ = rng.random(n) + 1.0  # never 0.0, the buffers' fill
+    order, dest, overflow = jax.jit(
+        lambda p: _dest_slots(p, parts, cap))(jnp.asarray(pid))
+    assert np.array_equal(np.asarray(order), np.argsort(pid, kind="stable"))
+    buf = jnp.zeros((parts, cap)).at[dest].set(
+        jnp.take(jnp.asarray(col_), order), mode="drop")
+    want = np.zeros((parts, cap))
+    dropped = 0
+    for p in range(parts):
+        rows = np.flatnonzero(pid == p)
+        want[p, :min(cap, len(rows))] = col_[rows[:cap]]
+        dropped += max(0, len(rows) - cap)
+    assert np.array_equal(np.asarray(buf), want)
+    assert int(overflow) == dropped
+    assert (dropped > 0) == (cap == 64)  # one case climbs, two fit
+
+
 # -- DeviceExchange unit ----------------------------------------------------
 
 def _kv_columns(n=5000, seed=3, null_rate=0.1):

@@ -138,3 +138,200 @@ def test_adversarial_same_slot_chain():
     assert int(num_groups2) == 24
     got2 = _table_dict(out2)
     assert all(c == 2 and s == 2.0 for s, c in got2.values())
+
+
+# -- hostile data against a plain numpy/Python group-by ---------------------
+# Keys are normalised as SQL grouping does (NULL = NULL, NaN = NaN
+# whatever the payload, -0.0 = 0.0) and tables are compared by GROUP:
+# where a group's slot is, is the table's business.
+
+def _nan_payloads(n, rng):
+    """float64 NaNs with DIFFERENT bit patterns: quiet, payload-bearing
+    and negative-sign.  Grouping makes one group of them."""
+    pats = np.array([0x7FF8000000000000, 0x7FF8000000000001,
+                     0xFFF8000000000099], dtype=np.uint64)
+    return pats[rng.integers(0, 3, n)].view(np.float64)
+
+
+def _trial_key_col(rng, n, dtype):
+    """(data, validity) of one hostile key column."""
+    if dtype in (np.float64, np.float32):
+        d = (rng.integers(0, 300, n) - 150).astype(dtype)
+        zero = rng.random(n) < 0.08
+        d = np.where(zero, np.where(rng.random(n) < 0.5, 0.0, -0.0
+                                    ).astype(dtype), d)
+        nan = rng.random(n) < 0.08
+        if dtype == np.float64:
+            d = np.where(nan, _nan_payloads(n, rng), d)
+        else:
+            d = np.where(nan, np.float32(np.nan), d)
+    else:
+        d = rng.integers(-1000, 1000, n).astype(dtype)
+    v = rng.random(n) > 0.15  # SQL NULL keys: still group together
+    return d, v
+
+
+def _group_key(key_cols, i):
+    out = []
+    for d, v in key_cols:
+        if not v[i]:
+            out.append(None)
+        elif d.dtype.kind == "f" and np.isnan(d[i]):
+            out.append("nan")
+        elif d.dtype.kind == "f":
+            out.append(float(d[i]) + 0.0)  # -0.0 + 0.0 == 0.0
+        else:
+            out.append(int(d[i]))
+    return tuple(out)
+
+
+_REDUCE = {"sum": sum, "min": min, "max": max}
+
+
+def _reference(key_cols, specs, mask):
+    """{group: [(value, valid) per accumulator]} over the rows `mask`
+    selects.  count counts the rows whose argument is valid and is
+    itself always valid; the others are valid once one valid argument
+    arrived."""
+    rows: dict = {}
+    for i in np.flatnonzero(mask):
+        rows.setdefault(_group_key(key_cols, i), []).append(i)
+    out = {}
+    for g, idx in rows.items():
+        accs = []
+        for kind, vd, vv in specs:
+            ok = [i for i in idx if vv is None or vv[i]]
+            if kind == "count":
+                accs.append((len(ok), True))
+            elif ok:
+                accs.append((_REDUCE[kind](float(vd[i]) for i in ok), True))
+            else:
+                accs.append((None, False))
+        out[g] = accs
+    return out
+
+
+def _table_groups(carry):
+    slots = np.flatnonzero(np.asarray(carry.used))
+    key_cols = [(np.asarray(k), np.asarray(v))
+                for k, v in zip(carry.keys, carry.key_valid)]
+    accs = [(np.asarray(a), np.asarray(v))
+            for a, v in zip(carry.accs, carry.acc_valid)]
+    got = {}
+    for s in slots:
+        g = _group_key(key_cols, s)
+        assert g not in got, f"group {g} sits in two slots"
+        got[g] = [((a[s].item() if v[s] else None), bool(v[s]))
+                  for a, v in accs]
+    return got
+
+
+def _assert_same_groups(got, want):
+    assert set(got) == set(want)
+    for g, accs in want.items():
+        for (gv, gok), (wv, wok) in zip(got[g], accs):
+            assert gok == wok, (g, got[g], accs)
+            if wok:
+                np.testing.assert_allclose(gv, wv, rtol=1e-12, atol=1e-12)
+
+
+_KINDS = ["sum", "min", "max", "count"]
+_ACC_DTYPES = (jnp.float64, jnp.float64, jnp.float64, jnp.int64)
+
+
+@jax.jit
+def _step_all_kinds(carry, key_cols, vals, av, mask):
+    """Every kind over one argument column, under jit as the engine
+    runs it."""
+    return hash_agg_step(carry, key_cols, [(k, vals, av) for k in _KINDS],
+                         mask)
+
+
+def _hostile_step(rng, key_dtypes, n=1024, slots=1 << 11):
+    """One step over hostile keys with NULL arguments and masked rows:
+    (carry, overflow, num_groups, reference)."""
+    key_cols = [_trial_key_col(rng, n, dt) for dt in key_dtypes]
+    vals = rng.random(n)
+    av = rng.random(n) > 0.2
+    mask = rng.random(n) > 0.25
+    specs = [(k, vals, av) for k in _KINDS]
+    carry = init_hash_carry([jnp.dtype(dt) for dt in key_dtypes], _KINDS,
+                            _ACC_DTYPES, slots)
+    out, overflow, num_groups = _step_all_kinds(
+        carry, [(jnp.asarray(d), jnp.asarray(v)) for d, v in key_cols],
+        jnp.asarray(vals), jnp.asarray(av), jnp.asarray(mask))
+    return out, int(overflow), int(num_groups), \
+        _reference(key_cols, specs, mask)
+
+
+@pytest.mark.parametrize("key_dtypes", [
+    [np.int64], [np.int32], [np.float64], [np.float32],
+    [np.int64, np.float64]],
+    ids=["int64", "int32", "float64", "float32", "int64-float64"])
+def test_hash_step_matches_reference(key_dtypes):
+    for seed in range(3):
+        out, overflow, num_groups, want = _hostile_step(
+            np.random.default_rng(seed), key_dtypes)
+        assert overflow == 0 and num_groups == len(want)
+        _assert_same_groups(_table_groups(out), want)
+
+
+@pytest.mark.parametrize("case", ["nulls", "invalid_accs"])
+def test_rehash_matches_reference(case):
+    """A table grown x4 holds the same groups and accumulators: with
+    NULL keys among them, and with accumulators that never saw a valid
+    argument (they must stay invalid, not turn into the identity)."""
+    n, S = 1024, 1 << 10
+    rng = np.random.default_rng(21)
+    kd = rng.integers(0, 400, n).astype(np.int64)
+    if case == "nulls":
+        kv = rng.random(n) > 0.1
+        av = rng.random(n) > 0.1
+    else:
+        kv = np.ones(n, bool)
+        av = kd % 3 != 0  # every third group: no valid argument, ever
+    vals = rng.random(n)
+    mask = np.ones(n, bool)
+    specs = [(k, vals, av) for k in _KINDS]
+    seeded, overflow, _ = _step_all_kinds(
+        init_hash_carry([jnp.int64], _KINDS, _ACC_DTYPES, S),
+        [(jnp.asarray(kd), jnp.asarray(kv))], jnp.asarray(vals),
+        jnp.asarray(av), jnp.asarray(mask))
+    assert int(overflow) == 0
+    want = _reference([(kd, kv)], specs, mask)
+    if case == "invalid_accs":
+        assert any(not ok for accs in want.values() for _v, ok in accs)
+    grown, overflow, num_groups = jax.jit(
+        lambda c: rehash_carry(c, _KINDS, 4 * S))(seeded)
+    assert int(overflow) == 0 and int(num_groups) == len(want)
+    assert grown.used.shape[0] == 4 * S
+    _assert_same_groups(_table_groups(grown), want)
+
+
+@pytest.mark.parametrize("held", [0, 32], ids=["empty", "half_full"])
+def test_overflow_returns_the_original_carry(held):
+    """The atomic contract where the table already holds groups: a batch
+    that cannot place leaves every leaf of the carry as it was."""
+    S = 64
+    rng = np.random.default_rng(9)
+
+    def batch(keys):
+        n = len(keys)
+        kv = jnp.asarray(rng.random(n) > 0.1)
+        return ([(jnp.asarray(keys.astype(np.float64)), kv)],
+                jnp.asarray(rng.random(n)),
+                jnp.asarray(rng.random(n) > 0.2), jnp.ones(n, bool))
+
+    carry = init_hash_carry([jnp.float64], _KINDS, _ACC_DTYPES, S)
+    if held:
+        carry, overflow, groups = _step_all_kinds(
+            carry, *batch(np.arange(held)))
+        # NULL keys share one group
+        assert int(overflow) == 0 and held // 2 < int(groups) <= held
+    out, overflow, groups = _step_all_kinds(
+        carry, *batch(np.arange(1000, 1080)))
+    assert int(overflow) > 0
+    assert int(groups) == int(jnp.sum(carry.used))
+    for a, b in zip(jax.tree_util.tree_leaves(out),
+                    jax.tree_util.tree_leaves(carry)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -176,17 +176,6 @@ def test_stage_loop_new_dtype_signature_builds_new_program(tmp_path,
     assert d["stage_loop_fallbacks"] == 0
 
 
-# -- ISSUE 9: Pallas kernel lane guard --------------------------------------
-
-@pytest.fixture
-def pallas_on():
-    config.conf.set(config.KERNELS_PALLAS.key, "on")
-    try:
-        yield
-    finally:
-        config.conf.unset(config.KERNELS_PALLAS.key)
-
-
 @pytest.fixture
 def rung_ladder():
     """A floor of 16 slots, 256-row batches folded one a chunk, and
@@ -203,9 +192,12 @@ def rung_ladder():
         config.conf.unset(config.STAGE_DEVICE_LOOP_CHUNK.key)
 
 
-def _climbs_once_compiled(tmp_path, tag):
+def test_stage_loop_capacity_rungs_compile_once(tmp_path, loop_on,
+                                                rung_ladder):
+    # the warm run compiles every rung's rehash + the fold at every
+    # rung; the repeat run climbs the same ladder with ZERO new compiles
     def plan():
-        return _fused(_loop_agg_plan(tmp_path, tag=tag, mode="final",
+        return _fused(_loop_agg_plan(tmp_path, tag="rung", mode="final",
                                      groups=4000))
     assert list(plan().execute(0))
     before = xla_stats.snapshot()
@@ -218,24 +210,3 @@ def _climbs_once_compiled(tmp_path, tag):
     assert d["stage_loop_reserves"] > 1
     assert d["stage_loop_rehash_lanes"] > 0
     assert d["stage_loop_fallbacks"] == 0
-    return d
-
-
-@pytest.mark.pallas
-def test_pallas_lane_capacity_rungs_compile_once(tmp_path, loop_on,
-                                                 pallas_on, rung_ladder):
-    # the rung ladder with the kernel lane forced on: the warm run
-    # compiles one placement kernel per capacity rung (the lane rides
-    # the fold/rehash cache keys); the repeat run climbs the same
-    # ladder with ZERO new compiles and zero fallbacks
-    d = _climbs_once_compiled(tmp_path, "prung")
-    # the kernel lane actually resolved (interpret on a CPU session)
-    assert (d["scatter_lane_hash_interpret"]
-            + d["scatter_lane_hash_pallas"]) > 0
-
-
-def test_stage_loop_capacity_rungs_compile_once(tmp_path, loop_on,
-                                                rung_ladder):
-    # the warm run compiles every rung's rehash + the fold at every
-    # rung; the repeat run climbs the same ladder with ZERO new compiles
-    _climbs_once_compiled(tmp_path, "rung")

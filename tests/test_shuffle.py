@@ -152,6 +152,41 @@ def test_range_partitioning_with_sampled_bounds():
     assert counts.min() > n // 10
 
 
+@pytest.mark.parametrize("n_parts", [9, 40],
+                         ids=["counting_sort", "argsort"])
+@pytest.mark.parametrize("n", [1, 777, 4096, 5000, 0],
+                         ids=["1", "777", "4096", "5000", "empty"])
+def test_writer_partition_order_is_stable(tmp_path, n, n_parts):
+    """Rows leave the writer grouped by partition and, within one, in
+    the order they arrived: np.argsort(kind="stable") with its starts
+    and ends, on both of the writer's grouping branches.  No row at all
+    is an empty .data and a length of 0 for every partition."""
+    from blaze_tpu.shuffle import IpcCompressionReader
+    from blaze_tpu.shuffle.writer import ShuffleRepartitioner
+    rng = np.random.default_rng(n)
+    pids = rng.integers(0, n_parts, n).astype(np.int32)
+    rep = ShuffleRepartitioner(RoundRobinPartitioning(n_parts), None)
+    if n:  # the insert paths never stage an empty batch
+        rep._stage(pa.RecordBatch.from_arrays(
+            [pa.array(pids), pa.array(np.arange(n, dtype=np.int64))],
+            names=["__pid", "row"]))
+    data, index = str(tmp_path / "o.data"), str(tmp_path / "o.index")
+    lengths = rep.write(data, index)
+    offsets = read_index_file(index)
+    assert len(offsets) == n_parts + 1
+    assert offsets[-1] == os.path.getsize(data) == sum(lengths)
+    ref = np.argsort(pids, kind="stable")
+    starts = np.searchsorted(pids[ref], np.arange(n_parts), "left")
+    ends = np.searchsorted(pids[ref], np.arange(n_parts), "right")
+    with open(data, "rb") as f:
+        blob = f.read()
+    for p in range(n_parts):
+        seg = blob[offsets[p]:offsets[p + 1]]
+        rows = [r for rb in IpcCompressionReader(io.BytesIO(seg))
+                .read_batches() for r in rb.column(0).to_pylist()]
+        assert rows == ref[starts[p]:ends[p]].tolist()
+
+
 def test_single_partitioning_roundtrip(tmp_path):
     t = pa.table({"a": pa.array([1, 2, 3])})
     scan = MemoryScanExec.from_arrow(t)

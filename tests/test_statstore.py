@@ -160,6 +160,28 @@ def test_torn_trailing_line_is_skipped(stats_on):
     assert rec["run_count"] == 2
 
 
+def test_record_with_a_retired_counter_still_replays(stats_on):
+    """A store written by an older build may carry a counter that has
+    since left INGEST_COUNTERS (the kernel lanes' decline count did, with
+    its eviction reason): the record is read as it stands, the old key
+    rides along untouched and later runs merge."""
+    statstore.ingest(_obs(wall=1.0))
+    path = statstore._fp_path(stats_on, "fp-a")
+    with open(path) as f:
+        old = json.loads(f.read().splitlines()[-1])
+    old["counters"]["retired_lane_declines"] = 3
+    old["fallback_reasons"]["retired_lane"] = 3
+    with open(path, "w") as f:
+        f.write(statstore._dumps(old) + "\n")
+    assert "retired_lane_declines" not in statstore.INGEST_COUNTERS
+    rec = statstore.ingest(_obs(wall=2.0))
+    assert rec["run_count"] == 2
+    assert rec["counters"]["partial_agg_probe_rows"] == 200
+    assert rec["counters"]["retired_lane_declines"] == 3
+    assert statstore.StatStore(stats_on).record("fp-a") == rec
+    assert {f["kind"] for f in advisor.findings(rec)} >= {"host_eviction"}
+
+
 def test_compaction_bounds_file_growth(stats_on):
     for i in range(statstore._MAX_LINES + 3):
         statstore.ingest(_obs(wall=1.0 + i * 0.01))

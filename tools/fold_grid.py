@@ -45,7 +45,7 @@ def _step(probe_rounds):
         ones = jnp.ones(LANES, bool)
         return hash_agg_step(carry, [(k1, ones), (k2, ones)],
                              [("sum", v, None)], mask,
-                             probe_rounds=probe_rounds, lane="scatter")
+                             probe_rounds=probe_rounds)
     return jax.jit(f)
 
 
@@ -114,7 +114,7 @@ def main() -> int:
                 step_s=t, overflow=int(ovf), groups_after=int(ng))
             if load in (1 / 8, 1 / 4) and log_s <= 20:
                 re = jax.jit(lambda c: rehash_carry(c, list(KINDS),
-                                                    4 * slots, lane="scatter"))
+                                                    4 * slots))
                 t, (_c, ovf, ng) = _timed(re, carry)
                 say(slots=slots, shape="rehash_x4",
                     load_before=groups / slots, step_s=t, overflow=int(ovf),
