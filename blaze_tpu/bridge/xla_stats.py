@@ -107,6 +107,7 @@ _stage_loop = {"stage_loop_programs_built": 0,
                "stage_loop_batches": 0, "stage_loop_rows": 0,
                "stage_loop_tasks": 0, "stage_loop_regrows": 0,
                "stage_loop_reserves": 0, "stage_loop_rehash_lanes": 0,
+               "stage_loop_full_rounds": 0, "stage_loop_narrow_rounds": 0,
                "stage_loop_max_slots": 0,
                "stage_loop_fallbacks": 0,
                "stage_loop_staged_dispatches_avoided": 0}
@@ -801,14 +802,18 @@ def note_stage_program(cache_hit: bool) -> None:
 
 def note_stage_loop_task(chunks: int, batches: int, rows: int,
                          regrows: int, reserves: int, rehash_lanes: int,
-                         slots: int, dispatches_avoided: int) -> None:
+                         slots: int, dispatches_avoided: int,
+                         full_rounds: int, narrow_rounds: int) -> None:
     """One map task completed through the device-resident stage loop:
     `chunks` loop program calls folded `batches` batches / `rows` rows.
     The agg table's capacity was raised at `reserves` chunk boundaries
     before the fold and `regrows` times after an overflow, pushing
     `rehash_lanes` old-table slots through the rehash, and ended at
     `slots` (`stage_loop_max_slots` is the high-water mark since
-    reset(), so its delta says how far it rose).  The staged per-batch
+    reset(), so its delta says how far it rose).  The table's probe ran
+    `full_rounds` rounds over a whole batch's lanes and `narrow_rounds`
+    over the compacted rows a batch still had unplaced
+    (parallel/stage.py hash_agg_step).  The staged per-batch
     path would have issued `dispatches_avoided` extra Python
     dispatches."""
     with _lock:
@@ -820,6 +825,8 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int,
         _stage_loop["stage_loop_regrows"] += int(regrows)
         _stage_loop["stage_loop_reserves"] += int(reserves)
         _stage_loop["stage_loop_rehash_lanes"] += int(rehash_lanes)
+        _stage_loop["stage_loop_full_rounds"] += int(full_rounds)
+        _stage_loop["stage_loop_narrow_rounds"] += int(narrow_rounds)
         _stage_loop["stage_loop_max_slots"] = max(
             _stage_loop["stage_loop_max_slots"], int(slots))
         _stage_loop["stage_loop_staged_dispatches_avoided"] += \

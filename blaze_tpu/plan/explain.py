@@ -348,6 +348,8 @@ class QueryProfile:
                 f"dispatches_avoided="
                 f"{x.get('stage_loop_staged_dispatches_avoided', 0)} "
                 f"reserves={x.get('stage_loop_reserves', 0)} "
+                f"probe_rounds={x.get('stage_loop_full_rounds', 0)}"
+                f"+{x.get('stage_loop_narrow_rounds', 0)}narrow "
                 f"regrows={x.get('stage_loop_regrows', 0)} "
                 f"fallbacks={x.get('stage_loop_fallbacks', 0)}")
         if x.get("stream_epochs"):

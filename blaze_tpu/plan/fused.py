@@ -1841,7 +1841,7 @@ class FusedPartialAggExec(ExecutionPlan):
             if carry is None:
                 carry = init_hash_carry(key_dtypes, kinds,
                                         self._acc_dtypes(), slots)
-            new_carry, overflow, _ng = step(carry, batch)
+            new_carry, overflow, _ng, _rounds = step(carry, batch)
             while int(to_host(overflow)) > 0:
                 if not self._grow:
                     new_carry = None
@@ -1850,11 +1850,11 @@ class FusedPartialAggExec(ExecutionPlan):
                 # the step is atomic, so carry is intact and lossless
                 slots *= 2
                 self.metrics.add("table_grown", 1)
-                bigger, re_ovf, _ = _rehash_jit(kinds, slots)(carry)
+                bigger, re_ovf, _, _ = _rehash_jit(kinds, slots)(carry)
                 if int(to_host(re_ovf)) > 0:
                     continue  # rare probe clustering: double again
                 carry = bigger
-                new_carry, overflow, _ng = step(carry, batch)
+                new_carry, overflow, _ng, _rounds = step(carry, batch)
             if new_carry is None:
                 skipping = True
                 self.metrics.add("partial_skipped", 1)
@@ -1877,7 +1877,7 @@ class FusedPartialAggExec(ExecutionPlan):
         while True:
             local = init_hash_carry(key_dtypes, kinds,
                                     self._acc_dtypes(), slots)
-            out, overflow, _ng = step(local, batch)
+            out, overflow, _ng, _rounds = step(local, batch)
             if int(to_host(overflow)) == 0:
                 return out
             slots *= 2

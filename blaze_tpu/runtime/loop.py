@@ -162,40 +162,42 @@ def _fold_factory(program, donate: bool):
 
     def fold_impl(carry, cols_stacked, masks, start, look):
         def body(state):
-            b, c, ovf_seen, first_ovf, folded = state
+            b, c, ovf_seen, first_ovf, folded, rounds = state
             kd, kv, ad, av, m = prepare(_batch_of(cols_stacked, b), masks[b])
             # once a batch overflows, later batches fold as no-ops: the
             # carry stays exactly at the pre-overflow state (hash_agg_step
             # is atomic), so the host can regrow and resume mid-chunk
             live = jnp.logical_and(m, jnp.logical_not(ovf_seen))
             specs = [(k, d, v) for k, d, v in zip(kinds, ad, av)]
-            new_c, ovf, _ng = hash_agg_step(c, list(zip(kd, kv)), specs,
-                                            live)
+            new_c, ovf, _ng, step_rounds = hash_agg_step(
+                c, list(zip(kd, kv)), specs, live)
             hit = ovf > 0
             first_ovf = jnp.where(hit & ~ovf_seen, b, first_ovf)
             # the rows of an overflowing batch are not in the table
             folded += jnp.where(hit, 0, jnp.sum(live, dtype=jnp.int32))
             return (b + 1, new_c, jnp.logical_or(ovf_seen, hit), first_ovf,
-                    folded)
+                    folded, rounds + step_rounds)
 
         def more(state):
-            b, _c, _ovf_seen, _first_ovf, folded = state
+            b, _c, _ovf_seen, _first_ovf, folded, _rounds = state
             # `look` live rows are in: stop at this batch boundary, so
             # the host can take its first look at groups per live row
             return (b < masks.shape[0]) & (folded < look)
 
         zero = jnp.asarray(0, jnp.int32)
-        b, carry, ovf_seen, first_ovf, folded = jax.lax.while_loop(
-            more, body, (start, carry, jnp.asarray(False), zero, zero))
-        # the table's group count and the live rows this call inserted
-        # ride the overflow scalars' round trip: the host sizes the next
-        # chunk's table from the one and judges the partial-skip ratio
-        # from both.  `resume` is the batch to go on from: the one that
+        b, carry, ovf_seen, first_ovf, folded, rounds = jax.lax.while_loop(
+            more, body, (start, carry, jnp.asarray(False), zero, zero,
+                         jnp.zeros(2, jnp.int32)))
+        # the table's group count, the live rows this call inserted and
+        # the probe rounds it ran (full width, narrow width) ride the
+        # overflow scalars' round trip: the host sizes the next chunk's
+        # table from the first and judges the partial-skip ratio from the
+        # first two.  `resume` is the batch to go on from: the one that
         # overflowed, else the first one not folded (the chunk's width
         # when nothing stopped the fold)
         groups = jnp.sum(carry.used, dtype=jnp.int32)
         resume = jnp.where(ovf_seen, first_ovf, b)
-        return carry, ovf_seen, resume, groups, folded
+        return carry, ovf_seen, resume, groups, folded, rounds
 
     kwargs = {"donate_argnums": (0,)} if donate else {}
     return _cached(
@@ -339,6 +341,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
               else program.source.execute(partition))
     windows = _batch_windows(stream, chunk)
     batches = rows = fold_calls = regrows = reserves = rehash_lanes = 0
+    full_rounds = narrow_rounds = 0
     ci = groups = live_folded = 0
     slots, carry = floor, None  # allocated at the first chunk, for it
     rest = None
@@ -356,7 +359,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                 return fresh(want), want
             _run_fences()  # drain in-flight overlapped exchanges
             rehash_lanes += slots
-            bigger, re_ovf, _ = _rehash_jit(program.kinds, want)(carry)
+            bigger, re_ovf, _, _ = _rehash_jit(program.kinds, want)(carry)
             if int(to_host(re_ovf)) == 0:
                 return bigger, want
             want *= 2  # rare probe clustering: double again
@@ -393,14 +396,16 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                     look = (min_rows - live_folded
                             if may_switch and live_folded < min_rows
                             else _NO_LOOK)
-                    carry, ovf_seen, resume, ngroups, nlive = fold(
+                    carry, ovf_seen, resume, ngroups, nlive, rounds = fold(
                         carry, cols_stacked, masks,
                         jnp.asarray(start, jnp.int32),
                         jnp.asarray(look, jnp.int32))
                     fold_calls += 1
                     # the host waits for the fold here
-                    ovf_seen, resume, ngroups, nlive = to_host(
-                        (ovf_seen, resume, ngroups, nlive))
+                    ovf_seen, resume, ngroups, nlive, rounds = to_host(
+                        (ovf_seen, resume, ngroups, nlive, rounds))
+                    full_rounds += int(rounds[0])
+                    narrow_rounds += int(rounds[1])
                     groups = int(ngroups)
                     live_folded += int(nlive)
                     start = int(resume)
@@ -439,6 +444,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
     xla_stats.note_stage_loop_task(
         chunks=fold_calls, batches=batches, rows=rows, regrows=regrows,
         reserves=reserves, rehash_lanes=rehash_lanes, slots=slots,
+        full_rounds=full_rounds, narrow_rounds=narrow_rounds,
         dispatches_avoided=max(0, batches - fold_calls))
     program.agg._note_lane(batches)
     return carry, rest

@@ -239,7 +239,7 @@ def phase_kernels(seed: int) -> None:
         step = jax.jit(lambda c, k, v, m: hash_agg_step(
             c, k, [("sum", v, None)], m))
         t0 = time.perf_counter()
-        out, overflow, groups = jax.block_until_ready(
+        out, overflow, groups, rounds = jax.block_until_ready(
             step(carry, keys, jnp.asarray(vals), jnp.asarray(mask)))
         took = time.perf_counter() - t0
         slots_used = np.flatnonzero(np.asarray(out.used))
@@ -254,6 +254,7 @@ def phase_kernels(seed: int) -> None:
         kinds = "+".join(np.dtype(dt).name for dt in kdt)
         say(f"  rows {n} slots {S} keys {kinds}: compile+run {took:.2f}s "
             f"groups {int(groups)} overflow {int(overflow)} "
+            f"rounds {int(rounds[0])} full + {int(rounds[1])} narrow "
             f"equal {got == want}")
         check(int(overflow) == 0 and got == want,
               f"hash_agg_step differs from numpy at {n}x{S}x{kinds}")

@@ -49,7 +49,7 @@ def test_full_load_overflow_is_atomic():
                             [jnp.float64, jnp.int64], S)
     keys = jnp.arange(80, dtype=jnp.int64)
     vals = jnp.ones(80, dtype=jnp.float64)
-    out, overflow, _ = _insert(carry, keys, vals)
+    out, overflow, _, _ = _insert(carry, keys, vals)
     assert int(overflow) > 0
     for a, b in zip(jax.tree_util.tree_leaves(out),
                     jax.tree_util.tree_leaves(carry)):
@@ -64,7 +64,8 @@ def test_probe_rounds_exhaustion_partial_chain():
                             [jnp.float64, jnp.int64], S)
     keys = jnp.arange(60, dtype=jnp.int64)
     vals = jnp.ones(60, dtype=jnp.float64)
-    out, overflow, num_groups = _insert(carry, keys, vals, probe_rounds=1)
+    out, overflow, num_groups, _ = _insert(carry, keys, vals,
+                                           probe_rounds=1)
     if int(overflow) == 0:  # statistically impossible at 60/64 in 1 round
         pytest.fail("60 keys into 64 slots placed in ONE probe round")
     # atomic: nothing was written
@@ -84,13 +85,13 @@ def test_rehash_grow_preserves_every_group():
     for lo in range(0, 1024, 256):
         k = jnp.asarray(all_keys[lo:lo + 256])
         v = jnp.asarray(all_vals[lo:lo + 256])
-        out, overflow, _ = _insert(carry, k, v)
+        out, overflow, _, _ = _insert(carry, k, v)
         if int(overflow) > 0:
             # production grow loop: rehash into 4x slots, retry batch
-            carry, ovf2, _ = rehash_carry(carry, ["sum", "count"], 512)
+            carry, ovf2, _, _ = rehash_carry(carry, ["sum", "count"], 512)
             assert int(ovf2) == 0, "grow re-insert itself overflowed"
             grown = True
-            out, overflow, _ = _insert(carry, k, v)
+            out, overflow, _, _ = _insert(carry, k, v)
             assert int(overflow) == 0
         carry = out
     assert grown, "test never exercised the grow path (tune sizes)"
@@ -123,8 +124,8 @@ def test_adversarial_same_slot_chain():
                             [jnp.float64, jnp.int64], S)
     keys = jnp.asarray(same)
     vals = jnp.ones(len(same), dtype=jnp.float64)
-    out, overflow, num_groups = _insert(carry, keys, vals,
-                                        probe_rounds=32)
+    out, overflow, num_groups, _ = _insert(carry, keys, vals,
+                                           probe_rounds=32)
     assert int(overflow) == 0, "32 rounds must place a 24-chain"
     assert int(num_groups) == 24
     got = _table_dict(out)
@@ -132,8 +133,8 @@ def test_adversarial_same_slot_chain():
     assert all(c == 1 and s == 1.0 for s, c in got.values())
 
     # second insert of the SAME keys must unify, not duplicate
-    out2, overflow2, num_groups2 = _insert(out, keys, vals,
-                                           probe_rounds=32)
+    out2, overflow2, num_groups2, _ = _insert(out, keys, vals,
+                                              probe_rounds=32)
     assert int(overflow2) == 0
     assert int(num_groups2) == 24
     got2 = _table_dict(out2)
@@ -257,7 +258,7 @@ def _hostile_step(rng, key_dtypes, n=1024, slots=1 << 11):
     specs = [(k, vals, av) for k in _KINDS]
     carry = init_hash_carry([jnp.dtype(dt) for dt in key_dtypes], _KINDS,
                             _ACC_DTYPES, slots)
-    out, overflow, num_groups = _step_all_kinds(
+    out, overflow, num_groups, _ = _step_all_kinds(
         carry, [(jnp.asarray(d), jnp.asarray(v)) for d, v in key_cols],
         jnp.asarray(vals), jnp.asarray(av), jnp.asarray(mask))
     return out, int(overflow), int(num_groups), \
@@ -293,7 +294,7 @@ def test_rehash_matches_reference(case):
     vals = rng.random(n)
     mask = np.ones(n, bool)
     specs = [(k, vals, av) for k in _KINDS]
-    seeded, overflow, _ = _step_all_kinds(
+    seeded, overflow, _, _ = _step_all_kinds(
         init_hash_carry([jnp.int64], _KINDS, _ACC_DTYPES, S),
         [(jnp.asarray(kd), jnp.asarray(kv))], jnp.asarray(vals),
         jnp.asarray(av), jnp.asarray(mask))
@@ -301,7 +302,7 @@ def test_rehash_matches_reference(case):
     want = _reference([(kd, kv)], specs, mask)
     if case == "invalid_accs":
         assert any(not ok for accs in want.values() for _v, ok in accs)
-    grown, overflow, num_groups = jax.jit(
+    grown, overflow, num_groups, _ = jax.jit(
         lambda c: rehash_carry(c, _KINDS, 4 * S))(seeded)
     assert int(overflow) == 0 and int(num_groups) == len(want)
     assert grown.used.shape[0] == 4 * S
@@ -324,14 +325,419 @@ def test_overflow_returns_the_original_carry(held):
 
     carry = init_hash_carry([jnp.float64], _KINDS, _ACC_DTYPES, S)
     if held:
-        carry, overflow, groups = _step_all_kinds(
+        carry, overflow, groups, _ = _step_all_kinds(
             carry, *batch(np.arange(held)))
         # NULL keys share one group
         assert int(overflow) == 0 and held // 2 < int(groups) <= held
-    out, overflow, groups = _step_all_kinds(
+    out, overflow, groups, _ = _step_all_kinds(
         carry, *batch(np.arange(1000, 1080)))
     assert int(overflow) > 0
     assert int(groups) == int(jnp.sum(carry.used))
     for a, b in zip(jax.tree_util.tree_leaves(out),
                     jax.tree_util.tree_leaves(carry)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the probe's two widths against a model of its rounds -------------------
+# hash_agg_step probes at full width while more rows are unplaced than
+# the narrow width holds (round one always), then compacts the rest and
+# probes them at narrow width.  The model below knows rounds and nothing
+# of widths beyond counting them: a Python loop over the unplaced rows in
+# row order, so "the lowest row index claims" is the order of the loop.
+# The table must equal the model's SLOT FOR SLOT: the narrow rounds may
+# not move a single group.
+
+from blaze_tpu.kernels import hashing as H  # noqa: E402
+from blaze_tpu.parallel.stage import narrow_width  # noqa: E402
+
+
+def _normalised(key_cols):
+    out = []
+    for d, v in key_cols:
+        if d.dtype.kind == "f":
+            d = np.where(d == 0, np.abs(d), d)
+            d = np.where(np.isnan(d), np.nan, d)
+        out.append((d, v))
+    return out
+
+
+def _home_slots(key_cols, slots):
+    cols = [(jnp.asarray(d), jnp.asarray(v), str(d.dtype))
+            for d, v in _normalised(key_cols)]
+    h = H.hash_columns(cols, seed=42, xp=jnp, algo="xxhash64")
+    return np.asarray(h).astype(np.int64) & (slots - 1)
+
+
+class _ModelTable:
+    def __init__(self, key_dtypes, slots):
+        self.used = np.zeros(slots, bool)
+        self.keys = [np.zeros(slots, dt) for dt in key_dtypes]
+        self.valid = [np.zeros(slots, bool) for _ in key_dtypes]
+        self.sums = np.zeros(slots)
+        self.counts = np.zeros(slots, np.int64)
+
+    def copy(self):
+        t = _ModelTable([], len(self.used))
+        t.used = self.used.copy()
+        t.keys = [k.copy() for k in self.keys]
+        t.valid = [v.copy() for v in self.valid]
+        t.sums, t.counts = self.sums.copy(), self.counts.copy()
+        return t
+
+    def _holds(self, s, key_cols, i):
+        for tk, tv, (d, v) in zip(self.keys, self.valid, key_cols):
+            if tv[s] != v[i]:
+                return False
+            if v[i] and not (tk[s] == d[i]
+                             or (d.dtype.kind == "f" and np.isnan(tk[s])
+                                 and np.isnan(d[i]))):
+                return False
+        return True
+
+    def insert(self, key_cols, vals, counts, mask, probe_rounds=16):
+        """(table after, overflow, [full rounds, narrow rounds]); the
+        table as it was when a row is left over."""
+        t = self.copy()
+        S, n = len(t.used), len(mask)
+        key_cols = _normalised(key_cols)
+        h = _home_slots(key_cols, S)
+        W = narrow_width(n)
+        unplaced = [int(i) for i in np.flatnonzero(mask)]
+        placed = {}
+        rounds = [0, 0]
+        r = 0
+        while r < probe_rounds and unplaced:
+            wide = rounds[1] == 0 and (r == 0 or len(unplaced) > W)
+            rounds[0 if wide else 1] += 1
+            for i in unplaced:           # ascending: the lowest row claims
+                s = (h[i] + r) % S
+                if not t.used[s]:
+                    t.used[s] = True
+                    for tk, tv, (d, v) in zip(t.keys, t.valid, key_cols):
+                        tk[s], tv[s] = d[i], v[i]
+            still = []
+            for i in unplaced:
+                s = (h[i] + r) % S
+                if t._holds(s, key_cols, i):
+                    placed[i] = s
+                else:
+                    still.append(i)
+            unplaced = still
+            r += 1
+        if unplaced:
+            return self, len(unplaced), rounds
+        for i in sorted(placed):
+            t.sums[placed[i]] += vals[i]
+            t.counts[placed[i]] += counts[i]
+        return t, 0, rounds
+
+
+def _assert_slot_for_slot(carry, model):
+    used = np.asarray(carry.used)
+    np.testing.assert_array_equal(used, model.used)
+    for k, v, mk, mv in zip(carry.keys, carry.key_valid, model.keys,
+                            model.valid):
+        np.testing.assert_array_equal(np.asarray(v)[used], mv[used])
+        np.testing.assert_array_equal(np.asarray(k)[used], mk[used])
+    np.testing.assert_array_equal(np.asarray(carry.accs[1])[used],
+                                  model.counts[used])
+    np.testing.assert_allclose(np.asarray(carry.accs[0])[used],
+                               model.sums[used], rtol=1e-12)
+
+
+@jax.jit
+def _step_sum_count(carry, key_cols, vals, mask):
+    return hash_agg_step(carry, key_cols,
+                         [("sum", vals, None), ("count", None, None)], mask)
+
+
+def _fresh(key_dtypes, slots):
+    return init_hash_carry([jnp.dtype(dt) for dt in key_dtypes],
+                           ["sum", "count"], [jnp.float64, jnp.int64], slots)
+
+
+def _step_and_model(carry, model, key_cols, vals, mask):
+    out, overflow, groups, rounds = _step_sum_count(
+        carry, [(jnp.asarray(d), jnp.asarray(v)) for d, v in key_cols],
+        jnp.asarray(vals), jnp.asarray(mask))
+    after, m_overflow, m_rounds = model.insert(
+        key_cols, vals, np.ones(len(mask), np.int64), mask)
+    assert int(overflow) == m_overflow
+    assert np.asarray(rounds).tolist() == m_rounds
+    assert int(groups) == int(after.used.sum())
+    _assert_slot_for_slot(out, after)
+    return out, after, m_overflow, m_rounds
+
+
+class _Sieve:
+    """int64 keys by their home slot in a table of `slots`."""
+
+    def __init__(self, slots, candidates=3_000_000):
+        cand = np.arange(1, candidates, dtype=np.int64) * 1000003 + 17
+        home = _home_slots([(cand, np.ones(len(cand), bool))], slots)
+        order = np.argsort(home, kind="stable")
+        self._cand = cand[order]
+        self._start = np.searchsorted(home[order], np.arange(slots + 1))
+
+    def at(self, slot, k):
+        lo, hi = self._start[slot], self._start[slot + 1]
+        assert hi - lo >= k, "sieve range too small"
+        return self._cand[lo:lo + k]
+
+
+@pytest.fixture(scope="module")
+def sieve():
+    made = {}
+
+    def get(slots):
+        if slots not in made:
+            made[slots] = _Sieve(slots)
+        return made[slots]
+    return get
+
+
+_LANES = 4096                      # narrow width 512
+_WIDTH_CASES = {
+    # name: (lanes, slots, [(keys sharing a home slot, how many homes)],
+    #        [full rounds, narrow rounds], rows left over)
+    "one_round": (_LANES, 1 << 14, [(1, 4096)], [1, 0], 0),
+    "fewer_than_width": (_LANES, 1 << 14, [(2, 100), (1, 3896)], [1, 1], 0),
+    "exactly_width": (_LANES, 1 << 14, [(2, 512), (1, 3072)], [1, 1], 0),
+    "width_plus_one": (_LANES, 1 << 14, [(2, 513), (1, 3070)], [2, 0], 0),
+    "wide_then_narrow": (_LANES, 1 << 14, [(2, 600), (3, 300), (1, 1996)],
+                         [2, 1], 0),
+    "never_under_width": (_LANES, 1 << 12, [(600, 1)], [16, 0], 584),
+    "chain_overflows_narrow": (_LANES, 1 << 14, [(1, 4056), (40, 1)],
+                               [1, 15], 24),
+    "lane_floor": (2048, 1 << 13, [(1, 2038), (10, 1)], [10, 0], 0),
+}
+
+
+def _engineered(sieve, lanes, slots, groups, rng):
+    """Distinct keys laid out by home slot: `k` keys on each of `homes`
+    home slots, homes four apart so that a loser's next slots are free;
+    rows shuffled, lanes beyond the keys masked out."""
+    keys, home = [], 0
+    for k, homes in groups:
+        for _ in range(homes):
+            keys.extend(sieve(slots).at(home, k))
+            home += 4
+    assert home <= slots and len(keys) <= lanes
+    kd = np.zeros(lanes, np.int64)
+    mask = np.zeros(lanes, bool)
+    rows = rng.permutation(lanes)[:len(keys)]
+    kd[rows], mask[rows] = keys, True
+    return kd, mask
+
+
+@pytest.mark.parametrize("case", list(_WIDTH_CASES))
+def test_step_matches_round_model(case, sieve):
+    lanes, slots, groups, want_rounds, want_left = _WIDTH_CASES[case]
+    assert narrow_width(lanes) == (512 if lanes == _LANES else 0)
+    rng = np.random.default_rng(len(case))
+    carry, model = _fresh([np.int64], slots), _ModelTable([np.int64], slots)
+    # the table already holds groups: a step that overflows must hand
+    # exactly these back
+    held = sieve(slots).at(slots - 3, 3)   # fills the last three slots
+    hd = np.zeros(lanes, np.int64)
+    hd[:3] = held
+    carry, model, overflow, _ = _step_and_model(
+        carry, model, [(hd, np.ones(lanes, bool))], rng.random(lanes),
+        np.arange(lanes) < 3)
+    assert overflow == 0
+    kd, mask = _engineered(sieve, lanes, slots, groups, rng)
+    out, after, overflow, rounds = _step_and_model(
+        carry, model, [(kd, np.ones(lanes, bool))], rng.random(lanes), mask)
+    assert rounds == want_rounds and overflow == want_left
+    if want_left:
+        for a, b in zip(jax.tree_util.tree_leaves(out),
+                        jax.tree_util.tree_leaves(carry)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_null_nan_and_negative_zero_keys_place_in_the_narrow_rounds():
+    """The home slots of the NULL, the NaN and the zero key are taken by
+    other keys first, so every such row loses round one and is placed by
+    a narrow round: under whatever NaN payload, zero sign or data a NULL
+    row carries, one group each, in the model's slot."""
+    lanes, slots = _LANES, 1 << 12
+    rng = np.random.default_rng(29)
+    special = [(np.array([np.nan]), np.array([True])),
+               (np.array([0.0]), np.array([True])),
+               (np.array([7.0]), np.array([False]))]
+    homes = [int(_home_slots([kc], slots)[0]) for kc in special]
+    assert len(set(homes)) == 3
+    cand = np.arange(1.0, 200_000.0)
+    cand_home = _home_slots([(cand, np.ones(len(cand), bool))], slots)
+    blockers = np.array([cand[cand_home == hm][0] for hm in homes])
+    bd = np.zeros(lanes)
+    bd[:3] = blockers
+    carry, model, overflow, rounds = _step_and_model(
+        _fresh([np.float64], slots), _ModelTable([np.float64], slots),
+        [(bd, np.ones(lanes, bool))], rng.random(lanes),
+        np.arange(lanes) < 3)
+    assert overflow == 0 and rounds == [1, 0]
+
+    kd = 1e6 + rng.permutation(lanes).astype(np.float64)  # distinct filler
+    kv = np.ones(lanes, bool)
+    rows = rng.permutation(lanes)
+    kd[rows[:100]] = _nan_payloads(100, rng)
+    kd[rows[100:200]] = np.where(rng.random(100) < 0.5, 0.0, -0.0)
+    kv[rows[200:300]] = False            # NULL keys over arbitrary data
+    mask = rng.random(lanes) > 0.6       # few enough for the load
+    vals = rng.random(lanes)
+    out, after, overflow, rounds = _step_and_model(
+        carry, model, [(kd, kv)], vals, mask)
+    assert overflow == 0 and rounds[0] == 1 and rounds[1] >= 1
+    for hm in homes:                     # pushed off their home slots
+        assert np.asarray(out.keys[0])[hm] in blockers
+    specs = [("sum", vals, None), ("count", None, None)]
+    want = _reference([(kd, kv)], specs, mask)
+    for b in blockers:
+        want[(float(b),)] = None
+    got = _table_groups(out)
+    assert set(got) == set(want)
+    for g, accs in want.items():
+        if accs is not None:
+            assert got[g][1] == accs[1]
+            np.testing.assert_allclose(got[g][0][0], accs[0][0], rtol=1e-12)
+
+
+def test_rehash_of_more_used_slots_than_the_narrow_width():
+    """rehash_carry probes the OLD table's slots as lanes: 8,192 lanes,
+    narrow width 1,024, about 2,000 of them used."""
+    lanes = old_slots = 1 << 13
+    rng = np.random.default_rng(31)
+    kd = rng.integers(0, 2000, lanes).astype(np.int64) * 1000003 + 17
+    ones = np.ones(lanes, bool)
+    old, old_model, overflow, _ = _step_and_model(
+        _fresh([np.int64], old_slots), _ModelTable([np.int64], old_slots),
+        [(kd, ones)], rng.random(lanes), ones)
+    assert overflow == 0
+    assert int(old_model.used.sum()) > narrow_width(old_slots)
+    grown, overflow, groups, rounds = jax.jit(
+        lambda c: rehash_carry(c, ["sum", "count"], 4 * old_slots))(old)
+    want, m_overflow, m_rounds = _ModelTable([np.int64], 4 * old_slots) \
+        .insert([(old_model.keys[0], old_model.valid[0])], old_model.sums,
+                old_model.counts, old_model.used)
+    assert int(overflow) == m_overflow == 0
+    assert np.asarray(rounds).tolist() == m_rounds and m_rounds[1] >= 1
+    assert int(groups) == int(old_model.used.sum())
+    _assert_slot_for_slot(grown, want)
+
+
+def test_a_second_call_at_the_same_shapes_builds_no_program(sieve):
+    """Which rounds run, and at which width, is decided on the device:
+    a batch that narrows and one that does not are ONE program."""
+    from blaze_tpu.bridge import xla_stats
+    from blaze_tpu.plan.fused import _hash_step_jit
+    step = _hash_step_jit(("sum",))
+    slots = 1 << 14
+    rng = np.random.default_rng(37)
+
+    def run(case):
+        kd, mask = _engineered(sieve, _LANES, slots, _WIDTH_CASES[case][2],
+                               rng)
+        ones = jnp.ones(_LANES, bool)
+        carry = init_hash_carry([jnp.int64], ["sum"], [jnp.float64], slots)
+        _c, overflow, _g, rounds = step(
+            carry, (jnp.asarray(kd),), (ones,),
+            (jnp.asarray(rng.random(_LANES)),), (ones,), jnp.asarray(mask))
+        return np.asarray(rounds).tolist()
+
+    assert run("one_round") == [1, 0]
+    before = xla_stats.snapshot()
+    assert run("wide_then_narrow") == [2, 1]
+    assert run("chain_overflows_narrow") == [1, 15]
+    assert run("one_round") == [1, 0]
+    d = xla_stats.delta(before)
+    assert d["total_compiles"] == 0 and d["backend_compiles"] == 0
+
+
+def test_stage_loop_round_counters_add_up_to_the_models_rounds(tmp_path,
+                                                               monkeypatch):
+    """Through the stage loop: the rounds a task's fold ran at each
+    width reach `xla_stats` with the scalars the fold returns anyway, and
+    are the model's, batch by batch."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from blaze_tpu import config
+    from blaze_tpu.bridge import xla_stats
+    from blaze_tpu.memory import MemManager
+    from blaze_tpu.plan.column_pruning import prune_columns
+    from blaze_tpu.bridge.metrics import MetricNode
+    from blaze_tpu.plan.explain import QueryProfile
+    from blaze_tpu.plan.fused import fuse_plan
+    from blaze_tpu.plan.planner import collapse_filter_project, create_plan
+    from blaze_tpu.runtime import loop as device_loop
+
+    batch, batches = _LANES, 4
+    rng = np.random.default_rng(41)
+    keys = rng.permutation(batch * batches).astype(np.int64) * 1000003 + 17
+    vals = rng.random(len(keys))
+    path = str(tmp_path / "rounds.parquet")
+    pq.write_table(pa.table({"k": pa.array(keys), "v": pa.array(vals)}),
+                   path, row_group_size=batch)
+    schema = {"fields": [
+        {"name": "k", "type": {"id": "int64"}, "nullable": True},
+        {"name": "v", "type": {"id": "float64"}, "nullable": True}]}
+    plan = {"kind": "hash_agg",
+            "groupings": [{"expr": {"kind": "column", "index": 0},
+                           "name": "k"}],
+            "aggs": [{"fn": "sum", "mode": "final", "name": "s",
+                      "args": [{"kind": "column", "index": 1}]}],
+            "input": {"kind": "parquet_scan", "schema": schema,
+                      "file_groups": [[path]]}}
+    settings = {config.STAGE_DEVICE_LOOP_ENABLE.key: "on",
+                config.ON_DEVICE_AGG_CAPACITY.key: 16,
+                config.BATCH_SIZE.key: batch,
+                config.STAGE_DEVICE_LOOP_CHUNK.key: batches,
+                config.FUSED_HOST_VECTORIZED_ENABLE.key: False}
+    MemManager.init(4 << 30)
+    folded_into = []                 # the table's slots at every fold call
+    real_factory = device_loop._fold_factory
+
+    def factory(*a, **k):
+        fold = real_factory(*a, **k)
+
+        def spy(carry, *rest):
+            folded_into.append(int(carry.used.shape[0]))
+            return fold(carry, *rest)
+        return spy
+
+    monkeypatch.setattr(device_loop, "_fold_factory", factory)
+    for k, v in settings.items():
+        config.conf.set(k, v)
+    try:
+        fused = fuse_plan(prune_columns(collapse_filter_project(
+            create_plan(plan))))
+        before = xla_stats.snapshot()
+        rows = sum(b.compact().to_arrow().num_rows
+                   for b in fused.execute(0))
+        d = xla_stats.delta(before)
+    finally:
+        for k in settings:
+            config.conf.unset(k)
+    assert rows == len(keys)
+    assert d["stage_loop_tasks"] == 1 and d["stage_loop_fallbacks"] == 0
+    assert d["stage_loop_calls"] == 1 and d["stage_loop_batches"] == batches
+    # one chunk: the table is sized once, for all of it
+    slots = device_loop._slots_for(len(keys), 16)
+    assert folded_into == [slots]
+    model = _ModelTable([np.int64], slots)
+    ones = np.ones(batch, bool)
+    want = [0, 0]
+    for b in range(batches):
+        lo = b * batch
+        model, overflow, rounds = model.insert(
+            [(keys[lo:lo + batch], ones)], vals[lo:lo + batch],
+            np.ones(batch, np.int64), ones)
+        assert overflow == 0
+        want = [want[0] + rounds[0], want[1] + rounds[1]]
+    assert want[1] > 0, "no batch narrowed (tune the sizes)"
+    assert [d["stage_loop_full_rounds"],
+            d["stage_loop_narrow_rounds"]] == want
+    footer = QueryProfile("q", 0, MetricNode("root"), 1, "local",
+                          xla=d).render_text()
+    assert f"probe_rounds={want[0]}+{want[1]}narrow" in footer

@@ -1,18 +1,34 @@
-"""Device time of one hash-aggregation step against table size and load.
+"""Device time of one hash-aggregation step against table size, load and
+lane width.
 
 The stage loop (blaze_tpu/runtime/loop.py) sizes its table from two
-constants, _TRIGGER_LOAD and _TARGET_LOAD.  This is the measurement they
-were chosen from (PERF.md section 6, PR 25): one 65,536-lane batch of
-the pair cell's shape (two int64 keys, one float64 sum, 41% of the lanes
-selected, every selected row a new group) inserted into a table of S
-slots that already holds load x S groups, and the same batch with 12
-groups in all (the q06 shape).  Run it on the chip:
+constants, _TRIGGER_LOAD and _TARGET_LOAD, and the table's probe
+(blaze_tpu/parallel/stage.py hash_agg_step) narrows to 1/_NARROW_SHARE
+of a batch's lanes once the rows still unplaced fit.  This is the
+measurement they were chosen from (PERF.md section 6, PRs 25 and 29):
+
+  * `new_groups`, `12_groups`, `rehash_x4`: one 65,536-lane batch of the
+    pair cell's shape (two int64 keys, one float64 sum, 41% of the lanes
+    selected, every selected row a new group) inserted into a table of S
+    slots that already holds load x S groups; the same batch with 12
+    groups in all (the q06 shape); the table re-inserted into one of 4 S;
+  * `lanes`: the same step at 65,536 down to 4,096 lanes into one table
+    at one load: is a round's cost linear in its lanes down there?
+  * `compaction`: what the narrow phase pays before its first round (the
+    unplaced lanes' positions, then the narrow gathers), beside
+    `jnp.nonzero`.
+
+Each reading carries the probe rounds the step ran at full and at narrow
+width.  Run it on the chip:
 
     chiprun -- python3 tools/fold_grid.py
 
-It prints one JSON line per reading and writes them to
-chiprun_out/fold_grid.jsonl.  Times are host-clock medians around
-`block_until_ready`, so they mean something only on the device.
+`--tree DIR` imports blaze_tpu from DIR, a checkout of another commit
+unpacked inside this one, to read the same grid from its step (a step
+that reports no rounds reads `null` there).  It prints one JSON line per
+reading and writes them to chiprun_out/fold_grid[.<tag>].jsonl.  Times
+are host-clock medians around `block_until_ready`, so they mean
+something only on the device.
 """
 
 from __future__ import annotations
@@ -24,46 +40,43 @@ import statistics
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-import blaze_tpu  # noqa: E402,F401  (x64, the compile cache)
-from blaze_tpu.parallel.stage import (hash_agg_step, init_hash_carry,  # noqa: E402
-                                      rehash_carry)
-
 LANES = 65536
+WIDTHS = (65536, 16384, 8192, 4096)
 LIVE_SHARE = 0.41
 KINDS = ("sum",)
 REPEATS = 5
 
 
-def _step(probe_rounds):
+def _step(stage, lanes, probe_rounds):
+    import jax
+    import jax.numpy as jnp
+
     def f(carry, k1, k2, v, mask):
-        ones = jnp.ones(LANES, bool)
-        return hash_agg_step(carry, [(k1, ones), (k2, ones)],
-                             [("sum", v, None)], mask,
-                             probe_rounds=probe_rounds)
+        ones = jnp.ones(lanes, bool)
+        return stage.hash_agg_step(carry, [(k1, ones), (k2, ones)],
+                                   [("sum", v, None)], mask,
+                                   probe_rounds=probe_rounds)
     return jax.jit(f)
 
 
-def _batch(rng, live, groups=None):
+def _batch(rng, live, groups=None, lanes=LANES):
     """(k1, k2, v, mask): `live` selected lanes, each a new group unless
     `groups` bounds the key domain."""
+    import jax.numpy as jnp
+    import numpy as np
     if groups is None:
-        k1 = rng.integers(1, 1 << 40, LANES)
+        k1 = rng.integers(1, 1 << 40, lanes)
     else:
-        k1 = rng.integers(1, groups + 1, LANES)
+        k1 = rng.integers(1, groups + 1, lanes)
     k2 = (k1 % 12) + 1
-    mask = np.zeros(LANES, bool)
-    mask[rng.permutation(LANES)[:live]] = True
+    mask = np.zeros(lanes, bool)
+    mask[rng.permutation(lanes)[:live]] = True
     return (jnp.asarray(k1), jnp.asarray(k2),
-            jnp.asarray(rng.random(LANES)), jnp.asarray(mask))
+            jnp.asarray(rng.random(lanes)), jnp.asarray(mask))
 
 
 def _timed(fn, *args):
+    import jax
     out = fn(*args)
     jax.block_until_ready(out)
     times = []
@@ -75,51 +88,136 @@ def _timed(fn, *args):
     return statistics.median(times), out
 
 
+def _reading(out):
+    """overflow, groups and the rounds at each width of a step's result
+    (a step of before PR 29 returns no rounds)."""
+    rounds = [int(r) for r in out[3]] if len(out) > 3 else [None, None]
+    return dict(overflow=int(out[1]), groups_after=int(out[2]),
+                full_rounds=rounds[0], narrow_rounds=rounds[1])
+
+
+def _filled(fill, rng, carry, groups, want):
+    """The table with `want` groups or more: whole new-group batches
+    through the 256-round fill step."""
+    while groups < want:
+        out = fill(carry, *_batch(rng, min(LANES, want - groups)))
+        assert int(out[1]) == 0
+        carry, groups = out[0], int(out[2])
+    return carry, groups
+
+
+def _compaction(stage, say, rng):
+    """The narrow phase's entry alone, at the pair cell's width: 65,536
+    lanes of which 1,700 are unplaced (the worst first round of a reduce
+    task's batch), compacted to 8,192."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    width = stage.narrow_width(LANES)
+    unplaced = np.zeros(LANES, bool)
+    unplaced[rng.permutation(LANES)[:1700]] = True
+    cols = [jnp.asarray(rng.integers(1, 1 << 40, LANES)) for _ in range(3)]
+    flags = [jnp.ones(LANES, bool)] * 2
+
+    def positions(u):
+        return stage._compact_lanes(u, width)
+
+    def entry(u, cols, flags):
+        lanes = stage._compact_lanes(u, width)
+        return ([jnp.take(c, lanes, mode="clip") for c in cols],
+                [jnp.take(f, lanes, mode="clip") for f in flags])
+
+    def nonzero(u):
+        return jnp.nonzero(u, size=width, fill_value=LANES)[0]
+
+    u = jnp.asarray(unplaced)
+    for name, fn, args in (("positions", positions, (u,)),
+                           ("positions_and_gathers", entry, (u, cols, flags)),
+                           ("jnp_nonzero", nonzero, (u,))):
+        t, _ = _timed(jax.jit(fn), *args)
+        say(shape="compaction", part=name, lanes=LANES, width=width,
+            step_s=t)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--log-slots", type=int, nargs="+", default=[18, 20, 22])
+    ap.add_argument("--tree", default=None,
+                    help="import blaze_tpu from this checkout")
+    ap.add_argument("--tag", default=None,
+                    help="write chiprun_out/fold_grid.<tag>.jsonl")
     args = ap.parse_args()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.abspath(args.tree) if args.tree else root)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import blaze_tpu  # noqa: F401  (x64, the compile cache)
+    from blaze_tpu.parallel import stage
+
     dev = jax.devices()[0]
-    out_dir = "chiprun_out"
+    out_dir = os.path.join(root, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     lines = []
 
     def say(**kw):
         kw["device"] = dev.device_kind
+        kw["tree"] = args.tree or "."
         lines.append(kw)
         print(json.dumps(kw), flush=True)
 
-    step16, fill = _step(16), _step(256)
+    def fresh(slots):
+        return stage.init_hash_carry([jnp.int64, jnp.int64], KINDS,
+                                     [jnp.float64], slots)
+
+    step16, fill = _step(stage, LANES, 16), _step(stage, LANES, 256)
     for log_s in args.log_slots:
         slots = 1 << log_s
         rng = np.random.default_rng(log_s)
-        carry = init_hash_carry([jnp.int64, jnp.int64], KINDS,
-                                [jnp.float64], slots)
+        carry = fresh(slots)
         # the q06 shape: 12 groups, every lane selected
         few = _batch(rng, LANES, groups=12)
-        warm, _, _ = fill(carry, *few)
-        t, (_c, ovf, ng) = _timed(step16, warm, *few)
-        say(slots=slots, shape="12_groups", load_before=0.0, step_s=t,
-            overflow=int(ovf), groups_after=int(ng))
+        warm = fill(carry, *few)[0]
+        t, out = _timed(step16, warm, *few)
+        say(slots=slots, shape="12_groups", lanes=LANES, load_before=0.0,
+            step_s=t, **_reading(out))
         groups = 0
         for load in (0.0, 1 / 16, 1 / 8, 3 / 16, 1 / 4, 3 / 8, 1 / 2):
-            while groups < int(load * slots):
-                n = min(LANES, int(load * slots) - groups)
-                carry, ovf, ng = fill(carry, *_batch(rng, n))
-                assert int(ovf) == 0
-                groups = int(ng)
-            t, (_c, ovf, ng) = _timed(
+            carry, groups = _filled(fill, rng, carry, groups,
+                                    int(load * slots))
+            t, out = _timed(
                 step16, carry, *_batch(rng, int(LIVE_SHARE * LANES)))
-            say(slots=slots, shape="new_groups", load_before=groups / slots,
-                step_s=t, overflow=int(ovf), groups_after=int(ng))
+            say(slots=slots, shape="new_groups", lanes=LANES,
+                load_before=groups / slots, step_s=t, **_reading(out))
             if load in (1 / 8, 1 / 4) and log_s <= 20:
-                re = jax.jit(lambda c: rehash_carry(c, list(KINDS),
-                                                    4 * slots))
-                t, (_c, ovf, ng) = _timed(re, carry)
-                say(slots=slots, shape="rehash_x4",
-                    load_before=groups / slots, step_s=t, overflow=int(ovf),
-                    groups_after=int(ng))
-    with open(os.path.join(out_dir, "fold_grid.jsonl"), "w") as f:
+                re = jax.jit(lambda c: stage.rehash_carry(c, list(KINDS),
+                                                          4 * slots))
+                t, out = _timed(re, carry)
+                say(slots=slots, shape="rehash_x4", lanes=slots,
+                    load_before=groups / slots, step_s=t, **_reading(out))
+
+    # the lane-width axis: one table, two loads, every selected row a
+    # new group
+    slots = 1 << 20
+    rng = np.random.default_rng(29)
+    carry, groups = fresh(slots), 0
+    for load in (0.0, 1 / 8):
+        carry, groups = _filled(fill, rng, carry, groups,
+                                int(load * slots))
+        for lanes in WIDTHS:
+            t, out = _timed(
+                _step(stage, lanes, 16), carry,
+                *_batch(rng, int(LIVE_SHARE * lanes), lanes=lanes))
+            say(slots=slots, shape="lanes", lanes=lanes,
+                load_before=groups / slots, step_s=t, **_reading(out))
+
+    if hasattr(stage, "_compact_lanes"):
+        _compaction(stage, say, np.random.default_rng(30))
+
+    name = f"fold_grid.{args.tag}.jsonl" if args.tag else "fold_grid.jsonl"
+    with open(os.path.join(out_dir, name), "w") as f:
         for ln in lines:
             f.write(json.dumps(ln) + "\n")
     return 0
