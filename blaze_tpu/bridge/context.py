@@ -32,6 +32,21 @@ class TaskContext:
     # chunk boundary, so teardown tests can assert the loop stopped
     # within one chunk of the cancel by reading this counter.
     loop_chunks: int = 0
+    # the chip this task runs on (parallel/mesh.task_device: partition p
+    # on device p mod n of the dp mesh), or None: the process's one
+    # device, or compute pinned to the host's XLA backend, where nothing
+    # is pinned and JAX's default placement holds.  `task_scope` makes
+    # it the thread's default device, so every array the task creates is
+    # made there; `xputil.to_device` commits the task's inputs to it; a
+    # PrefetchIterator worker re-enters the scope, so the chip rides
+    # with the context.
+    device: Optional[Any] = None
+
+    @property
+    def device_id(self) -> int:
+        """The chip's id for spans and counters (0 where nothing is
+        pinned: the one device there is)."""
+        return 0 if self.device is None else int(self.device.id)
 
     def check_running(self):
         if not self.is_running():
@@ -68,18 +83,28 @@ def set_current_task(ctx: Optional[TaskContext]) -> None:
 
 
 class task_scope:
-    """`with task_scope(TaskContext(...)):` — restores the previous context."""
+    """`with task_scope(TaskContext(...)):` — restores the previous
+    context.  A task that has a chip runs inside `jax.default_device` of
+    that chip (thread-local, like the context itself)."""
 
     def __init__(self, ctx: TaskContext):
         self._ctx = ctx
         self._prev: Optional[TaskContext] = None
+        self._on_chip = None
 
     def __enter__(self) -> TaskContext:
         self._prev = getattr(_local, "ctx", None)
         _local.ctx = self._ctx
+        if self._ctx.device is not None:
+            import jax
+            self._on_chip = jax.default_device(self._ctx.device)
+            self._on_chip.__enter__()
         return self._ctx
 
     def __exit__(self, *exc):
+        if self._on_chip is not None:
+            self._on_chip.__exit__(*exc)
+            self._on_chip = None
         _local.ctx = self._prev
         return False
 

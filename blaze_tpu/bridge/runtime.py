@@ -43,11 +43,17 @@ class NativeExecutionRuntime:
         ensure_placement()  # once per process; may pin compute to host XLA
         td = decode_task_definition(task_definition)
         from blaze_tpu.bridge.context import current_query
+        from blaze_tpu.parallel.mesh import task_device
         self.task = TaskContext(
             stage_id=td.get("stage_id", 0),
             partition_id=td.get("partition_id", 0),
             num_partitions=td.get("num_partitions", 1),
             task_attempt_id=td.get("task_attempt_id", 0),
+            # a pure function of the partition id and the visible
+            # devices: a retry or a speculative attempt of this task
+            # lands on the same chip, and a warm-up pass compiles
+            # exactly what the window runs
+            device=task_device(td.get("partition_id", 0)),
             # the constructor runs on the task-pool thread inside the
             # service's query_scope: the query rides the TaskContext into
             # the producer/prefetch threads that re-enter via task_scope
@@ -93,11 +99,13 @@ class NativeExecutionRuntime:
         # arrow_batches: plans whose output is already Arrow-resident
         # (fused host agg, scans) skip the ColumnBatch round trip; the
         # base implementation is exactly the old compact().to_arrow()
-        from blaze_tpu.bridge import tracing
+        from blaze_tpu.bridge import tracing, xla_stats
+        xla_stats.note_task_placed(self.task.device_id)
         with task_scope(self.task), \
                 tracing.execution_context(stage=self.task.stage_id,
                                           partition=self.task.partition_id), \
-                tracing.span("task", mode="sync"):
+                tracing.span("task", mode="sync",
+                             device=self.task.device_id):
             stream = self.plan.arrow_batches(self.task.partition_id)
             stats = config.INPUT_BATCH_STATISTICS.get()
             for rb in stream:
@@ -113,13 +121,15 @@ class NativeExecutionRuntime:
                 yield rb
 
     def _produce(self) -> None:
-        from blaze_tpu.bridge import tracing
+        from blaze_tpu.bridge import tracing, xla_stats
+        xla_stats.note_task_placed(self.task.device_id)
         try:
             with task_scope(self.task), \
                     tracing.execution_context(
                         stage=self.task.stage_id,
                         partition=self.task.partition_id), \
-                    tracing.span("task", mode="producer"):
+                    tracing.span("task", mode="producer",
+                                 device=self.task.device_id):
                 stream = self.plan.arrow_batches(self.task.partition_id)
                 stats = config.INPUT_BATCH_STATISTICS.get()
                 for rb in stream:  # HOT LOOP (ref rt.rs:175-192)

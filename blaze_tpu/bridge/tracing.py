@@ -66,7 +66,9 @@ _CHILD_BUF_CAP = 10_000
 SPAN_NAMES: Dict[str, str] = {
     # -- spans (dur_ns > 0) -------------------------------------------
     "task": "per-partition runtime stream covering one task's operator "
-            "chain (bridge/runtime.py; mode=sync|producer)",
+            "chain (bridge/runtime.py, mode=sync|producer; plan/stages.py "
+            "for a map task through the stage loop, mode=loop; attrs "
+            "device: the id of the chip the task runs on)",
     "task_attempt": "one scheduled attempt of a task in the wave loop, "
                     "local or routed to a pool worker (bridge/tasks.py; "
                     "attrs task/attempt/what/speculative/remote)",
@@ -78,7 +80,9 @@ SPAN_NAMES: Dict[str, str] = {
     "worker_task": "child-process execution of a remote task inside a "
                    "pool worker (parallel/workers.py child_main)",
     "device_exchange": "on-device collective shuffle dispatch for one "
-                       "stage (plan/stages.py -> DeviceExchange)",
+                       "stage (plan/stages.py -> DeviceExchange; attrs "
+                       "device: the chip of the thread that drives it, "
+                       "an overlapped one its map task's; chips)",
     "rss_exchange": "remote-shuffle-service exchange tier for one stage "
                     "(plan/stages.py)",
     "shuffle_exchange": "file-tier shuffle exchange for one stage "
@@ -88,17 +92,18 @@ SPAN_NAMES: Dict[str, str] = {
     "stage_loop_chunk": "one fused device-loop chunk dispatch folding a "
                         "window of batches in a single XLA call "
                         "(runtime/loop.py; overlap vs device_exchange "
-                        "is the ROADMAP item-4 signal)",
+                        "is the ROADMAP item-4 signal; attrs device)",
     "stream_epoch": "one streaming micro-batch epoch: poll -> plan -> "
                     "window/watermark -> sink attempt -> checkpoint "
                     "commit (streaming/executor.py; attrs epoch/rows)",
     "explain_analyze": "whole-query profiled execution (plan/explain.py)",
     "d2h": "one blocking device-to-host readback: the caller waits for "
            "the value and the programs that produce it (xputil.to_host; "
-           "attrs bytes)",
+           "attrs bytes, device: the reading task's chip)",
     "h2d": "one host-to-device placement; device_put returns before the "
            "copy lands, so this is host staging and dispatch time "
-           "(xputil.to_device; attrs bytes)",
+           "(xputil.to_device; attrs bytes, device: the task's chip, "
+           "which the buffers are committed to)",
     "prefetch_wait": "the consumer blocked on a prefetch queue: the "
                      "producer thread is behind (ops/base.py "
                      "PrefetchIterator.__next__; attrs source)",

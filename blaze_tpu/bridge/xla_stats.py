@@ -37,6 +37,14 @@ _kernels: Dict[str, Dict[str, Any]] = {}
 # includes waiting for the programs that produce the value.
 _transfers = {"h2d_bytes": 0, "h2d_transfers": 0, "h2d_ns": 0,
               "d2h_bytes": 0, "d2h_transfers": 0, "d2h_wait_ns": 0}
+# Task placement (bridge/context.TaskContext.device): tasks that ran
+# under a task scope, how many of them on a chip other than 0, and bytes
+# found on another chip than the task's and moved there outside the
+# exchange's collective (xputil.on_task_chip; 0 where placement holds).
+_placement = {"placed_tasks": 0, "placed_tasks_off_chip0": 0,
+              "cross_chip_bytes": 0}
+# the same by chip: device id -> {"tasks", "h2d_bytes", "d2h_bytes"}
+_chips: Dict[int, Dict[str, int]] = {}
 # batch-shaping + IO-pipeline counters (batch.bucket_capacity /
 # ops.base.PrefetchIterator): how many capacity requests were quantized
 # onto the bucket ladder (and the padding that cost), and how often the
@@ -78,7 +86,8 @@ _stage_loop_fallback_reasons: Dict[str, int] = {}
 # collective exchange vs the host file shuffle, collective dispatches,
 # and how often the device lane bailed to the file fallback.
 _shuffle = {"shuffle_device_bytes": 0, "shuffle_host_bytes": 0,
-            "shuffle_device_rows": 0, "shuffle_device_exchanges": 0,
+            "shuffle_device_rows": 0, "shuffle_device_row_bytes": 0,
+            "shuffle_device_exchanges": 0,
             "shuffle_device_collectives": 0,
             "shuffle_device_fallbacks": 0,
             # overlapped exchange (PR 18): per-task tickets drained in
@@ -346,8 +355,12 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
     static_argnames / donate_argnums.  Each call is timed; a call during
     which the traced body executed is a compile, otherwise a cache hit.
     The device program is named after the kernel (`program_name`).
+    Under a task that has a chip, an operand found on another chip is
+    moved to the task's and counted (`xputil.on_task_chip`).
     """
     import jax
+
+    from blaze_tpu.xputil import on_task_chip
 
     kname = name or getattr(fun, "__name__", "jit_fn")
     traced = threading.local()
@@ -365,6 +378,7 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
     @functools.wraps(fun)
     def wrapper(*args, **kwargs):
         traced.hit = False
+        args, kwargs = on_task_chip((args, kwargs))
         t0 = time.perf_counter_ns()
         out = jitted(*args, **kwargs)
         dt = time.perf_counter_ns() - t0
@@ -392,22 +406,58 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
     return wrapper
 
 
-def note_h2d(nbytes: int, ns: int = 0) -> None:
+def _chip_entry(chip: int) -> Dict[str, int]:
+    entry = _chips.get(chip)
+    if entry is None:
+        entry = _chips[chip] = {"tasks": 0, "h2d_bytes": 0, "d2h_bytes": 0}
+    return entry
+
+
+def note_h2d(nbytes: int, ns: int = 0, chip: int = 0) -> None:
     if nbytes <= 0:
         return
     with _lock:
         _transfers["h2d_bytes"] += int(nbytes)
         _transfers["h2d_transfers"] += 1
         _transfers["h2d_ns"] += int(ns)
+        _chip_entry(chip)["h2d_bytes"] += int(nbytes)
 
 
-def note_d2h(nbytes: int, wait_ns: int = 0) -> None:
+def note_d2h(nbytes: int, wait_ns: int = 0, chip: int = 0) -> None:
     if nbytes <= 0:
         return
     with _lock:
         _transfers["d2h_bytes"] += int(nbytes)
         _transfers["d2h_transfers"] += 1
         _transfers["d2h_wait_ns"] += int(wait_ns)
+        _chip_entry(chip)["d2h_bytes"] += int(nbytes)
+
+
+def note_task_placed(chip: int) -> None:
+    """One task's operator chain started on `chip`."""
+    with _lock:
+        _placement["placed_tasks"] += 1
+        _placement["placed_tasks_off_chip0"] += chip != 0
+        _chip_entry(chip)["tasks"] += 1
+
+
+def note_cross_chip(nbytes: int) -> None:
+    """Bytes moved to the task's chip from another, outside the
+    collective (xputil.on_task_chip)."""
+    with _lock:
+        _placement["cross_chip_bytes"] += int(nbytes)
+
+
+def placement_stats() -> dict:
+    with _lock:
+        return dict(_placement)
+
+
+def chip_stats() -> Dict[int, Dict[str, int]]:
+    """device id -> {"tasks", "h2d_bytes", "d2h_bytes"} since the last
+    reset: what each chip was given to do."""
+    with _lock:
+        return {chip: dict(e) for chip, e in sorted(_chips.items())}
 
 
 def note_bucket(capacity: int, pad_rows: int) -> None:
@@ -728,15 +778,18 @@ def latency_histograms() -> Dict[str, Dict[str, Any]]:
 
 
 def note_device_exchange(rows: int, nbytes: int,
-                         collectives: int = 1) -> None:
+                         collectives: int = 1, row_bytes: int = 0) -> None:
     """One map->reduce repartition completed over device collectives:
     `rows` real rows exchanged, `nbytes` buffer bytes that rode the
-    all-to-all (padded send buffers — what actually moved), and the
-    number of collective ops the program issued."""
+    all-to-all (padded send buffers — what actually moved), the number
+    of collective ops the program issued, and `row_bytes`, the real
+    rows' own bytes as they ride (columns, validity, partition id, row
+    mask; no padding)."""
     with _lock:
         _shuffle["shuffle_device_exchanges"] += 1
         _shuffle["shuffle_device_rows"] += int(rows)
         _shuffle["shuffle_device_bytes"] += int(nbytes)
+        _shuffle["shuffle_device_row_bytes"] += int(row_bytes)
         _shuffle["shuffle_device_collectives"] += int(collectives)
 
 
@@ -1026,6 +1079,7 @@ def counter_families() -> Dict[str, Dict[str, int]]:
     with _lock:
         return {
             "transfers": dict(_transfers),
+            "placement": dict(_placement),
             "pipeline": dict(_pipeline),
             "exprs": dict(_exprs),
             "faults": dict(_faults),
@@ -1049,6 +1103,9 @@ def snapshot() -> dict:
     """Flat counter snapshot for before/after deltas (explain_analyze)."""
     rep = compile_report()
     flat = transfer_stats()
+    flat.update(placement_stats())
+    for chip, entry in chip_stats().items():
+        flat.update({f"chip{chip}_{k}": v for k, v in entry.items()})
     ps = pipeline_stats()
     ps.pop("bucket_capacities", None)  # list: not delta-able
     flat.update(ps)
@@ -1085,6 +1142,9 @@ def reset() -> None:
         _kernels.clear()
         for k in _transfers:
             _transfers[k] = 0
+        for k in _placement:
+            _placement[k] = 0
+        _chips.clear()
         for k in _pipeline:
             _pipeline[k] = 0
         for k in _exprs:

@@ -627,6 +627,9 @@ MESH_DEVICES = int_conf(
     "auron.tpu.mesh.devices", 0,
     "Devices in the 1-D data-parallel mesh that runs device-resident "
     "stage execution (parallel/mesh.py make_mesh).  0 = every visible "
+    "device.  The mesh sizes the device exchange AND bounds task "
+    "placement: task p of every stage runs on device p mod N of it "
+    "(parallel/mesh.py task_device), so 1 keeps every task on the first "
     "device.  On CPU hosts, XLA_FLAGS="
     "--xla_force_host_platform_device_count=N provides N virtual "
     "devices for the same code path.", category="scale-out")

@@ -5,7 +5,11 @@ init, `:82` register_consumer, `:202` `MemConsumer` trait — update_mem_used
 triggers spill() of the biggest consumer when the pool overflows).
 
 TPU mapping: the budget models DEVICE HBM held by operator state (sort runs,
-agg tables, join build sides, shuffle staging).  Spill tiers mirror the
+agg tables, join build sides, shuffle staging), and is a budget PER CHIP:
+a consumer is charged to the chip its task runs on (bridge/context
+TaskContext.device), and pressure, the fair cap and the choice of what
+to shed are all taken among the consumers of that chip.  With one chip
+that is every consumer, as before.  Spill tiers mirror the
 reference's Spill abstraction (ref auron-memmgr/src/spill.rs:89
 try_new_spill: JVM on-heap if available else disk): here tier 1 is host RAM
 (the "on-heap" analog — device arrays become numpy/Arrow buffers), tier 2 is
@@ -63,6 +67,9 @@ class MemConsumer:
         #: None for standalone consumers.  Lets the manager arbitrate
         #: ACROSS queries and enforce per-query quotas.
         self.query = None
+        #: id of the chip whose budget this consumer's state is charged
+        #: to: its task's (captured at set_spillable time)
+        self.chip = 0
         self.spill_metrics = SpillMetrics()
         # owning operator's MetricNode; when set, retained-byte peaks are
         # recorded there as `mem_used` (baseline metric vocabulary).  A
@@ -75,9 +82,10 @@ class MemConsumer:
         return self._mem_used
 
     def set_spillable(self, manager: "MemManager") -> None:
-        from blaze_tpu.bridge.context import active_query
+        from blaze_tpu.bridge.context import active_query, current_task
         if self.query is None:
             self.query = active_query()
+        self.chip = current_task().device_id
         self._manager = manager
         manager.register_consumer(self)
 
@@ -113,7 +121,8 @@ class MemConsumer:
 
 
 class MemManager:
-    """Process-wide budget over registered consumers (ref lib.rs:38)."""
+    """Process-wide manager of one budget a chip over registered
+    consumers (ref lib.rs:38).  `total` is what ONE chip may hold."""
 
     _instance: Optional["MemManager"] = None
     _instance_lock = threading.Lock()
@@ -164,10 +173,19 @@ class MemManager:
         with self._lock:
             return sum(c.mem_used for c in self._consumers)
 
-    def consumer_cap(self) -> int:
-        """Fair per-consumer cap: total / max(1, N) (ref lib.rs fair share)."""
+    def _on_chip(self, chip: int) -> List[MemConsumer]:
+        return [c for c in self._consumers if c.chip == chip]
+
+    def chip_used(self, chip: int) -> int:
+        """Bytes the consumers charged to `chip` retain."""
         with self._lock:
-            return self.total // max(1, len(self._consumers))
+            return sum(c.mem_used for c in self._on_chip(chip))
+
+    def consumer_cap(self, chip: int = 0) -> int:
+        """Fair per-consumer cap on one chip: total / max(1, N there)
+        (ref lib.rs fair share)."""
+        with self._lock:
+            return self.total // max(1, len(self._on_chip(chip)))
 
     # -- pressure handling -------------------------------------------------
     def on_mem_updated(self, updated: MemConsumer) -> None:
@@ -190,8 +208,11 @@ class MemManager:
             used = self.mem_used
             if used > self.peak_used:
                 self.peak_used = used
-            overflow = used - self.total
-            cap = self.consumer_cap()
+            # the budget is the chip's: only what is charged to the
+            # updating consumer's chip can press on it or be shed for it
+            chip = updated.chip
+            overflow = self.chip_used(chip) - self.total
+            cap = self.consumer_cap(chip)
             # chaos hook: a scripted mem-pressure fault spills the
             # updating consumer as if the pool had overflowed (exercises
             # the spill / re-read path without a real over-budget
@@ -224,8 +245,8 @@ class MemManager:
             # rather than shed its lighter self while the hog's release
             # is pending.
             upd_q = getattr(updated, "query", None)
-            for c in self._arbitration_order():
-                if self.mem_used <= self.total * MEM_SPILL_FACTOR:
+            for c in self._arbitration_order(chip):
+                if self.chip_used(chip) <= self.total * MEM_SPILL_FACTOR:
                     break
                 if c.mem_used == 0:
                     continue
@@ -255,14 +276,18 @@ class MemManager:
         self.shed_bytes_by_query[qid] = (
             self.shed_bytes_by_query.get(qid, 0) + released)
 
-    def _arbitration_order(self) -> List[MemConsumer]:
-        """Consumers ordered heaviest-query-first, then biggest-first.
+    def _arbitration_order(self, chip: Optional[int] = None
+                           ) -> List[MemConsumer]:
+        """Consumers (of one chip, where given) ordered heaviest-query-
+        first, then biggest-first.
 
         Standalone consumers (no query) form singleton groups, which
         preserves the single-query behaviour: biggest consumer first.
         """
+        consumers = (self._consumers if chip is None
+                     else self._on_chip(chip))
         totals: Dict[object, int] = {}
-        for c in self._consumers:
+        for c in consumers:
             q = getattr(c, "query", None)
             key = id(q) if q is not None else ("solo", id(c))
             totals[key] = totals.get(key, 0) + c.mem_used
@@ -272,7 +297,7 @@ class MemManager:
             key = id(q) if q is not None else ("solo", id(c))
             return (-totals[key], -c.mem_used)
 
-        return sorted(self._consumers, key=order)
+        return sorted(consumers, key=order)
 
     def _enforce_query_quota(self, updated: MemConsumer) -> None:
         """Per-query quota: shed the breaching query's own state largest-
@@ -325,21 +350,26 @@ class MemManager:
                                 sorted(self.shed_bytes_by_query.items()))
                 lines.append(f"  shed_by_query: {shed}")
             for c in self._consumers:
-                lines.append(f"  {c.name}: used={c.mem_used}")
+                lines.append(f"  {c.name}: chip={c.chip} "
+                             f"used={c.mem_used}")
             return "\n".join(lines)
 
 
 def default_budget_bytes() -> int:
-    """HBM budget: device memory * memory fraction (the executor-overhead ×
-    fraction formula of the reference, NativeHelper.scala:51-73)."""
-    import jax
+    """HBM budget of one chip: device memory * memory fraction (the
+    executor-overhead × fraction formula of the reference,
+    NativeHelper.scala:51-73).  Every device that tasks are placed on is
+    asked (parallel/mesh.current_mesh); the smallest answer holds for
+    each."""
+    from blaze_tpu.parallel.mesh import current_mesh
     frac = config.MEMORY_FRACTION.get()
     # memory_stats() is None on the CPU backend and a dict with
     # bytes_limit on a TPU (16.9e9 on a v5e); an accelerator that cannot
     # report it is an error, not a 4 GiB host budget in silence
-    stats = jax.devices()[0].memory_stats()
-    if stats and "bytes_limit" in stats:
-        return int(stats["bytes_limit"] * frac)
+    limits = [(d.memory_stats() or {}).get("bytes_limit")
+              for d in current_mesh().devices.reshape(-1)]
+    if all(limits):
+        return int(min(limits) * frac)
     # CPU backend: host memory bounded by the process-RSS fraction
     # (ref auron.process.vmrss.memoryFraction), nominally capped at 4 GiB
     try:

@@ -50,6 +50,27 @@ def current_mesh() -> Mesh:
     return m
 
 
+def task_device(partition_id: int):
+    """The chip that task `partition_id` of any stage runs on: device
+    p mod n of the dp mesh, so `auron.tpu.mesh.devices` bounds task
+    placement as it sizes the exchange, and reduce partition r runs
+    where the exchange's collective left it (`_exchange_program`: r mod
+    n).  None where nothing is to be pinned: a mesh of one device, or
+    compute pinned to the host's XLA backend beside an accelerator
+    (bridge/placement), whose default device is not in the mesh.  Where
+    tasks do get chips, the chips share each program's compilation
+    (bridge/compile_share)."""
+    from blaze_tpu.bridge import compile_share
+    from blaze_tpu.bridge.placement import placement_info
+    devices = current_mesh().devices.reshape(-1)
+    info = placement_info()
+    if len(devices) == 1 or (info is not None and info.device_kind
+                             != devices[0].platform):
+        return None
+    compile_share.install()
+    return devices[int(partition_id) % len(devices)]
+
+
 def shard_rows(mesh: Mesh, *arrays: jax.Array):
     """Shard row-dimension arrays across the dp axis."""
     sharding = NamedSharding(mesh, P(DP_AXIS))

@@ -606,18 +606,11 @@ def _exchange_program(mesh, n_out: int, capacity: int,
     return meter_jit(sharded, name="mesh.exchange_rows")
 
 
-def _pad_rows(a, total: int, dtype=None):
-    """Zero-pad one column to `total` rows.  Host (numpy) input pads in
-    numpy; device (jax) input — the stage loop's D2D drain — pads with
-    jnp.pad so it never leaves the device."""
-    n = int(a.shape[0])
-    if isinstance(a, np.ndarray):
-        buf = np.zeros(total, dtype=dtype or a.dtype)
-        buf[:n] = a
-        return buf
-    import jax.numpy as jnp
-    out = jnp.pad(a, (0, total - n))
-    return out.astype(dtype) if dtype is not None else out
+def _pad_rows(a: np.ndarray, total: int, dtype=None) -> np.ndarray:
+    """One host column, zero-padded to `total` rows."""
+    buf = np.zeros(total, dtype=dtype or a.dtype)
+    buf[:int(a.shape[0])] = a
+    return buf
 
 
 class ExchangeTicket:
@@ -686,12 +679,13 @@ class DeviceExchange:
         ladder rungs, fire the per-shard fault sites, and dispatch the
         first rung's cached program.  Returns immediately — jax
         dispatch is async, so the returned ticket's `out` arrays are
-        device futures the collective is still filling."""
-        import time as _time
+        device futures the collective is still filling.
 
-        from blaze_tpu import config, faults
-        from blaze_tpu.batch import bucket_capacity, bucket_ladder
-        from blaze_tpu.parallel.collective import exchange_wire_cost
+        `columns` of one common length are cut evenly over the mesh
+        (host columns: the staged collect).  Device columns of map
+        tasks go through `dispatch_placed`, which leaves them on the
+        chips they lie on."""
+        from blaze_tpu.batch import bucket_capacity
         from blaze_tpu.parallel.mesh import DP_AXIS, shard_rows
 
         ncols = len(columns)
@@ -699,15 +693,9 @@ class DeviceExchange:
             raise DeviceExchangeError("no columns to exchange")
         n = int(len(columns[0]))
         n_dev = int(self.mesh.shape[DP_AXIS])
+        dtypes = tuple(np.dtype(c.dtype).name for c in columns)
         if n == 0:
-            parts = [([np.zeros(0, c.dtype) for c in columns],
-                      [np.zeros(0, dtype=bool) for _ in columns])
-                     for _ in range(n_out)]
-            return ExchangeTicket(parts=parts, n=0, ncols=ncols,
-                                  n_out=int(n_out), n_dev=n_dev,
-                                  ctx=ctx, rungs=[], moved_bytes=0,
-                                  collectives=0,
-                                  dispatch_ns=_time.perf_counter_ns())
+            return self._empty_ticket(dtypes, n_out, ctx)
 
         # pad to n_dev * rows_per_dev so NamedSharding splits evenly;
         # padding rows carry row_valid=False and are never sent
@@ -717,7 +705,107 @@ class DeviceExchange:
         row_valid[:n] = True
         datas = [_pad_rows(c, total) for c in columns]
         vbufs = [_pad_rows(v, total, dtype=bool) for v in valids]
+        row_valid, *rest = shard_rows(self.mesh, row_valid, *datas, *vbufs)
+        return self._fire(row_valid, rest[:ncols], rest[ncols:], n,
+                          rows_per_dev, dtypes, key_indices, n_out, ctx)
 
+    def dispatch_placed(self, tasks, key_indices: Sequence[int],
+                        n_out: int, ctx: str = "") -> ExchangeTicket:
+        """`dispatch` for map output that lies on the mesh already:
+        `tasks` are (datas, valids, n) device columns, one entry a map
+        task, each on the chip its task ran on.  The exchange's operands
+        are assembled from one shard a chip, where the rows lie: tasks
+        that share a chip are concatenated there in the order given,
+        every chip is padded there to the common `rows_per_dev` (a chip
+        that ran no task holds padding only), and the shards are joined
+        into global arrays without a copy.  The collective is then the
+        only place a row changes chip.  A reduce partition's rows come
+        out by (source chip, task on that chip, row)."""
+        from jax.sharding import NamedSharding, PartitionSpec as PS
+
+        from blaze_tpu.batch import bucket_capacity
+        from blaze_tpu.parallel.mesh import DP_AXIS
+        from blaze_tpu.xputil import on_task_chip
+
+        tasks = [t for t in tasks if t[2] > 0]
+        if not tasks:
+            raise DeviceExchangeError("no columns to exchange")
+        ncols = len(tasks[0][0])
+        dtypes = tuple(np.dtype(c.dtype).name for c in tasks[0][0])
+        devices = list(self.mesh.devices.reshape(-1))
+        by_chip = {d: [] for d in devices}
+        for task in tasks:
+            by_chip[devices[self.chip_of(task[0])]].append(task)
+        rows = {d: sum(t[2] for t in ts) for d, ts in by_chip.items()}
+        rows_per_dev = bucket_capacity(max(rows.values()))
+        dtype_of = [np.dtype(d) for d in dtypes]
+        shards = []   # per chip: [row_valid, datas..., valids...]
+        for dev in devices:
+            with jax.default_device(dev):
+                held = by_chip[dev]
+                if not held:
+                    shards.append(
+                        [jnp.zeros(rows_per_dev, bool)]
+                        + [jnp.zeros(rows_per_dev, dt) for dt in dtype_of]
+                        + [jnp.zeros(rows_per_dev, bool)] * ncols)
+                    continue
+                # a column found on another chip is moved, and counted
+                held = on_task_chip(held, dev)
+                cols = [[t[0][i] for t in held] for i in range(ncols)]
+                vals = [[t[1][i] for t in held] for i in range(ncols)]
+                pad = rows_per_dev - rows[dev]
+                shards.append(
+                    [jnp.arange(rows_per_dev) < rows[dev]]
+                    + [jnp.pad(c[0] if len(c) == 1 else jnp.concatenate(c),
+                               (0, pad)) for c in cols]
+                    + [jnp.pad(v[0] if len(v) == 1 else jnp.concatenate(v),
+                               (0, pad)).astype(bool) for v in vals])
+        sharding = NamedSharding(self.mesh, PS(DP_AXIS))
+        total = len(devices) * rows_per_dev
+        row_valid, *rest = [
+            jax.make_array_from_single_device_arrays(
+                (total,), sharding, [sh[k] for sh in shards])
+            for k in range(1 + 2 * ncols)]
+        return self._fire(row_valid, rest[:ncols], rest[ncols:],
+                          sum(rows.values()), rows_per_dev, dtypes,
+                          key_indices, n_out, ctx)
+
+    def chip_of(self, datas) -> int:
+        """Where on the mesh a task's device columns lie: the position
+        of their chip, by the first column.  Columns that lie on no chip
+        of the mesh count for the first, which takes them (and
+        `dispatch_placed` counts the move)."""
+        where = next(iter(datas[0].sharding.device_set))
+        devices = list(self.mesh.devices.reshape(-1))
+        return devices.index(where) if where in devices else 0
+
+    def _empty_ticket(self, dtypes, n_out: int, ctx: str) -> ExchangeTicket:
+        import time as _time
+
+        from blaze_tpu.parallel.mesh import DP_AXIS
+        parts = [([np.zeros(0, d) for d in dtypes],
+                  [np.zeros(0, dtype=bool) for _ in dtypes])
+                 for _ in range(n_out)]
+        return ExchangeTicket(parts=parts, n=0, ncols=len(dtypes),
+                              n_out=int(n_out),
+                              n_dev=int(self.mesh.shape[DP_AXIS]),
+                              ctx=ctx, rungs=[], moved_bytes=0,
+                              collectives=0,
+                              dispatch_ns=_time.perf_counter_ns())
+
+    def _fire(self, row_valid, datas, vbufs, n: int, rows_per_dev: int,
+              dtypes, key_indices: Sequence[int], n_out: int,
+              ctx: str) -> ExchangeTicket:
+        """The capacity ladder, the fault sites and the first rung's
+        dispatch, over operands that lie sharded on the mesh."""
+        import time as _time
+
+        from blaze_tpu import config, faults
+        from blaze_tpu.batch import bucket_capacity, bucket_ladder
+        from blaze_tpu.parallel.collective import exchange_wire_cost
+        from blaze_tpu.parallel.mesh import DP_AXIS
+
+        n_dev = int(self.mesh.shape[DP_AXIS])
         # capacity ladder: start at skew * expected rows/destination,
         # retry the next rung on overflow; rows_per_dev (= every local
         # row routed to ONE destination) is the guaranteed-fit ceiling
@@ -731,8 +819,6 @@ class DeviceExchange:
             rungs.append(bucket_capacity(rows_per_dev))
 
         key_idx = tuple(int(i) for i in key_indices)
-        dtypes = tuple(np.dtype(c.dtype).name for c in columns)
-
         cap = rungs[0]
         # the scripted mid-collective kill: one decision per shard
         # per dispatch, so `device-collective@k` targets shard k-1
@@ -740,14 +826,14 @@ class DeviceExchange:
             faults.maybe_fail("device-collective", shard=d, stage=ctx)
         fn = _exchange_program(self.mesh, int(n_out), int(cap),
                                key_idx, dtypes)
-        out = fn(*shard_rows(self.mesh, row_valid, *datas, *vbufs))
+        out = fn(row_valid, *datas, *vbufs)
         moved_bytes, collectives = exchange_wire_cost(n_dev, cap, dtypes)
         return ExchangeTicket(
             out=out, rungs=list(rungs[1:]), row_valid=row_valid,
-            datas=datas, vbufs=vbufs, key_idx=key_idx, dtypes=dtypes,
-            n=n, ncols=ncols, n_out=int(n_out), n_dev=n_dev,
-            rows_per_dev=rows_per_dev, ctx=ctx, moved_bytes=moved_bytes,
-            collectives=collectives,
+            datas=list(datas), vbufs=list(vbufs), key_idx=key_idx,
+            dtypes=dtypes, n=n, ncols=len(dtypes), n_out=int(n_out),
+            n_dev=n_dev, rows_per_dev=rows_per_dev, ctx=ctx,
+            moved_bytes=moved_bytes, collectives=collectives,
             dispatch_ns=_time.perf_counter_ns())
 
     def drain(self, ticket: ExchangeTicket):
@@ -759,7 +845,6 @@ class DeviceExchange:
         from blaze_tpu import faults
         from blaze_tpu.bridge import xla_stats
         from blaze_tpu.parallel.collective import exchange_wire_cost
-        from blaze_tpu.parallel.mesh import shard_rows
 
         if ticket.parts is not None:
             return ticket.parts
@@ -779,8 +864,7 @@ class DeviceExchange:
                                   stage=ticket.ctx)
             fn = _exchange_program(self.mesh, n_out, int(cap),
                                    ticket.key_idx, ticket.dtypes)
-            out = fn(*shard_rows(self.mesh, ticket.row_valid,
-                                 *ticket.datas, *ticket.vbufs))
+            out = fn(ticket.row_valid, *ticket.datas, *ticket.vbufs)
             mb, cc = exchange_wire_cost(ticket.n_dev, cap, ticket.dtypes)
             ticket.moved_bytes += mb
             ticket.collectives += cc
@@ -788,8 +872,11 @@ class DeviceExchange:
             raise DeviceExchangeError(
                 f"destination bucket overflow persisted through the "
                 f"ladder (rows_per_dev={ticket.rows_per_dev})")
+        # a row as it rides: one device, one slot
+        row_bytes, _ = exchange_wire_cost(1, 1, ticket.dtypes)
         xla_stats.note_device_exchange(ticket.n, ticket.moved_bytes,
-                                       ticket.collectives)
+                                       ticket.collectives,
+                                       ticket.n * row_bytes)
 
         result = to_host(list(result[:2 * ncols + 2]))
         out_cols = result[:ncols]

@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import pyarrow as pa
 
+from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.metrics import MetricNode
 from blaze_tpu.bridge.resource import put_resource, remove_resource
 from blaze_tpu.faults import FetchFailedError, InjectedFault
@@ -200,6 +201,9 @@ class DagScheduler:
         # (sid, map_id) -> times the task body ran; lineage-recovery
         # tests assert exactly ONE map task re-ran after a poisoned block
         self.task_runs: Dict[tuple, int] = {}
+        # (sid, partition) -> id of the chip the task last ran on (0
+        # where nothing is pinned); tasks run by pool workers are absent
+        self.task_chips: Dict[tuple, int] = {}
         # speculation: monotone per-(sid, map) attempt-id allocator (each
         # retry OR speculative duplicate gets a fresh id), and the table
         # of WINNING attempt ids — lineage recovery and crash
@@ -236,12 +240,16 @@ class DagScheduler:
         self._stats_dur0: Dict[str, int] = {}
         self._stats_t0: float = 0.0
 
-    def _record_task_metrics(self, sid: int, tree: MetricNode) -> None:
+    def _record_task_metrics(self, sid: int, tree: MetricNode,
+                             task=None) -> None:
+        """`task`: the TaskContext of a task that ran in this process."""
         from blaze_tpu.bridge import profiling
         with self._metrics_lock:
             merged = self.stage_metrics.setdefault(
                 sid, MetricNode(name=tree.name))
             merged.merge_from(tree)
+            if task is not None:
+                self.task_chips[(sid, task.partition_id)] = task.device_id
         profiling.record_metrics(tree.to_dict())
         from blaze_tpu.plan import statstore
         if statstore.enabled():
@@ -507,7 +515,8 @@ class DagScheduler:
             for _ in rt.batches():
                 pass
         finally:
-            self._record_task_metrics(stage.sid, rt.finalize())
+            self._record_task_metrics(stage.sid, rt.finalize(),
+                                      rt.task)
         with self._metrics_lock:
             self.task_runs[(stage.sid, m)] = \
                 self.task_runs.get((stage.sid, m), 0) + 1
@@ -888,7 +897,8 @@ class DagScheduler:
         try:
             out = list(rt.batches())
         finally:
-            self._record_task_metrics(stage.sid, rt.finalize())
+            self._record_task_metrics(stage.sid, rt.finalize(),
+                                      rt.task)
         with self._metrics_lock:
             self.task_runs[(stage.sid, m)] = \
                 self.task_runs.get((stage.sid, m), 0) + 1
@@ -919,8 +929,13 @@ class DagScheduler:
         from blaze_tpu.bridge import tracing, xla_stats
         from blaze_tpu.bridge.context import task_scope
         from blaze_tpu.runtime import loop as device_loop
+        xla_stats.note_task_placed(rt.task.device_id)
         try:
-            with task_scope(rt.task):
+            with task_scope(rt.task), \
+                    tracing.execution_context(stage=stage.sid,
+                                              partition=m), \
+                    tracing.span("task", mode="loop",
+                                 device=rt.task.device_id):
                 carry = device_loop.run_partition(prog, m,
                                                   ctx=str(stage.sid))
                 out = device_loop.drain_device(prog, carry)
@@ -936,7 +951,8 @@ class DagScheduler:
                             task=m, reason=str(e))
             return None
         finally:
-            self._record_task_metrics(stage.sid, rt.finalize())
+            self._record_task_metrics(stage.sid, rt.finalize(),
+                                      rt.task)
         with self._metrics_lock:
             self.task_runs[(stage.sid, m)] = \
                 self.task_runs.get((stage.sid, m), 0) + 1
@@ -945,20 +961,13 @@ class DagScheduler:
     @staticmethod
     def _merge_map_outputs(batches: List[pa.RecordBatch], col_tasks,
                            schema):
-        """Per-task map outputs -> one (cols, valids) column set for the
-        exchange.  All-loop output stays as device arrays (D2D: the
-        exchange shards them without a host round trip); any staged
-        batches force the host concat path."""
+        """Per-task map outputs -> one host (cols, valids) column set
+        for the exchange: the staged batches, then the loop tasks'
+        device columns read back.  (A wave of loop tasks only never
+        comes here: its columns stay where they lie,
+        `DeviceExchange.dispatch_placed`.)"""
         import numpy as np
         from blaze_tpu.xputil import asnp
-        if col_tasks and not batches:
-            import jax.numpy as jnp
-            ncols = len(col_tasks[0][0])
-            cols = [jnp.concatenate([t[0][i] for t in col_tasks])
-                    for i in range(ncols)]
-            valids = [jnp.concatenate([t[1][i] for t in col_tasks])
-                      for i in range(ncols)]
-            return cols, valids
         cols, valids = _batches_to_columns(batches, schema)
         for datas, vls, _n in col_tasks:
             for i, (d, v) in enumerate(zip(datas, vls)):
@@ -1007,11 +1016,22 @@ class DagScheduler:
         loop_tasks = sum(1 for kind, _o in per_task if kind == "cols")
         blocks: Dict[int, bytes] = {}
         if batches or col_tasks:
+            exchange = DeviceExchange()
             with tracing.span("device_exchange", stage=stage.sid,
-                              tasks=stage.num_tasks, partitions=n_out):
-                cols, valids = self._merge_map_outputs(batches,
-                                                       col_tasks, schema)
-                est = sum(int(c.nbytes) for c in cols)
+                              tasks=stage.num_tasks, partitions=n_out,
+                              device=current_task().device_id,
+                              chips=exchange.mesh.size):
+                # a wave of loop tasks only: the exchange starts from
+                # the chips their columns lie on; any staged batch
+                # forces the host concat
+                placed = bool(col_tasks) and not batches
+                if placed:
+                    est = sum(int(c.nbytes) for t in col_tasks
+                              for c in t[0])
+                else:
+                    cols, valids = self._merge_map_outputs(
+                        batches, col_tasks, schema)
+                    est = sum(int(c.nbytes) for c in cols)
                 if est > config.SHUFFLE_DEVICE_MAX_BYTES.get():
                     raise DeviceExchangeError(
                         f"map output {est}B exceeds "
@@ -1019,9 +1039,13 @@ class DagScheduler:
                 if done_ns:
                     xla_stats.note_barrier_idle(
                         max(0, _time.perf_counter_ns() - min(done_ns)))
-                parts = DeviceExchange().exchange(
-                    cols, valids, spec["key_indices"], n_out,
-                    ctx=str(stage.sid))
+                ticket = (exchange.dispatch_placed(
+                    col_tasks, spec["key_indices"], n_out,
+                    ctx=str(stage.sid)) if placed
+                    else exchange.dispatch(
+                        cols, valids, spec["key_indices"], n_out,
+                        ctx=str(stage.sid)))
+                parts = exchange.drain(ticket)
                 arrow_schema = schema.to_arrow()
                 for r, (datas, vls) in enumerate(parts):
                     if datas and len(datas[0]):
@@ -1049,8 +1073,9 @@ class DagScheduler:
             the drainer thread is always joined (leak_report clean);
           * assembly concatenates per-partition rows in the synchronous
             merge order (staged-batch tasks by task index, then
-            device-col tasks) and encodes ONE RecordBatch per
-            partition, so published blocks are byte-identical.
+            device-col tasks; a wave of device-col tasks only by chip,
+            then task) and encodes ONE RecordBatch per partition, so
+            published blocks are byte-identical.
         """
         import queue as _queue
         import time as _time
@@ -1089,8 +1114,8 @@ class DagScheduler:
                     tracing.emit_span(
                         "device_exchange",
                         _time.perf_counter_ns() - ticket.dispatch_ns,
-                        stage=stage.sid, task=key[1], partitions=n_out,
-                        overlapped=True)
+                        stage=stage.sid, task=key[-1], partitions=n_out,
+                        overlapped=True, device=key[-2])
                     xla_stats.note_exchange_overlap()
                     with lock:
                         parts_by_task[key] = parts
@@ -1135,9 +1160,12 @@ class DagScheduler:
                     raise DeviceExchangeError(
                         f"map output {est}B exceeds "
                         f"auron.tpu.shuffle.device.maxBytes")
-                ticket = exchange.dispatch(cols, valids,
-                                           spec["key_indices"], n_out,
-                                           ctx=str(stage.sid))
+                ticket = (exchange.dispatch_placed(
+                    [(cols, valids, nrows)], spec["key_indices"], n_out,
+                    ctx=str(stage.sid)) if kind == "cols"
+                    else exchange.dispatch(
+                        cols, valids, spec["key_indices"], n_out,
+                        ctx=str(stage.sid)))
                 with idle:
                     if state["first_dispatch"] is None:
                         state["first_dispatch"] = ticket.dispatch_ns
@@ -1147,7 +1175,8 @@ class DagScheduler:
                 # for the LAST straggler before its one merged exchange
                 xla_stats.note_barrier_idle(
                     max(0, ticket.dispatch_ns - fold_end))
-                q.put(((rank, m), ticket))
+                chip = exchange.chip_of(cols) if kind == "cols" else 0
+                q.put(((rank, chip, m), ticket))
             except BaseException as e:
                 slots.release()
                 with lock:
@@ -1172,7 +1201,12 @@ class DagScheduler:
         loop_tasks = sum(1 for kind, _o in per_task if kind == "cols")
 
         blocks: Dict[int, bytes] = {}
-        keys = sorted(parts_by_task)  # sync merge order
+        # sync merge order: staged tasks by task index, then loop tasks;
+        # a wave of loop tasks only by (chip, task), as the exchange
+        # that starts from the chips (dispatch_placed) leaves them
+        keys = sorted(parts_by_task)
+        if any(rank == 0 for rank, _chip, _m in keys):
+            keys.sort(key=lambda k: (k[0], k[2]))
         if keys:
             arrow_schema = schema.to_arrow()
             base = parts_by_task[keys[0]]
@@ -1278,7 +1312,8 @@ class DagScheduler:
                     for _ in rt.batches():
                         pass
                 finally:
-                    self._record_task_metrics(stage.sid, rt.finalize())
+                    self._record_task_metrics(stage.sid, rt.finalize(),
+                                              rt.task)
                 if not writer.commit():
                     # a sibling attempt already committed: this output
                     # is dead (reject-late arbitration); the task still
@@ -1662,6 +1697,7 @@ class DagScheduler:
             self._query.check()  # shed before any work if already overdue
         self.stage_metrics = {}  # instance may be reused per query
         self.task_runs = {}
+        self.task_chips = {}
         threshold = config.DAG_SINGLE_TASK_BYTES.get()
         if threshold > 0 and self._scan_input_bytes(plan) <= threshold:
             self.exec_mode = "local"
@@ -1712,7 +1748,8 @@ class DagScheduler:
                 try:
                     return list(rt.batches())
                 finally:
-                    self._record_task_metrics(result.sid, rt.finalize())
+                    self._record_task_metrics(result.sid, rt.finalize(),
+                                              rt.task)
 
             # bounded lineage recovery: a FetchFailedError anywhere in
             # the DAG names the producer map task whose output is

@@ -105,6 +105,7 @@ class StageLoopFallback(RuntimeError):
 # fingerprint -> jit'd chunk fold or pass-through; bounded FIFO like
 # fused's step caches
 _FOLD_CACHE: dict = {}
+_FOLD_CACHE_LOCK = threading.Lock()
 _FOLD_LIMIT = 128
 
 # -- regrow fences (overlapped exchange) ------------------------------------
@@ -143,12 +144,16 @@ def _run_fences() -> None:
 
 
 def _cached(skey, build):
-    fn = _FOLD_CACHE.get(skey)
-    if fn is None:
-        if len(_FOLD_CACHE) >= _FOLD_LIMIT:
-            _FOLD_CACHE.pop(next(iter(_FOLD_CACHE)))
-        fn = _FOLD_CACHE[skey] = build()
-    return fn
+    # under a lock: a wave's tasks ask for the same program at the same
+    # moment, and each must get the ONE wrapper whose traces the next
+    # query finds again
+    with _FOLD_CACHE_LOCK:
+        fn = _FOLD_CACHE.get(skey)
+        if fn is None:
+            if len(_FOLD_CACHE) >= _FOLD_LIMIT:
+                _FOLD_CACHE.pop(next(iter(_FOLD_CACHE)))
+            fn = _FOLD_CACHE[skey] = build()
+        return fn
 
 
 def _batch_of(cols_stacked, b):
@@ -375,7 +380,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
             faults.maybe_fail("device-loop", stage=ctx, chunk=ci)
             with tracing.span("stage_loop_chunk", stage=ctx,
                               partition=partition, chunk=ci,
-                              batches=count):
+                              batches=count, device=task.device_id):
                 # selected lanes per batch: before the chain's filter,
                 # so an upper bound on the rows the fold will insert
                 batch_rows = to_host(jnp.sum(masks, axis=1)).tolist()

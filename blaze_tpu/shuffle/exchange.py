@@ -17,7 +17,8 @@ from typing import List, Optional
 
 import numpy as np
 
-from blaze_tpu.bridge.context import TaskContext, task_scope
+from blaze_tpu.bridge.context import (TaskContext, current_task,
+                                      task_scope)
 from blaze_tpu.bridge.resource import put_resource, remove_resource
 from blaze_tpu.faults import FetchFailedError
 from blaze_tpu.ops.base import ExecutionPlan
@@ -108,9 +109,11 @@ class LocalShuffleExchange(ExecutionPlan):
             index = data.replace(".data", ".index")
             writer = ShuffleWriterExec(child, self.partitioning, data, index)
             writer.metrics = self.metrics  # surface write metrics here
+            # a map task inside the reducing task: on the reducer's chip
             with task_scope(TaskContext(stage_id=self.stage_id,
                                         partition_id=map_id,
-                                        num_partitions=child.num_partitions)):
+                                        num_partitions=child.num_partitions,
+                                        device=current_task().device)):
                 list(writer.execute(map_id))
             self._map_outputs.append((data, read_index_file(
                 index,

@@ -221,7 +221,7 @@ def test_to_host_counts_bytes_transfers_and_blocked_time(traced):
     assert d["d2h_transfers"] == 1
     assert d["d2h_wait_ns"] > 0
     (span,) = _named("d2h")
-    assert span["attrs"] == {"bytes": 8016}
+    assert span["attrs"] == {"bytes": 8016, "device": 0}
     assert span["dur_ns"] <= d["d2h_wait_ns"]
 
 
@@ -246,7 +246,7 @@ def test_to_device_counts_bytes_and_time(traced):
     assert d["h2d_bytes"] == 900 and d["h2d_transfers"] == 1
     assert d["h2d_ns"] > 0
     (span,) = _named("h2d")
-    assert span["attrs"] == {"bytes": 900}
+    assert span["attrs"] == {"bytes": 900, "device": 0}
 
 
 def test_batch_placement_goes_through_the_helper(monkeypatch, traced):

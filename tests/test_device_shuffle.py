@@ -153,10 +153,32 @@ def _multiset(datas, valids):
                   for i in range(len(k)))
 
 
-def test_device_exchange_routes_like_host_hash(device_mesh):
+LAYOUTS = ["host", "spread"]
+
+
+def _exchange(mesh, layout, cols, valids, key_idx, n_out):
+    """`host`: the columns as one host set, cut evenly over the mesh.
+    `spread`: the same rows as the output of one map task a device,
+    lying on that device (ragged: task t holds t+1 shares), taken where
+    they lie."""
+    ex = DeviceExchange(mesh)
+    if layout == "host":
+        return ex.exchange(cols, valids, key_idx, n_out)
+    import jax
+    devices = list(mesh.devices.reshape(-1))
+    shares = np.cumsum([0] + [t + 1 for t in range(len(devices))])
+    cuts = (shares * len(cols[0])) // shares[-1]
+    tasks = [([jax.device_put(c[lo:hi], d) for c in cols],
+              [jax.device_put(v[lo:hi], d) for v in valids], int(hi - lo))
+             for d, lo, hi in zip(devices, cuts[:-1], cuts[1:])]
+    return ex.drain(ex.dispatch_placed(tasks, key_idx, n_out))
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_device_exchange_routes_like_host_hash(device_mesh, layout):
     cols, valids = _kv_columns()
     xla_stats.reset()
-    parts = DeviceExchange(device_mesh).exchange(cols, valids, [0], 3)
+    parts = _exchange(device_mesh, layout, cols, valids, [0], 3)
     host_pids = H.spark_partition_ids(
         [(cols[0], valids[0])], ["int64"], 3, xp=np)
     assert len(parts) == 3
@@ -171,7 +193,8 @@ def test_device_exchange_routes_like_host_hash(device_mesh):
     assert ss["shuffle_device_collectives"] >= 2
 
 
-def test_device_exchange_skew_climbs_bucket_ladder(device_mesh):
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_device_exchange_skew_climbs_bucket_ladder(device_mesh, layout):
     """Pathological skew: every row hashes to ONE destination, so the
     per-destination buckets sized for uniform traffic overflow and the
     runner must climb the capacity ladder (the last rung — the full
@@ -183,7 +206,7 @@ def test_device_exchange_skew_climbs_bucket_ladder(device_mesh):
     config.conf.set(config.MESH_EXCHANGE_SKEW.key, 1.0)
     try:
         xla_stats.reset()
-        parts = DeviceExchange(device_mesh).exchange(cols, valids, [0], 3)
+        parts = _exchange(device_mesh, layout, cols, valids, [0], 3)
     finally:
         config.conf.unset(config.MESH_EXCHANGE_SKEW.key)
     target = int(H.spark_partition_ids(
@@ -192,6 +215,9 @@ def test_device_exchange_skew_climbs_bucket_ladder(device_mesh):
     assert sizes[target] == n and sum(sizes) == n
     assert _multiset(*parts[target]) == _multiset(cols, valids)
     assert xla_stats.shuffle_stats()["shuffle_device_exchanges"] == 1
+    # the first rung overflowed: more than one dispatch's collectives
+    assert xla_stats.shuffle_stats()["shuffle_device_collectives"] > 6
+    assert xla_stats.placement_stats()["cross_chip_bytes"] == 0
 
 
 def test_device_exchange_empty_and_degenerate(device_mesh):
@@ -263,9 +289,13 @@ def test_planner_respects_mode_gates():
 
 # -- staged end-to-end ------------------------------------------------------
 
-def _two_stage_plan(tmp_path, n=6000, n_reduce=3):
+def _two_stage_plan(tmp_path, n=6000, n_reduce=3, wide=False):
     rng = np.random.default_rng(7)
-    t = pa.table({"k": pa.array(rng.integers(0, 200, n), type=pa.int64()),
+    # `wide`: the same keys spread over 2^48, so that the aggregation
+    # takes the hash table (a compact key range takes the dense lane,
+    # which the stage loop leaves to its own fold)
+    keys = rng.integers(0, 200, n) << (40 if wide else 0)
+    t = pa.table({"k": pa.array(keys, type=pa.int64()),
                   "v": pa.array(rng.random(n))})
     paths = []
     for i in range(2):
@@ -300,9 +330,17 @@ def _sorted_df(tbl):
     return tbl.to_pandas().sort_values("k").reset_index(drop=True)
 
 
+@pytest.mark.parametrize("resident", ["host", "devices"])
 def test_staged_device_shuffle_bit_identical_to_file(tmp_path, device_mesh,
-                                                     staged_device):
-    plan = _two_stage_plan(tmp_path)
+                                                     staged_device,
+                                                     resident, monkeypatch):
+    """`devices`: batches live on the devices, the map tasks come out of
+    the stage loop as device columns on the devices they ran on, and the
+    exchange takes them where they lie."""
+    if resident == "devices":
+        import blaze_tpu.bridge.placement as P
+        monkeypatch.setattr(P, "host_resident", lambda: False)
+    plan = _two_stage_plan(tmp_path, wide=resident == "devices")
     config.conf.set(config.SHUFFLE_DEVICE.key, "off")
     clean = _sorted_df(DagScheduler(
         work_dir=str(tmp_path / "dag-file")).run_collect(plan))
@@ -319,6 +357,12 @@ def test_staged_device_shuffle_bit_identical_to_file(tmp_path, device_mesh,
     assert ss["shuffle_device_rows"] > 0
     assert ss["shuffle_device_fallbacks"] == 0
     assert ss["shuffle_host_bytes"] == 0
+    if resident == "devices":
+        producer = sched.stages[0].sid
+        assert sched.stage_placement[producer]["compute"] == "device-loop"
+        assert sorted(chip for (sid, _m), chip in sched.task_chips.items()
+                      if sid == producer) == [0, 1]
+        assert xla_stats.placement_stats()["cross_chip_bytes"] == 0
 
 
 def test_staged_auto_keeps_file_shuffle_on_host(tmp_path):

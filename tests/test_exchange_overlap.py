@@ -52,12 +52,16 @@ def overlap_on(staged_device):
         config.conf.unset(config.EXCHANGE_OVERLAP_ENABLE.key)
 
 
-def _two_stage_plan(tmp_path, n=8000, n_reduce=3, n_files=4):
+def _two_stage_plan(tmp_path, n=8000, n_reduce=3, n_files=4, wide=False):
     """hash_agg(final) <- hash exchange <- hash_agg(partial) <- scan,
     split over `n_files` map tasks so the overlap window sees several
     dispatches in flight."""
     rng = np.random.default_rng(7)
-    t = pa.table({"k": pa.array(rng.integers(0, 200, n), type=pa.int64()),
+    # `wide`: the same keys spread over 2^48, so that the aggregation
+    # takes the hash table (a compact key range takes the dense lane,
+    # which the stage loop leaves to its own fold)
+    keys = rng.integers(0, 200, n) << (40 if wide else 0)
+    t = pa.table({"k": pa.array(keys, type=pa.int64()),
                   "v": pa.array(rng.random(n))})
     per = n // n_files
     paths = []
@@ -101,12 +105,23 @@ def test_overlap_defaults_off():
     assert config.EXCHANGE_OVERLAP_ENABLE.get() is False
 
 
+@pytest.mark.parametrize("resident,n_files", [("host", 4), ("devices", 4),
+                                              ("devices", 12)])
 def test_overlap_bit_identical_to_sync(tmp_path, device_mesh,
-                                       staged_device):
+                                       staged_device, resident, n_files,
+                                       monkeypatch):
     """Same plan, same seeds, same grow schedule: the overlapped
     exchange must publish byte-identical results (float sums are exact
-    only if the per-partition concat order matches the sync merge)."""
-    plan = _two_stage_plan(tmp_path)
+    only if the per-partition concat order matches the sync merge).
+    `devices`: the map tasks' columns lie on the devices they ran on,
+    one ticket a task from its own device; with 12 tasks on 8 devices
+    some devices hold two tasks' rows, and both paths keep them in
+    (device, task) order."""
+    if resident == "devices":
+        import blaze_tpu.bridge.placement as P
+        monkeypatch.setattr(P, "host_resident", lambda: False)
+    plan = _two_stage_plan(tmp_path, n=8400, n_files=n_files,
+                           wide=resident == "devices")
     sync = _sorted_df(DagScheduler(
         work_dir=str(tmp_path / "dag-sync")).run_collect(plan))
 
@@ -124,6 +139,12 @@ def test_overlap_bit_identical_to_sync(tmp_path, device_mesh,
     assert ss["shuffle_device_fallbacks"] == 0
     assert ss["shuffle_host_bytes"] == 0
     assert all(v == [] for v in sched.leak_report().values())
+    if resident == "devices":
+        producer = sched.stages[0].sid
+        assert sched.stage_placement[producer]["compute"] == "device-loop"
+        assert {chip for (sid, _m), chip in sched.task_chips.items()
+                if sid == producer} == set(range(min(n_files, 8)))
+        assert xla_stats.placement_stats()["cross_chip_bytes"] == 0
 
 
 def test_overlap_fault_falls_back_wholesale(tmp_path, device_mesh,
@@ -193,10 +214,22 @@ def _multiset(datas, valids):
                   for i in range(len(k)))
 
 
-def test_dispatch_drain_compiles_once_per_rung(device_mesh):
+def _spread(mesh, cols, valids):
+    """The rows as the output of one map task a device, on that device."""
+    import jax
+    devices = list(mesh.devices.reshape(-1))
+    cuts = np.linspace(0, len(cols[0]), len(devices) + 1).astype(int)
+    return [([jax.device_put(c[lo:hi], d) for c in cols],
+             [jax.device_put(v[lo:hi], d) for v in valids], int(hi - lo))
+            for d, lo, hi in zip(devices, cuts[:-1], cuts[1:])]
+
+
+@pytest.mark.parametrize("layout", ["host", "spread"])
+def test_dispatch_drain_compiles_once_per_rung(device_mesh, layout):
     """The async split must NOT cost extra traces: dispatch+drain of
     the same shape signature reuses the one cached shard_map program
-    per ladder rung, and routes rows exactly like `exchange`."""
+    per ladder rung, and routes rows exactly like `exchange`.  So does
+    the dispatch that takes map output where it lies on the mesh."""
     from blaze_tpu.parallel.stage import _exchange_program
     _exchange_program.cache_clear()  # order-independent: force a trace
     cols, valids = _kv_columns()
@@ -207,10 +240,17 @@ def test_dispatch_drain_compiles_once_per_rung(device_mesh):
         kernels = xla_stats.compile_report()["kernels"]
         return kernels.get("mesh.exchange_rows", {}).get("compiles", 0)
 
+    def once():
+        if layout == "host":
+            return ex.drain(ex.dispatch(cols, valids, [0], 3))
+        return ex.drain(ex.dispatch_placed(
+            _spread(device_mesh, cols, valids), [0], 3))
+
+    once()   # the spread rows pad to a rung of their own: warm it
     c0 = compiles()
-    assert c0 >= 1  # the warm exchange above compiled the rung
+    assert c0 >= 1  # the warm exchanges above compiled the rungs
     for _ in range(2):
-        parts = ex.drain(ex.dispatch(cols, valids, [0], 3))
+        parts = once()
         assert len(parts) == 3
         for r in range(3):
             assert _multiset(*parts[r]) == _multiset(*ref[r])
