@@ -137,6 +137,12 @@ _agg = {"partial_agg_skip_events": 0, "partial_agg_skipped_rows": 0,
 _sortmerge = {"sort_device_rows": 0, "smj_device_rows": 0,
               "smj_device_pairs": 0, "smj_streamed_runs": 0}
 
+# The hash joins' probe side (ops/joins/exec.py): probe rows handed to a
+# join whose batches stay on the chip (`kernels/join.probe_gather`: inner,
+# unique fixed-width build key) and to every other join, whose pairs and
+# rows pass through the host.  By chip in `chip_stats()` too.
+_join = {"join_probe_device_rows": 0, "join_probe_host_rows": 0}
+
 # Streaming-runtime accounting (streaming/executor.py StreamExecutor):
 # committed epochs and their wall time, rows/records through the
 # pipeline, late-record routing, checkpoint commits, recovery rounds
@@ -409,7 +415,9 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
 def _chip_entry(chip: int) -> Dict[str, int]:
     entry = _chips.get(chip)
     if entry is None:
-        entry = _chips[chip] = {"tasks": 0, "h2d_bytes": 0, "d2h_bytes": 0}
+        entry = _chips[chip] = {"tasks": 0, "h2d_bytes": 0, "d2h_bytes": 0,
+                                "join_probe_device_rows": 0,
+                                "join_probe_host_rows": 0}
     return entry
 
 
@@ -433,6 +441,20 @@ def note_d2h(nbytes: int, wait_ns: int = 0, chip: int = 0) -> None:
         _chip_entry(chip)["d2h_bytes"] += int(nbytes)
 
 
+def note_join_probe(chip: int, on_device: bool, rows: int) -> None:
+    """`rows` probe rows reached a hash join on `chip`, through the
+    device-resident probe or the other."""
+    key = "join_probe_device_rows" if on_device else "join_probe_host_rows"
+    with _lock:
+        _join[key] += int(rows)
+        _chip_entry(chip)[key] += int(rows)
+
+
+def join_stats() -> dict:
+    with _lock:
+        return dict(_join)
+
+
 def note_task_placed(chip: int) -> None:
     """One task's operator chain started on `chip`."""
     with _lock:
@@ -454,7 +476,8 @@ def placement_stats() -> dict:
 
 
 def chip_stats() -> Dict[int, Dict[str, int]]:
-    """device id -> {"tasks", "h2d_bytes", "d2h_bytes"} since the last
+    """device id -> {"tasks", "h2d_bytes", "d2h_bytes",
+    "join_probe_device_rows", "join_probe_host_rows"} since the last
     reset: what each chip was given to do."""
     with _lock:
         return {chip: dict(e) for chip, e in sorted(_chips.items())}
@@ -1086,6 +1109,7 @@ def counter_families() -> Dict[str, Dict[str, int]]:
             "shuffle": dict(_shuffle),
             "stage_loop": dict(_stage_loop),
             "agg": dict(_agg),
+            "join": dict(_join),
             "stream": dict(_stream),
             "workers": dict(_workers),
             "speculation": dict(_speculation),
@@ -1117,6 +1141,7 @@ def snapshot() -> dict:
     flat.update(shuffle_stats())
     flat.update(stage_loop_stats())
     flat.update(sortmerge_stats())
+    flat.update(join_stats())
     flat.update(stream_stats())
     flat.update(worker_stats())
     flat.update(speculation_stats())
@@ -1159,6 +1184,8 @@ def reset() -> None:
             _stage_loop[k] = 0
         for k in _sortmerge:
             _sortmerge[k] = 0
+        for k in _join:
+            _join[k] = 0
         for k in _stream:
             _stream[k] = 0
         for k in _workers:

@@ -52,6 +52,7 @@ def build_runs(sorted_hashes: np.ndarray
 
 
 from blaze_tpu.bridge.xla_stats import meter_jit
+from blaze_tpu.kernels import hashing as H
 from blaze_tpu.xputil import to_host
 
 
@@ -136,6 +137,107 @@ def probe_expand_device(unique_hashes, run_start, run_count, sorted_idx,
     sp_np = sp_np[v_np[: len(sp_np)]][:total]
     b_np = np.asarray(sorted_idx)[sp_np]
     return p_np, b_np
+
+
+# ---------------------------------------------------------------------------
+# broadcast join on a unique build key: the rows stay on the chip
+# ---------------------------------------------------------------------------
+
+def hash_valid(flat_cols, tids):
+    """(xxhash64 of the key columns int64[cap], any key NULL bool[cap]):
+    the join's hash of a row, build side and probe side alike.
+    flat_cols: ((data, validity), ...) aligned with the type ids `tids`;
+    float keys are normalised first (-0.0 joins 0.0, NaN joins NaN)."""
+    flat_cols = H.norm_float_keys(flat_cols, tids, jnp)
+    cols = [(v, val, tid) for (v, val), tid in zip(flat_cols, tids)]
+    h = H.hash_columns(cols, seed=42, xp=jnp, algo="xxhash64")
+    anyn = None
+    for (_v, val) in flat_cols:
+        nv = ~val
+        anyn = nv if anyn is None else (anyn | nv)
+    return h, anyn
+
+
+def _shift_front(a: jax.Array, s: int) -> jax.Array:
+    """a[i + s] at i; what falls off the end is filled with zeros."""
+    return jnp.concatenate([a[s:], jnp.zeros((s,), a.dtype)])
+
+
+def pack_front(keep: jax.Array, arrays):
+    """Every array's kept lanes moved to the front, in order: lane i goes
+    to (number of kept lanes before i).  No gather and no sort: a kept
+    lane has to move left by d = (dropped lanes before it), and moves by
+    d's bits, lowest first, one static shift and one select a bit and an
+    array.  Two kept lanes never meet: between kept lanes i < j lie at
+    least d_j - d_i dropped ones, so after the bits below 2^b the lanes'
+    places still differ by at least one (d mod 2^b differs by no more
+    than d does).  Lanes from the count on hold leftovers."""
+    cap = keep.shape[0]
+    idt = jnp.int32
+    rank = jnp.cumsum(keep, dtype=idt) - 1
+    d = jnp.where(keep, jnp.arange(cap, dtype=idt) - rank, 0)
+    live = keep
+    arrays = list(arrays)
+    for b in range((cap - 1).bit_length()):  # 1 << b stays under cap
+        s = 1 << b
+        moves = live & (((d >> b) & 1) == 1)
+        arrives = _shift_front(moves, s)
+        arrays = [jnp.where(arrives, _shift_front(a, s), a) for a in arrays]
+        d = jnp.where(arrives, _shift_front(d, s), d)
+        live = arrives | (live & ~moves)
+    return arrays
+
+
+def _keys_equal(a: jax.Array, b: jax.Array, tid: str) -> jax.Array:
+    if tid in ("float32", "float64"):
+        return (a == b) | (jnp.isnan(a) & jnp.isnan(b))
+    return a == b
+
+
+@functools.partial(meter_jit, name="join.probe_gather",
+                   static_argnames=("tids",))
+def probe_gather(uh, urow, build_keys, build_cols, probe_keys, probe_cols,
+                 rows, selection, tids):
+    """One probe batch of an inner join on a UNIQUE build key, whole.
+
+    uh: the build side's distinct hashes, ascending, padded with the
+    largest int64; urow: the build row of each, -1 for padding and for
+    a build row with a NULL key (NULL joins nothing).  build_keys: the
+    build side's key data, one array a key; build_cols / probe_cols:
+    ((data, validity), ...) of the columns the join puts out;
+    probe_keys: ((data, validity), ...) of the batch's keys, of the types
+    `tids`; rows, selection: the batch's row count and selection mask
+    (or None), its `row_mask()`.
+
+    Hashes the probe keys, searches `uh` (as `probe_counts` does), takes
+    the candidate's build row, compares the REAL keys there (a hash
+    collision joins nothing), gathers the build columns at the
+    candidate and packs the matched rows of both sides to the front.
+    Returns (probe columns, build columns, count), columns as
+    (data, validity) with validity false from `count` on."""
+    probe_keys = H.norm_float_keys(probe_keys, tids, jnp)
+    h, any_null = hash_valid(probe_keys, tids)
+    mask = jnp.arange(h.shape[0], dtype=jnp.int32) < rows
+    if selection is not None:
+        mask = mask & selection
+    pos = jnp.searchsorted(uh, h)
+    pos = jnp.clip(pos, 0, uh.shape[0] - 1)
+    row = jnp.take(urow, pos)
+    hit = (jnp.take(uh, pos) == h) & (row >= 0) & ~any_null & mask
+    row = jnp.maximum(row, 0)
+    for (pk, _pv), bk, tid in zip(probe_keys, build_keys, tids):
+        (bk, _), = H.norm_float_keys([(jnp.take(bk, row), None)], (tid,),
+                                     jnp)
+        hit = hit & _keys_equal(pk, bk, tid)
+    cols = list(probe_cols) + [(jnp.take(d, row), jnp.take(v, row))
+                               for d, v in build_cols]
+    packed = pack_front(hit, [a for dv in cols for a in dv])
+    count = jnp.sum(hit, dtype=jnp.int32)
+    inside = jnp.arange(hit.shape[0], dtype=jnp.int32) < count
+    out = [(packed[2 * i], packed[2 * i + 1] & inside)
+           for i in range(len(cols))]
+    n_probe = len(probe_cols)
+    return tuple(out[:n_probe]), tuple(out[n_probe:]), count
 
 
 # ---------------------------------------------------------------------------

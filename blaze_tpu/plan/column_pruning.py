@@ -207,6 +207,12 @@ def _prune(plan, required: Optional[Set[int]]):
                      {i - n_left for i in filt_cols if i >= n_left})
         lchild, lm = _prune(plan.children[0], left_req)
         rchild, rm = _prune(plan.children[1], right_req)
+        from blaze_tpu.ops.joins.exec import BroadcastJoinExec
+        if isinstance(plan, BroadcastJoinExec):
+            if plan.build_side == "right":
+                rchild, rm = _narrow_build(rchild, rm, right_req, n_right)
+            else:
+                lchild, lm = _narrow_build(lchild, lm, left_req, n_left)
         if lm is None and rm is None:
             plan.children[0] = lchild
             plan.children[1] = rchild
@@ -224,7 +230,6 @@ def _prune(plan, required: Optional[Set[int]]):
                                    else None),
                       existence_col=plan._existence_col,
                       null_aware_anti=plan.null_aware_anti)
-        from blaze_tpu.ops.joins.exec import BroadcastJoinExec
         if isinstance(plan, BroadcastJoinExec):
             kwargs["broadcast_id"] = plan._broadcast_id
         new = type(plan)(lchild, rchild,
@@ -238,6 +243,27 @@ def _prune(plan, required: Optional[Set[int]]):
     for i, child in enumerate(plan.children):
         plan.children[i] = _prune(child, None)[0]
     return plan, None
+
+
+def _narrow_build(child, mapping: Mapping, required: Set[int], n: int):
+    """A broadcast join's build side under a projection of the columns
+    the join and its parents read, where the pass could not narrow it to
+    them beneath (its scan lies under an aggregation or another join):
+    the build side is collected whole and kept, by every task of the
+    stage, and a build side of fixed-width columns alone stays on the
+    device (ops/joins/exec.py `_probes_on_device`).  (child, mapping) as
+    `_prune` returns them.  A build-map stage keeps its own schema: its
+    map is made from it."""
+    from blaze_tpu.ops.basic import ProjectExec
+    from blaze_tpu.ops.joins.exec import BuildHashMapExec
+    if len(required) >= len(child.schema) \
+            or isinstance(child, BuildHashMapExec):
+        return child, mapping
+    at = mapping or _identity(n)
+    kept = sorted(required)
+    exprs = [BoundReference(at[i], child.schema[at[i]].name) for i in kept]
+    return (ProjectExec(child, exprs, [e.name for e in exprs]),
+            {old: new for new, old in enumerate(kept)})
 
 
 def _prune_scan(scan, required: Optional[Set[int]]):

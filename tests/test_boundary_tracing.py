@@ -73,6 +73,15 @@ def _lower_expand_pairs():
     return _module_name(expand_pairs, i32, i32, cap=1024)
 
 
+def _lower_probe_gather():
+    from blaze_tpu.kernels.join import probe_gather
+    i64, i32, ok = (jnp.zeros(8, jnp.int64), jnp.zeros(7, jnp.int32),
+                    jnp.ones(8, bool))
+    return _module_name(probe_gather, jnp.zeros(7, jnp.int64), i32,
+                        (i64,), ((i64, ok),), ((i64, ok),), ((i64, ok),),
+                        jnp.int32(8), None, tids=("int64",))
+
+
 def _lower_hash_valid():
     from blaze_tpu.ops.joins.exec import _hash_valid_jit
     return _module_name(_hash_valid_jit(("int64",)),
@@ -103,6 +112,7 @@ def _lower_hash_pmod():
 @pytest.mark.parametrize("lower,module", [
     (_lower_probe_counts, "jit_probe_counts__join_probe_counts"),
     (_lower_expand_pairs, "jit_expand_pairs__join_expand_pairs"),
+    (_lower_probe_gather, "jit_probe_gather__join_probe_gather"),
     (_lower_hash_valid, "jit_f__join_hash_valid"),
     (_lower_rehash, "jit__lambda__fused_rehash"),
     (_lower_hash_step, "jit_f__fused_hash_step"),

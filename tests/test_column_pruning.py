@@ -136,3 +136,50 @@ def test_shared_broadcast_id_with_different_pruning(tmp_path):
                            suffixes=("", "_r"))
         want_vals = sorted(want[cname + "_r"].tolist())
         assert sorted(out[vname].tolist()) == want_vals
+
+
+@pytest.mark.parametrize("build_side", ["right", "left"])
+def test_broadcast_build_side_under_an_aggregation_is_projected(
+        tmp_path, build_side):
+    """The pass narrows scans; a broadcast build side whose scan lies under
+    an aggregation keeps every column the aggregation makes.  The join
+    then reads it through a projection of what it and its parents use, so
+    a build side collected whole holds those columns alone (and one of
+    fixed-width columns alone can stay on the device)."""
+    p1, t1 = _wide_file(tmp_path, name="p.parquet")
+    p2, t2 = _wide_file(tmp_path, n=300, name="b.parquet")
+
+    def build():
+        probe = ParquetScanExec(Schema.from_arrow(t1.schema), [[p1]])
+        b_scan = ParquetScanExec(Schema.from_arrow(t2.schema), [[p2]])
+        agg = AggExec(b_scan, [(col(4, "c4"), "k"), (col(5, "c5"), "k2")],
+                      [(make_agg("count", [col(4)]), AggMode.COMPLETE, "n"),
+                       (make_agg("max", [col(7)]), AggMode.COMPLETE, "m")])
+        if build_side == "right":
+            j = BroadcastJoinExec(probe, agg, [col(2)], [col(0)],
+                                  JoinType.INNER)
+            return ProjectExec(j, [col(2), col(10 + 3)], ["k", "m"])
+        j = BroadcastJoinExec(agg, probe, [col(0)], [col(2)],
+                              JoinType.INNER, build_side="left")
+        return ProjectExec(j, [col(4 + 2), col(3)], ["k", "m"])
+
+    pruned = prune_columns(build())
+    join = pruned.children[0]
+    side = join.children[1 if build_side == "right" else 0]
+    assert isinstance(side, ProjectExec)
+    assert [f.name for f in side.schema] == ["k", "m"]
+    assert [f.name for f in side.children[0].schema] == ["k", "k2", "n", "m"]
+    # the probe side is narrowed at its scan, as ever, not projected
+    other = join.children[0 if build_side == "right" else 1]
+    assert isinstance(other, ParquetScanExec)
+    assert [f.name for f in other.schema] == ["c2"]
+    got = _collect(pruned).to_pandas().sort_values(["k", "m"]) \
+        .reset_index(drop=True)
+    config.conf.set(config.COLUMN_PRUNING_ENABLE.key, False)
+    try:
+        want = _collect(build()).to_pandas().sort_values(["k", "m"]) \
+            .reset_index(drop=True)
+    finally:
+        config.conf.unset(config.COLUMN_PRUNING_ENABLE.key)
+    assert len(want) > 0
+    pd.testing.assert_frame_equal(got, want)

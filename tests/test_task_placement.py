@@ -30,6 +30,7 @@ from blaze_tpu.bridge import tracing, xla_stats  # noqa: E402
 from blaze_tpu.bridge.context import (TaskContext, attempt_scope,  # noqa: E402
                                       current_task, task_scope)
 from blaze_tpu.memory import MemConsumer, MemManager  # noqa: E402
+from blaze_tpu.ops import MemoryScanExec  # noqa: E402
 from blaze_tpu.parallel.mesh import (current_mesh, make_mesh,  # noqa: E402
                                      task_device)
 from blaze_tpu.parallel.stage import DeviceExchange  # noqa: E402
@@ -158,6 +159,13 @@ def test_q06_on_four_devices_every_device_works_and_no_row_strays(
         assert moved[f"chip{d}_tasks"] >= 1
         assert moved[f"chip{d}_h2d_bytes"] > 0
         assert moved[f"chip{d}_d2h_bytes"] > 0
+        # each map task's sales were joined on its own chip
+        assert moved[f"chip{d}_join_probe_device_rows"] > 0
+    assert moved["join_probe_device_rows"] == tables["store_sales"].num_rows
+    # item ⋈ its category's average has a utf8 key: through the host
+    # (by each map task that finds the build side not made yet)
+    items = tables["item"].num_rows
+    assert moved["join_probe_host_rows"] in [items * n for n in (1, 2, 3, 4)]
     # the spans say where
     by_name = {}
     for s in spans:
@@ -363,6 +371,90 @@ def test_collective_fault_on_four_devices_falls_back_to_the_file_shuffle(
     assert moved["shuffle_host_bytes"] > 0
     assert "file" in {p["exchange"] for p in sched.stage_placement.values()}
     assert all(v == [] for v in sched.leak_report().values())
+
+
+# -- a broadcast build side, one copy a chip --------------------------------------
+
+class _ScansInsideTheTask(MemoryScanExec):
+    """Arrow in, placed when the task pulls it: on the task's chip, as a
+    parquet scan's batches are."""
+
+    def __init__(self, table: pa.Table, partitions: int):
+        from blaze_tpu.schema import Schema
+        super().__init__(Schema.from_arrow(table.schema),
+                         [[] for _ in range(partitions)])
+        self._slices = [table.slice(p * table.num_rows // partitions,
+                                    table.num_rows // partitions)
+                        for p in range(partitions)]
+
+    def execute(self, partition: int):
+        from blaze_tpu.batch import ColumnBatch
+        for rb in self._slices[partition].to_batches(max_chunksize=1024):
+            yield ColumnBatch.from_arrow(rb)
+
+
+def test_a_broadcast_build_side_is_placed_once_a_chip(on_devices):
+    """Eight tasks on four chips probe one shared `JoinMap` at once: each
+    chip gets its own copy of the build side, placed by the first task
+    that asks there and charged to that chip's budget, and no row changes
+    chip on its way through the join."""
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops.joins import BroadcastJoinExec, JoinType
+    devices = on_devices(4)
+    rng = np.random.default_rng(11)
+    build = pa.table({"bk": pa.array(rng.permutation(3000)[:700]),
+                      "bv": pa.array(rng.random(700))})
+    probe = pa.table({"pk": pa.array(rng.integers(0, 3000, 16384)),
+                      "pv": pa.array(np.arange(16384))})
+    plan = BroadcastJoinExec(_ScansInsideTheTask(probe, 8),
+                             MemoryScanExec.from_arrow(build),
+                             [col(0)], [col(0)], JoinType.INNER)
+    mgr = MemManager.get()
+    start = threading.Barrier(8)
+    got = [None] * 8
+
+    def task(p):
+        with task_scope(TaskContext(partition_id=p, num_partitions=8,
+                                    device=task_device(p))):
+            start.wait(timeout=60)
+            got[p] = [b.to_arrow() for b in plan.execute(p)]
+
+    before = xla_stats.snapshot()
+    threads = [threading.Thread(target=task, args=(p,)) for p in range(8)]
+    with jax.transfer_guard_device_to_device("disallow"):
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    moved = xla_stats.delta(before)
+    joined = pa.Table.from_batches([rb for part in got for rb in part]) \
+        .to_pandas().sort_values("pv").reset_index(drop=True)
+    want = probe.to_pandas().merge(build.to_pandas(), left_on="pk",
+                                   right_on="bk").sort_values("pv") \
+        .reset_index(drop=True)
+    assert joined.equals(want) and len(want) > 0
+    assert moved["cross_chip_bytes"] == 0
+    assert moved["join_probe_device_rows"] == 16384
+    copies = plan._get_join_map(0)._on_device
+    assert sorted(copies) == sorted(d.id for d in devices)
+    charged = set()
+    for d in devices:
+        held = copies[d.id].held
+        for a in (held.uh, held.urow, *held.keys,
+                  *(x for dv in held.cols for x in dv)):
+            assert a.devices() == {d}
+        assert copies[d.id].chip == d.id
+        assert moved[f"chip{d.id}_join_probe_device_rows"] == 4096
+        charged.add(mgr.chip_used(d.id))
+    # the same bytes on every chip, and they are the chip's to shed
+    assert len(charged) == 1 and charged.pop() > 700 * 16
+    with task_scope(TaskContext(partition_id=1, device=devices[1])):
+        assert copies[devices[1].id].spill() > 0
+        assert mgr.chip_used(devices[1].id) == 0
+        again = plan._get_join_map(0).on_device()   # placed anew, there
+        assert again.uh.devices() == {devices[1]}
+    assert mgr.chip_used(devices[1].id) == mgr.chip_used(devices[0].id)
 
 
 # -- one budget a chip ----------------------------------------------------------
