@@ -130,6 +130,11 @@ SPAN_NAMES: Dict[str, str] = {
                            "the live rows read back in accumulator form "
                            "(runtime/loop.py; attrs stage, partition, "
                            "chunk, batches)",
+    "table_rehash": "the stage loop moves its hash table into a larger "
+                    "one: the exchange fences, the rehash program and "
+                    "the readback of its overflow scalar "
+                    "(runtime/loop.py; attrs stage, partition, chunk, "
+                    "from_slots, to_slots, groups, device)",
     # -- instants (dur_ns == 0) ---------------------------------------
     "task_retry": "a failed attempt was classified retryable and will "
                   "back off and retry (bridge/tasks.py)",

@@ -116,6 +116,9 @@ _stage_loop = {"stage_loop_programs_built": 0,
                "stage_loop_batches": 0, "stage_loop_rows": 0,
                "stage_loop_tasks": 0, "stage_loop_regrows": 0,
                "stage_loop_reserves": 0, "stage_loop_rehash_lanes": 0,
+               "stage_loop_rehash_groups": 0,
+               "stage_loop_rehash_new_slots": 0,
+               "stage_loop_final_slots": 0, "stage_loop_table_bytes": 0,
                "stage_loop_full_rounds": 0, "stage_loop_narrow_rounds": 0,
                "stage_loop_max_slots": 0,
                "stage_loop_fallbacks": 0,
@@ -412,12 +415,20 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
     return wrapper
 
 
+# what the stage loop's tables cost each chip, in the order
+# note_stage_loop_task fills them
+_CHIP_TABLE_KEYS = ("stage_loop_rehash_lanes", "stage_loop_rehash_groups",
+                    "stage_loop_rehash_new_slots", "stage_loop_final_slots",
+                    "stage_loop_table_bytes")
+
+
 def _chip_entry(chip: int) -> Dict[str, int]:
     entry = _chips.get(chip)
     if entry is None:
         entry = _chips[chip] = {"tasks": 0, "h2d_bytes": 0, "d2h_bytes": 0,
                                 "join_probe_device_rows": 0,
-                                "join_probe_host_rows": 0}
+                                "join_probe_host_rows": 0,
+                                **{k: 0 for k in _CHIP_TABLE_KEYS}}
     return entry
 
 
@@ -477,8 +488,9 @@ def placement_stats() -> dict:
 
 def chip_stats() -> Dict[int, Dict[str, int]]:
     """device id -> {"tasks", "h2d_bytes", "d2h_bytes",
-    "join_probe_device_rows", "join_probe_host_rows"} since the last
-    reset: what each chip was given to do."""
+    "join_probe_device_rows", "join_probe_host_rows" and the stage loop's
+    table counters (_CHIP_TABLE_KEYS)} since the last reset: what each
+    chip was given to do."""
     with _lock:
         return {chip: dict(e) for chip, e in sorted(_chips.items())}
 
@@ -879,19 +891,29 @@ def note_stage_program(cache_hit: bool) -> None:
 def note_stage_loop_task(chunks: int, batches: int, rows: int,
                          regrows: int, reserves: int, rehash_lanes: int,
                          slots: int, dispatches_avoided: int,
-                         full_rounds: int, narrow_rounds: int) -> None:
+                         full_rounds: int, narrow_rounds: int,
+                         rehash_groups: int, rehash_new_slots: int,
+                         table_bytes: int, chip: int) -> None:
     """One map task completed through the device-resident stage loop:
     `chunks` loop program calls folded `batches` batches / `rows` rows.
     The agg table's capacity was raised at `reserves` chunk boundaries
-    before the fold and `regrows` times after an overflow, pushing
-    `rehash_lanes` old-table slots through the rehash, and ended at
-    `slots` (`stage_loop_max_slots` is the high-water mark since
-    reset(), so its delta says how far it rose).  The table's probe ran
-    `full_rounds` rounds over a whole batch's lanes and `narrow_rounds`
-    over the compacted rows a batch still had unplaced
-    (parallel/stage.py hash_agg_step).  The staged per-batch
-    path would have issued `dispatches_avoided` extra Python
-    dispatches."""
+    before the fold and `regrows` times after an overflow.  Each rehash
+    pushed the old table's slots (`rehash_lanes`, summed) holding
+    `rehash_groups` groups into a table of `rehash_new_slots` slots, and
+    the table ended at `slots` (`stage_loop_final_slots` sums them, so a
+    window's delta over `stage_loop_tasks` is the mean table;
+    `stage_loop_max_slots` is the high-water mark since reset(), which
+    a warm window's delta reads as 0).  `table_bytes` is the most the
+    memory manager held against the task's table at one time (old and
+    new table together while a rehash ran).  The table counters are kept
+    by `chip` too.  The table's probe ran `full_rounds` rounds over a
+    whole batch's lanes and `narrow_rounds` over the compacted rows a
+    batch still had unplaced (parallel/stage.py hash_agg_step).  The
+    staged per-batch path would have issued `dispatches_avoided` extra
+    Python dispatches."""
+    table = dict(zip(_CHIP_TABLE_KEYS, map(int, (
+        rehash_lanes, rehash_groups, rehash_new_slots, slots,
+        table_bytes))))
     with _lock:
         _stage_loop["stage_loop_tasks"] += 1
         _stage_loop["stage_loop_calls"] += int(chunks)
@@ -900,7 +922,10 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int,
         _stage_loop["stage_loop_rows"] += int(rows)
         _stage_loop["stage_loop_regrows"] += int(regrows)
         _stage_loop["stage_loop_reserves"] += int(reserves)
-        _stage_loop["stage_loop_rehash_lanes"] += int(rehash_lanes)
+        entry = _chip_entry(chip)
+        for k, v in table.items():
+            _stage_loop[k] += v
+            entry[k] += v
         _stage_loop["stage_loop_full_rounds"] += int(full_rounds)
         _stage_loop["stage_loop_narrow_rounds"] += int(narrow_rounds)
         _stage_loop["stage_loop_max_slots"] = max(

@@ -935,9 +935,12 @@ class DagScheduler:
                     tracing.execution_context(stage=stage.sid,
                                               partition=m), \
                     tracing.span("task", mode="loop",
-                                 device=rt.task.device_id):
-                carry = device_loop.run_partition(prog, m,
-                                                  ctx=str(stage.sid))
+                                 device=rt.task.device_id), \
+                    device_loop.charged_table(prog) as table:
+                # the table stays charged to the task's chip until its
+                # groups are drained into the exchange's columns
+                carry = device_loop.run_partition(
+                    prog, m, ctx=str(stage.sid), table=table)
                 out = device_loop.drain_device(prog, carry)
         except (KeyboardInterrupt, SystemExit, FetchFailedError):
             raise

@@ -15,7 +15,13 @@ the chunk boundary the host already holds the chunk's row counts and
 the table's group count (they ride the overflow scalars' round trip),
 so the table is sized for `groups + rows about to arrive` — a plain
 allocation while it is empty, one rehash otherwise — and overflow is a
-rare backstop, not the growth policy.
+rare backstop, not the growth policy.  The table is the largest object
+a task keeps on its chip, so it is a memory-manager consumer of that
+chip (`_TableCharge`): charged before each allocation and rehash (the
+old and the new table together while both live), released after the
+drain or the switch to pass-through.  A budget with no room for it
+declines the partition like any other ineligibility, before anything
+was emitted.
 
 Partial-aggregation skipping (the AGG_TRIGGER_PARTIAL_SKIPPING analog,
 ref agg_table.rs:108-122; the three `auron.tpu.partialAgg.skipping.*`
@@ -60,9 +66,10 @@ Discipline inherited from the staged path, kept intact:
 
 from __future__ import annotations
 
+import functools
 import math
 import threading
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import NamedTuple
 
 import jax
@@ -72,6 +79,7 @@ from blaze_tpu import config, faults
 from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.xla_stats import meter_jit
+from blaze_tpu.memory import MemConsumer, MemManager
 from blaze_tpu.parallel.stage import (hash_agg_step, init_hash_carry,
                                       normalize_float_keys,
                                       row_contribution)
@@ -100,6 +108,52 @@ class StageLoopFallback(RuntimeError):
     re-runs the partition through the staged per-batch executor.  Like
     DeviceExchangeError, this is an optimization bailing out — never a
     new failure mode."""
+
+
+@functools.lru_cache(maxsize=128)
+def _slot_bytes(key_dtypes, kinds, acc_dtypes) -> int:
+    """Bytes one slot of the table takes on the device, over all of the
+    carry's arrays."""
+    carry = jax.eval_shape(lambda: init_hash_carry(
+        list(key_dtypes), kinds, list(acc_dtypes), 1))
+    return sum(a.dtype.itemsize for a in jax.tree_util.tree_leaves(carry))
+
+
+class _TableCharge(MemConsumer):
+    """A task's hash table as the memory manager sees it, on the task's
+    chip.  It cannot be shed: a table in the middle of a fold has no
+    lower tier, so `spill` releases nothing.  Nor does it press the
+    chip's other consumers out: they belong to other tasks' threads.
+    What it can do is decline: `hold` raises StageLoopFallback when the
+    chip's budget has no room for the tables asked for, and the staged
+    path, whose state spills, re-runs the partition."""
+
+    def __init__(self, program):
+        super().__init__("stage_loop_table")
+        self.slot_bytes = _slot_bytes(tuple(program.key_dtypes),
+                                      tuple(program.kinds),
+                                      tuple(program.acc_dtypes))
+        self.peak = 0
+
+    def hold(self, *tables: int) -> None:
+        """Charge tables of so many slots each, before they are made."""
+        if self._manager is None:
+            self.set_spillable(MemManager.get())
+        manager, want = self._manager, sum(tables) * self.slot_bytes
+        if (manager.chip_used(self.chip) - self.mem_used + want
+                > manager.total):
+            raise StageLoopFallback(
+                f"memory budget of {manager.total} bytes has no room for "
+                f"a table of {max(tables)} slots")
+        self.update_mem_used(want)
+        self.peak = max(self.peak, want)
+
+    def spill(self) -> int:
+        return 0
+
+    def release(self) -> None:
+        self._mem_used = 0
+        self.unregister()
 
 
 # fingerprint -> jit'd chunk fold or pass-through; bounded FIFO like
@@ -305,7 +359,7 @@ def _may_switch(program) -> bool:
 
 
 def run_partition(program, partition: int, ctx: str = "",
-                  source_stream=None):
+                  source_stream=None, table=None):
     """Fold one partition through the stage program; returns the final
     HashAggCarry, which holds every row: this entry never switches to
     pass-through (its caller, the device-to-device exchange, drains ONE
@@ -316,18 +370,36 @@ def run_partition(program, partition: int, ctx: str = "",
 
     The table's capacity sequence is a function of the input alone (rows
     per batch, groups so far), never of timing: a repeat of the same
-    partition walks the same sizes and loads no new program."""
-    carry, _rest = _fold_partition(program, partition, ctx, source_stream,
-                                   may_switch=False)
+    partition walks the same sizes and loads no new program.
+
+    `table` is the caller's charge for the carry (`charged_table`), held
+    until the caller has drained it; without one the charge ends with
+    the fold."""
+    with (charged_table(program) if table is None
+          else nullcontext(table)) as table:
+        carry, _rest = _fold_partition(program, partition, ctx,
+                                       source_stream, False, table)
     return carry
 
 
+@contextmanager
+def charged_table(program):
+    """The memory manager's charge for one partition's table: whatever
+    the body holds is released when it ends, however it ends."""
+    table = _TableCharge(program)
+    try:
+        yield table
+    finally:
+        table.release()
+
+
 def _fold_partition(program, partition: int, ctx: str, source_stream,
-                    may_switch: bool):
+                    may_switch: bool, table: _TableCharge):
     """(carry, None), or (carry, _Unfolded) when `may_switch` and the
     table made more than `ratio` groups a live row: the carry holds what
     was folded until then and the rest is the caller's to pass through.
-    Emits nothing either way, so StageLoopFallback is lossless here."""
+    Emits nothing either way, so StageLoopFallback is lossless here.
+    The carry is charged to `table`, which the caller releases."""
     from blaze_tpu.plan.fused import _batch_windows, _pow2, _rehash_jit
     task = current_task()
     q = task.query
@@ -345,7 +417,8 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
     stream = (source_stream if source_stream is not None
               else program.source.execute(partition))
     windows = _batch_windows(stream, chunk)
-    batches = rows = fold_calls = regrows = reserves = rehash_lanes = 0
+    batches = rows = fold_calls = regrows = reserves = 0
+    rehashes = []  # (old table's slots, groups it held, new slots)
     full_rounds = narrow_rounds = 0
     ci = groups = live_folded = 0
     slots, carry = floor, None  # allocated at the first chunk, for it
@@ -357,15 +430,24 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
 
     def resized(want):
         """The table at `want` slots or more: a plain allocation while
-        it holds nothing, one rehash otherwise."""
-        nonlocal rehash_lanes
+        it holds nothing, one rehash otherwise.  Charged before it is
+        made."""
         while want <= _MAX_SLOTS:
             if carry is None or groups == 0:
+                table.hold(want)
                 return fresh(want), want
-            _run_fences()  # drain in-flight overlapped exchanges
-            rehash_lanes += slots
-            bigger, re_ovf, _, _ = _rehash_jit(program.kinds, want)(carry)
-            if int(to_host(re_ovf)) == 0:
+            table.hold(slots, want)
+            with tracing.span("table_rehash", stage=ctx,
+                              partition=partition, chunk=ci,
+                              from_slots=slots, to_slots=want,
+                              groups=groups, device=task.device_id):
+                _run_fences()  # drain in-flight overlapped exchanges
+                rehashes.append((slots, groups, want))
+                bigger, re_ovf, _, _ = _rehash_jit(program.kinds,
+                                                   want)(carry)
+                fits = int(to_host(re_ovf)) == 0
+            if fits:
+                table.hold(want)  # the old table goes with `carry`
                 return bigger, want
             want *= 2  # rare probe clustering: double again
         raise StageLoopFallback(f"table would exceed {_MAX_SLOTS} slots")
@@ -442,13 +524,16 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         # not a task retry — the chaos soak asserts THIS path converges
         raise StageLoopFallback(f"injected fault: {e}") from e
     if carry is None:
+        table.hold(slots)
         carry = fresh(slots)  # empty partition
     if rest is not None:
         program.agg.metrics.add("partial_skipped", 1)
         xla_stats.note_partial_agg_skip(live_folded)
     xla_stats.note_stage_loop_task(
         chunks=fold_calls, batches=batches, rows=rows, regrows=regrows,
-        reserves=reserves, rehash_lanes=rehash_lanes, slots=slots,
+        reserves=reserves, rehash_lanes=sum(r[0] for r in rehashes),
+        rehash_groups=sum(r[1] for r in rehashes),
+        rehash_new_slots=sum(r[2] for r in rehashes), slots=slots, table_bytes=table.peak, chip=task.device_id,
         full_rounds=full_rounds, narrow_rounds=narrow_rounds,
         dispatches_avoided=max(0, batches - fold_calls))
     program.agg._note_lane(batches)
@@ -537,17 +622,19 @@ def execute_loop(program, partition: int, ctx: str = ""):
         # no switch: codes decode through the stream's LAST dictionary,
         # and the guard may decline the partition at any batch, which
         # is lossless only while nothing has been emitted
-        carry = run_partition(program, partition, ctx=ctx,
-                              source_stream=stream)
-        key_dicts = [captured.get(s) if s is not None else None
-                     for s in dict_keys]
-        yield from program.agg._emit_hash(carry, key_dicts=key_dicts)
+        with charged_table(program) as table:
+            carry = run_partition(program, partition, ctx=ctx,
+                                  source_stream=stream, table=table)
+            key_dicts = [captured.get(s) if s is not None else None
+                         for s in dict_keys]
+            yield from program.agg._emit_hash(carry, key_dicts=key_dicts)
         return
-    carry, rest = _fold_partition(program, partition, ctx, None,
-                                  may_switch=_may_switch(program))
-    yield from program.agg._emit_hash(carry)
-    if rest is not None:
+    with charged_table(program) as table:
+        carry, rest = _fold_partition(program, partition, ctx, None,
+                                      _may_switch(program), table)
+        yield from program.agg._emit_hash(carry)
         del carry  # the table is released before the rest is read
+    if rest is not None:
         yield from _pass_through(program, rest, partition, ctx)
 
 

@@ -900,9 +900,10 @@ def test_skipping_disabled_is_the_loop_without_it(loop_on, skip_conf):
         prog = stage_compiler.compile_task_plan(
             _skip_agg(t, _AGGS["int_sum_counts"], filtered=True))
         before = xla_stats.snapshot()
-        with config.scoped(**conf), task_scope(TaskContext()):
+        with config.scoped(**conf), task_scope(TaskContext()), \
+                device_loop.charged_table(prog) as table:
             carry, rest = device_loop._fold_partition(
-                prog, 0, "t", None, may_switch=device_loop._may_switch(prog))
+                prog, 0, "t", None, device_loop._may_switch(prog), table)
             plain = device_loop.run_partition(prog, 0)
         assert rest is None
         return carry, plain, xla_stats.delta(before)

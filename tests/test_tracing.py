@@ -461,7 +461,7 @@ def test_span_registry_pin():
         "stream_epoch", "explain_analyze",
         "d2h", "h2d", "prefetch_wait", "produce:*", "join_build",
         "join_probe", "agg_drain", "sort_device", "smj_merge",
-        "partial_passthrough",
+        "partial_passthrough", "table_rehash",
         "task_retry", "fault_injected", "xla_compile",
         "device_shuffle_fallback", "rss_shuffle_fallback",
         "stage_loop_fallback", "quota_breach", "mem_spill",
