@@ -118,6 +118,7 @@ _stage_loop = {"stage_loop_programs_built": 0,
                "stage_loop_reserves": 0, "stage_loop_rehash_lanes": 0,
                "stage_loop_rehash_groups": 0,
                "stage_loop_rehash_new_slots": 0,
+               "stage_loop_rehash_probe_lanes": 0,
                "stage_loop_final_slots": 0, "stage_loop_table_bytes": 0,
                "stage_loop_full_rounds": 0, "stage_loop_narrow_rounds": 0,
                "stage_loop_max_slots": 0,
@@ -418,8 +419,9 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
 # what the stage loop's tables cost each chip, in the order
 # note_stage_loop_task fills them
 _CHIP_TABLE_KEYS = ("stage_loop_rehash_lanes", "stage_loop_rehash_groups",
-                    "stage_loop_rehash_new_slots", "stage_loop_final_slots",
-                    "stage_loop_table_bytes")
+                    "stage_loop_rehash_new_slots",
+                    "stage_loop_rehash_probe_lanes",
+                    "stage_loop_final_slots", "stage_loop_table_bytes")
 
 
 def _chip_entry(chip: int) -> Dict[str, int]:
@@ -893,15 +895,20 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int,
                          slots: int, dispatches_avoided: int,
                          full_rounds: int, narrow_rounds: int,
                          rehash_groups: int, rehash_new_slots: int,
-                         table_bytes: int, chip: int) -> None:
+                         rehash_probe_lanes: int, table_bytes: int,
+                         chip: int) -> None:
     """One map task completed through the device-resident stage loop:
     `chunks` loop program calls folded `batches` batches / `rows` rows.
     The agg table's capacity was raised at `reserves` chunk boundaries
     before the fold and `regrows` times after an overflow.  Each rehash
     pushed the old table's slots (`rehash_lanes`, summed) holding
-    `rehash_groups` groups into a table of `rehash_new_slots` slots, and
-    the table ended at `slots` (`stage_loop_final_slots` sums them, so a
-    window's delta over `stage_loop_tasks` is the mean table;
+    `rehash_groups` groups into a table of `rehash_new_slots` slots,
+    re-inserting them over `rehash_probe_lanes` lanes (summed: the width
+    the live slots were compacted to, or the old table's slots where the
+    rehash ran uncompacted; old slots over these is the compaction's
+    ratio), and the table ended at `slots` (`stage_loop_final_slots`
+    sums them, so a window's delta over `stage_loop_tasks` is the mean
+    table;
     `stage_loop_max_slots` is the high-water mark since reset(), which
     a warm window's delta reads as 0).  `table_bytes` is the most the
     memory manager held against the task's table at one time (old and
@@ -912,8 +919,8 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int,
     staged per-batch path would have issued `dispatches_avoided` extra
     Python dispatches."""
     table = dict(zip(_CHIP_TABLE_KEYS, map(int, (
-        rehash_lanes, rehash_groups, rehash_new_slots, slots,
-        table_bytes))))
+        rehash_lanes, rehash_groups, rehash_new_slots, rehash_probe_lanes,
+        slots, table_bytes))))
     with _lock:
         _stage_loop["stage_loop_tasks"] += 1
         _stage_loop["stage_loop_calls"] += int(chunks)

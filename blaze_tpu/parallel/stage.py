@@ -514,18 +514,48 @@ def init_accumulators(kinds: Sequence[str], acc_dtypes: Sequence,
     return tuple(accs), tuple(avalid)
 
 
+def rehash_width(groups: int, old_slots: int) -> int:
+    """Lanes over which a table of `old_slots` slots holding `groups`
+    groups is re-inserted: the power of two that holds the groups, no
+    fewer than a batch without a narrow phase and no more than the
+    table."""
+    return min(old_slots, max(_NARROW_MIN_LANES,
+                              1 << (int(groups) - 1).bit_length()))
+
+
 def rehash_carry(old: HashAggCarry, kinds: Sequence[str],
-                 new_slots: int, probe_rounds: int = 16):
+                 new_slots: int, lanes: Optional[int] = None,
+                 probe_rounds: int = 16):
     """Re-insert an existing table into a larger one (the grow path).
     `kinds` are the ORIGINAL accumulator kinds; stored accumulators
-    re-merge with merge semantics (count -> sum of counts)."""
+    re-merge with merge semantics (count -> sum of counts).
+
+    The old table's slots are the batch.  A step costs by its lanes
+    (850 ns a lane of a 2^21-slot table on a v5e, a quarter of them
+    live at most; PERF.md section 6, PR 32), so a caller that knows the
+    table's group count gives `lanes` (`rehash_width`, never fewer than
+    the groups): the used slots are compacted to the front of that many
+    lanes, in slot order, and the step runs over those.  The lowest lane
+    wins a contested slot at either width and compaction keeps the
+    order, so the new table is slot for slot the uncompacted one.
+    Groups beyond `lanes` would be dropped: the count is the caller's to
+    hold."""
     key_dtypes = [k.dtype for k in old.keys]
     acc_dtypes = [a.dtype for a in old.accs]
     fresh = init_hash_carry(key_dtypes, kinds, acc_dtypes, new_slots)
+    cols = (old.keys, old.key_valid, old.accs, old.acc_valid)
+    mask = old.used
+    if lanes is not None and lanes < mask.shape[0]:
+        live = _compact_lanes(mask, lanes)
+        cols = jax.tree_util.tree_map(
+            lambda a: jnp.take(a, live, mode="clip"), cols)
+        mask = jnp.arange(lanes, dtype=jnp.int32) < jnp.sum(
+            mask, dtype=jnp.int32)
+    keys, key_valid, accs, acc_valid = cols
     specs = [("sum" if k == "count" else k, a, av)
-             for k, a, av in zip(kinds, old.accs, old.acc_valid)]
-    return hash_agg_step(fresh, list(zip(old.keys, old.key_valid)), specs,
-                         old.used, probe_rounds)
+             for k, a, av in zip(kinds, accs, acc_valid)]
+    return hash_agg_step(fresh, list(zip(keys, key_valid)), specs, mask,
+                         probe_rounds)
 
 
 def merge_agg_tables(table: AggTable,

@@ -51,8 +51,8 @@ from blaze_tpu.ops.basic import (DebugExec, FilterExec, FilterProjectExec,
 from blaze_tpu.ops.scan import MemoryScanExec, ParquetScanExec
 from blaze_tpu.parallel.stage import (hash_agg_step, init_accumulators,
                                       init_hash_carry, pack_dense_keys,
-                                      rehash_carry, scatter_accumulate,
-                                      unpack_dense_keys)
+                                      rehash_carry, rehash_width,
+                                      scatter_accumulate, unpack_dense_keys)
 from blaze_tpu.schema import Field, Schema, TypeId
 from blaze_tpu.xputil import asnp, to_device, to_host
 
@@ -1850,7 +1850,12 @@ class FusedPartialAggExec(ExecutionPlan):
                 # the step is atomic, so carry is intact and lossless
                 slots *= 2
                 self.metrics.add("table_grown", 1)
-                bigger, re_ovf, _, _ = _rehash_jit(kinds, slots)(carry)
+                # ... and hands back the carry's own group count, which
+                # is ready with the overflow just read
+                lanes = rehash_width(int(to_host(_ng)),
+                                     carry.used.shape[0])
+                bigger, re_ovf, _, _ = _rehash_jit(kinds, slots,
+                                                   lanes)(carry)
                 if int(to_host(re_ovf)) > 0:
                     continue  # rare probe clustering: double again
                 carry = bigger
@@ -2463,9 +2468,12 @@ def _hash_step_jit(kinds):
 
 
 @functools.lru_cache(maxsize=128)
-def _rehash_jit(kinds, new_slots: int):
-    return meter_jit(lambda c: rehash_carry(c, list(kinds), new_slots),
-                     name="fused.rehash")
+def _rehash_jit(kinds, new_slots: int, lanes: Optional[int] = None):
+    """The table given re-inserted into `new_slots` slots, over `lanes`
+    lanes where the caller holds its group count (rehash_width)."""
+    return meter_jit(
+        lambda c: rehash_carry(c, list(kinds), new_slots, lanes),
+        name="fused.rehash")
 
 
 def _hash_chain_step_factory(key, prepare, kinds):

@@ -14,8 +14,9 @@ Capacity is reserved BEFORE a chunk is folded, in every agg mode: at
 the chunk boundary the host already holds the chunk's row counts and
 the table's group count (they ride the overflow scalars' round trip),
 so the table is sized for `groups + rows about to arrive` — a plain
-allocation while it is empty, one rehash otherwise — and overflow is a
-rare backstop, not the growth policy.  The table is the largest object
+allocation while it is empty, one rehash otherwise, over as many lanes
+as that group count needs (`rehash_width`) — and overflow is a rare
+backstop, not the growth policy.  The table is the largest object
 a task keeps on its chip, so it is a memory-manager consumer of that
 chip (`_TableCharge`): charged before each allocation and rehash (the
 old and the new table together while both live), released after the
@@ -81,7 +82,7 @@ from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.memory import MemConsumer, MemManager
 from blaze_tpu.parallel.stage import (hash_agg_step, init_hash_carry,
-                                      normalize_float_keys,
+                                      normalize_float_keys, rehash_width,
                                       row_contribution)
 from blaze_tpu.xputil import to_host
 
@@ -418,7 +419,8 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
               else program.source.execute(partition))
     windows = _batch_windows(stream, chunk)
     batches = rows = fold_calls = regrows = reserves = 0
-    rehashes = []  # (old table's slots, groups it held, new slots)
+    # (old table's slots, groups it held, new slots, lanes re-inserted)
+    rehashes = []
     full_rounds = narrow_rounds = 0
     ci = groups = live_folded = 0
     slots, carry = floor, None  # allocated at the first chunk, for it
@@ -437,14 +439,22 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                 table.hold(want)
                 return fresh(want), want
             table.hold(slots, want)
+            # the live slots are compacted to `lanes` lanes before they
+            # are re-inserted; `groups` is the table's own count, read
+            # after the fold that filled it
+            lanes = rehash_width(groups, slots)
+            if groups > lanes:  # not an `assert`: -O must not drop it
+                raise AssertionError(f"a rehash over {lanes} lanes would "
+                                     f"drop groups of {groups}")
             with tracing.span("table_rehash", stage=ctx,
                               partition=partition, chunk=ci,
                               from_slots=slots, to_slots=want,
-                              groups=groups, device=task.device_id):
+                              groups=groups, lanes=lanes,
+                              device=task.device_id):
                 _run_fences()  # drain in-flight overlapped exchanges
-                rehashes.append((slots, groups, want))
-                bigger, re_ovf, _, _ = _rehash_jit(program.kinds,
-                                                   want)(carry)
+                rehashes.append((slots, groups, want, lanes))
+                bigger, re_ovf, _, _ = _rehash_jit(program.kinds, want,
+                                                   lanes)(carry)
                 fits = int(to_host(re_ovf)) == 0
             if fits:
                 table.hold(want)  # the old table goes with `carry`
@@ -533,7 +543,9 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         chunks=fold_calls, batches=batches, rows=rows, regrows=regrows,
         reserves=reserves, rehash_lanes=sum(r[0] for r in rehashes),
         rehash_groups=sum(r[1] for r in rehashes),
-        rehash_new_slots=sum(r[2] for r in rehashes), slots=slots, table_bytes=table.peak, chip=task.device_id,
+        rehash_new_slots=sum(r[2] for r in rehashes),
+        rehash_probe_lanes=sum(r[3] for r in rehashes), slots=slots,
+        table_bytes=table.peak, chip=task.device_id,
         full_rounds=full_rounds, narrow_rounds=narrow_rounds,
         dispatches_avoided=max(0, batches - fold_calls))
     program.agg._note_lane(batches)

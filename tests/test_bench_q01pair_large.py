@@ -31,6 +31,7 @@ from blaze_tpu.runtime import loop as device_loop  # noqa: E402
 SCALE, DATA_SEED, SPLITS, PARTITIONS = 0.4, 20260927, 4, 4
 FLOOR, BATCH, CHUNK = 1024, 128, 8
 FIRST, LAST = 8 * FLOOR, 32 * FLOOR     # need / _TARGET_LOAD, as powers of 2
+REHASH_LANES = 2 * FLOOR                # the power of two over ~1.9K groups
 SLOT_BYTES = 8 + 8 + 1 + 1 + 8 + 1 + 1  # two int64 keys, a float64 sum, flags
 CELL = "sf100_q01pair_x1"
 BUDGET = 4 << 30
@@ -186,8 +187,13 @@ def two_passes(case, tmp_path_factory):
             mp.setattr(device_loop, "_pass_through", watched_pass)
             used_before = MemManager.get().chip_used(0)
             work = tmp_path_factory.mktemp("pair")
+            # the tally is the process's: a file that ran earlier on
+            # this worker may have left reasons of its own
+            reasons_before = xla_stats.stage_loop_fallback_reasons()
             passes = [run_pair(case, work) for _ in range(2)]
             made = {"passes": passes, "at_switch": at_switch,
+                    "reasons_before": reasons_before,
+                    "reasons_after": xla_stats.stage_loop_fallback_reasons(),
                     "used_before": used_before,
                     "used_after": MemManager.get().chip_used(0),
                     "left_charged": len(tables_charged()),
@@ -217,7 +223,7 @@ def test_no_task_leaves_the_loop_and_two_map_tasks_stop_grouping(two_passes):
     assert moved["stage_loop_fallbacks"] == 0
     assert moved["stage_loop_regrows"] == 0
     assert moved["partial_agg_skip_events"] == 2
-    assert xla_stats.stage_loop_fallback_reasons() == {}
+    assert two_passes["reasons_after"] == two_passes["reasons_before"]
 
 
 def test_a_reduce_task_rehashes_once_and_ends_at_four_times_its_first_table(
@@ -231,6 +237,9 @@ def test_a_reduce_task_rehashes_once_and_ends_at_four_times_its_first_table(
         # at its third chunk, holding two chunks of nearly distinct rows
         assert s["chunk"] == 2
         assert 0.9 * 2 * FLOOR <= s["groups"] <= 2 * FLOOR
+        # re-inserted over the power of two that holds them, a quarter
+        # of the old table's slots
+        assert s["lanes"] == REHASH_LANES >= s["groups"]
         assert s["device"] == 0
     # one first allocation a task, one rehash a reduce task
     assert moved["stage_loop_reserves"] == SPLITS + 2 * PARTITIONS
@@ -239,7 +248,11 @@ def test_a_reduce_task_rehashes_once_and_ends_at_four_times_its_first_table(
 def test_the_counters_read_what_the_schedule_says(two_passes):
     _got, moved, spans = two_passes["passes"][0]
     spans = rehashes(spans)
+    # the old tables' slots, and the lanes their live slots were
+    # compacted to before they were re-inserted
     assert moved["stage_loop_rehash_lanes"] == PARTITIONS * FIRST
+    assert moved["stage_loop_rehash_probe_lanes"] \
+        == PARTITIONS * REHASH_LANES == sum(s["lanes"] for s in spans)
     assert moved["stage_loop_rehash_new_slots"] == PARTITIONS * LAST
     assert moved["stage_loop_rehash_groups"] \
         == sum(s["groups"] for s in spans)
@@ -253,7 +266,7 @@ def test_the_counters_read_what_the_schedule_says(two_passes):
         2 * ([SLOT_BYTES * FIRST] * SPLITS
              + [SLOT_BYTES * (FIRST + LAST)] * PARTITIONS))
     for key in ("rehash_lanes", "rehash_groups", "rehash_new_slots",
-                "final_slots", "table_bytes"):
+                "rehash_probe_lanes", "final_slots", "table_bytes"):
         assert moved[f"chip0_stage_loop_{key}"] \
             == moved[f"stage_loop_{key}"]
         assert two_passes["by_chip"][0][f"stage_loop_{key}"] > 0
@@ -273,11 +286,13 @@ def test_a_second_pass_walks_the_same_capacities_and_asks_for_no_program(
     assert first["total_compiles"] > 0
     assert second["total_compiles"] == 0
     walk = [sorted((s["partition"], s["chunk"], s["from_slots"],
-                    s["to_slots"], s["groups"]) for s in rehashes(sp))
+                    s["to_slots"], s["groups"], s["lanes"])
+                   for s in rehashes(sp))
             for sp in (spans1, spans2)]
     assert walk[0] == walk[1]
     for key in ("stage_loop_reserves", "stage_loop_rehash_lanes",
-                "stage_loop_rehash_groups", "stage_loop_final_slots",
+                "stage_loop_rehash_groups", "stage_loop_rehash_probe_lanes",
+                "stage_loop_final_slots",
                 "stage_loop_table_bytes", "stage_loop_full_rounds",
                 "stage_loop_narrow_rounds", "partial_agg_skipped_rows"):
         assert first[key] == second[key], key
@@ -374,7 +389,7 @@ def test_every_metric_that_names_the_cell_has_its_file_and_its_reader(cell):
              if CELL in m.get("workloads", [])]
     assert {m["name"] for m in named} == {
         "rehash_device_s", "rehash_lanes", "rehash_roofline",
-        "fold_final_slots", "table_charged_mb"}
+        "fold_final_slots", "table_charged_mb", "rehash_probe_lanes"}
     specs = dict((m["name"], spec) for m, spec in cell.layer_metrics())
     for m in named:
         spec = specs[m["name"]]
@@ -393,6 +408,7 @@ def test_the_new_readers_read_the_counters_and_nothing_on_the_parent(cell):
     counters = {"stage_loop_rehash_lanes": 4 << 21,
                 "stage_loop_rehash_groups": 4 * 500_000,
                 "stage_loop_rehash_new_slots": 4 << 23,
+                "stage_loop_rehash_probe_lanes": 4 << 19,
                 "stage_loop_final_slots": (4 << 23) + (4 << 21),
                 "stage_loop_tasks": 8,
                 "stage_loop_table_bytes": 28 * ((4 << 23) + (8 << 21))}
@@ -406,6 +422,7 @@ def test_the_new_readers_read_the_counters_and_nothing_on_the_parent(cell):
             specs[name], ctx)
 
     assert read("rehash_lanes", ctx) == 4 << 21
+    assert read("rehash_probe_lanes", ctx) == 4 << 19
     assert read("fold_final_slots", ctx) == ((4 << 23) + (4 << 21)) / 8
     assert read("table_charged_mb", ctx) == pytest.approx(1409.286144)
     assert read("rehash_device_s", ctx) == 6.0
@@ -417,7 +434,8 @@ def test_the_new_readers_read_the_counters_and_nothing_on_the_parent(cell):
     parent = dict(ctx, counters={"stage_loop_rehash_lanes": 4 << 21,
                                  "stage_loop_tasks": 8})
     assert read("rehash_lanes", parent) == 4 << 21
-    for name in ("rehash_roofline", "fold_final_slots", "table_charged_mb"):
+    for name in ("rehash_roofline", "fold_final_slots", "table_charged_mb",
+                 "rehash_probe_lanes"):
         assert read(name, parent) is None
     quiet = dict(ctx, trace={"programs": {"jit_fold_impl": 1.0}})
     assert read("rehash_device_s", quiet) is None
@@ -429,3 +447,43 @@ def test_rehash_min_bytes_on_a_hand_worked_case():
     # 1,000 groups read and written at 25 B, 4,096 new slots written once
     assert rehash_min_bytes(1_000, 4_096, 25) == 50_000 + 102_400
     assert rehash_min_bytes(0, 16, 25) == 400
+
+
+def test_the_cells_traced_line_holds_what_the_manifest_lists_for_it(
+        like_sf100, tmp_path):
+    """`run.drive` over a copy of the benchmark whose configuration is cut
+    to this file's scale: every metric the manifest has for the cell that
+    needs no device plane is in the traced run's line, the compaction's
+    lanes among them."""
+    import shutil
+    import time
+
+    import jax
+    from benchmark import run as bench_run
+    from benchmark.manifest import Cell, load_json
+    root = str(tmp_path / "root")
+    shutil.copytree(os.path.join(ROOT, "benchmark"),
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    path = os.path.join(root, "benchmark", "configs", "tpcds-sf100-x1.json")
+    cfg = load_json(path)
+    cfg.update(scale=SCALE, tables={})
+    with open(path, "w") as f:
+        json.dump(cfg, f)
+    cell = Cell(CELL, root)
+    peaks = load_json(os.path.join(cell.bench_dir, "peaks.json"))
+    res = bench_run.drive(cell, 2_900_000_123, 0.3, 1, jax.devices()[:1],
+                          peaks["devices"]["TPU v5 lite"],
+                          time.perf_counter())
+    assert res["correct"] is True and res["failed"] == 0
+    listed = {m["name"]: m for m in cell.manifest["per_layer"]
+              if CELL in m.get("workloads", [CELL])}
+    missing = set(listed) - set(res["metrics"])
+    assert all(listed[name]["source"] == "device_trace" for name in missing)
+    got = {name: m["value"] for name, m in res["metrics"].items()}
+    assert got["rehash_probe_lanes"] == PARTITIONS * REHASH_LANES
+    assert got["rehash_lanes"] == PARTITIONS * FIRST
+    assert got["fold_final_slots"] \
+        == (PARTITIONS * LAST + SPLITS * FIRST) / (SPLITS + PARTITIONS)
+    assert got["stage_loop_fallbacks"] == got["stage_loop_regrows"] == 0

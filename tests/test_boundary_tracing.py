@@ -409,8 +409,11 @@ def test_stage_loop_fold_is_named_after_its_kernel(tmp_path, staged_loop):
     from blaze_tpu.runtime.loop import _FOLD_CACHE
     plan, _n = _pair_plan(tmp_path)
     DagScheduler().run_collect(plan)
+    # the cache is the process's: an earlier file on this worker may
+    # have left its pass-through programs beside the folds
     names = {"jit_" + fold._blaze_jitted.__name__
-             for fold in _FOLD_CACHE.values()}
+             for fold in _FOLD_CACHE.values()
+             if "passthrough" not in fold._blaze_jitted.__name__}
     assert names == {"jit_fold_impl__runtime_stage_loop"}
     assert "runtime.stage_loop" in xla_stats.compile_report()["kernels"]
 

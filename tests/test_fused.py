@@ -234,7 +234,22 @@ class TestMergeModeFusion:
                                    rtol=1e-9)
         assert (got.c.to_numpy() == want.c.to_numpy()).all()
 
-    def test_final_mode_grows_instead_of_skipping(self):
+    @pytest.mark.parametrize("compact", [False, True],
+                             ids=["old_slots", "compacted"])
+    def test_final_mode_grows_instead_of_skipping(self, compact,
+                                                  monkeypatch):
+        """`compacted`: the tables of this test are smaller than the
+        least width a rehash compacts to, so the floor is taken out of
+        the width; the staged path then hands each rehash the carry's
+        own group count and the answer stays what it was."""
+        import blaze_tpu.plan.fused as fused
+        widths, real = [], fused.rehash_width
+
+        def width(groups, old_slots):
+            lanes = min(old_slots, fused._pow2(groups))
+            widths.append((groups, old_slots, lanes))
+            return lanes if compact else real(groups, old_slots)
+        monkeypatch.setattr(fused, "rehash_width", width)
         t = _table(n=6000)  # ~200 distinct cust per partition
         config.conf.set(config.ON_DEVICE_AGG_CAPACITY.key, 16)
         # this test exercises the DEVICE hash-table growth mechanics; the
@@ -257,6 +272,11 @@ class TestMergeModeFusion:
         assert len(got) == len(want)
         np.testing.assert_allclose(got.s.to_numpy(), want.s.to_numpy(),
                                    rtol=1e-9)
+        # one width a rehash, from the groups the carry held: never more
+        # than its slots, and some of them fewer lanes than slots
+        assert len(widths) == plan.metrics.get("table_grown")
+        assert all(0 <= g <= lanes <= slots for g, slots, lanes in widths)
+        assert any(lanes < slots for _g, slots, lanes in widths)
 
     def test_config_gate(self):
         t = _table(n=100)

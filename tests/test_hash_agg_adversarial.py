@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from blaze_tpu.parallel.stage import (HashAggCarry, hash_agg_step,
-                                      init_hash_carry, rehash_carry)
+                                      init_hash_carry, rehash_carry,
+                                      rehash_width)
 
 
 def _insert(carry, keys, vals, probe_rounds=16):
@@ -41,6 +42,12 @@ def _table_dict(carry):
             for k, s, c in zip(keys, sums, counts)}
 
 
+def _assert_leaf_for_leaf(got, want):
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_full_load_overflow_is_atomic():
     """64 slots, 80 distinct keys: placement MUST overflow; the returned
     carry must be bit-identical to the input (lossless retry contract)."""
@@ -51,9 +58,7 @@ def test_full_load_overflow_is_atomic():
     vals = jnp.ones(80, dtype=jnp.float64)
     out, overflow, _, _ = _insert(carry, keys, vals)
     assert int(overflow) > 0
-    for a, b in zip(jax.tree_util.tree_leaves(out),
-                    jax.tree_util.tree_leaves(carry)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_leaf_for_leaf(out, carry)
 
 
 def test_probe_rounds_exhaustion_partial_chain():
@@ -333,9 +338,7 @@ def test_overflow_returns_the_original_carry(held):
         carry, *batch(np.arange(1000, 1080)))
     assert int(overflow) > 0
     assert int(groups) == int(jnp.sum(carry.used))
-    for a, b in zip(jax.tree_util.tree_leaves(out),
-                    jax.tree_util.tree_leaves(carry)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_leaf_for_leaf(out, carry)
 
 
 # -- the probe's two widths against a model of its rounds -------------------
@@ -550,9 +553,7 @@ def test_step_matches_round_model(case, sieve):
         carry, model, [(kd, np.ones(lanes, bool))], rng.random(lanes), mask)
     assert rounds == want_rounds and overflow == want_left
     if want_left:
-        for a, b in zip(jax.tree_util.tree_leaves(out),
-                        jax.tree_util.tree_leaves(carry)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        _assert_leaf_for_leaf(out, carry)
 
 
 def test_null_nan_and_negative_zero_keys_place_in_the_narrow_rounds():
@@ -603,27 +604,188 @@ def test_null_nan_and_negative_zero_keys_place_in_the_narrow_rounds():
             np.testing.assert_allclose(got[g][0][0], accs[0][0], rtol=1e-12)
 
 
-def test_rehash_of_more_used_slots_than_the_narrow_width():
+@pytest.mark.parametrize("lanes", [None, 2048],
+                         ids=["old_slots", "compacted"])
+def test_rehash_of_more_used_slots_than_the_narrow_width(lanes):
     """rehash_carry probes the OLD table's slots as lanes: 8,192 lanes,
-    narrow width 1,024, about 2,000 of them used."""
-    lanes = old_slots = 1 << 13
+    narrow width 1,024, about 2,000 of them used.  Compacted to 2,048
+    lanes (which have no narrow phase) it runs the same rounds, all at
+    full width, and leaves the same table."""
+    old_slots = 1 << 13
     rng = np.random.default_rng(31)
-    kd = rng.integers(0, 2000, lanes).astype(np.int64) * 1000003 + 17
-    ones = np.ones(lanes, bool)
+    kd = rng.integers(0, 2000, old_slots).astype(np.int64) * 1000003 + 17
+    ones = np.ones(old_slots, bool)
     old, old_model, overflow, _ = _step_and_model(
         _fresh([np.int64], old_slots), _ModelTable([np.int64], old_slots),
-        [(kd, ones)], rng.random(lanes), ones)
+        [(kd, ones)], rng.random(old_slots), ones)
     assert overflow == 0
-    assert int(old_model.used.sum()) > narrow_width(old_slots)
+    assert narrow_width(old_slots) < int(old_model.used.sum()) <= 2048
     grown, overflow, groups, rounds = jax.jit(
-        lambda c: rehash_carry(c, ["sum", "count"], 4 * old_slots))(old)
-    want, m_overflow, m_rounds = _ModelTable([np.int64], 4 * old_slots) \
-        .insert([(old_model.keys[0], old_model.valid[0])], old_model.sums,
-                old_model.counts, old_model.used)
+        lambda c: rehash_carry(c, ["sum", "count"], 4 * old_slots,
+                               lanes))(old)
+    want, m_overflow, m_rounds = _rehashed_model(old_model, 4 * old_slots)
     assert int(overflow) == m_overflow == 0
-    assert np.asarray(rounds).tolist() == m_rounds and m_rounds[1] >= 1
+    assert m_rounds[1] >= 1
+    assert np.asarray(rounds).tolist() == (
+        m_rounds if lanes is None else [sum(m_rounds), 0])
     assert int(groups) == int(old_model.used.sum())
     _assert_slot_for_slot(grown, want)
+
+
+# -- the compacted rehash against the uncompacted one ------------------------
+# With `lanes` given, rehash_carry compacts the old table's used slots to
+# the front of that many lanes, in slot order, and re-inserts those.  The
+# lowest lane wins a contested slot at either width, so the table must be
+# the uncompacted rehash's leaf for leaf, unused slots included, and the
+# round model's slot for slot.
+
+def _rehashed_model(old_model, new_slots, probe_rounds=16):
+    return _ModelTable([k.dtype for k in old_model.keys], new_slots).insert(
+        [(k, v) for k, v in zip(old_model.keys, old_model.valid)],
+        old_model.sums, old_model.counts, old_model.used, probe_rounds)
+
+
+def _rehash_both_ways(old, kinds, new_slots, lanes, probe_rounds=16):
+    """(uncompacted, compacted) results of rehash_carry, under jit; they
+    have to agree on everything but the split of the rounds."""
+    plain, compact = (
+        jax.jit(lambda c, w=w: rehash_carry(c, kinds, new_slots, w,
+                                            probe_rounds))(old)
+        for w in (None, lanes))
+    assert int(plain[1]) == int(compact[1])          # overflow
+    assert int(plain[2]) == int(compact[2])          # groups
+    assert int(jnp.sum(plain[3])) == int(jnp.sum(compact[3]))
+    _assert_leaf_for_leaf(compact[0], plain[0])
+    return plain, compact
+
+
+_OLD_SLOTS, _REHASH_LANES = 1 << 13, 2048
+
+
+def _table_of_distinct_keys(live, rng):
+    """(carry, model) of an 8,192-slot table holding `live` groups
+    wherever their hashes put them."""
+    lanes = max(live, 16)
+    kd = (rng.permutation(1 << 20)[:lanes].astype(np.int64) * 1000003) + 17
+    return _step_and_model(
+        _fresh([np.int64], _OLD_SLOTS), _ModelTable([np.int64], _OLD_SLOTS),
+        [(kd, np.ones(lanes, bool))], rng.random(lanes),
+        np.arange(lanes) < live)[:2]
+
+
+def _table_used_at_its_end(live, sieve, rng):
+    """The same with the used slots one contiguous run at the table's
+    end: every lane beyond the live ones then gathers a LIVE slot (the
+    padding position clips to the last slot) and only the mask keeps it
+    out."""
+    kd = np.concatenate([sieve(_OLD_SLOTS).at(s, 1)
+                         for s in range(_OLD_SLOTS - live, _OLD_SLOTS)])
+    kd = kd[rng.permutation(live)]
+    old, model = _step_and_model(
+        _fresh([np.int64], _OLD_SLOTS), _ModelTable([np.int64], _OLD_SLOTS),
+        [(kd, np.ones(live, bool))], rng.random(live),
+        np.ones(live, bool))[:2]
+    assert np.flatnonzero(model.used).tolist() == list(
+        range(_OLD_SLOTS - live, _OLD_SLOTS))
+    return old, model
+
+
+@pytest.mark.parametrize("live", [0, 1, _REHASH_LANES - 1, _REHASH_LANES,
+                                  "run_at_the_end"])
+def test_compacted_rehash_is_the_uncompacted_one_slot_for_slot(live, sieve):
+    rng = np.random.default_rng(41)
+    if live == "run_at_the_end":
+        old, old_model = _table_used_at_its_end(1500, sieve, rng)
+    else:
+        old, old_model = _table_of_distinct_keys(live, rng)
+        assert int(old_model.used.sum()) == live
+    assert rehash_width(int(old_model.used.sum()), _OLD_SLOTS) \
+        == _REHASH_LANES
+    plain, compact = _rehash_both_ways(old, ["sum", "count"],
+                                       4 * _OLD_SLOTS, _REHASH_LANES)
+    want, m_overflow, _rounds = _rehashed_model(old_model, 4 * _OLD_SLOTS)
+    assert int(compact[1]) == m_overflow == 0
+    assert int(compact[2]) == int(old_model.used.sum())
+    _assert_slot_for_slot(compact[0], want)
+    _assert_slot_for_slot(plain[0], want)
+
+
+@pytest.mark.parametrize("key_dtypes", [[np.float64], [np.int64, np.float64]],
+                         ids=["float64", "int64-float64"])
+def test_compacted_rehash_keeps_hostile_keys_and_every_kind(key_dtypes):
+    """NULL keys, NaN keys of several payloads and both zeros; min, max
+    and a count that re-merges as a sum; accumulators that never saw a
+    valid argument stay invalid."""
+    rng = np.random.default_rng(43)
+    old, overflow, groups, want = _hostile_step(
+        rng, key_dtypes, n=_REHASH_LANES, slots=_OLD_SLOTS)
+    assert overflow == 0 and 100 < groups <= _REHASH_LANES
+    assert any(None in g for g in want) and any("nan" in g for g in want)
+    assert any(not ok for accs in want.values() for _v, ok in accs)
+    plain, compact = _rehash_both_ways(old, _KINDS, 4 * _OLD_SLOTS,
+                                       _REHASH_LANES)
+    assert int(compact[1]) == 0 and int(compact[2]) == groups
+    _assert_same_groups(_table_groups(compact[0]), want)
+    _assert_same_groups(_table_groups(plain[0]), want)
+
+
+def test_a_rehash_that_overflows_leaves_both_tables_as_they_were(sieve):
+    """24 groups that share ONE home slot in the new table cannot place
+    in 16 rounds: compacted or not, the rehash reports the eight left
+    over and hands back the new table untouched, and the old carry,
+    which the caller keeps, is what it was."""
+    new_slots = 4 * _OLD_SLOTS
+    kd = sieve(new_slots).at(77, 24)
+    # they share their home in the old table too: rounds enough for it
+    old, overflow, _, _ = _insert(
+        _fresh([np.int64], _OLD_SLOTS), jnp.asarray(kd), jnp.ones(24),
+        probe_rounds=32)
+    assert int(overflow) == 0
+    before = jax.tree_util.tree_map(np.asarray, old)
+    plain, compact = _rehash_both_ways(old, ["sum", "count"], new_slots,
+                                       _REHASH_LANES)
+    assert int(compact[1]) == 8 and int(compact[2]) == 0
+    _assert_leaf_for_leaf(compact[0], _fresh([np.int64], new_slots))
+    _assert_leaf_for_leaf(old, before)
+    # and with rounds enough the same groups place, the same way
+    plain, compact = _rehash_both_ways(old, ["sum", "count"], new_slots,
+                                       _REHASH_LANES, probe_rounds=32)
+    assert int(compact[1]) == 0 and int(compact[2]) == 24
+
+
+@pytest.mark.parametrize("groups, old_slots, want", [
+    (0, 1 << 13, 2048), (1, 1 << 13, 2048), (2048, 1 << 13, 2048),
+    (2049, 1 << 13, 4096), (5000, 1 << 12, 1 << 12), (100, 1024, 1024),
+    # a reduce task at scale factor 100: 2^21 slots at its third chunk
+    (503_312, 1 << 21, 1 << 19), (524_288, 1 << 21, 1 << 19),
+    (524_289, 1 << 21, 1 << 20)])
+def test_rehash_width_is_the_power_of_two_that_holds_the_groups(
+        groups, old_slots, want):
+    got = rehash_width(groups, old_slots)
+    assert got == want and (groups <= got or got == old_slots)
+
+
+@pytest.mark.parametrize("lanes", [None, _OLD_SLOTS, 2 * _OLD_SLOTS])
+def test_without_fewer_lanes_the_rehash_is_the_step_over_the_old_slots(
+        lanes):
+    """No `lanes`, or as many as the table has: the program is
+    hash_agg_step with the old table's slots as the batch, nothing
+    more."""
+    old = _fresh([np.int64], _OLD_SLOTS)
+
+    def as_before(c):
+        fresh = _fresh([np.int64], 4 * _OLD_SLOTS)
+        specs = [("sum", c.accs[0], c.acc_valid[0]),
+                 ("sum", c.accs[1], c.acc_valid[1])]
+        return hash_agg_step(fresh, list(zip(c.keys, c.key_valid)), specs,
+                             c.used, 16)
+
+    def now(c):
+        return rehash_carry(c, ["sum", "count"], 4 * _OLD_SLOTS, lanes)
+
+    assert jax.jit(now).lower(old).as_text() \
+        == jax.jit(as_before).lower(old).as_text().replace(
+            "as_before", "now")
 
 
 def test_a_second_call_at_the_same_shapes_builds_no_program(sieve):

@@ -19,10 +19,24 @@ def _prefetch_threads():
             if t.name.startswith("blaze-prefetch")]
 
 
+# the threads are the process's: a file that ran earlier on this worker
+# may have left workers of its own blocked (a task that failed with its
+# scan half read), and those are not this test's to wait for
+_inherited: set = set()
+
+
+@pytest.fixture(autouse=True)
+def _threads_of_earlier_tests():
+    _inherited.clear()
+    _inherited.update(_prefetch_threads())
+    yield
+
+
 def _wait_no_threads(timeout=5.0):
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
-        alive = [t for t in _prefetch_threads() if t.is_alive()]
+        alive = [t for t in _prefetch_threads()
+                 if t.is_alive() and t not in _inherited]
         if not alive:
             return True
         time.sleep(0.01)

@@ -489,11 +489,38 @@ def test_growing_cardinality_resizes_with_hysteresis(
     assert d["stage_loop_reserves"] == 1 + len(steps)
     assert d["stage_loop_rehash_lanes"] == sum(
         capacities[i - 1] for i in steps)
+    # each rehash ran over the power of two that holds the 1,024 * i
+    # groups the table had by then (2,048 lanes or more, its slots or
+    # fewer), not over its slots
+    from blaze_tpu.parallel.stage import rehash_width
+    assert d["stage_loop_rehash_probe_lanes"] == sum(
+        rehash_width(1024 * i, capacities[i - 1]) for i in steps)
+    assert d["stage_loop_rehash_probe_lanes"] \
+        < d["stage_loop_rehash_lanes"]
     # the groups held and the rows about to arrive never pass the
     # trigger load
     from blaze_tpu.runtime import loop as device_loop
     assert all(1024 * (i + 1) <= c * device_loop._TRIGGER_LOAD
                for i, c in enumerate(capacities))
+
+
+def test_a_rehash_over_fewer_lanes_than_groups_is_refused_on_the_host(
+        tmp_path, loop_on, small_tables, monkeypatch):
+    """The compaction would drop the groups beyond its lanes without a
+    sign, so the width is checked against the count the host holds
+    before the program is asked for: the task fails and no rehash
+    ran."""
+    from blaze_tpu.runtime import loop as device_loop
+    monkeypatch.setattr(device_loop, "rehash_width",
+                        lambda groups, slots: 512)
+    plan, _want = _sum_agg(tmp_path, _wide(np.arange(16384)), "final",
+                           "short")
+    before = xla_stats.snapshot()
+    with pytest.raises(AssertionError, match="would drop groups"):
+        _emitted(plan)
+    d = xla_stats.delta(before)
+    assert d["stage_loop_rehash_probe_lanes"] == 0
+    assert d["stage_loop_fallbacks"] == 0  # not a quiet re-run
 
 
 @pytest.mark.parametrize("mode", ["partial", "final"])

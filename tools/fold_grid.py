@@ -11,7 +11,12 @@ measurement they were chosen from (PERF.md section 6, PRs 25 and 29):
     pair cell's shape (two int64 keys, one float64 sum, 41% of the lanes
     selected, every selected row a new group) inserted into a table of S
     slots that already holds load x S groups; the same batch with 12
-    groups in all (the q06 shape); the table re-inserted into one of 4 S;
+    groups in all (the q06 shape); the table re-inserted into one of 4 S
+    over its own slots as lanes, and (`rehash_x4_compacted`) over the
+    power of two that holds its groups (`stage.rehash_width`);
+  * `rehash_cell`: the rehash of `sf100_q01pair_x1`'s reduce tasks, 2^21
+    slots at a load of 1/4 into 2^23, both ways: a host-clock reading
+    beside the trace's `rehash_device_s`;
   * `lanes`: the same step at 65,536 down to 4,096 lanes into one table
     at one load: is a round's cost linear in its lanes down there?
   * `compaction`: what the narrow phase pays before its first round (the
@@ -142,6 +147,8 @@ def _compaction(stage, say, rng):
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--log-slots", type=int, nargs="+", default=[18, 20, 22])
+    ap.add_argument("--cell-log-slots", type=int, default=21,
+                    help="log2 of the `rehash_cell` table's slots")
     ap.add_argument("--tree", default=None,
                     help="import blaze_tpu from this checkout")
     ap.add_argument("--tag", default=None,
@@ -172,6 +179,23 @@ def main() -> int:
         return stage.init_hash_carry([jnp.int64, jnp.int64], KINDS,
                                      [jnp.float64], slots)
 
+    def rehash_both_ways(carry, groups, shape):
+        """The table into one of four times its slots: over its own
+        slots as lanes and, where this tree's rehash takes a width, over
+        the lanes that hold its groups."""
+        slots = carry.used.shape[0]
+        widths = [(shape, None)]
+        if hasattr(stage, "rehash_width"):
+            widths.append((shape + "_compacted",
+                           stage.rehash_width(groups, slots)))
+        for name, lanes in widths:
+            more = () if lanes is None else (lanes,)
+            re = jax.jit(lambda c: stage.rehash_carry(
+                c, list(KINDS), 4 * slots, *more))
+            t, out = _timed(re, carry)
+            say(slots=slots, shape=name, lanes=lanes or slots,
+                load_before=groups / slots, step_s=t, **_reading(out))
+
     step16, fill = _step(stage, LANES, 16), _step(stage, LANES, 256)
     for log_s in args.log_slots:
         slots = 1 << log_s
@@ -192,11 +216,7 @@ def main() -> int:
             say(slots=slots, shape="new_groups", lanes=LANES,
                 load_before=groups / slots, step_s=t, **_reading(out))
             if load in (1 / 8, 1 / 4) and log_s <= 20:
-                re = jax.jit(lambda c: stage.rehash_carry(c, list(KINDS),
-                                                          4 * slots))
-                t, out = _timed(re, carry)
-                say(slots=slots, shape="rehash_x4", lanes=slots,
-                    load_before=groups / slots, step_s=t, **_reading(out))
+                rehash_both_ways(carry, groups, "rehash_x4")
 
     # the lane-width axis: one table, two loads, every selected row a
     # new group
@@ -215,6 +235,12 @@ def main() -> int:
 
     if hasattr(stage, "_compact_lanes"):
         _compaction(stage, say, np.random.default_rng(30))
+
+    # a reduce task of sf100_q01pair_x1 at its third chunk
+    slots = 1 << args.cell_log_slots
+    carry, groups = _filled(fill, np.random.default_rng(34), fresh(slots),
+                            0, slots // 4)
+    rehash_both_ways(carry, groups, "rehash_cell")
 
     name = f"fold_grid.{args.tag}.jsonl" if args.tag else "fold_grid.jsonl"
     with open(os.path.join(out_dir, name), "w") as f:
