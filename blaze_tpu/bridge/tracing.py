@@ -37,6 +37,7 @@ programs.  Without a profiler session the annotation records nothing.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -46,9 +47,18 @@ from typing import Any, Dict, List, Optional
 
 _enabled = False
 _conf_probed = False  # lazy one-shot auron.tpu.trace.enable probe
-_lock = threading.Lock()
+# re-entrant: a collection can start at any bytecode of a thread that
+# holds it, and the collector's callback emits a span (`_on_gc`)
+_lock = threading.RLock()
 _spans: List[dict] = []
+# With one `op:*` span an operator a pull a query of the benchmark holds
+# 800 to 8,000 spans (PERF.md has each cell's count), a traced window a
+# few of them.  What is trimmed is counted (`dropped()`), never lost in
+# silence.
 _MAX_SPANS = 100_000
+_dropped = 0
+_unreported = 0  # of `_dropped`, not yet in xla_stats' obs_spans_dropped
+_gc_t0: Dict[int, int] = {}  # thread id -> clock at its collection's start
 _sink = None  # open JSONL file, when exporting
 _tls = threading.local()
 _ids = itertools.count(1)
@@ -107,6 +117,13 @@ SPAN_NAMES: Dict[str, str] = {
     "prefetch_wait": "the consumer blocked on a prefetch queue: the "
                      "producer thread is behind (ops/base.py "
                      "PrefetchIterator.__next__; attrs source)",
+    "op:*": "one pull of one operator, a real interval: next() of the "
+            "operator's stream, or its eager execute()/arrow_batches() "
+            "call (phase=open); suffix is the operator's class name.  "
+            "Operators pull, so the spans nest child inside parent on a "
+            "thread and the innermost open one is the operator whose own "
+            "code runs; none on an operator's re-entrant self-call "
+            "(ops/base.py _MeteredIter, _meter_stream; attrs rows)",
     "produce:*": "one item produced on a prefetch worker thread, "
                  "next(source) plus transform; suffix is the prefetcher "
                  "name, e.g. produce:parquet_scan = decode, dictionary "
@@ -135,6 +152,19 @@ SPAN_NAMES: Dict[str, str] = {
                     "the readback of its overflow scalar "
                     "(runtime/loop.py; attrs stage, partition, chunk, "
                     "from_slots, to_slots, groups, device)",
+    "coalesce": "small batches concatenated into one by the coalescing "
+                "stream (ops/base.py CoalesceStream; attrs batches, rows)",
+    "loop_window": "a chunk's source batches padded and stacked column "
+                   "by column for the stage loop's fold, the pulls of the "
+                   "source outside it (plan/fused.py _batch_windows; "
+                   "attrs batches)",
+    "table_init": "the stage loop allocates an empty hash table "
+                  "(runtime/loop.py _fold_partition; attrs slots, device)",
+    "gc_pause": "one run of Python's cyclic garbage collector, on the "
+                "thread whose allocation set it off; every other thread "
+                "waits for the interpreter lock meanwhile (bridge/"
+                "tracing.py, a gc.callbacks entry while tracing is on; "
+                "attrs generation, collected)",
     # -- instants (dur_ns == 0) ---------------------------------------
     "task_retry": "a failed attempt was classified retryable and will "
                   "back off and retry (bridge/tasks.py)",
@@ -196,12 +226,6 @@ SPAN_NAMES: Dict[str, str] = {
 }
 
 
-def register_span(name: str, doc: str) -> None:
-    """Escape hatch for out-of-tree emitters; mirrors
-    faults.register_site so conformance keeps covering them."""
-    SPAN_NAMES[name] = doc
-
-
 def _check_name(name: str) -> None:
     """Emitting an unregistered span name is a bug, not telemetry: the
     registry is the conformance contract (tests/test_span_names.py).
@@ -213,7 +237,7 @@ def _check_name(name: str) -> None:
         return
     raise ValueError(
         f"unregistered span name {name!r}: add it to tracing.SPAN_NAMES "
-        "(or register_span) and document it in docs/observability.md")
+        "and document it in docs/observability.md")
 
 
 def _probe_conf() -> None:
@@ -323,7 +347,8 @@ def span(name: str, **attrs):
         stack.pop()
         record = {"name": name, "t0_ns": t0, "t1_ns": t1,
                   "dur_ns": t1 - t0, "sid": sid,
-                  "thread": threading.current_thread().name}
+                  "thread": threading.current_thread().name,
+                  "tid": threading.get_ident()}
         if parent is not None:
             record["parent"] = parent
         ctx = current_context()
@@ -344,7 +369,8 @@ def emit_span(name: str, dur_ns: int, **attrs) -> None:
     t1 = time.perf_counter_ns()
     record = {"name": name, "t0_ns": t1 - int(dur_ns), "t1_ns": t1,
               "dur_ns": int(dur_ns), "sid": next(_ids),
-              "thread": threading.current_thread().name}
+              "thread": threading.current_thread().name,
+              "tid": threading.get_ident()}
     stack = _span_stack()
     if stack:
         record["parent"] = stack[-1]
@@ -365,7 +391,8 @@ def instant(name: str, **attrs) -> None:
     t = time.perf_counter_ns()
     record = {"name": name, "t0_ns": t, "t1_ns": t, "dur_ns": 0,
               "sid": next(_ids),
-              "thread": threading.current_thread().name}
+              "thread": threading.current_thread().name,
+              "tid": threading.get_ident()}
     stack = _span_stack()
     if stack:
         record["parent"] = stack[-1]
@@ -377,6 +404,30 @@ def instant(name: str, **attrs) -> None:
     _emit(record)
 
 
+def _trim() -> None:
+    """Drop the oldest spans over `_MAX_SPANS`, counted (caller holds
+    `_lock`)."""
+    global _dropped, _unreported
+    over = len(_spans) - _MAX_SPANS
+    if over > 0:
+        del _spans[:over]
+        _dropped += over
+        _unreported += over
+
+
+def _report_dropped() -> None:
+    """Hand what `_trim` counted to `obs_spans_dropped`.  Not from the
+    collector's callback: the collecting thread may hold xla_stats' lock,
+    and the next span emitted reports for it."""
+    global _unreported
+    if not _unreported or threading.get_ident() in _gc_t0:
+        return
+    with _lock:
+        n, _unreported = _unreported, 0
+    from blaze_tpu.bridge import xla_stats
+    xla_stats.note_obs(spans_dropped=n)
+
+
 def _emit(record: dict) -> None:
     with _lock:
         if _child_mode:
@@ -384,10 +435,35 @@ def _emit(record: dict) -> None:
             del _child_buf[:-_CHILD_BUF_CAP]
             return
         _spans.append(record)
-        del _spans[:-_MAX_SPANS]
+        _trim()
         if _sink is not None:
             _sink.write(json.dumps(record, default=str) + "\n")
             _sink.flush()
+    _report_dropped()
+
+
+def dropped() -> int:
+    """Spans trimmed from the buffer since `start_tracing()`: a reader of
+    `spans()` that finds this above 0 holds a window's end, not all of
+    it (counter `obs_spans_dropped` sums it over the process's life)."""
+    with _lock:
+        return _dropped
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` entry while tracing is on: one `gc_pause` span a
+    collection.  `start` and `stop` come on the collecting thread, and
+    collections do not nest, so the start's clock is kept by thread."""
+    tid = threading.get_ident()
+    if phase == "start":
+        _gc_t0[tid] = time.perf_counter_ns()
+        return
+    t0 = _gc_t0.get(tid)
+    if t0 is not None:
+        emit_span("gc_pause", time.perf_counter_ns() - t0,
+                  generation=info.get("generation"),
+                  collected=info.get("collected"))
+        del _gc_t0[tid]
 
 
 # -- cross-process propagation ---------------------------------------------
@@ -475,7 +551,7 @@ def ingest(records: Optional[List[dict]], worker=None,
             _spans.append(r)
             if _sink is not None:
                 _sink.write(json.dumps(r, default=str) + "\n")
-        del _spans[:-_MAX_SPANS]
+        _trim()
         if _sink is not None:
             _sink.flush()
     try:
@@ -483,6 +559,7 @@ def ingest(records: Optional[List[dict]], worker=None,
         xla_stats.note_obs(spans_ingested=len(records))
     except Exception:
         pass
+    _report_dropped()
     return len(records)
 
 
@@ -498,15 +575,18 @@ def spans_for_query(query_id) -> List[dict]:
 
 def start_tracing(path: Optional[str] = None) -> None:
     """Enable span collection; `path` additionally streams JSONL there."""
-    global _enabled, _sink, _conf_probed
+    global _enabled, _sink, _conf_probed, _dropped
     with _lock:
         _spans.clear()
+        _dropped = 0
         if _sink is not None:
             _sink.close()
             _sink = None
         if path:
             _sink = open(path, "w")
         _conf_probed = True
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
     _enabled = True
 
 
@@ -515,6 +595,9 @@ def stop_tracing() -> List[dict]:
     global _enabled, _sink
     _enabled = False
     with _lock:
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+        _gc_t0.clear()
         if _sink is not None:
             _sink.close()
             _sink = None

@@ -194,7 +194,7 @@ _speculation = {"speculation_waves": 0, "speculation_attempts": 0,
 # children, flight-recorder dumps written, and query-profile LRU
 # evictions (bridge/profiling.py store bound).
 _obs = {"obs_spans_ingested": 0, "obs_flight_dumps": 0,
-        "obs_profile_evictions": 0}
+        "obs_profile_evictions": 0, "obs_spans_dropped": 0}
 
 # Cross-query work sharing (blaze_tpu/cache/, serving single-flight,
 # shared scan decode).  scan_share_hits = follower rides a leader's
@@ -679,11 +679,12 @@ def duration_samples() -> Dict[str, List[int]]:
 
 
 def note_obs(spans_ingested: int = 0, flight_dumps: int = 0,
-             profile_evictions: int = 0) -> None:
+             profile_evictions: int = 0, spans_dropped: int = 0) -> None:
     with _lock:
         _obs["obs_spans_ingested"] += spans_ingested
         _obs["obs_flight_dumps"] += flight_dumps
         _obs["obs_profile_evictions"] += profile_evictions
+        _obs["obs_spans_dropped"] += spans_dropped
 
 
 def obs_stats() -> dict:

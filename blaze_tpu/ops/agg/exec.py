@@ -38,7 +38,7 @@ from blaze_tpu.ops.agg.functions import AggFunction
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
 from blaze_tpu.ops.sort import merge_sorted_batches
 from blaze_tpu.schema import DataType, Field, INT64, Schema, TypeId
-from blaze_tpu.xputil import to_host, xp_of
+from blaze_tpu.xputil import asnp, to_host, xp_of
 
 
 class AggMode(enum.Enum):
@@ -362,7 +362,7 @@ class _AggState(MemConsumer):
             sorted_ops = [xp.take(o, perm) for o in operands]
             sorted_valid = xp.take(valid_mask, perm)
             gids, ng = K.group_ids_from_sorted(sorted_ops, sorted_valid)
-            num_groups = int(ng)
+            num_groups = int(to_host(ng))
         else:
             perm = xp.arange(cap)
             sorted_valid = valid_mask
@@ -444,8 +444,8 @@ class _AggState(MemConsumer):
                    ) -> np.ndarray:
         """Group ids in ORIGINAL row order for host-side accumulators."""
         n = batch.num_rows
-        p = np.asarray(perm)
-        g = np.asarray(gids)
+        p = asnp(perm)
+        g = asnp(gids)
         out = np.full(batch.capacity, num_groups, dtype=np.int64)
         out[p] = g
         return out[:n]
@@ -549,7 +549,7 @@ class _AggState(MemConsumer):
             sorted_ops = [xp.take(o, perm) for o in operands]
             sorted_valid = xp.take(valid_mask, perm)
             gids, ng = K.group_ids_from_sorted(sorted_ops, sorted_valid)
-            num_groups = int(ng)
+            num_groups = int(to_host(ng))
         else:
             perm = xp.arange(cap)
             sorted_valid = valid_mask
@@ -570,8 +570,8 @@ class _AggState(MemConsumer):
             nacc = len(fn.acc_fields(self.in_schema))
             if fn.is_host:
                 if host_gids is None:
-                    p = np.asarray(perm)
-                    g = np.asarray(gids)
+                    p = asnp(perm)
+                    g = asnp(gids)
                     hg = np.full(cap, num_groups, dtype=np.int64)
                     hg[p] = g
                     host_gids = hg[:rb.num_rows]
@@ -771,8 +771,8 @@ def _key_dtype_of(data: jax.Array) -> DataType:
 
 
 def _device_to_arrow(data: jax.Array, valid: jax.Array, n: int) -> pa.Array:
-    d = np.asarray(data)[:n]
-    v = np.asarray(valid)[:n]
+    d = asnp(data)[:n]
+    v = asnp(valid)[:n]
     if d.dtype == np.bool_:
         return pa.array(d, mask=~v)
     return pa.array(d, mask=~v)

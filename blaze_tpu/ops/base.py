@@ -55,7 +55,8 @@ class _MeteredIter:
     `elapsed_compute_ns` (INCLUSIVE of child pull; renderers derive
     self-time), rows/batches counted per yield.  Metrics accumulate
     incrementally so a downstream early break (LimitExec) still records
-    the partial work."""
+    the partial work.  While tracing is on each pull is one `op:<class>`
+    span, a real interval: child pulls nest inside it."""
 
     __slots__ = ("_it", "_plan", "_key")
 
@@ -77,9 +78,20 @@ class _MeteredIter:
             # check — the outer frame already ran it this step)
             current_task().check_running()
             active.add(self._key)
+        # one span an operator a pull, as one meter: none on the
+        # re-entrant self-call.  Off, the flag is all that is read
+        traced = tracing._enabled and not reenter
+        rows = None
         t0 = time.perf_counter_ns()
         try:
-            item = next(self._it)
+            if traced:
+                with tracing.span(
+                        f"op:{type(self._plan).__name__}") as attrs:
+                    item = next(self._it)
+                    # the count's readback, if any, is the operator's
+                    rows = attrs["rows"] = _batch_rows(item)
+            else:
+                item = next(self._it)
         finally:
             self._plan.metrics.add("elapsed_compute_ns",
                                    time.perf_counter_ns() - t0)
@@ -87,7 +99,7 @@ class _MeteredIter:
                 active.discard(self._key)
         m = self._plan.metrics
         m.add("output_batches")
-        m.add("output_rows", _batch_rows(item))
+        m.add("output_rows", _batch_rows(item) if rows is None else rows)
         return item
 
 
@@ -105,7 +117,12 @@ def _meter_stream(fn):
         try:
             # eager call under the meter: operators like IpcWriterExec do
             # all their work here and return an empty iterator
-            it = fn(self, *args, **kwargs)
+            if tracing._enabled:
+                with tracing.span(f"op:{type(self).__name__}",
+                                  phase="open"):
+                    it = fn(self, *args, **kwargs)
+            else:
+                it = fn(self, *args, **kwargs)
         finally:
             setup_ns = time.perf_counter_ns() - t0
             active.discard(key)
@@ -419,11 +436,15 @@ class CoalesceStream:
             staged.append(batch)
             staged_rows += n
             if staged_rows >= target:
-                yield ColumnBatch.concat(staged,
-                                         bucket_capacity(staged_rows))
+                yield _concat(staged, staged_rows)
                 staged, staged_rows = [], 0
         if staged:
-            yield ColumnBatch.concat(staged, bucket_capacity(staged_rows))
+            yield _concat(staged, staged_rows)
+
+
+def _concat(staged: List[ColumnBatch], rows: int) -> ColumnBatch:
+    with tracing.span("coalesce", batches=len(staged), rows=rows):
+        return ColumnBatch.concat(staged, bucket_capacity(rows))
 
 
 def coalesce(stream: BatchIterator, batch_size: Optional[int] = None) -> BatchIterator:

@@ -252,7 +252,7 @@ class _SortState(MemConsumer):
         payload = batch.to_arrow()
         sel = None
         if batch.selection is not None:
-            sel = np.asarray(batch.row_mask())[:n]
+            sel = batch.selected_mask(n)
             arrays = [a.filter(pa.array(sel)) for a in arrays]
         for name, col in zip(self._schema.names, payload.columns):
             arrays.append(col)

@@ -2157,10 +2157,15 @@ def _batch_windows(stream, window: int):
     for batch in stream:
         buf.append(_source_inputs(batch))
         if len(buf) >= window:
-            yield _stack_window(buf)
+            yield _traced_stack(buf)
             buf = []
     if buf:
-        yield _stack_window(buf)
+        yield _traced_stack(buf)
+
+
+def _traced_stack(items):
+    with tracing.span("loop_window", batches=len(items)):
+        return _stack_window(items)
 
 
 def _stack_window(items):

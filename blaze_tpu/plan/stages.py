@@ -1110,10 +1110,9 @@ class DagScheduler:
                     return
                 key, ticket = item
                 try:
+                    # host columns already: drain() reads the received
+                    # rows back through xputil.to_host (one `d2h` span)
                     parts = exchange.drain(ticket)
-                    parts = [([np.asarray(d) for d in ds],
-                              [np.asarray(v) for v in vs])
-                             for ds, vs in parts]
                     tracing.emit_span(
                         "device_exchange",
                         _time.perf_counter_ns() - ticket.dispatch_ns,

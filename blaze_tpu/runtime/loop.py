@@ -427,8 +427,9 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
     rest = None
 
     def fresh(n):
-        return init_hash_carry(list(program.key_dtypes), program.kinds,
-                               list(program.acc_dtypes), n)
+        with tracing.span("table_init", slots=n, device=task.device_id):
+            return init_hash_carry(list(program.key_dtypes), program.kinds,
+                                   list(program.acc_dtypes), n)
 
     def resized(want):
         """The table at `want` slots or more: a plain allocation while

@@ -565,3 +565,251 @@ def test_backend_listeners_register_once():
             if getattr(cb, "__module__", "") == xla_stats.__name__]
     assert len(ours) == 1
     assert jax.__version__
+
+
+# -- who holds the thread: one real-interval span an operator a pull ---------
+
+def _chain():
+    """leaf -> pass -> pass over three batches of 100 rows.  The middle
+    operator's arrow_batches() routes through its own execute(): the
+    re-entrant self-call several real operators make."""
+    from blaze_tpu.batch import ColumnBatch
+    from blaze_tpu.ops.base import ExecutionPlan
+    from blaze_tpu.schema import Schema
+
+    t = pa.table({"a": pa.array(range(300), type=pa.int64())})
+    batches = [ColumnBatch.from_arrow(rb) for rb in t.to_batches(100)]
+
+    class LeafExec(ExecutionPlan):
+        schema = Schema.from_arrow(t.schema)
+
+        def execute(self, partition):
+            return iter(batches)
+
+    class PassExec(ExecutionPlan):
+        schema = LeafExec.schema
+
+        def execute(self, partition):
+            for cb in self.children[0].execute(partition):
+                yield cb
+
+        def arrow_batches(self, partition):
+            for cb in self.execute(partition):     # the inner self-call
+                yield cb.to_arrow()
+
+    class TopExec(PassExec):
+        pass
+
+    leaf = LeafExec()
+    mid = PassExec([leaf])
+    return TopExec([mid]), mid, leaf
+
+
+def _ops(name=None):
+    return [s for s in tracing.spans() if s["name"].startswith("op:")
+            and (name is None or s["name"] == name)]
+
+
+def _attr(span, key, default=None):
+    # the pull that finds the end of the stream carries no attrs
+    return span.get("attrs", {}).get(key, default)
+
+
+def _pulls(name):
+    return [s for s in _ops(name) if _attr(s, "phase") != "open"]
+
+
+def test_operator_pulls_are_nested_real_intervals(traced):
+    top, mid, _leaf = _chain()
+    with tracing.span("task", mode="sync"):
+        rows = sum(b.num_rows for b in top.execute(0))
+    assert rows == 300
+    (task,) = _named("task")
+    by_sid = {s["sid"]: s for s in tracing.spans()}
+    for name in ("op:TopExec", "op:PassExec", "op:LeafExec"):
+        # one for the eager execute() call, one a pull: three batches
+        # and the pull that finds the end
+        assert len(_ops(name)) - len(_pulls(name)) == 1
+        assert [_attr(s, "rows") for s in _pulls(name)] == \
+            [100, 100, 100, None], name
+    # child inside parent, on one thread, by `parent` and by the clock
+    for child, parent in (("op:LeafExec", "op:PassExec"),
+                          ("op:PassExec", "op:TopExec"),
+                          ("op:TopExec", "task")):
+        for s in _pulls(child):
+            up = by_sid[s["parent"]]
+            assert up["name"] == parent
+            assert s["tid"] == up["tid"] == task["tid"]
+            assert up["t0_ns"] <= s["t0_ns"] and s["t1_ns"] <= up["t1_ns"]
+    # the rows an operator's spans carry are the rows its meter counted
+    assert sum(_attr(s, "rows", 0) for s in _pulls("op:PassExec")) == \
+        mid.metrics.get("output_rows") == 300
+
+
+def test_no_span_on_an_operators_re_entrant_self_call(traced):
+    top, mid, _leaf = _chain()
+    assert sum(rb.num_rows for rb in top.arrow_batches(0)) == 300
+    # arrow_batches() is metered; the execute() it calls on itself is
+    # not: one `open` and one span a pull, as without the self-call
+    for name in ("op:TopExec", "op:PassExec"):
+        assert len(_ops(name)) == 1 + 4 and len(_pulls(name)) == 4, name
+    assert mid.metrics.get("output_rows") == 300
+    assert {s["parent"] for s in _pulls("op:LeafExec")} <= \
+        {m["sid"] for m in _pulls("op:PassExec")}
+
+
+def test_a_pull_with_tracing_off_reads_the_flag_and_nothing_else(
+        monkeypatch):
+    """Off, the hot path gains one module-level boolean test a pull: no
+    name is built, no context manager entered, nothing of the tracer
+    called."""
+    assert not tracing._enabled
+
+    def boom(*_a, **_k):
+        raise AssertionError("the tracer was called with tracing off")
+
+    for fn in ("span", "emit_span", "instant", "enabled", "_emit",
+               "_check_name"):
+        monkeypatch.setattr(tracing, fn, boom)
+    top, _mid, leaf = _chain()
+    assert sum(b.num_rows for b in top.execute(0)) == 300
+    assert sum(rb.num_rows for rb in top.arrow_batches(0)) == 300
+    assert leaf.metrics.get("output_rows") == 600
+
+
+def test_two_prefetch_workers_of_one_name_have_two_thread_ids(traced):
+    """Every map task's chain runs on a thread called
+    `blaze-prefetch-shuffle_map`: the name cannot tell them apart, the
+    `tid` can."""
+    gate = threading.Barrier(2)
+
+    def source(k):
+        gate.wait(5)          # both workers alive inside their first item
+        yield k
+        gate.wait(5)
+        yield k
+
+    with tracing.span("task", mode="sync"):
+        a = PrefetchIterator(source(1), depth=1, name="shuffle_map")
+        b = PrefetchIterator(source(2), depth=1, name="shuffle_map")
+        assert list(a) == [1, 1] and list(b) == [2, 2]
+    produced = _named("produce:shuffle_map")
+    assert {s["thread"] for s in produced} == {"blaze-prefetch-shuffle_map"}
+    tids = {s["tid"] for s in produced}
+    assert len(tids) == 2 and threading.get_ident() not in tids
+    (task,) = _named("task")
+    assert {s["parent"] for s in produced} == {task["sid"]}
+    waits = _named("prefetch_wait")
+    assert waits and {s["tid"] for s in waits} == {threading.get_ident()}
+
+
+# -- the three glue sites ----------------------------------------------------
+
+def test_coalesce_span_fires_where_batches_are_joined(traced):
+    from blaze_tpu.batch import ColumnBatch
+    from blaze_tpu.ops.base import CoalesceStream
+
+    def batches(n, rows):
+        return [ColumnBatch.from_arrow(pa.RecordBatch.from_arrays(
+            [pa.array(np.arange(rows, dtype=np.int64))], names=["a"]))
+            for _ in range(n)]
+
+    # large batches pass through one by one: nothing is joined
+    out = list(CoalesceStream(iter(batches(3, 600)), batch_size=1000))
+    assert [b.num_rows for b in out] == [600, 600, 600]
+    assert not _named("coalesce")
+    out = list(CoalesceStream(iter(batches(7, 300)), batch_size=1000))
+    assert [b.num_rows for b in out] == [1200, 900]
+    assert [s["attrs"] for s in _named("coalesce")] == [
+        {"batches": 4, "rows": 1200}, {"batches": 3, "rows": 900}]
+
+
+def test_loop_glue_spans_fire_in_the_stage_loop_and_nowhere_else(
+        tmp_path, staged_loop, file_shuffle, traced):
+    from blaze_tpu.plan.stages import DagScheduler
+    plan, n_groups = _pair_plan(tmp_path)
+    assert DagScheduler().run_collect(plan).num_rows == n_groups
+    by_sid = {s["sid"]: s for s in tracing.spans()}
+    windows, inits = _named("loop_window"), _named("table_init")
+    chunks = _named("stage_loop_chunk")
+    assert windows and len(windows) == len(chunks)
+    assert [w["attrs"]["batches"] for w in windows] == \
+        [c["attrs"]["batches"] for c in chunks]
+    # the pulls of the source are outside the window's span, under their
+    # own op:* spans
+    assert not [s for s in tracing.spans()
+                if s.get("parent") in {w["sid"] for w in windows}
+                and s["name"].startswith("op:")]
+    # no chip is pinned on one device: `device` is there and None
+    assert inits and all(
+        s["attrs"]["slots"] >= 2 and "device" in s["attrs"] for s in inits)
+    for s in windows + inits:
+        up = by_sid[s["parent"]]
+        # a table is made at the first chunk's boundary, inside its span
+        assert up["name"] in ("op:FusedPartialAggExec", "task",
+                              "stage_loop_chunk"), up["name"]
+        assert s["tid"] == up["tid"]
+
+
+def test_loop_glue_spans_stay_silent_off_the_stage_loop(tmp_path, traced):
+    """The same plan on the CPU's default path (host-vectorized
+    aggregation, no device loop): neither span."""
+    from blaze_tpu.plan.stages import DagScheduler
+    MemManager.init(4 << 30)
+    plan, n_groups = _pair_plan(tmp_path)
+    before = xla_stats.snapshot()
+    assert DagScheduler().run_collect(plan).num_rows == n_groups
+    assert xla_stats.delta(before)["stage_loop_tasks"] == 0
+    assert not _named("loop_window") and not _named("table_init")
+    assert _ops()       # the operators still say who held the thread
+
+
+# -- readbacks through the one door ------------------------------------------
+
+def test_a_sorts_selection_mask_is_read_back_through_to_host(
+        monkeypatch, traced):
+    """`_SortState._with_key_columns` on a device batch with a selection:
+    no value leaves the device but inside `xputil.to_host`, so the mask's
+    readback is a `d2h` span and counts in `d2h_bytes`."""
+    import jax
+    from jax._src import array as jarray
+    import blaze_tpu.batch as batch_mod
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops import MemoryScanExec, SortExec
+    from blaze_tpu.ops.sort import _SortState
+    monkeypatch.setattr(batch_mod, "_host_resident", lambda: False)
+    cb = batch_mod.ColumnBatch.from_arrow(pa.RecordBatch.from_arrays(
+        [pa.array(np.arange(1024, dtype=np.int64)[::-1])], names=["a"]))
+    cb = cb.with_selection(jnp.arange(cb.capacity) % 2 == 0)
+    scan = MemoryScanExec(cb.schema, [[cb]])
+    sort = SortExec(scan, [(col(0), True, True)])
+    state = _SortState(sort, scan.schema, sort._specs)
+
+    inside, round_the_door = [], []
+    real_get, real_value = jax.device_get, jarray.ArrayImpl._value
+
+    def device_get(tree):
+        inside.append(1)
+        try:
+            return real_get(tree)
+        finally:
+            inside.pop()
+
+    def value(self):
+        if not inside:
+            round_the_door.append(self.nbytes)
+        return real_value.fget(self)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(jarray.ArrayImpl, "_value", property(value))
+    before = xla_stats.snapshot()
+    rb = state._with_key_columns(cb)
+    d = xla_stats.delta(before)
+    assert rb.num_rows == 512
+    assert not round_the_door
+    # keys, payload and the selection mask twice (to_arrow's and the
+    # sort's own): each a counted transfer under a span
+    assert d["d2h_transfers"] == len(_named("d2h")) >= 3
+    assert d["d2h_bytes"] == sum(s["attrs"]["bytes"] for s in _named("d2h"))
+    assert [s["attrs"]["bytes"] for s in _named("d2h")].count(
+        cb.capacity) >= 2

@@ -98,7 +98,9 @@ def test_tracer_spans_context_and_jsonl(tmp_path):
                     pass
             tracing.instant("xla_compile", kernel="k1", ns=12)
     finally:
-        spans = tracing.stop_tracing()
+        # a collection may run anywhere: its span is not this test's
+        spans = [s for s in tracing.stop_tracing()
+                 if s["name"] != "gc_pause"]
     assert [s["name"] for s in spans] == ["task", "xla_compile"]
     task = spans[0]
     assert task["ctx"] == {"query": "q-test", "stage": 1, "partition": 2}
@@ -108,7 +110,8 @@ def test_tracer_spans_context_and_jsonl(tmp_path):
     assert spans[1]["ctx"] == {"query": "q-test", "stage": 1}
     with open(path) as f:
         lines = [json.loads(ln) for ln in f if ln.strip()]
-    assert [s["name"] for s in lines] == ["task", "xla_compile"]
+    assert [s["name"] for s in lines
+            if s["name"] != "gc_pause"] == ["task", "xla_compile"]
 
 
 def test_tracing_disabled_is_noop():
@@ -138,6 +141,8 @@ def test_operator_totals_in_the_metric_tree_not_in_spans():
     names = {s["name"] for s in spans}
     assert "task" in names
     assert not any(n.startswith("operator:") for n in names)
+    # `op:*` is another thing: one real interval a pull, no total
+    assert {"op:FilterExec", "op:MemoryScanExec"} <= names
     tree = plan.collect_metrics()
     assert tree.name == "FilterExec"
     assert tree.values["elapsed_compute_ns"] > 0

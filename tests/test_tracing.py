@@ -121,6 +121,118 @@ def test_unknown_span_name_rejected_when_enabled():
     assert _by_name(tracing.spans(), "produce:parquet_scan")
 
 
+# -- thread ids, collector pauses, the buffer's bound ------------------------
+
+def test_every_record_carries_the_emitting_threads_id():
+    """`thread` is a name, which threads share; `tid` is the thread's
+    own, so a reader nests spans by it."""
+    tracing.start_tracing()
+    seen = {}
+
+    def emit(key):
+        with tracing.span("task", task=key):
+            tracing.instant("task_retry", task=key)
+            tracing.emit_span("admission_wait", 10, query=key)
+        seen[key] = threading.get_ident()
+
+    # both alive at once: an identifier is unique among LIVE threads
+    gate = threading.Barrier(2)
+    threads = [threading.Thread(target=lambda k=k: (gate.wait(5), emit(k),
+                                                    gate.wait(5)),
+                                name="same-name") for k in (1, 2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(10)
+        assert not t.is_alive()
+    emit(0)
+    recs = [r for r in tracing.spans() if r["name"] != "gc_pause"]
+    assert len(recs) == 9 and all(r["thread"] for r in recs)
+    for r in recs:
+        key = r["attrs"].get("task", r["attrs"].get("query"))
+        assert r["tid"] == seen[key]
+    assert len({seen[1], seen[2], seen[0]}) == 3
+    assert {r["thread"] for r in recs if r["tid"] != seen[0]} == \
+        {"same-name"}
+
+
+def test_gc_pause_is_emitted_while_tracing_and_the_callback_goes():
+    import gc
+    before = list(gc.callbacks)
+    tracing.start_tracing()
+    assert len(gc.callbacks) == len(before) + 1
+    tracing.start_tracing()                      # twice: still one
+    assert len(gc.callbacks) == len(before) + 1
+    with tracing.execution_context(query="q-gc"):
+        with tracing.span("task", mode="sync"):
+            gc.collect()
+    spans = tracing.stop_tracing()
+    assert gc.callbacks == before
+    (task,) = _by_name(spans, "task")
+    full = [s for s in _by_name(spans, "gc_pause")
+            if s["attrs"]["generation"] == 2]
+    assert len(full) == 1
+    (pause,) = full
+    assert pause["dur_ns"] > 0 and pause["attrs"]["collected"] >= 0
+    assert pause["parent"] == task["sid"]
+    assert pause["tid"] == threading.get_ident()
+    assert pause["ctx"] == {"query": "q-gc"}
+    assert task["t0_ns"] <= pause["t0_ns"] and \
+        pause["t1_ns"] <= task["t1_ns"]
+    # off: nothing is installed and a collection leaves no record
+    n = len(tracing.spans())
+    gc.collect()
+    assert len(tracing.spans()) == n and gc.callbacks == before
+
+
+def test_a_collection_inside_the_tracers_lock_does_not_deadlock():
+    """The collector can start at any bytecode, also of a thread that
+    holds the tracer's lock; its callback emits under the same lock."""
+    import gc
+    tracing.start_tracing()
+    done = []
+
+    def collect_under_the_lock():
+        with tracing._lock:
+            gc.collect()
+        done.append(True)
+
+    t = threading.Thread(target=collect_under_the_lock)
+    t.start()
+    t.join(10)
+    assert done and not t.is_alive()
+    assert _by_name(tracing.stop_tracing(), "gc_pause")
+
+
+def test_trimming_the_buffer_is_counted(monkeypatch):
+    monkeypatch.setattr(tracing, "_MAX_SPANS", 5)
+    before = xla_stats.snapshot()
+    tracing.start_tracing()
+    assert tracing.dropped() == 0
+    for i in range(8):
+        tracing.instant("task_retry", task=i)
+    kept = _by_name(tracing.spans(), "task_retry")
+    total = len(tracing.spans()) + tracing.dropped()
+    assert len(tracing.spans()) == 5
+    assert tracing.dropped() == total - 5 >= 3
+    assert [r["attrs"]["task"] for r in kept][-1] == 7   # the newest stay
+    assert xla_stats.delta(before)["obs_spans_dropped"] == tracing.dropped()
+    # what a child ships home is bounded by the same count
+    tracing.ingest([{"name": "worker_task", "t0_ns": 1, "t1_ns": 2,
+                     "dur_ns": 1}] * 4, worker=1)
+    assert len(tracing.spans()) == 5
+    assert xla_stats.delta(before)["obs_spans_dropped"] == \
+        tracing.dropped() >= 7
+    tracing.start_tracing()                      # a new window counts anew
+    assert tracing.dropped() == 0
+
+
+def test_register_span_is_gone():
+    """It had no caller: a name is registered in SPAN_NAMES, where the
+    conformance tests see it."""
+    assert not hasattr(tracing, "register_span")
+
+
 # -- wire roundtrip ---------------------------------------------------------
 
 def test_wire_context_and_child_rebase_stitch_one_trace():
@@ -462,6 +574,7 @@ def test_span_registry_pin():
         "d2h", "h2d", "prefetch_wait", "produce:*", "join_build",
         "join_probe", "agg_drain", "sort_device", "smj_merge",
         "partial_passthrough", "table_rehash",
+        "op:*", "coalesce", "loop_window", "table_init", "gc_pause",
         "task_retry", "fault_injected", "xla_compile",
         "device_shuffle_fallback", "rss_shuffle_fallback",
         "stage_loop_fallback", "quota_breach", "mem_spill",
