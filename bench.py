@@ -1122,7 +1122,7 @@ def device_compute_loop(sr_paths, dd_path, iters: int = 32):
         num_slots *= (rhi - rlo + 2)
 
     window = next(F._batch_windows(node._source.execute(0), 1))
-    cols_stacked, masks, _cnt = window
+    cols_stacked, masks, _rows, _cnt = window
     # true per-iteration HBM operand traffic: column data + validity
     # bytes + the row mask (one-hot operands never leave VMEM)
     bpr = sum(c[0].dtype.itemsize + 1 for c in cols_stacked if c is not

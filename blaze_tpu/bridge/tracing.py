@@ -154,10 +154,12 @@ SPAN_NAMES: Dict[str, str] = {
                     "from_slots, to_slots, groups, device)",
     "coalesce": "small batches concatenated into one by the coalescing "
                 "stream (ops/base.py CoalesceStream; attrs batches, rows)",
-    "loop_window": "a chunk's source batches padded and stacked column "
-                   "by column for the stage loop's fold, the pulls of the "
-                   "source outside it (plan/fused.py _batch_windows; "
-                   "attrs batches)",
+    "loop_window": "a chunk's source batches assembled for the stage "
+                   "loop's fold by ONE device program (stacked, widened "
+                   "to the chunk, selected lanes counted), after a pad "
+                   "of each batch of another capacity; the pulls of the "
+                   "source outside it (plan/fused.py _assemble_window; "
+                   "attrs batches, padded: batches padded first)",
     "table_init": "the stage loop allocates an empty hash table "
                   "(runtime/loop.py _fold_partition; attrs slots, device)",
     "gc_pause": "one run of Python's cyclic garbage collector, on the "

@@ -123,7 +123,8 @@ _stage_loop = {"stage_loop_programs_built": 0,
                "stage_loop_full_rounds": 0, "stage_loop_narrow_rounds": 0,
                "stage_loop_max_slots": 0,
                "stage_loop_fallbacks": 0,
-               "stage_loop_staged_dispatches_avoided": 0}
+               "stage_loop_staged_dispatches_avoided": 0,
+               "stage_loop_windows": 0, "stage_loop_windows_fused": 0}
 
 # Adaptive partial-aggregation accounting (ops/agg/exec.py _AggState,
 # plan/fused.py host lane): cardinality probes run, mode switches
@@ -430,6 +431,8 @@ def _chip_entry(chip: int) -> Dict[str, int]:
         entry = _chips[chip] = {"tasks": 0, "h2d_bytes": 0, "d2h_bytes": 0,
                                 "join_probe_device_rows": 0,
                                 "join_probe_host_rows": 0,
+                                "stage_loop_windows": 0,
+                                "stage_loop_windows_fused": 0,
                                 **{k: 0 for k in _CHIP_TABLE_KEYS}}
     return entry
 
@@ -490,7 +493,8 @@ def placement_stats() -> dict:
 
 def chip_stats() -> Dict[int, Dict[str, int]]:
     """device id -> {"tasks", "h2d_bytes", "d2h_bytes",
-    "join_probe_device_rows", "join_probe_host_rows" and the stage loop's
+    "join_probe_device_rows", "join_probe_host_rows",
+    "stage_loop_windows", "stage_loop_windows_fused" and the stage loop's
     table counters (_CHIP_TABLE_KEYS)} since the last reset: what each
     chip was given to do."""
     with _lock:
@@ -940,6 +944,18 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int,
             _stage_loop["stage_loop_max_slots"], int(slots))
         _stage_loop["stage_loop_staged_dispatches_avoided"] += \
             int(dispatches_avoided)
+
+
+def note_stage_loop_window(fused: bool, chip: int) -> None:
+    """One window of source batches assembled for a fold
+    (plan/fused.py `_batch_windows`): stacked, widened and counted by
+    the one window program.  `fused` says that nothing ran beside it: no
+    batch of the window had to be padded to the others' capacity first,
+    array by array.  Kept by `chip` too."""
+    with _lock:
+        for counters in (_stage_loop, _chip_entry(chip)):
+            counters["stage_loop_windows"] += 1
+            counters["stage_loop_windows_fused"] += int(fused)
 
 
 def note_stage_loop_fallback(reason: str = "") -> None:

@@ -41,6 +41,7 @@ import pyarrow as pa
 from blaze_tpu import config
 from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.bridge import tracing, xla_stats
+from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.exprs import BoundReference, PhysicalExpr
 from blaze_tpu.ops.agg.exec import AggExec, AggMode
@@ -1559,7 +1560,7 @@ class FusedPartialAggExec(ExecutionPlan):
                 op = np.minimum if is_min else np.maximum
                 wide_mm[i][:] = op(wide_mm[i], np.asarray(mm[i], np.int64))
 
-        for cols_stacked, masks, count in _batch_windows(
+        for cols_stacked, masks, _rows, count in _batch_windows(
                 self._source.execute(partition),
                 config.FUSED_FOLD_WINDOW.get()):
             wrows = int(masks.shape[0]) * int(masks.shape[1])
@@ -1623,7 +1624,7 @@ class FusedPartialAggExec(ExecutionPlan):
             fold = _dense_fold_factory(self._prepare_key, self._prepare,
                                        tuple(self._ranges), tuple(kinds),
                                        num_slots)
-            for cols_stacked, masks, count in _batch_windows(
+            for cols_stacked, masks, _rows, count in _batch_windows(
                     self._source.execute(partition),
                     config.FUSED_FOLD_WINDOW.get()):
                 if carry is None:
@@ -2150,43 +2151,56 @@ def _prepare_factory(key, source_schema: Schema, chain, group_exprs,
     return result
 
 
-def _batch_windows(stream, window: int):
-    """Stack up to `window` source batches into (cols_stacked, masks,
-    count) with uniform capacity (tail batches pad with masked lanes)."""
+def _batch_windows(stream, window: int, pad_tail: bool = False):
+    """Up to `window` source batches a time as (cols_stacked, masks,
+    batch_rows, count): every column, validity and mask stacked on a new
+    leading axis by ONE device program a window (`_window_jit`), with the
+    masks' selected lanes a real batch beside them.  With `pad_tail` a
+    window of fewer batches is widened to `window` with masked-out ones,
+    so every window of a stream shares its consumer's one jit signature
+    (the batch-axis analog of the row-axis bucket ladder); `count` is the
+    number of real batches either way."""
     buf = []
     for batch in stream:
         buf.append(_source_inputs(batch))
         if len(buf) >= window:
-            yield _traced_stack(buf)
+            yield _assemble_window(buf, len(buf))
             buf = []
     if buf:
-        yield _traced_stack(buf)
+        yield _assemble_window(buf, window if pad_tail else len(buf))
 
 
-def _traced_stack(items):
-    with tracing.span("loop_window", batches=len(items)):
-        return _stack_window(items)
-
-
-def _stack_window(items):
+def _assemble_window(items, width: int):
+    """The window program enters all of a window's batches at ONE
+    capacity (its signature is capacity, batch count, column dtypes): an
+    odd batch, a stream's tail or a short IPC batch, is padded with
+    masked lanes to the window's capacity first, array by array, and
+    counted (`padded`).  The pulls of the source stay outside the span."""
     cap = max(m.shape[0] for _c, m in items)
+    short = [i for i, (_c, m) in enumerate(items) if m.shape[0] != cap]
+    with tracing.span("loop_window", batches=len(items), padded=len(short)):
+        for i in short:
+            items[i] = jax.tree_util.tree_map(
+                lambda a: jnp.pad(a, (0, cap - a.shape[0])), items[i])
+        cols_stacked, masks, batch_rows = _window_jit(width)(tuple(items))
+    xla_stats.note_stage_loop_window(fused=not short,
+                                     chip=current_task().device_id)
+    return cols_stacked, masks, batch_rows, len(items)
 
-    def padto(a):
-        if a.shape[0] == cap:
-            return a
-        widths = [(0, cap - a.shape[0])] + [(0, 0)] * (a.ndim - 1)
-        return jnp.pad(a, widths)
 
-    masks = jnp.stack([padto(m) for _c, m in items])
-    ncols = len(items[0][0])
-    cols = []
-    for i in range(ncols):
-        if items[0][0][i] is None:
-            cols.append(None)
-        else:
-            cols.append((jnp.stack([padto(c[i][0]) for c, _m in items]),
-                         jnp.stack([padto(c[i][1]) for c, _m in items])))
-    return tuple(cols), masks, len(items)
+@functools.lru_cache(maxsize=128)
+def _window_jit(width: int):
+    """ONE program a window: `items` are the window's (cols_flat, mask)
+    as they leave `_source_inputs`, all at one capacity; host (string)
+    columns are None and stay None."""
+    def window_impl(items):
+        cols, masks = jax.tree_util.tree_map(
+            lambda *arrays: jnp.pad(jnp.stack(arrays),
+                                    ((0, width - len(items)), (0, 0))),
+            *items)
+        return cols, masks, jnp.sum(masks[:len(items)], axis=1)
+
+    return meter_jit(window_impl, name="runtime.stage_loop_window")
 
 
 def _dense_fold_factory(key, prepare, ranges, kinds, num_slots: int):

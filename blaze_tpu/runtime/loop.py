@@ -8,7 +8,11 @@ This loop folds a CHUNK of bucket-padded batches per dispatch:
 `lax.while_loop` runs chain + probe-insert + accumulate for every batch
 of the chunk inside ONE program, carrying the agg hash table across
 iterations with buffer donation, so Python-side dispatches per
-partition drop from O(batches x operators) to O(chunks).
+partition drop from O(batches x operators) to O(chunks).  The chunk is
+assembled by one program as well (`plan/fused.py` `_window_jit`: the
+batches stacked, a tail chunk widened with masked-out batches to the one
+width the fold is compiled for, the selected lanes a batch counted), so
+a chunk is two dispatches and two readbacks.
 
 Capacity is reserved BEFORE a chunk is folded, in every agg mode: at
 the chunk boundary the host already holds the chunk's row counts and
@@ -302,24 +306,6 @@ def _donate_active() -> bool:
             and jax.default_backend() != "cpu")
 
 
-def _pad_chunk(cols_stacked, masks, window: int):
-    """Pad a tail chunk up to the full window with masked-out batches so
-    every chunk of a rung shares ONE jit signature (the batch-axis analog
-    of the row-axis bucket ladder)."""
-    w = int(masks.shape[0])
-    if w == window:
-        return cols_stacked, masks
-    extra = window - w
-
-    def padto(a):
-        widths = [(0, extra)] + [(0, 0)] * (a.ndim - 1)
-        return jnp.pad(a, widths)
-
-    cols = tuple(None if c is None else (padto(c[0]), padto(c[1]))
-                 for c in cols_stacked)
-    return cols, padto(masks)
-
-
 def loop_chunk_batches() -> int:
     """Configured chunk width, shrunk for degraded queries: the memory
     degradation ladder (serving/context.py) halves the chunk per shrink
@@ -417,7 +403,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
     ratio = config.PARTIAL_AGG_SKIPPING_RATIO.get()
     stream = (source_stream if source_stream is not None
               else program.source.execute(partition))
-    windows = _batch_windows(stream, chunk)
+    windows = _batch_windows(stream, chunk, pad_tail=True)
     batches = rows = fold_calls = regrows = reserves = 0
     # (old table's slots, groups it held, new slots, lanes re-inserted)
     rehashes = []
@@ -464,7 +450,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         raise StageLoopFallback(f"table would exceed {_MAX_SLOTS} slots")
 
     try:
-        for cols_stacked, masks, count in windows:
+        for cols_stacked, masks, batch_rows, count in windows:
             # chunk boundary: cooperative cancel, fault site, row counts.
             # The loop's host syncs are here and after each fold (the
             # overflow scalars with the table's group count and the
@@ -474,9 +460,10 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
             with tracing.span("stage_loop_chunk", stage=ctx,
                               partition=partition, chunk=ci,
                               batches=count, device=task.device_id):
-                # selected lanes per batch: before the chain's filter,
-                # so an upper bound on the rows the fold will insert
-                batch_rows = to_host(jnp.sum(masks, axis=1)).tolist()
+                # selected lanes per real batch, counted by the window's
+                # program: before the chain's filter, so an upper bound
+                # on the rows the fold will insert
+                batch_rows = to_host(batch_rows).tolist()
                 # reserve before fold
                 need = groups + sum(batch_rows)
                 if carry is None or need > slots * _TRIGGER_LOAD:
@@ -485,8 +472,6 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                         reserves += 1
                     if carry is None or want > slots:
                         carry, slots = resized(want)
-                cols_stacked, masks = _pad_chunk(cols_stacked, masks,
-                                                 chunk)
                 start = 0
                 while start < count:
                     # the first look: stop the fold once `min_rows` live
@@ -563,7 +548,6 @@ def _pass_through(program, rest: _Unfolded, partition: int, ctx: str):
     task = current_task()
     agg = program.agg
     passthrough = _passthrough_factory(program)
-    chunk = int(rest.masks.shape[0])
 
     def chunks():
         # the boundary checks of the window the switch fell in ran
@@ -571,11 +555,11 @@ def _pass_through(program, rest: _Unfolded, partition: int, ctx: str):
         if rest.start < rest.count:
             yield (rest.chunk, rest.cols_stacked, rest.masks, rest.count,
                    rest.start)
-        for ci, (cols_stacked, masks, count) in enumerate(rest.windows,
-                                                          rest.chunk + 1):
+        for ci, (cols_stacked, masks, _rows, count) in enumerate(
+                rest.windows, rest.chunk + 1):
             task.check_running()
             faults.maybe_fail("device-loop", stage=ctx, chunk=ci)
-            yield (ci, *_pad_chunk(cols_stacked, masks, chunk), count, 0)
+            yield ci, cols_stacked, masks, count, 0
 
     for ci, cols_stacked, masks, count, start in chunks():
         with tracing.span("partial_passthrough", stage=ctx,

@@ -751,6 +751,42 @@ def test_loop_glue_spans_fire_in_the_stage_loop_and_nowhere_else(
         assert s["tid"] == up["tid"]
 
 
+def test_loop_window_opens_once_a_window_and_the_counters_add_up(
+        tmp_path, staged_loop, file_shuffle, traced):
+    """Map tasks of 4,000 rows in batches of 512 (the last one of 416
+    rows: its capacity is the others'), chunks of three: every window
+    says how many batches it holds and how many of them had to be padded
+    to the window's capacity first; the two counters count the windows
+    and those that needed no pad."""
+    from blaze_tpu.plan.stages import DagScheduler
+    config.conf.set(config.BATCH_SIZE.key, 512)
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, 3)
+    try:
+        plan, n_groups = _pair_plan(tmp_path)
+        before = xla_stats.snapshot()
+        assert DagScheduler().run_collect(plan).num_rows == n_groups
+        d = xla_stats.delta(before)
+    finally:
+        config.conf.unset(config.BATCH_SIZE.key)
+        config.conf.unset(config.STAGE_DEVICE_LOOP_CHUNK.key)
+    windows, chunks = _named("loop_window"), _named("stage_loop_chunk")
+    assert len(windows) == len(chunks) == d["stage_loop_windows"] > 2
+    assert all(set(w["attrs"]) >= {"batches", "padded"} for w in windows)
+    assert [w["attrs"]["batches"] for w in windows] == \
+        [c["attrs"]["batches"] for c in chunks]
+    # each map task: 8 batches as 3 + 3 + 2
+    assert sorted(w["attrs"]["batches"] for w in windows)[-4:] == [3] * 4
+    assert 2 in [w["attrs"]["batches"] for w in windows]
+    assert d["stage_loop_windows_fused"] == \
+        sum(w["attrs"]["padded"] == 0 for w in windows)
+    assert d["stage_loop_windows"] - d["stage_loop_windows_fused"] == \
+        sum(w["attrs"]["padded"] > 0 for w in windows)
+    # by chip they sum to the whole
+    for k in ("stage_loop_windows", "stage_loop_windows_fused"):
+        assert sum(v for name, v in d.items() if name.startswith("chip")
+                   and name.endswith("_" + k)) == d[k]
+
+
 def test_loop_glue_spans_stay_silent_off_the_stage_loop(tmp_path, traced):
     """The same plan on the CPU's default path (host-vectorized
     aggregation, no device loop): neither span."""

@@ -210,3 +210,42 @@ def test_stage_loop_capacity_rungs_compile_once(tmp_path, loop_on,
     assert d["stage_loop_reserves"] > 1
     assert d["stage_loop_rehash_lanes"] > 0
     assert d["stage_loop_fallbacks"] == 0
+
+
+# -- ISSUE 36: the window program -------------------------------------------
+
+@pytest.mark.parametrize("chunk,counts", [(1, {1}), (3, {3, 2}), (8, {8})])
+def test_stage_loop_window_program_a_batch_count(tmp_path, loop_on, chunk,
+                                                 counts):
+    """A partition of 8 batches at one capacity in chunks of `chunk`: at
+    most one window program a distinct batch count, and a second run of
+    the same partition asks for none."""
+    config.conf.set(config.BATCH_SIZE.key, 256)
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, chunk)
+    try:
+        def windows():
+            k = xla_stats.compile_report()["kernels"].get(
+                "runtime.stage_loop_window", {})
+            return k.get("calls", 0), k.get("compiles", 0)
+
+        def plan():
+            return _fused(_loop_agg_plan(tmp_path, tag=f"w{chunk}"))
+        calls, compiles = windows()
+        before = xla_stats.snapshot()
+        assert list(plan().execute(0))
+        d = xla_stats.delta(before)
+        n_windows = -(-8 // chunk)
+        assert d["stage_loop_windows"] == n_windows
+        assert d["stage_loop_windows_fused"] == n_windows
+        assert windows()[0] - calls == n_windows
+        assert windows()[1] - compiles <= len(counts)
+        calls, compiles = windows()
+        before = xla_stats.snapshot()
+        assert list(plan().execute(0))
+        d = xla_stats.delta(before)
+        assert windows() == (calls + n_windows, compiles)
+        assert d["total_compiles"] == 0 and d["backend_compiles"] == 0
+        assert d["stage_loop_fallbacks"] == 0
+    finally:
+        config.conf.unset(config.BATCH_SIZE.key)
+        config.conf.unset(config.STAGE_DEVICE_LOOP_CHUNK.key)

@@ -580,8 +580,13 @@ def _overflowing_keys():
     return np.concatenate([np.repeat(plain, 2), np.resize(bad, 512)])
 
 
+@pytest.mark.parametrize("chunk", [2, 4])
 def test_residual_overflow_regrows_and_resumes(tmp_path, loop_on,
-                                               small_tables, capacities):
+                                               small_tables, capacities,
+                                               chunk):
+    # a chunk of 4 folds the same two batches as a tail window widened
+    # with two masked-out ones: the resume at batch 1 is the same
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, chunk)
     plan, want = _sum_agg(tmp_path, _overflowing_keys(), "partial", "ovf")
     before = xla_stats.snapshot()
     got = _emitted(plan)
@@ -1029,6 +1034,110 @@ def test_a_switch_at_a_windows_last_batch_passes_the_next_window_on(
     assert d["partial_agg_skipped_rows"] == 8192 - 2048
     assert [(a["chunk"], a["batches"]) for a in spans] == [
         (1, 4), (2, 4), (3, 4)]
+    grouped, _d = _run_skipping(
+        lambda: _skip_agg(t, _AGGS["int_sum_counts"]), enable=False)
+    _assert_same_answer(_final_merge(skipped, _AGGS["int_sum_counts"]),
+                        _final_merge(grouped, _AGGS["int_sum_counts"]))
+
+
+# -- the window is one device program (ISSUE 36) -----------------------------
+
+def _window_batches(count, short_tail):
+    """`count` batches of an int64, a float64 with NULLs and a string
+    column (host: no device form), the last one short where asked."""
+    from blaze_tpu.batch import ColumnBatch
+    rng = np.random.default_rng(36)
+    out = []
+    for b in range(count):
+        n = 100 if short_tail and b == count - 1 else 512
+        v = rng.random(n)
+        out.append(ColumnBatch.from_arrow(pa.RecordBatch.from_arrays(
+            [pa.array(rng.integers(-9, 9, n), type=pa.int64()),
+             pa.array(v, mask=v < 0.2),
+             pa.array([f"s{i}" for i in range(n)])],
+            names=["i", "v", "s"])))
+    return out
+
+
+def _numpy_window(items, width):
+    """The window as it was assembled before it was one program: every
+    array padded to the window's capacity, stacked, and the batch axis
+    padded to the width the caller folds at."""
+    cap = max(m.shape[0] for _c, m in items)
+
+    def wide(arrays):
+        a = np.stack([np.pad(np.asarray(a), (0, cap - a.shape[0]))
+                      for a in arrays])
+        return np.pad(a, ((0, width - len(arrays)), (0, 0)))
+
+    cols = tuple(None if col is None else
+                 (wide([c[i][0] for c, _m in items]),
+                  wide([c[i][1] for c, _m in items]))
+                 for i, col in enumerate(items[0][0]))
+    masks = wide([m for _c, m in items])
+    return cols, masks, masks[:len(items)].sum(axis=1)
+
+
+@pytest.mark.parametrize("tail", ["even", "short"])
+@pytest.mark.parametrize("pad_tail", [False, True], ids=["as_is", "widened"])
+@pytest.mark.parametrize("count", [1, 3, 8])
+def test_window_program_is_the_numpy_formulation_bit_for_bit(count, pad_tail,
+                                                             tail):
+    from blaze_tpu.plan import fused as F
+    batches = _window_batches(count, tail == "short")
+    # a short batch alone is its window's capacity: nothing to pad
+    short = int(tail == "short" and count > 1)
+    want_cols, want_masks, want_rows = _numpy_window(
+        [F._source_inputs(b) for b in batches], 8 if pad_tail else count)
+    before = xla_stats.snapshot()
+    (window,) = F._batch_windows(iter(batches), 8, pad_tail=pad_tail)
+    d = xla_stats.delta(before)
+    cols, masks, rows, n = window
+    assert n == count
+    assert cols[2] is None and want_cols[2] is None  # the string column
+    for got, want in zip(cols[:2], want_cols[:2]):
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            # bit for bit: a float's NaN payload would fail array_equal
+            assert np.asarray(g).tobytes() == w.tobytes()
+    assert masks.dtype == np.bool_
+    np.testing.assert_array_equal(np.asarray(masks), want_masks)
+    np.testing.assert_array_equal(np.asarray(rows), want_rows)
+    assert np.asarray(rows).tolist() == [b.num_rows for b in batches]
+    assert d["stage_loop_windows"] == 1
+    assert d["stage_loop_windows_fused"] == 1 - short
+
+
+def test_a_switched_partition_passes_a_widened_tail_window_on(loop_on,
+                                                              skip_conf):
+    """16 batches of 512 rows and one of 200 in chunks of three: the
+    last window holds two batches, the short one padded to the other's
+    capacity, widened to three.  The switch passes it on as it passes
+    the whole ones."""
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, 3)
+    from blaze_tpu.bridge import tracing
+    t = _skip_table(n=8392)
+    tracing.start_tracing()
+    try:
+        skipped, d = _run_skipping(
+            lambda: _skip_agg(t, _AGGS["int_sum_counts"]))
+        spans = tracing.spans()
+    finally:
+        tracing.stop_tracing()
+        tracing.reset_conf_probe()
+    windows = [s["attrs"] for s in spans if s["name"] == "loop_window"]
+    assert [(a["batches"], a["padded"]) for a in windows] == \
+        [(3, 0)] * 5 + [(2, 1)]
+    assert d["stage_loop_windows"] == 6
+    assert d["stage_loop_windows_fused"] == 5
+    # the first look falls in the first window; every later one passes
+    passed = [(s["attrs"]["chunk"], s["attrs"]["batches"]) for s in spans
+              if s["name"] == "partial_passthrough"]
+    assert passed[-1] == (5, 2) and [c for c, _b in passed[1:]] == \
+        [1, 2, 3, 4, 5]
+    assert d["partial_agg_skip_events"] == 1
+    assert d["partial_agg_skipped_rows"] == \
+        8392 - d["partial_agg_switch_rows"]
     grouped, _d = _run_skipping(
         lambda: _skip_agg(t, _AGGS["int_sum_counts"]), enable=False)
     _assert_same_answer(_final_merge(skipped, _AGGS["int_sum_counts"]),
