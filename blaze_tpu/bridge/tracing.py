@@ -92,7 +92,24 @@ SPAN_NAMES: Dict[str, str] = {
     "device_exchange": "on-device collective shuffle dispatch for one "
                        "stage (plan/stages.py -> DeviceExchange; attrs "
                        "device: the chip of the thread that drives it, "
-                       "an overlapped one its map task's; chips)",
+                       "an overlapped one its map task's; chips; rows; "
+                       "staged: the wave's rows were gathered on the "
+                       "host and cut over the mesh)",
+    "exchange_stage": "first part of a synchronous device_exchange, on "
+                      "the scheduler's thread: from the span's opening "
+                      "to the collective's dispatch returning (the host "
+                      "concat of a staged wave, padding, the cut over "
+                      "the mesh; a placed wave's per-chip assembly) "
+                      "(plan/stages.py _exchange_sync; attrs stage, "
+                      "rows, bytes, tasks, staged_tasks, device)",
+    "exchange_unstage": "last part of a synchronous device_exchange: "
+                        "from the overflow scalar's arrival to the last "
+                        "IPC block (the readback of the receive "
+                        "buffers, padding included, the host split by "
+                        "partition, the Arrow batch and its IPC bytes); "
+                        "between exchange_stage and this lies the wait "
+                        "for the collective (plan/stages.py; attrs "
+                        "rows, bytes_read, partitions)",
     "rss_exchange": "remote-shuffle-service exchange tier for one stage "
                     "(plan/stages.py)",
     "shuffle_exchange": "file-tier shuffle exchange for one stage "
@@ -113,7 +130,9 @@ SPAN_NAMES: Dict[str, str] = {
     "h2d": "one host-to-device placement; device_put returns before the "
            "copy lands, so this is host staging and dispatch time "
            "(xputil.to_device; attrs bytes, device: the task's chip, "
-           "which the buffers are committed to)",
+           "which the buffers are committed to; a staged device "
+           "exchange's columns, cut over the mesh, under the driving "
+           "thread's chip: parallel/stage.py DeviceExchange.dispatch)",
     "prefetch_wait": "the consumer blocked on a prefetch queue: the "
                      "producer thread is behind (ops/base.py "
                      "PrefetchIterator.__next__; attrs source)",

@@ -90,6 +90,13 @@ _shuffle = {"shuffle_device_bytes": 0, "shuffle_host_bytes": 0,
             "shuffle_device_exchanges": 0,
             "shuffle_device_collectives": 0,
             "shuffle_device_fallbacks": 0,
+            # where a device exchange took its rows from (noted at
+            # dispatch): host columns cut evenly over the mesh, or the
+            # chips the map output lay on; and ladder rungs climbed
+            # after a destination bucket overflowed
+            "shuffle_device_staged_rows": 0,
+            "shuffle_device_placed_rows": 0,
+            "shuffle_device_redispatches": 0,
             # overlapped exchange (PR 18): per-task tickets drained in
             # the background, and the host-side barrier — time from the
             # last fold completing to the first collective dispatch —
@@ -833,6 +840,22 @@ def note_device_exchange(rows: int, nbytes: int,
         _shuffle["shuffle_device_bytes"] += int(nbytes)
         _shuffle["shuffle_device_row_bytes"] += int(row_bytes)
         _shuffle["shuffle_device_collectives"] += int(collectives)
+
+
+def note_exchange_source(staged: int = 0, placed: int = 0) -> None:
+    """One device exchange dispatched: `staged` rows entered the
+    collective from host columns (`DeviceExchange.dispatch`), `placed`
+    rows from the chips they lay on (`dispatch_placed`)."""
+    with _lock:
+        _shuffle["shuffle_device_staged_rows"] += int(staged)
+        _shuffle["shuffle_device_placed_rows"] += int(placed)
+
+
+def note_exchange_redispatch() -> None:
+    """A destination bucket overflowed and the exchange was dispatched
+    again at the ladder's next rung."""
+    with _lock:
+        _shuffle["shuffle_device_redispatches"] += 1
 
 
 def note_host_exchange(nbytes: int) -> None:

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from blaze_tpu.kernels import compare
-from blaze_tpu.xputil import to_host
+from blaze_tpu.xputil import to_device, to_host
 
 
 class AggTable(NamedTuple):
@@ -650,13 +650,15 @@ class ExchangeTicket:
     padded send buffers kept alive for an overflow re-dispatch), the
     per-rung accounting accumulated so far, and the host-split
     metadata.  Produced by `dispatch`, consumed exactly once by
-    `drain`; between the two the collective and the D2D partition
+    `drain` (which says in `read_bytes` what it read back, padding
+    included); between the two the collective and the D2D partition
     routing are free to run while the host folds the next chunk."""
 
     __slots__ = ("out", "rungs", "row_valid", "datas", "vbufs",
                  "key_idx", "dtypes", "n", "ncols", "n_out",
                  "n_dev", "rows_per_dev", "ctx", "moved_bytes",
-                 "collectives", "dispatch_ns", "parts")
+                 "collectives", "dispatch_ns", "parts", "settled",
+                 "read_bytes")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -676,9 +678,10 @@ class DeviceExchange:
 
     The driver is split into `dispatch` (everything through issuing the
     first rung's shard_map call — returns an ExchangeTicket holding the
-    unawaited device futures) and `drain` (the overflow host sync, the
-    rung climb, accounting, and the host split).  `exchange` composes
-    the two back-to-back, which IS the synchronous path byte-for-byte;
+    unawaited device futures), `settle` (the overflow host sync, the
+    rung climb, accounting) and `drain` (`settle` where the caller has
+    not, then the readback and the host split).  `exchange` composes
+    them back-to-back, which IS the synchronous path byte-for-byte;
     the overlapped scheduler (plan/stages.py) instead drains ticket k
     on a background thread while task k+1 is still folding.
     """
@@ -715,8 +718,11 @@ class DeviceExchange:
         (host columns: the staged collect).  Device columns of map
         tasks go through `dispatch_placed`, which leaves them on the
         chips they lie on."""
+        from jax.sharding import NamedSharding, PartitionSpec as PS
+
         from blaze_tpu.batch import bucket_capacity
-        from blaze_tpu.parallel.mesh import DP_AXIS, shard_rows
+        from blaze_tpu.bridge import xla_stats
+        from blaze_tpu.parallel.mesh import DP_AXIS
 
         ncols = len(columns)
         if ncols == 0:
@@ -735,7 +741,11 @@ class DeviceExchange:
         row_valid[:n] = True
         datas = [_pad_rows(c, total) for c in columns]
         vbufs = [_pad_rows(v, total, dtype=bool) for v in valids]
-        row_valid, *rest = shard_rows(self.mesh, row_valid, *datas, *vbufs)
+        # the one H2D of a staged wave, counted as a task's is
+        row_valid, *rest = to_device(
+            (row_valid, *datas, *vbufs),
+            NamedSharding(self.mesh, PS(DP_AXIS)))
+        xla_stats.note_exchange_source(staged=n)
         return self._fire(row_valid, rest[:ncols], rest[ncols:], n,
                           rows_per_dev, dtypes, key_indices, n_out, ctx)
 
@@ -754,6 +764,7 @@ class DeviceExchange:
         from jax.sharding import NamedSharding, PartitionSpec as PS
 
         from blaze_tpu.batch import bucket_capacity
+        from blaze_tpu.bridge import xla_stats
         from blaze_tpu.parallel.mesh import DP_AXIS
         from blaze_tpu.xputil import on_task_chip
 
@@ -796,6 +807,7 @@ class DeviceExchange:
             jax.make_array_from_single_device_arrays(
                 (total,), sharding, [sh[k] for sh in shards])
             for k in range(1 + 2 * ncols)]
+        xla_stats.note_exchange_source(placed=sum(rows.values()))
         return self._fire(row_valid, rest[:ncols], rest[ncols:],
                           sum(rows.values()), rows_per_dev, dtypes,
                           key_indices, n_out, ctx)
@@ -838,10 +850,14 @@ class DeviceExchange:
         n_dev = int(self.mesh.shape[DP_AXIS])
         # capacity ladder: start at skew * expected rows/destination,
         # retry the next rung on overflow; rows_per_dev (= every local
-        # row routed to ONE destination) is the guaranteed-fit ceiling
+        # row routed to ONE destination) is the guaranteed-fit ceiling.
+        # Partition r goes to device r % n_dev, so an exchange to fewer
+        # partitions than devices has that many destinations: a gather
+        # to ONE partition starts at the ceiling, not a rung below it
         skew = max(1.0, config.MESH_EXCHANGE_SKEW.get())
-        expect = -(-rows_per_dev // n_dev)
-        start = bucket_capacity(max(int(expect * skew), 1))
+        expect = -(-rows_per_dev // min(int(n_out), n_dev))
+        start = min(bucket_capacity(max(int(expect * skew), 1)),
+                    bucket_capacity(rows_per_dev))
         rungs = [c for c in bucket_ladder(rows_per_dev) if c >= start]
         if not rungs:
             rungs = [start]
@@ -866,19 +882,19 @@ class DeviceExchange:
             moved_bytes=moved_bytes, collectives=collectives,
             dispatch_ns=_time.perf_counter_ns())
 
-    def drain(self, ticket: ExchangeTicket):
-        """Await a dispatched exchange: block on the overflow scalar
+    def settle(self, ticket: ExchangeTicket) -> None:
+        """Wait for a dispatched exchange: block on the overflow scalar
         (the one host sync), climb the remaining ladder rungs when a
         destination bucket overflowed (re-firing the per-shard fault
-        sites per re-dispatch, exactly like the synchronous loop), then
-        split the received rows into per-partition numpy columns."""
+        sites per re-dispatch, exactly like the synchronous loop), and
+        count what rode.  The received rows stay on the mesh until
+        `drain` reads them back; a ticket is settled once."""
         from blaze_tpu import faults
         from blaze_tpu.bridge import xla_stats
         from blaze_tpu.parallel.collective import exchange_wire_cost
 
-        if ticket.parts is not None:
-            return ticket.parts
-        ncols, n_out = ticket.ncols, ticket.n_out
+        if ticket.settled or ticket.parts is not None:
+            return
         out = ticket.out
         result = None
         while True:
@@ -892,9 +908,10 @@ class DeviceExchange:
             for d in range(ticket.n_dev):
                 faults.maybe_fail("device-collective", shard=d,
                                   stage=ticket.ctx)
-            fn = _exchange_program(self.mesh, n_out, int(cap),
+            fn = _exchange_program(self.mesh, ticket.n_out, int(cap),
                                    ticket.key_idx, ticket.dtypes)
             out = fn(ticket.row_valid, *ticket.datas, *ticket.vbufs)
+            xla_stats.note_exchange_redispatch()
             mb, cc = exchange_wire_cost(ticket.n_dev, cap, ticket.dtypes)
             ticket.moved_bytes += mb
             ticket.collectives += cc
@@ -907,8 +924,21 @@ class DeviceExchange:
         xla_stats.note_device_exchange(ticket.n, ticket.moved_bytes,
                                        ticket.collectives,
                                        ticket.n * row_bytes)
+        ticket.out = result
+        ticket.settled = True
+        ticket.row_valid = ticket.datas = ticket.vbufs = None  # free buffers
 
-        result = to_host(list(result[:2 * ncols + 2]))
+    def drain(self, ticket: ExchangeTicket):
+        """Await a dispatched exchange (`settle`, where the caller has
+        not), read every receive buffer back whole, padding and all
+        (`ticket.read_bytes`), and split the received rows into
+        per-partition numpy columns."""
+        if ticket.parts is not None:
+            return ticket.parts
+        self.settle(ticket)
+        ncols, n_out = ticket.ncols, ticket.n_out
+        result = to_host(list(ticket.out[:2 * ncols + 2]))
+        ticket.read_bytes = sum(int(a.nbytes) for a in result)
         out_cols = result[:ncols]
         out_vals = [a.astype(bool) for a in result[ncols:2 * ncols]]
         pid_r = result[2 * ncols]
@@ -927,5 +957,5 @@ class DeviceExchange:
             parts.append(([d[lo:hi] for d in datas_live],
                           [v[lo:hi] for v in vals_live]))
         ticket.parts = parts
-        ticket.out = ticket.datas = ticket.vbufs = None  # free buffers
+        ticket.out = None  # free buffers
         return parts

@@ -986,6 +986,13 @@ class DagScheduler:
         The `device_exchange` span covers merge+exchange+encode only —
         NOT the map wave — so the device ledger's barrier_idle category
         sees the real fold-end -> exchange-start gap this path pays.
+        Two child spans on this thread cut it: `exchange_stage` from its
+        opening to the collective's dispatch (the host concat of a
+        staged wave, padding, the cut over the mesh, or the per-chip
+        assembly of a placed one) and `exchange_unstage` from the
+        overflow scalar's arrival to the last IPC block (the readback
+        of the receive buffers, the host split, the encode); what lies
+        between them is the wait for the collective.
         shuffle_barrier_idle_ns counts the FIRST-finisher's wait: the
         earliest-completed task's output sits at the barrier until the
         last straggler lands and the merged exchange can start — the
@@ -1020,40 +1027,52 @@ class DagScheduler:
         blocks: Dict[int, bytes] = {}
         if batches or col_tasks:
             exchange = DeviceExchange()
+            device = current_task().device_id
+            # a wave of loop tasks only: the exchange starts from the
+            # chips their columns lie on; any staged batch forces the
+            # host concat
+            placed = bool(col_tasks) and not batches
             with tracing.span("device_exchange", stage=stage.sid,
                               tasks=stage.num_tasks, partitions=n_out,
-                              device=current_task().device_id,
-                              chips=exchange.mesh.size):
-                # a wave of loop tasks only: the exchange starts from
-                # the chips their columns lie on; any staged batch
-                # forces the host concat
-                placed = bool(col_tasks) and not batches
-                if placed:
-                    est = sum(int(c.nbytes) for t in col_tasks
-                              for c in t[0])
-                else:
-                    cols, valids = self._merge_map_outputs(
-                        batches, col_tasks, schema)
-                    est = sum(int(c.nbytes) for c in cols)
-                if est > config.SHUFFLE_DEVICE_MAX_BYTES.get():
-                    raise DeviceExchangeError(
-                        f"map output {est}B exceeds "
-                        f"auron.tpu.shuffle.device.maxBytes")
-                if done_ns:
-                    xla_stats.note_barrier_idle(
-                        max(0, _time.perf_counter_ns() - min(done_ns)))
-                ticket = (exchange.dispatch_placed(
-                    col_tasks, spec["key_indices"], n_out,
-                    ctx=str(stage.sid)) if placed
-                    else exchange.dispatch(
-                        cols, valids, spec["key_indices"], n_out,
-                        ctx=str(stage.sid)))
-                parts = exchange.drain(ticket)
-                arrow_schema = schema.to_arrow()
-                for r, (datas, vls) in enumerate(parts):
-                    if datas and len(datas[0]):
-                        rb = _columns_to_batch(datas, vls, arrow_schema)
-                        blocks[r] = write_batches_to_bytes([rb])
+                              device=device, chips=exchange.mesh.size,
+                              staged=not placed) as whole:
+                with tracing.span(
+                        "exchange_stage", stage=stage.sid,
+                        tasks=stage.num_tasks, device=device,
+                        staged_tasks=stage.num_tasks - loop_tasks) as st:
+                    if placed:
+                        est = sum(int(c.nbytes) for t in col_tasks
+                                  for c in t[0])
+                    else:
+                        cols, valids = self._merge_map_outputs(
+                            batches, col_tasks, schema)
+                        est = sum(int(c.nbytes) for c in cols)
+                    if est > config.SHUFFLE_DEVICE_MAX_BYTES.get():
+                        raise DeviceExchangeError(
+                            f"map output {est}B exceeds "
+                            f"auron.tpu.shuffle.device.maxBytes")
+                    if done_ns:
+                        xla_stats.note_barrier_idle(max(
+                            0, _time.perf_counter_ns() - min(done_ns)))
+                    ticket = (exchange.dispatch_placed(
+                        col_tasks, spec["key_indices"], n_out,
+                        ctx=str(stage.sid)) if placed
+                        else exchange.dispatch(
+                            cols, valids, spec["key_indices"], n_out,
+                            ctx=str(stage.sid)))
+                    st.update(rows=ticket.n, bytes=est)
+                    whole["rows"] = ticket.n
+                exchange.settle(ticket)   # the wait for the collective
+                with tracing.span("exchange_unstage", rows=ticket.n,
+                                  partitions=n_out) as un:
+                    parts = exchange.drain(ticket)
+                    un["bytes_read"] = ticket.read_bytes or 0
+                    arrow_schema = schema.to_arrow()
+                    for r, (datas, vls) in enumerate(parts):
+                        if datas and len(datas[0]):
+                            rb = _columns_to_batch(datas, vls,
+                                                   arrow_schema)
+                            blocks[r] = write_batches_to_bytes([rb])
         return blocks, loop_tasks
 
     def _exchange_overlapped(self, stage: Stage, spec, n_out: int,
@@ -1117,7 +1136,8 @@ class DagScheduler:
                         "device_exchange",
                         _time.perf_counter_ns() - ticket.dispatch_ns,
                         stage=stage.sid, task=key[-1], partitions=n_out,
-                        overlapped=True, device=key[-2])
+                        overlapped=True, device=key[-2], rows=ticket.n,
+                        staged=key[0] == 0)
                     xla_stats.note_exchange_overlap()
                     with lock:
                         parts_by_task[key] = parts

@@ -86,10 +86,12 @@ def to_host(tree):
     return out
 
 
-def to_device(tree):
+def to_device(tree, sharding=None):
     """Host -> device: one `jax.device_put` over an array or pytree of
     numpy buffers, committed to the current task's chip where it has one
-    (`task_device`), else to JAX's default device as ever.  Counts their
+    (`task_device`), else to JAX's default device as ever; or spread as
+    `sharding` says (a staged exchange's columns, cut over the mesh:
+    counted under the calling thread's chip all the same).  Counts their
     bytes (`h2d_bytes`, one `h2d_transfers`, by chip too) and the time
     spent here (`h2d_ns`), under an `h2d` span.  device_put returns
     before the copy lands, so the time is host staging and dispatch, not
@@ -101,7 +103,8 @@ def to_device(tree):
     task = _task()
     t0 = time.perf_counter_ns()
     with tracing.span("h2d", bytes=nbytes, device=task.device_id):
-        out = jax.device_put(tree, task.device)
+        out = jax.device_put(
+            tree, task.device if sharding is None else sharding)
     xla_stats.note_h2d(nbytes, time.perf_counter_ns() - t0, task.device_id)
     return out
 

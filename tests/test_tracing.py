@@ -568,8 +568,9 @@ def test_span_registry_pin():
     in a test (test_span_names.py enforces the latter two)."""
     assert set(tracing.SPAN_NAMES) == {
         "task", "task_attempt", "backoff_wait", "admission_wait",
-        "worker_task", "device_exchange", "rss_exchange",
-        "shuffle_exchange", "stage_recovery", "stage_loop_chunk",
+        "worker_task", "device_exchange", "exchange_stage",
+        "exchange_unstage", "rss_exchange", "shuffle_exchange",
+        "stage_recovery", "stage_loop_chunk",
         "stream_epoch", "explain_analyze",
         "d2h", "h2d", "prefetch_wait", "produce:*", "join_build",
         "join_probe", "agg_drain", "sort_device", "smj_merge",
