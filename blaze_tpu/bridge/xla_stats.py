@@ -121,6 +121,7 @@ _stage_loop = {"stage_loop_programs_built": 0,
                "stage_loop_program_cache_hits": 0,
                "stage_loop_calls": 0, "stage_loop_chunks": 0,
                "stage_loop_batches": 0, "stage_loop_rows": 0,
+               "stage_loop_lanes": 0,
                "stage_loop_tasks": 0, "stage_loop_regrows": 0,
                "stage_loop_reserves": 0, "stage_loop_rehash_lanes": 0,
                "stage_loop_rehash_groups": 0,
@@ -440,6 +441,7 @@ def _chip_entry(chip: int) -> Dict[str, int]:
                                 "join_probe_host_rows": 0,
                                 "stage_loop_windows": 0,
                                 "stage_loop_windows_fused": 0,
+                                "stage_loop_lanes": 0,
                                 **{k: 0 for k in _CHIP_TABLE_KEYS}}
     return entry
 
@@ -501,9 +503,9 @@ def placement_stats() -> dict:
 def chip_stats() -> Dict[int, Dict[str, int]]:
     """device id -> {"tasks", "h2d_bytes", "d2h_bytes",
     "join_probe_device_rows", "join_probe_host_rows",
-    "stage_loop_windows", "stage_loop_windows_fused" and the stage loop's
-    table counters (_CHIP_TABLE_KEYS)} since the last reset: what each
-    chip was given to do."""
+    "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes"
+    and the stage loop's table counters (_CHIP_TABLE_KEYS)} since the
+    last reset: what each chip was given to do."""
     with _lock:
         return {chip: dict(e) for chip, e in sorted(_chips.items())}
 
@@ -918,7 +920,7 @@ def note_stage_program(cache_hit: bool) -> None:
             _stage_loop["stage_loop_programs_built"] += 1
 
 
-def note_stage_loop_task(chunks: int, batches: int, rows: int,
+def note_stage_loop_task(chunks: int, batches: int, rows: int, lanes: int,
                          regrows: int, reserves: int, rehash_lanes: int,
                          slots: int, dispatches_avoided: int,
                          full_rounds: int, narrow_rounds: int,
@@ -926,7 +928,11 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int,
                          rehash_probe_lanes: int, table_bytes: int,
                          chip: int) -> None:
     """One map task completed through the device-resident stage loop:
-    `chunks` loop program calls folded `batches` batches / `rows` rows.
+    `chunks` loop program calls folded `batches` batches / `rows` rows,
+    over `lanes` lanes: for every batch handed to a fold its window's
+    capacity, so rows over lanes is how full the folds ran (a step of
+    the fold costs by its lanes, not by its live rows; kept by `chip`
+    too).
     The agg table's capacity was raised at `reserves` chunk boundaries
     before the fold and `regrows` times after an overflow.  Each rehash
     pushed the old table's slots (`rehash_lanes`, summed) holding
@@ -958,7 +964,7 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int,
         _stage_loop["stage_loop_regrows"] += int(regrows)
         _stage_loop["stage_loop_reserves"] += int(reserves)
         entry = _chip_entry(chip)
-        for k, v in table.items():
+        for k, v in (("stage_loop_lanes", int(lanes)), *table.items()):
             _stage_loop[k] += v
             entry[k] += v
         _stage_loop["stage_loop_full_rounds"] += int(full_rounds)

@@ -404,7 +404,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
     stream = (source_stream if source_stream is not None
               else program.source.execute(partition))
     windows = _batch_windows(stream, chunk, pad_tail=True)
-    batches = rows = fold_calls = regrows = reserves = 0
+    batches = rows = lanes = fold_calls = regrows = reserves = 0
     # (old table's slots, groups it held, new slots, lanes re-inserted)
     rehashes = []
     full_rounds = narrow_rounds = 0
@@ -511,6 +511,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                 # rows handed to the fold, and no others
                 rows += sum(batch_rows[:start])
                 batches += min(start, count)
+                lanes += min(start, count) * int(masks.shape[1])
             if rest is not None:
                 break
             ci += 1
@@ -526,8 +527,9 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         program.agg.metrics.add("partial_skipped", 1)
         xla_stats.note_partial_agg_skip(live_folded)
     xla_stats.note_stage_loop_task(
-        chunks=fold_calls, batches=batches, rows=rows, regrows=regrows,
-        reserves=reserves, rehash_lanes=sum(r[0] for r in rehashes),
+        chunks=fold_calls, batches=batches, rows=rows, lanes=lanes,
+        regrows=regrows, reserves=reserves,
+        rehash_lanes=sum(r[0] for r in rehashes),
         rehash_groups=sum(r[1] for r in rehashes),
         rehash_new_slots=sum(r[2] for r in rehashes),
         rehash_probe_lanes=sum(r[3] for r in rehashes), slots=slots,

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import io
 from dataclasses import dataclass
-from typing import BinaryIO, Callable, Iterator, List, Optional, Union
+from typing import BinaryIO, Callable, Iterable, Iterator, List, Union
 
 import pyarrow as pa
 
@@ -20,7 +20,8 @@ from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.bridge.resource import get_resource
 from blaze_tpu.faults import (FetchFailedError, InjectedFault,
                               ShuffleChecksumError)
-from blaze_tpu.ops.base import BatchIterator, CoalesceStream, ExecutionPlan
+from blaze_tpu.ops.base import (BatchIterator, ExecutionPlan,
+                                effective_batch_size)
 from blaze_tpu.schema import Schema
 from blaze_tpu.shuffle.ipc import IpcCompressionReader, IpcCompressionWriter
 
@@ -91,6 +92,46 @@ def _read_segment(block: FileSegmentBlock) -> Iterator[pa.RecordBatch]:
         yield from IpcCompressionReader(f, limit=block.length).read_batches()
 
 
+def _tiles(pieces: Iterable[pa.RecordBatch]) -> Iterator[pa.RecordBatch]:
+    """The blocks' record batches, in stream order, joined and cut into
+    batches of exactly `effective_batch_size()` rows and one tail.  The
+    writer cuts each (map, partition) block into pieces of the batch size
+    and a short tail, so pieces as they come would pair a tail with the
+    next block's full piece: one batch over the tile, placed at twice its
+    capacity.  A piece that alone holds more than the tile (the mesh
+    exchange's one block a reduce task) is passed whole.  Slices are
+    views; a join copies on the host."""
+    staged: List[pa.RecordBatch] = []
+    rows = 0
+    for rb in pieces:
+        # asked anew for every piece, so a mid-query degradation rung
+        # takes effect at the next boundary: what is staged over a shrunk
+        # tile goes first
+        tile = effective_batch_size()
+        n = rb.num_rows
+        if staged and (n > tile or rows >= tile):
+            yield _join(staged)
+            staged, rows = [], 0
+        if n > tile:
+            yield rb
+            continue
+        take = min(n, tile - rows)
+        if take:
+            staged.append(rb.slice(0, take))
+            rows += take
+        if rows == tile:
+            yield _join(staged)
+            staged, rows = [], 0
+            if take < n:
+                staged, rows = [rb.slice(take)], n - take
+    if staged:
+        yield _join(staged)
+
+
+def _join(staged: List[pa.RecordBatch]) -> pa.RecordBatch:
+    return staged[0] if len(staged) == 1 else pa.concat_batches(staged)
+
+
 class IpcReaderExec(ExecutionPlan):
     """Reads shuffle blocks for this partition from the resource map.
 
@@ -115,10 +156,11 @@ class IpcReaderExec(ExecutionPlan):
         return self._num_partitions
 
     def execute(self, partition: int) -> BatchIterator:
-        def gen():
-            for rb in self.arrow_batches(partition):
-                yield ColumnBatch.from_arrow(rb)
-        return iter(CoalesceStream(gen(), metrics=self.metrics))
+        # re-tiled in Arrow, on the host, BEFORE anything is placed: a
+        # reduce task's batches reach the chip at the tile's capacity and
+        # no device program joins them
+        for rb in _tiles(self.arrow_batches(partition)):
+            yield ColumnBatch.from_arrow(rb)
 
     def arrow_batches(self, partition: int):
         """Arrow-resident read: decoded IPC frames go straight to

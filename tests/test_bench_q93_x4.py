@@ -449,7 +449,9 @@ def test_the_cell_resolves_to_the_twins_tables_on_the_x4_deployment(cell):
 
 def test_every_new_metric_has_its_file_its_cells_and_its_unit(cell):
     entries = {m["name"]: m for m in cell.manifest["per_layer"]}
-    assert list(entries)[-8:] == list(NEW) + list(TWINS)
+    # appended together, in this order (later PRs append after them)
+    at = list(entries).index(NEW[0])
+    assert list(entries)[at:at + 8] == list(NEW) + list(TWINS)
     specs = dict((m["name"], spec) for m, spec in cell.layer_metrics())
     for name in NEW + tuple(TWINS):
         m, spec = entries[name], specs[name]

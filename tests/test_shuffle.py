@@ -197,3 +197,78 @@ def test_single_partitioning_roundtrip(tmp_path):
     put_resource("t3", [FileSegmentBlock(data, 0, offsets[1])])
     got = IpcReaderExec("t3", S.Schema.from_arrow(t.schema)).execute_collect()
     assert got.to_arrow().column(0).to_pylist() == [1, 2, 3]
+
+
+# -- ISSUE 39: the reader re-tiles in Arrow before it places anything -------
+
+def _ipc_blocks(table, blocks):
+    """One IPC block a list of piece lengths, cut from `table` in order."""
+    from blaze_tpu.shuffle.ipc import write_batches_to_bytes
+    out, off = [], 0
+    for pieces in blocks:
+        starts = np.cumsum([off] + pieces)
+        out.append(write_batches_to_bytes(
+            table.slice(int(s), n).to_batches()[0]
+            for s, n in zip(starts, pieces)))
+        off = int(starts[-1])
+    return out
+
+
+# blocks as lists of piece lengths; the query's capacity_shrink; the
+# selected rows a batch of what `execute` yields
+_TILE_CASES = {
+    # the SF10 pair's reduce partition: two hot map tasks' blocks
+    "two_blocks_with_tails": (
+        [[32768, 32768, 6200]] * 2, 0, [32768] * 4 + [12400]),
+    "one_batch_over_the_tile": ([[40000]], 0, [40000]),
+    # the four-chip exchange's one block a reduce task
+    "one_large_batch": ([[720000]], 0, [720000]),
+    "large_batch_between_tails": (
+        [[100], [40000], [100]], 0, [100, 40000, 100]),
+    "small_blocks_are_one_tail": ([[100]] * 3, 0, [300]),
+    # the tail is a slice of the second block's piece, at an offset
+    "tail_is_a_view": ([[20000]] * 2, 0, [32768, 7232]),
+    "exact_tiles_leave_no_tail": ([[32768], [16384, 16384]], 0, [32768] * 2),
+    "empty_partition": ([], 0, []),
+    "degraded_query_tiles_at_half": (
+        [[10000] * 5, [4000]], 1, [16384] * 3 + [4848]),
+}
+
+
+@pytest.mark.parametrize("case", list(_TILE_CASES))
+def test_ipc_reader_hands_the_chip_batches_of_one_tile(case, monkeypatch):
+    from blaze_tpu import batch as batch_mod
+    from blaze_tpu.batch import bucket_capacity
+    from blaze_tpu.bridge.context import query_scope
+    from blaze_tpu.serving import QueryContext
+    blocks, shrink, want = _TILE_CASES[case]
+    n = sum(sum(b) for b in blocks)
+    rng = np.random.default_rng(39)
+    v = rng.random(n)
+    t = pa.table({"k": pa.array(np.arange(n, dtype=np.int64)),
+                  "v": pa.array(v, mask=v < 0.1),
+                  "s": pa.array(rng.integers(0, 9, n)).cast(pa.string())})
+    put_resource(f"tile-{case}", _ipc_blocks(t, blocks))
+    reader = IpcReaderExec(f"tile-{case}", S.Schema.from_arrow(t.schema))
+    pieces = [p for b in blocks for p in b]
+    # Arrow-resident consumers read the blocks' pieces as they were written
+    assert [rb.num_rows for rb in reader.arrow_batches(0)] == pieces
+    q = QueryContext(f"tile-{case}")
+    for _ in range(shrink + 1 if shrink else 0):
+        q.degrade()  # rung 1 is agg pass-through; from rung 2 on halving
+    assert q.capacity_shrink == shrink
+    # device placement: a batch's capacity is its bucket on the ladder
+    monkeypatch.setattr(batch_mod, "_host_resident", lambda: False)
+    with query_scope(q):
+        got = list(reader.execute(0))
+    tile = 32768 >> shrink
+    assert [b.selected_count() for b in got] == want
+    assert [b.capacity for b in got] == [bucket_capacity(r) for r in want]
+    if max(pieces, default=0) <= tile:
+        # nothing was over the tile, so nothing is placed above it and
+        # every batch but the tail is full
+        assert all(b.capacity <= tile for b in got)
+        assert all(r == tile for r in want[:-1])
+    if got:
+        back = pa.Table.from_batches([b.to_arrow() for b in got])
+        assert back.equals(t)  # the rows, in the input's order

@@ -1142,3 +1142,123 @@ def test_a_switched_partition_passes_a_widened_tail_window_on(loop_on,
         lambda: _skip_agg(t, _AGGS["int_sum_counts"]), enable=False)
     _assert_same_answer(_final_merge(skipped, _AGGS["int_sum_counts"]),
                         _final_merge(grouped, _AGGS["int_sum_counts"]))
+
+
+# -- ISSUE 39: a reduce task's fold runs over batches of one tile -----------
+
+@pytest.mark.parametrize("chunk", [2, 8])
+@pytest.mark.parametrize("tile", [1024, 32768])
+def test_final_fold_over_the_ipc_reader_runs_at_the_tile(loop_on, tile,
+                                                         chunk, monkeypatch):
+    """Two map tasks' blocks as the shuffle writer cuts them (pieces of
+    the batch size and a tail): the FINAL aggregation's windows are
+    assembled at the tile, not at twice it, and a second run of the same
+    partition builds nothing.  In chunks of two the tail has a window of
+    its own, at its own bucket; in the default chunk of eight it rides
+    with the four full tiles and is the one batch padded."""
+    from blaze_tpu import batch as batch_mod
+    from blaze_tpu.bridge import tracing
+    from blaze_tpu.bridge.resource import put_resource
+    from blaze_tpu.plan.column_pruning import prune_columns
+    from blaze_tpu.plan.fused import fuse_plan
+    from blaze_tpu.plan.planner import collapse_filter_project, create_plan
+    from blaze_tpu.shuffle.ipc import write_batches_to_bytes
+    config.conf.set(config.BATCH_SIZE.key, tile)
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, chunk)
+    monkeypatch.setattr(batch_mod, "_host_resident", lambda: False)
+    pieces = [tile, tile, tile * 3 // 16]
+    n = 2 * sum(pieces)
+    rng = np.random.default_rng(39)
+    k = rng.integers(0, n // 2, n) * 1000003 + 17
+    t = pa.table({"k": pa.array(k, type=pa.int64()),
+                  "v": pa.array(rng.random(n))})
+    starts = np.cumsum([0] + pieces * 2)
+    put_resource(f"final-{tile}", [write_batches_to_bytes(
+        t.slice(int(s), p).to_batches()[0]
+        for s, p in zip(starts[b * 3:], pieces)) for b in range(2)])
+    schema = {"fields": [
+        {"name": "k", "type": {"id": "int64"}, "nullable": True},
+        {"name": "v", "type": {"id": "float64"}, "nullable": True}]}
+
+    def plan():
+        return fuse_plan(prune_columns(collapse_filter_project(create_plan({
+            "kind": "hash_agg",
+            "groupings": [{"expr": {"kind": "column", "index": 0},
+                           "name": "k"}],
+            "aggs": [{"fn": "sum", "mode": "final", "name": "s",
+                      "args": [{"kind": "column", "index": 1}]}],
+            "input": {"kind": "ipc_reader", "schema": schema,
+                      "resource_id": f"final-{tile}"}}))))
+    try:
+        tracing.start_tracing()
+        try:
+            before = xla_stats.snapshot()
+            out = pa.Table.from_batches(
+                [b.to_arrow() for b in plan().execute(0)])
+            d = xla_stats.delta(before)
+            windows = [s["attrs"] for s in tracing.spans()
+                       if s["name"] == "loop_window"]
+        finally:
+            tracing.stop_tracing()
+            tracing.reset_conf_probe()
+        # four full tiles and a tail of 3/8 of one
+        tail_cap = batch_mod.bucket_capacity(2 * pieces[2])
+        assert tail_cap < tile
+        if chunk == 2:
+            shapes, lanes = [(2, 0), (2, 0), (1, 0)], 4 * tile + tail_cap
+        else:
+            shapes, lanes = [(5, 1)], 5 * tile
+        assert [(a["batches"], a["padded"]) for a in windows] == shapes
+        assert d["stage_loop_tasks"] == 1 and d["stage_loop_fallbacks"] == 0
+        assert d["stage_loop_windows"] == len(shapes)
+        # every window is the one program's alone but the tail's, where
+        # the tail rides with batches of another capacity
+        assert d["stage_loop_windows_fused"] == \
+            sum(1 for _b, padded in shapes if not padded)
+        assert d["stage_loop_batches"] == 5
+        assert d["stage_loop_rows"] == n
+        assert d["stage_loop_lanes"] == lanes
+        assert xla_stats.chip_stats()[0]["stage_loop_lanes"] >= \
+            d["stage_loop_lanes"]
+        # the answer, by group
+        keys, inverse = np.unique(k, return_inverse=True)
+        want = np.bincount(inverse, weights=t["v"].to_numpy())
+        got = out.sort_by("k")
+        assert got.column("k").to_pylist() == keys.tolist()
+        np.testing.assert_allclose(got.column(1).to_numpy(), want,
+                                   rtol=1e-12)
+        # the same partition again: the same capacities, so no program
+        before = xla_stats.snapshot()
+        assert list(plan().execute(0))
+        d = xla_stats.delta(before)
+        assert d["total_compiles"] == 0 and d["backend_compiles"] == 0
+        assert d["stage_loop_lanes"] == lanes
+    finally:
+        config.conf.unset(config.BATCH_SIZE.key)
+        config.conf.unset(config.STAGE_DEVICE_LOOP_CHUNK.key)
+
+
+@pytest.mark.parametrize("counters,want", [
+    # the pair's four reduce tasks alone: 143K rows in 5 x 32,768 lanes
+    ({"stage_loop_rows": 4 * 143_536, "stage_loop_lanes": 4 * 5 * 32_768},
+     100.0 * 143_536 / (5 * 32_768)),
+    # a parent counts the rows and not the lanes: nothing to read
+    ({"stage_loop_rows": 4 * 143_536}, None),
+    # a window in which the stage loop folded nothing
+    ({"stage_loop_rows": 0, "stage_loop_lanes": 0}, None),
+], ids=["rows_over_lanes", "parent_without_the_counter", "no_fold"])
+def test_fold_lane_fill_share_is_rows_over_lanes(counters, want):
+    import os
+    from benchmark.manifest import Cell
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = Cell("sf10_q01pair_x1", root)
+    (entry, spec), = [(m, s) for m, s in cell.layer_metrics()
+                      if m["name"] == "fold_lane_fill_share"]
+    # list-less: every cell that reports query_wall_s reports it
+    assert "workloads" not in entry and entry["source"] == "program_counter"
+    assert (spec["unit"], spec["better"], spec["layer"]) == \
+        (entry["unit"], entry["better"], entry["layer"]) == \
+        ("%", "higher", "fused aggregation")
+    got = cell.module("sources", spec["source"]).read(
+        spec, {"counters": counters, "queries": 1})
+    assert got == (want if want is None else pytest.approx(want))
