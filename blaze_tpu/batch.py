@@ -115,16 +115,20 @@ def _arrow_fixed_values(arr: pa.Array, dtype: DataType) -> np.ndarray:
     if dtype.id == TypeId.DECIMAL:
         buf = arr.buffers()[1]
         if pa.types.is_decimal(arr.type):
+            pairs = decimal_limb_pairs(arr)
             if dtype.precision > 18 or arr.type.precision > 18:
-                # the low-8-bytes extraction below would silently
-                # truncate wide values; wide decimals are host-only
-                raise TypeError(
-                    f"decimal(p>{18}) cannot take the int64 device "
-                    f"representation (got {arr.type}); keep it host-"
-                    f"resident")
-            # decimal128 little-endian; p<=18 fits in the low 8 bytes
-            pairs = np.frombuffer(buf, dtype=np.int64).reshape(-1, 2)
-            return pairs[arr.offset:arr.offset + len(arr), 0].copy()
+                # a wider TYPE takes the int64 lane only where every VALUE
+                # fits it (the high limb is the low one's sign); a NULL's
+                # bytes are not looked at
+                fits = pairs[:, 1] == pairs[:, 0] >> 63
+                if arr.null_count:
+                    fits = fits | ~_unpack_validity(arr)
+                if not fits.all():
+                    raise TypeError(
+                        f"a value of {arr.type} does not fit the int64 "
+                        f"device representation; keep the column host-"
+                        f"resident")
+            return pairs[:, 0].copy()
         # unscaled-int64 storage (buffered partial acc columns keep the
         # device representation)
         vals = np.frombuffer(buf, dtype=np.int64)
@@ -135,6 +139,13 @@ def _arrow_fixed_values(arr: pa.Array, dtype: DataType) -> np.ndarray:
     return vals[arr.offset:arr.offset + len(arr)]
 
 
+def decimal_limb_pairs(arr: pa.Array) -> np.ndarray:
+    """A decimal128 arrow array's values as an (n, 2) int64 view: the
+    little-endian (low, high) limbs of each."""
+    return np.frombuffer(arr.buffers()[1], dtype=np.int64).reshape(-1, 2)[
+        arr.offset:arr.offset + len(arr)]
+
+
 def decimal_from_unscaled(values: np.ndarray, valid: Optional[np.ndarray],
                           t: pa.DataType) -> pa.Array:
     """Unscaled int64/int32 values -> decimal128 arrow array WITHOUT an
@@ -142,9 +153,17 @@ def decimal_from_unscaled(values: np.ndarray, valid: Optional[np.ndarray],
     representation).  Builds the 16-byte little-endian limbs directly:
     vectorized, unlike a per-value python-Decimal loop."""
     v = np.ascontiguousarray(values).astype(np.int64, copy=False)
-    limbs = np.empty((len(v), 2), dtype=np.int64)
-    limbs[:, 0] = v        # low limb (little-endian int128)
-    limbs[:, 1] = v >> 63  # arithmetic shift: sign extension
+    return decimal_from_limbs(v, v >> 63, valid, t)  # sign extension
+
+
+def decimal_from_limbs(lo: np.ndarray, hi: np.ndarray,
+                       valid: Optional[np.ndarray], t: pa.DataType
+                       ) -> pa.Array:
+    """(low, high) int64 limbs of two's-complement int128 unscaled values
+    -> decimal128 arrow array of type `t`, no cast."""
+    limbs = np.empty((len(lo), 2), dtype=np.int64)
+    limbs[:, 0] = lo
+    limbs[:, 1] = hi
     data_buf = pa.py_buffer(limbs.tobytes())
     if valid is None or bool(np.asarray(valid).all()):
         validity_buf, null_count = None, 0
@@ -153,8 +172,24 @@ def decimal_from_unscaled(values: np.ndarray, valid: Optional[np.ndarray],
         bits = np.packbits(valid.astype(np.uint8), bitorder="little")
         validity_buf = pa.py_buffer(bits.tobytes())
         null_count = int((~valid).sum())
-    return pa.Array.from_buffers(t, len(v), [validity_buf, data_buf],
+    return pa.Array.from_buffers(t, len(lo), [validity_buf, data_buf],
                                  null_count=null_count)
+
+
+def bounded_decimal(values: np.ndarray, valid: np.ndarray,
+                    t: pa.DataType) -> pa.Array:
+    """`decimal_from_unscaled` under Spark's non-ANSI CheckOverflow: a
+    value past the bound of `t` is NULL (and counted), never wrapped."""
+    v = np.asarray(values).astype(np.int64, copy=False)
+    valid = np.asarray(valid, dtype=bool)
+    if t.precision <= 18:
+        fits = np.abs(v) < 10 ** t.precision
+        lost = int((valid & ~fits).sum())
+        if lost:
+            from blaze_tpu.bridge import xla_stats
+            xla_stats.note_decimal(overflow_groups=lost)
+            valid = valid & fits
+    return decimal_from_unscaled(v, valid, t)
 
 
 @dataclass

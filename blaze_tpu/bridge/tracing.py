@@ -179,6 +179,14 @@ SPAN_NAMES: Dict[str, str] = {
                    "of each batch of another capacity; the pulls of the "
                    "source outside it (plan/fused.py _assemble_window; "
                    "attrs batches, padded: batches padded first)",
+    "decimal_host_eval": "decimal work outside a device program, a real "
+                         "interval: an expression batch with a decimal "
+                         "operand through Arrow or numpy "
+                         "(exprs/program.py FusedExprsEvaluator), an "
+                         "eager aggregation's batch over a decimal "
+                         "argument, a decimal average's final quotient "
+                         "(ops/agg/exec.py; attrs op, rows, precision, "
+                         "scale)",
     "table_init": "the stage loop allocates an empty hash table "
                   "(runtime/loop.py _fold_partition; attrs slots, device)",
     "gc_pause": "one run of Python's cyclic garbage collector, on the "

@@ -249,7 +249,17 @@ _encoding = {"dict_encoded_columns": 0, "dict_exchange_remaps": 0,
              "decimal_scaled_int64_dispatches": 0,
              "decimal_limb_dispatches": 0,
              "host_evictions_string": 0, "host_evictions_decimal": 0,
-             "host_evictions_other": 0}
+             "host_evictions_other": 0,
+             # decimals as exact scaled integers (note_decimal): rows the
+             # stage loop folded whose value lane is one, rows with a
+             # decimal aggregate argument that an aggregation outside the
+             # loop took, sums and averages sent to NULL or to the wide
+             # path, expression batches with a decimal operand inside a
+             # device program / through Arrow or numpy on the host
+             "stage_loop_decimal_rows": 0, "agg_decimal_rows_host": 0,
+             "decimal_overflow_groups": 0,
+             "expr_decimal_device_batches": 0,
+             "expr_decimal_host_batches": 0}
 
 # Fleet-scope serving (blaze_tpu/fleet/): queries routed by the
 # fingerprint-affine router, affinity hits (query landed on its
@@ -442,6 +452,7 @@ def _chip_entry(chip: int) -> Dict[str, int]:
                                 "stage_loop_windows": 0,
                                 "stage_loop_windows_fused": 0,
                                 "stage_loop_lanes": 0,
+                                "stage_loop_decimal_rows": 0,
                                 **{k: 0 for k in _CHIP_TABLE_KEYS}}
     return entry
 
@@ -503,8 +514,8 @@ def placement_stats() -> dict:
 def chip_stats() -> Dict[int, Dict[str, int]]:
     """device id -> {"tasks", "h2d_bytes", "d2h_bytes",
     "join_probe_device_rows", "join_probe_host_rows",
-    "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes"
-    and the stage loop's table counters (_CHIP_TABLE_KEYS)} since the
+    "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes",
+    "stage_loop_decimal_rows" and the stage loop's table counters (_CHIP_TABLE_KEYS)} since the
     last reset: what each chip was given to do."""
     with _lock:
         return {chip: dict(e) for chip, e in sorted(_chips.items())}
@@ -775,6 +786,28 @@ def note_encoding(**deltas: int) -> None:
                 _encoding[key] = int(v)
             else:
                 _encoding[key] += int(v)
+
+
+def note_decimal(stage_loop_rows: int = 0, agg_rows_host: int = 0,
+                 overflow_groups: int = 0, expr_device_batches: int = 0,
+                 expr_host_batches: int = 0, chip: int = 0) -> None:
+    """Decimals as exact scaled integers.  `stage_loop_rows`: rows a
+    stage-loop task folded whose value lane is a scaled integer (kept by
+    `chip` too); `agg_rows_host`: rows with a decimal aggregate argument
+    that an aggregation outside the stage loop took; `overflow_groups`:
+    sums or averages sent to NULL past their type's bound, or to the
+    wide path past 64 bits; `expr_device_batches` / `expr_host_batches`:
+    expression batches with a decimal operand evaluated inside a device
+    program, and through Arrow or numpy on the host."""
+    with _lock:
+        _encoding["stage_loop_decimal_rows"] += int(stage_loop_rows)
+        _encoding["agg_decimal_rows_host"] += int(agg_rows_host)
+        _encoding["decimal_overflow_groups"] += int(overflow_groups)
+        _encoding["expr_decimal_device_batches"] += int(expr_device_batches)
+        _encoding["expr_decimal_host_batches"] += int(expr_host_batches)
+        if stage_loop_rows:
+            _chip_entry(chip)["stage_loop_decimal_rows"] += \
+                int(stage_loop_rows)
 
 
 def encoding_stats() -> dict:

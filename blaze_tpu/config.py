@@ -392,15 +392,17 @@ ENCODING_DICT_MAX_ENTRIES = int_conf(
     "batches stay plain utf8; downstream consumers decode losslessly).",
     category="encoding")
 ENCODING_DECIMAL_ENABLE = bool_conf(
-    "auron.tpu.encoding.decimal.enable", False,
+    "auron.tpu.encoding.decimal.enable", True,
     "Lower decimal128 columns as scaled-integer arithmetic on the "
     "device lanes: precisions <= 18 run as scaled int64 (or int32, see "
     "encoding.decimal.int32) through expr programs, the stage loop and "
-    "DeviceExchange; unequal-scale comparisons rescale through the "
-    "two-limb int128 kernels (kernels/decimal128.py).  Overflow "
-    "promotes to the eager host path — never silently wraps.  Results "
-    "are bit-identical to host Arrow decimal arithmetic, ANSI and "
-    "non-ANSI.  Off by default.", category="encoding")
+    "DeviceExchange; unequal-scale comparisons, and a wider operand of "
+    "a multiply or a comparison, go through the two-limb int128 "
+    "kernels (kernels/decimal128.py).  Overflow promotes to the eager "
+    "host path — never silently wraps.  Results are bit-identical to "
+    "host Arrow decimal arithmetic, ANSI and non-ANSI.  On by default "
+    "since TPC-DS's money columns are decimal(7,2); off keeps every "
+    "decimal expression on the host.", category="encoding")
 ENCODING_DECIMAL_INT32 = bool_conf(
     "auron.tpu.encoding.decimal.int32", True,
     "With encoding.decimal.enable, store decimals of precision <= 9 as "

@@ -195,13 +195,32 @@ class Literal(PhysicalExpr):
                 return ColVal(self.dtype, data=data,
                               validity=xp.zeros(cap, dtype=bool),
                               literal=True)
-            data = xp.full(cap, self.value, dtype=self.dtype.jnp_dtype())
+            value = self.value
+            if self.dtype.id == TypeId.DECIMAL:
+                value = self.unscaled()  # the lane holds the unscaled int
+            data = xp.full(cap, value, dtype=self.dtype.jnp_dtype())
             return ColVal(self.dtype, data=data,
                           validity=xp.ones(cap, dtype=bool), literal=True)
         arr = pa.array([self.value] * batch.num_rows, type=self.dtype.to_arrow())
         return ColVal(self.dtype, array=arr, literal=True)
 
+    def unscaled(self) -> int:
+        """A decimal literal's value at its type's scale, exactly."""
+        import decimal as pydec
+        v = pydec.Decimal(str(self.value)) if isinstance(self.value, float) \
+            else pydec.Decimal(self.value)
+        u = v.scaleb(self.dtype.scale)
+        if u != u.to_integral_value() or \
+                abs(int(u)) >= 10 ** self.dtype.precision:
+            raise ValueError(f"literal {self.value!r} is not a "
+                             f"decimal({self.dtype.precision},"
+                             f"{self.dtype.scale})")
+        return int(u)
+
     def cache_key(self):
+        if self.dtype.id == TypeId.DECIMAL:
+            return ("lit", self.dtype.id.value, self.dtype.precision,
+                    self.dtype.scale, str(self.value))
         return ("lit", self.dtype.id.value, self.value)
 
     def __repr__(self):

@@ -80,6 +80,9 @@ class BinaryExpr(PhysicalExpr):
         if dec is not None:
             from blaze_tpu.exprs import decimal_arith as D
             return D.result_type(self.op, *dec)
+        if self._decimal_beside_float(lt, rt):
+            from blaze_tpu.schema import FLOAT64
+            return FLOAT64
         if not lt.is_fixed_width:
             return lt
         if not rt.is_fixed_width:
@@ -105,23 +108,125 @@ class BinaryExpr(PhysicalExpr):
         object.__setattr__(self, "_ct_cache", (schema, lt, rt))
         return lt, rt
 
+    def _decimal_beside_float(self, lt: DataType, rt: DataType) -> bool:
+        """A decimal beside a float or a double: Spark casts the decimal
+        to double and the operation is a double's."""
+        return (self.op in _ARITH or self.op in _CMP) and (
+            (lt.id == TypeId.DECIMAL and rt.is_floating)
+            or (rt.id == TypeId.DECIMAL and lt.is_floating))
+
+    @staticmethod
+    def _decimal_as_double(cv: ColVal, batch: ColumnBatch) -> ColVal:
+        """The value a decimal has as a double: its unscaled integer
+        over 10^scale (both exact in a double up to 2^53 and 10^22, so
+        the quotient is the correctly rounded one, as Spark's
+        Decimal.toDouble gives)."""
+        from blaze_tpu.schema import FLOAT64
+        if cv.dtype.id != TypeId.DECIMAL:
+            return cv
+        if not cv.is_device:
+            import pyarrow as pa
+            return ColVal.host(FLOAT64, cv.array.cast(pa.float64())) \
+                .to_device(batch.capacity)
+        xp = xp_of(cv.data)
+        data = cv.data.astype(xp.float64) / np.float64(10 ** cv.dtype.scale)
+        return ColVal(FLOAT64, data=data, validity=cv.validity,
+                      literal=cv.literal)
+
+    def _decimal_limbs(self, a: ColVal, b: ColVal, ldt: DataType,
+                       rdt: DataType, batch: ColumnBatch
+                       ) -> Optional[ColVal]:
+        """The decimal operations that are exact in two int64 limbs
+        (kernels/decimal128.py) and so can run inside a device program:
+        a comparison at the larger scale, and a multiply by a constant.
+        None where this is neither, or an operand has no lane."""
+        plan = self._decimal_limb_plan(ldt, rdt)
+        if plan is None:
+            return None
+        from blaze_tpu.kernels import decimal128 as d128
+        cap = batch.capacity
+        forms = []
+        for cv in (a, b):
+            if cv.is_device:
+                forms.append(cv)
+            elif cv.dtype.id == TypeId.DECIMAL \
+                    and not cv.dtype.is_fixed_width:
+                data, valid = d128.host_limbs(cv.array, cap)
+                forms.append(ColVal(cv.dtype, data=data, validity=valid))
+            elif cv.dtype.is_fixed_width:
+                forms.append(cv.to_device(cap))
+            else:
+                return None
+        fa, fb = forms
+        if plan == "cmp":
+            return d128.compare_colvals(self.op, fa, fb, ldt, rdt)
+        from blaze_tpu.exprs import decimal_arith as D
+        out_t = D.result_type("*", ldt, rdt)
+        m = self._constant_factor()
+        lit_is_right = m[0] == "r"
+        out = d128.multiply_colvals(fa if lit_is_right else fb,
+                                    fb if lit_is_right else fa, m[1], out_t)
+        if d128.is_limbs(out.data) \
+                and not isinstance(out.data, jax.core.Tracer):
+            # outside a trace a wider decimal is a host column
+            return ColVal.host(out_t, d128.limbs_to_arrow(
+                out.data, out.validity, batch.num_rows, out_t.to_arrow()))
+        return out
+
+    def _constant_factor(self):
+        """("l" | "r", unscaled value) where one side of a multiply is
+        a non-NULL decimal or integer literal under 2^31, else None."""
+        from blaze_tpu.exprs.base import Literal
+        for side, e in (("r", self.right), ("l", self.left)):
+            if not isinstance(e, Literal) or e.value is None:
+                continue
+            if e.dtype.id == TypeId.DECIMAL:
+                v = e.unscaled()
+            elif e.dtype.is_integer and not isinstance(e.value, bool):
+                v = int(e.value)
+            else:
+                continue
+            if abs(v) < (1 << 31):
+                return side, v
+        return None
+
+    def _decimal_limb_plan(self, ldt: DataType, rdt: DataType
+                           ) -> Optional[str]:
+        """"cmp": both sides fit 38 digits at the larger scale; "mul":
+        one side is a constant under 2^31 and the product's type keeps
+        every digit (p1 + p2 + 1 <= 38)."""
+        from blaze_tpu import config
+        if not config.ENCODING_DECIMAL_ENABLE.get():
+            return None
+        if self.op in _CMP:
+            s = max(ldt.scale, rdt.scale)
+            if max(ldt.precision + s - ldt.scale,
+                   rdt.precision + s - rdt.scale) <= 38:
+                return "cmp"
+            return None
+        if self.op == "*" and ldt.precision + rdt.precision + 1 <= 38 \
+                and self._constant_factor() is not None:
+            return "mul"
+        return None
+
     def evaluate(self, batch: ColumnBatch) -> ColVal:
         a = self.left.evaluate(batch)
         b = self.right.evaluate(batch)
         lt, rt = self._child_types(batch.schema)
         dec = self._decimal_types(lt, rt)
-        if dec is not None and self.op in _CMP \
-                and a.is_device and b.is_device \
-                and not self._decimal_device_ok(*dec) \
-                and self._decimal_limb_ok(*dec):
-            # unequal-scale comparison within p<=18: rescale through the
-            # two-limb int128 kernels — exact (no rounding, no overflow
-            # semantics needed for compares), and traceable, so these
-            # predicates keep their stage device-resident
-            from blaze_tpu.kernels import decimal128 as d128
-            return d128.compare_colvals(self.op, a, b, dec[0], dec[1])
+        if dec is None and self._decimal_beside_float(lt, rt):
+            a = self._decimal_as_double(a, batch)
+            b = self._decimal_as_double(b, batch)
         if dec is not None and not (self._decimal_device_ok(*dec)
                                     and a.is_device and b.is_device):
+            # a comparison of unequal scales, a wider operand, a multiply
+            # by a constant: through the two-limb int128 kernels, exact
+            # (no rounding; a product of in-bound operands cannot pass its
+            # type) and traceable, so these keep their stage
+            # device-resident
+            out = self._decimal_limbs(a, b, dec[0], dec[1], batch)
+            if out is not None:
+                return out
             # exact Spark decimal semantics (scale alignment, result
             # widening, overflow -> null) — the unscaled-int64 device
             # math below is only correct for EQUAL scales within p<=18,
@@ -144,6 +249,10 @@ class BinaryExpr(PhysicalExpr):
             return _kleene(self.op, a, b)
         if self.op in _CMP:
             return _compare(self.op, a, b)
+        if dec is not None and self.op == "*":
+            # two int32 lanes (p <= 9 each) would multiply in int32
+            a = ColVal(a.dtype, data=a.data.astype(jnp.int64),
+                       validity=a.validity)
         out = _arith(self.op, a, b, self.data_type(batch.schema))
         if self.op in ("+", "-", "*", "/", "%", "pmod"):
             from blaze_tpu import config
@@ -208,6 +317,9 @@ class BinaryExpr(PhysicalExpr):
         +/- result precision max(p1,p2)+1 <= 18 cannot overflow int64).
         Everything else (mixed scales, *, /, %, wide) needs the exact
         host path."""
+        if self.op == "*":
+            # |x * y| < 10^(p1 + p2): inside int64, and inside its type
+            return ldt.precision + rdt.precision + 1 <= 18
         if ldt.scale != rdt.scale:
             return False
         if max(ldt.precision, rdt.precision) > 18:
@@ -216,18 +328,6 @@ class BinaryExpr(PhysicalExpr):
             return True
         return self.op in ("+", "-") and \
             max(ldt.precision, rdt.precision) + 1 <= 18
-
-    def _decimal_limb_ok(self, ldt: DataType, rdt: DataType) -> bool:
-        """Unequal-scale comparisons stay on device through the two-limb
-        int128 rescale when both operands fit the int64 unscaled form and
-        the rescale multiplier keeps products inside int128 (10^18 *
-        10^20 < 2^127)."""
-        from blaze_tpu import config
-        if not config.ENCODING_DECIMAL_ENABLE.get():
-            return False
-        if max(ldt.precision, rdt.precision) > 18:
-            return False
-        return abs(ldt.scale - rdt.scale) <= 20
 
     def _evaluate_dict(self, batch: ColumnBatch, a: ColVal,
                        b: ColVal) -> Optional[ColVal]:

@@ -35,6 +35,15 @@ _INT_AS_DECIMAL = {"int8": (3, 0), "int16": (5, 0), "int32": (10, 0),
                    "int64": (20, 0), "bool": (1, 0)}
 
 
+def host_interval(op: str, rows: int, t: DataType):
+    """The real interval of decimal work outside a device program: an
+    eager expression batch, an eager aggregation's batch, an average's
+    final quotient (`t`: the first decimal operand's type)."""
+    from blaze_tpu.bridge import tracing
+    return tracing.span("decimal_host_eval", op=op, rows=int(rows),
+                        precision=t.precision, scale=t.scale)
+
+
 def as_decimal_type(t: DataType) -> Optional[DataType]:
     if t.id == TypeId.DECIMAL:
         return t

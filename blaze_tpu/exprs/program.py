@@ -43,13 +43,15 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from blaze_tpu import config
-from blaze_tpu.batch import ColumnBatch, DeviceColumn, bucket_capacity
+from blaze_tpu.batch import (ColumnBatch, DeviceColumn, HostColumn,
+                             bucket_capacity)
 from blaze_tpu.exprs.base import BoundReference, Literal, PhysicalExpr
 from blaze_tpu.exprs.binary import _ARITH, _BOOLEAN, _CMP, BinaryExpr
 from blaze_tpu.exprs.cast import Cast, _device_supported
 from blaze_tpu.exprs.conditional import (CaseWhen, Coalesce, If, InList,
                                          IsNotNull, IsNull, Not)
 from blaze_tpu.exprs.evaluator import CachedExprsEvaluator, split_conjuncts
+from blaze_tpu.kernels import decimal128 as d128
 from blaze_tpu.schema import DataType, Schema, TypeId
 
 
@@ -69,8 +71,14 @@ def _ref_dtype_ok(dt: DataType) -> bool:
     scaled integers (the op-level checks below still require exactness
     — equal-scale device math or the limb rescale for compares)."""
     if dt.id == TypeId.DECIMAL:
-        return config.ENCODING_DECIMAL_ENABLE.get() and dt.is_fixed_width
+        # (one wider than 18 digits enters as its two limbs)
+        return bool(config.ENCODING_DECIMAL_ENABLE.get())
     return _dtype_ok(dt)
+
+
+def _limb_column(col) -> bool:
+    return isinstance(col, HostColumn) \
+        and col.dtype.id == TypeId.DECIMAL
 
 
 def is_traceable(expr: PhysicalExpr, schema: Schema) -> bool:
@@ -86,6 +94,8 @@ def _traceable(e: PhysicalExpr, schema: Schema) -> bool:
     if isinstance(e, BoundReference):
         return _ref_dtype_ok(schema[e.index].data_type)
     if isinstance(e, Literal):
+        if e.dtype.id == TypeId.DECIMAL:
+            return _ref_dtype_ok(e.dtype) and e.dtype.is_fixed_width
         return _dtype_ok(e.dtype)
     if isinstance(e, BinaryExpr):
         if e.op not in _ARITH and e.op not in _CMP and e.op not in _BOOLEAN:
@@ -95,14 +105,18 @@ def _traceable(e: PhysicalExpr, schema: Schema) -> bool:
             return False
         if TypeId.DECIMAL in (lt.id, rt.id):
             # only the ops whose device math is exact may trace: equal-
-            # scale compares/+- on the unscaled ints, or unequal-scale
-            # compares through the limb rescale.  Everything else routes
-            # decimal_arith's host path, which cannot trace.
+            # scale compares/+- and narrow products on the unscaled ints,
+            # compares and a multiply by a constant through the limbs, a
+            # narrow decimal beside a double as a double.  Everything
+            # else routes decimal_arith's host path, which cannot trace.
             dec = e._decimal_types(lt, rt)
             if dec is None:
-                return False
-            if not (e._decimal_device_ok(*dec)
-                    or (e.op in _CMP and e._decimal_limb_ok(*dec))):
+                if not (e._decimal_beside_float(lt, rt)
+                        and lt.is_fixed_width and rt.is_fixed_width):
+                    return False
+            elif not (e._decimal_device_ok(*dec)
+                      and lt.is_fixed_width and rt.is_fixed_width) \
+                    and e._decimal_limb_plan(*dec) is None:
                 return False
         return _traceable(e.left, schema) and _traceable(e.right, schema)
     if isinstance(e, (Not, IsNull, IsNotNull)):
@@ -144,6 +158,29 @@ def _note_host_eviction(exprs: Sequence[PhysicalExpr],
     xla_stats.note_encoding(**{f"host_evictions_{reason}": 1})
 
 
+def _decimal_operand(exprs: Sequence[PhysicalExpr],
+                     schema: Schema) -> Optional[DataType]:
+    """The type of the first decimal a chain reads or computes."""
+    def walk(e: PhysicalExpr):
+        try:
+            t = e.data_type(schema)
+        except Exception:
+            t = None
+        if t is not None and t.id == TypeId.DECIMAL:
+            return t
+        for c in e.children():
+            found = walk(c)
+            if found is not None:
+                return found
+        return None
+
+    for e in exprs:
+        found = walk(e)
+        if found is not None:
+            return found
+    return None
+
+
 def _collect_refs(exprs: Sequence[PhysicalExpr]) -> List[int]:
     refs: set = set()
 
@@ -181,7 +218,10 @@ def program_fingerprint(mode: str, filters: Sequence[PhysicalExpr],
             # encoding knobs change what the trace computes (limb
             # compares, scaled-int decimal operands): new setting ->
             # new program, zero steady-state recompiles within one
-            bool(config.ENCODING_DECIMAL_ENABLE.get()),
+            # (where the input has a decimal column: a chain over none
+            # computes the same either way, and keeps its name)
+            bool(config.ENCODING_DECIMAL_ENABLE.get())
+            and any(f.data_type.id == TypeId.DECIMAL for f in in_schema),
             bool(config.ENCODING_DICT_ENABLE.get()))
 
 
@@ -252,6 +292,10 @@ class ExprProgram:
         flat = []
         for i in self.ref_idx:
             col = batch.columns[i]
+            if not isinstance(col, DeviceColumn):
+                # a decimal wider than 18 digits: its two limbs
+                flat.extend(d128.host_limbs(col.array, pcap))
+                continue
             for a in (col.data, col.validity):
                 if pcap != cap and isinstance(a, np.ndarray):
                     a = np.pad(a, (0, pcap - a.shape[0]))
@@ -260,6 +304,7 @@ class ExprProgram:
 
     def batch_ok(self, batch: ColumnBatch) -> bool:
         return all(isinstance(batch.columns[i], DeviceColumn)
+                   or _limb_column(batch.columns[i])
                    for i in self.ref_idx)
 
     def run_filter(self, batch: ColumnBatch) -> ColumnBatch:
@@ -299,6 +344,14 @@ class ExprProgram:
         cols = []
         pcap = pairs[0][0].shape[0] if pairs else batch.capacity
         for f, (data, valid) in zip(out_schema, pairs):
+            if d128.is_limbs(data):
+                # a limb lane: a decimal wider than 18 digits leaves the
+                # program as the host column its type is
+                from blaze_tpu.xputil import to_host
+                data, valid = to_host((data, valid))
+                cols.append(HostColumn(f.data_type, d128.limbs_to_arrow(
+                    data, valid, batch.num_rows, f.data_type.to_arrow())))
+                continue
             if to_np:
                 data, valid = np.asarray(data), np.asarray(valid)
             cols.append(DeviceColumn(f.data_type, data, valid))
@@ -379,6 +432,9 @@ class FusedExprsEvaluator:
         self._filter_prog: Optional[ExprProgram] = None
         self._project_prog: Optional[ExprProgram] = None
         self._fp_prog: Optional[ExprProgram] = None
+        # the type of the first decimal operand in the chain, if any
+        self._decimal: Optional[DataType] = None if in_schema is None \
+            else _decimal_operand(self.filters + self.projections, in_schema)
         if in_schema is None or not config.EXPR_FUSE.get() or \
                 config.ANSI_ENABLED.get():
             return
@@ -416,32 +472,49 @@ class FusedExprsEvaluator:
         return prog is not None and self._fusion_on() and \
             prog.batch_ok(batch)
 
-    def filter(self, batch: ColumnBatch) -> ColumnBatch:
+    def _in_program(self) -> None:
+        if self._decimal is not None:
+            from blaze_tpu.bridge import xla_stats
+            xla_stats.note_decimal(expr_device_batches=1)
+
+    def _eagerly(self, batch: ColumnBatch, op: str):
+        """Accounts one batch of the eager evaluator; where the chain has
+        a decimal operand, the real interval of its work on the host."""
+        from contextlib import nullcontext
         from blaze_tpu.bridge import xla_stats
-        if self._usable(self._filter_prog, batch):
-            return self._filter_prog.run_filter(batch)
+        from blaze_tpu.exprs.decimal_arith import host_interval
         xla_stats.note_expr_dispatch(eager=1)
-        return self._eager.filter(batch)
+        if self._decimal is None:
+            return nullcontext()
+        xla_stats.note_decimal(expr_host_batches=1)
+        return host_interval(op, batch.num_rows, self._decimal)
+
+    def filter(self, batch: ColumnBatch) -> ColumnBatch:
+        if self._usable(self._filter_prog, batch):
+            self._in_program()
+            return self._filter_prog.run_filter(batch)
+        with self._eagerly(batch, "filter"):
+            return self._eager.filter(batch)
 
     def project(self, batch: ColumnBatch, out_schema: Schema) -> ColumnBatch:
-        from blaze_tpu.bridge import xla_stats
         if self._usable(self._project_prog, batch):
+            self._in_program()
             return self._project_prog.run_project(batch, out_schema)
-        xla_stats.note_expr_dispatch(eager=1)
-        return self._eager.project(batch, out_schema)
+        with self._eagerly(batch, "project"):
+            return self._eager.project(batch, out_schema)
 
     def filter_project(self, batch: ColumnBatch, out_schema: Schema
                        ) -> ColumnBatch:
-        from blaze_tpu.bridge import xla_stats
         if self._usable(self._fp_prog, batch):
+            self._in_program()
             return self._fp_prog.run_filter_project(batch, out_schema)
         if self._usable(self._filter_prog, batch):
             # traceable filter + host-only projection: fuse the mask,
             # project eagerly on the narrowed batch
             filtered = self._filter_prog.run_filter(batch)
             return self._eager.project(filtered, out_schema)
-        xla_stats.note_expr_dispatch(eager=1)
-        return self._eager.filter_project(batch, out_schema)
+        with self._eagerly(batch, "filter_project"):
+            return self._eager.filter_project(batch, out_schema)
 
 
 def fused_filter(predicates: Sequence[PhysicalExpr], schema: Schema

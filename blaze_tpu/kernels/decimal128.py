@@ -133,18 +133,77 @@ def rescaled_pair(xp, values, scale: int, target_scale: int):
     return mul_pow10(xp, h, l, target_scale - scale)
 
 
-def compare_colvals(op: str, a, b, ldt: DataType, rdt: DataType):
-    """Device comparison of two decimal ColVals with unequal scales,
-    via int128 rescale.  Traceable (pure vector math), so predicates
-    using it keep their stage on the device loop.  Returns a BOOL
-    ColVal with Spark null semantics (<=> is null-safe)."""
+# A decimal wider than 18 digits has no int64 lane.  Inside an expression
+# it is its two limbs: `data` of shape (capacity, 2), [:, 0] the low limb
+# and [:, 1] the high one, which is also how Arrow lays a decimal128 out.
+
+def is_limbs(data) -> bool:
+    return getattr(data, "ndim", 1) == 2
+
+
+def pair_of(xp, data):
+    """(hi, lo) of a lane: a limb lane's own, an integer lane's sign-
+    extended."""
+    if is_limbs(data):
+        return data[:, 1], data[:, 0]
+    return from_int64(xp, data)
+
+
+def host_limbs(arr, capacity: int):
+    """A decimal128 Arrow array as a limb lane padded to `capacity`:
+    (data int64 (capacity, 2), validity bool (capacity,)), numpy."""
+    import pyarrow as pa
+    if isinstance(arr, pa.ChunkedArray):
+        arr = arr.combine_chunks()
+    from blaze_tpu.batch import decimal_limb_pairs
+    n = len(arr)
+    data = np.zeros((capacity, 2), dtype=np.int64)
+    data[:n] = decimal_limb_pairs(arr)
+    valid = np.zeros(capacity, dtype=bool)
+    valid[:n] = np.asarray(arr.is_valid()) if arr.null_count else True
+    return data, valid
+
+
+def limbs_to_arrow(data, validity, num_rows: int, t):
+    """A limb lane read back -> decimal128 Arrow array of `num_rows`."""
+    from blaze_tpu.batch import decimal_from_limbs
+    data = np.asarray(data)[:num_rows]
+    return decimal_from_limbs(data[:, 0], data[:, 1],
+                              np.asarray(validity)[:num_rows], t)
+
+
+def multiply_colvals(a, b, m: int, out: DataType):
+    """decimal * decimal where one side is the constant `m` (unscaled,
+    |m| < 2^31) and `a` is the other: exact at `out` = (p1+p2+1, s1+s2),
+    which no product of in-bound operands can pass.  Returns an integer
+    lane where `out` has one, a limb lane where it does not."""
     from blaze_tpu.exprs.base import ColVal
     xp = xp_of(a.data, b.data)
-    x = a.data.astype(xp.int64)
-    y = b.data.astype(xp.int64)
+    valid = a.validity & b.validity
+    if out.precision <= 18:
+        data = a.data.astype(xp.int64) * _i64(xp, m)
+        return ColVal(out, data=xp.where(valid, data, 0), validity=valid)
+    h, l = pair_of(xp, a.data)
+    h, l = mul_small(xp, h, l, abs(m))
+    if m < 0:
+        h, l = neg128(xp, h, l)
+    _note_limb_dispatch(a.data)
+    zero = _i64(xp, 0)
+    data = xp.stack([xp.where(valid, l, zero), xp.where(valid, h, zero)],
+                    axis=1)
+    return ColVal(out, data=data, validity=valid)
+
+
+def compare_colvals(op: str, a, b, ldt: DataType, rdt: DataType):
+    """Device comparison of two decimal ColVals with unequal scales or
+    more than 18 digits, via int128 rescale.  Traceable (pure vector
+    math), so predicates using it keep their stage on the device loop.
+    Returns a BOOL ColVal with Spark null semantics (<=> is null-safe)."""
+    from blaze_tpu.exprs.base import ColVal
+    xp = xp_of(a.data, b.data)
     target = max(ldt.scale, rdt.scale)
-    xh, xl = rescaled_pair(xp, x, ldt.scale, target)
-    yh, yl = rescaled_pair(xp, y, rdt.scale, target)
+    xh, xl = mul_pow10(xp, *pair_of(xp, a.data), target - ldt.scale)
+    yh, yl = mul_pow10(xp, *pair_of(xp, b.data), target - rdt.scale)
     _note_limb_dispatch(a.data)
     eq = eq128(xp, xh, xl, yh, yl)
     lt = lt128(xp, xh, xl, yh, yl)

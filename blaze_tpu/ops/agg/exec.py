@@ -31,10 +31,11 @@ from blaze_tpu import config
 from blaze_tpu.batch import ColumnBatch, DeviceColumn, bucket_capacity
 from blaze_tpu.exprs import PhysicalExpr
 from blaze_tpu.exprs.base import ColVal
+from blaze_tpu.exprs.decimal_arith import host_interval
 from blaze_tpu.kernels import compare
 from blaze_tpu.kernels import sort as K
 from blaze_tpu.memory import MemConsumer, MemManager, Spill, try_new_spill
-from blaze_tpu.ops.agg.functions import AggFunction
+from blaze_tpu.ops.agg.functions import AggFunction, AvgAgg
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
 from blaze_tpu.ops.sort import merge_sorted_batches
 from blaze_tpu.schema import DataType, Field, INT64, Schema, TypeId
@@ -71,7 +72,10 @@ class AggExec(ExecutionPlan):
         # Safety still rests on _skip_eligible().
         self.skip_partial_hint = bool(skip_partial_hint)
         in_schema = child.schema
-        for fn, _, _ in self._aggs:
+        for fn, mode, _ in self._aggs:
+            # a sum or an average over partial accumulators keeps their
+            # type (functions.py): the mode is known here, not at make_agg
+            fn.merging = mode in (AggMode.PARTIAL_MERGE, AggMode.FINAL)
             fn.bind(in_schema)
         self._out_schema = self._build_schema(in_schema)
 
@@ -180,6 +184,10 @@ class _AggState(MemConsumer):
         self.passthrough_rows = 0
         self._probe_done = False  # the cardinality probe runs ONCE
         self._internal_schema: Optional[pa.Schema] = None
+        # the type of the first decimal an aggregate function is given
+        self._decimal_arg: Optional[DataType] = next(
+            (fn.decimal_input for fn, _m, _n in op._aggs
+             if fn.decimal_input is not None), None)
 
     # ------------------------------------------------------------------
     # ingest
@@ -201,7 +209,15 @@ class _AggState(MemConsumer):
             if out is not None:
                 yield out
             return
-        partial = self._aggregate_input_batch(batch)
+        if self._decimal_arg is None:
+            partial = self._aggregate_input_batch(batch)
+        else:
+            # an aggregation over a decimal outside the stage loop
+            n = batch.selected_count()
+            from blaze_tpu.bridge import xla_stats
+            xla_stats.note_decimal(agg_rows_host=n)
+            with host_interval("agg", n, self._decimal_arg):
+                partial = self._aggregate_input_batch(batch)
         if partial is None:
             return
         self.rows_seen += batch.selected_count()
@@ -738,6 +754,18 @@ class _AggState(MemConsumer):
                     if fn.is_host:
                         sink.add_host(fn.host_eval(
                             [rb.column(j + t) for t in range(nacc)]))
+                    elif isinstance(fn, AvgAgg) \
+                            and fn.decimal_input is not None:
+                        # a decimal average: the quotient of the buffered
+                        # int64 sums and counts, on the host, at Spark's
+                        # type (which may be wider than 18 digits)
+                        out_t = fn.output_type(self.in_schema)
+                        with host_interval("avg", rb.num_rows, out_t):
+                            sums, counts = (
+                                np.asarray(rb.column(j + t).fill_null(0))
+                                .astype(np.int64) for t in range(2))
+                            sink.add_host(fn.final_eval_decimal(
+                                sums, counts, out_t))
                     else:
                         cap = bucket_capacity(rb.num_rows)
                         accs = []
@@ -823,10 +851,10 @@ def _cast_output(a: pa.Array, t: pa.DataType) -> pa.Array:
         return a
     if pa.types.is_decimal(t) and pa.types.is_integer(a.type):
         # internal unscaled int64 -> decimal: reinterpret at the target
-        # scale, NOT an arrow value cast (which would rescale)
-        import decimal as pydec
-        scale = t.scale
-        py = [None if not x.is_valid
-              else pydec.Decimal(x.as_py()).scaleb(-scale) for x in a]
-        return pa.array(py, type=t)
+        # scale, NOT an arrow value cast (which would rescale); past the
+        # type's bound a sum is NULL (non-ANSI CheckOverflow)
+        from blaze_tpu.batch import bounded_decimal
+        valid = np.asarray(a.is_valid()) if a.null_count \
+            else np.ones(len(a), dtype=bool)
+        return bounded_decimal(np.asarray(a.fill_null(0)), valid, t)
     return a.cast(t, safe=False)

@@ -41,10 +41,20 @@ class AggFunction:
     """One aggregate function instance bound to its input expressions."""
 
     name = "agg"
+    # the arguments are partial accumulators (PARTIAL_MERGE / FINAL): set
+    # by AggExec, which knows the mode; a sum's or an average's types
+    # depend on it
+    merging = False
 
     def __init__(self, children: Sequence[PhysicalExpr]):
         self.children = list(children)
         self.input_type: Optional[DataType] = None
+
+    @property
+    def decimal_input(self) -> Optional[DataType]:
+        """The first argument's type where it is a decimal (bound)."""
+        t = self.input_type
+        return t if t is not None and t.id == TypeId.DECIMAL else None
 
     def bind(self, input_schema: Schema) -> None:
         """Resolve input type once (AggExec calls this at plan time)."""
@@ -81,35 +91,75 @@ class AggFunction:
         return False
 
 
+_MAX_DECIMAL_PRECISION = 38
+
+
+def _bounded_decimal(precision: int, scale: int) -> DataType:
+    """Spark's DecimalType.bounded."""
+    return DataType(TypeId.DECIMAL, min(precision, _MAX_DECIMAL_PRECISION),
+                    min(scale, _MAX_DECIMAL_PRECISION))
+
+
 def _out_num_type(dt: DataType) -> DataType:
-    """Spark sum/avg result types: int sums stay int64, floats f64,
-    decimal sums keep decimal (scale preserved, precision widened)."""
+    """Spark sum result types over RAW input: int sums stay int64, floats
+    f64, sum(decimal(p,s)) is decimal(p+10, s)."""
     if dt.id == TypeId.DECIMAL:
-        return DataType(TypeId.DECIMAL, min(dt.precision + 10, 18), dt.scale)
+        return _bounded_decimal(dt.precision + 10, dt.scale)
     if dt.id in (TypeId.FLOAT32, TypeId.FLOAT64):
         return FLOAT64
     return INT64
 
 
+def decimal_sum_guard(data, valid) -> None:
+    """A sum of unscaled decimals over int64 lanes is exact only while no
+    group can pass 64 bits.  The sum of the magnitudes bounds every
+    group's sum, so where that stays under 2^62 nothing wraps.  Past it
+    this engine has no wider accumulator outside the stage loop: it
+    refuses, it never wraps."""
+    xp = xp_of(data)
+    live = xp.where(valid, data, xp.zeros_like(data))
+    mass = float(xp.sum(xp.abs(live.astype(xp.float64))))
+    if mass >= float(1 << 62):
+        from blaze_tpu.bridge import xla_stats
+        xla_stats.note_decimal(overflow_groups=1)
+        raise ArithmeticError(
+            "a decimal sum may pass 64 bits in this batch (sum of "
+            f"magnitudes {mass:.3e}); the eager aggregation has no wider "
+            "accumulator and will not wrap")
+
+
 class SumAgg(AggFunction):
+    """`merging`: the argument is a partial sum (PARTIAL_MERGE / FINAL),
+    whose type is the result's already: Spark's sum(decimal(p,s)) is
+    decimal(p+10, s) in every mode, never widened twice."""
+
     name = "sum"
 
+    def _sum_type(self, s):
+        t = self.children[0].data_type(s)
+        if self.merging and t.id == TypeId.DECIMAL:
+            return t
+        return _out_num_type(t)
+
     def acc_fields(self, s):
-        t = _out_num_type(self.children[0].data_type(s))
-        return [Field("sum", t)]
+        return [Field("sum", self._sum_type(s))]
 
     def output_type(self, s):
-        return _out_num_type(self.children[0].data_type(s))
+        return self._sum_type(s)
 
     def partial_update(self, args, gids, n):
         data, valid = args[0]
         acc_dt = jnp.float64 if jnp.issubdtype(data.dtype, jnp.floating) else jnp.int64
+        if self.decimal_input is not None:
+            decimal_sum_guard(data, valid)
         s = K.segment_sum(data.astype(acc_dt), gids, n, valid)
         has = K.segment_count(valid, gids, n) > 0
         return ((s, has),)
 
     def partial_merge(self, accs, gids, n):
         data, valid = accs[0]
+        if self.decimal_input is not None:
+            decimal_sum_guard(data, valid)
         s = K.segment_sum(data, gids, n, valid)
         has = K.segment_count(valid, gids, n) > 0
         return ((s, has),)
@@ -150,24 +200,29 @@ class CountAgg(AggFunction):
 
 
 class AvgAgg(AggFunction):
+    """`merging`: the arguments are the partial (sum, count).  Spark's
+    avg(decimal(p,s)) sums in decimal(p+10, s), counts in int64 and
+    gives decimal(p+4, s+4) of its INPUT, in every mode."""
+
     name = "avg"
 
-    def acc_fields(self, s):
+    def _sum_type(self, s):
         t = self.children[0].data_type(s)
         if t.id == TypeId.DECIMAL:
-            sum_t = _out_num_type(t)
-        elif t.id in (TypeId.FLOAT32, TypeId.FLOAT64):
-            sum_t = FLOAT64
-        else:
-            sum_t = INT64  # Spark avg(int) sums as long
-        return [Field("sum", sum_t), Field("count", INT64, nullable=False)]
+            return t if self.merging else _out_num_type(t)
+        if t.id in (TypeId.FLOAT32, TypeId.FLOAT64):
+            return FLOAT64
+        return INT64  # Spark avg(int) sums as long
+
+    def acc_fields(self, s):
+        return [Field("sum", self._sum_type(s)),
+                Field("count", INT64, nullable=False)]
 
     def output_type(self, s):
-        t = self.children[0].data_type(s)
+        t = self._sum_type(s)
         if t.id == TypeId.DECIMAL:
-            # Spark: avg(decimal(p,s)) -> decimal(p+4, s+4) capped
-            return DataType(TypeId.DECIMAL, min(t.precision + 4, 18),
-                            min(t.scale + 4, 18))
+            # of the input decimal(p,s), whose sum is decimal(p+10, s)
+            return _bounded_decimal(t.precision - 10 + 4, t.scale + 4)
         return FLOAT64
 
     def partial_update(self, args, gids, n):
@@ -175,12 +230,16 @@ class AvgAgg(AggFunction):
         if jnp.issubdtype(data.dtype, jnp.floating):
             s = K.segment_sum(data.astype(jnp.float64), gids, n, valid)
         else:  # int and decimal-unscaled sums stay exact in int64
+            if self.decimal_input is not None:
+                decimal_sum_guard(data, valid)
             s = K.segment_sum(data.astype(jnp.int64), gids, n, valid)
         c = K.segment_count(valid, gids, n)
         return ((s, c > 0), (c, xp_of(c).ones(n, dtype=bool)))
 
     def partial_merge(self, accs, gids, n):
         (s_d, s_v), (c_d, c_v) = accs
+        if self.decimal_input is not None:
+            decimal_sum_guard(s_d, s_v)
         s = K.segment_sum(s_d, gids, n, s_v)
         c = K.segment_sum(c_d, gids, n, c_v)
         return ((s, c > 0), (c, xp_of(c).ones(c.shape[0], dtype=bool)))
@@ -190,14 +249,47 @@ class AvgAgg(AggFunction):
         xp = xp_of(s_d, c_d)
         valid = c_d > 0
         denom = xp.where(valid, c_d, 1)
-        if self.input_type is not None and self.input_type.id == TypeId.DECIMAL:
-            # decimal(p,s) -> decimal(p+4, s+4): unscaled*10^4 / count, HALF_UP
-            num = s_d * xp.int64(10_000)
-            half = denom // 2
-            adj = xp.where(num >= 0, num + half, num - half)
-            q = xp.sign(adj) * (xp.abs(adj) // denom)
-            return q, valid
         return s_d / denom.astype(xp.float64), valid
+
+    def final_eval_decimal(self, sums: np.ndarray, counts: np.ndarray,
+                           out: DataType) -> pa.Array:
+        """sum * 10^4 / count rounded HALF_UP, as decimal(p+4, s+4): on
+        the host, over the unscaled int64 sums as they were read back.
+        The quotient is taken first and the remainder scaled, so
+        10^4 * sum is never formed; a row whose average passes 64 bits is
+        recomputed in Python integers, and one past its type's bound is
+        NULL (non-ANSI)."""
+        from blaze_tpu.batch import decimal_from_limbs
+        valid = counts > 0
+        den = np.where(valid, counts, 1).astype(np.int64)
+        with np.errstate(over="ignore"):
+            mag = np.abs(sums)  # INT64_MIN stays negative: flagged below
+            q0, r0 = np.divmod(mag, den)
+            frac = (2 * 10_000 * r0 + den) // (2 * den)
+            wide = (mag < 0) | (q0 > ((1 << 63) - 1 - frac) // 10_000)
+            lo = np.where(sums < 0, -(q0 * 10_000 + frac),
+                          q0 * 10_000 + frac)
+        hi = lo >> 63
+        bound = 10 ** out.precision
+        if out.precision <= 18:
+            valid = valid & ~wide & (np.abs(lo) < bound)
+        elif wide.any():
+            for i in np.flatnonzero(wide & valid):
+                total, n = int(sums[i]), int(counts[i])
+                q, r = divmod(abs(total) * 10_000, n)
+                q += 2 * r >= n
+                v = -q if total < 0 else q
+                if abs(v) >= bound:
+                    valid[i] = False
+                else:
+                    lo[i] = (v & ((1 << 64) - 1)) - ((v >> 63 & 1) << 64)
+                    hi[i] = v >> 64
+        lost = int((counts > 0).sum() - valid.sum())
+        if lost or wide.any():
+            from blaze_tpu.bridge import xla_stats
+            xla_stats.note_decimal(overflow_groups=max(lost,
+                                                       int(wide.sum())))
+        return decimal_from_limbs(lo, hi, valid, out.to_arrow())
 
 
 class MinMaxAgg(AggFunction):

@@ -346,6 +346,7 @@ class QueryProfile:
                 f"batches={x.get('stage_loop_batches', 0)} "
                 f"rows={x.get('stage_loop_rows', 0)} "
                 f"lanes={x.get('stage_loop_lanes', 0)} "
+                f"decimal={x.get('stage_loop_decimal_rows', 0)} "
                 f"dispatches_avoided="
                 f"{x.get('stage_loop_staged_dispatches_avoided', 0)} "
                 f"reserves={x.get('stage_loop_reserves', 0)} "

@@ -45,7 +45,8 @@ from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.exprs import BoundReference, PhysicalExpr
 from blaze_tpu.ops.agg.exec import AggExec, AggMode
-from blaze_tpu.ops.agg.functions import CountAgg, MinMaxAgg, SumAgg
+from blaze_tpu.ops.agg.functions import (AvgAgg, CountAgg, MinMaxAgg,
+                                          SumAgg)
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
 from blaze_tpu.ops.basic import (DebugExec, FilterExec, FilterProjectExec,
                                  ProjectExec)
@@ -99,6 +100,18 @@ def _try_fuse_agg(node: ExecutionPlan) -> Optional["FusedPartialAggExec"]:
 
     specs: List[Tuple[str, str, Optional[PhysicalExpr]]] = []
     for fn, _m, _name in aggs:
+        if isinstance(fn, AvgAgg):
+            # a PARTIAL average is its (sum, count) accumulators, in that
+            # order (AvgAgg.acc_fields): two lanes of the same argument.
+            # Only over a decimal: the float fold sums in another order
+            # than AggExec's segments, which an average shows
+            arg = fn.children[0]
+            if mode != AggMode.PARTIAL or not _decimal_lane(
+                    arg.data_type(in_schema)):
+                return None
+            specs.append(("sum", "sum", arg))
+            specs.append(("count", "count", arg))
+            continue
         if isinstance(fn, SumAgg):
             out_kind = "sum"
         elif isinstance(fn, CountAgg):
@@ -113,8 +126,9 @@ def _try_fuse_agg(node: ExecutionPlan) -> Optional["FusedPartialAggExec"]:
         if arg is not None and not arg.data_type(in_schema).is_fixed_width:
             return None
         if out_kind in ("sum", "min", "max"):
-            if arg is None or not (arg.data_type(in_schema).is_integer or
-                                   arg.data_type(in_schema).is_floating):
+            t = arg.data_type(in_schema) if arg is not None else None
+            if t is None or not (t.is_integer or t.is_floating
+                                 or _decimal_lane(t)):
                 return None
         # merging counts SUMS the partial counts
         reduce_kind = "sum" if (merging and out_kind == "count") \
@@ -153,7 +167,12 @@ def _try_fuse_agg(node: ExecutionPlan) -> Optional["FusedPartialAggExec"]:
 
     # dense needs integer keys with discoverable bounds
     ranges = None
-    if fixed_keys and all(t.is_integer for t in key_types):
+    decimal_args = any(a is not None and _decimal_lane(a.data_type(in_schema))
+                       for _rk, _ok, a in specs)
+    # (a decimal value lane folds in the stage loop, whose table is the
+    # hash lane's: its guard against 64-bit wrap lives there alone)
+    if fixed_keys and all(t.is_integer for t in key_types) \
+            and not decimal_args:
         ranges = _discover_ranges(child, groups)
         if ranges is not None:
             total = 1
@@ -181,6 +200,13 @@ def _try_fuse_agg(node: ExecutionPlan) -> Optional["FusedPartialAggExec"]:
     if ranges is not None:
         node._mxu_meta = _plan_mxu_meta(child, specs, ranges, in_schema)
     return node
+
+
+def _decimal_lane(t) -> bool:
+    """A decimal(p <= 18) rides the aggregation lanes as its unscaled
+    integer (int32 on the way in for p <= 9, int64 in the table): sums
+    and counts are exact there, and min/max order as the values do."""
+    return t.id == TypeId.DECIMAL and t.is_fixed_width
 
 
 def _host_vectorized_eligible(group_exprs, specs, in_schema) -> bool:
@@ -235,7 +261,11 @@ def _chain_cache_key(source_schema: Schema, chain, group_exprs, specs):
             chain_k.append(("f", tuple(p.cache_key() for p in preds)))
         else:
             chain_k.append(("p", tuple(e.cache_key() for e in exprs)))
-    return (tuple((f.name, f.data_type.id.value) for f in source_schema),
+    return (tuple((f.name, f.data_type.id.value)
+                  # (a decimal's scale decides what the chain computes)
+                  + ((f.data_type.precision, f.data_type.scale)
+                     if f.data_type.id == TypeId.DECIMAL else ())
+                  for f in source_schema),
             tuple(chain_k),
             tuple(e.cache_key() for e, _ in group_exprs),
             tuple((rk, ok, a.cache_key() if a is not None else None)
@@ -521,6 +551,11 @@ class FusedPartialAggExec(ExecutionPlan):
         self._prepare = None
         self._prepare_key = None
         self._mxu_meta = None  # set by _try_fuse_agg when stats qualify
+        # per spec: the value lane is a decimal's unscaled integer
+        self._decimal_specs = tuple(
+            i for i, (_rk, _ok, arg) in enumerate(self._specs)
+            if arg is not None
+            and arg.data_type(self._in_schema).id == TypeId.DECIMAL)
         if self._chain or source is not None:
             self._prepare_key = _chain_cache_key(
                 self._source.schema, self._chain, self._group_exprs,
@@ -595,6 +630,14 @@ class FusedPartialAggExec(ExecutionPlan):
             except StageLoopFallback as e:
                 xla_stats.note_stage_loop_fallback(str(e))
                 self.metrics.add("stage_loop_fallback", 1)
+        if self._decimal_specs:
+            # a decimal value lane is guarded against 64-bit wrap by the
+            # stage loop alone (runtime/loop.py); every other lane of
+            # this node sums int64 unguarded, so outside the loop a
+            # decimal aggregation runs where it ran before it could fuse
+            yield from AggExec(self.children[0], self._group_exprs,
+                               self._aggs).execute(partition)
+            return
         if self._has_var_keys and not self._use_host_vectorized():
             # re-check the ADMISSION-time exclusion (dict_ok in
             # _try_fuse_agg): a plan fused for the host path whose
@@ -2515,6 +2558,11 @@ def _hash_chain_step_factory(key, prepare, kinds):
 
 def _to_arrow(data: np.ndarray, valid: np.ndarray,
               t: pa.DataType) -> pa.Array:
+    if pa.types.is_decimal(t):
+        # the lane holds the unscaled value: no cast (it would rescale),
+        # and NULL past the type's bound (a sum's CheckOverflow)
+        from blaze_tpu.batch import bounded_decimal
+        return bounded_decimal(data, valid, t)
     arr = pa.array(data, mask=~np.asarray(valid, dtype=bool))
     if not arr.type.equals(t):
         arr = arr.cast(t, safe=False)

@@ -82,6 +82,9 @@ class StageProgram:
     # utf8 (codes fold as int32; the loop captures each stream's last
     # dictionary to decode the drain); None entries are plain keys
     dict_keys: Tuple[Any, ...] = ()
+    # the specs whose kind is "sum" over a decimal's unscaled integer:
+    # the fold bounds those sums so that none can pass 64 bits unseen
+    decimal_sums: Tuple[int, ...] = ()
 
     @property
     def source(self):
@@ -152,16 +155,22 @@ def compile_fused_agg(agg) -> StageProgram:
     fingerprint = (agg._prepare_key, kinds,
                    tuple(str(d) for d in key_dtypes),
                    tuple(str(d) for d in acc_dtypes), dict_keys)
+    if agg._decimal_specs:
+        # (appended only then: a program without a decimal keeps the
+        # fingerprint it had)
+        fingerprint += (("decimal", agg._decimal_specs),)
     hit = fingerprint in _SEEN_FINGERPRINTS
     xla_stats.note_stage_program(cache_hit=hit)
     if not hit:
         if len(_SEEN_FINGERPRINTS) >= _SEEN_LIMIT:
             _SEEN_FINGERPRINTS.pop(next(iter(_SEEN_FINGERPRINTS)))
         _SEEN_FINGERPRINTS[fingerprint] = True
+    decimal_sums = tuple(i for i in agg._decimal_specs if kinds[i] == "sum")
     return StageProgram(agg=agg, prepare=agg._prepare,
                         prepare_key=agg._prepare_key, kinds=kinds,
                         key_dtypes=key_dtypes, acc_dtypes=acc_dtypes,
-                        fingerprint=fingerprint, dict_keys=dict_keys)
+                        fingerprint=fingerprint, dict_keys=dict_keys,
+                        decimal_sums=decimal_sums)
 
 
 def try_compile(agg) -> Optional[StageProgram]:

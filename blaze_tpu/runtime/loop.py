@@ -85,6 +85,7 @@ from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.memory import MemConsumer, MemManager
+from blaze_tpu.schema import TypeId
 from blaze_tpu.parallel.stage import (hash_agg_step, init_hash_carry,
                                       normalize_float_keys, rehash_width,
                                       row_contribution)
@@ -102,6 +103,11 @@ _MAX_SLOTS = 1 << 24
 # on a TPU v5e (PERF.md section 6, PR 25).
 _TRIGGER_LOAD = 0.25
 _TARGET_LOAD = 0.125
+
+# A decimal's int64 value lane: the partition is declined once the bound on
+# its sums (the fold's `mass`, float32) reaches this.  A quarter of 2^63:
+# the bound is kept in float32 and summed over the calls
+_DECIMAL_MASS_LIMIT = float(1 << 61)
 
 # the fold's `look` argument when no first look is pending: more live
 # rows than a chunk can hold, so the fold never stops for it
@@ -223,10 +229,11 @@ def _batch_of(cols_stacked, b):
 def _fold_factory(program, donate: bool):
     prepare = program.prepare
     kinds = program.kinds
+    decimal_sums = program.decimal_sums
 
     def fold_impl(carry, cols_stacked, masks, start, look):
         def body(state):
-            b, c, ovf_seen, first_ovf, folded, rounds = state
+            b, c, ovf_seen, first_ovf, folded, rounds, *mass = state
             kd, kv, ad, av, m = prepare(_batch_of(cols_stacked, b), masks[b])
             # once a batch overflows, later batches fold as no-ops: the
             # carry stays exactly at the pre-overflow state (hash_agg_step
@@ -238,20 +245,30 @@ def _fold_factory(program, donate: bool):
             hit = ovf > 0
             first_ovf = jnp.where(hit & ~ovf_seen, b, first_ovf)
             # the rows of an overflowing batch are not in the table
-            folded += jnp.where(hit, 0, jnp.sum(live, dtype=jnp.int32))
+            nlive = jnp.sum(live, dtype=jnp.int32)
+            folded += jnp.where(hit, 0, nlive)
+            # a decimal sums as its unscaled integer in an int64 lane: no
+            # group's sum can pass the live rows times the largest
+            # magnitude among them, summed over every batch folded
+            # (a program without a decimal sum carries no such bound)
+            for i in decimal_sums:
+                big = jnp.max(jnp.where(live & av[i], jnp.abs(ad[i]), 0))
+                mass[0] += big.astype(jnp.float32) * nlive.astype(jnp.float32)
             return (b + 1, new_c, jnp.logical_or(ovf_seen, hit), first_ovf,
-                    folded, rounds + step_rounds)
+                    folded, rounds + step_rounds, *mass)
 
         def more(state):
-            b, _c, _ovf_seen, _first_ovf, folded, _rounds = state
+            b, _c, _ovf_seen, _first_ovf, folded, _rounds, *_mass = state
             # `look` live rows are in: stop at this batch boundary, so
             # the host can take its first look at groups per live row
             return (b < masks.shape[0]) & (folded < look)
 
         zero = jnp.asarray(0, jnp.int32)
-        b, carry, ovf_seen, first_ovf, folded, rounds = jax.lax.while_loop(
-            more, body, (start, carry, jnp.asarray(False), zero, zero,
-                         jnp.zeros(2, jnp.int32)))
+        mass = (jnp.asarray(0, jnp.float32),) if decimal_sums else ()
+        b, carry, ovf_seen, first_ovf, folded, rounds, *mass = \
+            jax.lax.while_loop(
+                more, body, (start, carry, jnp.asarray(False), zero, zero,
+                             jnp.zeros(2, jnp.int32), *mass))
         # the table's group count, the live rows this call inserted and
         # the probe rounds it ran (full width, narrow width) ride the
         # overflow scalars' round trip: the host sizes the next chunk's
@@ -261,7 +278,10 @@ def _fold_factory(program, donate: bool):
         # when nothing stopped the fold)
         groups = jnp.sum(carry.used, dtype=jnp.int32)
         resume = jnp.where(ovf_seen, first_ovf, b)
-        return carry, ovf_seen, resume, groups, folded, rounds
+        # `mass`, beside the overflow scalars: what the decimal lanes'
+        # sums are bounded by so far in this call (the host adds the
+        # calls up and declines the partition before a sum could wrap)
+        return (carry, ovf_seen, resume, groups, folded, rounds, *mass)
 
     kwargs = {"donate_argnums": (0,)} if donate else {}
     return _cached(
@@ -409,6 +429,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
     rehashes = []
     full_rounds = narrow_rounds = 0
     ci = groups = live_folded = 0
+    decimal_mass = 0.0
     slots, carry = floor, None  # allocated at the first chunk, for it
     rest = None
 
@@ -479,14 +500,20 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                     look = (min_rows - live_folded
                             if may_switch and live_folded < min_rows
                             else _NO_LOOK)
-                    carry, ovf_seen, resume, ngroups, nlive, rounds = fold(
+                    carry, *scalars = fold(
                         carry, cols_stacked, masks,
                         jnp.asarray(start, jnp.int32),
                         jnp.asarray(look, jnp.int32))
                     fold_calls += 1
                     # the host waits for the fold here
-                    ovf_seen, resume, ngroups, nlive, rounds = to_host(
-                        (ovf_seen, resume, ngroups, nlive, rounds))
+                    ovf_seen, resume, ngroups, nlive, rounds, *mass = \
+                        to_host(tuple(scalars))
+                    if mass:
+                        decimal_mass += float(mass[0])
+                        if decimal_mass >= _DECIMAL_MASS_LIMIT:
+                            xla_stats.note_decimal(overflow_groups=1)
+                            raise StageLoopFallback(
+                                "a decimal sum may pass 64 bits")
                     full_rounds += int(rounds[0])
                     narrow_rounds += int(rounds[1])
                     groups = int(ngroups)
@@ -536,6 +563,8 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         table_bytes=table.peak, chip=task.device_id,
         full_rounds=full_rounds, narrow_rounds=narrow_rounds,
         dispatches_avoided=max(0, batches - fold_calls))
+    if program.agg._decimal_specs:
+        xla_stats.note_decimal(stage_loop_rows=rows, chip=task.device_id)
     program.agg._note_lane(batches)
     return carry, rest
 
@@ -663,11 +692,19 @@ def drain_device(program, carry):
         valids.append(jnp.take(kv, sel)[:count])
     for (_rk, out_kind, _a), acc, av in zip(program.agg._specs,
                                             carry.accs, carry.acc_valid):
-        dt = fields[i].data_type.jnp_dtype()
+        t = fields[i].data_type
         i += 1
-        datas.append(jnp.take(acc, sel)[:count].astype(dt))
+        data = jnp.take(acc, sel)[:count].astype(t.jnp_dtype())
+        datas.append(data)
         if out_kind == "count":
             valids.append(jnp.ones((count,), dtype=bool))
-        else:
-            valids.append(jnp.take(av, sel)[:count])
+            continue
+        valid = jnp.take(av, sel)[:count]
+        if out_kind == "sum" and t.id == TypeId.DECIMAL \
+                and t.precision <= 18:
+            # a sum past its type's bound is NULL (CheckOverflow), as
+            # the Arrow emission makes it (fused._to_arrow); uncounted
+            # here, where nothing reads the rows back
+            valid = valid & (jnp.abs(data) < 10 ** t.precision)
+        valids.append(valid)
     return datas, valids, count

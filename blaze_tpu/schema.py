@@ -10,6 +10,9 @@ Spark emits.  Device representation rules (TPU has no pointers):
   utf8/binary -> host-resident by default; materialized on device on demand as
       (offsets:int32[cap+1], bytes:uint8[byte_cap]) for hash/compare kernels.
   decimal(p<=18) -> int64 unscaled values (Spark's long-backed decimals).
+  decimal(p>18)  -> a host Arrow column between operators; inside an
+      expression program its two int64 limbs, (capacity, 2), low first
+      (kernels/decimal128.py).
 """
 
 from __future__ import annotations
