@@ -152,7 +152,8 @@ SPAN_NAMES: Dict[str, str] = {
                   "hash-indexed (step=index) (ops/joins/exec.py)",
     "join_probe": "one probe batch from hashed keys to joined batch, or "
                   "one Arrow-lane join over the collected probe side "
-                  "(ops/joins/exec.py; attrs rows)",
+                  "(ops/joins/exec.py; attrs rows, lane, and for "
+                  "lane=device index: direct or search)",
     "sort_device": "a sort's permutation taken on the device, one pass "
                    "per 32-bit digit of the order keys, under a merge join "
                    "or not (ops/sort.py; attrs rows, passes)",

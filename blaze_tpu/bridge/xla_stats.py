@@ -153,8 +153,11 @@ _sortmerge = {"sort_device_rows": 0, "smj_device_rows": 0,
 # The hash joins' probe side (ops/joins/exec.py): probe rows handed to a
 # join whose batches stay on the chip (`kernels/join.probe_gather`: inner,
 # unique fixed-width build key) and to every other join, whose pairs and
-# rows pass through the host.  By chip in `chip_stats()` too.
-_join = {"join_probe_device_rows": 0, "join_probe_host_rows": 0}
+# rows pass through the host; and, of the first, the rows whose build side
+# the program addressed by the key itself (`JoinMap.direct_key`: one dense
+# integer key, no hash and no search).  By chip in `chip_stats()` too.
+_join = {"join_probe_device_rows": 0, "join_probe_host_rows": 0,
+         "join_probe_direct_rows": 0}
 
 # Streaming-runtime accounting (streaming/executor.py StreamExecutor):
 # committed epochs and their wall time, rows/records through the
@@ -449,6 +452,7 @@ def _chip_entry(chip: int) -> Dict[str, int]:
         entry = _chips[chip] = {"tasks": 0, "h2d_bytes": 0, "d2h_bytes": 0,
                                 "join_probe_device_rows": 0,
                                 "join_probe_host_rows": 0,
+                                "join_probe_direct_rows": 0,
                                 "stage_loop_windows": 0,
                                 "stage_loop_windows_fused": 0,
                                 "stage_loop_lanes": 0,
@@ -477,13 +481,18 @@ def note_d2h(nbytes: int, wait_ns: int = 0, chip: int = 0) -> None:
         _chip_entry(chip)["d2h_bytes"] += int(nbytes)
 
 
-def note_join_probe(chip: int, on_device: bool, rows: int) -> None:
+def note_join_probe(chip: int, on_device: bool, rows: int,
+                    direct: bool = False) -> None:
     """`rows` probe rows reached a hash join on `chip`, through the
-    device-resident probe or the other."""
-    key = "join_probe_device_rows" if on_device else "join_probe_host_rows"
+    device-resident probe or the other; `direct`: the device-resident
+    probe read the build row at the key's own offset."""
+    keys = ["join_probe_device_rows" if on_device else "join_probe_host_rows"]
+    if direct:
+        keys.append("join_probe_direct_rows")
     with _lock:
-        _join[key] += int(rows)
-        _chip_entry(chip)[key] += int(rows)
+        for key in keys:
+            _join[key] += int(rows)
+            _chip_entry(chip)[key] += int(rows)
 
 
 def join_stats() -> dict:
@@ -514,6 +523,7 @@ def placement_stats() -> dict:
 def chip_stats() -> Dict[int, Dict[str, int]]:
     """device id -> {"tasks", "h2d_bytes", "d2h_bytes",
     "join_probe_device_rows", "join_probe_host_rows",
+    "join_probe_direct_rows",
     "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes",
     "stage_loop_decimal_rows" and the stage loop's table counters (_CHIP_TABLE_KEYS)} since the
     last reset: what each chip was given to do."""

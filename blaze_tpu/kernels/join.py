@@ -194,32 +194,17 @@ def _keys_equal(a: jax.Array, b: jax.Array, tid: str) -> jax.Array:
     return a == b
 
 
-@functools.partial(meter_jit, name="join.probe_gather",
-                   static_argnames=("tids",))
-def probe_gather(uh, urow, build_keys, build_cols, probe_keys, probe_cols,
-                 rows, selection, tids):
-    """One probe batch of an inner join on a UNIQUE build key, whole.
+def _row_mask(lanes: int, rows, selection):
+    mask = jnp.arange(lanes, dtype=jnp.int32) < rows
+    return mask if selection is None else mask & selection
 
-    uh: the build side's distinct hashes, ascending, padded with the
-    largest int64; urow: the build row of each, -1 for padding and for
-    a build row with a NULL key (NULL joins nothing).  build_keys: the
-    build side's key data, one array a key; build_cols / probe_cols:
-    ((data, validity), ...) of the columns the join puts out;
-    probe_keys: ((data, validity), ...) of the batch's keys, of the types
-    `tids`; rows, selection: the batch's row count and selection mask
-    (or None), its `row_mask()`.
 
-    Hashes the probe keys, searches `uh` (as `probe_counts` does), takes
-    the candidate's build row, compares the REAL keys there (a hash
-    collision joins nothing), gathers the build columns at the
-    candidate and packs the matched rows of both sides to the front.
-    Returns (probe columns, build columns, count), columns as
-    (data, validity) with validity false from `count` on."""
-    probe_keys = H.norm_float_keys(probe_keys, tids, jnp)
+def _search_rows(uh, urow, build_keys, probe_keys, tids, rows, selection):
+    """(candidate build row, hit) a lane through the hash-sorted index:
+    the probe keys' hash searched in `uh`, the hash and then the REAL
+    keys compared at the candidate (a hash collision joins nothing)."""
     h, any_null = hash_valid(probe_keys, tids)
-    mask = jnp.arange(h.shape[0], dtype=jnp.int32) < rows
-    if selection is not None:
-        mask = mask & selection
+    mask = _row_mask(h.shape[0], rows, selection)
     pos = jnp.searchsorted(uh, h)
     pos = jnp.clip(pos, 0, uh.shape[0] - 1)
     row = jnp.take(urow, pos)
@@ -229,6 +214,67 @@ def probe_gather(uh, urow, build_keys, build_cols, probe_keys, probe_cols,
         (bk, _), = H.norm_float_keys([(jnp.take(bk, row), None)], (tid,),
                                      jnp)
         hit = hit & _keys_equal(pk, bk, tid)
+    return row, hit
+
+
+def _direct_rows(drow, kmin, probe_key, rows, selection):
+    """(candidate build row, hit) a lane through the direct-address
+    index of ONE dense integer key: `drow[key - kmin]`.  The address is
+    the key, so there is nothing to hash, search or compare.  The
+    difference wraps in the key's own width (an int8 or int16 key is
+    widened to 32 bits first, since the index may have more entries than
+    such a key has positive values), and a wrapped difference that lands
+    inside the index is still the true one: key and `kmin` + offset are
+    both representable, so equal modulo the width is equal."""
+    key, valid = probe_key
+    if key.dtype.itemsize < 4:
+        key = key.astype(jnp.int32)
+    off = key - kmin.astype(key.dtype)
+    size = drow.shape[0]
+    inside = (off >= 0) & (off < size)
+    row = jnp.take(drow, jnp.clip(off, 0, size - 1).astype(jnp.int32))
+    hit = (inside & (row >= 0) & valid
+           & _row_mask(key.shape[0], rows, selection))
+    return jnp.maximum(row, 0), hit
+
+
+@functools.partial(meter_jit, name="join.probe_gather",
+                   static_argnames=("tids",))
+def probe_gather(uh, urow, build_keys, build_cols, probe_keys, probe_cols,
+                 rows, selection, tids, direct=None):
+    """One probe batch of an inner join on a UNIQUE build key, whole.
+
+    build_cols / probe_cols: ((data, validity), ...) of the columns the
+    join puts out; probe_keys: ((data, validity), ...) of the batch's
+    keys, of the types `tids`; rows, selection: the batch's row count and
+    selection mask (or None), its `row_mask()`.
+
+    The build side's index comes in one of two forms, two traces of this
+    one program (`JoinMap.direct_key` says which a map has):
+
+      * searched (`direct` None).  uh: the build side's distinct hashes,
+        ascending, padded with the largest int64; urow: the build row of
+        each, -1 for padding and for a build row with a NULL key (NULL
+        joins nothing); build_keys: the build side's key data, one array
+        a key.  Hashes the probe keys, searches `uh` (as `probe_counts`
+        does), takes the candidate's build row and compares the REAL
+        keys there;
+      * direct (`direct` = (drow, kmin); uh, urow and build_keys None):
+        one dense integer key.  drow[k - kmin] is the build row of key
+        k, -1 where no build row has that key (and in the padding to a
+        power of two); the candidate is read at the key's own offset,
+        with no hash, no search and no comparison.
+
+    From the candidate on the two are one: gathers the build columns
+    there and packs the matched rows of both sides to the front.
+    Returns (probe columns, build columns, count), columns as
+    (data, validity) with validity false from `count` on."""
+    probe_keys = H.norm_float_keys(probe_keys, tids, jnp)
+    if direct is None:
+        row, hit = _search_rows(uh, urow, build_keys, probe_keys, tids,
+                                rows, selection)
+    else:
+        row, hit = _direct_rows(*direct, probe_keys[0], rows, selection)
     cols = list(probe_cols) + [(jnp.take(d, row), jnp.take(v, row))
                                for d, v in build_cols]
     packed = pack_front(hit, [a for dv in cols for a in dv])

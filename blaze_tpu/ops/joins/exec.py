@@ -17,7 +17,9 @@ mirroring how the reference shares probe code between SHJ and BHJ.
 Under device placement the common case, an inner join on a unique
 fixed-width build key, does all of that in one device program a probe
 batch (`kernels/join.probe_gather`): the rows of both sides never leave
-the chip, and the host reads back one count (`_probes_on_device`).
+the chip, and the host reads back one count (`_probes_on_device`).  A
+build side with one dense integer key, which a dimension's surrogate key
+is, is addressed by the key itself (`JoinMap.direct_key`).
 """
 
 from __future__ import annotations
@@ -185,6 +187,8 @@ class _Resident(NamedTuple):
     keys: Optional[Tuple[jax.Array, ...]] = None
     cols: Optional[Tuple[Tuple[jax.Array, jax.Array], ...]] = None
     dtypes: Optional[Tuple[DataType, ...]] = None
+    # of a map with a `direct_key` only: (drow, kmin)
+    direct: Optional[Tuple[jax.Array, jax.Array]] = None
 
 
 class _DeviceBuild(MemConsumer):
@@ -197,7 +201,11 @@ class _DeviceBuild(MemConsumer):
     (the largest int64, counts of 0: nothing matches padding, and the
     search takes as many rounds as over the entries alone) and the rows
     to their capacity bucket, so a new build size is rarely a new
-    program.  Charged to the chip of the task that places it.  Shed, it
+    program.  Where the map has a `direct_key`, also the direct-address
+    index `probe_gather` reads in place of the search: `drow`, int32,
+    the key range padded to a power of two, drow[k - kmin] the build row
+    of key k and -1 where there is none, and `kmin` in the key's type.
+    Charged to the chip of the task that places it.  Shed, it
     lets go (`held` None: a probe under way keeps what it was handed) and
     the next probe on that chip places the copy again."""
 
@@ -222,6 +230,13 @@ class _DeviceBuild(MemConsumer):
                 np.where(jmap._valid[row], row, -1).astype(np.int32), -1))
         nbytes = sum(a.nbytes for a in index)
         held = _Resident(*to_device(index))
+        if jmap.direct_key is not None:
+            kmin, span = jmap.direct_key
+            live = np.flatnonzero(jmap._valid)
+            drow = np.full(1 << (span - 1).bit_length(), -1, dtype=np.int32)
+            drow[jmap._int_keys()[live] - kmin] = live
+            nbytes += drow.nbytes
+            held = held._replace(direct=to_device((drow, kmin)))
         if jmap.unique_fixed:
             rows = ColumnBatch.from_arrow(jmap.table)
             keys = tuple(e.evaluate(rows).to_device(rows.capacity).data
@@ -249,7 +264,9 @@ class JoinMap:
       * device-resident (accelerator placement, an inner join on a
         unique fixed-width key: `BaseJoinExec._probes_on_device`):
         kernels/join.py `probe_gather`, one program a probe batch, the
-        rows never leave the chip;
+        rows never leave the chip; where the build side has ONE dense
+        integer key (`direct_key`) the program reads the build row at
+        `key - min`, with no hash and no search;
       * device (accelerator placement, every other join): kernels/join.py
         jit'd binary search + scan-based bounded pair expansion, one
         scalar sync per batch, pairs verified and rows taken on the host;
@@ -329,6 +346,43 @@ class JoinMap:
                 and all(e.data_type(self.schema).is_fixed_width
                         for e in self._key_exprs)
                 and (not len(self.ucount) or int(self.ucount.max()) == 1))
+
+    def _int_keys(self) -> np.ndarray:
+        """The one integer key's value a build row, as int64 (a date or
+        a timestamp as the integer it is stored as; 0 where the key is
+        NULL)."""
+        key = self.key_arrays[0]
+        if not pa.types.is_integer(key.type):
+            key = key.view(pa.int32() if key.type.bit_width == 32
+                           else pa.int64())
+        return key.fill_null(0).to_numpy(zero_copy_only=False) \
+            .astype(np.int64)
+
+    @functools.cached_property
+    def direct_key(self) -> Optional[Tuple[np.generic, int]]:
+        """(smallest valid build key, in the key's own type; entries of
+        the key range) where `probe_gather` can address the build side by
+        the key itself, else None.  A surrogate key is dense by
+        construction, and a unique dense integer key needs no hash table:
+        the build row of key k stands at k - min (Spark's
+        `LongToUnsafeRowMap` has the same dense mode).  So: the map is
+        `unique_fixed`, has ONE key, of an integer type, and its valid
+        keys' range is at most max(8 x build rows, 65,536) and 2^24
+        entries (4 B each on the chip).  Decided once a map from what the
+        build side itself shows; two keys, a float key, a sparse key set
+        and an empty or all-NULL build side keep the hash-sorted index."""
+        if len(self._key_exprs) != 1:
+            return None
+        dtype = self._key_exprs[0].data_type(self.schema)
+        if (not dtype.is_integer or not self.unique_fixed
+                or not self._valid.any()):
+            return None
+        keys = self._int_keys()[self._valid]
+        kmin = int(keys.min())
+        span = int(keys.max()) - kmin + 1   # Python integers: cannot wrap
+        if span > min(max(8 * self.num_rows, 1 << 16), 1 << 24):
+            return None
+        return dtype.np_dtype().type(kmin), span
 
     def on_device(self) -> _Resident:
         """The copy of the build side on the current task's chip, placed
@@ -517,14 +571,17 @@ class BaseJoinExec(ExecutionPlan):
         """Incremental vectorized probe: the build index is hashed once,
         batches stream through lookup (bounded memory)."""
         on_device = self._probes_on_device(jmap, probe_keys, probe_is_left)
+        direct = on_device and jmap.direct_key is not None
         chip = current_task().device_id
         for batch in batches:
             if batch.num_rows == 0:
                 continue
-            xla_stats.note_join_probe(chip, on_device, batch.num_rows)
+            xla_stats.note_join_probe(chip, on_device, batch.num_rows,
+                                      direct)
             if on_device:
                 with tracing.span("join_probe", rows=batch.num_rows,
-                                  lane="device"):
+                                  lane="device",
+                                  index="direct" if direct else "search"):
                     out = self._probe_batch_device(jmap, batch, probe_keys,
                                                    probe_is_left)
                 if out is not None:
@@ -561,19 +618,23 @@ class BaseJoinExec(ExecutionPlan):
                             probe_is_left: bool) -> Optional[ColumnBatch]:
         """One probe batch through `kernels/join.probe_gather`: in on the
         device (selection and all), out on the device, the matched rows of
-        both sides packed to the front; the host reads their count."""
+        both sides packed to the front; the host reads their count.  The
+        program reads whichever index the resident copy holds: the
+        direct-address one of a `direct_key`, else the hash-sorted one."""
         from blaze_tpu.kernels.join import probe_gather
         if jmap.num_rows == 0:
             return None
         build = jmap.on_device()
         keys = [e.evaluate(batch).to_device(batch.capacity)
                 for e in probe_keys]
+        index = ((None, None, None) if build.direct is not None
+                 else (build.uh, build.urow, build.keys))
         probe_cols, build_cols, count = probe_gather(
-            build.uh, build.urow, build.keys, build.cols,
+            *index, build.cols,
             tuple((k.data, k.validity) for k in keys),
             tuple((c.data, c.validity) for c in batch.columns),
             np.int32(batch.num_rows), batch.selection,
-            tids=jmap.key_tids)
+            tids=jmap.key_tids, direct=build.direct)
         n = int(to_host(count))
         if n == 0:
             return None

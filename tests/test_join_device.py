@@ -216,10 +216,71 @@ def _probe_cases():
     cases["tail_batch_of_another_capacity"] = (
         build, [(probe(4096), None), (probe(4096), None),
                 (probe(130), None)], 1)
+
+    # dense integer keys (`JoinMap.direct_key`) and their edges
+    def keyed(bk, pk):
+        return (pa.table({"bk": bk, "bv": _i64(range(len(bk)))}),
+                [(pa.table({"pk": pk, "pv": _i64(range(len(pk)))}), None)],
+                1)
+
+    cases["negative_keys"] = keyed(
+        _i64(rng.permutation(900)[:600] - 450),
+        _i64(rng.integers(-700, 700, 2500)))
+    top, low = np.iinfo(np.int64).max, np.iinfo(np.int64).min
+    cases["range_that_wraps_int64"] = keyed(
+        _i64([low, -3, 0, 7, top]),
+        _i64([top, low, low + 1, top - 1, 7, -3, 1, 0, None]))
+    cases["keys_at_the_top_of_int64"] = keyed(
+        _i64([top - 5, top, top - 2]),
+        _i64([top, top - 1, top - 2, top - 5, top - 6, low, 0, -1]))
+    cases["int32_key"] = keyed(
+        pa.array(rng.permutation(3000)[:800].astype(np.int32) - 100),
+        pa.array(rng.integers(-500, 3500, 2500).astype(np.int32)))
+    cases["int8_key_over_its_whole_range"] = keyed(
+        pa.array(np.arange(-128, 128, 3, dtype=np.int8)),
+        pa.array(rng.integers(-128, 128, 1500).astype(np.int8)))
+    cases["date32_key"] = keyed(
+        pa.array(rng.permutation(366)[:300].astype(np.int32) + 10957,
+                 type=pa.date32()),
+        pa.array(rng.integers(10000, 12000, 2500).astype(np.int32),
+                 type=pa.date32()))
+    cases["timestamp_key"] = keyed(
+        pa.array(1_700_000_000_000_000 + rng.permutation(5000)[:900],
+                 type=pa.timestamp("us")),
+        pa.array(1_700_000_000_000_000 + rng.integers(-100, 5100, 2500),
+                 type=pa.timestamp("us")))
+    cases["probe_keys_below_min_and_above_max"] = keyed(
+        _i64(np.arange(1000, 1400)),
+        _i64(np.concatenate([np.arange(0, 2400, 3), [999, 1000, 1399,
+                                                      1400]])))
+    cases["null_build_key_inside_a_dense_range"] = keyed(
+        _i64([10, None, 12, 14, 11]),
+        _i64([None, 13, 12, 10, 14, 9, 15, 11, None]))
+    cases["build_side_of_one_row"] = keyed(
+        _i64([28]), _i64(rng.integers(20, 36, 700)))
+    # the range rule: at most max(8 x build rows, 65,536) entries
+    ten_k = rng.permutation(80_000)[:10_000]
+    ten_k[:2] = 0, 79_999          # 8 x 10,000 entries: just inside
+    cases["sparse_keys_just_inside_the_rule"] = keyed(
+        _i64(ten_k), _i64(rng.integers(-10, 80_010, 4000)))
+    ten_k = ten_k.copy()
+    ten_k[1] = 80_000              # one entry more: searched
+    cases["sparse_keys_just_past_the_rule"] = keyed(
+        _i64(ten_k), _i64(rng.integers(-10, 80_010, 4000)))
+    cases["few_keys_past_the_floor_of_the_rule"] = keyed(
+        _i64([0, 65_536, 9]), _i64([65_536, 9, 0, 1, 65_535, -1]))
+    cases["few_keys_inside_the_floor_of_the_rule"] = keyed(
+        _i64([0, 65_535, 9]), _i64([65_535, 9, 0, 1, 65_534, -1]))
     return cases
 
 
 _PROBE_CASES = _probe_cases()
+# the cases whose build side keeps the hash-sorted index: every other
+# one has one dense integer key and is addressed by it
+_SEARCHED = {"two_column_key", "float64_key_nan_and_negative_zero",
+             "empty_build_side", "range_that_wraps_int64",
+             "sparse_keys_just_past_the_rule",
+             "few_keys_past_the_floor_of_the_rule"}
 
 
 def _broadcast_join(build_t, probe_batches, nkeys, how=None, flt=None,
@@ -274,9 +335,21 @@ def test_device_probe_gives_the_host_paths_answer(case, monkeypatch):
     assert got.equals(want), (got, want)
     assert len(want) > 0 or case == "empty_build_side"
     # every probe row went through the device-resident probe
-    assert moved["join_probe_device_rows"] == sum(
-        t.num_rows for t, _sel in probe_batches)
+    n = sum(t.num_rows for t, _sel in probe_batches)
+    assert moved["join_probe_device_rows"] == n
     assert moved["join_probe_host_rows"] == 0
+    # ... addressed by its key where the build side has ONE dense integer
+    # key, and then the searched index gives the same rows
+    if case in _SEARCHED:
+        assert moved["join_probe_direct_rows"] == 0
+        return
+    assert moved["join_probe_direct_rows"] == n
+    from blaze_tpu.ops.joins.exec import JoinMap
+    monkeypatch.setattr(JoinMap, "direct_key", None)
+    searched, moved = _answer(_broadcast_join(build_t, probe_batches, nkeys))
+    assert searched.equals(got), (searched, got)
+    assert moved["join_probe_device_rows"] == n
+    assert moved["join_probe_direct_rows"] == 0
 
 
 def test_device_probe_reads_back_one_scalar_a_batch(on_device):
@@ -364,6 +437,189 @@ def test_probe_rows_add_up_over_both_paths(on_device):
     assert inner["join_probe_host_rows"] == left["join_probe_device_rows"] == 0
     assert inner["chip0_join_probe_device_rows"] == n
     assert left["chip0_join_probe_host_rows"] == n
+
+
+def test_direct_key_is_decided_from_the_build_side_alone():
+    """(smallest key in the key's type, entries of the range) or None,
+    from the map's own keys: no knob, no plan attribute."""
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops.joins.exec import JoinMap
+    from blaze_tpu.schema import Schema
+
+    def direct_key(case):
+        build_t, _probe, nkeys = _PROBE_CASES[case]
+        return JoinMap(build_t, [col(i) for i in range(nkeys)],
+                       Schema.from_arrow(build_t.schema)).direct_key
+
+    for case in _SEARCHED:
+        assert direct_key(case) is None, case
+    kmin, span = direct_key("negative_keys")
+    assert kmin.dtype == np.int64 and kmin < 0 and span <= 900
+    assert direct_key("keys_at_the_top_of_int64") == (
+        np.iinfo(np.int64).max - 5, 6)
+    kmin, span = direct_key("int8_key_over_its_whole_range")
+    assert (kmin, span, kmin.dtype) == (-128, 256, np.int8)
+    kmin, span = direct_key("date32_key")
+    assert kmin.dtype == np.int32 and 10957 <= kmin and span <= 366
+    assert direct_key("null_build_key_inside_a_dense_range") == (10, 5)
+    assert direct_key("build_side_of_one_row") == (28, 1)
+    assert direct_key("sparse_keys_just_inside_the_rule") == (0, 80_000)
+    assert direct_key("few_keys_inside_the_floor_of_the_rule") == (0, 65_536)
+    # duplicate keys and a utf8 column: not `unique_fixed`, so not direct
+    for case in ("duplicate_build_keys", "utf8_build_column"):
+        build_t = _NOT_TAKEN[case][0]
+        jmap = JoinMap(build_t, [col(0)], Schema.from_arrow(build_t.schema))
+        assert not jmap.unique_fixed and jmap.direct_key is None
+
+
+def test_the_range_rule_caps_the_index_at_2_to_the_24():
+    """8 x build rows past 2^24 entries: the rule's ceiling holds."""
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops.joins.exec import JoinMap
+    from blaze_tpu.schema import Schema
+    rows = (1 << 21) + 8
+    for last, want in (((1 << 24) - 1, (0, 1 << 24)), (1 << 24, None)):
+        keys = np.arange(rows, dtype=np.int64)
+        keys[-1] = last
+        build_t = pa.table({"bk": keys})
+        jmap = JoinMap(build_t, [col(0)], Schema.from_arrow(build_t.schema))
+        assert jmap.direct_key == want
+
+
+def test_the_span_says_which_index_a_batch_read(on_device):
+    from blaze_tpu.bridge import tracing
+    tracing.start_tracing()
+    try:
+        for case in ("int64_key", "two_column_key"):
+            _answer(_broadcast_join(*_PROBE_CASES[case]))
+        _answer(_broadcast_join(*_NOT_TAKEN["duplicate_build_keys"][:3]))
+        probes = [s["attrs"] for s in tracing.spans()
+                  if s["name"] == "join_probe"]
+    finally:
+        tracing.stop_tracing()
+        tracing.reset_conf_probe()
+    assert [(a["lane"], a.get("index")) for a in probes] == [
+        ("device", "direct"), ("device", "search"), ("host", None)]
+
+
+def test_direct_rows_are_counted_by_chip_and_reset(on_device):
+    from blaze_tpu.bridge import xla_stats
+    build_t, probe_batches, nkeys = _PROBE_CASES["negative_keys"]
+    _got, moved = _answer(_broadcast_join(build_t, probe_batches, nkeys))
+    assert moved["join_probe_direct_rows"] == 2500 \
+        == moved["chip0_join_probe_direct_rows"]
+    assert xla_stats.chip_stats()[0]["join_probe_direct_rows"] >= 2500
+    assert xla_stats.counter_families()["join"][
+        "join_probe_direct_rows"] >= 2500
+    xla_stats.reset()
+    assert xla_stats.snapshot()["join_probe_direct_rows"] == 0
+    assert xla_stats.join_stats() == {
+        "join_probe_device_rows": 0, "join_probe_host_rows": 0,
+        "join_probe_direct_rows": 0}
+    assert xla_stats.chip_stats() == {}
+
+
+def _probe_gather_compiles():
+    from blaze_tpu.bridge import xla_stats
+    return xla_stats.compile_report()["kernels"].get(
+        "join.probe_gather", {"compiles": 0})["compiles"]
+
+
+def test_build_sides_in_one_bucket_share_one_program(on_device):
+    """The direct index is padded to a power of two, as the searched one
+    is: another build size, key range and smallest key inside the same
+    buckets is the same program."""
+    rng = np.random.default_rng(5)
+    probe_t = pa.table({"pk": _i64(rng.integers(0, 4000, 3000)),
+                        "pv": _i64(range(3000))})
+
+    def join(rows, first, span):
+        keys = first + rng.permutation(span)[:rows]
+        build_t = pa.table({"bk": _i64(keys), "bv": _i64(range(rows))})
+        got, moved = _answer(_broadcast_join(build_t, [(probe_t, None)], 1))
+        assert moved["join_probe_direct_rows"] == 3000
+        assert len(got) == np.isin(probe_t["pk"].to_numpy(), keys).sum()
+
+    join(600, 100, 900)
+    before = _probe_gather_compiles()
+    join(700, 2000, 1000)
+    join(513, -5, 1024)
+    assert _probe_gather_compiles() == before
+    join(700, 0, 1025)     # the next bucket of the index: a new program
+    assert _probe_gather_compiles() == before + 1
+
+
+def _parents_probe_gather():
+    """`probe_gather` as it stood before the direct form (commit fefaad9),
+    word for word, under the program's name."""
+    from blaze_tpu.bridge.xla_stats import meter_jit
+    from blaze_tpu.kernels import hashing as H
+    from blaze_tpu.kernels.join import _keys_equal, hash_valid, pack_front
+
+    def probe_gather(uh, urow, build_keys, build_cols, probe_keys,
+                     probe_cols, rows, selection, tids):
+        probe_keys = H.norm_float_keys(probe_keys, tids, jnp)
+        h, any_null = hash_valid(probe_keys, tids)
+        mask = jnp.arange(h.shape[0], dtype=jnp.int32) < rows
+        if selection is not None:
+            mask = mask & selection
+        pos = jnp.searchsorted(uh, h)
+        pos = jnp.clip(pos, 0, uh.shape[0] - 1)
+        row = jnp.take(urow, pos)
+        hit = (jnp.take(uh, pos) == h) & (row >= 0) & ~any_null & mask
+        row = jnp.maximum(row, 0)
+        for (pk, _pv), bk, tid in zip(probe_keys, build_keys, tids):
+            (bk, _), = H.norm_float_keys([(jnp.take(bk, row), None)],
+                                         (tid,), jnp)
+            hit = hit & _keys_equal(pk, bk, tid)
+        cols = list(probe_cols) + [(jnp.take(d, row), jnp.take(v, row))
+                                   for d, v in build_cols]
+        packed = pack_front(hit, [a for dv in cols for a in dv])
+        count = jnp.sum(hit, dtype=jnp.int32)
+        inside = jnp.arange(hit.shape[0], dtype=jnp.int32) < count
+        out = [(packed[2 * i], packed[2 * i + 1] & inside)
+               for i in range(len(cols))]
+        n_probe = len(probe_cols)
+        return tuple(out[:n_probe]), tuple(out[n_probe:]), count
+
+    return meter_jit(probe_gather, name="join.probe_gather",
+                     static_argnames=("tids",))
+
+
+@pytest.mark.parametrize("tids,selected", [
+    (("int64",), False), (("int64",), True),
+    (("float64", "int32"), False), (("date32",), True)])
+def test_the_searched_form_is_the_parents_program(tids, selected):
+    """A join the direct form does not take (two keys, a float key,
+    duplicate or sparse keys) runs what it ran: the searched form lowers
+    to the parent's module, text for text.  The direct form is another
+    trace of the same program, with no loop and no hash in it."""
+    from blaze_tpu.kernels.join import probe_gather
+    from blaze_tpu.schema import DataType, TypeId
+    cap, rows = 4096, 1024
+    ok, bok = jnp.ones(cap, bool), jnp.ones(rows, bool)
+    dts = [DataType(TypeId(t)).jnp_dtype() for t in tids]
+    args = (jnp.zeros(1023, jnp.int64), jnp.zeros(1023, jnp.int32),
+            tuple(jnp.zeros(rows, d) for d in dts),
+            ((jnp.zeros(rows, jnp.float64), bok),),
+            tuple((jnp.zeros(cap, d), ok) for d in dts),
+            ((jnp.zeros(cap, jnp.int64), ok),), jnp.int32(8),
+            ok if selected else None)
+    mine = probe_gather._blaze_jitted.lower(*args, tids=tids).as_text()
+    parents = _parents_probe_gather()._blaze_jitted.lower(
+        *args, tids=tids).as_text()
+    assert mine == parents
+    assert "module @jit_probe_gather__join_probe_gather" in mine
+    assert "stablehlo.while" in mine
+    if len(tids) > 1:
+        return
+    direct = probe_gather._blaze_jitted.lower(
+        None, None, None, *args[3:], tids=tids,
+        direct=(jnp.full(2048, -1, jnp.int32),
+                jnp.zeros((), dts[0]))).as_text()
+    assert "module @jit_probe_gather__join_probe_gather" in direct
+    assert "stablehlo.while" not in direct
+    assert "stablehlo.multiply" not in direct     # no hash
 
 
 def test_pack_front_is_a_stable_compaction():

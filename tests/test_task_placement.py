@@ -441,7 +441,7 @@ def test_a_broadcast_build_side_is_placed_once_a_chip(on_devices):
     charged = set()
     for d in devices:
         held = copies[d.id].held
-        for a in (held.uh, held.urow, *held.keys,
+        for a in (held.uh, held.urow, *held.keys, *held.direct,
                   *(x for dv in held.cols for x in dv)):
             assert a.devices() == {d}
         assert copies[d.id].chip == d.id
@@ -454,7 +454,53 @@ def test_a_broadcast_build_side_is_placed_once_a_chip(on_devices):
         assert mgr.chip_used(devices[1].id) == 0
         again = plan._get_join_map(0).on_device()   # placed anew, there
         assert again.uh.devices() == {devices[1]}
+        assert again.direct[0].devices() == {devices[1]}
     assert mgr.chip_used(devices[1].id) == mgr.chip_used(devices[0].id)
+
+
+def test_the_direct_index_is_charged_to_its_chip_and_placed_again(
+        on_devices, monkeypatch):
+    """`drow` (4 B an entry of the key range, padded to a power of two)
+    is part of what the build side's copy holds against its chip's
+    budget; shed, the next probe there places it again."""
+    from blaze_tpu.exprs import col
+    from blaze_tpu.ops.joins.exec import JoinMap
+    from blaze_tpu.schema import Schema
+    devices = on_devices(4)
+    rng = np.random.default_rng(12)
+    build = pa.table({"bk": pa.array(5000 + rng.permutation(3000)[:700]),
+                      "bv": pa.array(rng.random(700))})
+    mgr = MemManager.get()
+
+    def placed(chip):
+        jmap = JoinMap(build, [col(0)], Schema.from_arrow(build.schema))
+        with task_scope(TaskContext(partition_id=chip,
+                                    device=devices[chip])):
+            held = jmap.on_device()
+        return jmap, held, mgr.chip_used(devices[chip].id)
+
+    jmap, held, with_drow = placed(2)
+    drow, kmin = held.direct
+    assert drow.dtype == np.int32 and drow.shape == (4096,)
+    assert int(kmin) == build["bk"].to_numpy().min() and kmin.shape == ()
+    rows = np.asarray(drow)
+    keys = build["bk"].to_numpy()
+    assert np.array_equal(np.flatnonzero(rows >= 0), np.sort(keys) - kmin)
+    assert np.array_equal(keys[rows[rows >= 0]], np.sort(keys))
+    copy = jmap._on_device[devices[2].id]
+    assert copy.chip == devices[2].id and copy.mem_used == with_drow
+    with monkeypatch.context() as m:
+        m.setattr(JoinMap, "direct_key", None)
+        _jmap, searched, without = placed(3)
+    assert searched.direct is None
+    assert with_drow - without == 4 * 4096
+    with task_scope(TaskContext(partition_id=2, device=devices[2])):
+        assert copy.spill() == with_drow
+        assert mgr.chip_used(devices[2].id) == 0 and copy.held is None
+        again = jmap.on_device()
+    assert again.direct[0].devices() == {devices[2]}
+    assert np.array_equal(np.asarray(again.direct[0]), rows)
+    assert mgr.chip_used(devices[2].id) == with_drow
 
 
 # -- one budget a chip ----------------------------------------------------------
