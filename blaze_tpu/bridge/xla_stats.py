@@ -143,12 +143,16 @@ _agg = {"partial_agg_skip_events": 0, "partial_agg_skipped_rows": 0,
         "partial_agg_switch_rows": 0, "partial_agg_spill_switches": 0}
 
 # Sort and sort-merge join on the device (ops/sort.py, ops/joins/exec.py):
-# rows whose sort permutation came from the device, rows of both sides and
-# pairs written by the device merge join, and equal-key runs the Python
-# run cursor walked instead (ops/joins/smj.py: a partition the memory manager
-# shed, or a join shape the device path states it does not take).
-_sortmerge = {"sort_device_rows": 0, "smj_device_rows": 0,
-              "smj_device_pairs": 0, "smj_streamed_runs": 0}
+# rows whose sort permutation came from the device and, of those, the rows
+# of partitions that stayed on the chip while they were sorted (staged as
+# device batches, their columns gathered by the permutation there: by chip
+# in `chip_stats()` too), rows of both sides and pairs written by the
+# device merge join, and equal-key runs the Python run cursor walked
+# instead (ops/joins/smj.py: a partition the memory manager shed, or a join
+# shape the device path states it does not take).
+_sortmerge = {"sort_device_rows": 0, "sort_resident_rows": 0,
+              "smj_device_rows": 0, "smj_device_pairs": 0,
+              "smj_streamed_runs": 0}
 
 # The hash joins' probe side (ops/joins/exec.py): probe rows handed to a
 # join whose batches stay on the chip (`kernels/join.probe_gather`: inner,
@@ -457,6 +461,7 @@ def _chip_entry(chip: int) -> Dict[str, int]:
                                 "stage_loop_windows_fused": 0,
                                 "stage_loop_lanes": 0,
                                 "stage_loop_decimal_rows": 0,
+                                "sort_resident_rows": 0,
                                 **{k: 0 for k in _CHIP_TABLE_KEYS}}
     return entry
 
@@ -525,8 +530,9 @@ def chip_stats() -> Dict[int, Dict[str, int]]:
     "join_probe_device_rows", "join_probe_host_rows",
     "join_probe_direct_rows",
     "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes",
-    "stage_loop_decimal_rows" and the stage loop's table counters (_CHIP_TABLE_KEYS)} since the
-    last reset: what each chip was given to do."""
+    "stage_loop_decimal_rows", "sort_resident_rows" and the stage loop's
+    table counters (_CHIP_TABLE_KEYS)} since the last reset: what each chip
+    was given to do."""
     with _lock:
         return {chip: dict(e) for chip, e in sorted(_chips.items())}
 
@@ -1089,6 +1095,14 @@ def note_sortmerge(**deltas: int) -> None:
     with _lock:
         for k, v in deltas.items():
             _sortmerge[k] += int(v)
+
+
+def note_sort_resident(rows: int, chip: int) -> None:
+    """A partition of `rows` rows sorted on `chip` without leaving it."""
+    with _lock:
+        _sortmerge["sort_device_rows"] += int(rows)
+        _sortmerge["sort_resident_rows"] += int(rows)
+        _chip_entry(chip)["sort_resident_rows"] += int(rows)
 
 
 def sortmerge_stats() -> dict:

@@ -78,6 +78,139 @@ def lsd_pass(digit: jax.Array, perm: jax.Array) -> jax.Array:
 sort_pass = meter_jit(lsd_pass, name="sort.pass")
 
 
+# -- a partition sorted where it lies (ops/sort.py, the resident lane) ------
+# Tiles are ((data, validity), ...) a staged batch, one pair a column, every
+# tile of a call at one width; `rows` says how many lanes of each are rows.
+
+PAD_DIGIT = 0xFFFFFFFF  # what a padding lane carries in every digit
+
+
+def _widen_tile(tile, width: int):
+    """A tile of a narrower bucket at the partition's width."""
+    return jax.tree_util.tree_map(
+        lambda a: jnp.pad(a, (0, width - a.shape[0])), tile)
+
+
+widen_tile = meter_jit(_widen_tile, name="sort.widen",
+                       static_argnames=("width",))
+
+
+def _assemble_tiles(tiles, rows, cap: int):
+    """The tiles' rows end to end in columns of `cap` lanes, in arrival
+    order, with their count: ((data, validity), ...), total.  Copies alone,
+    whatever the tiles hold: tile k is written whole where tile k-1's rows
+    end, over what that tile carried behind its rows, so full tiles (an
+    exchange reader's) and ragged ones (a filter's) cost the same.  A
+    padding lane reads 0 and is not valid."""
+    width = tiles[0][0][0].shape[0]
+    starts = jnp.cumsum(rows) - rows
+    total = starts[-1] + rows[-1]
+
+    def laid(*parts):
+        # room for the last tile's own padding behind the last row
+        buf = jnp.zeros((cap + width,), parts[0].dtype)
+        for at, part in zip(starts, parts):
+            buf = jax.lax.dynamic_update_slice(buf, part, (at,))
+        return buf[:cap]
+
+    cols = jax.tree_util.tree_map(laid, *tiles)
+    live = jnp.arange(cap, dtype=jnp.int32) < total
+    return tuple((jnp.where(live, d, jnp.zeros_like(d)), v & live)
+                 for d, v in cols), total
+
+
+assemble_tiles = meter_jit(_assemble_tiles, name="sort.assemble",
+                           static_argnames=("cap",))
+
+
+def _f32_digit(x):
+    """A float32 as the uint32 whose `<` is the float's (-0.0 as 0.0,
+    whatever the compiler made of `order_key`'s `+ 0.0`)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(x < 0, ~bits, bits | jnp.uint32(0x80000000))
+
+
+def _halves(key):
+    return [(key >> jnp.uint64(32)).astype(jnp.uint32),
+            key.astype(jnp.uint32)]
+
+
+def _value_digits(key, float_pair: bool):
+    """An order key's value (kernels/compare.order_key: no NaN, no -0.0) as
+    uint32 digits, most significant first.  A float64 is cut through its
+    bits where the backend has them; where it is a pair of float32
+    (`float_pair`: the TPU, which has no 64-bit bitcast) into the pair's
+    two halves, which order as their sum does."""
+    if key.dtype == jnp.uint64:
+        return _halves(key)
+    if key.dtype == jnp.float32:
+        return [_f32_digit(key)]
+    if key.dtype != jnp.float64:
+        return [key.astype(jnp.uint32)]
+    if not float_pair:
+        bits = jax.lax.bitcast_convert_type(key, jnp.uint64)
+        return _halves(jnp.where(key < 0, ~bits,
+                                 bits | jnp.uint64(0x8000000000000000)))
+    hi = key.astype(jnp.float32)
+    lo = jnp.where(jnp.isinf(hi), jnp.float32(0),
+                   (key - hi.astype(jnp.float64)).astype(jnp.float32))
+    return [_f32_digit(hi), _f32_digit(lo)]
+
+
+def _key_digits(keys, total, dtypes, descending, nulls_first,
+                float_pair: bool):
+    """The sort keys ((data, validity) a key, `total` rows in front) as
+    32-bit digits, most significant first: (digits, varies, lanes).  Their
+    joint lexicographic order is the SQL order, as `ops/sort.py`
+    `host_sort_keys` orders: a key's bucket (NULLs first or last, NaN
+    beyond the values), then its value.  Padding lanes carry `PAD_DIGIT`;
+    `varies[i]` says if any two rows differ in digit i (one that does not
+    cannot move a row); `lanes` is the identity permutation."""
+    cap = keys[0][0].shape[0]
+    lanes = jnp.arange(cap, dtype=jnp.int32)
+    live = lanes < total
+    digits = []
+    for (data, validity), dtype, desc, first in zip(keys, dtypes, descending,
+                                                    nulls_first):
+        bucket, value = compare.order_key(data, validity, dtype, desc, first)
+        digits.append(bucket.astype(jnp.uint32))
+        digits.extend(_value_digits(value, float_pair))
+    padded = tuple(jnp.where(live, d, jnp.uint32(PAD_DIGIT)) for d in digits)
+    varies = jnp.stack([
+        jnp.min(p) != jnp.max(jnp.where(live, d, jnp.uint32(0)))
+        for p, d in zip(padded, digits)])
+    return padded, varies, lanes
+
+
+key_digits = meter_jit(
+    _key_digits, name="sort.digits",
+    static_argnames=("dtypes", "descending", "nulls_first", "float_pair"))
+
+_WORD = 32  # validity bits gathered as one word
+
+
+def _gather_sorted(cols, perm, rows, out_cap: int):
+    """The first `rows` rows in the order `perm` gives, in `out_cap` lanes:
+    every column's data by one gather, the validity of up to 32 columns by
+    one gather of their bits."""
+    idx = perm[:out_cap]
+    keep = jnp.arange(out_cap, dtype=jnp.int32) < rows
+    valid = []
+    for at in range(0, len(cols), _WORD):
+        group = cols[at:at + _WORD]
+        word = sum(v.astype(jnp.uint32) << i
+                   for i, (_d, v) in enumerate(group))
+        word = jnp.take(word, idx)
+        valid.extend(((word >> i) & 1).astype(bool) & keep
+                     for i in range(len(group)))
+    return tuple((jnp.where(keep, jnp.take(d, idx), jnp.zeros((), d.dtype)),
+                  v) for (d, _v), v in zip(cols, valid))
+
+
+gather_sorted = meter_jit(_gather_sorted, name="sort.gather",
+                          static_argnames=("out_cap",))
+
+
 def group_ids_from_sorted(keys: Sequence[jax.Array], valid_mask: jax.Array
                           ) -> Tuple[jax.Array, jax.Array]:
     """Dense group ids for rows already sorted by `keys`.

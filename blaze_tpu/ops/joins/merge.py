@@ -200,6 +200,10 @@ matched_rows = meter_jit(_matched_rows, name="smj.matched_rows",
 # ---------------------------------------------------------------------------
 
 def _concat(schema, batches: Sequence[ColumnBatch]) -> ColumnBatch:
+    if len(batches) == 1 and batches[0].selection is None:
+        # a side is a `SortExec`'s output: one batch where the sort kept
+        # its partition on the device, already at its bucket
+        return batches[0]
     if batches:
         return ColumnBatch.concat(batches)
     return ColumnBatch.from_arrow(

@@ -8,6 +8,10 @@ TPU-first redesign:
   * in-memory runs sort ON DEVICE via the order-key encoding +
     `lax.sort` (kernels/compare.py) — XLA's fused lexicographic sort is the
     radix-sort replacement;
+  * a partition of fixed-width device columns STAYS on the device while it
+    is sorted (`_SortState.sorted_on_device`): staged as the batches that
+    arrive, laid end to end, ordered by the same passes and gathered by the
+    permutation there; only a few booleans are read back;
   * runs that exceed the memory budget spill as sorted Arrow runs through
     the shared Spill tiers;
   * the k-way merge is BATCH-vectorized on host (numpy lexsort over u64
@@ -36,6 +40,21 @@ from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
 from blaze_tpu.schema import Schema, TypeId
 
 SortSpec = Tuple[PhysicalExpr, bool, bool]  # (expr, descending, nulls_first)
+
+# under this many rows a sort is not worth the device's round trips
+_DEVICE_SORT_ROWS = 1024
+# the most batches a partition is staged as on the device: ONE program lays
+# them end to end, its operands tiles x columns (kernels/sort.py
+# `assemble_tiles`), and its run is ONE batch for whatever consumes the sort.
+# A partition of more goes through the host lane, which yields batches of
+# BATCH_SIZE (2M rows of an exchange reader's 32,768-lane tiles)
+_RESIDENT_TILES = 64
+# the key types `kernels/compare.order_key` orders on the device as
+# `_host_order_key` orders them here
+_DEVICE_KEY_TYPES = frozenset({
+    TypeId.BOOL, TypeId.INT8, TypeId.INT16, TypeId.INT32, TypeId.INT64,
+    TypeId.FLOAT32, TypeId.FLOAT64, TypeId.DATE32, TypeId.TIMESTAMP_MICROS,
+    TypeId.DECIMAL})
 
 
 # ---------------------------------------------------------------------------
@@ -196,6 +215,10 @@ class SortExec(ExecutionPlan, MemConsumer):
         try:
             for batch in self.children[0].execute(partition):
                 state.insert(batch)
+            run = state.sorted_on_device(self._fetch)
+            if run is not None:
+                yield run
+                return
             out_rows = 0
             for rb in state.merged_output():
                 if self._fetch is not None:
@@ -225,6 +248,12 @@ class _SortState(MemConsumer):
         self._specs = specs
         self._staged: List[pa.RecordBatch] = []
         self._staged_bytes = 0
+        # the resident lane: while every batch that arrives is one the
+        # device can sort where it lies, the partition is staged as
+        # (compacted batch, its evaluated keys) and nothing is read back
+        self._resident = True
+        self._tiles: List[Tuple[ColumnBatch, list]] = []
+        self._tile_bytes = 0
         self._spills: List[Spill] = []
         # sort keys are evaluated through exprs on the ColumnBatch, then
         # carried as extra leading columns in the staged arrow batches so
@@ -233,6 +262,16 @@ class _SortState(MemConsumer):
 
     # -- ingest -------------------------------------------------------------
     def insert(self, batch: ColumnBatch) -> None:
+        if self._resident:
+            tile = self._device_tile(batch)
+            if tile is not None and not tile[0].num_rows:
+                return
+            if tile is not None and len(self._tiles) < _RESIDENT_TILES:
+                self._tiles.append(tile)
+                self._tile_bytes += _tile_nbytes(tile)
+                self.update_mem_used(self._tile_bytes)
+                return
+            self._leave_device()
         rb = self._with_key_columns(batch)
         if rb.num_rows == 0:
             return
@@ -259,8 +298,109 @@ class _SortState(MemConsumer):
             names.append(name)
         return pa.RecordBatch.from_arrays(arrays, names=names)
 
+    # -- the resident lane ---------------------------------------------------
+    def _device_tile(self, batch: ColumnBatch):
+        """`batch` compacted, beside its evaluated keys, if the device can
+        sort it where it lies: compute is placed there, every column is a
+        plain fixed-width device column, every key a device value of a type
+        `order_key` takes.  None for anything else (a utf8, dictionary or
+        host column, a key the host alone orders)."""
+        import jax
+        from blaze_tpu.batch import DeviceColumn
+        from blaze_tpu.bridge.placement import host_resident
+        if host_resident() or not batch.columns or not all(
+                type(c) is DeviceColumn and isinstance(c.data, jax.Array)
+                and c.data.ndim == 1 for c in batch.columns):
+            return None
+        batch = batch.compact()
+        keys = []
+        for expr, _, _ in self._specs:
+            v = expr.evaluate(batch)
+            if not (v.is_device and v.dictionary is None
+                    and isinstance(v.data, jax.Array)
+                    and v.dtype.id in _DEVICE_KEY_TYPES
+                    and v.data.shape == (batch.capacity,)):
+                return None
+            keys.append(v)
+        return batch, keys
+
+    def _leave_device(self) -> None:
+        """The partition goes on through the host lane: what is staged on
+        the device is read back into its staging, arrival order kept."""
+        self._resident = False
+        tiles, self._tiles, self._tile_bytes = self._tiles, [], 0
+        for batch, _keys in tiles:
+            rb = self._with_key_columns(batch)
+            self._staged.append(rb)
+            self._staged_bytes += rb.nbytes
+
+    def _laid_tiles(self, cap: int):
+        """What is staged, handed over to ONE `assemble_tiles` program:
+        (every column and then every key at `cap` lanes, the rows' count).
+        The staging is empty afterwards and the tiles are the program's."""
+        from blaze_tpu.kernels import sort as ksort
+        tiles, self._tiles, self._tile_bytes = self._tiles, [], 0
+        width = max(b.capacity for b, _ in tiles)
+        parts = []
+        for b, keys in tiles:
+            part = tuple((c.data, c.validity) for c in b.columns) \
+                + tuple((v.data, v.validity) for v in keys)
+            parts.append(part if b.capacity == width
+                         else ksort.widen_tile(part, width=width))
+        # one program a power of two of tiles: the spare places take the
+        # first tile again, with no row
+        spare = (1 << (len(parts) - 1).bit_length()) - len(parts)
+        counts = np.array([b.num_rows for b, _ in tiles] + [0] * spare,
+                          dtype=np.int32)
+        return ksort.assemble_tiles(tuple(parts) + (parts[0],) * spare,
+                                    counts, cap=cap)
+
+    def sorted_on_device(self, fetch: Optional[int]) -> Optional[ColumnBatch]:
+        """The whole partition as ONE sorted device batch (its first `fetch`
+        rows), if it stayed resident and is worth the device; None where
+        `merged_output` has it.  Same order as the host lane's: the order
+        keys' digits (`kernels/sort.key_digits`), those that differ between
+        rows sorted least significant first by `sort_pass`, every pass
+        stable.  Nothing is held against the memory manager on return."""
+        rows = sum(b.num_rows for b, _ in self._tiles)
+        if not self._resident or rows < _DEVICE_SORT_ROWS:
+            self._leave_device()
+            return None
+        import jax
+        from blaze_tpu.batch import DeviceColumn
+        from blaze_tpu.bridge import tracing, xla_stats
+        from blaze_tpu.bridge.context import current_task
+        from blaze_tpu.kernels import sort as ksort
+        from blaze_tpu.xputil import to_host
+        ncols = len(self._schema)
+        key_types = tuple(v.dtype for v in self._tiles[0][1])
+        out_rows = rows if fetch is None else min(fetch, rows)
+        with tracing.span("sort_device", rows=rows, lane="resident") as attrs:
+            cols, total = self._laid_tiles(bucket_capacity(rows))
+            digits, varies, perm = ksort.key_digits(
+                cols[ncols:], total, dtypes=key_types,
+                descending=tuple(d for _, d, _ in self._specs),
+                nulls_first=tuple(f for _, _, f in self._specs),
+                float_pair=jax.default_backend() == "tpu")
+            self.update_mem_used(sum(
+                a.nbytes for a in jax.tree_util.tree_leaves((cols, digits))))
+            moving = [d for d, moves in zip(digits, to_host(varies)) if moves]
+            for d in reversed(moving):
+                perm = ksort.sort_pass(d, perm)
+            out = ksort.gather_sorted(cols[:ncols], perm, np.int32(out_rows),
+                                      out_cap=bucket_capacity(out_rows))
+            attrs["passes"] = len(moving)
+        xla_stats.note_sort_resident(rows, current_task().device_id)
+        self.update_mem_used(0)
+        return ColumnBatch(
+            self._schema,
+            [DeviceColumn(f.data_type, d, v)
+             for f, (d, v) in zip(self._schema, out)], out_rows, None)
+
     # -- spilling (MemConsumer) --------------------------------------------
     def spill(self) -> int:
+        if self._tiles:
+            self._leave_device()
         if not self._staged:
             return 0
         run = self._sort_staged()
@@ -296,7 +436,7 @@ class _SortState(MemConsumer):
         nf = [f for _, _, f in self._specs]
         keys = host_sort_keys(rb, key_cols, desc, nf)
         from blaze_tpu.bridge.placement import host_resident
-        if host_resident() or rb.num_rows < 1024 \
+        if host_resident() or rb.num_rows < _DEVICE_SORT_ROWS \
                 or any(k.dtype == object for k in keys):
             return lexsort_host(keys)
         return _device_permutation(keys, rb.num_rows)
@@ -328,6 +468,14 @@ class _SortState(MemConsumer):
         for rb in merge_sorted_batches(runs, list(range(self._num_keys)),
                                        desc, nf):
             yield self._strip_keys(rb)
+
+
+def _tile_nbytes(tile) -> int:
+    """A staged tile's device bytes; a key that IS a column is held once."""
+    batch, keys = tile
+    held = {id(a): a.nbytes for c in list(batch.columns) + keys
+            for a in (c.data, c.validity)}
+    return sum(held.values())
 
 
 def merge_sorted_batches(runs: List[Iterator[pa.RecordBatch]],

@@ -32,24 +32,30 @@ DECIMAL_COUNTERS = ("stage_loop_decimal_rows", "agg_decimal_rows_host",
                     "decimal_scaled_int32_dispatches",
                     "decimal_scaled_int64_dispatches",
                     "decimal_limb_dispatches")
-# q01's float plan at this scale, as the parent commit (01e2d5a) ran it
+# q01's float plan at this scale, a task at a time, as the parent commit
+# (e20583a) ran it (each broadcast map built once: 25 fused and 3 eager
+# expression batches and 9 string evictions, where tasks that arrive
+# together build a map each and read up to 91, 6 and 21), and the three
+# programs of `SortExec`'s resident lane (PR 42: the merge join's 2,373-row
+# side stays on the device while it is sorted)
 FLOAT_PROGRAMS = [
     "expr_program_12719fd5421b", "expr_program_26d11000e24c",
     "expr_program_50070b6d01df", "expr_program_db9f9d15a1b6",
     "join.expand_pairs", "join.hash_valid", "join.probe_counts",
     "join.probe_gather", "mesh.exchange_rows", "runtime.stage_loop",
     "runtime.stage_loop_window", "smj.bounds", "smj.expand_pairs",
-    "smj.gather", "sort.pass"]
+    "smj.gather", "sort.pass", "sort.assemble", "sort.digits", "sort.gather"]
 FLOAT_COUNTERS = {
     "stage_loop_tasks": 16, "stage_loop_rows": 11718,
     "stage_loop_lanes": 16384, "stage_loop_calls": 12,
     "stage_loop_windows": 12, "stage_loop_windows_fused": 12,
     "stage_loop_full_rounds": 18, "stage_loop_final_slots": 4194304,
-    "expr_fused_batches": 91, "expr_eager_batches": 6,
+    "expr_fused_batches": 25, "expr_eager_batches": 3,
     "join_probe_device_rows": 29893, "join_probe_host_rows": 180,
     "smj_device_rows": 2876, "smj_device_pairs": 2864,
-    "sort_device_rows": 2373, "shuffle_device_exchanges": 4,
-    "shuffle_device_rows": 11524, "host_evictions_string": 21,
+    "sort_device_rows": 2373, "sort_resident_rows": 2373,
+    "shuffle_device_exchanges": 4,
+    "shuffle_device_rows": 11524, "host_evictions_string": 9,
     "chip0_tasks": 21}
 
 
@@ -92,9 +98,9 @@ def device_path(monkeypatch):
         config.conf.unset(config.MESH_DEVICES.key)
 
 
-def collect(plan):
+def collect(plan, **scheduler):
     before = xla_stats.snapshot()
-    with DagScheduler() as sched:
+    with DagScheduler(**scheduler) as sched:
         got = sched.run_collect(plan)
         assert sched.exec_mode == "staged"
     return got, xla_stats.delta(before)
@@ -309,7 +315,12 @@ def test_q01s_float_plan_asks_for_the_programs_it_did(device_path,
     paths = float_gen.write_parquet_splits(tables, str(tmp_path), SPLITS,
                                            4096)
     known = set(xla_stats.compile_report()["kernels"])
-    got, d = collect(q01.plan(paths, tables, PARTITIONS))
+    # a task at a time: tasks that arrive together at a broadcast join each
+    # build its map (`bridge/resource.get_or_create` runs the factory
+    # outside its lock), so how often a build side's expressions run is
+    # the threads' timing; one after another each map is built once
+    got, d = collect(q01.plan(paths, tables, PARTITIONS),
+                     max_task_parallelism=1)
     assert check.verdict(check.compare(got, q01.oracle(tables), q01.KEYS,
                                        q01.ORDERED))[0]
     # every program the query asked for has the name it had (an expression

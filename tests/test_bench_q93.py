@@ -148,6 +148,20 @@ def test_q93_plan_on_the_device_path_matches_its_oracle(
     # only the sales side of a partition has the 1,024 rows a device sort
     # is worth
     assert 0 < d["sort_device_rows"] <= returns + sales
+    # and those partitions never left the device while they were sorted:
+    # the cell's result line carries `sort_resident_share`
+    from benchmark.manifest import load_json
+    from benchmark.sources import counter
+    entry, = [e for e in load_json(os.path.join(ROOT, "BENCHMARK.json"))[
+        "per_layer"] if e["name"] == "sort_resident_share"]
+    assert entry["workloads"] == ["sf1_q93_x1", "sf1_q93_x4", "sf10_q01_x1",
+                                  "sf10_q01_dec_x1"]
+    spec = load_json(os.path.join(ROOT, "benchmark", "layer_metrics",
+                                  "sort_resident_share.json"))
+    assert counter.read(spec, {"counters": d, "queries": 1}) == 100.0
+    assert d["sort_resident_rows"] >= sales
+    parent = {k: v for k, v in d.items() if k != "sort_resident_rows"}
+    assert counter.read(spec, {"counters": parent, "queries": 1}) is None
 
 
 def _check():

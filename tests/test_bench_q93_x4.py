@@ -148,6 +148,11 @@ def test_the_entry_answers_as_the_oracle_and_finds_no_problem(one_run, case):
     assert moved["cross_chip_bytes"] == 0
     assert moved["smj_streamed_runs"] == 0
     assert moved["smj_device_pairs"] == tables["store_returns"].num_rows
+    # each chip sorted its sales partition where it lay (PR 42)
+    assert moved["sort_resident_rows"] == moved["sort_device_rows"] \
+        >= tables["store_sales"].num_rows
+    assert all(moved[f"chip{c}_sort_resident_rows"] > 0
+               for c in range(CHIPS))
     assert entry.fact_rows == sum(
         tables[n].num_rows for n in ("store_sales", "store_returns"))
     assert moved["shuffle_device_rows"] >= entry.fact_rows
@@ -476,7 +481,9 @@ def test_every_new_metric_has_its_file_its_cells_and_its_unit(cell):
     # and every metric without a list reports here by itself
     listless = [m["name"] for m in cell.manifest["per_layer"]
                 if "workloads" not in m]
-    assert len(specs) == len(listless) + len(NEW) + len(TWINS)
+    # (and `sort_resident_share`, which PR 42 listed this cell under)
+    assert "sort_resident_share" in specs
+    assert len(specs) == len(listless) + len(NEW) + len(TWINS) + 1
 
 
 def test_the_new_span_and_counter_metrics_read_a_rehearsals_context(

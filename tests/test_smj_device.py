@@ -202,6 +202,67 @@ def test_device_merge_join_matches_reference(on_device, jt, shape, filtered):
     assert ordered == sorted(ordered)
 
 
+@pytest.mark.parametrize("jt", [JoinType.INNER, JoinType.FULL],
+                         ids=lambda jt: jt.value)
+def test_sides_sorted_on_the_device_join_as_arrow_joins_them(on_device, jt):
+    """Both sides of fixed-width columns alone, over 1,024 rows and in
+    ragged batches: each `SortExec` keeps its partition on the device and
+    hands `_merge_device` ONE sorted batch, which `merge._concat` passes
+    through; the pairs are Arrow's hash join's."""
+    from blaze_tpu.batch import ColumnBatch
+    from blaze_tpu.bridge import tracing
+    from blaze_tpu.schema import Schema
+    rng = np.random.default_rng(23)
+    nl, nr = 2600, 1700
+    left = pa.table({
+        "lk0": _nullable(rng.integers(0, 900, nl), .05, rng, pa.int64()),
+        "lk1": _nullable(rng.integers(0, 3, nl), .05, rng, pa.int32()),
+        "lv": pa.array(np.round(rng.random(nl) * 10, 3)),
+        "lid": pa.array(np.arange(nl))})
+    right = pa.table({
+        "rk0": _nullable(rng.integers(0, 900, nr), .05, rng, pa.int64()),
+        "rk1": _nullable(rng.integers(0, 3, nr), .05, rng, pa.int32()),
+        "rid": pa.array(np.arange(nr))})
+
+    def scan(t, cuts):
+        at, batches = 0, []
+        for n in cuts:
+            batches.append(ColumnBatch.from_arrow(
+                t.slice(at, n).combine_chunks().to_batches()[0]))
+            at += n
+        assert at == t.num_rows
+        return MemoryScanExec(Schema.from_arrow(t.schema), [batches])
+
+    smj = SortMergeJoinExec(scan(left, [1024, 1024, 552]),
+                            scan(right, [900, 1, 799]),
+                            [col(0), col(1)], [col(0), col(1)], jt)
+    before = xla_stats.snapshot()
+    tracing.start_tracing()
+    try:
+        got = _run(smj)
+    finally:
+        spans = tracing.stop_tracing()
+    d = xla_stats.delta(before)
+    want = left.join(right, keys=["lk0", "lk1"], right_keys=["rk0", "rk1"],
+                     join_type="inner" if jt == JoinType.INNER
+                     else "full outer", coalesce_keys=False) \
+        .select(left.column_names + right.column_names)
+    assert len(got) == want.num_rows > 1000
+    assert _canon(got) == _canon(tuple(r.values()) for r in want.to_pylist())
+    assert d["sort_resident_rows"] == d["sort_device_rows"] == nl + nr
+    assert d["smj_device_rows"] == nl + nr and d["smj_streamed_runs"] == 0
+    assert sorted((s["attrs"]["rows"], s["attrs"]["lane"]) for s in spans
+                  if s["name"] == "sort_device") == [
+        (nr, "resident"), (nl, "resident")]
+    # no side was cut and concatenated again on its way into the join
+    assert not [s for s in spans if s["name"] == "coalesce"
+                and s["attrs"]["batches"] > 1]
+    keys = [tuple(a if a is not None else b for a, b in zip(r[:2], r[4:6]))
+            for r in got]
+    ordered = [_order_key(k) for k in keys]
+    assert ordered == sorted(ordered)
+
+
 @pytest.mark.parametrize("budget,denied", [
     (20_000, "a side"),       # under the left side's bytes
     (400_000, "the pairs"),   # over both sides' and the sorts', under the pairs'
