@@ -35,8 +35,8 @@ is the longitudinal layer on top of it:
   from the history surface alone.
 
 The HTTP surface (`/history`, `/history/<qid>`, `/history/rollup`)
-lives in bridge/profiling.py; the regression sentinel that diffs
-rollups and bench artifacts is blaze_tpu/tools/sentinel.py.
+lives in bridge/profiling.py; the regression sentinel that diffs two
+saved rollups is blaze_tpu/tools/sentinel.py.
 
 This module deliberately imports nothing heavy at module scope (no jax,
 no pyarrow): a fresh process can replay history without touching the

@@ -204,7 +204,7 @@ class NativeExecutionRuntime:
 
 def execute_plan(plan_or_td, partition: Optional[int] = None
                  ) -> List[pa.RecordBatch]:
-    """Convenience driver: run one task to completion (test/bench helper —
+    """Convenience driver: run one task to completion (test helper —
     the NativeHelper.executeNativePlan analog)."""
     if isinstance(plan_or_td, ExecutionPlan):
         parts = ([partition] if partition is not None

@@ -1,11 +1,11 @@
-"""Bounded task-pool helper shared by the stage scheduler and bench.
+"""Bounded task-pool helper of the stage scheduler.
 
 A task thread wedged inside backend init/compile must convert to a
 TimeoutError for the caller instead of hanging ThreadPoolExecutor
-forever (threads stuck in `jax.devices()` once turned a bench run into
-rc=124).  shutdown(wait=False) leaves any stuck thread behind;
-callers that must exit promptly despite one should use os._exit after
-reporting (bench.py child does).
+forever (a thread stuck in `jax.devices()` otherwise ends its process
+at the caller's time limit, rc=124).  shutdown(wait=False) leaves any
+stuck thread behind; a caller that must exit promptly despite one
+reports and then uses os._exit.
 
 Fault tolerance: each task gets bounded retries with exponential
 backoff + jitter for RETRYABLE failures (transient IO, injected faults —

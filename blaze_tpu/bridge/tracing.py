@@ -635,7 +635,7 @@ def stop_tracing() -> List[dict]:
 
 
 def reset_conf_probe() -> None:
-    """Forget the lazy auron.tpu.trace.enable probe (tests/bench)."""
+    """Forget the lazy auron.tpu.trace.enable probe (tests)."""
     global _conf_probed, _enabled, _child_mode
     with _lock:
         _conf_probed = False

@@ -188,9 +188,8 @@ _workers = {"worker_spawns": 0, "worker_tasks": 0, "worker_crashes": 0,
             "worker_hangs": 0, "worker_restarts": 0,
             "worker_blacklisted": 0, "worker_cancels": 0,
             # child-process CPU actually burned running tasks (user+sys
-            # os.times() delta shipped in each result frame) — what
-            # bench.py --multichip derives host_core_limited from,
-            # instead of a host-core-count heuristic
+            # os.times() delta shipped in each result frame); over a
+            # wave's wall it says how many cores the children kept busy
             "worker_cpu_ns": 0}
 
 # Speculative-execution accounting (bridge/tasks.py wave loop,
@@ -249,8 +248,8 @@ _aqe = {"aqe_rewrites": 0, "aqe_broadcast_switches": 0,
 # boundaries, decimal dispatches split by storage tier (scaled int32 /
 # scaled int64 / two-limb int128), and host-lane evictions split by the
 # column dtype that caused them — the per-column accounting the advisor
-# and BENCH_* compute_placement read instead of the old whole-stage
-# "string somewhere -> host" verdict.
+# reads instead of the old whole-stage "string somewhere -> host"
+# verdict.
 _encoding = {"dict_encoded_columns": 0, "dict_exchange_remaps": 0,
              "decimal_scaled_int32_dispatches": 0,
              "decimal_scaled_int64_dispatches": 0,
@@ -284,8 +283,8 @@ _fleet = {"fleet_queries_routed": 0, "fleet_queries_completed": 0,
           "fleet_torn_frames": 0, "fleet_hedges": 0,
           "fleet_hedge_wins": 0, "fleet_replicas_up_last": 0}
 
-# Bounded raw-sample reservoirs feeding tail-latency percentiles
-# (bench.py --workers / --speculate): successful task-attempt durations
+# Bounded raw-sample reservoirs feeding tail-latency percentiles (the
+# statstore's per-fingerprint task sketch): successful task-attempt durations
 # and run_tasks wave walls, in ns.  Lists, so NOT folded into
 # snapshot()/delta() — read via duration_samples(), cleared by reset().
 _task_duration_ns: List[int] = []
@@ -696,7 +695,7 @@ def speculation_stats() -> dict:
 
 def note_task_duration(ns: int) -> None:
     """One successful task attempt's wall time (speculation's straggler
-    cutoff and the bench's p50/p99 task percentiles feed from here)."""
+    cutoff and the statstore's task percentiles feed from here)."""
     with _lock:
         if len(_task_duration_ns) < _SAMPLE_CAP:
             _task_duration_ns.append(int(ns))

@@ -198,7 +198,7 @@ def get_cache() -> Optional[ResultCache]:
             c.query = None
             _singleton = c
         elif _singleton._manager is not manager:
-            # MemManager.init() swapped the pool (tests, bench legs):
+            # MemManager.init() swapped the pool (tests):
             # re-home the accounting
             _singleton._manager = None
             _singleton.set_spillable(manager)
@@ -207,7 +207,7 @@ def get_cache() -> Optional[ResultCache]:
 
 
 def reset_cache() -> None:
-    """Drop the singleton (tests / bench teardown): clears entries and
+    """Drop the singleton (tests teardown): clears entries and
     unregisters the consumer so leak checks see an empty pool."""
     global _singleton
     with _singleton_lock:

@@ -582,17 +582,6 @@ WORKERS_DRAIN_MS = int_conf(
     "Graceful-drain budget at pool shutdown: workers get a shutdown "
     "message and this long to exit cleanly before SIGTERM, then "
     "SIGKILL.", category="fault-tolerance")
-WORKERS_PIN_DEVICES = bool_conf(
-    "auron.tpu.workers.pinDevices", False,
-    "Pin ONE emulated XLA device per worker child at spawn "
-    "(JAX_PLATFORMS=cpu + --xla_force_host_platform_device_count=1 in "
-    "the child's env, replacing any inherited device-count flag).  N "
-    "pinned workers model N independent single-device hosts — the "
-    "process-per-device harness bench.py --multichip uses so the "
-    "scaling curve measures real per-process work instead of N "
-    "virtual devices serializing collectives on one core.  Each child "
-    "echoes its device_spec (platform, device count) in the hello "
-    "frame; pool.health() surfaces it.", category="fault-tolerance")
 SPECULATION_ENABLE = bool_conf(
     "auron.tpu.speculation.enable", False,
     "Speculative execution (the spark.speculation analog): once the "
@@ -689,7 +678,7 @@ STAGE_DEVICE_LOOP_ENABLE = str_conf(
     "where the per-batch dispatch RTT it amortizes exists — on stages "
     "that compile (plan/stage_compiler.py eligibility: fixed-width "
     "dtypes, traceable exprs, hash-lane agg); 'on' forces it wherever "
-    "it compiles, regardless of placement (tests/bench on CPU hosts); "
+    "it compiles, regardless of placement (tests on CPU hosts); "
     "'off' always uses the staged per-batch executor.  Any loop "
     "failure — injected fault, overflow past the "
     "table cap, untraceable chain — falls back wholesale to the staged "
@@ -1161,7 +1150,7 @@ STREAM_EPOCH_INTERVAL_MS = int_conf(
     "auron.tpu.stream.epoch.intervalMs", 0,
     "Target pacing between micro-batch epochs of the streaming runtime "
     "(streaming/executor.py).  0 = run epochs back-to-back (drain mode, "
-    "the bench/test default); >0 sleeps out the remainder of the "
+    "the test default); >0 sleeps out the remainder of the "
     "interval after each epoch, like Flink's checkpoint interval.",
     category="streaming")
 STREAM_CHECKPOINT_DIR = str_conf(

@@ -2,7 +2,7 @@
 
 The reference folds constants on the Spark side before the plan crosses
 the wire (Catalyst ConstantFolding), so its native planner rarely sees
-`lit(2) * lit(3)`.  Directly-authored IR (tests, bench, the itest
+`lit(2) * lit(3)`.  Directly-authored IR (tests, the itest
 builders) has no such pass — and every unfolded constant subtree widens
 the expression fingerprint of the whole-stage program cache
 (exprs/program.py), so identical queries written with equivalent

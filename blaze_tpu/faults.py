@@ -7,8 +7,8 @@ shuffle fetches fail, and the query must still finish with the same
 rows.  This module is the *test* side of that contract: a process-wide
 injection registry with named sites threaded through the scheduler,
 task pool, shuffle writer/reader and memory manager, so chaos runs
-(`bench.py --chaos`, tests/test_fault_tolerance.py) can script failures
-deterministically and assert bit-identical recovery.
+(tests/test_fault_tolerance.py, tests/test_serving_soak.py) can script
+failures deterministically and assert bit-identical recovery.
 
 Sites (the code points that call in here):
     task-start     bridge/tasks.py, before each task attempt
@@ -347,7 +347,8 @@ def install(site: str, **kw: Any) -> FaultInjector:
 
 def configure(rules: str, seed: int = 0) -> FaultInjector:
     """Replace the active injector with one built from a rule string
-    (the `bench.py --chaos` entry point)."""
+    (`site=p*cap,site@k:action`, parse_rules' grammar): a whole seeded
+    chaos script in one call."""
     global _injector, _conf_probed
     inj = FaultInjector(seed=seed)
     for site, kw in parse_rules(rules):
@@ -375,7 +376,7 @@ def activate_from_conf() -> Optional[FaultInjector]:
 
 
 def clear() -> None:
-    """Deactivate injection entirely (tests/bench teardown)."""
+    """Deactivate injection entirely (tests teardown)."""
     global _injector, _conf_probed
     with _lock:
         _injector = None

@@ -18,7 +18,7 @@ VPU inside the kernel (they never touch HBM), and the output table stays
 resident in VMEM across the whole grid (constant out index_map).
 On a v5e (jax 0.9.0 / libtpu 0.0.34) the kernel compiles under Mosaic at
 every layout `plan_layout` admits and is bit-identical to the scatter
-table (chip_smoke.py); its rows/s on the directly attached chip: not
+table (chip_smoke.py, `kernels`); its rows/s on the directly attached chip: not
 measured.
 
 Exactness without f64 (TPU v5e emulates all 64-bit types, ~10x slower):

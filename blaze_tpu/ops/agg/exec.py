@@ -363,8 +363,8 @@ class _AggState(MemConsumer):
         key_dev = self._encode_keys(key_vals, batch)
 
         xp = xp_of(valid_mask, *[d for d, _v in key_dev])
-        # observed-lane evidence: bench's per-stage placement breakdown
-        # reads these instead of trusting the session-level default
+        # observed-lane evidence: where a stage really ran, whatever
+        # the session-level default says
         op.metrics.add("device_lane_batches" if xp is not np
                        else "host_lane_batches", 1)
         if self.num_keys:

@@ -43,7 +43,6 @@ import logging
 import os
 import pickle
 import queue
-import re
 import signal
 import subprocess
 import sys
@@ -183,7 +182,6 @@ class _Slot:
         self.cancel_kill = False   # cancel/deadline kill: not a crash
         self.inbox: "queue.Queue" = queue.Queue()
         self.write_lock = threading.Lock()
-        self.device_spec: Optional[Dict[str, Any]] = None  # hello frame
         self.platform: Optional[str] = None  # hello frame: JAX_PLATFORMS
         self.cpu_ns = 0            # child CPU (user+sys) across tasks
 
@@ -255,26 +253,9 @@ class WorkerPool:
         and stated: a child inherits the parent's JAX_PLATFORMS, and a
         child that would open the accelerator its parent holds is
         refused (bridge/placement.py refuse_chip_contention) — the pool then
-        fails to start and tasks run in-process.
-        With workers.pinDevices each child is pinned to exactly ONE
-        emulated device (`JAX_PLATFORMS=cpu`,
-        `--xla_force_host_platform_device_count=1`) — the
-        process-per-device scaling harness: N workers x 1 device instead
-        of 1 process x N virtual devices, so the multichip bench's
-        collective overhead is cross-PROCESS, not cross-thread.  Any
-        device-count flag inherited from a multichip parent is stripped
-        first (the parent emulates N devices; its children must not)."""
-        from blaze_tpu import config
+        fails to start and tasks run in-process."""
         from blaze_tpu.bridge.placement import refuse_chip_contention
         env = dict(os.environ)
-        if config.WORKERS_PIN_DEVICES.get():
-            env["JAX_PLATFORMS"] = "cpu"
-            flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
-                           "", env.get("XLA_FLAGS", "")).strip()
-            env["XLA_FLAGS"] = (flags +
-                                " --xla_force_host_platform_device_count=1"
-                                ).strip()
-            env["BLAZE_WORKER_DEVICE_SLOT"] = str(slot.id)
         refuse_chip_contention(env, f"worker {slot.id}")
         return env
 
@@ -296,7 +277,6 @@ class WorkerPool:
                         if slot.proc is proc and slot.state == _STARTING:
                             slot.state = _IDLE
                             slot.platform = msg.get("platform")
-                            slot.device_spec = msg.get("device_spec")
                             slot.last_heartbeat = time.monotonic()
                             self._cond.notify_all()
                 elif kind == "heartbeat":
@@ -683,8 +663,8 @@ class WorkerPool:
         cpu_ns = res.get("cpu_ns")
         if cpu_ns:
             # actual worker-process CPU (user+sys from os.times in the
-            # child) — the multichip bench derives host_core_limited
-            # from the SUM of these vs wall, not from a host heuristic
+            # child): the SUM of these over a wave's wall is the cores
+            # the children kept busy
             from blaze_tpu.bridge import xla_stats
             xla_stats.note_worker_cpu(int(cpu_ns))
         with self._cond:
@@ -762,7 +742,6 @@ class WorkerPool:
                      "crashes": s.crashes, "tasks_done": s.tasks_done,
                      "incarnation": s.incarnation,
                      "platform": s.platform,
-                     "device_spec": s.device_spec,
                      "cpu_s": s.cpu_ns / 1e9,
                      "heartbeat_age_ms": int((now - s.last_heartbeat) * 1e3)
                      if s.state == _BUSY else None}
@@ -820,7 +799,7 @@ def active_pool() -> Optional[WorkerPool]:
 
 
 def shutdown_pool(wait: bool = True) -> None:
-    """Close and forget the singleton (tests/bench re-knob between
+    """Close and forget the singleton (tests re-knob between
     legs; serving shutdown)."""
     global _pool, _pool_failed
     with _pool_lock:
@@ -891,18 +870,18 @@ def run_shuffle_map_task(task: dict) -> dict:
 
 
 def _task_echo(*args) -> dict:
-    """Test/bench helper: round-trips its args."""
+    """Test helper: round-trips its args."""
     return {"echo": list(args), "pid": os.getpid()}
 
 
 def _task_sleep(seconds: float, value: Any = None) -> dict:
-    """Test/bench helper: hold a worker busy (heartbeating) then echo."""
+    """Test helper: hold a worker busy (heartbeating) then echo."""
     time.sleep(float(seconds))
     return {"value": value, "pid": os.getpid()}
 
 
 def _task_raise(kind: str = "runtime") -> None:
-    """Test/bench helper: raise a classified error inside the worker."""
+    """Test helper: raise a classified error inside the worker."""
     if kind == "fetch":
         raise FetchFailedError(7, 3, "injected remote fetch failure")
     if kind == "retryable":
@@ -910,61 +889,8 @@ def _task_raise(kind: str = "runtime") -> None:
     raise RuntimeError("injected fatal failure")
 
 
-def _task_device_shard(rows: int, groups: int, reps: int = 1,
-                       seed: int = 0) -> dict:
-    """Bench helper (bench.py --multichip): one process-per-device shard
-    of the grouped-agg microbench.  jax initializes INSIDE this pinned
-    child, seeing exactly the one emulated device the spawn env granted,
-    so the N-shard wave measures real cross-process scaling rather than
-    N virtual devices time-slicing one interpreter.  Reports wall AND
-    process CPU (user+sys) so the supervisor can compute
-    cpu_parallelism = sum(cpu_s) / wall across the wave — the honest
-    host_core_limited signal."""
-    t_wall = time.perf_counter()
-    cpu0 = os.times()
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    rows, groups = int(rows), int(groups)
-    rng = np.random.default_rng(int(seed))
-    keys = jnp.asarray(rng.integers(0, groups, size=rows, dtype=np.int64))
-    vals = jnp.asarray(rng.random(rows))
-
-    @jax.jit
-    def worker_microbench_agg(k, v):
-        return jax.ops.segment_sum(v, k, num_segments=groups)
-
-    out = None
-    for _ in range(max(1, int(reps))):
-        out = worker_microbench_agg(keys, vals)
-    out.block_until_ready()
-    cpu1 = os.times()
-    return {"wall_s": time.perf_counter() - t_wall,
-            "cpu_s": ((cpu1.user - cpu0.user) +
-                      (cpu1.system - cpu0.system)),
-            "checksum": float(jnp.sum(out)),
-            "devices": jax.device_count(),
-            "platform": jax.default_backend(),
-            "pid": os.getpid()}
-
-
 # ---------------------------------------------------------------------------
 # Child side
-
-def _child_device_spec() -> Optional[Dict[str, Any]]:
-    """Describe the device this child was pinned to, from the spawn env
-    ALONE — importing jax in the frame loop would initialize a backend
-    the first task's conf snapshot has not configured yet.  None when
-    the pool spawned without pinning (the default)."""
-    slot = os.environ.get("BLAZE_WORKER_DEVICE_SLOT")
-    if slot is None:
-        return None
-    m = re.search(r"--xla_force_host_platform_device_count=(\d+)",
-                  os.environ.get("XLA_FLAGS", ""))
-    return {"slot": int(slot),
-            "platform": os.environ.get("JAX_PLATFORMS") or "default",
-            "local_devices": int(m.group(1)) if m else None}
-
 
 def _resolve_fn(spec: str) -> Callable:
     mod_name, _, qual = spec.partition(":")
@@ -1083,9 +1009,6 @@ def child_main() -> int:
     hello: Dict[str, Any] = {
         "kind": "hello", "pid": os.getpid(),
         "platform": os.environ.get("JAX_PLATFORMS") or "default"}
-    spec = _child_device_spec()
-    if spec is not None:
-        hello["device_spec"] = spec
     _send_msg(out, hello, out_lock)
     while True:
         try:

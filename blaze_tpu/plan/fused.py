@@ -696,8 +696,8 @@ class FusedPartialAggExec(ExecutionPlan):
     def _note_lane(self, batches: int, host: bool = False) -> None:
         """Observed-lane evidence, the same counters AggExec keeps:
         input batches this operator aggregated as device-resident
-        columns vs on the host (numpy / Arrow) — what a smoke or bench
-        reads instead of trusting the session-level placement."""
+        columns vs on the host (numpy / Arrow) — what says where a
+        stage really ran, whatever the session-level placement is."""
         from blaze_tpu.bridge.placement import host_resident
         self.metrics.add("host_lane_batches" if host or host_resident()
                          else "device_lane_batches", batches)
@@ -2267,7 +2267,6 @@ def _dense_fold_factory(key, prepare, ranges, kinds, num_slots: int):
         return jax.lax.fori_loop(0, masks.shape[0], body, carry)
 
     fold = meter_jit(fold_impl, name="fused.dense_fold", donate_argnums=0)
-    fold.raw = fold_impl  # see _mxu_fold_factory: embeddable traced body
     _DENSE_STEP_CACHE[skey] = fold
     return fold
 
@@ -2351,10 +2350,6 @@ def _mxu_fold_factory(key, prepare, ranges, meta: _MxuMeta,
         return jax.lax.fori_loop(0, masks.shape[0], body, carry)
 
     fold = meter_jit(fold_impl, name="fused.mxu_fold", donate_argnums=0)
-    # raw traced body, for callers embedding the fold in a larger
-    # program (bench device loop): a nested-jit call boundary inside a
-    # fori_loop defeats XLA's cross-stage fusion on TPU (~10x slower)
-    fold.raw = fold_impl
     _DENSE_STEP_CACHE[skey] = fold
     return fold
 

@@ -416,7 +416,7 @@ _DEVICE_EXCHANGE_TIDS = frozenset((
 def _note_exchange_type_eviction(tid) -> None:
     """An exchange boundary just stayed on the host file shuffle because
     of a column TYPE (not mode/keys): account the reason so the advisor
-    and bench placement reports show what actually evicted it."""
+    shows what actually evicted it."""
     from blaze_tpu.bridge import xla_stats
     if tid in ("utf8", "binary"):
         xla_stats.note_encoding(host_evictions_string=1)

@@ -100,7 +100,7 @@ def stage_loop_mode() -> str:
 
 
 def stage_loop_active() -> bool:
-    """'on' forces the loop wherever it compiles (tests/bench on CPU
+    """'on' forces the loop wherever it compiles (tests on CPU
     hosts); 'auto' runs it only for device-resident compute, where the
     per-batch dispatch RTT it amortizes actually exists — on host
     placement the staged Arrow lanes win."""

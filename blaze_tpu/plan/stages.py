@@ -217,9 +217,9 @@ class DagScheduler:
         self._metrics_lock = threading.Lock()
         # sid -> {"compute": "device-loop"|"staged"|"mixed",
         #         "exchange": "device"|"rss"|"file"|"result"} — the
-        # OBSERVED per-stage placement (bench/explain derive
-        # compute_placement from this instead of the session-level
-        # default, which reported "cpu" even when device lanes ran)
+        # OBSERVED per-stage placement (explain and history read this
+        # instead of the session-level default, which reported "cpu"
+        # even when device lanes ran)
         self.stage_placement: Dict[int, Dict[str, str]] = {}
         # work-sharing (auron.tpu.cache.subplan): sid -> (fp, snapshot)
         # of stages served FROM the cross-query cache this run, and of

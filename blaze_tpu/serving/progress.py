@@ -6,8 +6,7 @@ task metrics (rows/bytes) as it runs; `progress(qid)` renders that into
 per-stage done/total counts, row/byte rates, and an ETA.  The ETA is
 seeded from the statstore prior for the plan fingerprint (p50 wall of
 earlier runs) and falls back to fraction-done extrapolation on a cold
-fingerprint — the warm-vs-cold accuracy difference is what
-`bench.py --obs` measures.
+fingerprint (`eta_source` says which).
 
 Gated with the rest of the stats plane on `auron.tpu.stats.enable`
 (the scheduler checks `statstore.enabled()` before calling in), so the

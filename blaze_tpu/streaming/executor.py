@@ -67,7 +67,7 @@ class StreamWindowConfig:
 
 
 class MemoryStreamSource:
-    """Bounded in-memory Kafka (the broker-less test/bench source): one
+    """Bounded in-memory Kafka (the broker-less test source): one
     record list per partition, polled by offset.  ``poll`` returns None
     once a partition is drained — end-of-stream for the executor."""
 

@@ -90,7 +90,7 @@ class ExactlyOnceParquetSink:
 
     def committed_table(self) -> pa.Table:
         """All committed epoch outputs, concatenated in epoch order (the
-        stream's total sink output — what the bench compares against an
+        stream's total sink output — what a test compares against an
         offline batch run).  Raises only when NO epoch has committed;
         committed-but-all-empty epochs (a query whose windows produced
         no output) yield an empty table with the sink schema."""

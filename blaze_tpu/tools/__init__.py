@@ -1,9 +1,6 @@
-"""Operator-facing CLI tools (``python -m blaze_tpu.tools.<name>``) and
-the shared bench-artifact schema.
+"""Operator-facing CLI tools (``python -m blaze_tpu.tools.<name>``).
 
-* ``sentinel``     — regression sentinel: diff unified BENCH_*.json
-                     artifacts / history rollups against a baseline
-                     with noise-floor thresholds (CI exit codes).
-* ``bench_schema`` — the unified schema-versioned envelope every
-                     BENCH_*.json artifact is written through.
+* ``sentinel`` — regression sentinel: diff two saved /history/rollup
+                 payloads with noise-floor thresholds (CI exit codes).
+* ``top``      — live table of running queries from /progress.
 """

@@ -1,16 +1,13 @@
-"""Regression sentinel: diff bench artifacts / history rollups against
-a stored baseline.
+"""Regression sentinel: diff two saved /history/rollup payloads (or any
+two JSON documents of numbers) against each other.
 
     python -m blaze_tpu.tools.sentinel \
-        --baseline BENCH_BASE.json --candidate BENCH_NEW.json \
-        [--threshold 0.10] [--abs-floor 1e-6] [--metrics 'q01.*'] \
+        --baseline rollup_before.json --candidate rollup_after.json \
+        [--threshold 0.10] [--abs-floor 1e-6] [--metrics 'counters.*'] \
         [--ci] [--json]
 
-`--baseline` / `--candidate` each name either one JSON file (a unified
-BENCH_*.json artifact or a saved /history/rollup payload) or a
-directory, in which case every `BENCH_*.json` inside is merged under
-its filename stem.  Numeric leaves are flattened to dotted metric keys
-and compared pairwise.
+`--baseline` / `--candidate` each name one JSON file.  Numeric leaves
+are flattened to dotted metric keys and compared pairwise.
 
 A metric regresses when its relative change exceeds `--threshold` in
 the WORSE direction — metric names carry the direction (`wall`, `_ms`,
@@ -23,11 +20,11 @@ the relative change is computed against max(|baseline|, 1e-9).
 Exit codes (the CI contract):
 
 * ``0`` — no regression (identical runs always exit 0);
-* ``1`` — usage / IO / schema error;
+* ``1`` — usage / IO error;
 * ``2`` — regression: every offending metric is named on stdout.
 
 ``--ci`` additionally fails (exit 2) on metrics present in the baseline
-but missing from the candidate, and on bench schema_version mismatches.
+but missing from the candidate.
 Default thresholds come from `auron.tpu.sentinel.threshold`.
 """
 
@@ -36,12 +33,12 @@ from __future__ import annotations
 import argparse
 import fnmatch
 import json
-import os
 import re
 import sys
 from typing import Any, Dict, List, Optional
 
-from blaze_tpu.tools.bench_schema import ENVELOPE_KEYS
+#: top-level keys that tag a payload's shape and are not metrics
+_NOT_METRICS = ("schema_version",)
 
 _LOWER_IS_BETTER = re.compile(
     r"(wall|latency|_ms\b|_ns\b|_s\b|seconds|p50|p95|p99|overhead|"
@@ -54,10 +51,9 @@ _LOWER_IS_BETTER = re.compile(
     r"eviction_fraction|dict_exchange_remaps)",
     re.IGNORECASE)
 _HIGHER_IS_BETTER = re.compile(
-    r"(rows_per_sec|per_sec|qps|throughput|speedup|hit_rate|hits\b|"
+    r"(rows_per_sec|per_sec|qps|throughput|hit_rate|hits\b|"
     r"fraction|utilization|rows\b|completed|coalesces|bytes_saved|"
-    r"overlap(?:ped)?|cpu_parallelism|"
-    r"share_ratio|replicas_up|hedge_wins|"
+    r"overlap(?:ped)?|replicas_up|hedge_wins|"
     r"aqe_(rewrites|broadcast_switches|partitions_coalesced|"
     r"skew_splits|history_seeds|stages_elided)|"
     # encoding lanes (ISSUE 20): more columns riding int codes / more
@@ -78,11 +74,12 @@ def metric_direction(key: str) -> str:
 
 
 def flatten(obj: Any, prefix: str = "") -> Dict[str, float]:
-    """Numeric leaves as dotted keys; envelope metadata is skipped."""
+    """Numeric leaves as dotted keys; the payload's version tag is
+    skipped."""
     out: Dict[str, float] = {}
     if isinstance(obj, dict):
         for k, v in obj.items():
-            if not prefix and k in ENVELOPE_KEYS:
+            if not prefix and k in _NOT_METRICS:
                 continue
             out.update(flatten(v, f"{prefix}{k}."))
     elif isinstance(obj, (list, tuple)):
@@ -96,17 +93,6 @@ def flatten(obj: Any, prefix: str = "") -> Dict[str, float]:
 
 
 def load(path: str) -> Dict[str, Any]:
-    """One JSON file, or a directory of BENCH_*.json merged by stem."""
-    if os.path.isdir(path):
-        merged: Dict[str, Any] = {}
-        for name in sorted(os.listdir(path)):
-            if name.startswith("BENCH_") and name.endswith(".json"):
-                with open(os.path.join(path, name)) as f:
-                    merged[name[len("BENCH_"):-len(".json")]] = \
-                        json.load(f)
-        if not merged:
-            raise FileNotFoundError(f"no BENCH_*.json under {path}")
-        return merged
     with open(path) as f:
         return json.load(f)
 
@@ -163,13 +149,12 @@ def _default_threshold() -> float:
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m blaze_tpu.tools.sentinel",
-        description="diff bench artifacts / history rollups against a "
-                    "baseline; exit 2 on regression")
+        description="diff two saved /history/rollup payloads; exit 2 "
+                    "on regression")
     ap.add_argument("--baseline", required=True,
-                    help="baseline JSON file or directory of "
-                         "BENCH_*.json")
+                    help="baseline JSON file")
     ap.add_argument("--candidate", required=True,
-                    help="candidate JSON file or directory")
+                    help="candidate JSON file")
     ap.add_argument("--threshold", type=float,
                     default=_default_threshold(),
                     help="relative noise floor (default "
@@ -179,8 +164,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--metrics", default=None,
                     help="fnmatch filter on dotted metric keys")
     ap.add_argument("--ci", action="store_true",
-                    help="strict mode: missing metrics and schema "
-                         "mismatches also regress")
+                    help="strict mode: missing metrics also regress")
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="machine-readable report on stdout")
     args = ap.parse_args(argv)
@@ -191,26 +175,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     except (OSError, ValueError) as e:
         print(f"sentinel: cannot load inputs: {e}", file=sys.stderr)
         return 1
-
-    if args.ci:
-        bv = baseline.get("schema_version")
-        cv = candidate.get("schema_version")
-        if bv is not None and cv is not None and bv != cv:
-            print(f"sentinel: schema_version mismatch "
-                  f"(baseline={bv}, candidate={cv})", file=sys.stderr)
-            return 2
-        # directory mode: every committed baseline artifact must have a
-        # candidate counterpart — a bench leg silently not running is a
-        # regression (this is what makes BENCH_AQE.json mandatory once
-        # it exists in the baseline)
-        if os.path.isdir(args.baseline) and os.path.isdir(args.candidate):
-            missing = sorted(set(baseline) - set(candidate))
-            if missing:
-                for stem in missing:
-                    print(f"sentinel: baseline artifact "
-                          f"BENCH_{stem}.json missing from candidate",
-                          file=sys.stderr)
-                return 2
 
     findings = compare(baseline, candidate, threshold=args.threshold,
                        abs_floor=args.abs_floor, metrics=args.metrics,

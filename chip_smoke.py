@@ -1,27 +1,21 @@
 #!/usr/bin/env python3
-"""chip_smoke.py — the quickest proof that the wire path still starts on
-the chip.
+"""chip_smoke.py — the kernels alone on the chip, compiled and compared.
 
-One process, no supervisor or child, NO configuration key set: the
-defaults are the thing under test.  Data is made from --seed.  Phases:
+One process, no supervisor or child, NO configuration key set.  Test
+vectors are made from --seed.  One phase:
 
-  kernels  the aggregation/partition kernels alone, compiled (not
-           interpreted) at production shapes and compared with their
-           references: mxu_agg.window_table bit for bit against the
-           scatter table; hash_agg_step against a numpy group-by; the
-           exchange's partition order against a stable argsort.
-  pair     the q01 stage pair bench.py builds (scan -> filter -> partial
-           hash-agg -> shuffle_writer -> .data/.index -> ipc_reader ->
-           final agg) as TaskDefinition bytes through
-           NativeExecutionRuntime at SF10, 4 map x 4 reduce tasks,
-           against the pyarrow group_by oracle.
-  q01 q06  itest q01 at SF10 and q06 at SF1, 4 partitions, through
-           DagScheduler.run_collect, against their pandas oracles.
+  kernels  the aggregation/partition kernels, compiled (not interpreted)
+           at production shapes and compared with their references:
+           mxu_agg.window_table bit for bit against the scatter table;
+           hash_agg_step against a numpy group-by; the exchange's
+           partition order against a stable argsort.
 
-Each engine phase runs twice, cold then warm, and prints both walls, the
-programs compiled in each (warm must be 0), H2D/D2H bytes and the lane
-evidence.  With more than one chip visible the same phases run over all
-of them and the mesh exchange must carry the query shuffles.
+The proof of the ENGINE path on the chip is a benchmark cell's run, not
+this file:
+  python3 benchmark/run.py --workload sf10_q01pair_x1 --seed 1 \
+      --seconds 10 --trace 0
+refuses a placement other than `tpu`, compares every answer with the
+oracle and counts the programs compiled inside the window.
 
 Exit code 0 and a last stdout line
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
@@ -38,13 +32,9 @@ import json
 import logging
 import os
 import sys
-import tempfile
 import time
 
-PHASES = ("kernels", "pair", "q01", "q06")
-PAIR_SF = 10.0
-QUERY_SF = {"q01": 10.0, "q06": 1.0}
-PARTITIONS = 4
+PHASES = ("kernels",)
 DEADLINE_S = 1150  # the contract allows 1200 s, compilation included
 
 
@@ -80,103 +70,6 @@ def cache_entries(path: str) -> int:
     if not os.path.isdir(path):
         return 0
     return sum(1 for n in os.listdir(path) if not n.endswith("-atime"))
-
-
-def lane_evidence(trees) -> dict:
-    """{operator: {device_lane_batches, host_lane_batches}} summed over
-    the task metric trees of one run."""
-    per_op: dict = {}
-    for node in _all_nodes(trees):
-        vals = node.get("values") or {}
-        dev = int(vals.get("device_lane_batches", 0))
-        host = int(vals.get("host_lane_batches", 0))
-        if dev or host:
-            slot = per_op.setdefault(node.get("name") or "?",
-                                     {"device_lane_batches": 0,
-                                      "host_lane_batches": 0})
-            slot["device_lane_batches"] += dev
-            slot["host_lane_batches"] += host
-    return per_op
-
-
-EVIDENCE_KEYS = (
-    "total_compiles", "h2d_bytes", "d2h_bytes",
-    "stage_loop_tasks", "stage_loop_batches", "stage_loop_fallbacks",
-    "stage_loop_regrows", "stage_loop_reserves", "stage_loop_rehash_lanes",
-    "shuffle_device_exchanges",
-    "shuffle_device_fallbacks", "shuffle_host_bytes",
-    "unexpected_fallbacks", "partial_agg_skip_events")
-
-
-def run_leg(name: str, run):
-    """One timed run of an engine phase.  Prints its wall, counter
-    deltas, lane evidence and fallback reasons, checks them, and returns
-    (wall, programs compiled)."""
-    from blaze_tpu.bridge import profiling, xla_stats
-    seen = {id(t) for t in profiling.recent_metrics()}
-    reasons_before = xla_stats.stage_loop_fallback_reasons()
-    before = xla_stats.snapshot()
-    t0 = time.perf_counter()
-    run()
-    wall = time.perf_counter() - t0
-    delta = xla_stats.delta(before)
-    trees = [t for t in profiling.recent_metrics() if id(t) not in seen]
-    lanes = lane_evidence(trees)
-    reasons = {r: c - reasons_before.get(r, 0)
-               for r, c in xla_stats.stage_loop_fallback_reasons().items()
-               if c - reasons_before.get(r, 0)}
-    ev = {k: int(delta.get(k, 0)) for k in EVIDENCE_KEYS}
-    ev["mxu_verify_fallback"] = sum(
-        int((t.get("values") or {}).get("mxu_verify_fallback", 0))
-        for t in _all_nodes(trees))
-    say(f"  [{name}] wall {wall:.2f}s  compiled {ev['total_compiles']}  "
-        f"h2d {ev['h2d_bytes']}B  d2h {ev['d2h_bytes']}B")
-    say(f"  [{name}] counters " + json.dumps(ev))
-    say(f"  [{name}] lanes " + json.dumps(lanes))
-    say(f"  [{name}] stage_loop_fallback_reasons " + json.dumps(reasons))
-    check_leg(name, ev, lanes, reasons)
-    return wall, ev["total_compiles"]
-
-
-def check_leg(name: str, ev: dict, lanes: dict, reasons: dict) -> None:
-    from blaze_tpu.bridge import xla_stats
-    check(ev["unexpected_fallbacks"] == 0,
-          f"{name}: a device tier fell back on an undeclared error: "
-          f"{xla_stats.fallback_errors()}")
-    for k in ("shuffle_device_fallbacks", "mxu_verify_fallback"):
-        check(ev[k] == 0, f"{name}: {k} = {ev[k]}")
-    # the loop sizes its table for the rows about to reach it, in every
-    # mode: at these sizes no task has a reason to leave it
-    check(not reasons, f"{name}: stage loop fell back for {reasons}")
-    agg = {op: v for op, v in lanes.items() if "Agg" in op}
-    check(sum(v["device_lane_batches"] for v in agg.values()) > 0,
-          f"{name}: the aggregation ran no batch on the device")
-    check(sum(v["host_lane_batches"] for v in agg.values()) == 0,
-          f"{name}: aggregation batches ran on the host lane: {agg}")
-
-
-def _all_nodes(trees):
-    stack = list(trees)
-    while stack:
-        node = stack.pop()
-        yield node
-        stack.extend(node.get("children") or [])
-
-
-def cold_then_warm(name: str, run) -> None:
-    """run() twice; the warm leg must compile nothing."""
-    cold_wall, _ = run_leg(f"{name} cold", run)
-    warm_wall, warm_compiles = run_leg(f"{name} warm", run)
-    check(warm_compiles == 0,
-          f"{name}: the warm run compiled {warm_compiles} programs")
-    say(f"  [{name}] cold {cold_wall:.2f}s -> warm {warm_wall:.2f}s")
-
-
-def make_tables(names, scale: float, seed: int) -> dict:
-    from blaze_tpu.itest.tpcds_data import GENERATORS
-    order = sorted(GENERATORS)
-    return {n: GENERATORS[n](scale, seed=seed * 1000 + order.index(n))
-            for n in names}
 
 
 # ---------------------------------------------------------------------------
@@ -294,78 +187,6 @@ def phase_kernels(seed: int) -> None:
           f"{said!r}")
 
 
-def phase_pair(seed: int, work: str) -> None:
-    import pyarrow.parquet as pq
-
-    import bench
-    say(f"-- q01 stage pair, SF{PAIR_SF:g}, {PARTITIONS} map x "
-        f"{PARTITIONS} reduce, TaskDefinition bytes -> "
-        f"NativeExecutionRuntime")
-    tables = make_tables(["store_returns", "date_dim"], PAIR_SF, seed)
-    sr = tables["store_returns"]
-    per = -(-sr.num_rows // PARTITIONS)
-    sr_paths = []
-    for i in range(PARTITIONS):
-        path = os.path.join(work, f"store_returns_{i}.parquet")
-        pq.write_table(sr.slice(i * per, per), path, row_group_size=1 << 16)
-        sr_paths.append(path)
-    dd_path = os.path.join(work, "date_dim.parquet")
-    pq.write_table(tables["date_dim"], dd_path)
-    want_groups, want_sum = bench.run_baseline(sr_paths, dd_path)
-    say(f"  {sr.num_rows} store_returns rows in {PARTITIONS} files; "
-        f"oracle: {want_groups} groups, sum {want_sum!r}")
-
-    def run():
-        shuffle_dir = tempfile.mkdtemp(prefix="shuffle-", dir=work)
-        groups, total = bench.run_engine(sr_paths, dd_path, shuffle_dir,
-                                         PARTITIONS, PARTITIONS)
-        say(f"  engine: {groups} groups, sum {total!r}")
-        check(groups == want_groups,
-              f"pair: {groups} groups, oracle {want_groups}")
-        check(abs(total - want_sum) <= 1e-9 * abs(want_sum),
-              f"pair: sum {total!r}, oracle {want_sum!r}")
-
-    cold_then_warm("pair", run)
-
-
-def phase_query(qname: str, seed: int, work: str) -> None:
-    from blaze_tpu import config
-    from blaze_tpu.itest.queries import QUERIES
-    from blaze_tpu.itest.runner import compare_frames
-    from blaze_tpu.itest.tpcds_data import write_parquet_splits
-    from blaze_tpu.plan.stages import DagScheduler
-    sf = QUERY_SF[qname]
-    say(f"-- itest {qname}, SF{sf:g}, {PARTITIONS} partitions, "
-        f"DagScheduler.run_collect")
-    builder, names = QUERIES[qname]
-    tables = make_tables(names, sf, seed)
-    paths = write_parquet_splits(tables, os.path.join(work, qname),
-                                 PARTITIONS)
-    plan, oracle = builder(paths, tables, PARTITIONS)
-    scanned = DagScheduler._scan_input_bytes(plan)
-    say(f"  scans {scanned} bytes of parquet "
-        f"(singleTaskBytes {config.DAG_SINGLE_TASK_BYTES.get()})")
-    want = oracle()
-    legs = []
-
-    def run():
-        with DagScheduler() as sched:
-            got = sched.run_collect(plan)
-            mode, n_stages = sched.exec_mode, len(sched.stages)
-            placement = dict(sched.stage_placement)
-        say(f"  mode {mode}, {n_stages} stages, {got.num_rows} rows; "
-            f"stage placement {json.dumps(placement, sort_keys=True)}")
-        check(mode == "staged" and n_stages > 1,
-              f"{qname}: took the single-task shortcut ({mode}, "
-              f"{n_stages} stages)")
-        err = compare_frames(got.to_pandas(), want)
-        check(err is None, f"{qname}: differs from the pandas oracle: {err}")
-        legs.append(got)
-
-    cold_then_warm(qname, run)
-    check(legs[0].equals(legs[1]), f"{qname}: cold and warm results differ")
-
-
 # ---------------------------------------------------------------------------
 
 def main(argv=None) -> int:
@@ -399,7 +220,6 @@ def main(argv=None) -> int:
     cache_events = CacheEvents()
 
     import blaze_tpu
-    from blaze_tpu.bridge import native
     from blaze_tpu.bridge.placement import ensure_placement
     say(f"jax {jax.__version__}  platform {d0.platform}  device_kind "
         f"{d0.device_kind!r}  devices {len(devices)}")
@@ -408,43 +228,18 @@ def main(argv=None) -> int:
     say(f"compile cache {cache_dir} (JAX_COMPILATION_CACHE_DIR "
         f"{'set' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'unset'}"
         f"): {entries_before} entries")
-    try:
-        built = native.build_native_libs()
-    except native.NativeBuildError as e:
-        built = (f"NOT BUILT ({e}); pure-Python paths: zstd codec in "
-                 f"Python, numpy partition ids, pyarrow host group-by")
-    say(f"native libraries: {built}; loaded "
-        f"{json.dumps(native.loaded_libraries())}")
-
     pi = ensure_placement()
     say(f"placement: device_kind {pi.device_kind!r} default_platform "
         f"{pi.default_platform!r} dispatch_rtt_ms {pi.rtt_ms:.3f} policy "
         f"{pi.policy!r}")
-    check(pi.policy == "auto", "a placement policy is set; the smoke tests "
+    check(pi.policy == "auto", "a placement policy is set; the smoke runs "
                                "the defaults")
     check(pi.device_kind == "tpu",
           f"auto placed stage compute on {pi.device_kind!r}, not the chip "
           f"(dispatch RTT {pi.rtt_ms:.2f} ms)")
 
-    with tempfile.TemporaryDirectory(prefix="chip-smoke-") as work:
-        if "kernels" in phases:
-            phase_kernels(args.seed)
-        if "pair" in phases:
-            phase_pair(args.seed, work)
-        before = _shuffle_device_exchanges()
-        for qname in ("q01", "q06"):
-            if qname in phases:
-                phase_query(qname, args.seed, work)
-        exchanges = _shuffle_device_exchanges() - before
-
-    if len(devices) == 1:
-        say("mesh leg: skipped (1 device)")
-    elif {"q01", "q06"} & set(phases):
-        say(f"mesh leg: {len(devices)} devices, shuffle_device_exchanges "
-            f"{exchanges}")
-        check(exchanges >= 1, "no query shuffle went over the mesh")
-    else:
-        say("mesh leg: skipped (no query phase selected)")
+    if "kernels" in phases:
+        phase_kernels(args.seed)
     for d in devices:
         say(f"device {d.id} peak_bytes_in_use "
             f"{d.memory_stats()['peak_bytes_in_use']}")
@@ -456,11 +251,6 @@ def main(argv=None) -> int:
         "platform": d0.platform, "kind": d0.device_kind,
         "count": len(devices)}}))
     return 0
-
-
-def _shuffle_device_exchanges() -> int:
-    from blaze_tpu.bridge import xla_stats
-    return int(xla_stats.shuffle_stats()["shuffle_device_exchanges"])
 
 
 if __name__ == "__main__":

@@ -6,7 +6,9 @@ without a cluster, SURVEY.md §4).
 The platform is forced here, in code, so that a bare `pytest` on a machine
 with a chip attached still runs the suite on the host and never takes the
 chip; `JAX_PLATFORMS=cpu` in the environment says the same thing.  The
-chip is exercised by `python chip_smoke.py`, not by pytest.
+chip is not pytest's: the engine path is proven there by a benchmark
+cell's run (`python3 benchmark/run.py --workload <cell> ...`, every
+answer compared), the kernels alone by `python chip_smoke.py`.
 """
 
 import os

@@ -1,6 +1,7 @@
 """bridge/compile_share.py: a single-device program is compiled once and
 kept once in the persistent cache, whichever device asks for it."""
 
+import os
 import threading
 import time
 
@@ -35,10 +36,18 @@ def counts():
 
 
 def _fresh_program():
-    """A program no cache has seen: the salt is a constant in its text."""
-    salt = time.time_ns()
-    return jax.jit(lambda x: (jnp.sort(x) * 3 + salt % 1009).sum()), \
-        lambda x: (np.sort(x) * 3 + salt % 1009).sum()
+    """A program no cache has seen: the clock's nanoseconds and the
+    process id, digit by digit, are constants in its text (small
+    integers, so the float sums stay exact in any order)."""
+    salt = [int(d) for d in f"{time.time_ns()}{os.getpid()}"]
+
+    def body(xp, x):
+        y = xp.sort(x) * 3
+        for d in salt:
+            y = y + d
+        return y.sum()
+
+    return jax.jit(lambda x: body(jnp, x)), lambda x: body(np, x)
 
 
 def test_the_second_device_loads_what_the_first_compiled(counts):

@@ -560,8 +560,7 @@ def test_decimal_zero_steady_state_recompiles(tmp_path, staged_path):
 def test_knob_off_eviction_accounting(tmp_path, staged_path):
     """With the decimal knob off the boundary stays on the host file
     shuffle — and the stats plane records WHY (decimal_column), which is
-    what the advisor's host_eviction finding and the bench placement
-    report key off."""
+    what the advisor's host_eviction finding keys off."""
     plan = _decimal_plan(tmp_path, _decimal_table(seed=31, precision=8), 8, 2,
                          tag="ev")
     clean = _run_clean(tmp_path, plan)

@@ -1,7 +1,6 @@
 """Fault-site conformance: every registered chaos site must be
-exercised somewhere — by a test or by a bench chaos rule — so a new
-site cannot land without coverage and a renamed site cannot silently
-orphan its tests."""
+exercised by a test, so a new site cannot land without coverage and a
+renamed site cannot silently orphan its tests."""
 
 import os
 import re
@@ -9,7 +8,6 @@ import re
 from blaze_tpu import faults
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_REPO = os.path.dirname(_HERE)
 
 
 def _corpus() -> str:
@@ -21,8 +19,6 @@ def _corpus() -> str:
             continue  # self-references must not count as coverage
         with open(os.path.join(_HERE, name)) as f:
             chunks.append(f.read())
-    with open(os.path.join(_REPO, "bench.py")) as f:
-        chunks.append(f.read())
     return "\n".join(chunks)
 
 
@@ -36,9 +32,9 @@ def test_every_fault_site_is_exercised():
                          corpus):
             missing.append(site)
     assert not missing, (
-        f"fault sites with no test or bench coverage: {missing} — add a "
-        f"test exercising faults at the site (faults.scoped / "
-        f"faults.configure) or a bench chaos rule naming it")
+        f"fault sites with no test coverage: {missing} — add a test "
+        f"exercising faults at the site (faults.scoped / "
+        f"faults.configure)")
 
 
 def test_sites_registry_matches_docstring():

@@ -2,7 +2,7 @@
 declares in `profiling.ROUTES` answers with its documented status, a
 correct Content-Type, and a parseable body — including the new
 /stats, /progress and /query/<qid>/bottleneck endpoints — plus the
-`tools.top` CLI against a live server and the tools/ci_check.sh gate.
+`tools.top` CLI against a live server.
 """
 
 import json
@@ -176,14 +176,3 @@ def test_top_cli_errors_cleanly_without_server():
     assert out.returncode == 1
     assert "no response" in out.stderr
 
-
-def test_ci_check_script_is_wired():
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    script = os.path.join(repo, "tools", "ci_check.sh")
-    assert os.path.exists(script)
-    assert os.access(script, os.X_OK), "tools/ci_check.sh not executable"
-    subprocess.run(["bash", "-n", script], check=True)
-    with open(script) as f:
-        text = f.read()
-    assert "blaze_tpu.tools.sentinel" in text and "--ci" in text
-    assert "pytest" in text

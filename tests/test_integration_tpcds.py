@@ -82,8 +82,9 @@ def test_wire_query_on_real_accelerator():
     """Device-placement wire path on REAL accelerator hardware: q52
     through DagScheduler with auron.tpu.placement=device.  conftest.py
     pins pytest to the CPU platform, so this skips everywhere pytest
-    runs; the chip's proof of the same path is `python chip_smoke.py`
-    (q01 SF10 and q06 SF1 through DagScheduler, CHANGES.md PR 21)."""
+    runs; the chip's proof of the same path is a benchmark cell's run
+    (`sf10_q01_x1` and `sf1_q06_x1` go through DagScheduler and compare
+    every answer; `chip_smoke.py` proves the kernels alone)."""
     import jax
 
     from blaze_tpu import config

@@ -1,41 +1,12 @@
-"""Regression sentinel (blaze_tpu/tools/sentinel.py) and the unified
-bench-artifact schema (blaze_tpu/tools/bench_schema.py): envelope
-fields, direction inference, noise floors, and the CI exit-code
-contract the bench trajectory depends on."""
+"""Regression sentinel (blaze_tpu/tools/sentinel.py) over saved
+/history/rollup payloads: direction inference, noise floors, and the
+exit-code contract."""
 
 import json
-import os
 
 import pytest
 
 from blaze_tpu.tools import sentinel
-from blaze_tpu.tools.bench_schema import (BENCH_SCHEMA_VERSION,
-                                          ENVELOPE_KEYS, bench_envelope,
-                                          write_bench_artifact)
-
-
-# -- unified bench envelope --------------------------------------------------
-
-def test_envelope_carries_schema_git_and_host():
-    env = bench_envelope()
-    for k in ENVELOPE_KEYS:
-        assert k in env, k
-    assert env["schema_version"] == BENCH_SCHEMA_VERSION
-    assert env["git_sha"]  # sha or "unknown", never empty
-    assert env["host"]["python"]
-    assert env["host"]["cpu_count"] >= 1
-
-
-def test_write_bench_artifact_wraps_and_leg_keys_win(tmp_path):
-    path = str(tmp_path / "BENCH_X.json")
-    merged = write_bench_artifact(path, {"metric": "m", "value": 7,
-                                         "git_sha": "leg-override"})
-    with open(path) as f:
-        on_disk = json.load(f)
-    assert on_disk == json.loads(json.dumps(merged, default=str))
-    assert on_disk["schema_version"] == BENCH_SCHEMA_VERSION
-    assert on_disk["value"] == 7
-    assert on_disk["git_sha"] == "leg-override"  # leg keys win
 
 
 # -- direction inference / flatten -------------------------------------------
@@ -55,8 +26,8 @@ def test_metric_direction(key, want):
     assert sentinel.metric_direction(key) == want
 
 
-def test_flatten_skips_envelope_and_bools():
-    rec = {"schema_version": 1, "git_sha": "abc", "host": {"cpu_count": 8},
+def test_flatten_skips_version_tag_and_bools():
+    rec = {"schema_version": 1, "status": "done",
            "value": 2.5, "nested": {"ok": True, "n": 3},
            "list": [1.0, {"x": 4}]}
     flat = sentinel.flatten(rec)
@@ -68,26 +39,28 @@ def test_flatten_skips_envelope_and_bools():
 
 def _write(tmp_path, name, rec):
     path = str(tmp_path / name)
-    write_bench_artifact(path, rec)
+    with open(path, "w") as f:
+        json.dump(rec, f)
     return path
 
 
-BASE = {"metric": "m", "q01": {"wall_s": 1.0, "rows_per_sec": 1000.0},
+BASE = {"schema_version": 1,
+        "q01": {"wall_s": 1.0, "rows_per_sec": 1000.0},
         "oddball": 10.0}
 
 
 def test_identical_artifacts_exit_zero(tmp_path, capsys):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
-    c = _write(tmp_path, "BENCH_B.json", dict(BASE))
+    b = _write(tmp_path, "rollup_before.json", BASE)
+    c = _write(tmp_path, "rollup_after.json", dict(BASE))
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--ci"]) == 0
     assert "0 regression(s)" in capsys.readouterr().out
 
 
 def test_regression_exits_two_and_names_metric(tmp_path, capsys):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
+    b = _write(tmp_path, "rollup_before.json", BASE)
     worse = {**BASE, "q01": {"wall_s": 1.5, "rows_per_sec": 1000.0}}
-    c = _write(tmp_path, "BENCH_B.json", worse)
+    c = _write(tmp_path, "rollup_after.json", worse)
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--threshold", "0.10"]) == 2
     out = capsys.readouterr().out
@@ -96,98 +69,72 @@ def test_regression_exits_two_and_names_metric(tmp_path, capsys):
 
 
 def test_improvement_does_not_fail(tmp_path):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
+    b = _write(tmp_path, "rollup_before.json", BASE)
     better = {**BASE, "q01": {"wall_s": 0.5, "rows_per_sec": 2000.0}}
-    c = _write(tmp_path, "BENCH_B.json", better)
+    c = _write(tmp_path, "rollup_after.json", better)
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--threshold", "0.10"]) == 0
 
 
 def test_throughput_drop_regresses(tmp_path, capsys):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
+    b = _write(tmp_path, "rollup_before.json", BASE)
     worse = {**BASE, "q01": {"wall_s": 1.0, "rows_per_sec": 500.0}}
-    c = _write(tmp_path, "BENCH_B.json", worse)
+    c = _write(tmp_path, "rollup_after.json", worse)
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--threshold", "0.10"]) == 2
     assert "q01.rows_per_sec" in capsys.readouterr().out
 
 
 def test_unknown_direction_fails_on_drift_either_way(tmp_path):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
-    c = _write(tmp_path, "BENCH_B.json", {**BASE, "oddball": 20.0})
+    b = _write(tmp_path, "rollup_before.json", BASE)
+    c = _write(tmp_path, "rollup_after.json", {**BASE, "oddball": 20.0})
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--threshold", "0.10"]) == 2
 
 
 def test_change_within_threshold_passes(tmp_path):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
+    b = _write(tmp_path, "rollup_before.json", BASE)
     mild = {**BASE, "q01": {"wall_s": 1.05, "rows_per_sec": 1000.0}}
-    c = _write(tmp_path, "BENCH_B.json", mild)
+    c = _write(tmp_path, "rollup_after.json", mild)
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--threshold", "0.10"]) == 0
 
 
 def test_abs_floor_suppresses_tiny_changes(tmp_path):
-    b = _write(tmp_path, "BENCH_A.json", {"tiny": 1e-9})
-    c = _write(tmp_path, "BENCH_B.json", {"tiny": 5e-9})  # +400% but tiny
+    b = _write(tmp_path, "rollup_before.json", {"tiny": 1e-9})
+    c = _write(tmp_path, "rollup_after.json", {"tiny": 5e-9})  # +400% but tiny
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--threshold", "0.10"]) == 0
 
 
 def test_missing_metric_fails_only_in_ci_mode(tmp_path):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
+    b = _write(tmp_path, "rollup_before.json", BASE)
     dropped = {k: v for k, v in BASE.items() if k != "oddball"}
-    c = _write(tmp_path, "BENCH_B.json", dropped)
+    c = _write(tmp_path, "rollup_after.json", dropped)
     args = ["--baseline", b, "--candidate", c, "--threshold", "0.10"]
     assert sentinel.main(args) == 0
     assert sentinel.main(args + ["--ci"]) == 2
 
 
-def test_schema_version_mismatch_fails_in_ci(tmp_path):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
-    c = _write(tmp_path, "BENCH_B.json",
-               {**BASE, "schema_version": BENCH_SCHEMA_VERSION + 1})
-    args = ["--baseline", b, "--candidate", c]
-    assert sentinel.main(args) == 0  # tolerated outside CI
-    assert sentinel.main(args + ["--ci"]) == 2
-
-
 def test_unloadable_input_exits_one(tmp_path):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
+    b = _write(tmp_path, "rollup_before.json", BASE)
     assert sentinel.main(["--baseline", b,
                           "--candidate", str(tmp_path / "nope.json")]) == 1
 
 
 def test_metrics_filter_limits_the_diff(tmp_path):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
+    b = _write(tmp_path, "rollup_before.json", BASE)
     worse = {**BASE, "q01": {"wall_s": 1.5, "rows_per_sec": 1000.0}}
-    c = _write(tmp_path, "BENCH_B.json", worse)
+    c = _write(tmp_path, "rollup_after.json", worse)
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--threshold", "0.10",
                           "--metrics", "oddball*"]) == 0
 
 
-def test_directory_mode_merges_by_stem(tmp_path):
-    base_dir = tmp_path / "base"
-    cand_dir = tmp_path / "cand"
-    for d in (base_dir, cand_dir):
-        os.makedirs(d)
-    _write(base_dir, "BENCH_EXPR.json", {"wall_s": 1.0})
-    _write(base_dir, "BENCH_SERVE.json", {"qps": 100.0})
-    _write(cand_dir, "BENCH_EXPR.json", {"wall_s": 2.0})  # regressed
-    _write(cand_dir, "BENCH_SERVE.json", {"qps": 100.0})
-    findings = sentinel.compare(sentinel.load(str(base_dir)),
-                                sentinel.load(str(cand_dir)),
-                                threshold=0.10)
-    regressed = [f["metric"] for f in findings
-                 if f["kind"] == "regression"]
-    assert regressed == ["EXPR.wall_s"]
-
-
 def test_json_report_mode(tmp_path, capsys):
-    b = _write(tmp_path, "BENCH_A.json", BASE)
+    b = _write(tmp_path, "rollup_before.json", BASE)
     worse = {**BASE, "q01": {"wall_s": 1.5, "rows_per_sec": 1000.0}}
-    c = _write(tmp_path, "BENCH_B.json", worse)
+    c = _write(tmp_path, "rollup_after.json", worse)
     assert sentinel.main(["--baseline", b, "--candidate", c,
                           "--threshold", "0.10", "--json"]) == 2
     report = json.loads(capsys.readouterr().out)

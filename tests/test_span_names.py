@@ -1,6 +1,6 @@
 """Span-name conformance: the tracing registry (tracing.SPAN_NAMES) is
 the contract for the whole observability surface.  Every registered
-name must be exercised by a test (or the bench obs leg), documented in
+name must be exercised by a test, documented in
 docs/observability.md, and actually emitted somewhere in the engine —
 so a new span cannot land without coverage or docs, and a renamed or
 removed emitter cannot silently orphan its registry entry.  Mirrors
@@ -30,8 +30,6 @@ def _corpus() -> str:
             continue  # self-references must not count as coverage
         with open(os.path.join(_HERE, name)) as f:
             chunks.append(f.read())
-    with open(os.path.join(_REPO, "bench.py")) as f:
-        chunks.append(f.read())
     return "\n".join(chunks)
 
 
@@ -69,7 +67,7 @@ def test_every_span_name_is_exercised():
         if not ok:
             missing.append(name)
     assert not missing, (
-        f"span names with no test or bench coverage: {missing} — add a "
+        f"span names with no test coverage: {missing} — add a "
         f"test that emits or asserts on the span (see tests/"
         f"test_tracing.py)")
 

@@ -14,7 +14,7 @@ import pyarrow.parquet as pq
 import pytest
 
 from blaze_tpu import config, faults
-from blaze_tpu.bridge import xla_stats
+from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.tasks import run_tasks
 from blaze_tpu.faults import (FetchFailedError, WorkerCrashed,
                               classify_exception, parse_rules)
@@ -23,6 +23,7 @@ from blaze_tpu.parallel import workers
 from blaze_tpu.parallel.workers import (RemoteTaskError, WorkerPool,
                                         WorkerPoolUnavailable, _recv_msg,
                                         _send_msg)
+from blaze_tpu.plan import statstore
 from blaze_tpu.plan.stages import DagScheduler, Stage
 
 ECHO = "blaze_tpu.parallel.workers:_task_echo"
@@ -39,7 +40,11 @@ def clean_slate():
     finally:
         faults.clear()
         workers.shutdown_pool(wait=False)
-        for key in ("auron.tpu.workers.enable", "auron.tpu.workers.count",
+        tracing.stop_tracing()
+        with tracing._lock:
+            tracing._spans.clear()
+        for key in ("auron.tpu.stats.enable", "auron.tpu.stats.dir",
+                    "auron.tpu.workers.enable", "auron.tpu.workers.count",
                     "auron.tpu.workers.heartbeatMs",
                     "auron.tpu.workers.livenessMs",
                     "auron.tpu.workers.crashBudget",
@@ -48,6 +53,7 @@ def clean_slate():
                     "auron.tpu.task.retryBackoffMs",
                     "auron.tpu.task.maxAttempts"):
             config.conf.unset(key)
+        statstore.reset_conf_probe()
 
 
 def _pool(count=2, **kw) -> WorkerPool:
@@ -332,27 +338,50 @@ def _enable_workers(count=2):
     config.conf.set(config.WORKERS_RESTART_BACKOFF_MS.key, 10)
 
 
-def test_staged_query_through_pool_bit_identical(tmp_path):
+@pytest.mark.parametrize("observed", ["plain", "traced", "stats"])
+def test_staged_query_through_pool_bit_identical(tmp_path, observed):
+    """The pool changes where a task runs, never the answer; and neither
+    does watching it: with the span tracer on (child spans ride the
+    frames home) or the statistics store on (the run merges into its
+    fingerprint's record) the frame is the plain run's, bit for bit."""
     plan = _two_stage_plan(tmp_path)
     config.conf.set(config.DAG_SINGLE_TASK_BYTES.key, 0)
     clean = _sorted_df(DagScheduler(
         work_dir=str(tmp_path / "dag0")).run_collect(plan))
     _enable_workers()
     xla_stats.reset()
+    if observed == "traced":
+        tracing.start_tracing()
+    elif observed == "stats":
+        config.conf.set(config.STATS_ENABLE.key, "on")
+        config.conf.set(config.STATS_DIR.key, str(tmp_path / "stats"))
+        statstore.reset_conf_probe()
     sched = DagScheduler(work_dir=str(tmp_path / "dag1"))
     got = _sorted_df(sched.run_collect(plan))
     assert got.equals(clean)
+    if observed == "stats":
+        again = DagScheduler(work_dir=str(tmp_path / "dag2"))
+        assert _sorted_df(again.run_collect(plan)).equals(clean)
+        assert statstore.prior(again.stats_fingerprint)["run_count"] == 2
     ws = xla_stats.worker_stats()
-    assert ws["worker_tasks"] == 2  # both map tasks process-isolated
+    # both map tasks process-isolated (twice where the plan ran twice)
+    assert ws["worker_tasks"] == (4 if observed == "stats" else 2)
+    if observed == "traced":
+        assert [r for r in tracing.spans() if r["name"] == "worker_task"]
+        assert xla_stats.snapshot()["obs_spans_ingested"] > 0
     # per-task metric trees rode the result frames home
     assert sched.stage_metrics[0].to_dict()
     assert all(v == [] for v in sched.leak_report().values())
 
 
-def test_sigkill_mid_map_task_recovers_via_retry(tmp_path):
+@pytest.mark.parametrize("entry", ["scheduler", "service"])
+def test_sigkill_mid_map_task_recovers_via_retry(tmp_path, entry):
     """SIGKILL mid-shuffle-write: tmp+os.replace commit means NO
     committed partial output exists, the retry (on another worker)
-    produces the whole output, and the query is bit-identical."""
+    produces the whole output, and the query is bit-identical, handed
+    to the scheduler directly or admitted through the query service
+    (which must complete it and stay open for the next query)."""
+    from blaze_tpu.serving import QueryService
     plan = _two_stage_plan(tmp_path)
     config.conf.set(config.DAG_SINGLE_TASK_BYTES.key, 0)
     clean = _sorted_df(DagScheduler(
@@ -360,14 +389,27 @@ def test_sigkill_mid_map_task_recovers_via_retry(tmp_path):
     _enable_workers()
     xla_stats.reset()
     with faults.scoped(("worker-crash", dict(at=(1,)))):
-        sched = DagScheduler(work_dir=str(tmp_path / "dag1"))
-        got = _sorted_df(sched.run_collect(plan))
+        if entry == "scheduler":
+            sched = DagScheduler(work_dir=str(tmp_path / "dag1"))
+            got = _sorted_df(sched.run_collect(plan))
+            leaks = sched.leak_report()
+        else:
+            with QueryService(max_concurrent=2) as svc:
+                victim = svc.submit(plan, tenant="a")
+                other = svc.submit(plan, tenant="b")
+                assert victim.exception(timeout=120) is None
+                assert other.exception(timeout=120) is None
+                assert (victim.status, other.status) == ("done", "done")
+                got = _sorted_df(victim.result())
+                assert _sorted_df(other.result()).equals(clean)
+                leaks = victim.leak_report
     assert got.equals(clean)
     ws = xla_stats.worker_stats()
     assert ws["worker_crashes"] == 1
-    assert ws["worker_tasks"] == 3  # 2 map tasks + 1 crash retry
+    # 2 map tasks a query + 1 crash retry
+    assert ws["worker_tasks"] == (3 if entry == "scheduler" else 5)
     # leak_report clean after a crash-recovered query
-    assert all(v == [] for v in sched.leak_report().values())
+    assert all(v == [] for v in leaks.values())
     # the wave retried in place (different worker) — no lineage round
     # was needed because nothing poisoned was ever committed
     assert xla_stats.fault_stats()["task_retries"] >= 1
