@@ -160,6 +160,13 @@ SPAN_NAMES: Dict[str, str] = {
     "smj_merge": "one partition's sort-merge join as device programs: "
                  "bounds, expansion, gather (ops/joins/merge.py; attrs "
                  "rows of both sides, pairs)",
+    "window_device": "one sorted run's window functions: flags and every "
+                     "function's scan, in ONE device program where the run "
+                     "stayed on the chip (lane=resident; no d2h inside), "
+                     "in numpy over Arrow where it did not (lane=host, a "
+                     "partition-aligned chunk a span) (ops/window.py; "
+                     "attrs lane, rows, functions, partitions: the "
+                     "sorted runs covered, 1, not SQL partitions)",
     "agg_drain": "an aggregation table read back and turned into an "
                  "Arrow batch (plan/fused.py _emit_*; attrs table)",
     "partial_passthrough": "one chunk of a partial aggregation that "

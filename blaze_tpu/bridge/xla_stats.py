@@ -154,6 +154,19 @@ _sortmerge = {"sort_device_rows": 0, "sort_resident_rows": 0,
               "smj_device_rows": 0, "smj_device_pairs": 0,
               "smj_streamed_runs": 0}
 
+# Window functions (ops/window.py): rows a `WindowExec` computed its
+# functions over and, of those, the rows of sorted runs that never left the
+# chip (the resident lane: flags from the key columns and every function's
+# scan in one program, kernels/window.py); `window_partitions`: sorted RUNS
+# handed to a lane (a task's partition of the exchange whole in the
+# resident lane, a flushed chunk of it in the host lane), not SQL
+# `PARTITION BY` groups, which the resident lane never reads back to count;
+# and the least bytes the resident lane's scans had to move (key and
+# argument columns read once, results written once).  By chip in
+# `chip_stats()` too.
+_window = {"window_rows": 0, "window_resident_rows": 0,
+           "window_partitions": 0, "window_scan_bytes": 0}
+
 # The hash joins' probe side (ops/joins/exec.py): probe rows handed to a
 # join whose batches stay on the chip (`kernels/join.probe_gather`: inner,
 # unique fixed-width build key) and to every other join, whose pairs and
@@ -461,6 +474,7 @@ def _chip_entry(chip: int) -> Dict[str, int]:
                                 "stage_loop_lanes": 0,
                                 "stage_loop_decimal_rows": 0,
                                 "sort_resident_rows": 0,
+                                **{k: 0 for k in _window},
                                 **{k: 0 for k in _CHIP_TABLE_KEYS}}
     return entry
 
@@ -529,9 +543,10 @@ def chip_stats() -> Dict[int, Dict[str, int]]:
     "join_probe_device_rows", "join_probe_host_rows",
     "join_probe_direct_rows",
     "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes",
-    "stage_loop_decimal_rows", "sort_resident_rows" and the stage loop's
-    table counters (_CHIP_TABLE_KEYS)} since the last reset: what each chip
-    was given to do."""
+    "stage_loop_decimal_rows", "sort_resident_rows", the four window
+    counters (`_window`) and the stage loop's table counters
+    (_CHIP_TABLE_KEYS)} since the last reset: what each chip was given to
+    do."""
     with _lock:
         return {chip: dict(e) for chip, e in sorted(_chips.items())}
 
@@ -1109,6 +1124,26 @@ def sortmerge_stats() -> dict:
         return dict(_sortmerge)
 
 
+def note_window(rows: int, chip: int, resident: bool,
+                scan_bytes: int = 0) -> None:
+    """A `WindowExec` lane computed its functions over one sorted run of
+    `rows` rows on `chip`; `resident`: the run never left the chip, and
+    its scans had `scan_bytes` to move at the least."""
+    deltas = {"window_rows": rows, "window_partitions": 1,
+              "window_resident_rows": rows if resident else 0,
+              "window_scan_bytes": scan_bytes}
+    with _lock:
+        entry = _chip_entry(chip)
+        for k, v in deltas.items():
+            _window[k] += int(v)
+            entry[k] += int(v)
+
+
+def window_stats() -> dict:
+    with _lock:
+        return dict(_window)
+
+
 def note_stream_epoch(wall_ns: int, rows: int = 0,
                       records: int = 0) -> None:
     """One committed micro-batch epoch: wall time, sink rows emitted,
@@ -1275,6 +1310,7 @@ def snapshot() -> dict:
     flat.update(shuffle_stats())
     flat.update(stage_loop_stats())
     flat.update(sortmerge_stats())
+    flat.update(window_stats())
     flat.update(join_stats())
     flat.update(stream_stats())
     flat.update(worker_stats())
@@ -1318,6 +1354,8 @@ def reset() -> None:
             _stage_loop[k] = 0
         for k in _sortmerge:
             _sortmerge[k] = 0
+        for k in _window:
+            _window[k] = 0
         for k in _join:
             _join[k] = 0
         for k in _stream:

@@ -8,17 +8,19 @@ TPU-first: the input arrives sorted by (partition keys, order keys) —
 Spark plans a SortExec under every window — so all processors become
 vectorized prefix scans over segment structure: partition boundaries ->
 cumsum segment ids, rank = position of the last order-key change, running
-aggregates = segmented cumulative sums.  No per-row state machine; one
-fused device pass per batch set.
+aggregates = segmented cumulative sums.  No per-row state machine.  A
+partition that arrives on the chip stays there: flags and every function's
+scan are one device program (`WindowExec`'s resident lane,
+kernels/window.py); the host lane runs the same scans in numpy.
 """
 
 from __future__ import annotations
 
 import enum
+import itertools
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
@@ -28,7 +30,8 @@ from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.memory import MemConsumer, try_new_spill
 from blaze_tpu.exprs import PhysicalExpr
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
-from blaze_tpu.ops.sort import host_sort_keys
+from blaze_tpu.ops.sort import (_DEVICE_KEY_TYPES, _DEVICE_SORT_ROWS,
+                                host_sort_keys)
 from blaze_tpu.schema import (DataType, Field, FLOAT64, INT32, INT64, Schema, TypeId)
 
 
@@ -140,6 +143,24 @@ class _WindowBuffer(MemConsumer):
 
 
 class WindowExec(ExecutionPlan):
+    """Two lanes, chosen a partition from what arrives (no option):
+
+    resident  under device placement, where every function has a device
+              form (the rank family, sum / count / min / max / avg over a
+              running or whole-partition frame, a result type the chip
+              holds) and the sorted run arrives as ONE batch of plain
+              fixed-width device columns with device keys and arguments,
+              of 1,024 rows or more (a `SortExec`'s resident run as it
+              is): every function by one `segmented_scan` program
+              (kernels/window.py), the results appended as device columns,
+              `group_limit` as a selection.  One batch in, one batch out:
+              nothing is buffered and nothing is read back.
+    host      everything else (lead / lag / nth_value, a utf8, dictionary
+              or host column, a decimal result past 18 digits, a small
+              run or one of several batches, host placement): Arrow on the
+              host, numpy scans, partition-aligned chunks from a
+              spill-capable buffer.
+    """
 
     def __init__(self, child: ExecutionPlan,
                  funcs: Sequence[WindowFunc],
@@ -157,12 +178,126 @@ class WindowExec(ExecutionPlan):
                 f.agg.bind(in_schema)
         self._out_schema = Schema(
             list(in_schema) + [f.out_field(in_schema) for f in self.funcs])
+        self._scan_funcs = self._device_forms()
 
     @property
     def schema(self) -> Schema:
         return self._out_schema
 
     def execute(self, partition: int) -> BatchIterator:
+        source = iter(self.children[0].execute(partition))
+        if self._scan_funcs is None:
+            yield from self._host_lane(source)
+            return
+        # the resident lane takes a run that IS one batch; a second batch
+        # sends the run through the host lane from its first row
+        head = list(itertools.islice(source, 2))
+        tile = self._device_tile(head[0]) if len(head) == 1 else None
+        if tile is not None and tile[0].num_rows >= _DEVICE_SORT_ROWS:
+            yield self._scan_resident(*tile)
+        else:
+            yield from self._host_lane(itertools.chain(head, source))
+
+    # -- the resident lane ---------------------------------------------------
+    def _device_forms(self) -> Optional[tuple]:
+        """The node's functions as `kernels/window.segmented_scan` takes
+        them, or None where one has no device form or a result the chip
+        does not hold: the node then takes the host lane whole."""
+        from blaze_tpu.ops.agg.functions import (AvgAgg, CountAgg, MinMaxAgg,
+                                                 SumAgg)
+        in_schema = self.children[0].schema
+        forms = []
+        for f in self.funcs:
+            if isinstance(f, RankFunc):
+                forms.append((f.kind.value,))
+                continue
+            if not isinstance(f, WindowAggFunc) or not \
+                    f.out_field(in_schema).data_type.is_fixed_width:
+                return None
+            arg = f.agg.children[0].data_type(in_schema) \
+                if f.agg.children else None
+            if isinstance(f.agg, CountAgg):
+                kind = "count"
+            elif arg is None or arg.id not in _DEVICE_KEY_TYPES:
+                return None
+            elif isinstance(f.agg, MinMaxAgg):
+                kind = "min" if f.agg.minimum else "max"
+            elif arg.id in (TypeId.DATE32, TypeId.TIMESTAMP_MICROS,
+                            TypeId.BOOL):
+                return None         # no sum of a date
+            elif isinstance(f.agg, SumAgg):
+                kind = "sum"
+            elif isinstance(f.agg, AvgAgg) and arg.id != TypeId.DECIMAL:
+                kind = "avg"        # a decimal quotient rounds on the host
+            else:
+                return None
+            forms.append((kind, bool(f.running)))
+        return tuple(forms)
+
+    def _device_tile(self, batch: ColumnBatch):
+        """(`batch` compacted, its partition keys, order keys, arguments)
+        if the device can scan it where it lies, as `ops/sort.py`
+        `_SortState._device_tile` asks for a sort: compute placed there,
+        every column a plain fixed-width device column, every key and
+        argument a device value.  None for anything else."""
+        import jax
+        from blaze_tpu.batch import DeviceColumn
+        from blaze_tpu.bridge.placement import host_resident
+        if host_resident() or not batch.columns or not all(
+                type(c) is DeviceColumn and isinstance(c.data, jax.Array)
+                and c.data.ndim == 1 for c in batch.columns):
+            return None
+        batch = batch.compact()
+
+        def value(expr, types):
+            v = expr.evaluate(batch)
+            if not (v.is_device and v.dictionary is None
+                    and isinstance(v.data, jax.Array)
+                    and v.dtype.id in types
+                    and v.data.shape == (batch.capacity,)):
+                return None
+            return v
+
+        part = [value(e, _DEVICE_KEY_TYPES) for e in self.partition_by]
+        order = [value(e, _DEVICE_KEY_TYPES) for e, _, _ in self.order_by]
+        has_arg = [isinstance(f, WindowAggFunc) and bool(f.agg.children)
+                   for f in self.funcs]
+        args = [value(f.agg.children[0], _DEVICE_KEY_TYPES) if has else None
+                for f, has in zip(self.funcs, has_arg)]
+        if any(v is None for v in part + order) or any(
+                a is None for a, has in zip(args, has_arg) if has):
+            return None
+        return batch, part, order, args
+
+    def _scan_resident(self, batch: ColumnBatch, part, order,
+                       args) -> ColumnBatch:
+        """`batch`'s rows with every function's column appended, all on the
+        device, at the batch's own capacity."""
+        from blaze_tpu.batch import DeviceColumn
+        from blaze_tpu.bridge import tracing, xla_stats
+        from blaze_tpu.bridge.context import current_task
+        from blaze_tpu.kernels import window as kwin
+        rows = batch.num_rows
+        pairs = [[None if v is None else (v.data, v.validity) for v in vs]
+                 for vs in (part, order, args)]
+        with tracing.span("window_device", lane="resident", rows=rows,
+                          partitions=1, functions=len(self.funcs)):
+            out, selection = kwin.segmented_scan(
+                *map(tuple, pairs), np.int32(rows),
+                part_types=tuple(v.dtype for v in part),
+                order_types=tuple(v.dtype for v in order),
+                funcs=self._scan_funcs, group_limit=self.group_limit)
+        xla_stats.note_window(rows, current_task().device_id, True,
+                              kwin.scan_bytes(rows, *pairs, out))
+        return ColumnBatch(
+            self.schema,
+            [DeviceColumn(f.data_type, d, v) for f, (d, v) in zip(
+                self.schema,
+                tuple((c.data, c.validity) for c in batch.columns) + out)],
+            rows, selection)
+
+    # -- the host lane -------------------------------------------------------
+    def _host_lane(self, source) -> BatchIterator:
         # Stream in partition-boundary-aligned chunks: input is sorted by
         # partition_by (the planner places a SortExec below, as Spark does),
         # so once a later partition starts every earlier one is complete and
@@ -177,7 +312,7 @@ class WindowExec(ExecutionPlan):
         prev_last: Optional[tuple] = None  # prior batch's last-row part keys
         last_cut: Optional[int] = None  # buffer-relative last partition start
         try:
-            for b in self.children[0].execute(partition):
+            for b in source:
                 rb = b.compact().to_arrow()
                 if rb.num_rows == 0:
                     continue
@@ -225,41 +360,48 @@ class WindowExec(ExecutionPlan):
 
     # ------------------------------------------------------------------
     def _process(self, rb: pa.RecordBatch) -> List[ColumnBatch]:
+        """One partition-aligned chunk through the host lane: numpy scans
+        over what Arrow holds, whatever the placement."""
+        from blaze_tpu.bridge import tracing, xla_stats
+        from blaze_tpu.bridge.context import current_task
+        with tracing.span("window_device", lane="host", rows=rb.num_rows,
+                          partitions=1, functions=len(self.funcs)):
+            out = self._process_host(rb)
+        xla_stats.note_window(rb.num_rows, current_task().device_id, False)
+        return out
+
+    def _process_host(self, rb: pa.RecordBatch) -> List[ColumnBatch]:
         n = rb.num_rows
-        in_schema = self.children[0].schema
         cb = ColumnBatch.from_arrow(rb)
 
-        xp = _window_xp()
-        part_seg, order_change = self._segments(rb, cb, xp)
-        # positions & per-partition geometry (prefix scans; xp = numpy
-        # on host placement, jnp on device)
-        pos = xp.arange(n, dtype=xp.int64)
-        seg_start = _segment_start(part_seg, pos, xp)
-        row_number = (pos - seg_start + 1).astype(xp.int32)
+        part_seg, order_change = self._segments(rb, cb)
+        # positions & per-partition geometry (prefix scans)
+        pos = np.arange(n, dtype=np.int64)
+        seg_start = _segment_start(part_seg, pos)
+        row_number = (pos - seg_start + 1).astype(np.int32)
         # partition sizes via boundary scatter
-        part_size = _segment_size(part_seg, n, xp)
+        part_size = _segment_size(part_seg, n)
 
         # rank: position of the last (partition-or-order) change before/at row
         change = part_seg | order_change
-        rank_pos = _running_max_where(change, pos, xp)
-        rank_val = (rank_pos - seg_start + 1).astype(xp.int32)
-        dense = _segmented_cumsum(order_change & ~part_seg, part_seg,
-                                  xp).astype(xp.int32) + 1
+        rank_pos = _running_max_where(change, pos)
+        rank_val = (rank_pos - seg_start + 1).astype(np.int32)
+        dense = _segmented_cumsum(order_change & ~part_seg,
+                                  part_seg).astype(np.int32) + 1
 
         out_cols: List[pa.Array] = list(rb.columns)
-        np_part_seg = np.asarray(part_seg)
         for f in self.funcs:
             if isinstance(f, RankFunc):
                 out_cols.append(self._rank_col(f, row_number, rank_val, dense,
                                                part_size, seg_start, change,
-                                               pos, n, xp))
+                                               pos, n))
             elif isinstance(f, LeadLagFunc):
-                out_cols.append(self._lead_lag(f, cb, np_part_seg, n))
+                out_cols.append(self._lead_lag(f, cb, part_seg, n))
             elif isinstance(f, NthValueFunc):
                 out_cols.append(self._nth_value(f, cb, seg_start, part_size, n))
             elif isinstance(f, WindowAggFunc):
-                out_cols.append(self._window_agg(f, cb, rb, part_seg,
-                                                 order_change, n, xp))
+                out_cols.append(self._window_agg(f, cb, part_seg,
+                                                 order_change, n))
             else:
                 raise TypeError(f"unknown window function {f}")
 
@@ -270,8 +412,7 @@ class WindowExec(ExecutionPlan):
         out = pa.RecordBatch.from_arrays(out_cols, schema=out_schema)
         if self.group_limit is not None:
             # window-group-limit: keep rows with rank <= k (proto :600)
-            keep = np.asarray(rank_val) <= self.group_limit
-            out = out.filter(pa.array(keep))
+            out = out.filter(pa.array(rank_val <= self.group_limit))
         return [ColumnBatch.from_arrow(out)]
 
     def _part_keys(self, rb: pa.RecordBatch,
@@ -297,7 +438,7 @@ class WindowExec(ExecutionPlan):
                 part_seg[1:] |= k[1:] != k[:-1]
         return part_seg
 
-    def _segments(self, rb: pa.RecordBatch, cb: ColumnBatch, xp=jnp):
+    def _segments(self, rb: pa.RecordBatch, cb: ColumnBatch):
         """(partition_boundary, order_change) bool arrays over rows."""
         n = rb.num_rows
         part_seg = self._part_boundaries(rb, cb)
@@ -314,27 +455,27 @@ class WindowExec(ExecutionPlan):
                 order_change[1:] |= k[1:] != k[:-1]
         else:
             order_change = np.ones(n, dtype=bool)
-        return xp.asarray(part_seg), xp.asarray(order_change)
+        return part_seg, order_change
 
     def _rank_col(self, f: RankFunc, row_number, rank_val, dense, part_size,
-                  seg_start, change, pos, n, xp=jnp) -> pa.Array:
+                  seg_start, change, pos, n) -> pa.Array:
         k = f.kind
         if k == WindowRankType.ROW_NUMBER:
-            return pa.array(np.asarray(row_number), type=pa.int32())
+            return pa.array(row_number, type=pa.int32())
         if k == WindowRankType.RANK:
-            return pa.array(np.asarray(rank_val), type=pa.int32())
+            return pa.array(rank_val, type=pa.int32())
         if k == WindowRankType.DENSE_RANK:
-            return pa.array(np.asarray(dense), type=pa.int32())
+            return pa.array(dense, type=pa.int32())
         if k == WindowRankType.PERCENT_RANK:
-            denom = xp.maximum(part_size - 1, 1).astype(xp.float64)
-            out = (rank_val.astype(xp.float64) - 1.0) / denom
-            out = xp.where(part_size == 1, 0.0, out)
-            return pa.array(np.asarray(out), type=pa.float64())
+            denom = np.maximum(part_size - 1, 1).astype(np.float64)
+            out = (rank_val.astype(np.float64) - 1.0) / denom
+            out = np.where(part_size == 1, 0.0, out)
+            return pa.array(out, type=pa.float64())
         # CUME_DIST: (last row position with same order value + 1 - start)/size
-        last_same = _next_change_pos(change, pos, n, xp)
-        out = (last_same - seg_start).astype(xp.float64) / \
-            part_size.astype(xp.float64)
-        return pa.array(np.asarray(out), type=pa.float64())
+        last_same = _next_change_pos(change, pos, n)
+        out = (last_same - seg_start).astype(np.float64) / \
+            part_size.astype(np.float64)
+        return pa.array(out, type=pa.float64())
 
     def _lead_lag(self, f: LeadLagFunc, cb: ColumnBatch, part_seg: np.ndarray,
                   n: int) -> pa.Array:
@@ -349,10 +490,9 @@ class WindowExec(ExecutionPlan):
         default = pa.scalar(f.default, type=vals.type)
         return pc.if_else(pa.array(ok), shifted, default)
 
-    def _nth_value(self, f: NthValueFunc, cb: ColumnBatch, seg_start,
+    def _nth_value(self, f: NthValueFunc, cb: ColumnBatch, starts,
                    part_size, n: int) -> pa.Array:
         vals = f.expr.evaluate(cb).to_host(n)
-        starts = np.asarray(seg_start)
         if f.ignore_nulls:
             # nth NON-NULL row of the partition: rank each non-null value
             # within its partition via a prefix count, pick rank == n
@@ -368,184 +508,141 @@ class WindowExec(ExecutionPlan):
             ok = target >= 0
         else:
             target = starts + (f.n - 1)
-            ok = (f.n - 1) < np.asarray(part_size)
+            ok = (f.n - 1) < part_size
         safe = np.clip(target, 0, n - 1)
         taken = vals.take(pa.array(safe, type=pa.int64()))
         return pc.if_else(pa.array(ok), taken,
                           pa.scalar(None, type=vals.type))
 
-    def _window_agg(self, f: WindowAggFunc, cb: ColumnBatch,
-                    rb: pa.RecordBatch, part_seg, order_change, n, xp=jnp
-                    ) -> pa.Array:
+    def _window_agg(self, f: WindowAggFunc, cb: ColumnBatch, part_seg,
+                    order_change, n) -> pa.Array:
         from blaze_tpu.ops.agg.functions import (AvgAgg, CountAgg, MinMaxAgg,
                                                  SumAgg)
+        from blaze_tpu.xputil import to_host
         e = f.agg.children[0] if f.agg.children else None
-        if e is not None:
-            v = e.evaluate(cb)
-            host_fast = (xp is np and
-                         e.data_type(cb.schema).id != TypeId.DECIMAL)
-            if host_fast:
-                arr = v.to_host(n)
-                data = np.asarray(arr.cast(
-                    pa.float64() if pa.types.is_floating(arr.type)
-                    else pa.int64(), safe=False).fill_null(0))
-                valid = np.asarray(arr.is_valid())
-            else:
-                # decimals keep the unscaled-int64 device representation
-                # on either placement (a float/int cast would truncate
-                # the fraction)
-                dv = v.to_device(cb.capacity)
-                data = dv.data[:n]
-                valid = dv.validity[:n]
-                if xp is np:
-                    data = np.asarray(data)
-                    valid = np.asarray(valid)
+        decimal = e is not None and \
+            e.data_type(cb.schema).id == TypeId.DECIMAL
+        if e is None:
+            data = np.ones(n, dtype=np.int64)
+            valid = np.ones(n, dtype=bool)
+        elif decimal:
+            # decimals keep the unscaled-int64 device representation (a
+            # float/int cast would truncate the fraction); a readback
+            # under device placement is a `d2h` like any other
+            dv = e.evaluate(cb).to_device(cb.capacity)
+            data, valid = (np.asarray(a)[:n]
+                           for a in to_host((dv.data, dv.validity)))
+            data = data.astype(np.int64)    # p <= 9 may ride as int32
         else:
-            data = xp.ones(n, dtype=xp.int64)
-            valid = xp.ones(n, dtype=bool)
+            arr = e.evaluate(cb).to_host(n)
+            data = np.asarray(arr.cast(
+                pa.float64() if pa.types.is_floating(arr.type)
+                else pa.int64(), safe=False).fill_null(0))
+            valid = np.asarray(arr.is_valid())
         running = f.running and bool(self.order_by)
+        seen = _segmented_cumsum(valid.astype(np.int64), part_seg)
         if isinstance(f.agg, CountAgg):
-            acc = _segmented_cumsum(valid.astype(xp.int64), part_seg, xp)
-            out, ovalid = acc, xp.ones(n, dtype=bool)
+            out, ovalid = seen, np.ones(n, dtype=bool)
         elif isinstance(f.agg, (SumAgg, AvgAgg)):
-            dt = xp.float64 if xp.issubdtype(data.dtype, xp.floating) \
-                else xp.int64
-            s = _segmented_cumsum(xp.where(valid, data.astype(dt), 0),
-                                  part_seg, xp)
-            c = _segmented_cumsum(valid.astype(xp.int64), part_seg, xp)
-            if isinstance(f.agg, SumAgg):
-                out, ovalid = s, c > 0
-            else:
-                out = s.astype(xp.float64) / xp.maximum(c, 1)
-                ovalid = c > 0
+            dt = np.float64 if np.issubdtype(data.dtype, np.floating) \
+                else np.int64
+            out = _segmented_cumsum(np.where(valid, data.astype(dt), 0),
+                                    part_seg)
+            if isinstance(f.agg, AvgAgg):
+                out = out.astype(np.float64) / np.maximum(seen, 1)
+            ovalid = seen > 0
         elif isinstance(f.agg, MinMaxAgg):
-            big = xp.iinfo(xp.int64).max if not xp.issubdtype(
-                data.dtype, xp.floating) else xp.inf
-            fill = big if f.agg.minimum else (-big if not xp.issubdtype(
-                data.dtype, xp.floating) else -xp.inf)
-            x = xp.where(valid, data, xp.asarray(fill, dtype=data.dtype))
-            out = _segmented_cummin(x, part_seg, xp) if f.agg.minimum \
-                else _segmented_cummax(x, part_seg, xp)
-            ovalid = _segmented_cumsum(valid.astype(xp.int64), part_seg,
-                                       xp) > 0
+            floating = np.issubdtype(data.dtype, np.floating)
+            big = np.inf if floating else np.iinfo(np.int64).max
+            x = np.where(valid, data, np.asarray(
+                big if f.agg.minimum else -big, dtype=data.dtype))
+            out = _segmented_cummin(x, part_seg) if f.agg.minimum \
+                else _segmented_cummax(x, part_seg)
+            ovalid = seen > 0
         else:
             raise TypeError(f"window agg {f.agg.name} unsupported")
         if not running:
             # whole-partition frame: broadcast the partition's last value
-            last = _partition_last(out, part_seg, n, xp)
-            out = last
-            ovalid = _partition_last(ovalid.astype(xp.int64), part_seg, n,
-                                     xp) > 0
+            out = _partition_last(out, part_seg, n)
+            ovalid = _partition_last(ovalid, part_seg, n)
         else:
             # RANGE frame: ties (same order value) share the frame end value
             last_same = _next_change_pos(part_seg | order_change,
-                                         xp.arange(n, dtype=xp.int64),
-                                         n, xp) - 1
-            out = xp.take(out, last_same)
-            ovalid = xp.take(ovalid, last_same)
-        d = np.asarray(out)
-        m = ~np.asarray(ovalid)
-        return pa.array(d, mask=m)
+                                         np.arange(n, dtype=np.int64), n) - 1
+            out = np.take(out, last_same)
+            ovalid = np.take(ovalid, last_same)
+        if decimal and f.agg.output_type(cb.schema).id == TypeId.DECIMAL:
+            from blaze_tpu.batch import decimal_from_unscaled
+            return decimal_from_unscaled(
+                out, ovalid, f.agg.output_type(cb.schema).to_arrow())
+        return pa.array(out, mask=~ovalid)
 
 
-# -- prefix-scan helpers ------------------------------------------------------
-# xp-parameterized: device placement runs them as jnp (XLA fuses the scan
-# chains); host placement runs numpy directly — eagerly dispatched jnp on
-# the CPU backend compiles one tiny XLA program per op PER SHAPE, which
-# dominated window-heavy queries (q51: ~4s of compiles for ~0.1s of work).
+# -- prefix-scan helpers (the host lane's: numpy) ------------------------------
 
-def _window_xp():
-    from blaze_tpu.bridge.placement import host_resident
-    return np if host_resident() else jnp
+def _segment_start(part_seg, pos):
+    return _running_max_where(part_seg, pos)
 
 
-def _cummax(x, xp):
-    if xp is np:
-        return np.maximum.accumulate(x)
-    import jax.lax
-    return jax.lax.cummax(x)
-
-
-def _cummin(x, xp):
-    if xp is np:
-        return np.minimum.accumulate(x)
-    import jax.lax
-    return jax.lax.cummin(x)
-
-
-def _segment_start(part_seg, pos, xp=jnp):
-    return _running_max_where(part_seg, pos, xp)
-
-
-def _running_max_where(mask, pos, xp=jnp):
+def _running_max_where(mask, pos):
     """For each row, the position of the most recent row where mask=True."""
-    marked = xp.where(mask, pos, xp.int64(-1))
-    return _cummax(marked, xp)
+    return np.maximum.accumulate(np.where(mask, pos, np.int64(-1)))
 
 
-def _segment_size(part_seg, n, xp=jnp):
-    pos = xp.arange(n, dtype=xp.int64)
-    start = _segment_start(part_seg, pos, xp)
+def _segment_size(part_seg, n):
+    pos = np.arange(n, dtype=np.int64)
+    start = _segment_start(part_seg, pos)
     # size = next_start - start; next start found from the right
-    is_last = xp.concatenate([part_seg[1:], xp.ones(1, dtype=bool)])
-    end_pos = _next_true_pos(is_last, pos, n, xp)
+    is_last = np.concatenate([part_seg[1:], np.ones(1, dtype=bool)])
+    end_pos = _next_true_pos(is_last, pos, n)
     return end_pos - start + 1
 
 
-def _next_true_pos(mask, pos, n, xp=jnp):
+def _next_true_pos(mask, pos, n):
     """Position of the next row (>= current) where mask is True."""
-    marked = xp.where(mask, pos, xp.int64(n))
-    return xp.flip(_cummin(xp.flip(marked), xp))
+    marked = np.where(mask, pos, np.int64(n))
+    return np.flip(np.minimum.accumulate(np.flip(marked)))
 
 
-def _next_change_pos(change, pos, n, xp=jnp):
+def _next_change_pos(change, pos, n):
     """Exclusive end of the run of rows equal to this row: position of the
     next change after current, or n."""
-    nxt = xp.concatenate([change[1:], xp.ones(1, dtype=bool)])
-    return _next_true_pos(nxt, pos, n, xp) + 1
+    nxt = np.concatenate([change[1:], np.ones(1, dtype=bool)])
+    return _next_true_pos(nxt, pos, n) + 1
 
 
-def _partition_last(values, part_seg, n, xp=jnp):
+def _partition_last(values, part_seg, n):
     """Broadcast each partition's LAST row value to all its rows."""
-    pos = xp.arange(n, dtype=xp.int64)
-    is_last = xp.concatenate([part_seg[1:], xp.ones(1, dtype=bool)])
-    last_pos = _next_true_pos(is_last, pos, n, xp)
-    return xp.take(values, xp.clip(last_pos, 0, n - 1))
+    pos = np.arange(n, dtype=np.int64)
+    is_last = np.concatenate([part_seg[1:], np.ones(1, dtype=bool)])
+    last_pos = _next_true_pos(is_last, pos, n)
+    return np.take(values, np.clip(last_pos, 0, n - 1))
 
 
-def _segmented_cumsum(values, part_seg, xp=jnp):
-    """Cumulative sum restarting at each partition boundary."""
-    total = xp.cumsum(values)
-    pos = xp.arange(values.shape[0], dtype=xp.int64)
-    start = _segment_start(part_seg, pos, xp)
-    base = xp.take(total, xp.maximum(start - 1, 0))
-    base = xp.where(start == 0, xp.zeros_like(base), base)
-    return total - base
+def _by_partition(values, part_seg):
+    import pandas as pd
+    return pd.Series(values).groupby(np.cumsum(part_seg) - 1)
 
 
-def _segmented_cummax(values, part_seg, xp=jnp):
-    n = values.shape[0]
-    pid = xp.cumsum(part_seg.astype(xp.int64)) - 1
-    if xp is np:
-        import pandas as pd
-        # segmented running max in C; skipna=False propagates NaN like
-        # the device path's jnp.maximum (NaN dominates a running max)
-        return pd.Series(values).groupby(np.asarray(pid)) \
-            .cummax(skipna=False).to_numpy()
-    # log-steps doubling scan bounded by segment membership
-    out = values
-    shift = 1
-    while shift < n:
-        prev = xp.concatenate([out[:shift], out[:-shift]])
-        prev_pid = xp.concatenate([pid[:shift], pid[:-shift]])
-        ok = (xp.arange(n) >= shift) & (prev_pid == pid)
-        out = xp.where(ok, xp.maximum(out, prev), out)
-        shift *= 2
-    return out
+def _segmented_cumsum(values, part_seg):
+    """Cumulative sum that RESTARTS at each partition boundary: every
+    partition summed on its own, first row to last, so no other
+    partition's total enters its rounding (`cumsum(all) - cumsum at the
+    start` is a difference of two sums that do not restart)."""
+    values = np.asarray(values)
+    if values.dtype == bool:
+        values = values.astype(np.int64)
+    return _by_partition(values, part_seg).cumsum().to_numpy()
 
 
-def _segmented_cummin(values, part_seg, xp=jnp):
-    return -_segmented_cummax(-values, part_seg, xp)
+def _segmented_cummax(values, part_seg):
+    # skipna=False propagates NaN like the device lane's jnp.maximum (NaN
+    # dominates a running max)
+    return _by_partition(values, part_seg).cummax(skipna=False).to_numpy()
+
+
+def _segmented_cummin(values, part_seg):
+    return -_segmented_cummax(-values, part_seg)
 
 
 # -- event-time windows (streaming runtime) ----------------------------------

@@ -354,6 +354,12 @@ class QueryProfile:
                 f"+{x.get('stage_loop_narrow_rounds', 0)}narrow "
                 f"regrows={x.get('stage_loop_regrows', 0)} "
                 f"fallbacks={x.get('stage_loop_fallbacks', 0)}")
+        if x.get("window_rows"):
+            lines.append(
+                f"window={x.get('window_resident_rows', 0)}"
+                f"/{x.get('window_rows', 0)} rows resident "
+                f"runs={x.get('window_partitions', 0)} "
+                f"scan={_fmt_bytes(x.get('window_scan_bytes', 0))}")
         if x.get("stream_epochs"):
             epochs = x.get("stream_epochs", 0)
             wall = x.get("stream_epoch_wall_ns", 0)
