@@ -49,10 +49,15 @@ _chips: Dict[int, Dict[str, int]] = {}
 # ops.base.PrefetchIterator): how many capacity requests were quantized
 # onto the bucket ladder (and the padding that cost), and how often the
 # consumer actually waited on the prefetch queue (0 wait = IO fully
-# overlapped with compute).
+# overlapped with compute); and the rows `CoalesceStream` re-batched
+# (ops/base.py), by the lane they left through: laid end to end on the chip
+# by the tile program (kernels/tiles.py `lay_tile`) or joined by
+# `ColumnBatch.concat`.  A batch that passed whole is in neither.  By chip
+# in `chip_stats()` too.
 _pipeline = {"bucket_batches": 0, "bucket_pad_rows": 0,
              "prefetch_batches": 0, "prefetch_wait_ns": 0,
-             "prefetch_waits": 0}
+             "prefetch_waits": 0,
+             "coalesce_tiled_rows": 0, "coalesce_concat_rows": 0}
 _bucket_caps: set = set()
 
 # Whole-stage expression-program accounting (exprs/program.py).  Programs
@@ -474,6 +479,8 @@ def _chip_entry(chip: int) -> Dict[str, int]:
                                 "stage_loop_lanes": 0,
                                 "stage_loop_decimal_rows": 0,
                                 "sort_resident_rows": 0,
+                                "coalesce_tiled_rows": 0,
+                                "coalesce_concat_rows": 0,
                                 **{k: 0 for k in _window},
                                 **{k: 0 for k in _CHIP_TABLE_KEYS}}
     return entry
@@ -543,7 +550,8 @@ def chip_stats() -> Dict[int, Dict[str, int]]:
     "join_probe_device_rows", "join_probe_host_rows",
     "join_probe_direct_rows",
     "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes",
-    "stage_loop_decimal_rows", "sort_resident_rows", the four window
+    "stage_loop_decimal_rows", "sort_resident_rows",
+    "coalesce_tiled_rows", "coalesce_concat_rows", the four window
     counters (`_window`) and the stage loop's table counters
     (_CHIP_TABLE_KEYS)} since the last reset: what each chip was given to
     do."""
@@ -558,6 +566,15 @@ def note_bucket(capacity: int, pad_rows: int) -> None:
         _pipeline["bucket_batches"] += 1
         _pipeline["bucket_pad_rows"] += max(0, int(pad_rows))
         _bucket_caps.add(int(capacity))
+
+
+def note_coalesce(chip: int, tiled: bool, rows: int) -> None:
+    """`rows` rows left a `CoalesceStream` on `chip` re-batched, through
+    the tile program or through `ColumnBatch.concat`."""
+    key = "coalesce_tiled_rows" if tiled else "coalesce_concat_rows"
+    with _lock:
+        _pipeline[key] += int(rows)
+        _chip_entry(chip)[key] += int(rows)
 
 
 def note_prefetch(batches: int = 0, wait_ns: int = 0) -> None:

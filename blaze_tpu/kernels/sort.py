@@ -18,6 +18,7 @@ import numpy as np
 
 from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.kernels import compare
+from blaze_tpu.kernels.tiles import _assemble_tiles
 from blaze_tpu.schema import DataType
 from blaze_tpu.xputil import xp_of
 
@@ -94,31 +95,7 @@ def _widen_tile(tile, width: int):
 widen_tile = meter_jit(_widen_tile, name="sort.widen",
                        static_argnames=("width",))
 
-
-def _assemble_tiles(tiles, rows, cap: int):
-    """The tiles' rows end to end in columns of `cap` lanes, in arrival
-    order, with their count: ((data, validity), ...), total.  Copies alone,
-    whatever the tiles hold: tile k is written whole where tile k-1's rows
-    end, over what that tile carried behind its rows, so full tiles (an
-    exchange reader's) and ragged ones (a filter's) cost the same.  A
-    padding lane reads 0 and is not valid."""
-    width = tiles[0][0][0].shape[0]
-    starts = jnp.cumsum(rows) - rows
-    total = starts[-1] + rows[-1]
-
-    def laid(*parts):
-        # room for the last tile's own padding behind the last row
-        buf = jnp.zeros((cap + width,), parts[0].dtype)
-        for at, part in zip(starts, parts):
-            buf = jax.lax.dynamic_update_slice(buf, part, (at,))
-        return buf[:cap]
-
-    cols = jax.tree_util.tree_map(laid, *tiles)
-    live = jnp.arange(cap, dtype=jnp.int32) < total
-    return tuple((jnp.where(live, d, jnp.zeros_like(d)), v & live)
-                 for d, v in cols), total
-
-
+# the laying itself is kernels/tiles.py's, which the coalescer shares
 assemble_tiles = meter_jit(_assemble_tiles, name="sort.assemble",
                            static_argnames=("cap",))
 

@@ -20,11 +20,15 @@ import threading
 import time
 from typing import Callable, Iterator, List, Optional, Sequence
 
+import jax
+import numpy as np
+
 from blaze_tpu import config
-from blaze_tpu.batch import ColumnBatch, bucket_capacity
+from blaze_tpu.batch import ColumnBatch, DeviceColumn, bucket_capacity
 from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.metrics import BASELINE_METRICS, MetricNode
+from blaze_tpu.kernels.tiles import lay_tile, narrow_tile
 from blaze_tpu.schema import Schema
 
 BatchIterator = Iterator[ColumnBatch]
@@ -406,6 +410,16 @@ class CoalesceStream:
     compacts sparse selections: a batch whose surviving-row density is below
     `min_density` is compacted so downstream device work stops paying for
     dead lanes — the static-shape analog of selection vectors.
+
+    A small batch is held and joined with what follows it, by one of two
+    lanes that its own columns choose.  Rows packed to the front of plain
+    fixed-width columns held as device arrays (a device probe's output, a
+    compacted filter's) are laid end to end on the chip (`_TileLane`), one
+    program a few batches, and leave as tiles of exactly the batch size.  Any other batch (a host or
+    dictionary column, numpy buffers under host placement) is staged and
+    joined by `ColumnBatch.concat` once the staged rows reach the batch
+    size.  A batch of at least half the batch size passes whole while
+    nothing is held.
     """
 
     def __init__(self, stream: BatchIterator, batch_size: Optional[int] = None,
@@ -418,7 +432,9 @@ class CoalesceStream:
     def __iter__(self) -> BatchIterator:
         staged: List[ColumnBatch] = []
         staged_rows = 0
+        tiles = _TileLane()
         ctx = current_task()
+        chip = ctx.device_id
         for batch in self._stream:
             ctx.check_running()
             # re-evaluated per batch so a mid-query degradation rung
@@ -430,21 +446,135 @@ class CoalesceStream:
             density = n / max(1, batch.capacity)
             if density < self._min_density:
                 batch = batch.compact()
-            if n >= target // 2 and not staged:
+            if n >= target // 2 and not staged and not tiles.rows:
                 yield batch
                 continue
+            batch = batch.compact()
+            if not staged and _tileable(batch):
+                for tile in tiles.lay(batch, target):
+                    xla_stats.note_coalesce(chip, True, tile.num_rows)
+                    yield tile
+                continue
+            if tiles.rows:
+                # a batch the tile program does not take, behind rows it
+                # holds: they go on through `concat`, in arrival order
+                staged_rows = tiles.rows
+                staged.append(tiles.tail())
             staged.append(batch)
             staged_rows += n
             if staged_rows >= target:
-                yield _concat(staged, staged_rows)
+                yield _concat(staged, staged_rows, chip)
                 staged, staged_rows = [], 0
+        if tiles.rows:
+            xla_stats.note_coalesce(chip, True, tiles.rows)
+            yield tiles.tail()
         if staged:
-            yield _concat(staged, staged_rows)
+            yield _concat(staged, staged_rows, chip)
 
 
-def _concat(staged: List[ColumnBatch], rows: int) -> ColumnBatch:
-    with tracing.span("coalesce", batches=len(staged), rows=rows):
-        return ColumnBatch.concat(staged, bucket_capacity(rows))
+def _concat(staged: List[ColumnBatch], rows: int, chip: int) -> ColumnBatch:
+    with tracing.span("coalesce", batches=len(staged), rows=rows,
+                      lane="concat"):
+        out = ColumnBatch.concat(staged, bucket_capacity(rows))
+    xla_stats.note_coalesce(chip, False, rows)
+    return out
+
+
+def _tileable(batch: ColumnBatch) -> bool:
+    """Whether `_TileLane` takes the batch as it lies: rows packed to the
+    front, every column a plain fixed-width one held as a device array."""
+    return (batch.selection is None and bool(batch.columns)
+            and all(type(c) is DeviceColumn and isinstance(c.data, jax.Array)
+                    for c in batch.columns))
+
+
+# the most batches one `lay_tile` program takes: a stream holds them as they
+# arrived until their rows reach a tile or there are this many (its
+# signature is the count, the widths and the column types; spare places
+# take the last batch again, with no row)
+_LAY_PARTS = 4
+
+
+class _TileLane:
+    """The rows a `CoalesceStream` holds on the chip: what the last tile
+    left, laid, and behind it the batches that arrived since, as they are.
+    Once their rows reach the batch size (or `_LAY_PARTS` batches wait) ONE
+    program lays them end to end (kernels/tiles.py `lay_tile`: offsets and
+    counts are runtime scalars, so a new count is never a new program) and
+    a tile of exactly the batch size leaves, at that capacity and with no
+    selection; the rest stays as the head of the next.  No per-column op,
+    and fewer dispatches than batches.  Row order and values are
+    `ColumnBatch.concat`'s."""
+
+    def __init__(self):
+        self.rows = 0         # held: laid and waiting
+        self._tile = 0        # the batch size, and its capacity
+        self._lanes = 0
+        self._schema = None
+        self._parts = []      # the batches that wait, and their rows
+        self._counts = []
+        self._last = None     # the batch laid last
+        self._held = None     # `lay_tile`'s rest
+        self._head = None     # its head, while that holds every row held
+        self._cut = False     # a full tile has left
+
+    def _lay(self) -> None:
+        """Everything that waits goes behind the rows laid (nothing waits:
+        `lay_tile` cuts the rows laid once more)."""
+        parts, counts = self._parts or [self._last], self._counts or [0]
+        spare = _LAY_PARTS - len(parts)
+        laid = [] if self._held is None else [self.rows - sum(counts)]
+        with tracing.span("coalesce", batches=len(self._parts),
+                          rows=sum(counts), lane="tile"):
+            self._head, self._held = lay_tile(
+                self._held, tuple(parts) + (parts[-1],) * spare,
+                np.array(laid + counts + [0] * spare, np.int32),
+                tile=self._tile, lanes=self._lanes)
+        self._last, self._parts, self._counts = parts[-1], [], []
+
+    def _batch(self, cols, n: int) -> ColumnBatch:
+        return ColumnBatch(
+            self._schema,
+            [DeviceColumn(f.data_type, d, v)
+             for f, (d, v) in zip(self._schema, cols)], n, None)
+
+    def lay(self, batch: ColumnBatch, target: int) -> List[ColumnBatch]:
+        """`batch`'s rows behind the rows held; the full tiles that makes
+        (and first, where the batch size changed under the rows held, those
+        rows as they are: `lay_tile`'s room is one tile's)."""
+        out = []
+        if target != self._tile:
+            if self.rows:
+                out.append(self.tail())
+            self._tile, self._lanes = target, bucket_capacity(target)
+        self._schema = batch.schema
+        self._parts.append(tuple((c.data, c.validity) for c in batch.columns))
+        self._counts.append(batch.num_rows)
+        self.rows += batch.num_rows
+        if self.rows >= self._tile or len(self._parts) == _LAY_PARTS:
+            self._lay()
+        while self.rows >= self._tile:
+            out.append(self._batch(self._head, self._tile))
+            self.rows -= self._tile
+            self._head, self._cut = None, True
+            if self.rows >= self._tile:   # a batch wider than a tile
+                self._lay()
+        return out
+
+    def tail(self) -> ColumnBatch:
+        """The rows held, as one batch; nothing is held afterwards.  At
+        the tile's capacity once a full tile has left, so that a consumer
+        sees ONE capacity; at the rows' own bucket before that, so that a
+        small stream is not widened to a batch size."""
+        if self._parts or self._head is None:
+            self._lay()
+        head, n = self._head, self.rows
+        lanes = self._lanes if self._cut else bucket_capacity(n)
+        if lanes < self._lanes:
+            with tracing.span("coalesce", batches=0, rows=n, lane="tile"):
+                head = narrow_tile(head, lanes=lanes)
+        self.rows, self._head = 0, None
+        return self._batch(head, n)
 
 
 def coalesce(stream: BatchIterator, batch_size: Optional[int] = None) -> BatchIterator:

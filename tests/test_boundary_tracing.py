@@ -721,7 +721,8 @@ def test_coalesce_span_fires_where_batches_are_joined(traced):
     out = list(CoalesceStream(iter(batches(7, 300)), batch_size=1000))
     assert [b.num_rows for b in out] == [1200, 900]
     assert [s["attrs"] for s in _named("coalesce")] == [
-        {"batches": 4, "rows": 1200}, {"batches": 3, "rows": 900}]
+        {"batches": 4, "rows": 1200, "lane": "concat"},
+        {"batches": 3, "rows": 900, "lane": "concat"}]
 
 
 def test_loop_glue_spans_fire_in_the_stage_loop_and_nowhere_else(
