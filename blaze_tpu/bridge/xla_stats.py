@@ -120,7 +120,8 @@ _shuffle = {"shuffle_device_bytes": 0, "shuffle_host_bytes": 0,
 # plan/stage_compiler.py): stage programs built vs served from the
 # fingerprint cache, loop program calls (the O(1)-per-chunk dispatch
 # the loop buys) vs the per-batch dispatches the staged path would have
-# issued, rows folded device-side, overflow-driven table regrows, and
+# issued, rows folded device-side, overflow-driven table regrows (and
+# the steps of the table whose claims were taken back for them), and
 # wholesale fallbacks to the staged per-batch executor.
 _stage_loop = {"stage_loop_programs_built": 0,
                "stage_loop_program_cache_hits": 0,
@@ -128,6 +129,7 @@ _stage_loop = {"stage_loop_programs_built": 0,
                "stage_loop_batches": 0, "stage_loop_rows": 0,
                "stage_loop_lanes": 0,
                "stage_loop_tasks": 0, "stage_loop_regrows": 0,
+               "stage_loop_undone_steps": 0,
                "stage_loop_reserves": 0, "stage_loop_rehash_lanes": 0,
                "stage_loop_rehash_groups": 0,
                "stage_loop_rehash_new_slots": 0,
@@ -477,6 +479,7 @@ def _chip_entry(chip: int) -> Dict[str, int]:
                                 "stage_loop_windows": 0,
                                 "stage_loop_windows_fused": 0,
                                 "stage_loop_lanes": 0,
+                                "stage_loop_undone_steps": 0,
                                 "stage_loop_decimal_rows": 0,
                                 "sort_resident_rows": 0,
                                 "coalesce_tiled_rows": 0,
@@ -550,7 +553,7 @@ def chip_stats() -> Dict[int, Dict[str, int]]:
     "join_probe_device_rows", "join_probe_host_rows",
     "join_probe_direct_rows",
     "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes",
-    "stage_loop_decimal_rows", "sort_resident_rows",
+    "stage_loop_undone_steps", "stage_loop_decimal_rows", "sort_resident_rows",
     "coalesce_tiled_rows", "coalesce_concat_rows", the four window
     counters (`_window`) and the stage loop's table counters
     (_CHIP_TABLE_KEYS)} since the last reset: what each chip was given to
@@ -1001,7 +1004,8 @@ def note_stage_program(cache_hit: bool) -> None:
 
 
 def note_stage_loop_task(chunks: int, batches: int, rows: int, lanes: int,
-                         regrows: int, reserves: int, rehash_lanes: int,
+                         regrows: int, reserves: int, undone_steps: int,
+                         rehash_lanes: int,
                          slots: int, dispatches_avoided: int,
                          full_rounds: int, narrow_rounds: int,
                          rehash_groups: int, rehash_new_slots: int,
@@ -1014,7 +1018,11 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int, lanes: int,
     the fold costs by its lanes, not by its live rows; kept by `chip`
     too).
     The agg table's capacity was raised at `reserves` chunk boundaries
-    before the fold and `regrows` times after an overflow.  Each rehash
+    before the fold and `regrows` times after an overflow;
+    `undone_steps` steps of the table (the fold's, counted on the
+    device, and a rehash's) overflowed and took their claims back, kept
+    by `chip` too: it reads 0 wherever the table was sized before the
+    fold.  Each rehash
     pushed the old table's slots (`rehash_lanes`, summed) holding
     `rehash_groups` groups into a table of `rehash_new_slots` slots,
     re-inserting them over `rehash_probe_lanes` lanes (summed: the width
@@ -1044,7 +1052,9 @@ def note_stage_loop_task(chunks: int, batches: int, rows: int, lanes: int,
         _stage_loop["stage_loop_regrows"] += int(regrows)
         _stage_loop["stage_loop_reserves"] += int(reserves)
         entry = _chip_entry(chip)
-        for k, v in (("stage_loop_lanes", int(lanes)), *table.items()):
+        for k, v in (("stage_loop_lanes", int(lanes)),
+                     ("stage_loop_undone_steps", int(undone_steps)),
+                     *table.items()):
             _stage_loop[k] += v
             entry[k] += v
         _stage_loop["stage_loop_full_rounds"] += int(full_rounds)

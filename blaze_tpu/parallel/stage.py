@@ -244,6 +244,70 @@ def dense_partial_agg(gid: jax.Array, num_slots: int,
     return accs, avalid, occupied
 
 
+# `owner` of a slot nothing holds.  Every other value it takes is smaller:
+# a claim made inside a step (zero or more) and, between steps, the
+# negative that says which keys of the stored group are NULL.
+FREE = int(np.iinfo(np.int32).max)
+
+# One null bit a key column in an int32 `owner`: `-1 - nullbits` has to
+# stay negative.  A grouping of more columns is declined where the table
+# is planned (plan/fused.py `_try_fuse_agg`).
+MAX_KEY_COLUMNS = 31
+
+
+def key_lane_dtypes(dtype) -> Tuple:
+    """The dtypes of the lanes `split_key` makes of a key column."""
+    dtype = jnp.dtype(dtype)
+    if dtype == jnp.int64:
+        return (jnp.dtype(jnp.uint32), jnp.dtype(jnp.int32))
+    if dtype == jnp.uint64:
+        return (jnp.dtype(jnp.uint32), jnp.dtype(jnp.uint32))
+    return (dtype,)
+
+
+def split_key(data: jax.Array) -> Tuple[jax.Array, ...]:
+    """A key column as the table stores it: lanes of at most 32 bits.  A
+    64-bit integer is its low word (uint32) and its high word (int32, or
+    uint32 for an unsigned key: the high word says which).  The chip
+    holds a 64-bit lane as two 32-bit ones and scatters the pair through
+    a path of its own, 3.0 ms over 32,768 lanes where two scatters of a
+    word each take 0.41 together (PERF.md section 6, PR 47).  Anything
+    else is one lane as it is (a float64 too: its bits cannot be viewed
+    on the chip)."""
+    lanes = key_lane_dtypes(data.dtype)
+    if len(lanes) == 1:
+        return (data,)
+    return data.astype(lanes[0]), (data >> 32).astype(lanes[1])
+
+
+def key_dtype(lanes: Sequence) -> np.dtype:
+    """The dtype of the key column these lanes store."""
+    if len(lanes) == 1:
+        return np.dtype(lanes[0].dtype)
+    return np.dtype(np.int64 if lanes[1].dtype == np.int32 else np.uint64)
+
+
+def join_key(lanes: Sequence[jax.Array]) -> jax.Array:
+    """The key column `split_key` made these lanes of.  Elementwise, on
+    device arrays or on numpy's after a readback: over the slots a drain
+    or a rehash has gathered, never over a step."""
+    if len(lanes) == 1:
+        return lanes[0]
+    low, high = lanes
+    wide = key_dtype(lanes)
+    return (high.astype(wide) << 32) | low.astype(wide)
+
+
+def key_valid_lanes(owner: jax.Array, num_keys: int) -> Tuple[jax.Array, ...]:
+    """Per key column, whether the group `owner` stands for holds a value
+    there (bit i of `-1 - owner` clear).  Elementwise over `owner`, of
+    whatever shape, on the device or on numpy's copy: the used slots a
+    drain has gathered, or the whole lane.  A FREE slot reads as
+    anything."""
+    nullbits = ~owner   # -1 - owner
+    return tuple(((nullbits >> i) & 1) == 0 for i in range(num_keys))
+
+
 class HashAggCarry(NamedTuple):
     """Device open-addressing group table (the agg_hash_map.rs analog,
     ref agg_hash_map.rs open-addressing map keyed by grouping bytes).
@@ -255,22 +319,52 @@ class HashAggCarry(NamedTuple):
     afterwards (hash_agg_step), and stop when every row is placed.  A
     multi-operand `lax.sort` grouping program takes minutes to compile
     on TPU; this compiles in seconds.  Its cost is its rounds: each op
-    of a round costs by the lanes it runs over (PERF.md section 6)."""
+    of a round costs by the lanes it runs over (PERF.md section 6), and
+    nothing in a step runs over the slots.
 
-    keys: Tuple[jax.Array, ...]        # stored key data, each (S,)
-    key_valid: Tuple[jax.Array, ...]
+    What a slot holds is said by ONE int32 lane, `owner`: FREE, or
+    `-1 - nullbits` once a group has it (bit i set when key i of the
+    stored group is NULL).  Between steps it holds nothing else; what it
+    holds inside one is hash_agg_step's business.  `keys` holds each
+    key column as `split_key` lays it out, lanes of at most 32 bits (an
+    int64 key is two); at a free slot they are whatever was there.
+    `groups` counts the used slots.  A consumer derives `used`,
+    `key_valid` and the key columns once a drain or a rehash, over the
+    slots it gathered (`key_valid_lanes`, `join_key`), never a step."""
+
+    keys: Tuple[Tuple[jax.Array, ...], ...]   # per key its lanes, each (S,)
     accs: Tuple[jax.Array, ...]
     acc_valid: Tuple[jax.Array, ...]
-    used: jax.Array                    # (S,) bool
+    owner: jax.Array                   # (S,) int32
+    groups: jax.Array                  # scalar int32
+
+    @property
+    def used(self) -> jax.Array:
+        """(S,) bool, derived over every slot."""
+        return self.owner < 0
+
+    @property
+    def key_valid(self) -> Tuple[jax.Array, ...]:
+        """Per key (S,) bool, derived over every slot."""
+        return key_valid_lanes(self.owner, len(self.keys))
+
+    @property
+    def key_columns(self) -> Tuple[jax.Array, ...]:
+        """Per key its (S,) column, joined over every slot."""
+        return tuple(join_key(lanes) for lanes in self.keys)
 
 
 def init_hash_carry(key_dtypes: Sequence, acc_kinds: Sequence[str],
                     acc_dtypes: Sequence, num_slots: int) -> HashAggCarry:
-    keys = tuple(jnp.zeros(num_slots, dtype=dt) for dt in key_dtypes)
-    kvalid = tuple(jnp.zeros(num_slots, dtype=bool) for _ in key_dtypes)
+    # slots, row numbers and claims are int32: a table has at most 2^30
+    # slots (the stage loop stops at 2^24, runtime/loop.py `_MAX_SLOTS`)
+    assert num_slots <= 1 << 30 and len(key_dtypes) <= MAX_KEY_COLUMNS
+    keys = tuple(tuple(jnp.zeros(num_slots, dtype=lane)
+                       for lane in key_lane_dtypes(dt)) for dt in key_dtypes)
     accs, avalid = init_accumulators(acc_kinds, acc_dtypes, num_slots)
-    return HashAggCarry(keys, kvalid, accs, avalid,
-                        jnp.zeros(num_slots, dtype=bool))
+    return HashAggCarry(keys, accs, avalid,
+                        jnp.full(num_slots, FREE, dtype=jnp.int32),
+                        jnp.int32(0))
 
 
 def normalize_float_keys(key_cols):
@@ -334,9 +428,11 @@ def hash_agg_step(carry: HashAggCarry,
                                             Optional[jax.Array]]],
                   mask: jax.Array, probe_rounds: int = 16):
     """Insert one batch into the table.  Returns (new_carry, overflow,
-    num_groups, rounds); ATOMIC: when any row fails to place within
-    probe_rounds, the ORIGINAL carry is returned unchanged (overflow > 0)
-    so the host can grow/degrade and retry the whole batch losslessly.
+    num_groups, rounds).  When any row fails to place within
+    probe_rounds (overflow > 0) the table handed back is LOGICALLY the
+    one given: the same used slots, and the same keys, null bits and
+    accumulators at them (key data at a free slot is nobody's), so the
+    host can grow/degrade and retry the whole batch losslessly.
 
     The probe has two widths.  Rounds run over all `n` lanes while more
     than `narrow_width(n)` rows are unplaced (round one always does);
@@ -345,61 +441,96 @@ def hash_agg_step(carry: HashAggCarry,
     many lanes against the same table.  The winner of a slot is the
     lowest row number either way, so every group lands in the slot it
     would land in at full width.  `rounds` is int32[2]: the rounds run
-    at full and at narrow width; together at most `probe_rounds`."""
+    at full and at narrow width; together at most `probe_rounds`.
+
+    A step costs by its lanes alone.  A round is 3 + 2k indexed
+    operations for k key lanes (`split_key`: one a key column, two for a
+    64-bit integer), each over the round's lanes and none wider than 32
+    bits: the claim (`owner.at[slot].min`), the read of what came of it,
+    the stored group's null bits where a row of this batch holds the
+    slot, and a scatter and a gather of each key lane.  The step ends in one
+    scatter over the batch's lanes, which either writes the winners'
+    null bits into `owner` or, after an overflow, takes their claims
+    back; the accumulators of an overflowing step are not touched
+    (every row scatters out of range).  Nothing is allocated, selected
+    or summed over the slots, and nothing reads the table given after
+    the first write, so a donated table is updated where it lies.
+
+    Inside a step `owner` holds a third kind of value: the claim of the
+    row that won a free slot, `round << bits(n) | row`.  A used slot is
+    negative and never yields to a claim; a slot won in an earlier round
+    holds a lower claim than any this round makes; among this round's
+    claims the lowest row wins."""
     from blaze_tpu.kernels import hashing as H
-    S = carry.used.shape[0]
+    S = carry.owner.shape[0]
     n = mask.shape[0]
     W = narrow_width(n)
+    row_bits = max(1, (n - 1).bit_length())
+    assert probe_rounds << row_bits <= FREE, "claims must stay under FREE"
 
     key_cols = normalize_float_keys(key_cols)
 
     cols = [(d, v, _dtype_of(d).id.value) for d, v in key_cols]
     h = H.hash_columns(cols, seed=42, xp=jnp, algo="xxhash64")
-    h = h.astype(jnp.int64) & (S - 1)  # S is a power of two
+    h = h.astype(jnp.int32) & (S - 1)  # S is a power of two
+    # per key column its lanes, as the table lays them out
+    key_data = [split_key(d) for d, _v in key_cols]
+    # what `owner` says of a slot that holds this row's group
+    nullbits = jnp.zeros(n, jnp.int32)
+    for i, (_d, v) in enumerate(key_cols):
+        nullbits |= (~v).astype(jnp.int32) << i
+    mark = ~nullbits  # -1 - nullbits
 
-    def probe(h, key_cols, row_idx, state, wide: bool):
+    def probe(h, key_data, row_idx, my_mark, state, wide: bool):
         """Probe rounds over the lanes given (all of the batch, or its
         compacted unplaced rows) until every lane is placed, the rounds
-        are spent or, at full width, the rest fits the narrow width."""
+        are spent or, at full width, the rest fits the narrow width.
+        `placed` takes the slot a row matched, `-1 - slot` where the row
+        also won it, and keeps S for a row not placed."""
+        key_valid = key_valid_lanes(my_mark, len(key_data))
 
         def round_body(state):
-            r, used, tkeys, tkvalid, placed, unplaced, _left = state
+            r, owner, tkeys, placed, unplaced, _left = state
             slot = (h + r) & (S - 1)
-            used_g = jnp.take(used, slot)
-            can_claim = unplaced & ~used_g
             # deterministic winner per slot: the lowest row index
-            claim = jnp.full(S, n, dtype=jnp.int64).at[
-                jnp.where(can_claim, slot, S)].min(row_idx, mode="drop")
-            winner = (jnp.take(claim, slot) == row_idx) & can_claim
+            claim = (r << row_bits) | row_idx
+            owner = owner.at[jnp.where(unplaced, slot, S)].min(
+                claim, mode="drop")
+            o = owner.at[slot].get(mode="promise_in_bounds")
+            winner = unplaced & (o == claim)
             wslot = jnp.where(winner, slot, S)
-            tkeys = tuple(tk.at[wslot].set(kd, mode="drop")
-                          for tk, (kd, _kv) in zip(tkeys, key_cols))
-            tkvalid = tuple(tv.at[wslot].set(kv, mode="drop")
-                            for tv, (_kd, kv) in zip(tkvalid, key_cols))
-            used = used.at[wslot].set(True, mode="drop")
-            # match AFTER claims so same-key rows placed this round unify
-            eq = jnp.take(used, slot)
-            for tk, tv, (kd, kv) in zip(tkeys, tkvalid, key_cols):
-                sk = jnp.take(tk, slot)
-                sv = jnp.take(tv, slot)
-                same = sk == kd
-                if jnp.issubdtype(kd.dtype, jnp.floating):
-                    # grouping treats NaN as equal to NaN (Spark
-                    # normalizes)
-                    same = same | (jnp.isnan(sk) & jnp.isnan(kd))
-                # SQL grouping: null == null; valid keys compare by value
-                eq &= (sv == kv) & jnp.where(kv, same, True)
+            tkeys = tuple(
+                tuple(tl.at[wslot].set(kl, mode="drop")
+                      for tl, kl in zip(tk, kd))
+                for tk, kd in zip(tkeys, key_data))
+            # match AFTER claims so same-key rows placed this round
+            # unify.  The slot's group: one from before this step says
+            # its null bits itself; one a row of this batch holds (this
+            # round or an earlier one) has that row's
+            eq = jnp.where(o < 0, o, jnp.take(
+                mark, o & ((1 << row_bits) - 1), mode="clip")) == my_mark
+            for tk, kd, kv in zip(tkeys, key_data, key_valid):
+                same = True
+                for tl, kl in zip(tk, kd):
+                    sl = tl.at[slot].get(mode="promise_in_bounds")
+                    if jnp.issubdtype(kl.dtype, jnp.floating):
+                        # grouping treats NaN as equal to NaN (Spark
+                        # normalizes)
+                        same &= (sl == kl) | (jnp.isnan(sl) & jnp.isnan(kl))
+                    else:
+                        same &= sl == kl
+                # SQL grouping: null == null (the null bits above); valid
+                # keys compare by value
+                eq &= jnp.where(kv, same, True)
             ok = unplaced & eq
-            placed = jnp.where(ok, slot, placed)
+            placed = jnp.where(ok, jnp.where(winner, ~slot, slot), placed)
             unplaced = unplaced & ~ok
-            return (r + 1, used, tkeys, tkvalid, placed, unplaced,
+            return (r + 1, owner, tkeys, placed, unplaced,
                     jnp.sum(unplaced, dtype=jnp.int32))
 
         def round_cond(state):
-            r, _used, _tk, _tv, _placed, _unplaced, left = state
-            # early exit: most batches place everything in 1-2 rounds — on
-            # the host backend the remaining rounds' S-sized claim arrays
-            # would dominate the whole step
+            r, _owner, _tk, _placed, _unplaced, left = state
+            # early exit: most batches place everything in 1-2 rounds
             more = (r < probe_rounds) & (left > 0)
             if wide and W:
                 more &= (r == 0) | (left > W)
@@ -407,48 +538,55 @@ def hash_agg_step(carry: HashAggCarry,
 
         return jax.lax.while_loop(round_cond, round_body, state)
 
-    def narrow(r, used, tkeys, tkvalid, placed, unplaced, left):
+    def narrow(r, owner, tkeys, placed, unplaced, left):
         lanes = _compact_lanes(unplaced, W)
-        nkeys = [(jnp.take(kd, lanes, mode="clip"),
-                  jnp.take(kv, lanes, mode="clip")) for kd, kv in key_cols]
-        r, used, tkeys, tkvalid, nplaced, _unplaced, left = probe(
-            jnp.take(h, lanes, mode="clip"), nkeys, lanes.astype(jnp.int64),
-            (r, used, tkeys, tkvalid, jnp.full(W, S, dtype=jnp.int64),
+        r, owner, tkeys, nplaced, _unplaced, left = probe(
+            jnp.take(h, lanes, mode="clip"),
+            [tuple(jnp.take(kl, lanes, mode="clip") for kl in kd)
+             for kd in key_data], lanes,
+            jnp.take(mark, lanes, mode="clip"),
+            (r, owner, tkeys, jnp.full(W, S, dtype=jnp.int32),
              jnp.arange(W, dtype=jnp.int32) < left, left), wide=False)
         placed = placed.at[lanes].set(nplaced, mode="drop")
-        return r, used, tkeys, tkvalid, placed, left
+        return r, owner, tkeys, placed, left
 
-    def settled(r, used, tkeys, tkvalid, placed, _unplaced, left):
-        return r, used, tkeys, tkvalid, placed, left
+    def settled(r, owner, tkeys, placed, _unplaced, left):
+        return r, owner, tkeys, placed, left
 
     state = probe(
-        h, key_cols, jnp.arange(n, dtype=jnp.int64),
-        (jnp.int32(0), carry.used, tuple(carry.keys), tuple(carry.key_valid),
-         jnp.full(n, S, dtype=jnp.int64),  # S == unplaced sentinel
+        h, key_data, jnp.arange(n, dtype=jnp.int32), mark,
+        (jnp.int32(0), carry.owner, tuple(tuple(k) for k in carry.keys),
+         jnp.full(n, S, dtype=jnp.int32),  # S == unplaced sentinel
          mask, jnp.sum(mask, dtype=jnp.int32)), wide=True)
-    full_rounds, left = state[0], state[6]
+    full_rounds, left = state[0], state[5]
     if W:
         # the rows still unplaced number W or fewer, unless the rounds
         # are spent; a batch that placed in its full rounds pays the
         # count and this branch
-        r, used, tkeys, tkvalid, placed, overflow = jax.lax.cond(
+        r, owner, tkeys, placed, overflow = jax.lax.cond(
             (left > 0) & (full_rounds < probe_rounds), narrow, settled,
             *state)
     else:
-        r, used, tkeys, tkvalid, placed, overflow = settled(*state)
+        r, owner, tkeys, placed, overflow = settled(*state)
     rounds = jnp.stack([full_rounds, r - full_rounds])
 
-    # the S sentinel of an unplaced row drops out of every scatter below
+    # every slot claimed in this step has one winner.  Their slots get
+    # their groups' null bits; after an overflow they are FREE again,
+    # and every row's accumulation drops out like an unplaced row's (the
+    # S sentinel)
+    failed = overflow > 0
+    won = placed < 0
+    g = jnp.where(won, ~placed, placed)
+    owner = owner.at[jnp.where(won, g, S)].set(
+        jnp.where(failed, FREE, mark), mode="drop")
     new_accs, new_avalid = scatter_accumulate(
-        placed, agg_specs, mask, carry.accs, carry.acc_valid)
-
-    new_carry = HashAggCarry(tkeys, tkvalid,
-                             tuple(new_accs), tuple(new_avalid), used)
-    keep_new = overflow == 0
-    sel = jax.tree_util.tree_map(
-        lambda nw, old: jnp.where(keep_new, nw, old), new_carry, carry)
-    num_groups = jnp.sum(sel.used.astype(jnp.int32))
-    return sel, overflow, num_groups, rounds
+        jnp.where(failed, S, g), agg_specs, mask, carry.accs,
+        carry.acc_valid)
+    num_groups = carry.groups + jnp.where(
+        failed, 0, jnp.sum(won, dtype=jnp.int32))
+    return (HashAggCarry(tkeys, tuple(new_accs), tuple(new_avalid), owner,
+                         num_groups),
+            overflow, num_groups, rounds)
 
 
 def row_contribution(kind: str, vd: Optional[jax.Array],
@@ -531,31 +669,33 @@ def rehash_carry(old: HashAggCarry, kinds: Sequence[str],
     re-merge with merge semantics (count -> sum of counts).
 
     The old table's slots are the batch.  A step costs by its lanes
-    (850 ns a lane of a 2^21-slot table on a v5e, a quarter of them
-    live at most; PERF.md section 6, PR 32), so a caller that knows the
-    table's group count gives `lanes` (`rehash_width`, never fewer than
-    the groups): the used slots are compacted to the front of that many
-    lanes, in slot order, and the step runs over those.  The lowest lane
-    wins a contested slot at either width and compaction keeps the
-    order, so the new table is slot for slot the uncompacted one.
-    Groups beyond `lanes` would be dropped: the count is the caller's to
-    hold."""
-    key_dtypes = [k.dtype for k in old.keys]
+    (PERF.md section 6), so a caller that knows the table's group count
+    gives `lanes` (`rehash_width`, never fewer than the groups): the
+    used slots are compacted to the front of that many lanes, in slot
+    order, and the step runs over those.  The lowest lane wins a
+    contested slot at either width and compaction keeps the order, so
+    the new table is slot for slot the uncompacted one.  Groups beyond
+    `lanes` would be dropped: the count is the caller's to hold.
+
+    The old table's mask, its keys' validity (from its `owner` lane) and
+    its key columns (from their lanes) are read once, here: over the
+    compacted lanes where there are any, over the old slots otherwise."""
+    key_dtypes = [key_dtype(k) for k in old.keys]
     acc_dtypes = [a.dtype for a in old.accs]
     fresh = init_hash_carry(key_dtypes, kinds, acc_dtypes, new_slots)
-    cols = (old.keys, old.key_valid, old.accs, old.acc_valid)
+    cols = (old.keys, old.accs, old.acc_valid, old.owner)
     mask = old.used
     if lanes is not None and lanes < mask.shape[0]:
         live = _compact_lanes(mask, lanes)
         cols = jax.tree_util.tree_map(
             lambda a: jnp.take(a, live, mode="clip"), cols)
-        mask = jnp.arange(lanes, dtype=jnp.int32) < jnp.sum(
-            mask, dtype=jnp.int32)
-    keys, key_valid, accs, acc_valid = cols
+        mask = jnp.arange(lanes, dtype=jnp.int32) < old.groups
+    keys, accs, acc_valid, owner = cols
     specs = [("sum" if k == "count" else k, a, av)
              for k, a, av in zip(kinds, accs, acc_valid)]
-    return hash_agg_step(fresh, list(zip(keys, key_valid)), specs, mask,
-                         probe_rounds)
+    key_cols = list(zip([join_key(k) for k in keys],
+                        key_valid_lanes(owner, len(keys))))
+    return hash_agg_step(fresh, key_cols, specs, mask, probe_rounds)
 
 
 def merge_agg_tables(table: AggTable,

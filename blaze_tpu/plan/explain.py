@@ -353,6 +353,7 @@ class QueryProfile:
                 f"probe_rounds={x.get('stage_loop_full_rounds', 0)}"
                 f"+{x.get('stage_loop_narrow_rounds', 0)}narrow "
                 f"regrows={x.get('stage_loop_regrows', 0)} "
+                f"undone={x.get('stage_loop_undone_steps', 0)} "
                 f"fallbacks={x.get('stage_loop_fallbacks', 0)}")
         if x.get("window_rows"):
             lines.append(

@@ -51,8 +51,10 @@ from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
 from blaze_tpu.ops.basic import (DebugExec, FilterExec, FilterProjectExec,
                                  ProjectExec)
 from blaze_tpu.ops.scan import MemoryScanExec, ParquetScanExec
-from blaze_tpu.parallel.stage import (hash_agg_step, init_accumulators,
-                                      init_hash_carry, pack_dense_keys,
+from blaze_tpu.parallel.stage import (MAX_KEY_COLUMNS, hash_agg_step,
+                                      init_accumulators, init_hash_carry,
+                                      join_key, key_valid_lanes,
+                                      pack_dense_keys,
                                       rehash_carry, rehash_width,
                                       scatter_accumulate, unpack_dense_keys)
 from blaze_tpu.schema import Field, Schema, TypeId
@@ -88,6 +90,8 @@ def _try_fuse_agg(node: ExecutionPlan) -> Optional["FusedPartialAggExec"]:
     aggs = node._aggs
     if not groups or not aggs:
         return None
+    if len(groups) > MAX_KEY_COLUMNS:
+        return None  # the table keeps one null bit a key in an int32 lane
     child = node.children[0]
     in_schema = child.schema
 
@@ -1897,7 +1901,7 @@ class FusedPartialAggExec(ExecutionPlan):
                 # ... and hands back the carry's own group count, which
                 # is ready with the overflow just read
                 lanes = rehash_width(int(to_host(_ng)),
-                                     carry.used.shape[0])
+                                     carry.owner.shape[0])
                 bigger, re_ovf, _, _ = _rehash_jit(kinds, slots,
                                                    lanes)(carry)
                 if int(to_host(re_ovf)) > 0:
@@ -1936,23 +1940,31 @@ class FusedPartialAggExec(ExecutionPlan):
             sel, count = _used_slots(carry.used)
             if count == 0:
                 return
-            rb = self._take_to_arrow(sel, count, carry.keys,
-                                     carry.key_valid, carry.accs,
-                                     carry.acc_valid, key_dicts=key_dicts)
+            rb = self._take_to_arrow(sel, count, carry.keys, carry.accs,
+                                     carry.acc_valid, owner=carry.owner,
+                                     key_dicts=key_dicts)
         yield from self._emit_chunks(rb)
 
-    def _take_to_arrow(self, sel, count: int, keys, key_valid, accs,
-                       acc_valid, key_dicts=None) -> pa.RecordBatch:
+    def _take_to_arrow(self, sel, count: int, keys, accs, acc_valid,
+                       key_valid=None, owner=None,
+                       key_dicts=None) -> pa.RecordBatch:
         """Rows `sel[:count]` of device columns in accumulator form (a
         hash table's used slots, a pass-through window's live rows),
-        read back and laid out in the out-schema."""
+        read back and laid out in the out-schema.  `keys` holds per key
+        its lanes (`split_key`); the keys' validity comes as a column a
+        key (`key_valid`) or as a table's `owner` lane.  Lanes are
+        joined and the owner's null bits read on the host, over the rows
+        read back."""
         keys_h, kvalid_h, accs_h, avalid_h = to_host(
-            ([jnp.take(k, sel) for k in keys],
-             [jnp.take(v, sel) for v in key_valid],
-             [jnp.take(a, sel) for a in accs],
-             [jnp.take(v, sel) for v in acc_valid]))
+            jax.tree_util.tree_map(
+                lambda a: jnp.take(a, sel),
+                (keys, owner if key_valid is None else list(key_valid),
+                 accs, acc_valid)))
+        if key_valid is None:
+            kvalid_h = key_valid_lanes(kvalid_h, len(keys_h))
         return self._rows_to_arrow(
-            [(kd[:count], kv[:count]) for kd, kv in zip(keys_h, kvalid_h)],
+            [(join_key(lanes)[:count], kv[:count])
+             for lanes, kv in zip(keys_h, kvalid_h)],
             [a[:count] for a in accs_h], [v[:count] for v in avalid_h],
             key_dicts=key_dicts)
 

@@ -48,11 +48,14 @@ direct caller (the device-to-device exchange wants ONE carry).
 
 Discipline inherited from the staged path, kept intact:
 
-  * ATOMIC overflow (hash_agg_step): the first batch that overflows
-    leaves the carry unchanged and masks every later batch of the chunk
-    to a no-op; the host re-sizes + rehashes and resumes the SAME chunk
-    at the overflow batch.  Past `_MAX_SLOTS` every mode falls back
-    wholesale.
+  * An overflow is undone (hash_agg_step): the first batch that
+    overflows gives its claims back and moves no accumulator, so the
+    table is logically what it was (the same used slots, and the same
+    keys, null bits and sums at them), and every later batch of the
+    chunk is masked to a no-op; the host re-sizes + rehashes and resumes
+    the SAME chunk at the overflow batch (`stage_loop_undone_steps`
+    counts the steps taken back: the fold's and a rehash's).  Past
+    `_MAX_SLOTS` every mode falls back wholesale.
   * Fallback only before the first emission.  Until a partition
     switches (and for ever, if it never does) the loop has emitted
     nothing, so `StageLoopFallback` and the staged re-run are lossless.
@@ -87,6 +90,7 @@ from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.memory import MemConsumer, MemManager
 from blaze_tpu.schema import TypeId
 from blaze_tpu.parallel.stage import (hash_agg_step, init_hash_carry,
+                                      join_key, key_valid_lanes,
                                       normalize_float_keys, rehash_width,
                                       row_contribution)
 from blaze_tpu.xputil import to_host
@@ -127,7 +131,8 @@ def _slot_bytes(key_dtypes, kinds, acc_dtypes) -> int:
     carry's arrays."""
     carry = jax.eval_shape(lambda: init_hash_carry(
         list(key_dtypes), kinds, list(acc_dtypes), 1))
-    return sum(a.dtype.itemsize for a in jax.tree_util.tree_leaves(carry))
+    return sum(a.dtype.itemsize for a in jax.tree_util.tree_leaves(carry)
+               if a.ndim)
 
 
 class _TableCharge(MemConsumer):
@@ -233,11 +238,12 @@ def _fold_factory(program, donate: bool):
 
     def fold_impl(carry, cols_stacked, masks, start, look):
         def body(state):
-            b, c, ovf_seen, first_ovf, folded, rounds, *mass = state
+            b, c, ovf_seen, first_ovf, folded, rounds, undone, *mass = state
             kd, kv, ad, av, m = prepare(_batch_of(cols_stacked, b), masks[b])
             # once a batch overflows, later batches fold as no-ops: the
-            # carry stays exactly at the pre-overflow state (hash_agg_step
-            # is atomic), so the host can regrow and resume mid-chunk
+            # table stays what it was before the overflow (hash_agg_step
+            # takes an overflowing step's claims back), so the host can
+            # regrow and resume mid-chunk
             live = jnp.logical_and(m, jnp.logical_not(ovf_seen))
             specs = [(k, d, v) for k, d, v in zip(kinds, ad, av)]
             new_c, ovf, _ng, step_rounds = hash_agg_step(
@@ -255,33 +261,34 @@ def _fold_factory(program, donate: bool):
                 big = jnp.max(jnp.where(live & av[i], jnp.abs(ad[i]), 0))
                 mass[0] += big.astype(jnp.float32) * nlive.astype(jnp.float32)
             return (b + 1, new_c, jnp.logical_or(ovf_seen, hit), first_ovf,
-                    folded, rounds + step_rounds, *mass)
+                    folded, rounds + step_rounds, undone + hit, *mass)
 
         def more(state):
-            b, _c, _ovf_seen, _first_ovf, folded, _rounds, *_mass = state
+            b, _c, _ovf_seen, _first_ovf, folded, *_rest = state
             # `look` live rows are in: stop at this batch boundary, so
             # the host can take its first look at groups per live row
             return (b < masks.shape[0]) & (folded < look)
 
         zero = jnp.asarray(0, jnp.int32)
         mass = (jnp.asarray(0, jnp.float32),) if decimal_sums else ()
-        b, carry, ovf_seen, first_ovf, folded, rounds, *mass = \
+        b, carry, ovf_seen, first_ovf, folded, rounds, undone, *mass = \
             jax.lax.while_loop(
                 more, body, (start, carry, jnp.asarray(False), zero, zero,
-                             jnp.zeros(2, jnp.int32), *mass))
+                             jnp.zeros(2, jnp.int32), zero, *mass))
         # the table's group count, the live rows this call inserted and
         # the probe rounds it ran (full width, narrow width) ride the
         # overflow scalars' round trip: the host sizes the next chunk's
         # table from the first and judges the partial-skip ratio from the
         # first two.  `resume` is the batch to go on from: the one that
         # overflowed, else the first one not folded (the chunk's width
-        # when nothing stopped the fold)
-        groups = jnp.sum(carry.used, dtype=jnp.int32)
+        # when nothing stopped the fold).  `undone` counts the steps
+        # whose claims hash_agg_step took back
         resume = jnp.where(ovf_seen, first_ovf, b)
         # `mass`, beside the overflow scalars: what the decimal lanes'
         # sums are bounded by so far in this call (the host adds the
         # calls up and declines the partition before a sum could wrap)
-        return (carry, ovf_seen, resume, groups, folded, rounds, *mass)
+        return (carry, ovf_seen, resume, carry.groups, folded, rounds, undone,
+                *mass)
 
     kwargs = {"donate_argnums": (0,)} if donate else {}
     return _cached(
@@ -424,7 +431,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
     stream = (source_stream if source_stream is not None
               else program.source.execute(partition))
     windows = _batch_windows(stream, chunk, pad_tail=True)
-    batches = rows = lanes = fold_calls = regrows = reserves = 0
+    batches = rows = lanes = fold_calls = regrows = reserves = undone = 0
     # (old table's slots, groups it held, new slots, lanes re-inserted)
     rehashes = []
     full_rounds = narrow_rounds = 0
@@ -442,6 +449,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         """The table at `want` slots or more: a plain allocation while
         it holds nothing, one rehash otherwise.  Charged before it is
         made."""
+        nonlocal undone
         while want <= _MAX_SLOTS:
             if carry is None or groups == 0:
                 table.hold(want)
@@ -467,6 +475,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
             if fits:
                 table.hold(want)  # the old table goes with `carry`
                 return bigger, want
+            undone += 1  # the rehash is one step, and it took its claims back
             want *= 2  # rare probe clustering: double again
         raise StageLoopFallback(f"table would exceed {_MAX_SLOTS} slots")
 
@@ -506,8 +515,9 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                         jnp.asarray(look, jnp.int32))
                     fold_calls += 1
                     # the host waits for the fold here
-                    ovf_seen, resume, ngroups, nlive, rounds, *mass = \
-                        to_host(tuple(scalars))
+                    ovf_seen, resume, ngroups, nlive, rounds, nundone, \
+                        *mass = to_host(tuple(scalars))
+                    undone += int(nundone)
                     if mass:
                         decimal_mass += float(mass[0])
                         if decimal_mass >= _DECIMAL_MASS_LIMIT:
@@ -555,7 +565,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         xla_stats.note_partial_agg_skip(live_folded)
     xla_stats.note_stage_loop_task(
         chunks=fold_calls, batches=batches, rows=rows, lanes=lanes,
-        regrows=regrows, reserves=reserves,
+        regrows=regrows, reserves=reserves, undone_steps=undone,
         rehash_lanes=sum(r[0] for r in rehashes),
         rehash_groups=sum(r[1] for r in rehashes),
         rehash_new_slots=sum(r[2] for r in rehashes),
@@ -599,8 +609,9 @@ def _pass_through(program, rest: _Unfolded, partition: int, ctx: str):
             keys, key_valid, accs, acc_valid, live = passthrough(
                 cols_stacked, masks, jnp.asarray(start, jnp.int32))
             sel, n = _used_slots(live)
-            rb = (agg._take_to_arrow(sel, n, keys, key_valid, accs,
-                                     acc_valid) if n else None)
+            rb = (agg._take_to_arrow(sel, n, [(k,) for k in keys], accs,
+                                     acc_valid, key_valid=key_valid)
+                  if n else None)
         xla_stats.note_partial_agg_rows(n)
         agg._note_lane(count - start)
         task.loop_chunks = ci + 1
@@ -685,11 +696,16 @@ def drain_device(program, carry):
     fields = list(program.out_schema)
     datas, valids = [], []
     i = 0
-    for kd, kv in zip(carry.keys, carry.key_valid):
+    # the key columns and their validity, read from the table's lanes and
+    # its `owner` over the used slots alone
+    key_valid = key_valid_lanes(jnp.take(carry.owner, sel)[:count],
+                                len(carry.keys))
+    for lanes, kv in zip(carry.keys, key_valid):
         dt = fields[i].data_type.jnp_dtype()
         i += 1
-        datas.append(jnp.take(kd, sel)[:count].astype(dt))
-        valids.append(jnp.take(kv, sel)[:count])
+        datas.append(join_key([jnp.take(lane, sel)[:count]
+                               for lane in lanes]).astype(dt))
+        valids.append(kv)
     for (_rk, out_kind, _a), acc, av in zip(program.agg._specs,
                                             carry.accs, carry.acc_valid):
         t = fields[i].data_type

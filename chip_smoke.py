@@ -135,8 +135,8 @@ def phase_kernels(seed: int) -> None:
         out, overflow, groups, rounds = jax.block_until_ready(
             step(carry, keys, jnp.asarray(vals), jnp.asarray(mask)))
         took = time.perf_counter() - t0
-        slots_used = np.flatnonzero(np.asarray(out.used))
-        out_keys = [np.asarray(k)[slots_used] for k in out.keys]
+        slots_used = np.flatnonzero(np.asarray(out.owner) < 0)
+        out_keys = [np.asarray(k)[slots_used] for k in out.key_columns]
         out_sums = np.asarray(out.accs[0])[slots_used]
         got = {tuple(int(k[j]) for k in out_keys): float(out_sums[j])
                for j in range(len(slots_used))}

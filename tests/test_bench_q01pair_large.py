@@ -32,7 +32,9 @@ SCALE, DATA_SEED, SPLITS, PARTITIONS = 0.4, 20260927, 4, 4
 FLOOR, BATCH, CHUNK = 1024, 128, 8
 FIRST, LAST = 8 * FLOOR, 32 * FLOOR     # need / _TARGET_LOAD, as powers of 2
 REHASH_LANES = 2 * FLOOR                # the power of two over ~1.9K groups
-SLOT_BYTES = 8 + 8 + 1 + 1 + 8 + 1 + 1  # two int64 keys, a float64 sum, flags
+# two int64 keys as four 32-bit lanes, the int32 owner lane, a float64
+# sum and its flag
+SLOT_BYTES = 4 * 4 + 4 + 8 + 1
 CELL = "sf100_q01pair_x1"
 BUDGET = 4 << 30
 

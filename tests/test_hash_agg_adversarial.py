@@ -4,7 +4,9 @@ The scatter-probe claim loop early-exits when every row places in a
 round or two; these tests force the OTHER regimes:
 
   * load factor ~1.0 — long probe chains, probe_rounds exhaustion,
-  * overflow atomicity — a failed batch must leave the carry unchanged,
+  * overflow atomicity — a failed batch must leave the table logically
+    unchanged: the same used slots, and the same keys, null bits and
+    accumulators at them,
   * the rehash/grow path — re-inserting a full table into a larger one
     must preserve every group and every accumulator exactly,
   * the production grow loop end-to-end against a pandas oracle.
@@ -14,14 +16,17 @@ agg table growth in agg/agg_table.rs is likewise exercised by its
 fuzz tests).
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from blaze_tpu.parallel.stage import (HashAggCarry, hash_agg_step,
+from blaze_tpu.parallel.stage import (FREE, hash_agg_step,
                                       init_hash_carry, rehash_carry,
                                       rehash_width)
+from tests.hash_step_parent import parent_init, parent_step
 
 
 def _insert(carry, keys, vals, probe_rounds=16):
@@ -35,11 +40,16 @@ def _insert(carry, keys, vals, probe_rounds=16):
 
 def _table_dict(carry):
     used = np.asarray(carry.used)
-    keys = np.asarray(carry.keys[0])[used]
+    keys = _keys(carry)[0][used]
     sums = np.asarray(carry.accs[0])[used]
     counts = np.asarray(carry.accs[1])[used]
     return {int(k): (float(s), int(c))
             for k, s, c in zip(keys, sums, counts)}
+
+
+def _keys(carry):
+    """The table's key columns, one numpy array a key, slot by slot."""
+    return [np.asarray(k) for k in carry.key_columns]
 
 
 def _assert_leaf_for_leaf(got, want):
@@ -48,9 +58,35 @@ def _assert_leaf_for_leaf(got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _assert_owner_is_settled(carry):
+    """Between steps a slot's owner is FREE or `-1 - nullbits`: no claim
+    outlives its step, and the count on the carry is the used slots'."""
+    owner = np.asarray(carry.owner)
+    assert owner.dtype == np.int32
+    assert ((owner == FREE) | (owner < 0)).all()
+    assert int(carry.groups) == int((owner < 0).sum())
+
+
+def _assert_same_table(got, want):
+    """What a step that overflowed owes its caller: the same used slots
+    with the same null bits (the whole owner lane), and the same keys
+    and accumulators at them.  Key data at a free slot is nobody's."""
+    _assert_owner_is_settled(got)
+    np.testing.assert_array_equal(np.asarray(got.owner),
+                                  np.asarray(want.owner))
+    assert int(got.groups) == int(want.groups)
+    used = np.asarray(want.used)
+    for a, b in zip(jax.tree_util.tree_leaves(
+                        (got.keys, got.accs, got.acc_valid)),
+                    jax.tree_util.tree_leaves(
+                        (want.keys, want.accs, want.acc_valid))):
+        np.testing.assert_array_equal(np.asarray(a)[used],
+                                      np.asarray(b)[used])
+
+
 def test_full_load_overflow_is_atomic():
     """64 slots, 80 distinct keys: placement MUST overflow; the returned
-    carry must be bit-identical to the input (lossless retry contract)."""
+    table must be the one given (lossless retry contract)."""
     S = 64
     carry = init_hash_carry([jnp.int64], ["sum", "count"],
                             [jnp.float64, jnp.int64], S)
@@ -58,7 +94,7 @@ def test_full_load_overflow_is_atomic():
     vals = jnp.ones(80, dtype=jnp.float64)
     out, overflow, _, _ = _insert(carry, keys, vals)
     assert int(overflow) > 0
-    _assert_leaf_for_leaf(out, carry)
+    _assert_same_table(out, carry)
 
 
 def test_probe_rounds_exhaustion_partial_chain():
@@ -220,7 +256,7 @@ def _reference(key_cols, specs, mask):
 def _table_groups(carry):
     slots = np.flatnonzero(np.asarray(carry.used))
     key_cols = [(np.asarray(k), np.asarray(v))
-                for k, v in zip(carry.keys, carry.key_valid)]
+                for k, v in zip(_keys(carry), carry.key_valid)]
     accs = [(np.asarray(a), np.asarray(v))
             for a, v in zip(carry.accs, carry.acc_valid)]
     got = {}
@@ -317,7 +353,7 @@ def test_rehash_matches_reference(case):
 @pytest.mark.parametrize("held", [0, 32], ids=["empty", "half_full"])
 def test_overflow_returns_the_original_carry(held):
     """The atomic contract where the table already holds groups: a batch
-    that cannot place leaves every leaf of the carry as it was."""
+    that cannot place leaves the table as it was."""
     S = 64
     rng = np.random.default_rng(9)
 
@@ -338,7 +374,7 @@ def test_overflow_returns_the_original_carry(held):
         carry, *batch(np.arange(1000, 1080)))
     assert int(overflow) > 0
     assert int(groups) == int(jnp.sum(carry.used))
-    _assert_leaf_for_leaf(out, carry)
+    _assert_same_table(out, carry)
 
 
 # -- the probe's two widths against a model of its rounds -------------------
@@ -372,18 +408,29 @@ def _home_slots(key_cols, slots):
 
 
 class _ModelTable:
+    """The owner lane is the model's too: FREE, or `-1 - nullbits` of the
+    group a slot holds (bit i: key i is NULL).  `used` and the keys'
+    validity are read from it, as the engine's consumers read them."""
+
     def __init__(self, key_dtypes, slots):
-        self.used = np.zeros(slots, bool)
+        self.owner = np.full(slots, FREE, np.int32)
         self.keys = [np.zeros(slots, dt) for dt in key_dtypes]
-        self.valid = [np.zeros(slots, bool) for _ in key_dtypes]
         self.sums = np.zeros(slots)
         self.counts = np.zeros(slots, np.int64)
 
+    @property
+    def used(self):
+        return self.owner < 0
+
+    @property
+    def valid(self):
+        return [((-1 - self.owner) >> i) & 1 == 0
+                for i in range(len(self.keys))]
+
     def copy(self):
-        t = _ModelTable([], len(self.used))
-        t.used = self.used.copy()
+        t = _ModelTable([], len(self.owner))
+        t.owner = self.owner.copy()
         t.keys = [k.copy() for k in self.keys]
-        t.valid = [v.copy() for v in self.valid]
         t.sums, t.counts = self.sums.copy(), self.counts.copy()
         return t
 
@@ -401,7 +448,7 @@ class _ModelTable:
         """(table after, overflow, [full rounds, narrow rounds]); the
         table as it was when a row is left over."""
         t = self.copy()
-        S, n = len(t.used), len(mask)
+        S, n = len(t.owner), len(mask)
         key_cols = _normalised(key_cols)
         h = _home_slots(key_cols, S)
         W = narrow_width(n)
@@ -414,10 +461,11 @@ class _ModelTable:
             rounds[0 if wide else 1] += 1
             for i in unplaced:           # ascending: the lowest row claims
                 s = (h[i] + r) % S
-                if not t.used[s]:
-                    t.used[s] = True
-                    for tk, tv, (d, v) in zip(t.keys, t.valid, key_cols):
-                        tk[s], tv[s] = d[i], v[i]
+                if t.owner[s] == FREE:
+                    t.owner[s] = -1 - sum(
+                        (not v[i]) << c for c, (_d, v) in enumerate(key_cols))
+                    for tk, (d, _v) in zip(t.keys, key_cols):
+                        tk[s] = d[i]
             still = []
             for i in unplaced:
                 s = (h[i] + r) % S
@@ -436,9 +484,11 @@ class _ModelTable:
 
 
 def _assert_slot_for_slot(carry, model):
+    _assert_owner_is_settled(carry)
+    np.testing.assert_array_equal(np.asarray(carry.owner), model.owner)
     used = np.asarray(carry.used)
     np.testing.assert_array_equal(used, model.used)
-    for k, v, mk, mv in zip(carry.keys, carry.key_valid, model.keys,
+    for k, v, mk, mv in zip(_keys(carry), carry.key_valid, model.keys,
                             model.valid):
         np.testing.assert_array_equal(np.asarray(v)[used], mv[used])
         np.testing.assert_array_equal(np.asarray(k)[used], mk[used])
@@ -553,7 +603,7 @@ def test_step_matches_round_model(case, sieve):
         carry, model, [(kd, np.ones(lanes, bool))], rng.random(lanes), mask)
     assert rounds == want_rounds and overflow == want_left
     if want_left:
-        _assert_leaf_for_leaf(out, carry)
+        _assert_same_table(out, carry)
 
 
 def test_null_nan_and_negative_zero_keys_place_in_the_narrow_rounds():
@@ -591,7 +641,7 @@ def test_null_nan_and_negative_zero_keys_place_in_the_narrow_rounds():
         carry, model, [(kd, kv)], vals, mask)
     assert overflow == 0 and rounds[0] == 1 and rounds[1] >= 1
     for hm in homes:                     # pushed off their home slots
-        assert np.asarray(out.keys[0])[hm] in blockers
+        assert _keys(out)[0][hm] in blockers
     specs = [("sum", vals, None), ("count", None, None)]
     want = _reference([(kd, kv)], specs, mask)
     for b in blockers:
@@ -732,8 +782,8 @@ def test_compacted_rehash_keeps_hostile_keys_and_every_kind(key_dtypes):
 def test_a_rehash_that_overflows_leaves_both_tables_as_they_were(sieve):
     """24 groups that share ONE home slot in the new table cannot place
     in 16 rounds: compacted or not, the rehash reports the eight left
-    over and hands back the new table untouched, and the old carry,
-    which the caller keeps, is what it was."""
+    over and hands back the new table with nothing in it, and the old
+    carry, which the caller keeps, is what it was."""
     new_slots = 4 * _OLD_SLOTS
     kd = sieve(new_slots).at(77, 24)
     # they share their home in the old table too: rounds enough for it
@@ -745,7 +795,7 @@ def test_a_rehash_that_overflows_leaves_both_tables_as_they_were(sieve):
     plain, compact = _rehash_both_ways(old, ["sum", "count"], new_slots,
                                        _REHASH_LANES)
     assert int(compact[1]) == 8 and int(compact[2]) == 0
-    _assert_leaf_for_leaf(compact[0], _fresh([np.int64], new_slots))
+    _assert_same_table(compact[0], _fresh([np.int64], new_slots))
     _assert_leaf_for_leaf(old, before)
     # and with rounds enough the same groups place, the same way
     plain, compact = _rehash_both_ways(old, ["sum", "count"], new_slots,
@@ -774,11 +824,14 @@ def test_without_fewer_lanes_the_rehash_is_the_step_over_the_old_slots(
     old = _fresh([np.int64], _OLD_SLOTS)
 
     def as_before(c):
+        # `used` and `key_valid` are read from the owner lane and the
+        # key columns from their lanes, over the old slots
         fresh = _fresh([np.int64], 4 * _OLD_SLOTS)
+        mask = c.used
         specs = [("sum", c.accs[0], c.acc_valid[0]),
                  ("sum", c.accs[1], c.acc_valid[1])]
-        return hash_agg_step(fresh, list(zip(c.keys, c.key_valid)), specs,
-                             c.used, 16)
+        return hash_agg_step(fresh, list(zip(c.key_columns, c.key_valid)),
+                             specs, mask, 16)
 
     def now(c):
         return rehash_carry(c, ["sum", "count"], 4 * _OLD_SLOTS, lanes)
@@ -864,7 +917,7 @@ def test_stage_loop_round_counters_add_up_to_the_models_rounds(tmp_path,
         fold = real_factory(*a, **k)
 
         def spy(carry, *rest):
-            folded_into.append(int(carry.used.shape[0]))
+            folded_into.append(int(carry.owner.shape[0]))
             return fold(carry, *rest)
         return spy
 
@@ -903,3 +956,307 @@ def test_stage_loop_round_counters_add_up_to_the_models_rounds(tmp_path,
     footer = QueryProfile("q", 0, MetricNode("root"), 1, "local",
                           xla=d).render_text()
     assert f"probe_rounds={want[0]}+{want[1]}narrow" in footer
+
+
+# -- the owner lane (PR 47) --------------------------------------------------
+# One int32 lane says what a slot holds: FREE, or `-1 - nullbits` of its
+# group.  Inside a step it also holds claims; none may outlive the step,
+# whether the step placed its rows or overflowed and took them back.
+
+def test_owner_is_free_or_negative_after_every_step_of_a_long_stream():
+    """200 steps of hostile two-column keys (NULLs in both) into a table
+    that starts at 256 slots: steps overflow, the table is regrown as the
+    engine regrows it and the batch given again.  After EVERY step, kept
+    or undone, the owner lane is settled; at the end the table is a plain
+    group-by of the stream."""
+    lanes, slots = 64, 256
+    rng = np.random.default_rng(47)
+    carry = init_hash_carry([jnp.int64, jnp.int32], _KINDS, _ACC_DTYPES,
+                            slots)
+    seen_k0, seen_v0, seen_k1, seen_v1 = [], [], [], []
+    seen_vals, seen_av, seen_mask = [], [], []
+    undone = 0
+    for step in range(200):
+        span = 4 + 2 * step              # the key domain widens: new groups
+        k0 = rng.integers(0, span, lanes).astype(np.int64) * 1000003
+        k1 = rng.integers(0, 3, lanes).astype(np.int32)
+        v0, v1 = rng.random(lanes) > 0.1, rng.random(lanes) > 0.1
+        vals, av = rng.random(lanes), rng.random(lanes) > 0.2
+        mask = rng.random(lanes) > 0.2
+        args = ([(jnp.asarray(k0), jnp.asarray(v0)),
+                 (jnp.asarray(k1), jnp.asarray(v1))],
+                jnp.asarray(vals), jnp.asarray(av), jnp.asarray(mask))
+        out, overflow, groups, _ = _step_all_kinds(carry, *args)
+        _assert_owner_is_settled(out)
+        while int(overflow) > 0:
+            undone += 1
+            _assert_same_table(out, carry)
+            slots *= 2
+            grown, re_overflow, _, _ = jax.jit(
+                lambda c, s=slots: rehash_carry(c, _KINDS, s))(carry)
+            _assert_owner_is_settled(grown)
+            if int(re_overflow) > 0:
+                continue
+            carry = grown
+            out, overflow, groups, _ = _step_all_kinds(carry, *args)
+            _assert_owner_is_settled(out)
+        carry = out
+        for acc, new in zip((seen_k0, seen_v0, seen_k1, seen_v1, seen_vals,
+                             seen_av, seen_mask),
+                            (k0, v0, k1, v1, vals, av, mask)):
+            acc.append(new)
+    assert undone >= 3, "the stream never overflowed (tune the sizes)"
+    cat = np.concatenate
+    key_cols = [(cat(seen_k0), cat(seen_v0)), (cat(seen_k1), cat(seen_v1))]
+    want = _reference(key_cols, [(k, cat(seen_vals), cat(seen_av))
+                                 for k in _KINDS], cat(seen_mask))
+    assert int(carry.groups) == len(want)
+    _assert_same_groups(_table_groups(carry), want)
+
+
+_OVERFLOW_CASES = {
+    # name: (groups by home slot, [full rounds, narrow rounds], rows left)
+    # forty homes of thirty keys each, four slots apart: their chains run
+    # into one another, 1,035 rows are still unplaced after sixteen
+    # rounds, more than the narrow width holds, so every round ran at
+    # full width
+    "in_the_full_rounds": ([(30, 40), (1, 2000)], [16, 0], 1035),
+    # one chain of forty: the other rows place in round one, the chain
+    # is compacted and spends the fifteen rounds left at narrow width
+    "in_the_narrow_rounds": ([(1, 4056), (40, 1)], [1, 15], 24),
+}
+
+
+@pytest.mark.parametrize("case", list(_OVERFLOW_CASES))
+def test_an_undone_step_regrown_and_given_again_is_a_numpy_group_by(
+        case, sieve):
+    """The host's regrow-and-resume over the step's undo: a batch that
+    overflows (at either width) hands back the table it was given; that
+    table, re-inserted into a larger one by rehash_carry and given the
+    SAME batch again, holds exactly what a group-by of every row holds."""
+    groups, want_rounds, want_left = _OVERFLOW_CASES[case]
+    lanes, slots = _LANES, 1 << 14
+    rng = np.random.default_rng(len(case))
+    ones = np.ones(lanes, bool)
+    # the table already holds 300 groups, NULL among them
+    hd = rng.integers(1, 300, lanes).astype(np.int64) * 7919
+    hv = rng.random(lanes) > 0.05
+    hvals, hmask = rng.random(lanes), rng.random(lanes) > 0.3
+    carry, model, overflow, _ = _step_and_model(
+        _fresh([np.int64], slots), _ModelTable([np.int64], slots),
+        [(hd, hv)], hvals, hmask)
+    assert overflow == 0
+    kd, mask = _engineered(sieve, lanes, slots, groups, rng)
+    vals = rng.random(lanes)
+    out, _after, overflow, rounds = _step_and_model(
+        carry, model, [(kd, ones)], vals, mask)
+    assert rounds == want_rounds and overflow == want_left
+    _assert_same_table(out, carry)
+    # regrow from the table handed BACK, as a donated fold has no other
+    held = int(out.groups)
+    grown, re_overflow, re_groups, _ = jax.jit(
+        lambda c: rehash_carry(c, ["sum", "count"], 16 * slots,
+                               rehash_width(held, slots)))(out)
+    assert int(re_overflow) == 0 and int(re_groups) == held
+    again, overflow, total, _ = _step_sum_count(
+        grown, [(jnp.asarray(kd), jnp.asarray(ones))], jnp.asarray(vals),
+        jnp.asarray(mask))
+    assert int(overflow) == 0
+    _assert_owner_is_settled(again)
+    key_col = (np.concatenate([hd, kd]), np.concatenate([hv, ones]))
+    all_vals = np.concatenate([hvals, vals])
+    want = _reference([key_col],
+                      [("sum", all_vals, None), ("count", None, None)],
+                      np.concatenate([hmask, mask]))
+    assert int(total) == len(want)
+    _assert_same_groups(_table_groups(again), want)
+
+
+# -- today's step against the parent's, kept word for word -------------------
+# tests/hash_step_parent.py is `hash_agg_step` as it was before the owner
+# lane.  The probe, the round limit, the two widths and the winner are the
+# same, so every group lands in the slot it landed in and the rounds at
+# both widths are the parent's, digit for digit.
+
+@partial(jax.jit, static_argnames=("rounds",))
+def _parent_all_kinds(carry, key_cols, vals, av, mask, rounds=16):
+    return parent_step(carry, key_cols, [(k, vals, av) for k in _KINDS],
+                       mask, probe_rounds=rounds)
+
+
+@partial(jax.jit, static_argnames=("rounds",))
+def _today_all_kinds(carry, key_cols, vals, av, mask, rounds=16):
+    return hash_agg_step(carry, key_cols, [(k, vals, av) for k in _KINDS],
+                         mask, probe_rounds=rounds)
+
+
+def _assert_the_parents_table(got, want):
+    """Slot for slot: the same used slots, and at them the same keys,
+    the same key validity (read from the owner lane) and accumulators."""
+    _assert_owner_is_settled(got)
+    used = np.asarray(want.used)
+    np.testing.assert_array_equal(np.asarray(got.used), used)
+    for gk, gv, wk, wv in zip(_keys(got), got.key_valid, want.keys,
+                              want.key_valid):
+        np.testing.assert_array_equal(np.asarray(gv)[used],
+                                      np.asarray(wv)[used])
+        np.testing.assert_array_equal(gk[used], np.asarray(wk)[used])
+    for a, b in zip(jax.tree_util.tree_leaves((got.accs, got.acc_valid)),
+                    jax.tree_util.tree_leaves((want.accs, want.acc_valid))):
+        np.testing.assert_array_equal(np.asarray(a)[used],
+                                      np.asarray(b)[used])
+
+
+_PARENT_STREAMS = {
+    # name: ([(key dtype, span of its values or None for the hostile
+    #         column of _trial_key_col)], lanes, slots, steps, rounds,
+    #        steps that overflow)
+    "one_int64_narrow_rounds": ([(np.int64, 5_000)], 4096, 1 << 14, 6, 16,
+                                False),
+    "pair_of_int64_like_q01": ([(np.int64, 800), (np.int64, 6)], 4096,
+                               1 << 14, 6, 16, False),
+    "float_and_int32_with_nulls": ([(np.float64, None), (np.int32, 4)],
+                                   2048, 1 << 12, 8, 16, False),
+    "eight_narrow_columns": ([(np.int32, 5), (np.int16, None),
+                              (np.int8, None), (np.int64, 3)] * 2,
+                             1024, 1 << 15, 6, 16, False),
+    "overflowing_small_table": ([(np.int64, 5_000)], 1024, 1 << 10, 6, 16,
+                                True),
+    "four_rounds_only": ([(np.int64, 400), (np.int32, 3)], 4096, 1 << 13,
+                         5, 4, True),
+}
+
+
+@pytest.mark.parametrize("stream", list(_PARENT_STREAMS))
+def test_every_step_is_the_parents_step_slot_for_slot(stream):
+    columns, lanes, slots, steps, rounds, overflows = _PARENT_STREAMS[stream]
+    rng = np.random.default_rng(len(stream))
+    key_dtypes = [jnp.dtype(dt) for dt, _span in columns]
+    today = init_hash_carry(key_dtypes, _KINDS, _ACC_DTYPES, slots)
+    parent = parent_init(key_dtypes, _KINDS, _ACC_DTYPES, slots)
+    overflowed = 0
+    for _ in range(steps):
+        key_cols = []
+        for dt, span in columns:
+            d, v = _trial_key_col(rng, lanes, dt)
+            if span is not None:
+                d = (rng.integers(0, span, lanes) * 7919 - span).astype(dt)
+            key_cols.append((jnp.asarray(d), jnp.asarray(v)))
+        args = (key_cols, jnp.asarray(rng.random(lanes)),
+                jnp.asarray(rng.random(lanes) > 0.2),
+                jnp.asarray(rng.random(lanes) > 0.25))
+        today, t_over, t_groups, t_rounds = _today_all_kinds(
+            today, *args, rounds=rounds)
+        parent, p_over, p_groups, p_rounds = _parent_all_kinds(
+            parent, *args, rounds=rounds)
+        assert np.asarray(t_rounds).tolist() == np.asarray(p_rounds).tolist()
+        assert int(t_over) == int(p_over)
+        assert int(t_groups) == int(p_groups) == int(today.groups)
+        overflowed += int(p_over) > 0
+        _assert_the_parents_table(today, parent)
+    assert int(today.groups) > lanes // 4 or overflows
+    assert (overflowed > 0) == overflows
+
+
+@pytest.mark.parametrize("case", list(_WIDTH_CASES))
+def test_the_round_models_cases_are_the_parents_too(case, sieve):
+    """PR 29's eight engineered cases, through both steps: the rounds at
+    each width and every group's slot are the parent's."""
+    lanes, slots, groups, want_rounds, want_left = _WIDTH_CASES[case]
+    rng = np.random.default_rng(len(case))
+    kd, mask = _engineered(sieve, lanes, slots, groups, rng)
+    args = ([(jnp.asarray(kd), jnp.ones(lanes, bool))],
+            jnp.asarray(rng.random(lanes)), jnp.ones(lanes, bool),
+            jnp.asarray(mask))
+    today, t_over, _, t_rounds = _today_all_kinds(
+        init_hash_carry([jnp.int64], _KINDS, _ACC_DTYPES, slots), *args)
+    parent, p_over, _, p_rounds = _parent_all_kinds(
+        parent_init([jnp.int64], _KINDS, _ACC_DTYPES, slots), *args)
+    assert np.asarray(t_rounds).tolist() == np.asarray(p_rounds).tolist() \
+        == want_rounds
+    assert int(t_over) == int(p_over) == want_left
+    _assert_the_parents_table(today, parent)
+
+
+# -- what a step touches (the structural guard) ------------------------------
+# A step costs by its lanes alone: nothing in it is allocated, selected or
+# reduced over the table's slots, and a probe round is 3 + 2k indexed
+# operations for k key lanes, none wider than 32 bits.
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, each with
+    the loop bodies it lies in (innermost last)."""
+    def inner(jaxpr, loops):
+        for eqn in jaxpr.eqns:
+            yield eqn, loops
+            for name, sub in eqn.params.items():
+                subs = sub if isinstance(sub, (tuple, list)) else [sub]
+                for j in subs:
+                    j = getattr(j, "jaxpr", j)
+                    if hasattr(j, "eqns"):
+                        body = eqn.primitive.name == "while" \
+                            and name == "body_jaxpr"
+                        yield from inner(j, loops + ((id(eqn),) if body
+                                                     else ()))
+    yield from inner(jaxpr, ())
+
+
+_STRUCTURE_CASES = {
+    # name: (key dtypes, key lanes)
+    "q01s_two_int64_keys": ([np.int64, np.int64], 4),
+    "one_int32_key": ([np.int32], 1),
+    "int64_float64_int16": ([np.int64, np.float64, np.int16], 4),
+}
+
+
+@pytest.mark.parametrize("case", list(_STRUCTURE_CASES))
+def test_a_step_runs_over_its_lanes_and_a_round_is_3_plus_2k(case):
+    key_dtypes, k = _STRUCTURE_CASES[case]
+    S, n = 1 << 16, 4096
+
+    def step(carry, key_cols, vals, av, mask):
+        return hash_agg_step(carry, key_cols,
+                             [(kind, vals, av) for kind in _KINDS], mask)
+
+    carry = jax.eval_shape(lambda: init_hash_carry(
+        [jnp.dtype(d) for d in key_dtypes], _KINDS, _ACC_DTYPES, S))
+    assert sum(len(lanes) for lanes in carry.keys) == k
+    args = (carry,
+            [(jax.ShapeDtypeStruct((n,), jnp.dtype(d)),
+              jax.ShapeDtypeStruct((n,), jnp.bool_)) for d in key_dtypes],
+            jax.ShapeDtypeStruct((n,), jnp.float64),
+            jax.ShapeDtypeStruct((n,), jnp.bool_),
+            jax.ShapeDtypeStruct((n,), jnp.bool_))
+    # the lowered text: nothing selected, broadcast or reduced over S
+    text = jax.jit(step).lower(*args).as_text()
+    wide = [ln.strip() for ln in text.splitlines()
+            if f"tensor<{S}x" in ln
+            and any(f"stablehlo.{op}" in ln
+                    for op in ("select", "broadcast", "reduce", "constant",
+                               "iota", "convert"))]
+    assert not wide, wide[:3]
+    # the program's equations: whatever reads or writes an S-wide value
+    # is a scatter, a gather or the control flow that carries the table
+    def indexed(name):
+        return name == "gather" or name.startswith("scatter")
+
+    carries = ("while", "cond", "pjit", "jit", "closed_call", "core_call",
+               "custom_jvp_call")
+    rounds = {}
+    for eqn, loops in _equations(jax.make_jaxpr(step)(*args).jaxpr):
+        name = eqn.primitive.name
+        touches = any(getattr(v.aval, "shape", ()) == (S,)
+                      for v in (*eqn.invars, *eqn.outvars)
+                      if hasattr(v, "aval"))
+        assert not touches or indexed(name) or name in carries, \
+            str(eqn)[:300]
+        if indexed(name) and loops:
+            for v in (*eqn.invars, *eqn.outvars):
+                # no indexed operation of a round moves a 64-bit lane,
+                # but for a float64 key (its bits cannot be split on the
+                # chip)
+                assert v.aval.dtype.itemsize <= 4 \
+                    or v.aval.dtype == np.float64, str(eqn)[:300]
+            rounds[loops[-1]] = rounds.get(loops[-1], 0) + 1
+    # the two probes (full width, narrow width), each 3 + 2k a round
+    assert sorted(rounds.values()) == [3 + 2 * k] * 2, rounds

@@ -353,7 +353,7 @@ def capacities(monkeypatch):
         fold = real(*a, **k)
 
         def spy(carry, *rest):
-            seen.append(int(carry.used.shape[0]))
+            seen.append(int(carry.owner.shape[0]))
             return fold(carry, *rest)
         return spy
 
@@ -1262,3 +1262,141 @@ def test_fold_lane_fill_share_is_rows_over_lanes(counters, want):
     got = cell.module("sources", spec["source"]).read(
         spec, {"counters": counters, "queries": 1})
     assert got == (want if want is None else pytest.approx(want))
+
+
+# -- the owner lane: null bits and the undo's counter (ISSUE 47) -------------
+# A slot's int32 `owner` says which keys of its group are NULL; the drains
+# read the keys' validity from it over the used slots alone.
+
+def _null_key_agg(tmp_path, k, tag, groups=64, rows=3000):
+    """sum(v) group by `k` WIDE int64 columns, fused.  Group g holds
+    NULL in column j when (g + j) % 5 == 0, so every column is NULL in
+    some groups, some groups in several columns (group 0 of one column:
+    the all-NULL key), and two groups differ in their values, in their
+    NULLs or in both.  Returns (plan, {key tuple: sum})."""
+    from blaze_tpu.plan.column_pruning import prune_columns
+    from blaze_tpu.plan.fused import fuse_plan
+    from blaze_tpu.plan.planner import collapse_filter_project, create_plan
+    rng = np.random.default_rng(k)
+    gid = rng.integers(0, groups, rows)
+    vals = rng.random(rows)
+    cols, fields = {}, []
+    for j in range(k):
+        data = _wide((gid * (j + 3)) % 7 + gid)
+        null = (gid + j) % 5 == 0
+        cols[f"k{j}"] = pa.array(data, type=pa.int64(),
+                                 mask=null)
+        fields.append({"name": f"k{j}", "type": {"id": "int64"},
+                       "nullable": True})
+    cols["v"] = pa.array(vals)
+    fields.append({"name": "v", "type": {"id": "float64"}, "nullable": True})
+    t = pa.table(cols)
+    p = str(tmp_path / f"{tag}.parquet")
+    pq.write_table(t, p, row_group_size=512)
+    plan = {"kind": "hash_agg",
+            "groupings": [{"expr": {"kind": "column", "index": j},
+                           "name": f"k{j}"} for j in range(k)],
+            "aggs": [{"fn": "sum", "mode": "partial", "name": "s",
+                      "args": [{"kind": "column", "index": k}]}],
+            "input": {"kind": "parquet_scan", "schema": {"fields": fields},
+                      "file_groups": [[p]]}}
+    want = {}
+    keys = [t.column(j).to_pylist() for j in range(k)]
+    for i, key in enumerate(zip(*keys)):
+        want[key] = want.get(key, 0.0) + vals[i]
+    return fuse_plan(prune_columns(collapse_filter_project(
+        create_plan(plan)))), want
+
+
+def _assert_groups(got_keys, got_sums, want):
+    got = {}
+    for key, s in zip(zip(*got_keys), got_sums):
+        assert key not in got, "a group came out of the table twice"
+        got[key] = s
+    assert set(got) == set(want)
+    for key, s in want.items():
+        assert got[key] == pytest.approx(s, rel=1e-12)
+
+
+@pytest.mark.parametrize("consumer", ["emit_hash", "drain_device"])
+@pytest.mark.parametrize("k", [1, 2, 8, 31])
+def test_null_keys_round_trip_through_the_owner_lane(tmp_path, loop_on,
+                                                     small_tables, k,
+                                                     consumer):
+    from blaze_tpu.plan import fused, stage_compiler
+    from blaze_tpu.runtime import loop as device_loop
+    plan, want = _null_key_agg(tmp_path, k, f"nulls{k}{consumer}")
+    assert isinstance(plan, fused.FusedPartialAggExec)
+    before = xla_stats.snapshot()
+    if consumer == "emit_hash":
+        out = pa.Table.from_batches(
+            [b.compact().to_arrow() for b in plan.execute(0)])
+        keys = [out.column(j).to_pylist() for j in range(k)]
+        sums = out.column(k).to_pylist()
+    else:
+        prog = stage_compiler.compile_task_plan(plan)
+        with task_scope(TaskContext()):
+            carry = device_loop.run_partition(prog, 0, ctx="t")
+            datas, valids, n = device_loop.drain_device(prog, carry)
+        assert n == len(want) == int(carry.groups)
+        keys = [[int(d) if v else None for d, v in
+                 zip(np.asarray(datas[j]), np.asarray(valids[j]))]
+                for j in range(k)]
+        sums = np.asarray(datas[k]).tolist()
+    d = xla_stats.delta(before)
+    assert d["stage_loop_tasks"] == 1 and d["stage_loop_fallbacks"] == 0
+    assert d["stage_loop_undone_steps"] == 0
+    _assert_groups(keys, sums, want)
+
+
+def test_more_key_columns_than_null_bits_are_declined_where_planned(
+        tmp_path, loop_on, small_tables):
+    """32 grouping columns have no room in an int32 owner: the node is
+    left as the aggregation an unfusable one is, and answers."""
+    from blaze_tpu.parallel.stage import MAX_KEY_COLUMNS
+    from blaze_tpu.plan import fused
+    k = MAX_KEY_COLUMNS + 1
+    plan, want = _null_key_agg(tmp_path, k, "nulls32", rows=600)
+    assert not isinstance(plan, fused.FusedPartialAggExec)
+    before = xla_stats.snapshot()
+    out = pa.Table.from_batches(
+        [b.compact().to_arrow() for b in plan.execute(0)])
+    assert xla_stats.delta(before)["stage_loop_tasks"] == 0
+    _assert_groups([out.column(j).to_pylist() for j in range(k)],
+                   out.column(k).to_pylist(), want)
+
+
+@pytest.mark.parametrize("chunk", [2, 4])
+def test_an_overflow_in_the_fold_counts_its_undone_step(tmp_path, loop_on,
+                                                        small_tables, chunk):
+    """`stage_loop_undone_steps`: 0 from reset(), one for the fold's step
+    whose winners were taken back (counted on the device, read with the
+    overflow scalars) and one more for every rehash that had to double
+    again; by chip, and in the explain footer beside `regrows=`."""
+    from blaze_tpu.bridge.metrics import MetricNode
+    from blaze_tpu.plan.explain import QueryProfile
+    xla_stats.reset()
+    assert xla_stats.snapshot()["stage_loop_undone_steps"] == 0
+    config.conf.set(config.STAGE_DEVICE_LOOP_CHUNK.key, chunk)
+    plan, want = _sum_agg(tmp_path, _overflowing_keys(), "partial", "undo")
+    before = xla_stats.snapshot()
+    got = _emitted(plan)
+    d = xla_stats.delta(before)
+    _assert_sums(_merged(got), want)
+    # every regrow follows a fold step that was undone; a rehash that
+    # overflowed was undone too, without a regrow of its own
+    assert d["stage_loop_regrows"] >= 1
+    assert d["stage_loop_undone_steps"] >= d["stage_loop_regrows"]
+    assert sum(c["stage_loop_undone_steps"]
+               for c in xla_stats.chip_stats().values()) == \
+        d["stage_loop_undone_steps"]
+    footer = QueryProfile("q", 0, MetricNode("root"), 1, "local",
+                          xla=d).render_text()
+    assert (f"regrows={d['stage_loop_regrows']} "
+            f"undone={d['stage_loop_undone_steps']} ") in footer
+    # a table sized before the fold undoes nothing
+    plan, want = _sum_agg(tmp_path, _wide(np.arange(3000) % 700), "partial",
+                          "noundo")
+    before = xla_stats.snapshot()
+    _assert_sums(_merged(_emitted(plan)), want)
+    assert xla_stats.delta(before)["stage_loop_undone_steps"] == 0

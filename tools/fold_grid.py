@@ -17,6 +17,13 @@ measurement they were chosen from (PERF.md section 6, PRs 25 and 29):
   * `rehash_cell`: the rehash of `sf100_q01pair_x1`'s reduce tasks, 2^21
     slots at a load of 1/4 into 2^23, both ways: a host-clock reading
     beside the trace's `rehash_device_s`;
+  * `slots`: ONE live 32,768-lane batch of the pair's shape (every lane
+    a new group: a reduce task's tile) into tables of 2^19, 2^21 and
+    2^23 slots at a load of 1/8, as the fold runs it: the table donated
+    to the program, so that nothing but the step itself can copy it.  A
+    step that costs by its lanes alone reads the same at all three;
+    what grows with the table is cost by SLOT (PR 47).  `--only slots`
+    runs this and `rehash_cell` alone;
   * `lanes`: the same step at 65,536 down to 4,096 lanes into one table
     at one load: is a round's cost linear in its lanes down there?
   * `compaction`: what the narrow phase pays before its first round (the
@@ -46,6 +53,7 @@ import sys
 import time
 
 LANES = 65536
+TILE = 32768
 WIDTHS = (65536, 16384, 8192, 4096)
 LIVE_SHARE = 0.41
 KINDS = ("sum",)
@@ -111,6 +119,32 @@ def _filled(fill, rng, carry, groups, want):
     return carry, groups
 
 
+def _slots(stage, say, fill, fresh, log_slots):
+    """One live TILE-lane batch into tables of 2^log_slots slots at a
+    load of 1/8, the table donated (a copy of it made before each timed
+    call, outside the clock)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    step = jax.jit(_step(stage, TILE, 16), donate_argnums=(0,))
+    for log_s in log_slots:
+        slots = 1 << log_s
+        rng = np.random.default_rng(47 + log_s)
+        master, groups = _filled(fill, rng, fresh(slots), 0, slots // 8)
+        batch = _batch(rng, TILE, lanes=TILE)
+        times = []
+        for _ in range(REPEATS + 1):
+            carry = jax.block_until_ready(
+                jax.tree_util.tree_map(jnp.copy, master))
+            t0 = time.perf_counter()
+            out = step(carry, *batch)
+            jax.block_until_ready(out)
+            times.append(time.perf_counter() - t0)
+        say(slots=slots, shape="slots", lanes=TILE,
+            load_before=groups / slots, step_s=statistics.median(times[1:]),
+            **_reading(out))
+
+
 def _compaction(stage, say, rng):
     """The narrow phase's entry alone, at the pair cell's width: 65,536
     lanes of which 1,700 are unplaced (the worst first round of a reduce
@@ -149,6 +183,11 @@ def main() -> int:
     ap.add_argument("--log-slots", type=int, nargs="+", default=[18, 20, 22])
     ap.add_argument("--cell-log-slots", type=int, default=21,
                     help="log2 of the `rehash_cell` table's slots")
+    ap.add_argument("--slots-log-slots", type=int, nargs="+",
+                    default=[19, 21, 23],
+                    help="log2 of the `slots` reading's tables")
+    ap.add_argument("--only", choices=["slots"], default=None,
+                    help="`slots`: that reading and `rehash_cell` alone")
     ap.add_argument("--tree", default=None,
                     help="import blaze_tpu from this checkout")
     ap.add_argument("--tag", default=None,
@@ -183,7 +222,7 @@ def main() -> int:
         """The table into one of four times its slots: over its own
         slots as lanes and, where this tree's rehash takes a width, over
         the lanes that hold its groups."""
-        slots = carry.used.shape[0]
+        slots = jax.tree_util.tree_leaves(carry.keys)[0].shape[0]
         widths = [(shape, None)]
         if hasattr(stage, "rehash_width"):
             widths.append((shape + "_compacted",
@@ -197,7 +236,8 @@ def main() -> int:
                 load_before=groups / slots, step_s=t, **_reading(out))
 
     step16, fill = _step(stage, LANES, 16), _step(stage, LANES, 256)
-    for log_s in args.log_slots:
+    _slots(stage, say, fill, fresh, args.slots_log_slots)
+    for log_s in ([] if args.only else args.log_slots):
         slots = 1 << log_s
         rng = np.random.default_rng(log_s)
         carry = fresh(slots)
@@ -223,7 +263,7 @@ def main() -> int:
     slots = 1 << 20
     rng = np.random.default_rng(29)
     carry, groups = fresh(slots), 0
-    for load in (0.0, 1 / 8):
+    for load in (() if args.only else (0.0, 1 / 8)):
         carry, groups = _filled(fill, rng, carry, groups,
                                 int(load * slots))
         for lanes in WIDTHS:
@@ -233,7 +273,7 @@ def main() -> int:
             say(slots=slots, shape="lanes", lanes=lanes,
                 load_before=groups / slots, step_s=t, **_reading(out))
 
-    if hasattr(stage, "_compact_lanes"):
+    if hasattr(stage, "_compact_lanes") and not args.only:
         _compaction(stage, say, np.random.default_rng(30))
 
     # a reduce task of sf100_q01pair_x1 at its third chunk
