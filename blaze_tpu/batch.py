@@ -25,8 +25,12 @@ and every path routes through jnp exactly as before.
 
 from __future__ import annotations
 
+import collections
+import functools
+import hashlib
+import threading
 from dataclasses import dataclass, replace
-from typing import List, Optional, Sequence, Union
+from typing import List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -285,6 +289,172 @@ class DeviceColumn:
                                        bucket_capacity(len(indices)))
 
 
+
+# ---------------------------------------------------------------------------
+# A dictionary's identity and order
+# ---------------------------------------------------------------------------
+# Codes of two columns compare only under the same dictionary.  A
+# dictionary is a host `pa.Array` of utf8 values; what says whether two of
+# them are the same is a fingerprint of their content, and what says
+# whether code order is string order is `sorted` (strictly ascending,
+# byte-wise: Spark's UTF8String order).  Both are read once an array and
+# kept beside it.
+
+class DictInfo(NamedTuple):
+    fingerprint: bytes
+    sorted: bool
+
+
+_DICT_INFO: "collections.OrderedDict" = collections.OrderedDict()
+_DICT_INFO_LOCK = threading.Lock()
+_DICT_INFO_LIMIT = 512
+
+
+def dict_info(d: pa.Array) -> DictInfo:
+    """`d`'s fingerprint and whether it is sorted, computed once an array
+    (the array is held with its entry, so its id is not used again)."""
+    key, held = id(d), d
+    with _DICT_INFO_LOCK:
+        hit = _DICT_INFO.get(key)
+        if hit is not None and hit[0] is d:
+            _DICT_INFO.move_to_end(key)
+            return hit[1]
+    h = hashlib.blake2b(digest_size=16)
+    h.update(len(d).to_bytes(8, "little"))
+    if len(d):
+        if not pa.types.is_string(d.type):
+            d = d.cast(pa.string())
+        bufs = d.buffers()
+        offsets = np.frombuffer(bufs[1], dtype=np.int32)[
+            d.offset:d.offset + len(d) + 1]
+        h.update((offsets - offsets[0]).tobytes())
+        if bufs[2] is not None:
+            h.update(memoryview(bufs[2])[int(offsets[0]):int(offsets[-1])])
+    import pyarrow.compute as pc
+    ordered = len(d) < 2 or bool(
+        pc.all(pc.less(d.slice(0, len(d) - 1), d.slice(1))).as_py())
+    info = DictInfo(h.digest(), ordered)
+    with _DICT_INFO_LOCK:
+        _DICT_INFO[key] = (held, info)
+        while len(_DICT_INFO) > _DICT_INFO_LIMIT:
+            _DICT_INFO.popitem(last=False)
+    return info
+
+
+def same_dictionary(a: Optional[pa.Array], b: Optional[pa.Array]) -> bool:
+    if a is b:
+        return True
+    if a is None or b is None or len(a) != len(b):
+        return False
+    return dict_info(a).fingerprint == dict_info(b).fingerprint
+
+
+def encode_sorted(arr: pa.Array) -> pa.DictionaryArray:
+    """`arr` (utf8) against the SORTED dictionary of its distinct values:
+    code order is string order.  For a table that is collected once (a
+    broadcast build side), never a fact batch at a time."""
+    import pyarrow.compute as pc
+    if isinstance(arr, pa.ChunkedArray):
+        arr = arr.combine_chunks()
+    if pa.types.is_dictionary(arr.type):
+        # already codes (a scan's encoder): the ENTRIES are sorted and the
+        # codes moved through their ranks, no row is decoded
+        entries = arr.dictionary.cast(pa.string())
+        ranks = pa.array(dict_order_ranks(entries))
+        return pa.DictionaryArray.from_arrays(
+            ranks.take(arr.indices), entries.take(pc.sort_indices(entries)))
+    values = pc.unique(arr.drop_null()).cast(pa.string())
+    values = values.take(pc.sort_indices(values))
+    codes = pc.index_in(arr, value_set=values).cast(pa.int32())
+    return pa.DictionaryArray.from_arrays(codes, values)
+
+
+def one_schema(batches: List[pa.RecordBatch]) -> List[pa.RecordBatch]:
+    """Record batches under one schema: where a column arrived
+    dictionary-encoded in some and plain in others (an encoder that hit
+    its cap mid-stream), its dictionary batches are decoded."""
+    if all(b.schema.equals(batches[0].schema) for b in batches[1:]):
+        return batches
+    plain = {i for b in batches for i, f in enumerate(b.schema)
+             if not pa.types.is_dictionary(f.type)}
+    return [pa.RecordBatch.from_arrays(
+        [d if i in plain else c
+         for i, (c, d) in enumerate(zip(b.columns,
+                                        plain_columns(b.columns)))],
+        names=b.schema.names) for b in batches]
+
+
+def plain_columns(columns) -> list:
+    """Arrow columns with every dictionary-encoded one decoded to its
+    value type."""
+    return [c.cast(c.type.value_type) if pa.types.is_dictionary(c.type)
+            else c for c in columns]
+
+
+def dict_order_ranks(d: pa.Array) -> np.ndarray:
+    """rank[code]: the place of each entry in string order (int32), so
+    that an unsorted dictionary's codes become an order key by one gather."""
+    import pyarrow.compute as pc
+    order = np.asarray(pc.sort_indices(d))
+    ranks = np.empty(len(d), dtype=np.int32)
+    ranks[order] = np.arange(len(d), dtype=np.int32)
+    return ranks
+
+
+def unify_dictionary(base: pa.Array, other: pa.Array
+                     ) -> Tuple[pa.Array, Optional[np.ndarray]]:
+    """(the dictionary both sets of codes are valid under, the remap lane
+    for `other`'s codes or None where they hold as they are).  `base`'s
+    codes always hold: the result is `base` with what it lacks appended
+    (merge order = arrival order, so unification is deterministic), which
+    is why a stream's LAST dictionary decodes every earlier batch."""
+    import pyarrow.compute as pc
+    if same_dictionary(base, other):
+        return base, None
+    if len(other) >= len(base) and same_dictionary(
+            other.slice(0, len(base)), base):
+        return other, None    # an incremental encoder's prefix growth
+    if len(other) < len(base) and same_dictionary(
+            base.slice(0, len(other)), other):
+        return base, None     # an earlier state of that encoder, late
+    pos = pc.index_in(other, value_set=base)
+    missing = np.asarray(pc.is_null(pos))
+    remap = np.asarray(pos.fill_null(0)).astype(np.int32)
+    merged = base
+    if missing.any():
+        remap[missing] = len(base) + np.cumsum(missing)[missing] - 1
+        merged = pa.concat_arrays([base, other.filter(pa.array(missing))])
+    return merged, remap
+
+
+def _remap_lane(remap: np.ndarray):
+    """`remap` padded to a power of two of entries, so that a new
+    dictionary size is rarely a new program."""
+    size = max(LANE, 1 << max(0, len(remap) - 1).bit_length())
+    out = np.zeros(size, dtype=np.int32)
+    out[:len(remap)] = remap
+    return out
+
+
+def _lane_at_codes(lane, codes):
+    return jnp.take(lane, codes, mode="clip")
+
+
+@functools.lru_cache(maxsize=1)
+def _dict_remap_jit():
+    from blaze_tpu.bridge.xla_stats import meter_jit
+    return meter_jit(_lane_at_codes, name="batch.dict_remap")
+
+
+def gather_by_code(lane: np.ndarray, codes):
+    """lane[codes], where `codes` lies: one device program
+    (`jit__lane_at_codes__batch_dict_remap`) for a jax array, numpy otherwise."""
+    if isinstance(codes, np.ndarray):
+        return lane[np.clip(codes, 0, max(0, len(lane) - 1))] \
+            if len(lane) else np.zeros_like(codes)
+    return _dict_remap_jit()(to_device(_remap_lane(lane)), codes)
+
+
 @dataclass
 class DictColumn(DeviceColumn):
     """utf8 column dictionary-encoded for the device lanes: `data` holds
@@ -333,9 +503,7 @@ class DictColumn(DeviceColumn):
         return DictColumn.from_codes(codes, valid, dtype, capacity, d,
                                      stage_host=stage_host)
 
-    def to_arrow(self, num_rows: int, selection: Optional[np.ndarray] = None,
-                 prefetched: Optional[tuple] = None) -> pa.Array:
-        """Decode codes back to plain utf8 (host materialization)."""
+    def _host_codes(self, num_rows: int, selection, prefetched):
         if prefetched is not None:
             codes, valid = prefetched
             codes = codes[:num_rows]
@@ -346,9 +514,40 @@ class DictColumn(DeviceColumn):
         if selection is not None:
             codes = codes[selection[:num_rows]]
             valid = valid[selection[:num_rows]]
+        return codes, valid
+
+    def to_arrow(self, num_rows: int, selection: Optional[np.ndarray] = None,
+                 prefetched: Optional[tuple] = None) -> pa.Array:
+        """Decode codes back to plain utf8 (host materialization)."""
+        codes, valid = self._host_codes(num_rows, selection, prefetched)
         idx = pa.array(codes.astype(np.int64),
                        mask=None if valid.all() else ~valid)
+        if len(codes):
+            from blaze_tpu.bridge import tracing, xla_stats
+            xla_stats.note_dict(dict_rows_decoded=len(codes))
+            tracing.instant("dict_decode", rows=len(codes))
         return self.dictionary.take(idx).cast(self.dtype.to_arrow())
+
+    def to_arrow_coded(self, num_rows: int,
+                       selection: Optional[np.ndarray] = None,
+                       prefetched: Optional[tuple] = None
+                       ) -> pa.DictionaryArray:
+        """The column as Arrow holds a dictionary column: the codes as
+        they are, under this dictionary; nothing is decoded."""
+        codes, valid = self._host_codes(num_rows, selection, prefetched)
+        idx = pa.array(codes.astype(np.int32, copy=False),
+                       mask=None if valid.all() else ~valid)
+        return pa.DictionaryArray.from_arrays(idx, self.dictionary)
+
+    def remapped(self, merged: pa.Array,
+                 remap: Optional[np.ndarray]) -> "DictColumn":
+        """The same values under `merged`; the codes go through `remap`
+        (`unify_dictionary`'s) where they lie."""
+        if remap is None:
+            return self if merged is self.dictionary \
+                else replace(self, dictionary=merged)
+        data = gather_by_code(remap, self.data)
+        return replace(self, data=data, dictionary=merged)
 
     def take_host(self, indices: np.ndarray) -> "DictColumn":
         codes = asnp(self.data)[indices]
@@ -356,6 +555,57 @@ class DictColumn(DeviceColumn):
         return DictColumn.from_codes(codes, valid, self.dtype,
                                      bucket_capacity(len(indices)),
                                      self.dictionary)
+
+
+def column_of(dtype: DataType, data, validity,
+              dictionary: Optional[pa.Array] = None) -> DeviceColumn:
+    """A device column over `data` / `validity`: a `DictColumn` where the
+    lane holds codes under `dictionary`."""
+    if dictionary is None:
+        return DeviceColumn(dtype, data, validity)
+    return DictColumn(dtype, data, validity, dictionary=dictionary)
+
+
+class DictStream:
+    """A stream's dictionary columns under ONE dictionary a column.
+
+    Batches of one stream (a reduce task's blocks of several map tasks, a
+    sort's tiles) may bring each its own dictionary.  Where the
+    fingerprints agree a batch passes as it is.  Where they do not, the
+    stream's dictionary grows by what the batch's has and it lacks
+    (`unify_dictionary`: on the host, over the ENTRIES) and the batch's
+    codes are remapped where they lie, one gather through the remap lane
+    (span `dict_remap`; counters `dict_unified`, `dict_remap_rows`).  The
+    stream's dictionary only ever grows at its end, so its LAST state
+    (`dicts`, by column) decodes every batch handed on before.  `only`
+    names the columns to hold (all of them where it is None)."""
+
+    def __init__(self, only=None):
+        self.dicts: dict = {}
+        self._only = only
+
+    def under_one_dictionary(self, batch: "ColumnBatch") -> "ColumnBatch":
+        from blaze_tpu.bridge import tracing, xla_stats
+        cols = None
+        for i, c in enumerate(batch.columns):
+            if not isinstance(c, DictColumn) or (
+                    self._only is not None and i not in self._only):
+                continue
+            merged, remap = unify_dictionary(
+                self.dicts.get(i, c.dictionary), c.dictionary)
+            self.dicts[i] = merged
+            if remap is None and merged is c.dictionary:
+                continue
+            cols = cols if cols is not None else list(batch.columns)
+            if remap is None:
+                cols[i] = c.remapped(merged, None)
+                continue
+            with tracing.span("dict_remap", rows=batch.num_rows,
+                              entries=len(remap)):
+                cols[i] = c.remapped(merged, remap)
+            xla_stats.note_dict(dict_unified=1,
+                                dict_remap_rows=batch.num_rows)
+        return batch if cols is None else replace(batch, columns=cols)
 
 
 @dataclass
@@ -575,7 +825,10 @@ class ColumnBatch:
                            [self.columns[i] for i in indices],
                            self.num_rows, self.selection)
 
-    def to_arrow(self) -> pa.RecordBatch:
+    def to_arrow(self, keep_dict: bool = False) -> pa.RecordBatch:
+        """`keep_dict`: a dictionary column leaves as an Arrow dictionary
+        array (its codes, nothing decoded), for a consumer that carries
+        codes on: the exchange's writer."""
         # batch ALL device reads (mask + every column) into one device_get:
         # the round trip dominates, and device_get overlaps transfers
         to_fetch = []
@@ -599,9 +852,15 @@ class ColumnBatch:
         for i in dev_idx:
             pre[i] = (fetched[pos], fetched[pos + 1])
             pos += 2
-        arrays = [c.to_arrow(self.num_rows, sel, prefetched=pre[i])
+        coded = [keep_dict and isinstance(c, DictColumn)
+                 for c in self.columns]
+        arrays = [c.to_arrow_coded(self.num_rows, sel, prefetched=pre[i])
+                  if k else c.to_arrow(self.num_rows, sel, prefetched=pre[i])
                   if i in pre else c.to_arrow(self.num_rows, sel)
-                  for i, c in enumerate(self.columns)]
+                  for i, (c, k) in enumerate(zip(self.columns, coded))]
+        if any(coded):
+            return pa.RecordBatch.from_arrays(arrays,
+                                              names=self.schema.names)
         return pa.RecordBatch.from_arrays(arrays, schema=self.schema.to_arrow())
 
     @staticmethod
@@ -630,8 +889,8 @@ class ColumnBatch:
                 cols.append(DeviceColumn(f.data_type, vals, valid))
             elif all(isinstance(b.columns[i], DictColumn) for b in batches):
                 cols.append(_concat_dict_columns(
-                    [(b.columns[i], b.num_rows) for b in batches],
-                    f.data_type, cap))
+                    [b.columns[i] for b in batches],
+                    [b.num_rows for b in batches], f.data_type, cap))
             elif any(isinstance(b.columns[i], DictColumn) for b in batches):
                 # mixed encoded/plain (encoder hit its cardinality cap
                 # mid-stream): decode losslessly to a host column
@@ -657,43 +916,41 @@ class ColumnBatch:
                 f"cols={[f.name for f in self.schema]})")
 
 
-def _concat_dict_columns(parts, dtype: DataType, cap: int) -> DictColumn:
-    """Concatenate dict-encoded columns by unifying their dictionaries:
-    codes remap onto a merged first-seen dictionary (merge order = batch
-    order, so cross-partition unification is deterministic).  The common
-    case — one stream's incremental encoder, where each batch's
-    dictionary is a prefix of the next — costs zero remaps."""
-    import pyarrow.compute as pc
-    merged = None
-    datas, valids = [], []
-    remaps = 0
-    for c, n in parts:
-        codes = asnp(c.data)[:n].astype(np.int64)
-        valid = asnp(c.validity)[:n]
-        d = c.dictionary
-        if merged is None or d is merged or merged.equals(d):
-            merged = d
-        elif len(d) >= len(merged) and d.slice(0, len(merged)).equals(merged):
-            # incremental-encoder prefix growth: old codes stay valid
-            merged = d
-        else:
-            pos = pc.index_in(d, value_set=merged)
-            missing = np.asarray(pc.is_null(pos))
-            remap = np.asarray(pos.fill_null(0)).astype(np.int64)
-            if missing.any():
-                base = len(merged)
-                merged = pa.concat_arrays(
-                    [merged, d.filter(pa.array(missing))])
-                remap[missing] = base + np.cumsum(missing)[missing] - 1
-            codes = remap[codes]
-            remaps += 1
-        datas.append(codes)
-        valids.append(valid)
-    if remaps:
+def _concat_dict_columns(parts: Sequence[DictColumn], rows: Sequence[int],
+                         dtype: DataType, cap: int) -> DictColumn:
+    """Concatenate dict-encoded columns under ONE dictionary: where their
+    fingerprints agree the codes are joined as the int32 lanes they are;
+    where they do not, the dictionaries are unified on the host
+    (`unify_dictionary`: merge order = batch order, an incremental
+    encoder's prefix growth costs nothing) and the codes of a part whose
+    dictionary differs are remapped where they lie (`dict_remap_rows`)."""
+    merged = parts[0].dictionary if parts else None
+    remaps = []
+    for c in parts:
+        merged, remap = unify_dictionary(merged, c.dictionary)
+        remaps.append(remap)
+    unified = sum(r is not None for r in remaps)
+    if unified:
         from blaze_tpu.bridge import xla_stats
-        xla_stats.note_encoding(dict_exchange_remaps=remaps)
-    return DictColumn.from_codes(
-        np.concatenate(datas) if datas else np.zeros(0, np.int64),
-        np.concatenate(valids) if valids else np.zeros(0, bool),
-        dtype, cap, merged if merged is not None
-        else pa.array([], type=pa.string()))
+        xla_stats.note_encoding(dict_exchange_remaps=unified)
+        xla_stats.note_dict(
+            dict_unified=unified,
+            dict_remap_rows=sum(n for n, r in zip(rows, remaps)
+                                if r is not None))
+    xp = xp_of(*[c.data for c in parts]) if parts else np
+    datas, valids = [], []
+    for c, n, remap in zip(parts, rows, remaps):
+        data = c.data[:n]
+        if remap is not None:
+            data = gather_by_code(remap, data)
+        datas.append(data)
+        valids.append(c.validity[:n])
+    total = sum(rows)
+    vals = xp.concatenate(datas) if datas else np.zeros(0, np.int32)
+    valid = xp.concatenate(valids) if valids else np.zeros(0, bool)
+    if cap > total:
+        vals = xp.pad(vals, (0, cap - total))
+        valid = xp.pad(valid, (0, cap - total))
+    return DictColumn(dtype, vals.astype(xp.int32), valid,
+                      dictionary=merged if merged is not None
+                      else pa.array([], type=pa.string()))

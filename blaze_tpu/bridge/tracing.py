@@ -195,6 +195,15 @@ SPAN_NAMES: Dict[str, str] = {
                          "argument, a decimal average's final quotient "
                          "(ops/agg/exec.py; attrs op, rows, precision, "
                          "scale)",
+    "dict_decode": "dictionary codes decoded to strings on the host "
+                   "(batch.py DictColumn.to_arrow), an instant under the "
+                   "operator's span: where rows are shown, or where an "
+                   "operator has no code lane (attrs rows)",
+    "dict_remap": "a batch's codes moved under its stream's dictionary: "
+                  "the dictionaries unified on the host, the codes "
+                  "gathered through the remap lane where they lie "
+                  "(shuffle/reader.py, batch.py concat; attrs rows, "
+                  "entries)",
     "table_init": "the stage loop allocates an empty hash table "
                   "(runtime/loop.py _fold_partition; attrs slots, device)",
     "gc_pause": "one run of Python's cyclic garbage collector, on the "

@@ -147,7 +147,10 @@ _stage_loop = {"stage_loop_programs_built": 0,
 # streamed through the pass-through lane un-aggregated.
 _agg = {"partial_agg_skip_events": 0, "partial_agg_skipped_rows": 0,
         "partial_agg_probe_rows": 0, "partial_agg_probe_groups": 0,
-        "partial_agg_switch_rows": 0, "partial_agg_spill_switches": 0}
+        "partial_agg_switch_rows": 0, "partial_agg_spill_switches": 0,
+        # rows the unfused, batch-at-a-time aggregation took (AggExec:
+        # outside every fused lane and the stage loop)
+        "agg_eager_rows": 0}
 
 # Sort and sort-merge join on the device (ops/sort.py, ops/joins/exec.py):
 # rows whose sort permutation came from the device and, of those, the rows
@@ -182,6 +185,20 @@ _window = {"window_rows": 0, "window_resident_rows": 0,
 # integer key, no hash and no search).  By chip in `chip_stats()` too.
 _join = {"join_probe_device_rows": 0, "join_probe_host_rows": 0,
          "join_probe_direct_rows": 0}
+
+# Strings as dictionary codes (batch.DictColumn) and the Expand a fused
+# stage folds in place: rows an `ExpandExec` absorbed into a stage loop
+# would have emitted (`expand_rows_out`: input rows x projections, never
+# batches); rows x utf8 columns that crossed an operator boundary as int32
+# codes (`dict_rows_coded`: a device probe's output, a fold's drain, an
+# exchange block written or read, a resident sort or window) and that were
+# decoded to strings (`dict_rows_decoded`: `DictColumn.to_arrow`, with a
+# `dict_decode` instant under the operator's span); dictionaries that
+# differed from their stream's and were unified on the host
+# (`dict_unified`) and the rows whose codes were then remapped on the
+# device (`dict_remap_rows`).  By chip in `chip_stats()` too.
+_dicts = {"expand_rows_out": 0, "dict_rows_coded": 0,
+          "dict_rows_decoded": 0, "dict_unified": 0, "dict_remap_rows": 0}
 
 # Streaming-runtime accounting (streaming/executor.py StreamExecutor):
 # committed epochs and their wall time, rows/records through the
@@ -485,6 +502,7 @@ def _chip_entry(chip: int) -> Dict[str, int]:
                                 "coalesce_tiled_rows": 0,
                                 "coalesce_concat_rows": 0,
                                 **{k: 0 for k in _window},
+                                **{k: 0 for k in _dicts},
                                 **{k: 0 for k in _CHIP_TABLE_KEYS}}
     return entry
 
@@ -555,7 +573,8 @@ def chip_stats() -> Dict[int, Dict[str, int]]:
     "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes",
     "stage_loop_undone_steps", "stage_loop_decimal_rows", "sort_resident_rows",
     "coalesce_tiled_rows", "coalesce_concat_rows", the four window
-    counters (`_window`) and the stage loop's table counters
+    counters (`_window`), the five dictionary and Expand counters
+    (`_dicts`) and the stage loop's table counters
     (_CHIP_TABLE_KEYS)} since the last reset: what each chip was given to
     do."""
     with _lock:
@@ -1126,6 +1145,12 @@ def note_partial_agg_rows(rows: int) -> None:
         _agg["partial_agg_skipped_rows"] += int(rows)
 
 
+def note_agg_eager(rows: int) -> None:
+    """Rows an unfused `AggExec` took, a batch at a time."""
+    with _lock:
+        _agg["agg_eager_rows"] += int(rows)
+
+
 def agg_stats() -> dict:
     with _lock:
         return dict(_agg)
@@ -1169,6 +1194,24 @@ def note_window(rows: int, chip: int, resident: bool,
 def window_stats() -> dict:
     with _lock:
         return dict(_window)
+
+
+def note_dict(chip: Optional[int] = None, **deltas: int) -> None:
+    """Dictionary-code and Expand accounting: kwargs name `_dicts` keys
+    (all sums); `chip` None reads the current task's."""
+    if chip is None:
+        from blaze_tpu.bridge.context import current_task
+        chip = current_task().device_id
+    with _lock:
+        entry = _chip_entry(chip)
+        for k, v in deltas.items():
+            _dicts[k] += int(v)
+            entry[k] += int(v)
+
+
+def dict_stats() -> dict:
+    with _lock:
+        return dict(_dicts)
 
 
 def note_stream_epoch(wall_ns: int, rows: int = 0,
@@ -1338,6 +1381,7 @@ def snapshot() -> dict:
     flat.update(stage_loop_stats())
     flat.update(sortmerge_stats())
     flat.update(window_stats())
+    flat.update(dict_stats())
     flat.update(join_stats())
     flat.update(stream_stats())
     flat.update(worker_stats())
@@ -1385,6 +1429,8 @@ def reset() -> None:
             _window[k] = 0
         for k in _join:
             _join[k] = 0
+        for k in _dicts:
+            _dicts[k] = 0
         for k in _stream:
             _stream[k] = 0
         for k in _workers:

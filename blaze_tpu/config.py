@@ -375,15 +375,20 @@ FUSED_DICT_DEVICE_MAX_SLOTS = int_conf(
     "Dense code-table ceiling for the dict-device strategy; growth "
     "past it falls back to the host-vectorized aggregation.")
 ENCODING_DICT_ENABLE = bool_conf(
-    "auron.tpu.encoding.dict.enable", False,
+    "auron.tpu.encoding.dict.enable", True,
     "Dictionary-encode utf8 columns at scan decode: the device lanes "
     "see only the int32 code column, so group-by/join keys, equality "
     "filters and IN-list predicates ride the existing int lanes "
     "(expr programs, device stage loop, hash kernels); strings decode "
     "back to utf8 only at host materialization.  Operations the codes "
     "cannot answer (substring, LIKE, concat) fall back eager per "
-    "EXPRESSION, not per stage.  Off by default; the disabled path is "
-    "byte-identical to pre-encoding behavior.", category="encoding")
+    "EXPRESSION, not per stage.  A utf8 column is then a DictColumn from "
+    "where it enters a query to where a row is shown: broadcast build "
+    "sides are encoded against sorted dictionaries, a fused stage's "
+    "string group keys are key lanes of the stage loop's table, the "
+    "exchange carries codes and the resident sort and window take them.  "
+    "On by default since PR 48; the disabled path is byte-identical to "
+    "pre-encoding behavior.", category="encoding")
 ENCODING_DICT_MAX_ENTRIES = int_conf(
     "auron.tpu.encoding.dict.maxEntries", 1 << 16,
     "Per-column dictionary cardinality ceiling for scan-side string "

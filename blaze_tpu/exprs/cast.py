@@ -50,8 +50,11 @@ class Cast(PhysicalExpr):
         if src == self.to:
             return v
         ansi = self.ansi_capable and config.ANSI_ENABLED.get()
-        if (v.is_device and self.to.is_fixed_width and
-                _device_supported(src, self.to)):
+        # (dictionary codes are not the values: a coded utf8 column casts
+        # through the strings it stands for, on the host)
+        if (v.is_device and v.dictionary is None
+                and self.to.is_fixed_width
+                and _device_supported(src, self.to)):
             data, valid = cast_kernels.cast_column(v.data, v.validity,
                                                    src, self.to)
             if ansi:

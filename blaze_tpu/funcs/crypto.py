@@ -82,7 +82,7 @@ def _hash_impl(algo: str, out_dtype):
         cols = []
         n = batch.num_rows
         for v in args:
-            if v.is_device:
+            if v.is_device and v.dictionary is None:
                 cols.append((v.data, v.validity, v.dtype.id.value))
             else:
                 arr = v.to_host(n)

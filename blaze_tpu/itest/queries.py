@@ -802,7 +802,9 @@ def q51(paths, tables, partitions: int = 2):
 
 def q67(paths, tables, partitions: int = 2):
     """Rollup(category, class) of store revenue + rank() within category
-    by revenue desc, rank <= 10 (the q67 shape: Expand + window rank)."""
+    by revenue desc, rank <= 10 (the q67 shape: Expand + window rank, two
+    keys over three levels).  The query at the template's own eight keys
+    and nine levels is `benchmark/queries/q67.py`."""
     ss, it, dd = tables["store_sales"], tables["item"], tables["date_dim"]
 
     dd_f = filter_(scan(paths, tables, "date_dim"),

@@ -293,21 +293,31 @@ def hash_columns(columns: Sequence[Tuple], seed: int = 42, xp=jnp,
     """Spark-chained multi-column hash.
 
     columns: sequence of (values, validity_or_None, type_id_str) where values
-    for utf8/binary are (byte_mat, lengths) tuples.
+    for utf8/binary are (byte_mat, lengths) tuples and for utf8_dict
+    (codes, the dictionary entries' byte_mat, their lengths).
     Returns int32 array (murmur3) or int64 array (xxhash64).
     """
     assert columns, "need at least one column"
     if num_rows is None:
         first = columns[0][0]
         num_rows = first[0].shape[0] if isinstance(first, tuple) else first.shape[0]
+        # (a utf8_dict column's first array is its codes: a row each)
     if algo == "murmur3":
         seeds = xp.full(num_rows, seed, dtype=xp.uint32)
     else:
         seeds = (xp.full(num_rows, seed, dtype=xp.int64)).view(xp.uint64) if xp is np \
             else jnp.full(num_rows, seed, dtype=jnp.int64).view(jnp.uint64)
     for values, validity, tid in columns:
-        if tid in ("utf8", "binary"):
-            byte_mat, lengths = values
+        if tid in ("utf8", "binary", "utf8_dict"):
+            if tid == "utf8_dict":
+                # a dictionary column: the ENTRIES' bytes, laid out once a
+                # dictionary, gathered by code; the hash is the string's
+                # own, so a coded and a plain batch of one exchange agree
+                codes, entry_mat, entry_len = values
+                byte_mat = xp.take(entry_mat, codes, axis=0)
+                lengths = xp.take(entry_len, codes)
+            else:
+                byte_mat, lengths = values
             fn = murmur3_hash_bytes if algo == "murmur3" else xxhash64_bytes
             h = fn(byte_mat, lengths, seeds, xp)
             seeds = xp.where(validity, h, seeds) if validity is not None else h

@@ -193,6 +193,8 @@ class _AggState(MemConsumer):
     # ingest
     # ------------------------------------------------------------------
     def process(self, batch: ColumnBatch) -> Iterator[pa.RecordBatch]:
+        from blaze_tpu.bridge import xla_stats
+        xla_stats.note_agg_eager(batch.selected_count())
         if self.skipping:
             # pass-through lane: no lexsort, no compaction, no dict
             # encode/decode round trip, no spill — raw rows leave as
@@ -214,7 +216,6 @@ class _AggState(MemConsumer):
         else:
             # an aggregation over a decimal outside the stage loop
             n = batch.selected_count()
-            from blaze_tpu.bridge import xla_stats
             xla_stats.note_decimal(agg_rows_host=n)
             with host_interval("agg", n, self._decimal_arg):
                 partial = self._aggregate_input_batch(batch)

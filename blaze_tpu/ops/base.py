@@ -24,7 +24,8 @@ import jax
 import numpy as np
 
 from blaze_tpu import config
-from blaze_tpu.batch import ColumnBatch, DeviceColumn, bucket_capacity
+from blaze_tpu.batch import (ColumnBatch, DeviceColumn, bucket_capacity,
+                             column_of, same_dictionary)
 from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.metrics import BASELINE_METRICS, MetricNode
@@ -412,11 +413,13 @@ class CoalesceStream:
     dead lanes — the static-shape analog of selection vectors.
 
     A small batch is held and joined with what follows it, by one of two
-    lanes that its own columns choose.  Rows packed to the front of plain
+    lanes that its own columns choose.  Rows packed to the front of
     fixed-width columns held as device arrays (a device probe's output, a
-    compacted filter's) are laid end to end on the chip (`_TileLane`), one
-    program a few batches, and leave as tiles of exactly the batch size.  Any other batch (a host or
-    dictionary column, numpy buffers under host placement) is staged and
+    compacted filter's; a dictionary column is its int32 code lane, laid
+    with rows of the same dictionary) are laid end to end on the chip
+    (`_TileLane`), one program a few batches, and leave as tiles of exactly
+    the batch size.  Any other batch (a host column, numpy buffers under
+    host placement) is staged and
     joined by `ColumnBatch.concat` once the staged rows reach the batch
     size.  A batch of at least half the batch size passes whole while
     nothing is held.
@@ -482,10 +485,17 @@ def _concat(staged: List[ColumnBatch], rows: int, chip: int) -> ColumnBatch:
 
 def _tileable(batch: ColumnBatch) -> bool:
     """Whether `_TileLane` takes the batch as it lies: rows packed to the
-    front, every column a plain fixed-width one held as a device array."""
+    front, every column a fixed-width one held as a device array (a
+    dictionary column is its int32 code lane)."""
     return (batch.selection is None and bool(batch.columns)
-            and all(type(c) is DeviceColumn and isinstance(c.data, jax.Array)
+            and all(isinstance(c, DeviceColumn)
+                    and isinstance(c.data, jax.Array)
                     for c in batch.columns))
+
+
+def _dictionaries(batch: ColumnBatch) -> tuple:
+    """A column's dictionary where it is a `DictColumn`, else None."""
+    return tuple(getattr(c, "dictionary", None) for c in batch.columns)
 
 
 # the most batches one `lay_tile` program takes: a stream holds them as they
@@ -511,6 +521,7 @@ class _TileLane:
         self._tile = 0        # the batch size, and its capacity
         self._lanes = 0
         self._schema = None
+        self._dicts = ()      # a column's dictionary, or None
         self._parts = []      # the batches that wait, and their rows
         self._counts = []
         self._last = None     # the batch laid last
@@ -535,19 +546,27 @@ class _TileLane:
     def _batch(self, cols, n: int) -> ColumnBatch:
         return ColumnBatch(
             self._schema,
-            [DeviceColumn(f.data_type, d, v)
-             for f, (d, v) in zip(self._schema, cols)], n, None)
+            [column_of(f.data_type, d, v, codes)
+             for f, (d, v), codes in zip(self._schema, cols, self._dicts)],
+            n, None)
 
     def lay(self, batch: ColumnBatch, target: int) -> List[ColumnBatch]:
         """`batch`'s rows behind the rows held; the full tiles that makes
-        (and first, where the batch size changed under the rows held, those
-        rows as they are: `lay_tile`'s room is one tile's)."""
+        (and first, where the batch size or a column's dictionary changed
+        under the rows held, those rows as they are: `lay_tile`'s room is
+        one tile's, and its lanes are one dictionary's)."""
         out = []
+        dicts = _dictionaries(batch)
+        if self.rows and not all(same_dictionary(a, b)
+                                 for a, b in zip(dicts, self._dicts)):
+            # codes lie end to end only under one dictionary: the rows
+            # held leave as they are
+            out.append(self.tail())
         if target != self._tile:
             if self.rows:
                 out.append(self.tail())
             self._tile, self._lanes = target, bucket_capacity(target)
-        self._schema = batch.schema
+        self._schema, self._dicts = batch.schema, dicts
         self._parts.append(tuple((c.data, c.validity) for c in batch.columns))
         self._counts.append(batch.num_rows)
         self.rows += batch.num_rows

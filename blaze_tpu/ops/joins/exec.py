@@ -37,7 +37,10 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from blaze_tpu import config
-from blaze_tpu.batch import ColumnBatch, DeviceColumn
+from dataclasses import replace
+
+from blaze_tpu.batch import (ColumnBatch, DeviceColumn, DictColumn,
+                             column_of, one_schema, plain_columns)
 from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.xputil import asnp, to_device, to_host
 from blaze_tpu.bridge.context import current_task
@@ -100,7 +103,7 @@ def _device_hash_keys(batch: ColumnBatch, key_exprs: Sequence[PhysicalExpr]
         v = e.evaluate(batch)
         arr = v.to_host(n)
         key_arrays.append(arr)
-        if v.is_device:
+        if v.is_device and v.dictionary is None:
             data = asnp(v.data)[:cap] if on_host else v.data
             valid = asnp(v.validity)[:cap] if on_host else v.validity
             flat_cols.append((data, valid))
@@ -187,6 +190,9 @@ class _Resident(NamedTuple):
     keys: Optional[Tuple[jax.Array, ...]] = None
     cols: Optional[Tuple[Tuple[jax.Array, jax.Array], ...]] = None
     dtypes: Optional[Tuple[DataType, ...]] = None
+    # a build column's dictionary where it is utf8 (its lane holds int32
+    # codes under it), else None
+    dicts: Optional[Tuple[Optional[pa.Array], ...]] = None
     # of a map with a `direct_key` only: (drow, kmin)
     direct: Optional[Tuple[jax.Array, jax.Array]] = None
 
@@ -197,7 +203,8 @@ class _DeviceBuild(MemConsumer):
     `ucount`) and, where the map is `unique_fixed`, what
     `kernels/join.probe_gather` reads: `urow`, the build row of each
     distinct hash (-1 for a row with a NULL key), the key columns' data
-    and the build columns.  Index arrays are padded to 2^k - 1 entries
+    and the build columns (a utf8 column as the int32 codes of
+    `JoinMap.coded_table`, its sorted dictionary kept beside them).  Index arrays are padded to 2^k - 1 entries
     (the largest int64, counts of 0: nothing matches padding, and the
     search takes as many rounds as over the entries alone) and the rows
     to their capacity bucket, so a new build size is rarely a new
@@ -238,14 +245,16 @@ class _DeviceBuild(MemConsumer):
             nbytes += drow.nbytes
             held = held._replace(direct=to_device((drow, kmin)))
         if jmap.unique_fixed:
-            rows = ColumnBatch.from_arrow(jmap.table)
+            rows = ColumnBatch.from_arrow(jmap.coded_table)
             keys = tuple(e.evaluate(rows).to_device(rows.capacity).data
                          for e in jmap._key_exprs)
             nbytes += rows.nbytes_device() + sum(k.nbytes for k in keys)
             held = held._replace(
                 keys=keys,
                 cols=tuple((c.data, c.validity) for c in rows.columns),
-                dtypes=tuple(c.dtype for c in rows.columns))
+                dtypes=tuple(c.dtype for c in rows.columns),
+                dicts=tuple(getattr(c, "dictionary", None)
+                            for c in rows.columns))
         self.held = held
         self.set_spillable(MemManager.get())
         self.update_mem_used(nbytes)
@@ -277,13 +286,31 @@ class JoinMap:
 
     def __init__(self, table: pa.Table, key_exprs: Sequence[PhysicalExpr],
                  schema: Schema):
-        self.table = table.combine_chunks()
+        # as it was collected: a utf8 column as plain strings or, where
+        # the build side's batches carried dictionary columns, as an Arrow
+        # dictionary array (nothing was decoded to collect it)
+        self._collected = table.combine_chunks()
         self.schema = schema
         self._key_exprs = list(key_exprs)
         self._built = False
-        self.matched = np.zeros(self.table.num_rows, dtype=bool)
+        self.matched = np.zeros(self._collected.num_rows, dtype=bool)
         self._on_device: Dict[int, _DeviceBuild] = {}
         self._on_device_lock = threading.Lock()
+
+    @functools.cached_property
+    def table(self) -> pa.Table:
+        """The build side as plain Arrow columns, for the paths that take
+        rows from it on the host (the pair expansion, Acero): a column
+        collected as codes is decoded here, once a map, and only where
+        such a path asks."""
+        t = self._collected
+        coded = sum(pa.types.is_dictionary(f.type) for f in t.schema)
+        if not coded:
+            return t
+        xla_stats.note_dict(dict_rows_decoded=t.num_rows * coded)
+        tracing.instant("dict_decode", rows=t.num_rows * coded)
+        return pa.Table.from_arrays(plain_columns(t.columns),
+                                    names=t.schema.names)
 
     def _ensure_index(self) -> None:
         """Hash-sort the build side on first probe.  Lazy because the
@@ -291,16 +318,20 @@ class JoinMap:
         touch the hash index at all."""
         if self._built:
             return
-        with tracing.span("join_build", rows=self.table.num_rows,
-                          step="index"):
+        with tracing.span("join_build", rows=self.num_rows, step="index"):
             self._build_index()
         self._built = True
 
     def _build_index(self) -> None:
         from blaze_tpu.kernels.join import build_runs
-        n = self.table.num_rows
+        n = self.num_rows
         if n:
-            cb = ColumnBatch.from_arrow(self.table)
+            # (a string KEY hashes as the string it is; a fixed-width key
+            # does not care how the payload is held)
+            cb = ColumnBatch.from_arrow(
+                self.coded_table if all(
+                    e.data_type(self.schema).is_fixed_width
+                    for e in self._key_exprs) else self.table)
             hashes, any_null, self.key_arrays = _device_hash_keys(
                 cb, self._key_exprs)
             # null keys never match: a reserved hash bucket we skip
@@ -324,7 +355,7 @@ class JoinMap:
 
     @property
     def num_rows(self) -> int:
-        return self.table.num_rows
+        return self._collected.num_rows
 
     @property
     def has_null_keys(self) -> bool:
@@ -337,12 +368,36 @@ class JoinMap:
                      for e in self._key_exprs)
 
     @functools.cached_property
+    def coded_table(self) -> pa.Table:
+        """The build side with every utf8 column dictionary-encoded against
+        the SORTED dictionary of its values: built once a map, where the
+        table was collected, so code order is string order and every task
+        that probes the map hands on the same dictionaries."""
+        from blaze_tpu.batch import encode_sorted
+        t = self._collected
+
+        def utf8(f):
+            return pa.types.is_string(f.type) or pa.types.is_dictionary(
+                f.type)
+
+        if not any(utf8(f) for f in t.schema):
+            return t
+        with tracing.span("join_build", rows=t.num_rows,
+                          step="dictionaries"):
+            return pa.Table.from_arrays(
+                [encode_sorted(col) if utf8(f) else col
+                 for f, col in zip(t.schema, t.columns)],
+                names=t.schema.names)
+
+    @functools.cached_property
     def unique_fixed(self) -> bool:
         """No two build rows share a hash, so a probe row has at most one
-        candidate, and the build side's keys and columns are all
-        fixed-width: the build side `probe_gather` takes."""
+        candidate, the build side's keys are fixed-width and its columns
+        fixed-width or utf8 (a code lane on the chip): the build side
+        `probe_gather` takes."""
         self._ensure_index()
-        return (all(f.data_type.is_fixed_width for f in self.schema)
+        return (all(f.data_type.is_fixed_width
+                    or f.data_type.id == TypeId.UTF8 for f in self.schema)
                 and all(e.data_type(self.schema).is_fixed_width
                         for e in self._key_exprs)
                 and (not len(self.ucount) or int(self.ucount.max()) == 1))
@@ -466,7 +521,7 @@ class JoinMap:
 def build_join_map(batches: Iterator[pa.RecordBatch], schema: Schema,
                    key_exprs: Sequence[PhysicalExpr]) -> JoinMap:
     blist = list(batches)
-    table = (pa.Table.from_batches(blist) if blist
+    table = (pa.Table.from_batches(one_schema(blist)) if blist
              else pa.Table.from_batches([], schema=schema.to_arrow()))
     return JoinMap(table, key_exprs, schema)
 
@@ -535,7 +590,8 @@ class BaseJoinExec(ExecutionPlan):
     def _get_join_map(self, partition: int) -> JoinMap:
         build = 1 if self.build_side == "right" else 0
         child = self.children[build]
-        stream = (b.compact().to_arrow() for b in child.execute(partition))
+        stream = (b.compact().to_arrow(keep_dict=True)
+                  for b in child.execute(partition))
         keys = self.right_keys if build == 1 else self.left_keys
         with tracing.span("join_build", partition=partition,
                           step="collect"):
@@ -570,14 +626,17 @@ class BaseJoinExec(ExecutionPlan):
     def _stream_probe(self, jmap, batches, probe_keys, probe_is_left):
         """Incremental vectorized probe: the build index is hashed once,
         batches stream through lookup (bounded memory)."""
-        on_device = self._probes_on_device(jmap, probe_keys, probe_is_left)
-        direct = on_device and jmap.direct_key is not None
+        eligible = self._probes_on_device(jmap, probe_keys, probe_is_left)
+        direct = eligible and jmap.direct_key is not None
         chip = current_task().device_id
         for batch in batches:
             if batch.num_rows == 0:
                 continue
+            # a utf8 probe column has a device form only as codes
+            on_device = eligible and all(
+                isinstance(c, DeviceColumn) for c in batch.columns)
             xla_stats.note_join_probe(chip, on_device, batch.num_rows,
-                                      direct)
+                                      direct and on_device)
             if on_device:
                 with tracing.span("join_probe", rows=batch.num_rows,
                                   lane="device",
@@ -594,21 +653,43 @@ class BaseJoinExec(ExecutionPlan):
                                          probe_is_left)
         yield from self._emit_unmatched_build(jmap, probe_is_left)
 
+    def device_probe_planned(self) -> bool:
+        """What the PLAN says of `_probes_on_device`, before any build
+        side exists: an inner join with no filter whose keys are
+        fixed-width and of one type a pair and whose columns, both sides,
+        are fixed-width or utf8.  Whether a probe row has at most one
+        candidate is the build side's to say, when it is collected."""
+        sides = (self.children[0].schema, self.children[1].schema)
+        keys = (self.left_keys, self.right_keys)
+        tids = [tuple(_tid(e.data_type(s)) for e in k)
+                for k, s in zip(keys, sides)]
+        return (self.join_type == JoinType.INNER
+                and self.join_filter is None and tids[0] == tids[1]
+                and all(e.data_type(s).is_fixed_width
+                        for k, s in zip(keys, sides) for e in k)
+                and all(f.data_type.is_fixed_width
+                        or f.data_type.id == TypeId.UTF8
+                        for s in sides for f in s))
+
     def _probes_on_device(self, jmap: JoinMap,
                           probe_keys: Sequence[PhysicalExpr],
                           probe_is_left: bool) -> bool:
         """Whether this join's probe batches stay on the chip
         (`_probe_batch_device`): batches live there, the join is inner
-        with no filter, a probe row has at most one candidate, and every
-        key and column of both sides is fixed-width, the keys of one type
-        a pair.  Decided once a join, from what the plan and the build
-        side say; everything else goes through `_probe_batch`."""
+        with no filter, a probe row has at most one candidate, every key
+        of both sides is fixed-width, the keys of one type a pair, and
+        every column fixed-width or utf8 (a build column as its code
+        lane; a probe batch's has to arrive as a `DictColumn`, which
+        `_stream_probe` looks at a batch).  Decided once a join, from what
+        the plan and the build side say; everything else goes through
+        `_probe_batch`."""
         from blaze_tpu.bridge.placement import host_resident
         if (host_resident() or self.join_type != JoinType.INNER
                 or self.join_filter is not None):
             return False
         probe_schema = self.children[0 if probe_is_left else 1].schema
-        return (all(f.data_type.is_fixed_width for f in probe_schema)
+        return (all(f.data_type.is_fixed_width
+                    or f.data_type.id == TypeId.UTF8 for f in probe_schema)
                 and tuple(_tid(e.data_type(probe_schema))
                           for e in probe_keys) == jmap.key_tids
                 and jmap.unique_fixed)
@@ -638,10 +719,17 @@ class BaseJoinExec(ExecutionPlan):
         n = int(to_host(count))
         if n == 0:
             return None
-        probe_out = [DeviceColumn(c.dtype, d, v)
+        # (`replace` keeps a probe column's class: a dictionary column
+        # stays one, under its dictionary)
+        probe_out = [replace(c, data=d, validity=v)
                      for c, (d, v) in zip(batch.columns, probe_cols)]
-        build_out = [DeviceColumn(t, d, v)
-                     for t, (d, v) in zip(build.dtypes, build_cols)]
+        build_out = [column_of(t, d, v, codes)
+                     for t, (d, v), codes in zip(build.dtypes, build_cols,
+                                                 build.dicts)]
+        coded = sum(isinstance(c, DictColumn) for c in probe_out + build_out)
+        if coded:
+            xla_stats.note_dict(chip=current_task().device_id,
+                                dict_rows_coded=n * coded)
         return ColumnBatch(
             self.schema,
             probe_out + build_out if probe_is_left
@@ -1486,7 +1574,7 @@ class BroadcastJoinExec(BaseJoinExec):
             with tracing.span("join_build", partition=partition,
                               step="collect"):
                 for p in range(child.num_partitions):
-                    batches.extend(b.compact().to_arrow()
+                    batches.extend(b.compact().to_arrow(keep_dict=True)
                                    for b in child.execute(p))
                 return build_join_map(iter(batches), child.schema, keys)
         # the cache key folds the build-side output schema: plan rewrites

@@ -33,8 +33,8 @@ import numpy as np
 import pyarrow as pa
 
 from blaze_tpu import config
-from blaze_tpu.batch import ColumnBatch, bucket_capacity
-from blaze_tpu.exprs import PhysicalExpr
+from blaze_tpu.batch import ColumnBatch, DictStream, bucket_capacity
+from blaze_tpu.exprs import BoundReference, PhysicalExpr
 from blaze_tpu.memory import MemConsumer, MemManager, Spill, try_new_spill
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
 from blaze_tpu.schema import Schema, TypeId
@@ -252,6 +252,7 @@ class _SortState(MemConsumer):
         # device can sort where it lies, the partition is staged as
         # (compacted batch, its evaluated keys) and nothing is read back
         self._resident = True
+        self._dict_stream = DictStream()
         self._tiles: List[Tuple[ColumnBatch, list]] = []
         self._tile_bytes = 0
         self._spills: List[Spill] = []
@@ -302,23 +303,30 @@ class _SortState(MemConsumer):
     def _device_tile(self, batch: ColumnBatch):
         """`batch` compacted, beside its evaluated keys, if the device can
         sort it where it lies: compute is placed there, every column is a
-        plain fixed-width device column, every key a device value of a type
-        `order_key` takes.  None for anything else (a utf8, dictionary or
-        host column, a key the host alone orders)."""
+        fixed-width device column (a dictionary column is its int32 code
+        lane, payload or key), every key a device value of a type
+        `order_key` takes or a bare reference to a dictionary column.
+        None for anything else (a plain utf8 or host column, a key the
+        host alone orders)."""
         import jax
         from blaze_tpu.batch import DeviceColumn
         from blaze_tpu.bridge.placement import host_resident
         if host_resident() or not batch.columns or not all(
-                type(c) is DeviceColumn and isinstance(c.data, jax.Array)
+                isinstance(c, DeviceColumn) and isinstance(c.data, jax.Array)
                 and c.data.ndim == 1 for c in batch.columns):
             return None
-        batch = batch.compact()
+        # (a partition's tiles are laid end to end, so a dictionary column
+        # needs ONE dictionary over all of them)
+        batch = self._dict_stream.under_one_dictionary(batch.compact())
         keys = []
         for expr, _, _ in self._specs:
             v = expr.evaluate(batch)
-            if not (v.is_device and v.dictionary is None
-                    and isinstance(v.data, jax.Array)
-                    and v.dtype.id in _DEVICE_KEY_TYPES
+            if v.dictionary is not None and not isinstance(
+                    expr, BoundReference):
+                return None     # codes pass through, nothing computes on them
+            if not (v.is_device and isinstance(v.data, jax.Array)
+                    and (v.dtype.id in _DEVICE_KEY_TYPES
+                         or v.dictionary is not None)
                     and v.data.shape == (batch.capacity,)):
                 return None
             keys.append(v)
@@ -372,13 +380,31 @@ class _SortState(MemConsumer):
         from blaze_tpu.bridge.context import current_task
         from blaze_tpu.kernels import sort as ksort
         from blaze_tpu.xputil import to_host
+        from blaze_tpu.batch import (column_of, dict_info, dict_order_ranks,
+                                     gather_by_code)
+        from blaze_tpu.schema import INT32
         ncols = len(self._schema)
-        key_types = tuple(v.dtype for v in self._tiles[0][1])
+        # a dictionary key orders as int32: its codes where the
+        # partition's dictionary is sorted (code order is string order),
+        # else each code's rank in string order, one gather over the
+        # partition through a lane the host sorts the ENTRIES for
+        dicts = self._dict_stream.dicts
+        key_dicts = [dicts.get(e.index) if v.dictionary is not None
+                     else None
+                     for (e, _, _), v in zip(self._specs, self._tiles[0][1])]
+        key_types = tuple(INT32 if d is not None else v.dtype
+                          for d, v in zip(key_dicts, self._tiles[0][1]))
         out_rows = rows if fetch is None else min(fetch, rows)
         with tracing.span("sort_device", rows=rows, lane="resident") as attrs:
             cols, total = self._laid_tiles(bucket_capacity(rows))
+            cols = list(cols)
+            for i, d in enumerate(key_dicts):
+                if d is not None and not dict_info(d).sorted:
+                    codes, valid = cols[ncols + i]
+                    cols[ncols + i] = (gather_by_code(dict_order_ranks(d),
+                                                      codes), valid)
             digits, varies, perm = ksort.key_digits(
-                cols[ncols:], total, dtypes=key_types,
+                tuple(cols[ncols:]), total, dtypes=key_types,
                 descending=tuple(d for _, d, _ in self._specs),
                 nulls_first=tuple(f for _, _, f in self._specs),
                 float_pair=jax.default_backend() == "tpu")
@@ -387,15 +413,19 @@ class _SortState(MemConsumer):
             moving = [d for d, moves in zip(digits, to_host(varies)) if moves]
             for d in reversed(moving):
                 perm = ksort.sort_pass(d, perm)
-            out = ksort.gather_sorted(cols[:ncols], perm, np.int32(out_rows),
+            out = ksort.gather_sorted(tuple(cols[:ncols]), perm,
+                                      np.int32(out_rows),
                                       out_cap=bucket_capacity(out_rows))
             attrs["passes"] = len(moving)
         xla_stats.note_sort_resident(rows, current_task().device_id)
         self.update_mem_used(0)
+        if dicts:
+            xla_stats.note_dict(dict_rows_coded=rows * len(dicts))
         return ColumnBatch(
             self._schema,
-            [DeviceColumn(f.data_type, d, v)
-             for f, (d, v) in zip(self._schema, out)], out_rows, None)
+            [column_of(f.data_type, d, v, dicts.get(i))
+             for i, (f, (d, v)) in enumerate(zip(self._schema, out))],
+            out_rows, None)
 
     # -- spilling (MemConsumer) --------------------------------------------
     def spill(self) -> int:

@@ -28,7 +28,8 @@ import pyarrow.compute as pc
 from blaze_tpu import config
 from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.memory import MemConsumer, try_new_spill
-from blaze_tpu.exprs import PhysicalExpr
+from blaze_tpu.exprs import BoundReference, PhysicalExpr
+from blaze_tpu.exprs.base import ColVal
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
 from blaze_tpu.ops.sort import (_DEVICE_KEY_TYPES, _DEVICE_SORT_ROWS,
                                 host_sort_keys)
@@ -238,28 +239,40 @@ class WindowExec(ExecutionPlan):
         """(`batch` compacted, its partition keys, order keys, arguments)
         if the device can scan it where it lies, as `ops/sort.py`
         `_SortState._device_tile` asks for a sort: compute placed there,
-        every column a plain fixed-width device column, every key and
-        argument a device value.  None for anything else."""
+        every column a fixed-width device column (a dictionary column is
+        its code lane), every key and argument a device value.  None for
+        anything else."""
         import jax
         from blaze_tpu.batch import DeviceColumn
         from blaze_tpu.bridge.placement import host_resident
         if host_resident() or not batch.columns or not all(
-                type(c) is DeviceColumn and isinstance(c.data, jax.Array)
+                isinstance(c, DeviceColumn) and isinstance(c.data, jax.Array)
                 and c.data.ndim == 1 for c in batch.columns):
             return None
         batch = batch.compact()
 
-        def value(expr, types):
+        def value(expr, types, codes=None):
             v = expr.evaluate(batch)
-            if not (v.is_device and v.dictionary is None
-                    and isinstance(v.data, jax.Array)
+            if v.dictionary is not None:
+                # a dictionary column is its int32 code lane: a partition
+                # key compares by code under the batch's one dictionary,
+                # an order key orders by code where that is sorted
+                from blaze_tpu.batch import dict_info
+                if not isinstance(expr, BoundReference) or codes is None \
+                        or (codes == "ordered"
+                            and not dict_info(v.dictionary).sorted):
+                    return None
+                v = ColVal(INT32, data=v.data, validity=v.validity)
+            if not (v.is_device and isinstance(v.data, jax.Array)
                     and v.dtype.id in types
                     and v.data.shape == (batch.capacity,)):
                 return None
             return v
 
-        part = [value(e, _DEVICE_KEY_TYPES) for e in self.partition_by]
-        order = [value(e, _DEVICE_KEY_TYPES) for e, _, _ in self.order_by]
+        part = [value(e, _DEVICE_KEY_TYPES, "equal")
+                for e in self.partition_by]
+        order = [value(e, _DEVICE_KEY_TYPES, "ordered")
+                 for e, _, _ in self.order_by]
         has_arg = [isinstance(f, WindowAggFunc) and bool(f.agg.children)
                    for f in self.funcs]
         args = [value(f.agg.children[0], _DEVICE_KEY_TYPES) if has else None
@@ -289,11 +302,17 @@ class WindowExec(ExecutionPlan):
                 funcs=self._scan_funcs, group_limit=self.group_limit)
         xla_stats.note_window(rows, current_task().device_id, True,
                               kwin.scan_bytes(rows, *pairs, out))
+        coded = sum(getattr(c, "dictionary", None) is not None
+                    for c in batch.columns)
+        if coded:
+            xla_stats.note_dict(dict_rows_coded=rows * coded)
+        # the run's columns as they lie (a dictionary column stays one),
+        # the functions' behind them
         return ColumnBatch(
             self.schema,
-            [DeviceColumn(f.data_type, d, v) for f, (d, v) in zip(
-                self.schema,
-                tuple((c.data, c.validity) for c in batch.columns) + out)],
+            list(batch.columns) + [
+                DeviceColumn(f.data_type, d, v) for f, (d, v) in zip(
+                    list(self.schema)[len(batch.columns):], out)],
             rows, selection)
 
     # -- the host lane -------------------------------------------------------

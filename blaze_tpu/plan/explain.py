@@ -361,6 +361,14 @@ class QueryProfile:
                 f"/{x.get('window_rows', 0)} rows resident "
                 f"runs={x.get('window_partitions', 0)} "
                 f"scan={_fmt_bytes(x.get('window_scan_bytes', 0))}")
+        if (x.get("dict_rows_coded") or x.get("dict_rows_decoded")
+                or x.get("expand_rows_out")):
+            lines.append(
+                f"dict: coded={x.get('dict_rows_coded', 0)} "
+                f"decoded={x.get('dict_rows_decoded', 0)} "
+                f"unified={x.get('dict_unified', 0)} "
+                f"remap_rows={x.get('dict_remap_rows', 0)} "
+                f"expand_rows_out={x.get('expand_rows_out', 0)}")
         if x.get("stream_epochs"):
             epochs = x.get("stream_epochs", 0)
             wall = x.get("stream_epoch_wall_ns", 0)

@@ -23,8 +23,11 @@ Two fused strategies, chosen at plan time:
     dedup pass-through (the AGG_TRIGGER_PARTIAL_SKIPPING analog,
     ref agg_table.rs:108-122) because the final stage re-merges.
 
-Anything else (string keys, host aggs, avg/collect, merge modes) stays on
-the eager path.
+A utf8 group key is an int32 code lane of the HASH table where it arrives
+as a `DictColumn` (the stage loop, runtime/loop.py); an `ExpandExec` under
+a partial aggregation is absorbed into the chain where the stage loop runs
+the stage, which folds each batch once a projection list.  Anything else
+(host aggs, avg/collect, merge modes) stays on the eager path.
 """
 
 from __future__ import annotations
@@ -48,8 +51,8 @@ from blaze_tpu.ops.agg.exec import AggExec, AggMode
 from blaze_tpu.ops.agg.functions import (AvgAgg, CountAgg, MinMaxAgg,
                                           SumAgg)
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
-from blaze_tpu.ops.basic import (DebugExec, FilterExec, FilterProjectExec,
-                                 ProjectExec)
+from blaze_tpu.ops.basic import (DebugExec, ExpandExec, FilterExec,
+                                 FilterProjectExec, ProjectExec)
 from blaze_tpu.ops.scan import MemoryScanExec, ParquetScanExec
 from blaze_tpu.parallel.stage import (MAX_KEY_COLUMNS, hash_agg_step,
                                       init_accumulators, init_hash_carry,
@@ -198,7 +201,14 @@ def _try_fuse_agg(node: ExecutionPlan) -> Optional["FusedPartialAggExec"]:
     # step when every expression traces (the CachedExprsEvaluator work
     # moves INSIDE the XLA program: one dispatch per batch, ref rt.rs:156
     # whole-chain-in-one-task)
-    source, chain = _absorbable_chain(child)
+    # An Expand is absorbed too, but only where the stage loop will run
+    # the stage: the loop alone folds a batch once a projection list
+    # (placement is decided before plans build, so this is stable for the
+    # task); everywhere else the Expand stays the child it was
+    from blaze_tpu.plan import stage_compiler
+    source, chain = _absorbable_chain(
+        child, expand=mode == AggMode.PARTIAL
+        and stage_compiler.stage_loop_active())
     node = FusedPartialAggExec(child, groups, aggs, specs, ranges,
                                complete, grow, source=source, chain=chain)
     if ranges is not None:
@@ -235,14 +245,22 @@ def _host_vectorized_eligible(group_exprs, specs, in_schema) -> bool:
     return True
 
 
-def _absorbable_chain(child: ExecutionPlan):
+def _absorbable_chain(child: ExecutionPlan, expand: bool = False):
     """Peel Filter/Project/FilterProject off the agg's child.  Returns
     (source_plan, chain_steps) where chain_steps apply source->agg order;
-    (child, []) when nothing absorbs."""
+    (child, []) when nothing absorbs.  With `expand`, ONE ExpandExec is
+    peeled as well: the step carries its K projection lists, and the
+    program that runs the chain says which of them a call evaluates
+    (`prepare`'s third argument), so the expanded rows never exist as
+    batches."""
     steps = []
     node = child
     while True:
-        if isinstance(node, FilterExec):
+        if expand and isinstance(node, ExpandExec) \
+                and _expand_traceable(node):
+            steps.append(("expand", None, node._projections, node.schema))
+            expand = False
+        elif isinstance(node, FilterExec):
             steps.append(("filter", node._predicates, None, None))
         elif isinstance(node, ProjectExec):
             steps.append(("project", None, node._exprs, node.schema))
@@ -258,11 +276,45 @@ def _absorbable_chain(child: ExecutionPlan):
     return node, steps
 
 
+def _expand_traceable(node: ExpandExec) -> bool:
+    """Every projection list gives the same types a column, and a utf8
+    column is a bare reference or a NULL literal in each (a code lane
+    under its sibling's dictionary, or no valid bit)."""
+    in_schema = node.children[0].schema
+    for j, f in enumerate(node.schema):
+        for p in node._projections:
+            e = p[j]
+            if e.data_type(in_schema).id != f.data_type.id \
+                    and not _null_literal(e):
+                return False
+            if f.data_type.id == TypeId.UTF8 and not (
+                    isinstance(e, BoundReference) or _null_literal(e)):
+                return False
+            if not (f.data_type.is_fixed_width
+                    or f.data_type.id == TypeId.UTF8):
+                return False
+    return True
+
+
+def _null_literal(e) -> bool:
+    from blaze_tpu.exprs.base import Literal
+    return isinstance(e, Literal) and e.value is None
+
+
+def chain_expand(chain) -> int:
+    """The projection lists of the chain's Expand step, 0 without one."""
+    return next((len(exprs) for kind, _p, exprs, _s in chain
+                 if kind == "expand"), 0)
+
+
 def _chain_cache_key(source_schema: Schema, chain, group_exprs, specs):
     chain_k = []
     for kind, preds, exprs, _schema in chain:
         if kind == "filter":
             chain_k.append(("f", tuple(p.cache_key() for p in preds)))
+        elif kind == "expand":
+            chain_k.append(("x", tuple(tuple(e.cache_key() for e in p)
+                                       for p in exprs)))
         else:
             chain_k.append(("p", tuple(e.cache_key() for e in exprs)))
     return (tuple((f.name, f.data_type.id.value)
@@ -552,6 +604,10 @@ class FusedPartialAggExec(ExecutionPlan):
         # chain doesn't trace (strings, host-only exprs).
         self._source = source if source is not None else child
         self._chain = list(chain or [])
+        # projection lists of an absorbed Expand (0: none): the stage loop
+        # alone runs such a chain, every other lane takes the child as it
+        # was planned, ExpandExec and all
+        self._expand = chain_expand(self._chain)
         self._prepare = None
         self._prepare_key = None
         self._mxu_meta = None  # set by _try_fuse_agg when stats qualify
@@ -595,7 +651,8 @@ class FusedPartialAggExec(ExecutionPlan):
 
     def _use_host_vectorized(self) -> bool:
         from blaze_tpu.bridge.placement import host_resident
-        return (config.FUSED_HOST_VECTORIZED_ENABLE.get() and
+        return (not self._expand and
+                config.FUSED_HOST_VECTORIZED_ENABLE.get() and
                 host_resident() and self._host_vectorized_eligible())
 
     @property
@@ -634,6 +691,12 @@ class FusedPartialAggExec(ExecutionPlan):
             except StageLoopFallback as e:
                 xla_stats.note_stage_loop_fallback(str(e))
                 self.metrics.add("stage_loop_fallback", 1)
+        if self._expand:
+            # the chain holds an Expand, which only the loop folds in
+            # place: the unfused aggregation over `ExpandExec.execute`
+            yield from AggExec(self.children[0], self._group_exprs,
+                               self._aggs).execute(partition)
+            return
         if self._decimal_specs:
             # a decimal value lane is guarded against 64-bit wrap by the
             # stage loop alone (runtime/loop.py); every other lane of
@@ -2002,16 +2065,18 @@ class FusedPartialAggExec(ExecutionPlan):
         n = len(accs[0]) if accs else len(keys[0][0])
         arrays: List[pa.Array] = []
         out_arrow = self._out_schema.to_arrow()
-        i = 0
+        i = coded = 0
         for j, ((kd, kv), f) in enumerate(zip(keys, out_arrow)):
             d = key_dicts[j] if key_dicts is not None else None
             if d is not None:
-                # dict-encoded key: the table folded int32 codes; decode
-                # through the stream's final dictionary snapshot (its
-                # prefix covers every code of every earlier batch)
-                idx = pa.array(np.where(kv, kd.astype(np.int64), 0),
-                               pa.int64(), mask=~kv)
-                arrays.append(d.take(idx).cast(f.type))
+                # dict-encoded key: the table folded int32 codes, and they
+                # leave as codes, under the stream's final dictionary
+                # snapshot (its prefix covers every code of every earlier
+                # batch): the next operator takes a `DictColumn`
+                idx = pa.array(np.where(kv, kd, 0).astype(np.int32),
+                               pa.int32(), mask=~kv)
+                arrays.append(pa.DictionaryArray.from_arrays(idx, d))
+                coded += 1
             else:
                 arrays.append(_to_arrow(kd, kv, f.type))
             i += 1
@@ -2023,6 +2088,9 @@ class FusedPartialAggExec(ExecutionPlan):
             else:
                 arrays.append(_to_arrow(a, v, f.type))
             i += 1
+        if coded:
+            xla_stats.note_dict(dict_rows_coded=n * coded)
+            return pa.RecordBatch.from_arrays(arrays, names=out_arrow.names)
         return pa.RecordBatch.from_arrays(arrays, schema=out_arrow)
 
 
@@ -2057,8 +2125,12 @@ def _source_inputs(batch: ColumnBatch):
 
 def _make_prepare(source_schema: Schema, chain, group_exprs, specs):
     """The in-graph chain evaluator: rebuild the batch from traced arrays,
-    run filter/project expression trees, emit key/agg device columns."""
-    def prepare(cols_flat, mask):
+    run filter/project expression trees, emit key/agg device columns.
+    Where the chain holds an Expand, `prepare` takes the projection
+    list's index as a third (traced) argument: every list is evaluated
+    (bare references, literals) and the one asked for is selected lane
+    by lane, so one program serves all K."""
+    def prepare(cols_flat, mask, which=None):
         cap = mask.shape[0]
         cols = [DeviceColumn(f.data_type, cf[0], cf[1])
                 if cf is not None else None
@@ -2072,6 +2144,11 @@ def _make_prepare(source_schema: Schema, chain, group_exprs, specs):
                     m = pm if m is None else (m & pm)
                 if m is not None:
                     batch = batch.with_selection(m)
+            elif kind == "expand":
+                batch = ColumnBatch(
+                    out_schema, _expanded_columns(batch, exprs, out_schema,
+                                                  which), cap,
+                    batch.selection)
             else:
                 new_cols = [e.evaluate(batch).to_column(cap)
                             for e in exprs]
@@ -2092,6 +2169,31 @@ def _make_prepare(source_schema: Schema, chain, group_exprs, specs):
                 av.append(v.validity)
         return tuple(kd), tuple(kv), tuple(ad), tuple(av), batch.row_mask()
     return prepare
+
+
+def _expanded_columns(batch: ColumnBatch, projections, out_schema: Schema,
+                      which):
+    """An Expand's output columns for projection list `which` (a traced
+    int32): each list's value a column, the asked one selected.  A NULL
+    literal of type utf8 is a code lane with no valid bit."""
+    cap = batch.capacity
+    out = []
+    for j, f in enumerate(out_schema):
+        store = jnp.int32 if f.data_type.id == TypeId.UTF8 \
+            else f.data_type.jnp_dtype()
+        datas, valids = [], []
+        for p in projections:
+            if _null_literal(p[j]):
+                datas.append(jnp.zeros(cap, store))
+                valids.append(jnp.zeros(cap, bool))
+                continue
+            v = p[j].evaluate(batch).to_device(cap)
+            datas.append(jnp.asarray(v.data).astype(store))
+            valids.append(jnp.asarray(v.validity))
+        k = jnp.clip(which, 0, len(projections) - 1)
+        out.append(DeviceColumn(f.data_type, jax.lax.select_n(k, *datas),
+                                jax.lax.select_n(k, *valids)))
+    return out
 
 
 # key -> raw prepare fn | None when the chain doesn't trace
@@ -2127,6 +2229,14 @@ def _dict_chain_safe(source_schema: Schema, chain, group_exprs,
         if kind == "filter":
             if not all(_utf8_ref_free(p, sch) for p in preds):
                 return False
+        elif kind == "expand":
+            # (`_expand_traceable` holds a utf8 column to bare references
+            # and NULL literals)
+            if not all(isinstance(e, BoundReference) or _null_literal(e)
+                       or _utf8_ref_free(e, sch)
+                       for p in exprs for e in p):
+                return False
+            sch = out_schema
         else:
             for e in exprs:
                 if isinstance(e, BoundReference):
@@ -2163,6 +2273,17 @@ def _dict_key_sources(agg):
             return None
         idx = e.index
         for kind, _preds, exprs, _schema in reversed(agg._chain):
+            if kind == "expand":
+                # every list that has the column at all has to take it
+                # from ONE input column: its dictionary is the key's
+                refs = {p[idx].index for p in exprs
+                        if isinstance(p[idx], BoundReference)}
+                if len(refs) != 1 or not all(
+                        isinstance(p[idx], BoundReference)
+                        or _null_literal(p[idx]) for p in exprs):
+                    return None
+                idx = refs.pop()
+                continue
             if kind != "project":
                 continue
             pe = exprs[idx]
@@ -2197,8 +2318,10 @@ def _prepare_factory(key, source_schema: Schema, chain, group_exprs,
 
     try:
         fake_cols = tuple(_slot(f) for f in source_schema)
+        which = ((jax.ShapeDtypeStruct((), jnp.int32),)
+                 if chain_expand(chain) else ())
         jax.eval_shape(prepare, fake_cols,
-                       jax.ShapeDtypeStruct((128,), jnp.bool_))
+                       jax.ShapeDtypeStruct((128,), jnp.bool_), *which)
         result = prepare  # consumers inline it into their own jit step
     except Exception:
         result = None  # strings / host-only exprs: stay on the eager path

@@ -24,7 +24,9 @@ Conventions where the reference delegates to the JVM side:
 
 from __future__ import annotations
 
+import copy
 import io
+import json
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import pyarrow as pa
@@ -134,24 +136,57 @@ def schema_to_proto(sd: Dict[str, Any]) -> pb.Schema:
 # (ref auron-planner/src/lib.rs:451-459)
 # ---------------------------------------------------------------------------
 
+# A plan's literals are few and come again with every task of every query
+# (an Expand's NULLs and grouping ids, once a projection list), so each is
+# written and read through Arrow IPC ONCE a process.  An IPC writer lets
+# the GIL go several times a literal, and with a stage's tasks encoding
+# their plans side by side each hand-back waits out the interpreter's
+# switch interval: eighty literals took a q67 map task 120 ms where one
+# thread alone takes 3.
+
+_IMMUTABLE = (type(None), bool, int, float, str, bytes)
+_SCALAR_LIMIT = 4096
+# (repr, type, type as JSON) -> IPC bytes: `repr` and the type tell 0.0
+# from -0.0 and True from 1, which `==` does not
+_SCALAR_IPC: Dict[tuple, bytes] = {}
+# IPC bytes -> (value, type dict), immutable values alone
+_SCALAR_VALUE: Dict[bytes, tuple] = {}
+
+
 def scalar_from_proto(sv: pb.ScalarValue) -> Tuple[Any, Dict[str, Any]]:
     from blaze_tpu.plan.types import type_to_dict
     from blaze_tpu.schema import DataType
+    hit = _SCALAR_VALUE.get(sv.ipc_bytes)
+    if hit is not None:
+        return hit[0], copy.deepcopy(hit[1])  # the caller owns its dict
     with pa.ipc.open_stream(io.BytesIO(sv.ipc_bytes)) as r:
         rb = next(iter(r))
     col = rb.column(0)
     val = col[0].as_py() if col[0].is_valid else None
-    return val, type_to_dict(DataType.from_arrow(col.type))
+    type_dict = type_to_dict(DataType.from_arrow(col.type))
+    if isinstance(val, _IMMUTABLE) and len(_SCALAR_VALUE) < _SCALAR_LIMIT:
+        _SCALAR_VALUE[bytes(sv.ipc_bytes)] = (val, copy.deepcopy(type_dict))
+    return val, type_dict
 
 
 def scalar_to_proto(value: Any, type_dict: Dict[str, Any]) -> pb.ScalarValue:
     from blaze_tpu.plan.types import type_from_dict
+    key = None
+    if isinstance(value, _IMMUTABLE):
+        key = (repr(value), type(value),
+               json.dumps(type_dict, sort_keys=True, default=repr))
+        hit = _SCALAR_IPC.get(key)
+        if hit is not None:
+            return pb.ScalarValue(ipc_bytes=hit)
     t = type_from_dict(type_dict).to_arrow()
     rb = pa.record_batch([pa.array([value], type=t)], names=["c0"])
     sink = io.BytesIO()
     with pa.ipc.new_stream(sink, rb.schema) as w:
         w.write_batch(rb)
-    return pb.ScalarValue(ipc_bytes=sink.getvalue())
+    ipc = sink.getvalue()
+    if key is not None and len(_SCALAR_IPC) < _SCALAR_LIMIT:
+        _SCALAR_IPC[key] = ipc
+    return pb.ScalarValue(ipc_bytes=ipc)
 
 
 # ---------------------------------------------------------------------------

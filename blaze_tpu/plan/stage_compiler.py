@@ -15,8 +15,11 @@ Eligibility (anything else stays on the staged per-batch executor):
     lane already has its own windowed fold (fused.dense_fold);
   * the filter/project chain traced (`_prepare` survived the
     jax.eval_shape probe — no strings / host-only exprs in the chain);
-  * every group key is fixed-width (utf8 keys belong to the Arrow host
-    lane), and there is at least one group key;
+  * every group key is fixed-width or a utf8 column that arrives
+    dictionary-encoded (its int32 codes are a key lane; the chain may only
+    pass codes through, `fused._dict_chain_safe`), and there is at least
+    one group key; an Expand in the chain is folded a projection list a
+    step (`StageProgram.expand`);
   * the source plan is re-executable, so a wholesale fallback can re-run
     the partition from scratch losslessly (the loop falls back only
     while it has emitted nothing: runtime/loop.py).
@@ -85,6 +88,9 @@ class StageProgram:
     # the specs whose kind is "sum" over a decimal's unscaled integer:
     # the fold bounds those sums so that none can pass 64 bits unseen
     decimal_sums: Tuple[int, ...] = ()
+    # projection lists of the chain's Expand (0: none): the fold runs
+    # `prepare` once a list a batch, `prepare`'s third argument says which
+    expand: int = 0
 
     @property
     def source(self):
@@ -170,7 +176,7 @@ def compile_fused_agg(agg) -> StageProgram:
                         prepare_key=agg._prepare_key, kinds=kinds,
                         key_dtypes=key_dtypes, acc_dtypes=acc_dtypes,
                         fingerprint=fingerprint, dict_keys=dict_keys,
-                        decimal_sums=decimal_sums)
+                        decimal_sums=decimal_sums, expand=agg._expand)
 
 
 def try_compile(agg) -> Optional[StageProgram]:

@@ -14,6 +14,12 @@ batches stacked, a tail chunk widened with masked-out batches to the one
 width the fold is compiled for, the selected lanes a batch counted), so
 a chunk is two dispatches and two readbacks.
 
+A stage whose chain holds an Expand (plan/fused.py `_absorbable_chain`)
+folds each batch once a projection list inside the same program
+(`runtime.stage_loop_fold_expand`): a step is a (batch, list) pair, the
+expanded rows never exist as batches, and a chunk is cut to as many
+batches as keep its steps at the configured width.
+
 Capacity is reserved BEFORE a chunk is folded, in every agg mode: at
 the chunk boundary the host already holds the chunk's row counts and
 the table's group count (they ride the overflow scalars' round trip),
@@ -43,7 +49,7 @@ un-aggregated in accumulator form (`_pass_through`), one elementwise
 program per chunk over the same stacked window.  The FINAL aggregation
 downstream merges raw rows exactly as it merges partial groups.  FINAL,
 merge and complete programs never switch (nothing merges after them),
-nor do programs with dictionary-encoded keys, nor `run_partition`'s
+nor do programs with dictionary-encoded keys or an Expand, nor `run_partition`'s
 direct caller (the device-to-device exchange wants ONE carry).
 
 Discipline inherited from the staged path, kept intact:
@@ -235,12 +241,21 @@ def _fold_factory(program, donate: bool):
     prepare = program.prepare
     kinds = program.kinds
     decimal_sums = program.decimal_sums
+    # a chain with an Expand folds each batch once a projection list: a
+    # STEP is (batch, list), numbered batch-major, and `start`, `look`
+    # and `resume` count steps (without an Expand a step is a batch)
+    fan = program.expand or 1
 
     def fold_impl(carry, cols_stacked, masks, start, look):
+        steps = masks.shape[0] * fan
+
         def body(state):
-            b, c, ovf_seen, first_ovf, folded, rounds, undone, *mass = state
-            kd, kv, ad, av, m = prepare(_batch_of(cols_stacked, b), masks[b])
-            # once a batch overflows, later batches fold as no-ops: the
+            i, c, ovf_seen, first_ovf, folded, rounds, undone, *mass = state
+            b = i // fan
+            which = (i % fan,) if program.expand else ()
+            kd, kv, ad, av, m = prepare(_batch_of(cols_stacked, b), masks[b],
+                                        *which)
+            # once a step overflows, later steps fold as no-ops: the
             # table stays what it was before the overflow (hash_agg_step
             # takes an overflowing step's claims back), so the host can
             # regrow and resume mid-chunk
@@ -249,29 +264,29 @@ def _fold_factory(program, donate: bool):
             new_c, ovf, _ng, step_rounds = hash_agg_step(
                 c, list(zip(kd, kv)), specs, live)
             hit = ovf > 0
-            first_ovf = jnp.where(hit & ~ovf_seen, b, first_ovf)
-            # the rows of an overflowing batch are not in the table
+            first_ovf = jnp.where(hit & ~ovf_seen, i, first_ovf)
+            # the rows of an overflowing step are not in the table
             nlive = jnp.sum(live, dtype=jnp.int32)
             folded += jnp.where(hit, 0, nlive)
             # a decimal sums as its unscaled integer in an int64 lane: no
             # group's sum can pass the live rows times the largest
             # magnitude among them, summed over every batch folded
             # (a program without a decimal sum carries no such bound)
-            for i in decimal_sums:
-                big = jnp.max(jnp.where(live & av[i], jnp.abs(ad[i]), 0))
+            for j in decimal_sums:
+                big = jnp.max(jnp.where(live & av[j], jnp.abs(ad[j]), 0))
                 mass[0] += big.astype(jnp.float32) * nlive.astype(jnp.float32)
-            return (b + 1, new_c, jnp.logical_or(ovf_seen, hit), first_ovf,
+            return (i + 1, new_c, jnp.logical_or(ovf_seen, hit), first_ovf,
                     folded, rounds + step_rounds, undone + hit, *mass)
 
         def more(state):
-            b, _c, _ovf_seen, _first_ovf, folded, *_rest = state
-            # `look` live rows are in: stop at this batch boundary, so
+            i, _c, _ovf_seen, _first_ovf, folded, *_rest = state
+            # `look` live rows are in: stop at this step's boundary, so
             # the host can take its first look at groups per live row
-            return (b < masks.shape[0]) & (folded < look)
+            return (i < steps) & (folded < look)
 
         zero = jnp.asarray(0, jnp.int32)
         mass = (jnp.asarray(0, jnp.float32),) if decimal_sums else ()
-        b, carry, ovf_seen, first_ovf, folded, rounds, undone, *mass = \
+        i, carry, ovf_seen, first_ovf, folded, rounds, undone, *mass = \
             jax.lax.while_loop(
                 more, body, (start, carry, jnp.asarray(False), zero, zero,
                              jnp.zeros(2, jnp.int32), zero, *mass))
@@ -279,11 +294,11 @@ def _fold_factory(program, donate: bool):
         # the probe rounds it ran (full width, narrow width) ride the
         # overflow scalars' round trip: the host sizes the next chunk's
         # table from the first and judges the partial-skip ratio from the
-        # first two.  `resume` is the batch to go on from: the one that
-        # overflowed, else the first one not folded (the chunk's width
+        # first two.  `resume` is the step to go on from: the one that
+        # overflowed, else the first one not folded (the chunk's steps
         # when nothing stopped the fold).  `undone` counts the steps
         # whose claims hash_agg_step took back
-        resume = jnp.where(ovf_seen, first_ovf, b)
+        resume = jnp.where(ovf_seen, first_ovf, i)
         # `mass`, beside the overflow scalars: what the decimal lanes'
         # sums are bounded by so far in this call (the host adds the
         # calls up and declines the partition before a sum could wrap)
@@ -291,6 +306,15 @@ def _fold_factory(program, donate: bool):
                 *mass)
 
     kwargs = {"donate_argnums": (0,)} if donate else {}
+    # (the function's name is what the trace matches a fold by; the
+    # kernel's says which fold: `jit_fold_impl__runtime_stage_loop` or
+    # `jit_fold_impl__runtime_stage_loop_fold_expand`)
+    if program.expand:
+        return _cached(
+            (program.fingerprint, bool(donate)),
+            lambda: meter_jit(fold_impl,
+                              name="runtime.stage_loop_fold_expand",
+                              **kwargs))
     return _cached(
         (program.fingerprint, bool(donate)),
         lambda: meter_jit(fold_impl, name="runtime.stage_loop", **kwargs))
@@ -368,7 +392,9 @@ def _may_switch(program) -> bool:
     """Only a PARTIAL aggregation has a merge downstream that makes
     groups of passed-through rows."""
     agg = program.agg
-    return (not agg._complete and not agg._grow
+    # (nor does a fold over an Expand switch: the pass-through program
+    # lays a batch out once, not once a projection list)
+    return (not agg._complete and not agg._grow and not program.expand
             and config.PARTIAL_AGG_SKIPPING_ENABLE.get())
 
 
@@ -422,7 +448,12 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         q = active_query()
     if q is not None and q.force_agg_passthrough:
         raise StageLoopFallback("query degraded to agg pass-through")
-    chunk = loop_chunk_batches()
+    # with an Expand a chunk's batches fold `fan` times each: the chunk is
+    # cut to as many batches as keep its STEPS at the configured width, so
+    # the table is sized for the rows a chunk's steps can bring and not
+    # for `fan` chunks of them
+    fan = program.expand or 1
+    chunk = max(1, loop_chunk_batches() // fan)
     fold = _fold_factory(program, _donate_active())
     floor = _pow2(config.ON_DEVICE_AGG_CAPACITY.get())
     min_rows = min(_NO_LOOK,
@@ -489,11 +520,18 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
             faults.maybe_fail("device-loop", stage=ctx, chunk=ci)
             with tracing.span("stage_loop_chunk", stage=ctx,
                               partition=partition, chunk=ci,
-                              batches=count, device=task.device_id):
+                              batches=count, device=task.device_id) as attrs:
                 # selected lanes per real batch, counted by the window's
                 # program: before the chain's filter, so an upper bound
                 # on the rows the fold will insert
                 batch_rows = to_host(batch_rows).tolist()
+                if fan > 1:
+                    # (batch, projection list) steps, batch-major: each
+                    # brings the batch's rows again
+                    attrs.update(expand=fan, rows_in=sum(batch_rows[:count]),
+                                 rows_out=fan * sum(batch_rows[:count]))
+                    batch_rows = [r for r in batch_rows for _ in range(fan)]
+                    count *= fan
                 # reserve before fold
                 need = groups + sum(batch_rows)
                 if carry is None or need > slots * _TRIGGER_LOAD:
@@ -547,7 +585,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                             break
                 # rows handed to the fold, and no others
                 rows += sum(batch_rows[:start])
-                batches += min(start, count)
+                batches += min(start, count) // fan
                 lanes += min(start, count) * int(masks.shape[1])
             if rest is not None:
                 break
@@ -575,6 +613,8 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
         dispatches_avoided=max(0, batches - fold_calls))
     if program.agg._decimal_specs:
         xla_stats.note_decimal(stage_loop_rows=rows, chip=task.device_id)
+    if fan > 1:
+        xla_stats.note_dict(chip=task.device_id, expand_rows_out=rows)
     program.agg._note_lane(batches)
     return carry, rest
 
@@ -619,15 +659,17 @@ def _pass_through(program, rest: _Unfolded, partition: int, ctx: str):
             yield from agg._emit_chunks(rb)
 
 
-def _dict_stream_guard(stream, utf8_cols, key_srcs, captured):
+def _dict_stream_guard(stream, utf8_cols, held):
     """Wrap a dict-key stage's source stream: every utf8 source column
     must arrive dictionary-encoded (the prepare traced int32 code slots
     for them — a plain utf8 batch, e.g. after encoder overflow, has no
-    device form and must fall back BEFORE the fold sees it), and the
-    latest dictionary per key source is captured as it passes.  The
-    encoder's prefix property makes the LAST dictionary of the stream
-    decode every earlier batch's codes, so capture is just
-    last-writer-wins."""
+    device form and must fall back BEFORE the fold sees it), and the key
+    sources leave under ONE dictionary a column (`held`, a
+    `batch.DictStream` over them).  A source may bring batches under
+    unrelated dictionaries (a `UnionExec` hands on one child's batches
+    after another's, each child with its own encoder or build side):
+    their codes are remapped where they lie before they reach the table,
+    and `held.dicts` decodes the drain."""
     from blaze_tpu.batch import DictColumn
     for batch in stream:
         for ci in utf8_cols:
@@ -636,9 +678,7 @@ def _dict_stream_guard(stream, utf8_cols, key_srcs, captured):
                 raise StageLoopFallback(
                     "utf8 source column arrived without dictionary "
                     "encoding (encoder overflow or unencoded source)")
-            if ci in key_srcs:
-                captured[ci] = c.dictionary
-        yield batch
+        yield held.under_one_dictionary(batch)
 
 
 def execute_loop(program, partition: int, ctx: str = ""):
@@ -654,17 +694,17 @@ def execute_loop(program, partition: int, ctx: str = ""):
         from blaze_tpu.schema import TypeId
         utf8_cols = {i for i, f in enumerate(program.source.schema)
                      if f.data_type.id == TypeId.UTF8}
-        key_srcs = {s for s in dict_keys if s is not None}
-        captured: dict = {}
+        from blaze_tpu.batch import DictStream
+        held = DictStream(only={s for s in dict_keys if s is not None})
         stream = _dict_stream_guard(program.source.execute(partition),
-                                    utf8_cols, key_srcs, captured)
+                                    utf8_cols, held)
         # no switch: codes decode through the stream's LAST dictionary,
         # and the guard may decline the partition at any batch, which
         # is lossless only while nothing has been emitted
         with charged_table(program) as table:
             carry = run_partition(program, partition, ctx=ctx,
                                   source_stream=stream, table=table)
-            key_dicts = [captured.get(s) if s is not None else None
+            key_dicts = [held.dicts.get(s) if s is not None else None
                          for s in dict_keys]
             yield from program.agg._emit_hash(carry, key_dicts=key_dicts)
         return

@@ -11,6 +11,8 @@ rangePartitioningBound + evaluate_range_partition_ids).
 
 from __future__ import annotations
 
+import collections
+import threading
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -114,12 +116,49 @@ def _native_pmod(flat_cols, tids, n_parts):
     return out if rc == 0 else None
 
 
+# dictionary fingerprint -> (entries' padded bytes, lengths), numpy on the
+# host: a dimension table's dictionaries are few and come again with every
+# query.  What is placed on a chip is held by the partitioner that placed
+# it, for its task alone (`HashPartitioning._placed`)
+_DICT_BYTES: "collections.OrderedDict" = collections.OrderedDict()
+_DICT_BYTES_LOCK = threading.Lock()
+_DICT_BYTES_LIMIT = 64
+
+
+def _dictionary_bytes(fingerprint: bytes, dictionary: pa.Array):
+    """(byte matrix, lengths) of a dictionary's entries, as
+    `H.hash_columns` takes a utf8_dict column's: rows padded to a power
+    of two of entries and of bytes, so that a new dictionary is rarely a
+    new program."""
+    with _DICT_BYTES_LOCK:
+        entry = _DICT_BYTES.get(fingerprint)
+        if entry is not None:
+            _DICT_BYTES.move_to_end(fingerprint)
+            return entry
+    (mat, lengths), _valid = H.string_column_to_padded_bytes(dictionary)
+    rows = max(128, 1 << max(0, len(dictionary) - 1).bit_length())
+    width = max(4, 1 << max(0, mat.shape[1] - 1).bit_length())
+    full = np.zeros((rows, width), dtype=np.uint8)
+    full[:mat.shape[0], :mat.shape[1]] = mat
+    full_len = np.zeros(rows, dtype=np.int32)
+    full_len[:len(lengths)] = lengths
+    with _DICT_BYTES_LOCK:
+        _DICT_BYTES[fingerprint] = (full, full_len)
+        while len(_DICT_BYTES) > _DICT_BYTES_LIMIT:
+            _DICT_BYTES.popitem(last=False)
+    return full, full_len
+
+
 class HashPartitioning(Partitioning):
     def __init__(self, exprs: Sequence[PhysicalExpr], num_partitions: int):
         self.exprs = list(exprs)
         self.num_partitions = num_partitions
+        # key -> (fingerprint, its dictionary's bytes on this task's
+        # chip): the newest dictionary's alone, let go with the plan
+        self._placed: dict = {}
 
     def partition_ids(self, batch: ColumnBatch) -> np.ndarray:
+        from blaze_tpu.batch import dict_info
         from blaze_tpu.bridge.placement import host_resident
         from blaze_tpu.xputil import asnp, to_device
         n = batch.num_rows
@@ -132,9 +171,26 @@ class HashPartitioning(Partitioning):
         cap = n if on_host else batch.capacity
         flat_cols = []
         tids = []
-        for e in self.exprs:
+        for i, e in enumerate(self.exprs):
             v = e.evaluate(batch)
-            if v.is_device:
+            if v.is_device and v.dictionary is not None:
+                # a dictionary column hashes as the strings it stands
+                # for: the entries' bytes (laid out once a dictionary,
+                # on the chip while this task's batches come under it)
+                # gathered by code inside the program
+                fp = dict_info(v.dictionary).fingerprint
+                mat, lengths = _dictionary_bytes(fp, v.dictionary)
+                if not on_host:
+                    held = self._placed.get(i)
+                    if held is None or held[0] != fp:
+                        held = self._placed[i] = (
+                            fp, tuple(to_device((mat, lengths))))
+                    mat, lengths = held[1]
+                codes, valid = ((asnp(v.data)[:cap], asnp(v.validity)[:cap])
+                                if on_host else (v.data, v.validity))
+                flat_cols.append(((codes, mat, lengths), valid))
+                tids.append("utf8_dict")
+            elif v.is_device:
                 if on_host:
                     flat_cols.append((asnp(v.data)[:cap],
                                       asnp(v.validity)[:cap]))

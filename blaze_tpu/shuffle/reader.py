@@ -16,7 +16,8 @@ from typing import BinaryIO, Callable, Iterable, Iterator, List, Union
 import pyarrow as pa
 
 from blaze_tpu import faults
-from blaze_tpu.batch import ColumnBatch
+from blaze_tpu.batch import ColumnBatch, DictStream, plain_columns
+from blaze_tpu.bridge import xla_stats
 from blaze_tpu.bridge.resource import get_resource
 from blaze_tpu.faults import (FetchFailedError, InjectedFault,
                               ShuffleChecksumError)
@@ -159,15 +160,30 @@ class IpcReaderExec(ExecutionPlan):
         # re-tiled in Arrow, on the host, BEFORE anything is placed: a
         # reduce task's batches reach the chip at the tile's capacity and
         # no device program joins them
-        for rb in _tiles(self.arrow_batches(partition)):
-            yield ColumnBatch.from_arrow(rb)
+        # blocks of several map tasks bring each its own dictionaries: the
+        # task's batches leave under one a column (`batch.DictStream`)
+        stream = DictStream()
+        for rb in _tiles(self._block_batches(partition)):
+            batch = stream.under_one_dictionary(ColumnBatch.from_arrow(rb))
+            if stream.dicts:
+                xla_stats.note_dict(
+                    dict_rows_coded=batch.num_rows * len(stream.dicts))
+            yield batch
 
     def arrow_batches(self, partition: int):
         """Arrow-resident read: decoded IPC frames go straight to
         Arrow-resident consumers (the reduce-side host agg) without a
-        ColumnBatch round trip.  Segment reads + IPC decode run on the
-        prefetch worker so reduce-side compute overlaps them
+        ColumnBatch round trip, a dictionary-encoded column as the plain
+        strings those consumers take.  Segment reads + IPC decode run on
+        the prefetch worker so reduce-side compute overlaps them
         (kill-switch auron.tpu.io.prefetch)."""
+        for rb in self._block_batches(partition):
+            if any(pa.types.is_dictionary(f.type) for f in rb.schema):
+                rb = pa.RecordBatch.from_arrays(plain_columns(rb.columns),
+                                                names=rb.schema.names)
+            yield rb
+
+    def _block_batches(self, partition: int):
         from blaze_tpu.ops.base import prefetch
         return prefetch(self._read_blocks(partition), name="ipc_reader")
 

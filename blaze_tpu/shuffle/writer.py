@@ -30,7 +30,7 @@ import numpy as np
 import pyarrow as pa
 
 from blaze_tpu import config
-from blaze_tpu.batch import ColumnBatch
+from blaze_tpu.batch import ColumnBatch, one_schema
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.memory import MemConsumer, MemManager
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
@@ -226,7 +226,13 @@ class ShuffleRepartitioner(MemConsumer):
                 self._stage(batch.to_arrow())
             return
         pids = self.partitioning.partition_ids(batch)
-        rb = batch.to_arrow()
+        # a dictionary column crosses the exchange as its codes: the IPC
+        # block holds a dictionary-encoded array, nothing is decoded
+        rb = batch.to_arrow(keep_dict=True)
+        coded = sum(pa.types.is_dictionary(c.type) for c in rb.columns)
+        if coded:
+            from blaze_tpu.bridge import xla_stats
+            xla_stats.note_dict(dict_rows_coded=rb.num_rows * coded)
         arrays = [pa.array(pids, type=pa.int32())] + list(rb.columns)
         staged = pa.RecordBatch.from_arrays(
             arrays, names=["__pid"] + list(rb.schema.names))
@@ -304,7 +310,8 @@ class ShuffleRepartitioner(MemConsumer):
                 w.write_batch(staged)
             w.finish()
             return [0, sink.tell()]
-        tbl = pa.Table.from_batches(self._staged).combine_chunks()
+        tbl = pa.Table.from_batches(one_schema(self._staged)) \
+            .combine_chunks()
         rb = tbl.to_batches()[0]
         pids = np.asarray(rb.column(0))
         if n_parts <= 32:

@@ -37,11 +37,17 @@ DECIMAL_COUNTERS = ("stage_loop_decimal_rows", "agg_decimal_rows_host",
 # expression batches and 9 string evictions, where tasks that arrive
 # together build a map each and read up to 91, 6 and 21), and the three
 # programs of `SortExec`'s resident lane (PR 42: the merge join's 2,373-row
-# side stays on the device while it is sorted)
+# side stays on the device while it is sorted).  Since PR 48 strings are
+# dictionary columns by default: the expression programs' names carry the
+# encoding (new digests), and the customer join's utf8 payload
+# (`c_customer_id`) rides the device probe as codes, so no probe row goes
+# through the host's pair expansion and its 179 rows are laid by the tile
+# lane
 FLOAT_PROGRAMS = [
-    "expr_program_12719fd5421b", "expr_program_26d11000e24c",
-    "expr_program_50070b6d01df", "expr_program_db9f9d15a1b6",
-    "join.expand_pairs", "join.hash_valid", "join.probe_counts",
+    "coalesce.lay", "coalesce.tail",
+    "expr_program_5624fbfe8f85", "expr_program_79fd57fed3c8",
+    "expr_program_a67b25f53eed", "expr_program_d460fd360439",
+    "join.hash_valid",
     "join.probe_gather", "mesh.exchange_rows", "runtime.stage_loop",
     "runtime.stage_loop_window", "smj.bounds", "smj.expand_pairs",
     "smj.gather", "sort.pass", "sort.assemble", "sort.digits", "sort.gather"]
@@ -51,7 +57,7 @@ FLOAT_COUNTERS = {
     "stage_loop_windows": 12, "stage_loop_windows_fused": 12,
     "stage_loop_full_rounds": 18, "stage_loop_final_slots": 4194304,
     "expr_fused_batches": 25, "expr_eager_batches": 3,
-    "join_probe_device_rows": 29893, "join_probe_host_rows": 180,
+    "join_probe_device_rows": 30073, "join_probe_host_rows": 0,
     "smj_device_rows": 2876, "smj_device_pairs": 2864,
     "sort_device_rows": 2373, "sort_resident_rows": 2373,
     "shuffle_device_exchanges": 4,

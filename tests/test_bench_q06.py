@@ -184,7 +184,8 @@ def test_both_q06_cells_list_the_share_and_its_file_reads_the_counters(name):
                      "better": "higher", "source": "program_counter",
                      "layer": "plan decode + per-task runtime",
                      "moves": "query_wall_s", "workloads": list(LISTED)}
-    assert cell.manifest["per_layer"][-1] == entry     # added at the end
+    # added at the end, before the next PR's entries
+    assert cell.manifest["per_layer"].index(entry) == 111
     assert spec["manifest_source"] == entry["source"]
     assert entry["layer"] == specs["idle_coalesce_s"][0]["layer"]
     read = cell.module("sources", spec["source"]).read

@@ -160,7 +160,8 @@ def test_every_meter_jit_call_names_its_kernel():
 
 
 @pytest.mark.parametrize("kernel", [
-    "runtime.stage_loop", "fused.dense_fold", "fused.mxu_fold"])
+    "runtime.stage_loop", "runtime.stage_loop_fold_expand",
+    "fused.dense_fold", "fused.mxu_fold"])
 def test_fold_programs_still_match_the_trace_readers_pattern(kernel):
     """`fold_device_s` and `fold_roofline` (benchmark/layer_metrics) find
     the fold programs by `^jit_fold_impl`: the function name stays in

@@ -207,10 +207,14 @@ def test_string_keys_without_dict_still_evict(tmp_path, staged_path,
                                               loop_on):
     """Knob off: utf8 group keys keep rejecting the loop, and the
     rejection is accounted as a STRING eviction (satellite 2)."""
-    plan = _group_by_plan(tmp_path, _utf8_table(n=2000), tag="ev")
-    before = xla_stats.snapshot()
-    DagScheduler(work_dir=str(tmp_path / "dag")).run_collect(plan)
-    d = xla_stats.delta(before)
+    config.conf.set(config.ENCODING_DICT_ENABLE.key, False)
+    try:
+        plan = _group_by_plan(tmp_path, _utf8_table(n=2000), tag="ev")
+        before = xla_stats.snapshot()
+        DagScheduler(work_dir=str(tmp_path / "dag")).run_collect(plan)
+        d = xla_stats.delta(before)
+    finally:
+        config.conf.unset(config.ENCODING_DICT_ENABLE.key)
     assert d["stage_loop_tasks"] == 0
     assert d["host_evictions_string"] >= 1
 

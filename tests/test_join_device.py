@@ -205,6 +205,13 @@ def _probe_cases():
         pa.table({"bk": _i64([3, None, 5, 9]), "bv": _i64([30, 0, 50, 90])}),
         [(pa.table({"pk": _i64([None, 5, 3, None, 0, 9, 7]),
                     "pv": _i64(range(7))}), None)], 1)
+    # a utf8 payload rides the probe as int32 codes (the build side's
+    # sorted dictionary; a probe batch's own where it arrives coded)
+    cases["utf8_build_column"] = (
+        pa.table({"bk": _i64([3, None, 5, 9]),
+                  "name": pa.array(["b", "a", None, "d"])}),
+        [(pa.table({"pk": _i64([None, 5, 3, None, 0, 9, 7]),
+                    "pv": _i64(range(7))}), None)], 1)
     cases["selection_already_set"] = (
         build, [(probe(3000), rng.random(3000) < 0.4),
                 (probe(500), np.zeros(500, dtype=bool))], 1)
@@ -381,9 +388,10 @@ def _not_taken():
     from blaze_tpu.ops.joins import JoinType
     build_t, probe_batches, nkeys = _PROBE_CASES["null_keys_on_both_sides"]
     cases = {
-        "utf8_build_column": (
-            build_t.append_column("name", pa.array(["a", "b", None, "d"])),
-            probe_batches, nkeys, JoinType.INNER, None),
+        "utf8_build_key": (
+            build_t.set_column(0, "bk", pa.array(["1", "2", None, "4"])),
+            [(b.set_column(0, "pk", b.column(0).cast(pa.string())), s)
+             for b, s in probe_batches], nkeys, JoinType.INNER, None),
         "duplicate_build_keys": (
             pa.concat_tables([build_t, build_t.slice(0, 1)]),
             probe_batches, nkeys, JoinType.INNER, None),
@@ -401,9 +409,10 @@ _NOT_TAKEN = _not_taken()
 
 @pytest.mark.parametrize("case", list(_NOT_TAKEN))
 def test_joins_the_device_probe_does_not_take(case, monkeypatch):
-    """A utf8 build column, duplicate build keys, a join filter and every
-    join type but inner go through the pair expansion and the host, as
-    before, and answer as Arrow's join does under host placement."""
+    """A utf8 KEY, duplicate build keys, a join filter and every join type
+    but inner go through the pair expansion and the host, as before, and
+    answer as Arrow's join does under host placement.  (A utf8 build
+    COLUMN rides the device probe as codes: tests/test_dict_columns.py.)"""
     build_t, probe_batches, nkeys, how, flt = _NOT_TAKEN[case]
     want, _host = _answer(_broadcast_join(build_t, probe_batches, nkeys,
                                           how, flt, place=False))
@@ -465,8 +474,8 @@ def test_direct_key_is_decided_from_the_build_side_alone():
     assert direct_key("build_side_of_one_row") == (28, 1)
     assert direct_key("sparse_keys_just_inside_the_rule") == (0, 80_000)
     assert direct_key("few_keys_inside_the_floor_of_the_rule") == (0, 65_536)
-    # duplicate keys and a utf8 column: not `unique_fixed`, so not direct
-    for case in ("duplicate_build_keys", "utf8_build_column"):
+    # duplicate keys and a utf8 key: not `unique_fixed`, so not direct
+    for case in ("duplicate_build_keys", "utf8_build_key"):
         build_t = _NOT_TAKEN[case][0]
         jmap = JoinMap(build_t, [col(0)], Schema.from_arrow(build_t.schema))
         assert not jmap.unique_fixed and jmap.direct_key is None

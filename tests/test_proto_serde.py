@@ -595,3 +595,28 @@ def test_generate_required_cols_survive_the_wire(tmp_path):
     assert [f.name for f in wired.schema] == \
         [f.name for f in direct.schema]
     assert len(wired.schema) == 3  # sk + pos + exploded element
+
+
+@pytest.mark.parametrize("value,type_dict", [
+    (0.0, {"id": "float64"}), (-0.0, {"id": "float64"}),
+    (1, {"id": "int64"}), (1, {"id": "int32"}), (True, {"id": "bool"}),
+    (None, {"id": "utf8"}), (None, {"id": "int32"}), ("", {"id": "utf8"}),
+    (float("nan"), {"id": "float64"})])
+def test_a_literal_is_written_once_and_reads_as_itself(value, type_dict):
+    """A plan's literals go through Arrow IPC once a process: what tells
+    two of them apart is the value's repr, its Python type and the type
+    it is written as (0.0 == -0.0 and True == 1, so the value alone would
+    not), and a caller may change the dict it is handed."""
+    import math
+    first = scalar_to_proto(value, type_dict)
+    again = scalar_to_proto(value, dict(type_dict))
+    assert first.ipc_bytes == again.ipc_bytes
+    for _ in range(2):  # read, then read from what was kept
+        got, got_type = scalar_from_proto(first)
+        assert got_type == type_dict and type(got) is type(value)
+        if isinstance(value, float):
+            assert math.isnan(got) if math.isnan(value) \
+                else math.copysign(1.0, got) == math.copysign(1.0, value)
+        else:
+            assert got == value
+        got_type["id"] = "changed"

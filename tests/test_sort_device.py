@@ -233,17 +233,26 @@ def test_a_utf8_payload_column_takes_the_host_lane():
     assert moved["sort_device_rows"] == ROWS    # the permutation alone
 
 
-def test_a_dictionary_column_takes_the_host_lane():
-    names = pa.array([f"n{i % 13}" for i in range(ROWS)]).dictionary_encode()
+@pytest.mark.parametrize("as_key", [False, True])
+def test_a_dictionary_column_stays_on_the_chip(as_key):
+    """A dictionary column is its int32 code lane: a payload is gathered
+    like any column, a key orders by its codes' ranks in string order (the
+    dictionary here is in first-seen order, not sorted).  The host lane
+    sorts the strings themselves."""
+    names = pa.array([None if i % 11 == 0 else f"n{(i * 7) % 13}"
+                      for i in range(ROWS)]).dictionary_encode()
     table = _table(["int64"]).append_column("name", names)
-    want = _collect(SortExec(_scan(table, RAGGED), [(col(0), False, True)]))
+    specs = [(col(table.num_columns - 1), True, False)] * as_key \
+        + [(col(0), False, True)]
+    want = _collect(SortExec(_scan(table, RAGGED), specs))
     with device_placement():
         before = xla_stats.snapshot()
-        got = _collect(SortExec(_scan(table, RAGGED), [(col(0), False, True)]))
+        got = _collect(SortExec(_scan(table, RAGGED), specs))
         moved = xla_stats.delta(before)
     assert got.column("rid").equals(want.column("rid"))
     assert got.column("name").to_pylist() == want.column("name").to_pylist()
-    assert moved["sort_resident_rows"] == 0
+    assert moved["sort_resident_rows"] == ROWS
+    assert moved["dict_rows_coded"] == ROWS
 
 
 def test_a_host_column_takes_the_host_lane():

@@ -576,7 +576,7 @@ def test_span_registry_pin():
         "join_probe", "agg_drain", "sort_device", "smj_merge",
         "partial_passthrough", "table_rehash",
         "op:*", "coalesce", "loop_window", "table_init", "gc_pause",
-        "decimal_host_eval", "window_device",
+        "decimal_host_eval", "window_device", "dict_decode", "dict_remap",
         "task_retry", "fault_injected", "xla_compile",
         "device_shuffle_fallback", "rss_shuffle_fallback",
         "stage_loop_fallback", "quota_breach", "mem_spill",
