@@ -147,7 +147,10 @@ SPAN_NAMES: Dict[str, str] = {
                  "next(source) plus transform; suffix is the prefetcher "
                  "name, e.g. produce:parquet_scan = decode, dictionary "
                  "encoding, from_arrow and the nested h2d (ops/base.py "
-                 "PrefetchIterator._work; attrs rows)",
+                 "PrefetchIterator._work; attrs rows, and for a parquet "
+                 "scan row_groups and pruned: the row groups of the files "
+                 "the pull opened and those left undecoded on their "
+                 "statistics)",
     "join_build": "a join's build side collected (step=collect) or "
                   "hash-indexed (step=index) (ops/joins/exec.py)",
     "join_probe": "one probe batch from hashed keys to joined batch, or "

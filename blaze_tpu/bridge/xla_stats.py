@@ -52,12 +52,17 @@ _chips: Dict[int, Dict[str, int]] = {}
 # overlapped with compute); and the rows `CoalesceStream` re-batched
 # (ops/base.py), by the lane they left through: laid end to end on the chip
 # by the tile program (kernels/tiles.py `lay_tile`) or joined by
-# `ColumnBatch.concat`.  A batch that passed whole is in neither.  By chip
+# `ColumnBatch.concat`.  A batch that passed whole is in neither.  And the
+# row groups of the parquet files a scan opened one by one
+# (ops/scan.py `_decode_batches`), with those among them that it never
+# decoded because their statistics proved that no row of theirs could meet
+# the scan's predicate or its consumer's condition.  The last four by chip
 # in `chip_stats()` too.
 _pipeline = {"bucket_batches": 0, "bucket_pad_rows": 0,
              "prefetch_batches": 0, "prefetch_wait_ns": 0,
              "prefetch_waits": 0,
-             "coalesce_tiled_rows": 0, "coalesce_concat_rows": 0}
+             "coalesce_tiled_rows": 0, "coalesce_concat_rows": 0,
+             "scan_row_groups": 0, "scan_row_groups_pruned": 0}
 _bucket_caps: set = set()
 
 # Whole-stage expression-program accounting (exprs/program.py).  Programs
@@ -501,6 +506,8 @@ def _chip_entry(chip: int) -> Dict[str, int]:
                                 "sort_resident_rows": 0,
                                 "coalesce_tiled_rows": 0,
                                 "coalesce_concat_rows": 0,
+                                "scan_row_groups": 0,
+                                "scan_row_groups_pruned": 0,
                                 **{k: 0 for k in _window},
                                 **{k: 0 for k in _dicts},
                                 **{k: 0 for k in _CHIP_TABLE_KEYS}}
@@ -572,7 +579,8 @@ def chip_stats() -> Dict[int, Dict[str, int]]:
     "join_probe_direct_rows",
     "stage_loop_windows", "stage_loop_windows_fused", "stage_loop_lanes",
     "stage_loop_undone_steps", "stage_loop_decimal_rows", "sort_resident_rows",
-    "coalesce_tiled_rows", "coalesce_concat_rows", the four window
+    "coalesce_tiled_rows", "coalesce_concat_rows", "scan_row_groups",
+    "scan_row_groups_pruned", the four window
     counters (`_window`), the five dictionary and Expand counters
     (`_dicts`) and the stage loop's table counters
     (_CHIP_TABLE_KEYS)} since the last reset: what each chip was given to
@@ -597,6 +605,16 @@ def note_coalesce(chip: int, tiled: bool, rows: int) -> None:
     with _lock:
         _pipeline[key] += int(rows)
         _chip_entry(chip)[key] += int(rows)
+
+
+def note_scan_groups(chip: int, groups: int, pruned: int) -> None:
+    """A scan on `chip` opened a parquet file of `groups` row groups and
+    left `pruned` of them undecoded on their statistics."""
+    with _lock:
+        for key, n in (("scan_row_groups", groups),
+                       ("scan_row_groups_pruned", pruned)):
+            _pipeline[key] += int(n)
+            _chip_entry(chip)[key] += int(n)
 
 
 def note_prefetch(batches: int = 0, wait_ns: int = 0) -> None:

@@ -264,7 +264,12 @@ TOKIO_WORKER_THREADS_PER_CPU = int_conf(
     "(ref rt.rs:108-112; our executor is a thread pool feeding the device).")
 PARQUET_ENABLE_PAGE_FILTERING = bool_conf(
     "auron.parquet.enable.pageFiltering", True,
-    "Row-group/page pruning with min-max stats on scan (ref conf.rs:43).")
+    "Row-group/page pruning with min-max stats on scan (ref conf.rs:43): "
+    "by the scan's own predicate and, for one read, by what its consumer "
+    "says of the rows it will use (a fused aggregation's leading filters, "
+    "a FilterExec's conjuncts, a join's build-key range), on the host's "
+    "path and on the chip's alike.  A row group without statistics is "
+    "read.")
 PARQUET_ENABLE_BLOOM_FILTER = bool_conf(
     "auron.parquet.enable.bloomFilter", False,
     "Parquet bloom-filter pruning on scan (ref conf.rs:44).")
@@ -450,7 +455,11 @@ JOIN_RUNTIME_FILTER_ENABLE = bool_conf(
     "auron.tpu.join.runtimeFilter", True,
     "Drop probe rows outside the build side's join-key [min, max] before "
     "hash-probing (the runtime-filter join analog; ref bloom_filter agg "
-    "+ bloom_filter_might_contain.rs).")
+    "+ bloom_filter_might_contain.rs).  On the host's path and on the "
+    "chip's the range also prunes the row groups of a parquet scan the "
+    "join probes (under auron.parquet.enable.pageFiltering; inner and "
+    "probe-side semi joins, integer and date keys that are plain "
+    "columns); on the chip's path the probe program is the row filter.")
 FUSED_HOST_EAGER_SCAN_BYTES = int_conf(
     "auron.tpu.fused.hostVectorized.eagerScanBytes", 128 << 20,
     "Parquet inputs up to this size read eagerly (pq.read_table + "

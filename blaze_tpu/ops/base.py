@@ -172,13 +172,18 @@ class PrefetchIterator:
 
     def __init__(self, source, depth: Optional[int] = None,
                  transform: Optional[Callable] = None,
-                 name: str = "prefetch"):
+                 name: str = "prefetch",
+                 span_attrs: Optional[dict] = None):
         if depth is None:
             depth = (config.IO_PREFETCH_DEPTH.get()
                      if config.IO_PREFETCH_ENABLE.get() else 0)
         self._source = iter(source)
         self._transform = transform
         self._name = name
+        # a dict the source fills as it produces an item: what is in it
+        # after a pull moves to that pull's `produce:*` span (a scan: the
+        # row groups it looked at)
+        self._span_attrs = span_attrs
         self._done = False
         if depth <= 0:
             self._queue = None
@@ -208,6 +213,12 @@ class PrefetchIterator:
                             item = next(self._source)
                         except StopIteration:
                             break
+                        finally:
+                            # (the last pull too: a file all of whose
+                            # row groups were pruned yields nothing)
+                            if self._span_attrs:
+                                attrs.update(self._span_attrs)
+                                self._span_attrs.clear()
                         if self._transform is not None:
                             item = self._transform(item)
                         attrs["rows"] = getattr(item, "num_rows", 0)
@@ -283,11 +294,12 @@ class PrefetchIterator:
 
 def prefetch(source, depth: Optional[int] = None,
              transform: Optional[Callable] = None,
-             name: str = "prefetch"):
+             name: str = "prefetch",
+             span_attrs: Optional[dict] = None):
     """Wrap a host-IO stream with the bounded background prefetcher (see
     PrefetchIterator); semantics of the stream are unchanged."""
     return PrefetchIterator(source, depth=depth, transform=transform,
-                            name=name)
+                            name=name, span_attrs=span_attrs)
 
 
 class ExecutionPlan:
@@ -348,15 +360,37 @@ class ExecutionPlan:
         """Pull-stream of batches for one partition."""
         raise NotImplementedError
 
-    def arrow_batches(self, partition: int):
+    def arrow_batches(self, partition: int, **kwargs):
         """Pull-stream of Arrow record batches.  Host-resident consumers
         (Acero joins, host-vectorized agg) use this to stay
         Arrow-resident; sources that already hold Arrow data override it
-        to skip the ColumnBatch round trip entirely."""
-        for cb in self.execute(partition):
+        to skip the ColumnBatch round trip entirely.  Keyword arguments
+        are `execute`'s."""
+        for cb in self.execute(partition, **kwargs):
             cb = cb.compact()
             if cb.num_rows:
                 yield cb.to_arrow()
+
+    # Whether `execute` and `arrow_batches` take `extra_prune`: a condition
+    # over this operator's output that every row its consumer goes on to
+    # use meets, for a parquet scan beneath to prune its row groups by (a
+    # parquet scan; a `FilterExec`, whose output is its child's).
+    accepts_prune = False
+
+    def execute_pruned(self, partition: int, conjuncts, arrow: bool = False):
+        """`execute(partition)` (`arrow_batches` where `arrow`) for a
+        consumer that uses only the rows that meet every one of
+        `conjuncts`, conditions over `self.schema`.  An operator that
+        `accepts_prune` takes their AND with THIS read as a
+        statistics-only pruning predicate, so that a row group none of
+        whose rows can meet it is never decoded nor placed on the chip;
+        any other ignores them.  The consumer still filters row by row."""
+        from blaze_tpu.ops.pruning import conjunction
+        open_ = self.arrow_batches if arrow else self.execute
+        pred = conjunction(conjuncts) if self.accepts_prune else None
+        if pred is None:
+            return open_(partition)
+        return open_(partition, extra_prune=pred)
 
     def execute_collect(self) -> "ColumnBatch":
         """All partitions concatenated (test/driver helper)."""

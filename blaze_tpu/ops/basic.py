@@ -22,6 +22,8 @@ class FilterExec(ExecutionPlan):
     """Selection-mask filter; no compaction until density drops
     (ref filter_exec.rs; compaction by CoalesceStream)."""
 
+    accepts_prune = True
+
     def __init__(self, child: ExecutionPlan, predicates: Sequence[PhysicalExpr]):
         super().__init__([child])
         self._predicates = list(predicates)
@@ -30,13 +32,20 @@ class FilterExec(ExecutionPlan):
     def schema(self) -> Schema:
         return self.children[0].schema
 
-    def execute(self, partition: int) -> BatchIterator:
+    def execute(self, partition: int, extra_prune=None) -> BatchIterator:
+        """`extra_prune`: a condition of the consumer's over this filter's
+        output, which is its child's: handed on with the filter's own
+        conjuncts to a child that prunes by statistics
+        (`execute_pruned`)."""
         # per-partition instance, but the compiled program behind it is
         # resolved from the process-wide fingerprint cache (exprs/program)
         ev = FusedExprsEvaluator(filters=self._predicates,
                                  in_schema=self.schema)
+        conjuncts = self._predicates + (
+            [extra_prune] if extra_prune is not None else [])
         def gen():
-            for batch in self.children[0].execute(partition):
+            for batch in self.children[0].execute_pruned(partition,
+                                                         conjuncts):
                 yield ev.filter(batch)
         return iter(CoalesceStream(gen(), metrics=self.metrics))
 

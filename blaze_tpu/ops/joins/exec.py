@@ -266,6 +266,26 @@ class _DeviceBuild(MemConsumer):
         return released
 
 
+def key_ranges(rows: int, key_arrays) -> Optional[List[Optional[Tuple]]]:
+    """A key position: the (min, max) Arrow scalars of a build side's
+    values of that key over its non-NULL rows where the key is of an
+    integer or date type, else None.  The list is None where some key has
+    no value at all (an empty build side, an all-NULL key): no probe row
+    can match then.  The ONE derivation of the join-key runtime filter's
+    ranges: the row filter of the Acero lane's collection and the
+    row-group pruning of every lane's probe scan read it."""
+    if not rows or any(k.null_count == len(k) for k in key_arrays):
+        return None
+    ranges = []
+    for key in key_arrays:
+        if not (pa.types.is_integer(key.type) or pa.types.is_date(key.type)):
+            ranges.append(None)
+            continue
+        mm = pc.min_max(key)
+        ranges.append((mm["min"], mm["max"]))
+    return ranges
+
+
 class JoinMap:
     """Hash-sorted build table (the JoinHashMap analog, join_hash_map.rs:277).
 
@@ -401,6 +421,13 @@ class JoinMap:
                 and all(e.data_type(self.schema).is_fixed_width
                         for e in self._key_exprs)
                 and (not len(self.ucount) or int(self.ucount.max()) == 1))
+
+    @functools.cached_property
+    def key_ranges(self):
+        """`key_ranges` of the build side's keys (the streamed lanes'; the
+        Acero lane reads its own key columns and never builds the index)."""
+        self._ensure_index()
+        return key_ranges(self.num_rows, self.key_arrays)
 
     def _int_keys(self) -> np.ndarray:
         """The one integer key's value a build row, as int64 (a date or
@@ -618,9 +645,13 @@ class BaseJoinExec(ExecutionPlan):
                 self._pa_join(jmap, partition, probe, probe_keys,
                               probe_is_left),
                 metrics=self.metrics))
+        conjuncts = (self._key_range_conjuncts(jmap.key_ranges, probe.schema,
+                                               probe_keys)
+                     if self._runtime_filter_on(probe_is_left) else [])
         return iter(CoalesceStream(
-            self._stream_probe(jmap, probe.execute(partition), probe_keys,
-                               probe_is_left),
+            self._stream_probe(jmap,
+                               probe.execute_pruned(partition, conjuncts),
+                               probe_keys, probe_is_left),
             metrics=self.metrics))
 
     def _stream_probe(self, jmap, batches, probe_keys, probe_is_left):
@@ -803,18 +834,15 @@ class BaseJoinExec(ExecutionPlan):
         # outside the build key range never occupy collect memory (and a
         # selective filter keeps large probes under the collect limit
         # instead of tipping them onto the streaming path)
-        prefilter, covered, rf_ranges = self._collect_prefilter(
-            build_tbl, probe_keys, probe_is_left)
-        prune_pred = self._scan_prune_pred(probe, rf_ranges)
+        prefilter, covered, conjuncts = self._collect_prefilter(
+            build_tbl, probe.schema, probe_keys, probe_is_left)
         chunks: List[pa.RecordBatch] = []
         rows = 0
         # Arrow-resident collection: sources that hold Arrow data (scans)
         # stream it straight through without a ColumnBatch round trip;
         # parquet probes additionally row-group-prune by the runtime
         # filter for THIS read only
-        stream = (probe.arrow_batches(partition, extra_prune=prune_pred)
-                  if prune_pred is not None
-                  else probe.arrow_batches(partition))
+        stream = probe.execute_pruned(partition, conjuncts, arrow=True)
         overflowed = False
         for rb in stream:
             if prefilter is not None and rb.num_rows:
@@ -837,35 +865,34 @@ class BaseJoinExec(ExecutionPlan):
                                       probe_is_left, skip_filter_keys=covered)
 
     @staticmethod
-    def _scan_prune_pred(probe, rf_ranges):
-        """Build-side join-key [min, max] runtime filter as a
-        scan-granularity pruning predicate for the probe's parquet scan —
-        with date-clustered fact tables whole row groups outside the
-        build key range are never decoded (the reference pushes its bloom
-        runtime filters into the probe scan the same way:
-        bloom_filter_might_contain.rs + parquet page filtering).
-        Row-exact filtering still happens in the collect prefilter; the
-        predicate is handed to ONE arrow_batches read (never stored on
-        the shared plan node).  None when inapplicable."""
+    def _key_range_conjuncts(ranges, schema: Schema, probe_keys) -> list:
+        """What the build side's `key_ranges` say of every probe row that
+        can match, as conditions over the probe's `schema` for
+        `execute_pruned` to hand to the probe's parquet scan - with
+        date-clustered fact tables whole row groups outside the build key
+        range are never decoded (the reference pushes its bloom runtime
+        filters into the probe scan the same way:
+        bloom_filter_might_contain.rs + parquet page filtering): a key
+        that is a plain column of the probe lies inside the build side's
+        [min, max] of it, and nothing matches a build side without a
+        key.  Row-exact filtering stays with the join."""
         from blaze_tpu.exprs.base import BoundReference, Literal
         from blaze_tpu.exprs.binary import BinaryExpr
-        from blaze_tpu.ops.scan import ParquetScanExec
-        if (not rf_ranges or not isinstance(probe, ParquetScanExec)
-                or probe._out_partition_fields
-                or not config.PARQUET_ENABLE_PAGE_FILTERING.get()):
-            return None
-        pred = None
-        for _k, idx, mn, mx in rf_ranges:
-            if idx >= len(probe.schema):
-                continue
-            f = probe.schema[idx]
-            col = BoundReference(idx, f.name)
-            rng = BinaryExpr(
-                "and",
-                BinaryExpr(">=", col, Literal(mn.as_py(), f.data_type)),
-                BinaryExpr("<=", col, Literal(mx.as_py(), f.data_type)))
-            pred = rng if pred is None else BinaryExpr("and", pred, rng)
-        return pred
+        from blaze_tpu.exprs.conditional import InList
+        cols = [(i, BoundReference(e.index, schema[e.index].name),
+                 schema[e.index].data_type)
+                for i, e in enumerate(probe_keys)
+                if isinstance(e, BoundReference) and e.index < len(schema)]
+        if ranges is None:
+            # IN (): statistics of any kind prove a row group empty
+            return [InList(col, ()) for _i, col, _t in cols[:1]]
+        out = []
+        for i, col, dtype in cols:
+            if ranges[i] is not None:
+                mn, mx = ranges[i]
+                out += [BinaryExpr(">=", col, Literal(mn.as_py(), dtype)),
+                        BinaryExpr("<=", col, Literal(mx.as_py(), dtype))]
+        return out
 
     def _runtime_filter_drop_ok(self, probe_is_left: bool) -> bool:
         """Whether dropping never-matching probe rows is semantics-
@@ -875,46 +902,45 @@ class BaseJoinExec(ExecutionPlan):
                 (jt == JoinType.LEFT_SEMI and probe_is_left) or
                 (jt == JoinType.RIGHT_SEMI and not probe_is_left))
 
+    def _runtime_filter_on(self, probe_is_left: bool) -> bool:
+        return (self._runtime_filter_drop_ok(probe_is_left)
+                and config.JOIN_RUNTIME_FILTER_ENABLE.get())
+
     @staticmethod
     def _range_mask(col, mn, mx):
         return pc.and_(pc.greater_equal(col, mn), pc.less_equal(col, mx))
 
-    def _collect_prefilter(self, build_tbl, probe_keys,
-                           probe_is_left: bool):
-        """(closure, covered-keys) pair: the closure drops probe rows
-        outside the build side's integer join-key [min, max] ranges,
-        applied batch-by-batch while the probe is being collected;
-        `covered` lists the key positions it handled so the join-time
-        filter skips them; `ranges` [(key, probe_col, min, max)] lets the
-        caller push scan-granularity pruning.  (None, frozenset(), [])
-        when inapplicable (non-droppable join type, computed/non-integer
-        keys)."""
+    def _collect_prefilter(self, build_tbl, probe_schema: Schema,
+                           probe_keys, probe_is_left: bool):
+        """(closure, covered, conjuncts): the closure drops probe rows
+        outside the build side's join-key [min, max] ranges (`key_ranges`:
+        integer and date keys that are plain probe columns), applied
+        batch-by-batch while the probe is being collected; `covered`
+        lists the key positions it handled so the join-time filter skips
+        them; `conjuncts` say the same of the probe's rows for its scan
+        to prune row groups by (`_key_range_conjuncts`).
+        (None, frozenset(), []) when inapplicable (non-droppable join
+        type, computed or other keys)."""
         none = (None, frozenset(), [])
-        if not (self._runtime_filter_drop_ok(probe_is_left)
-                and config.JOIN_RUNTIME_FILTER_ENABLE.get()):
+        if not self._runtime_filter_on(probe_is_left):
             return none
+        from blaze_tpu.exprs.base import BoundReference
         bprefix = "l" if not probe_is_left else "r"
-        ranges = []
-        empty = build_tbl.num_rows == 0
-        if not empty:
-            from blaze_tpu.exprs.base import BoundReference
-            for i, e in enumerate(probe_keys):
-                if not isinstance(e, BoundReference):
-                    continue
-                bcol = build_tbl.column(f"__{bprefix}k{i}")
-                if not pa.types.is_integer(bcol.type):
-                    continue
-                mm = pc.min_max(bcol)
-                if not mm["min"].is_valid:
-                    empty = True  # all-null build keys: nothing matches
-                    break
-                ranges.append((i, e.index, mm["min"], mm["max"]))
+        ranges = key_ranges(
+            build_tbl.num_rows,
+            [build_tbl.column(f"__{bprefix}k{i}")
+             for i in range(len(probe_keys))])
+        conjuncts = self._key_range_conjuncts(ranges, probe_schema,
+                                              probe_keys)
         metrics = self.metrics
-        if empty:
+        if ranges is None:
             def drop_all(rb):
                 metrics.add("runtime_filter_pruned", rb.num_rows)
                 return rb.slice(0, 0)
-            return drop_all, frozenset(range(len(probe_keys))), []
+            return drop_all, frozenset(range(len(probe_keys))), conjuncts
+        ranges = [(i, e.index) + ranges[i]
+                  for i, e in enumerate(probe_keys)
+                  if isinstance(e, BoundReference) and ranges[i] is not None]
         if not ranges:
             return none
 
@@ -927,7 +953,7 @@ class BaseJoinExec(ExecutionPlan):
             metrics.add("runtime_filter_pruned",
                         rb.num_rows - out.num_rows)
             return out
-        return apply, frozenset(k for k, *_r in ranges), ranges
+        return apply, frozenset(k for k, *_r in ranges), conjuncts
 
     def _runtime_filter_probe(self, build_tbl, probe_tbl, pprefix: str,
                               probe_is_left: bool,

@@ -9,7 +9,9 @@ evaluator that returns "maybe" unless stats prove a group empty.
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+import decimal
+import functools
+from typing import List, Optional, Sequence, Tuple
 
 from blaze_tpu.exprs.base import BoundReference, Literal, PhysicalExpr
 from blaze_tpu.exprs.binary import BinaryExpr
@@ -17,6 +19,53 @@ from blaze_tpu.exprs.conditional import InList, IsNotNull, IsNull
 from blaze_tpu.schema import Schema
 
 Interval = Tuple[Optional[object], Optional[object], bool]  # (min, max, has_nulls)
+
+
+def conjunction(preds: Sequence[PhysicalExpr]) -> Optional[PhysicalExpr]:
+    """The AND of `preds`; None for none."""
+    preds = list(preds)
+    if not preds:
+        return None
+    return functools.reduce(lambda a, b: BinaryExpr("and", a, b), preds)
+
+
+def by_name(pred: PhysicalExpr, schema: Schema) -> Optional[PhysicalExpr]:
+    """What of `pred` statistics can decide, every column reference
+    carrying the name `schema` gives its ORDINAL (the ordinal is what a
+    row-wise evaluation reads; a reference may carry no name, and a scan
+    with a projection numbers its output otherwise than its file).  A
+    conjunct statistics cannot decide is dropped, which only weakens the
+    predicate; None where nothing is left.  The result may be held
+    against any schema that has the columns by these names."""
+    if isinstance(pred, BinaryExpr):
+        if pred.op in ("and", "or"):
+            l, r = by_name(pred.left, schema), by_name(pred.right, schema)
+            if pred.op == "and":
+                return conjunction([e for e in (l, r) if e is not None])
+            return None if l is None or r is None else BinaryExpr("or", l, r)
+        if pred.op in ("==", "<", "<=", ">", ">="):
+            sides = [_named(e, schema) for e in (pred.left, pred.right)]
+            if None in sides:
+                return None
+            return BinaryExpr(pred.op, *sides)
+        return None
+    if isinstance(pred, (InList, IsNull, IsNotNull)):
+        child = _named(pred.child, schema)
+        if not isinstance(child, BoundReference):
+            return None
+        if isinstance(pred, InList):
+            return (None if pred.negated
+                    else InList(child, tuple(pred.values)))
+        return type(pred)(child)
+    return None
+
+
+def _named(e: PhysicalExpr, schema: Schema) -> Optional[PhysicalExpr]:
+    if isinstance(e, Literal):
+        return e
+    if isinstance(e, BoundReference) and 0 <= e.index < len(schema):
+        return BoundReference(e.index, schema[e.index].name)
+    return None
 
 
 def _name_to_col(md):
@@ -36,21 +85,18 @@ def pred_columns(pred: PhysicalExpr, schema: Schema) -> set:
     return out
 
 
-def _group_stats(rg, name_to_col, strict_nulls: bool) -> dict:
-    """Per-column (min, max, has_nulls) for one row group.
-
-    strict_nulls: a MISSING null_count counts as "may have nulls" — the
-    always-match direction is only sound when absence of nulls is
-    PROVEN; the may-match direction stays permissive."""
+def _group_stats(rg, name_to_col) -> dict:
+    """Per-column (min, max, has_nulls) for one row group.  A MISSING
+    null_count counts as "may have nulls": absence of nulls has to be
+    PROVEN before `_always_match` elides a filter, and their presence
+    cannot be ruled out before `_may_match` prunes on `is_null`."""
     stats = {}
     for name, ci in name_to_col.items():
         col = rg.column(ci)
         if col.statistics is not None and col.statistics.has_min_max:
             nc = col.statistics.null_count
-            has_nulls = ((nc is None or nc > 0) if strict_nulls
-                         else (nc or 0) > 0)
             stats[name] = (col.statistics.min, col.statistics.max,
-                           has_nulls)
+                           nc is None or nc > 0)
     return stats
 
 
@@ -76,8 +122,7 @@ def prune_with_stats(md, schema: Schema, predicate: PhysicalExpr,
     name_to_col = _pred_cols_map(md, schema, predicate)
     keep = []
     for g in groups:
-        stats = _group_stats(md.row_group(g), name_to_col,
-                             strict_nulls=False)
+        stats = _group_stats(md.row_group(g), name_to_col)
         if _may_match(predicate, schema, stats):
             keep.append(g)
     return keep
@@ -100,8 +145,7 @@ def split_covered(md, schema: Schema, predicate: PhysicalExpr,
     name_to_col = _pred_cols_map(md, schema, predicate)
     covered, boundary = [], []
     for g in groups:
-        stats = _group_stats(md.row_group(g), name_to_col,
-                             strict_nulls=True)
+        stats = _group_stats(md.row_group(g), name_to_col)
         (covered if _always_match(predicate, schema, stats)
          else boundary).append(g)
     return covered, boundary
@@ -129,6 +173,7 @@ def _always_match(pred: PhysicalExpr, schema: Schema, stats: dict) -> bool:
             if name is None or lit is None or name not in stats:
                 return False
             mn, mx, has_nulls = stats[name]
+            lit = _like(lit, mn)
             if has_nulls:
                 return False
             # parquet float/double min/max statistics IGNORE NaN rows,
@@ -174,6 +219,16 @@ def _lit_value(expr: PhysicalExpr):
     return None
 
 
+def _like(lit, stat):
+    """`lit` as it compares with the statistics' values: a decimal
+    column's bounds are `Decimal`s, and a literal written as a float
+    means its shortest decimal (`Literal.unscaled` reads it so): 1.2,
+    not the double nearest to it."""
+    if isinstance(stat, decimal.Decimal) and isinstance(lit, float):
+        return decimal.Decimal(str(lit))
+    return lit
+
+
 def _may_match(pred: PhysicalExpr, schema: Schema, stats: dict) -> bool:
     """Conservative: False only when stats PROVE no row matches."""
     if isinstance(pred, BinaryExpr):
@@ -193,6 +248,7 @@ def _may_match(pred: PhysicalExpr, schema: Schema, stats: dict) -> bool:
             if name is None or lit is None or name not in stats:
                 return True
             mn, mx, _ = stats[name]
+            lit = _like(lit, mn)
             try:
                 if op == "==":
                     return mn <= lit <= mx
@@ -213,7 +269,8 @@ def _may_match(pred: PhysicalExpr, schema: Schema, stats: dict) -> bool:
             return True
         mn, mx, _ = stats[name]
         try:
-            return any(v is not None and mn <= v <= mx for v in pred.values)
+            return any(v is not None and mn <= _like(v, mn) <= mx
+                       for v in pred.values)
         except TypeError:
             return True
     if isinstance(pred, IsNull):

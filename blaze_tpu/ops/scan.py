@@ -145,6 +145,8 @@ class ParquetScanExec(ExecutionPlan):
     row-wise on device by the FilterExec above this scan.
     """
 
+    accepts_prune = True
+
     def __init__(self, schema: Schema, file_groups: Sequence[Sequence[str]],
                  projection: Optional[Sequence[str]] = None,
                  predicate=None, batch_rows: Optional[int] = None,
@@ -195,7 +197,12 @@ class ParquetScanExec(ExecutionPlan):
     def num_partitions(self) -> int:
         return len(self._file_groups)
 
-    def execute(self, partition: int) -> BatchIterator:
+    def execute(self, partition: int, extra_prune=None) -> BatchIterator:
+        """`extra_prune`: a condition over THIS scan's output that every
+        row the consumer goes on to use meets (the fused aggregation's
+        filter, a join's build-key range).  It prunes row groups by their
+        statistics for this read alone (see `_decode_batches`); the
+        consumer still filters row by row."""
         # decode AND ColumnBatch conversion (incl. device placement) run on
         # the prefetch worker: the next batch's pyarrow decode + H2D
         # overlap downstream compute (double-buffering; kill-switch
@@ -215,10 +222,12 @@ class ParquetScanExec(ExecutionPlan):
                     rb = _enc(rb)
                 cb = ColumnBatch.from_arrow(rb)
                 return _post(cb) if _post is not None else cb
-        return prefetch(self._decode_batches(partition),
+        # the row groups each pull looked at, for its `produce:*` span
+        seen = {}
+        return prefetch(self._decode_batches(partition, extra_prune, seen),
                         depth=self._prefetch_depth(),
                         transform=transform,
-                        name="parquet_scan")
+                        name="parquet_scan", span_attrs=seen)
 
     @staticmethod
     def _prefetch_depth():
@@ -256,7 +265,8 @@ class ParquetScanExec(ExecutionPlan):
         return prefetch(self._decode_batches(partition, extra_prune),
                         name="parquet_scan")
 
-    def _decode_batches(self, partition: int, extra_prune=None):
+    def _decode_batches(self, partition: int, extra_prune=None,
+                        seen: Optional[dict] = None):
         """Arrow-resident scan stream.  Files under the eager threshold
         decode with pq.read_row_groups (multithreaded column decode,
         measurably faster than the single-threaded iter_batches slicer);
@@ -270,13 +280,22 @@ class ParquetScanExec(ExecutionPlan):
         the same way, ref bloom_filter_might_contain.rs + parquet page
         filtering).  It prunes via statistics only; exact row filtering
         stays with the caller.  Passing it per-read keeps the shared
-        plan node immutable across partitions/executions."""
+        plan node immutable across partitions/executions.  The caller
+        speaks of this scan's OUTPUT columns, which a projection numbers
+        otherwise than the file, so it is held against the file by
+        column name (`pruning.by_name`).
+
+        `seen`: the caller's tally of `row_groups` looked at and `pruned`
+        among them (`xla_stats` counts the same, by chip)."""
         import os
+        from blaze_tpu.bridge import xla_stats
+        from blaze_tpu.bridge.context import current_task
+        from blaze_tpu.ops.pruning import by_name, conjunction
         prune_pred = self._predicate
-        if extra_prune is not None:
-            from blaze_tpu.exprs.binary import BinaryExpr
-            prune_pred = (extra_prune if prune_pred is None
-                          else BinaryExpr("and", prune_pred, extra_prune))
+        if extra_prune is not None and not self._out_partition_fields:
+            prune_pred = conjunction(
+                [p for p in (prune_pred, by_name(extra_prune, self._schema))
+                 if p is not None])
         eager_limit = config.SCAN_EAGER_FILE_BYTES.get()
         group = self._file_groups[partition]
         columns = ([f.name for f in self._file_part]
@@ -312,8 +331,14 @@ class ParquetScanExec(ExecutionPlan):
                     continue
                 raise
             row_groups = self._prune_row_groups(f, prune_pred)
-            self.metrics.add("pruned_row_groups",
-                             f.metadata.num_row_groups - len(row_groups))
+            total = f.metadata.num_row_groups
+            pruned = total - len(row_groups)
+            self.metrics.add("pruned_row_groups", pruned)
+            xla_stats.note_scan_groups(current_task().device_id, total,
+                                       pruned)
+            if seen is not None:
+                seen["row_groups"] = seen.get("row_groups", 0) + total
+                seen["pruned"] = seen.get("pruned", 0) + pruned
             if not row_groups:
                 continue
             if (share_max and isinstance(path, str)

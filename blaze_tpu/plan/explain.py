@@ -256,6 +256,10 @@ class QueryProfile:
                 f"prefetch: batches={x.get('prefetch_batches', 0)} "
                 f"consumer_wait={_fmt_ns(x.get('prefetch_wait_ns', 0))} "
                 f"({x.get('prefetch_waits', 0)} waits)")
+        if x.get("scan_row_groups"):
+            lines.append(
+                f"scan: groups={x.get('scan_row_groups', 0)} "
+                f"pruned={x.get('scan_row_groups_pruned', 0)}")
         if (x.get("expr_fused_batches") or x.get("expr_eager_batches")
                 or x.get("expr_programs_built")):
             looked_up = (x.get("expr_programs_built", 0)

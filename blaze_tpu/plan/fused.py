@@ -1090,6 +1090,26 @@ class FusedPartialAggExec(ExecutionPlan):
                 return None
         return idxs
 
+    def _leading_filters(self):
+        """The conjuncts of the chain's filter steps that come before any
+        step that re-numbers columns: conditions over the SOURCE's schema
+        that every row the aggregation folds meets."""
+        conjuncts = []
+        for kind, preds, _exprs, _schema in self._chain:
+            if kind != "filter":
+                break
+            conjuncts.extend(preds or ())
+        return conjuncts
+
+    def source_stream(self, partition: int) -> BatchIterator:
+        """The source's stream for the lanes that run the chain inside
+        their program (the stage loop opens it here too).  A parquet scan
+        reads only the row groups whose statistics let a row pass the
+        chain's leading filters, as the host lane's eager read does; the
+        chain still filters row by row."""
+        return self._source.execute_pruned(partition,
+                                           self._leading_filters())
+
     def _host_scan_arrow(self, partition: int):
         """Push the absorbed filter chain into Arrow's C++ parquet reader
         (predicate + projection pushdown, the parquet_exec.rs analog) when
@@ -1108,17 +1128,15 @@ class FusedPartialAggExec(ExecutionPlan):
             return None
         if src._partition_schema is not None:
             return None  # partition constants need engine-side assembly
+        if any(kind != "filter" for kind, *_rest in self._chain):
+            return None
         filt = None
-        plain_preds = []
-        for kind, preds, _exprs, _schema in self._chain:
-            if kind != "filter":
+        plain_preds = self._leading_filters()
+        for p in plain_preds:
+            e = to_arrow_filter(p, src.schema)
+            if e is None:
                 return None
-            for p in preds or ():
-                e = to_arrow_filter(p, src.schema)
-                if e is None:
-                    return None
-                filt = e if filt is None else (filt & e)
-                plain_preds.append(p)
+            filt = e if filt is None else (filt & e)
         paths = src._file_groups[partition]
         if not paths:
             return iter(())
@@ -1156,15 +1174,13 @@ class FusedPartialAggExec(ExecutionPlan):
         predicates over date-clustered fact tables make both the common
         case).  Falls back to one whole read_table when nothing prunes —
         identical cost to the pre-pruning path."""
-        import functools
         import pyarrow as pa
         import pyarrow.parquet as pq
-        from blaze_tpu.exprs.binary import BinaryExpr
-        from blaze_tpu.ops.pruning import prune_with_stats, split_covered
+        from blaze_tpu.ops.pruning import (conjunction, prune_with_stats,
+                                           split_covered)
         from blaze_tpu.ops.scan import open_source
 
-        pred = functools.reduce(
-            lambda a, b: BinaryExpr("and", a, b), plain_preds)
+        pred = conjunction(plain_preds)
         files = []          # (ParquetFile, covered_groups, boundary_groups)
         kept_total = 0
         groups_total = 0
@@ -1671,7 +1687,7 @@ class FusedPartialAggExec(ExecutionPlan):
                 wide_mm[i][:] = op(wide_mm[i], np.asarray(mm[i], np.int64))
 
         for cols_stacked, masks, _rows, count in _batch_windows(
-                self._source.execute(partition),
+                self.source_stream(partition),
                 config.FUSED_FOLD_WINDOW.get()):
             wrows = int(masks.shape[0]) * int(masks.shape[1])
             if wrows > mxu_agg.MAX_ROWS_PER_TABLE:
@@ -1735,7 +1751,7 @@ class FusedPartialAggExec(ExecutionPlan):
                                        tuple(self._ranges), tuple(kinds),
                                        num_slots)
             for cols_stacked, masks, _rows, count in _batch_windows(
-                    self._source.execute(partition),
+                    self.source_stream(partition),
                     config.FUSED_FOLD_WINDOW.get()):
                 if carry is None:
                     carry = _init_carry(kinds, self._acc_dtypes(),
@@ -1925,7 +1941,7 @@ class FusedPartialAggExec(ExecutionPlan):
             # prepare is INLINED into the step jit: one dispatch per batch
             # (a second program would pay another dispatch and
             # materialize kd/kv/ad/av between programs)
-            stream = self._source.execute(partition)
+            stream = self.source_stream(partition)
             raw_step = _hash_chain_step_factory(self._prepare_key,
                                                 self._prepare, kinds)
             step = lambda c, b: raw_step(c, *_source_inputs(b))  # noqa: E731

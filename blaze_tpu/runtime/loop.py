@@ -460,7 +460,7 @@ def _fold_partition(program, partition: int, ctx: str, source_stream,
                    max(1, config.PARTIAL_AGG_SKIPPING_MIN_ROWS.get()))
     ratio = config.PARTIAL_AGG_SKIPPING_RATIO.get()
     stream = (source_stream if source_stream is not None
-              else program.source.execute(partition))
+              else program.agg.source_stream(partition))
     windows = _batch_windows(stream, chunk, pad_tail=True)
     batches = rows = lanes = fold_calls = regrows = reserves = undone = 0
     # (old table's slots, groups it held, new slots, lanes re-inserted)
@@ -696,7 +696,7 @@ def execute_loop(program, partition: int, ctx: str = ""):
                      if f.data_type.id == TypeId.UTF8}
         from blaze_tpu.batch import DictStream
         held = DictStream(only={s for s in dict_keys if s is not None})
-        stream = _dict_stream_guard(program.source.execute(partition),
+        stream = _dict_stream_guard(program.agg.source_stream(partition),
                                     utf8_cols, held)
         # no switch: codes decode through the stream's LAST dictionary,
         # and the guard may decline the partition at any batch, which

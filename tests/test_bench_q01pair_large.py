@@ -29,6 +29,11 @@ from blaze_tpu.memory import MemManager  # noqa: E402
 from blaze_tpu.runtime import loop as device_loop  # noqa: E402
 
 SCALE, DATA_SEED, SPLITS, PARTITIONS = 0.4, 20260927, 4, 4
+# files 1 and 2 hold the year: since PR 49 the scans of files 0 and 3 read
+# no row group, their map tasks fold nothing and drain an empty table of
+# the floor's size
+HOT = 2
+COLD = SPLITS - HOT
 FLOOR, BATCH, CHUNK = 1024, 128, 8
 FIRST, LAST = 8 * FLOOR, 32 * FLOOR     # need / _TARGET_LOAD, as powers of 2
 REHASH_LANES = 2 * FLOOR                # the power of two over ~1.9K groups
@@ -243,8 +248,8 @@ def test_a_reduce_task_rehashes_once_and_ends_at_four_times_its_first_table(
         # of the old table's slots
         assert s["lanes"] == REHASH_LANES >= s["groups"]
         assert s["device"] == 0
-    # one first allocation a task, one rehash a reduce task
-    assert moved["stage_loop_reserves"] == SPLITS + 2 * PARTITIONS
+    # one first allocation a task that folds a row, one rehash a reduce task
+    assert moved["stage_loop_reserves"] == HOT + 2 * PARTITIONS
 
 
 def test_the_counters_read_what_the_schedule_says(two_passes):
@@ -258,20 +263,46 @@ def test_the_counters_read_what_the_schedule_says(two_passes):
     assert moved["stage_loop_rehash_new_slots"] == PARTITIONS * LAST
     assert moved["stage_loop_rehash_groups"] \
         == sum(s["groups"] for s in spans)
-    # a map task stays at its first table: a cold one never holds a
-    # group, a hot one drains its table before a third chunk
+    # a map task stays at its first table: a cold one is handed no batch
+    # and drains the floor's empty table, a hot one drains its table
+    # before a third chunk
     assert moved["stage_loop_final_slots"] \
-        == PARTITIONS * LAST + SPLITS * FIRST
+        == PARTITIONS * LAST + HOT * FIRST + COLD * FLOOR
     assert moved["stage_loop_table_bytes"] == SLOT_BYTES * (
-        PARTITIONS * (FIRST + LAST) + SPLITS * FIRST)
+        PARTITIONS * (FIRST + LAST) + HOT * FIRST + COLD * FLOOR)
     assert two_passes["peaks"] == sorted(
-        2 * ([SLOT_BYTES * FIRST] * SPLITS
+        2 * ([SLOT_BYTES * FLOOR] * COLD + [SLOT_BYTES * FIRST] * HOT
              + [SLOT_BYTES * (FIRST + LAST)] * PARTITIONS))
     for key in ("rehash_lanes", "rehash_groups", "rehash_new_slots",
                 "rehash_probe_lanes", "final_slots", "table_bytes"):
         assert moved[f"chip0_stage_loop_{key}"] \
             == moved[f"stage_loop_{key}"]
         assert two_passes["by_chip"][0][f"stage_loop_{key}"] > 0
+
+
+def test_the_cold_map_tasks_read_no_row_group(case, two_passes):
+    """The chain's date filter prunes the scan beneath the stage loop:
+    files 0 and 3 lie outside the year whole, files 1 and 2 keep the row
+    groups that touch it, and a cold task's `produce:parquet_scan` span
+    says that it looked at its file and decoded nothing."""
+    import pyarrow.parquet as pq
+    _query, paths, _tables, _want = case
+    groups = [pq.ParquetFile(g[0]).metadata.num_row_groups
+              for g in paths["store_returns"]]
+    _got, moved, spans = two_passes["passes"][0]
+    assert moved["scan_row_groups"] == sum(groups)
+    assert moved["scan_row_groups_pruned"] >= groups[0] + groups[3]
+    assert moved["scan_row_groups_pruned"] < sum(groups) - 2
+    by_task = {}
+    for s in spans:
+        if s["name"] == "produce:parquet_scan":
+            tally = by_task.setdefault(s["ctx"]["partition"], [0, 0, 0])
+            for i, key in enumerate(("row_groups", "pruned", "rows")):
+                tally[i] += s["attrs"].get(key, 0)
+    assert by_task[0] == [groups[0], groups[0], 0]
+    assert by_task[3] == [groups[3], groups[3], 0]
+    assert all(0 < by_task[p][1] < groups[p] and by_task[p][2] > 0
+               for p in (1, 2))
 
 
 def test_the_manager_holds_nothing_after_the_drain_or_the_switch(two_passes):
@@ -496,5 +527,6 @@ def test_the_cells_traced_line_holds_what_the_manifest_lists_for_it(
     assert got["rehash_probe_lanes"] == PARTITIONS * REHASH_LANES
     assert got["rehash_lanes"] == PARTITIONS * FIRST
     assert got["fold_final_slots"] \
-        == (PARTITIONS * LAST + SPLITS * FIRST) / (SPLITS + PARTITIONS)
+        == (PARTITIONS * LAST + HOT * FIRST + COLD * FLOOR) \
+        / (SPLITS + PARTITIONS)
     assert got["stage_loop_fallbacks"] == got["stage_loop_regrows"] == 0
