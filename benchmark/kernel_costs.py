@@ -4,8 +4,14 @@ benchmark so that no later PR can change what a roofline share divides."""
 from __future__ import annotations
 
 
-def fold_min_bytes(rows_folded: int, row_bytes: int, table_slots: int,
-                   slot_bytes: int, tasks: int) -> int:
-    """Hash-aggregation fold: every folded row's keys, value and selection
-    byte are read once, and each task's table is written once."""
-    return rows_folded * row_bytes + tasks * table_slots * slot_bytes
+def fold_min_bytes(work, row_bytes: int, slot_bytes: int) -> int:
+    """Hash aggregation, one query: `work` is the query file's
+    `fold_work(tables)`, (input rows, groups) of every aggregation of the
+    SQL.  Every input row's keys, value and selection byte are read once
+    and every group's slot is written once.  A partial stage before the
+    final one, rows handed over that a filter then drops, an Expand's
+    copies, empty slots and further probe rounds cost nothing here: a
+    program that moves them does more than it has to, and the share says
+    so.  The same work whatever implements it."""
+    return sum(rows * row_bytes + groups * slot_bytes
+               for rows, groups in work)

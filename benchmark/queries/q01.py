@@ -15,7 +15,7 @@ from benchmark.queries.ir import (Ids, agg, binop, c, ci, exchange,
                                   filter_, join, lit, partial_final,
                                   project, scan, sort_limit)
 from benchmark.queries.q01pair import (  # noqa: F401  the same fold
-    FOLD_ROW_BYTES, FOLD_SLOT_BYTES)
+    FOLD_ROW_BYTES, FOLD_SLOT_BYTES, group_count, year_returns)
 
 TABLES = ["store_returns", "date_dim", "store", "customer"]
 FACT = "store_returns"
@@ -86,3 +86,15 @@ def oracle(tables, money=np.float64) -> pa.Table:
     out = j[["c_customer_id"]].sort_values("c_customer_id")[:100]
     return pa.table({"c_customer_id":
                      pa.array(out["c_customer_id"].tolist(), pa.string())})
+
+
+def fold_work(tables) -> list:
+    """[(input rows, groups)] of the SQL's two aggregations: the year's
+    returns by (customer, store), and those totals by store (the
+    average).  The inner join to `date_dim` is the pair's key range: the
+    year's days are contiguous keys."""
+    f = year_returns(tables)
+    ctr = f.group_by(["sr_customer_sk", "sr_store_sk"],
+                     use_threads=False).aggregate([])
+    return [(f.num_rows, ctr.num_rows),
+            (ctr.num_rows, group_count(ctr, ["sr_store_sk"]))]

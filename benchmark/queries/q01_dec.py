@@ -25,6 +25,7 @@ import pyarrow as pa
 from benchmark.queries.ir import (Ids, agg, binop, c, ci, exchange,
                                   filter_, join, partial_final, project,
                                   scan, sort_limit)
+from benchmark.queries.q01 import fold_work  # noqa: F401  q01's two
 
 TABLES = ["store_returns", "date_dim", "store", "customer"]
 FACT = "store_returns"

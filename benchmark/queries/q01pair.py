@@ -25,8 +25,9 @@ FACT = "store_returns"
 KEYS = ["ctr_customer_sk", "ctr_store_sk"]
 ORDERED = False
 # least bytes the fold has to move for one input row: two int64 keys, one
-# float64 value, one selection byte; and for one table slot: the same
-# keys, one float64 accumulator, one used flag
+# float64 value, one selection byte; and for one group's slot: the same
+# keys, one float64 accumulator, one used flag (the least a slot needs, not
+# what the program charges: 29 B since PR 47)
 FOLD_ROW_BYTES = 8 + 8 + 8 + 1
 FOLD_SLOT_BYTES = 8 + 8 + 8 + 1
 
@@ -127,3 +128,25 @@ def oracle(tables, money=np.float64) -> pa.Table:
     return pa.table({KEYS[0]: out["sr_customer_sk"],
                      KEYS[1]: out["sr_store_sk"],
                      "ctr_total_return": total.cast(pa.float64())})
+
+
+def year_returns(tables) -> pa.Table:
+    """The year's returns, the three columns the aggregation reads."""
+    sr = tables["store_returns"].select(_PROJECTION)
+    lo, hi = _date_sk_range(tables["date_dim"])
+    return sr.filter(pc.and_(
+        pc.greater_equal(sr["sr_returned_date_sk"], lo),
+        pc.less_equal(sr["sr_returned_date_sk"], hi)))
+
+
+def group_count(t: pa.Table, keys) -> int:
+    """Groups of `keys` in `t`, a NULL key a group of its own (SQL)."""
+    return t.group_by(list(keys), use_threads=False).aggregate([]).num_rows
+
+
+def fold_work(tables) -> list:
+    """[(input rows, groups)] of every aggregation of the SQL, from the
+    tables alone: what `fold_roofline` prices, whatever the program does
+    to answer.  One here: the year's returns by (customer, store)."""
+    f = year_returns(tables)
+    return [(f.num_rows, group_count(f, ["sr_customer_sk", "sr_store_sk"]))]

@@ -65,3 +65,19 @@ def oracle(tables, money=np.float64) -> pa.Table:
            .sort_values("store"))
     return pa.table({"store": out["store"].to_numpy().astype(np.int64),
                      "cnt": out["cnt"].to_numpy().astype(np.int64)})
+
+
+def fold_work(tables) -> list:
+    """[(input rows, groups)] of the SQL's two aggregations: the average
+    price by category over `item`, and the count by store over the sales
+    whose item passed."""
+    it = tables["item"].select(
+        ["i_item_sk", "i_category", "i_current_price"]).to_pandas()
+    avg = it.groupby("i_category", dropna=False).i_current_price \
+        .transform("mean")
+    sel = it[it.i_current_price > 1.2 * avg]
+    ss = tables["store_sales"].select(["ss_item_sk", "ss_store_sk"]) \
+        .to_pandas()
+    kept = ss[ss.ss_item_sk.isin(sel.i_item_sk)]
+    return [(len(it), it.i_category.nunique(dropna=False)),
+            (len(kept), kept.ss_store_sk.nunique(dropna=False))]

@@ -229,3 +229,19 @@ def oracle(tables, money=np.float64) -> pa.Table:
 def full_oracle(tables, money=np.float64) -> pa.Table:
     """What `plan_full` has to give: compared as a set, by (item, date)."""
     return _table(full_answer(tables, money))
+
+
+def fold_work(tables) -> list:
+    """[(input rows, groups)] of the SQL's two aggregations, one a WITH
+    view: the year's sales with an item, by (item, date)."""
+    dd = tables["date_dim"].select(["d_date_sk", "d_month_seq"]).to_pandas()
+    days = dd[(dd.d_month_seq >= DMS) & (dd.d_month_seq <= DMS + 11)] \
+        .d_date_sk
+    work = []
+    for side in ("web", "store"):
+        fact, date_col, item_col, _price = SIDES[side]
+        f = tables[fact].select([date_col, item_col]).to_pandas()
+        f = f[f[item_col].notna() & f[date_col].isin(days)]
+        # a day is one `d_date_sk`: groups by (item, date key)
+        work.append((len(f), len(f.drop_duplicates())))
+    return work

@@ -82,11 +82,12 @@ ORDERED = True
 # spark_grouping_id (a bit a dropped key, the last key the lowest bit)
 LEVELS = [(kept, (1 << (len(KEYS) - kept)) - 1)
           for kept in range(len(KEYS), -1, -1)]
-# what `kernel_costs.fold_min_bytes` prices a folded (expanded) row at:
-# five int32 codes, three int32 calendar keys, the int64 grouping id, the
-# float64 product and a selection byte; a slot holds the nine keys, the
-# sum, a validity byte and the owner lane
-FOLD_ROW_BYTES = 5 * 4 + 3 * 4 + 8 + 8 + 1
+# what `kernel_costs.fold_min_bytes` prices an input row of the rollup at
+# (a joined sale, `fold_work`): five int32 codes, three int32 calendar
+# keys, the float64 product and a selection byte (no grouping id: that is
+# the Expand's, a rolled-up group has one and a sale has none); a group's
+# slot holds the nine keys, the sum, a validity byte and the owner lane
+FOLD_ROW_BYTES = 5 * 4 + 3 * 4 + 8 + 1
 FOLD_SLOT_BYTES = 5 * 4 + 3 * 4 + 8 + 8 + 1 + 4
 # the table's 32-bit lanes a probe round touches a row: the ten key lanes
 # (the int64 grouping id is two) and the owner lane; and what the row
@@ -302,3 +303,15 @@ def near_ties(full: pa.Table, rel_tol: float):
     back = np.empty(len(s), dtype=np.int64)
     back[order] = np.arange(len(s))
     return near[back], low[back], high[back]
+
+
+def fold_work(tables) -> list:
+    """[(input rows, groups)] of the SQL's one aggregation, the rollup:
+    the year's joined sales ONCE (an Expand that hands them on nine times
+    is the program's way, not the query's work) and every rolled-up group
+    of the nine levels."""
+    sales = _sales(tables, np.float64).select(KEYS)
+    groups = sum(
+        sales.group_by(KEYS[:kept], use_threads=False).aggregate([])
+        .num_rows if kept else 1 for kept, _gid in LEVELS)
+    return [(sales.num_rows, groups)]

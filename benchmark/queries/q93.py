@@ -162,3 +162,15 @@ def oracle(tables, money=np.float64) -> pa.Table:
 def full_oracle(tables, money=np.float64) -> pa.Table:
     """What `plan_full` has to give: compared as a set, by customer."""
     return _table(full_answer(tables, money))
+
+
+def fold_work(tables) -> list:
+    """[(input rows, groups)] of the SQL's one aggregation: the line items
+    returned for the reason, by customer."""
+    sr = tables["store_returns"].select(SR_COLUMNS).to_pandas()
+    re = tables["reason"].to_pandas()
+    sr = sr[sr.sr_reason_sk.isin(re[re.r_reason_desc == REASON].r_reason_sk)]
+    ss = tables["store_sales"].select(SS_COLUMNS[:3]).to_pandas()
+    m = ss.merge(sr, left_on=["ss_item_sk", "ss_ticket_number"],
+                 right_on=["sr_item_sk", "sr_ticket_number"])
+    return [(len(m), m.ss_customer_sk.nunique(dropna=False))]

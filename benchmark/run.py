@@ -6,10 +6,14 @@
 One process, one chip per cell today.  It finds the cell in BENCHMARK.json,
 makes the cell's tables (values from the configuration's `data_seed`, row
 order from --seed), writes them as parquet, builds the
-plan, computes the oracle's answer, warms the plan up until a pass neither
+plan, computes the oracle's answer and what the query's aggregations have
+to fold (`fold_work`: both from the tables alone, neither counted in
+`setup_s`), warms the plan up until a pass neither
 compiles nor loads a program, then measures a closed loop of one client for
 --seconds.  `correct` is decided after the window, on every answer the
-window produced.  The last line of stdout is the result.
+window produced.  The last line of stdout is the result; its last key,
+`compared`, and the last lines of stderr hold every number compared beside
+its limit.
 
 --trace 0 reports the cell's end-to-end metrics.  --trace 1 measures a
 shorter window (the traffic file's `trace_seconds`, or one query if that is
@@ -113,9 +117,9 @@ class Run:
     # ---- set-up ----------------------------------------------------------
     def make_data(self) -> None:
         cfg = self.cell.config
+        t0 = time.perf_counter()
         gen = self.cell.module("data", cfg["generator"])
         self.query = self.cell.module("queries", self.cell.traffic["query"])
-        t0 = time.perf_counter()
         self.tables = gen.make_tables(self.query.TABLES, cfg["scale"],
                                       cfg["data_seed"], cfg["splits"],
                                       self.seed)
@@ -140,9 +144,15 @@ class Run:
         self.fact_rows = self.tables[self.query.FACT].num_rows
 
     def make_oracle(self) -> None:
+        """The oracle's answer, and what the query's aggregations have to
+        fold: both from the tables alone, on the host, off `setup_s`."""
         t0 = time.perf_counter()
         self.want = self.query.oracle(self.tables)
         self.oracle_wall_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        fold_work = getattr(self.query, "fold_work", None)
+        self.fold_work = fold_work(self.tables) if fold_work else None
+        self.fold_work_s = time.perf_counter() - t0
 
     def load_program(self) -> None:
         """Native libraries, the program's imports, placement."""
@@ -235,8 +245,9 @@ class Run:
 
         return run_window(run_one, seconds, expected_s), starts
 
-    def verdicts(self) -> int:
-        """Compares every answer of the window; returns how many differ."""
+    def verdicts(self):
+        """Compares every answer of the window; returns how many differ
+        and the worst reading of each number compared."""
         failed = 0
         worst = {}
         for got in self.results:
@@ -247,7 +258,7 @@ class Run:
         _ok, line = check.verdict(worst)
         say(f"answers compared {len(self.results)}, differing {failed}; "
             f"worst of each number: {line}")
-        return failed
+        return failed, worst
 
 
 def traced_window(run: Run, seconds: float, expected_s: float, trace_dir: str):
@@ -327,13 +338,16 @@ def drive(cell, seed: int, seconds: float, trace: int, devices, peaks: dict,
             say(f"NOT CORRECT before the window: {e}")
             expected_s = None
         loaded_in_setup = events.cache_hits
-        setup_s = time.perf_counter() - t_process - run.oracle_wall_s
+        setup_s = time.perf_counter() - t_process - run.oracle_wall_s \
+            - run.fold_work_s
         say(json.dumps({"setup_parts": dict(
-            run.parts, oracle_wall_s=run.oracle_wall_s, setup_s=setup_s,
+            run.parts, oracle_wall_s=run.oracle_wall_s,
+            fold_work_s=run.fold_work_s, setup_s=setup_s,
             programs_requested=events.requested,
             programs_loaded=loaded_in_setup)}))
 
-        walls, read_ctx, failed, compiles_in_window = [], {}, 1, None
+        walls, read_ctx, failed, worst = [], {}, 1, {}
+        compiles_in_window = None
         if expected_s is not None:
             asked_before = events.requested
             if trace:
@@ -344,7 +358,7 @@ def drive(cell, seed: int, seconds: float, trace: int, devices, peaks: dict,
                 walls, _ = run.window(seconds, expected_s)
             compiles_in_window = events.requested - asked_before
             say("query walls: " + " ".join(f"{w:.4f}" for w in walls))
-            failed = run.verdicts()
+            failed, worst = run.verdicts()
             say(f"programs compiled or loaded inside the window: "
                 f"{compiles_in_window} (limit 0)")
         correct = bool(walls) and failed == 0 and compiles_in_window == 0
@@ -367,13 +381,14 @@ def drive(cell, seed: int, seconds: float, trace: int, devices, peaks: dict,
         else:
             ctx = dict(read_ctx, queries=len(walls), query=run.query,
                        fact_rows=run.fact_rows, peaks=peaks,
-                       table_slots=cell.config["agg_table_slots"],
-                       harness={
-                           "query_wall_max_s": max(walls, default=None),
-                           "oracle_wall_s": run.oracle_wall_s,
-                           "programs_loaded": loaded_in_setup,
-                           "compiles_in_window": compiles_in_window,
-                           "peak_hbm_mb": peak_bytes / 1e6})
+                       fold_work=run.fold_work,
+                       harness=dict(
+                           run.parts,  # set-up by part: `setup_*_s`
+                           query_wall_max_s=max(walls, default=None),
+                           oracle_wall_s=run.oracle_wall_s,
+                           programs_loaded=loaded_in_setup,
+                           compiles_in_window=compiles_in_window,
+                           peak_hbm_mb=peak_bytes / 1e6))
             result["metrics"] = {}
             for entry, spec in cell.layer_metrics() if walls else []:
                 value = cell.module("sources", spec["source"]).read(spec, ctx)
@@ -387,6 +402,17 @@ def drive(cell, seed: int, seconds: float, trace: int, devices, peaks: dict,
                 device["window_s"] = summary["window_s"]
                 result["breakdown"] = device_trace.breakdown(summary)
         result["device"] = device
+        # every number compared beside its limit: the result's last key,
+        # and the last lines of standard error
+        compared = {name: {"value": worst.get(name), "limit": limit}
+                    for name, limit in check.LIMITS.items()}
+        compared["compiles_in_window"] = {"value": compiles_in_window,
+                                          "limit": 0}
+        result["compared"] = compared
+        for name, c in compared.items():
+            sys.stderr.write(f"compared {name}={c['value']!r} "
+                             f"(limit {c['limit']!r})\n")
+        sys.stderr.flush()
     finally:
         shutil.rmtree(os.path.join(work_dir, "tables"), ignore_errors=True)
     return result

@@ -14,9 +14,16 @@ difference brings the spans over.
 
 spec["read"]:
   {"stat": "idle_share"}                        100 * (1 - busy / window)
+  {"stat": "idle_share", "less": [categories]}  the same with those idle
+                                                gaps out of both terms
   {"stat": "program_seconds", "pattern": regex, "den": "queries" | null}
   {"stat": "gap_seconds", "categories": [...], "den": "queries" | null}
-  {"stat": "fold_roofline", "pattern": regex}   least bytes / time / peak
+  {"stat": "fold_roofline", "pattern": regex}   least bytes / time / peak:
+      kernel_costs.fold_min_bytes of the query file's `fold_work(tables)`
+      (ctx["fold_work"], computed by the harness beside the oracle: the
+      SQL's aggregations, never a counter of the program) times the
+      queries of the window, over the matching programs' device time
+      SUMMED over the chips, over one chip's HBM peak
 """
 
 from __future__ import annotations
@@ -151,7 +158,8 @@ def read(spec: dict, ctx: dict):
     r = spec["read"]
     per = ctx["queries"] if r.get("den") == "queries" else 1
     if r["stat"] == "idle_share":
-        return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
+        less = sum(t["gaps"].get(c, 0.0) for c in r.get("less", []))
+        return 100.0 * (1.0 - t["busy_s"] / (t["window_s"] - less))
     if r["stat"] == "gap_seconds":
         return sum(t["gaps"].get(c, 0.0) for c in r["categories"]) / per
     pat = re.compile(r["pattern"])
@@ -161,12 +169,13 @@ def read(spec: dict, ctx: dict):
     if r["stat"] == "program_seconds":
         return secs / per
     if r["stat"] == "fold_roofline":
-        q, c = ctx["query"], ctx["counters"]
-        rows, tasks = c.get("stage_loop_rows", 0), c.get("stage_loop_tasks", 0)
-        if not rows:
+        q, work = ctx["query"], ctx.get("fold_work")
+        if not work:
             return None
-        least = kernel_costs.fold_min_bytes(
-            rows, q.FOLD_ROW_BYTES, ctx["table_slots"], q.FOLD_SLOT_BYTES,
-            tasks)
-        return 100.0 * least / secs / ctx["peaks"]["hbm_bytes_per_s"]
+        least = ctx["queries"] * kernel_costs.fold_min_bytes(
+            work, q.FOLD_ROW_BYTES, q.FOLD_SLOT_BYTES)
+        # `programs` holds the mean over the chips: the sum is what the
+        # chips spent, each on its share of the bytes
+        return 100.0 * least / (secs * t["devices"]) \
+            / ctx["peaks"]["hbm_bytes_per_s"]
     raise ValueError(f"unknown device_trace stat {r['stat']!r}")

@@ -9,8 +9,6 @@ from __future__ import annotations
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import pyarrow as pa  # noqa: E402
 import pyarrow.compute as pc  # noqa: E402
 import pytest  # noqa: E402
@@ -57,19 +55,21 @@ def test_controls_come_out_not_correct(tmp_path, cell_name, seed):
 
 def _alter(table: pa.Table) -> pa.Table:
     """One answer altered where it is produced: the last column's first
-    value, by one unit in its type."""
+    value, by one unit in its type (a float by a millionth part, or to a
+    millionth where it is 0.0, as q93's first sums are)."""
     i = table.num_columns - 1
     col = table.column(i).combine_chunks()
     first = col[0].as_py()
     bumped = (first + "x" if isinstance(first, str)
-              else first * (1 + 1e-6) if isinstance(first, float)
+              else (first * (1 + 1e-6) or 1e-6) if isinstance(first, float)
               else first + 1)
     new = pa.concat_arrays([pa.array([bumped], col.type), col.slice(1)])
     return table.set_column(i, table.schema.field(i), new)
 
 
 @pytest.mark.parametrize("cell_name", REHEARSED)
-def test_a_broken_timed_path_is_not_correct(tmp_path, cell_name, monkeypatch):
+def test_a_broken_timed_path_is_not_correct(tmp_path, cell_name, monkeypatch,
+                                            device_path):
     root = tiny_root(tmp_path)
     cell = Cell(cell_name, root)
     from benchmark import manifest
@@ -94,13 +94,16 @@ def test_a_broken_timed_path_is_not_correct(tmp_path, cell_name, monkeypatch):
         return mod
 
     monkeypatch.setattr(manifest.Cell, "module", module)
-    res = drive(root, cell_name, trace=0, seconds=0.2)
+    res = drive(root, cell_name, device_path, trace=0, seconds=0.2)
     assert res["correct"] is False
     assert res["failed"] >= 1 and res["attempted"] >= res["failed"]
+    exceeded = [name for name, c in res["compared"].items()
+                if c["value"] > c["limit"]]
+    assert exceeded and "compiles_in_window" not in exceeded
 
 
 def test_a_wrong_warm_up_answer_stops_the_run_before_any_timing(
-        tmp_path, monkeypatch):
+        tmp_path, monkeypatch, device_path):
     root = tiny_root(tmp_path)
     from benchmark import manifest
     real = manifest.Cell.module
@@ -113,6 +116,6 @@ def test_a_wrong_warm_up_answer_stops_the_run_before_any_timing(
         return mod
 
     monkeypatch.setattr(manifest.Cell, "module", module)
-    res = drive(root, REHEARSED[1], trace=0, seconds=0.2)
+    res = drive(root, REHEARSED[1], device_path, trace=0, seconds=0.2)
     assert res["correct"] is False
     assert "query_wall_s" not in res["metrics"]

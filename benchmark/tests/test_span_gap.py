@@ -216,8 +216,9 @@ def test_the_new_metrics_are_in_the_manifest_with_their_cells():
         m = entries[name]
         assert (m["unit"], m["better"], m["moves"]) == \
             ("s", "lower", "query_wall_s")
-    assert entries["idle_join_host_s"]["workloads"] == ["sf1_q06_x1"]
-    assert [m["name"] for m in manifest["per_layer"]][-10:] == [
-        "h2d_s", "scan_decode_s", "prefetch_wait_s", "d2h_wait_s",
-        "idle_prefetch_wait_s", "idle_h2d_s", "idle_d2h_s",
-        "idle_agg_drain_s", "idle_join_host_s", "idle_task_other_s"]
+    # the two q06 cells since PR 50 merged the `x4_` twin into the entry
+    assert entries["idle_join_host_s"]["workloads"] == ["sf1_q06_x1",
+                                                        "sf1_q06_x4"]
+    # over the mesh a map task's table is drained on the device: no
+    # `agg_drain` span, so the four-chip cell is not in this one's list
+    assert "sf1_q06_x4" not in entries["idle_agg_drain_s"]["workloads"]
