@@ -78,8 +78,9 @@ def _intervals(spans: List[dict]) -> List[Tuple[int, int, int]]:
             t1 = int(r.get("t1_ns", t0))
         except (TypeError, ValueError):
             continue
-        if name == "xla_compile":
-            # compile instants carry their duration in attrs["ns"]
+        if name == "xla_compile" and t1 <= t0:
+            # a recorded instant (before the program ledger made it an
+            # interval) carries its duration in attrs["ns"]
             try:
                 t1 = t0 + max(0, int((r.get("attrs") or {}).get("ns", 0)))
             except (TypeError, ValueError):

@@ -446,7 +446,8 @@ def device_ledger(spans: List[dict]) -> Dict[str, Any]:
                 continue
             s0 = _t0(r)
             dur = _ns(r.get("dur_ns", 0)) or 0
-            if name == "xla_compile":  # instant carrying its wall in ns
+            if name == "xla_compile" and not dur:  # an instant of old:
+                # its wall rides attrs["ns"]
                 attrs = (r.get("attrs")
                          if isinstance(r.get("attrs"), dict) else {})
                 dur = _ns(attrs.get("ns", 0)) or 0

@@ -69,6 +69,7 @@ _annotation = None  # jax.profiler.TraceAnnotation, imported on first use
 _child_mode = False
 _child_buf: List[dict] = []
 _CHILD_BUF_CAP = 10_000
+_child_dropped = 0  # trimmed from `_child_buf`, not yet sent home
 
 #: Registry of every span/instant name the runtime emits, with the
 #: one-line doc rendered into docs/observability.md.  A trailing `*`
@@ -171,7 +172,10 @@ SPAN_NAMES: Dict[str, str] = {
                      "attrs lane, rows, functions, partitions: the "
                      "sorted runs covered, 1, not SQL partitions)",
     "agg_drain": "an aggregation table read back and turned into an "
-                 "Arrow batch (plan/fused.py _emit_*; attrs table)",
+                 "Arrow batch (plan/fused.py _emit_*; attrs table), or a "
+                 "`mode=loop` map task's table drained into the device "
+                 "exchange's columns where it lies (plan/stages.py, "
+                 "table=loop)",
     "partial_passthrough": "one chunk of a partial aggregation that "
                            "stopped grouping: the chain as one program, "
                            "the live rows read back in accumulator form "
@@ -214,13 +218,24 @@ SPAN_NAMES: Dict[str, str] = {
                 "waits for the interpreter lock meanwhile (bridge/"
                 "tracing.py, a gc.callbacks entry while tracing is on; "
                 "attrs generation, collected)",
+    "xla_compile": "one phase of one program JAX was asked for, a real "
+                   "interval on the dispatching thread: phase=trace (to a "
+                   "jaxpr), lower (to a module) or backend (compiled, or "
+                   "looked up and loaded from the persistent cache: "
+                   "cache_hit).  Emitted from JAX's own monitoring events, "
+                   "so the eager one-operation programs are here too; a "
+                   "`jit` traced inside a `jit` nests inside its parent's "
+                   "trace (bridge/xla_stats.py _on_program_phase, the "
+                   "program ledger's record as a span; attrs program: the "
+                   "name the device trace prints, phase, site: "
+                   "module:function:line of the caller in blaze_tpu/, ns, "
+                   "source=backend, cache_hit on a backend phase, kernel "
+                   "where the program is a metered one)",
     # -- instants (dur_ns == 0) ---------------------------------------
     "task_retry": "a failed attempt was classified retryable and will "
                   "back off and retry (bridge/tasks.py)",
     "fault_injected": "a seeded chaos fault fired at a registered site "
                       "(faults.py)",
-    "xla_compile": "an XLA kernel compiled (cache miss) with wall ns "
-                   "(bridge/xla_stats.py meter_jit)",
     "device_shuffle_fallback": "device collective exchange declined or "
                                "failed; stage fell back a tier "
                                "(plan/stages.py)",
@@ -298,6 +313,7 @@ def _probe_conf() -> None:
     try:
         from blaze_tpu import config
         if config.TRACE_ENABLE.get():
+            _watch_gc(True)
             _enabled = True
     except Exception:
         pass
@@ -478,10 +494,14 @@ def _report_dropped() -> None:
 
 
 def _emit(record: dict) -> None:
+    global _child_dropped
     with _lock:
         if _child_mode:
             _child_buf.append(record)
-            del _child_buf[:-_CHILD_BUF_CAP]
+            over = len(_child_buf) - _CHILD_BUF_CAP
+            if over > 0:
+                del _child_buf[:over]
+                _child_dropped += over
             return
         _spans.append(record)
         _trim()
@@ -497,6 +517,18 @@ def dropped() -> int:
     it (counter `obs_spans_dropped` sums it over the process's life)."""
     with _lock:
         return _dropped
+
+
+def _watch_gc(on: bool) -> None:
+    """Install or take out the `gc.callbacks` entry (`gc_pause` spans):
+    in while tracing is on, however it was switched on."""
+    with _lock:
+        if on and _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+        elif not on:
+            if _on_gc in gc.callbacks:
+                gc.callbacks.remove(_on_gc)
+            _gc_t0.clear()
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -576,13 +608,28 @@ def take_buffered() -> List[dict]:
     return out
 
 
+def take_child_dropped() -> int:
+    """Spans the child-mode buffer's cap trimmed since the last frame:
+    rides the frame beside `take_buffered()`'s spans, and the parent's
+    `ingest()` adds it to `obs_spans_dropped`."""
+    global _child_dropped
+    with _lock:
+        n, _child_dropped = _child_dropped, 0
+    return n
+
+
 def ingest(records: Optional[List[dict]], worker=None,
-           clock_ns: Optional[int] = None) -> int:
+           clock_ns: Optional[int] = None, dropped: int = 0) -> int:
     """Parent side: stitch spans shipped back from a worker child into
     the process trace.  `worker` tags the originating slot; `clock_ns`
     is the child's perf_counter_ns at frame-send time, used to rebase
     the child's clock origin onto ours (transit latency is absorbed
-    into the offset — fine at heartbeat granularity)."""
+    into the offset — fine at heartbeat granularity); `dropped`: spans
+    the child's buffer trimmed before the frame left, counted as the
+    parent's own trims are."""
+    if dropped:
+        from blaze_tpu.bridge import xla_stats
+        xla_stats.note_obs(spans_dropped=int(dropped))
     if not records or not _enabled:
         return 0
     offset = 0
@@ -634,8 +681,7 @@ def start_tracing(path: Optional[str] = None) -> None:
         if path:
             _sink = open(path, "w")
         _conf_probed = True
-        if _on_gc not in gc.callbacks:
-            gc.callbacks.append(_on_gc)
+        _watch_gc(True)
     _enabled = True
 
 
@@ -644,9 +690,7 @@ def stop_tracing() -> List[dict]:
     global _enabled, _sink
     _enabled = False
     with _lock:
-        if _on_gc in gc.callbacks:
-            gc.callbacks.remove(_on_gc)
-        _gc_t0.clear()
+        _watch_gc(False)
         if _sink is not None:
             _sink.close()
             _sink = None
@@ -655,12 +699,14 @@ def stop_tracing() -> List[dict]:
 
 def reset_conf_probe() -> None:
     """Forget the lazy auron.tpu.trace.enable probe (tests)."""
-    global _conf_probed, _enabled, _child_mode
+    global _conf_probed, _enabled, _child_mode, _child_dropped
     with _lock:
         _conf_probed = False
         _enabled = False
         _child_mode = False
         del _child_buf[:]
+        _child_dropped = 0
+        _watch_gc(False)
 
 
 def spans() -> List[dict]:

@@ -23,10 +23,13 @@ call; a cache hit never re-enters it.
 from __future__ import annotations
 
 import functools
+import os
 import re
+import sys
+import sysconfig
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 _lock = threading.Lock()
 
@@ -342,12 +345,42 @@ HISTOGRAM_BUCKETS_S = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
 # What JAX itself asked its backend for (jax.monitoring events): every
 # program, the eager glue ones included, that `meter_jit` never sees.
 # backend_compiles counts compile requests (a persistent-cache hit is
-# also a request, answered by compile_cache_hits).
+# also a request, answered by compile_cache_hits).  `program_loads_trimmed`
+# counts the records the program ledger's cap has dropped
+# (`_on_program_phase`); the ledger's totals are `program_load_summary()`'s,
+# computed from the records, and have no counter beside them.
 _backend = {"backend_compiles": 0, "backend_compile_ns": 0,
-            "compile_cache_hits": 0}
+            "compile_cache_hits": 0, "program_loads_trimmed": 0}
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# the three phases JAX times for a program it is asked for, each with the
+# function's name: tracing to a jaxpr, lowering to a module, and
+# `compile_or_get_cached` (on a warm cache the look-up and the
+# deserialisation)
+_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           _BACKEND_COMPILE_EVENT: "backend"}
 _backend_listening = False
+
+# The program ledger: one record a phase of a request, for the life of
+# the process, stamped on `time.perf_counter_ns` (the span tracer's clock,
+# which the benchmark brings onto the device trace's).  Written only from
+# JAX's monitoring callbacks, which run on the dispatching thread when a
+# program is requested and never otherwise.  Oldest trimmed over the cap,
+# counted in `program_loads_trimmed`.
+_program_loads: List[Dict[str, Any]] = []
+_PROGRAM_LOADS_CAP = 32768
+_requesting = threading.local()  # .open: phases entered and not yet left;
+                                 # .cache: what the persistent cache said
+                                 # inside the open backend phase
+_PKG_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__))) \
+    + os.sep
+_SITE_SKIP = (os.path.abspath(__file__),
+              os.path.join(_PKG_DIR, "xputil.py"))
+_LIBRARY_DIRS = tuple(sorted({
+    os.path.join(p, "") for k, p in sysconfig.get_paths().items()
+    if k in ("stdlib", "platstdlib", "purelib", "platlib")}))
 
 # Distinct signatures beyond this on one kernel = shape churn (the
 # recompilation-storm smell: unpadded dynamic shapes hitting jit).
@@ -392,36 +425,197 @@ def program_name(fun_name: str, kernel: str) -> str:
             f"__{_NOT_WORD.sub('_', kernel)}")
 
 
-def _on_backend_compile(event: str, secs: float, **_kw) -> None:
-    if event != _BACKEND_COMPILE_EVENT:
+_WRAPPED = re.compile(r"^\w+\(.*\)$")
+_MODULE_NAME = re.compile(r"[^\w.-]")
+
+
+def loaded_program_name(fun_name: str) -> str:
+    """The name the device trace prints for a program JAX's monitoring
+    events call `fun_name`: `_take` (the trace event) and `jit(_take)`
+    (the other two) are both `jit__take`, as JAX names the module
+    (`mlir.sanitize_name`, trailing `_` stripped)."""
+    if not _WRAPPED.match(fun_name):
+        fun_name = f"jit({fun_name})"
+    return _MODULE_NAME.sub("_", fun_name).rstrip("_")
+
+
+def program_kind(name: str) -> Tuple[str, Optional[str]]:
+    """("metered", kernel) for a program named by `program_name`
+    (`jit_<function>__<kernel>`), ("eager", None) for one-operation glue
+    (`jit__take`: the function is `_take`, no `__` follows it)."""
+    body = name.partition("_")[2]
+    i = body.find("__", 1)
+    return ("metered", body[i + 2:]) if i > 0 else ("eager", None)
+
+
+def _call_site(frame) -> str:
+    """`module:function:line` of the innermost frame from `frame` outward
+    that lies in `blaze_tpu/` and is neither this file nor `xputil.py`;
+    where the program's code is not on the stack, of the innermost frame
+    outside the interpreter's and the installed packages' directories."""
+    outside = None
+    while frame is not None:
+        code = frame.f_code
+        path = code.co_filename
+        if path.startswith(_PKG_DIR):
+            if path not in _SITE_SKIP:
+                return (f"{path[len(_PKG_DIR):]}:{code.co_name}:"
+                        f"{frame.f_lineno}")
+        elif outside is None and not path.startswith("<") \
+                and not path.startswith(_LIBRARY_DIRS):
+            outside = (f"{os.path.basename(path)}:{code.co_name}:"
+                       f"{frame.f_lineno}")
+        frame = frame.f_back
+    return outside or "?"
+
+
+def _note_cache_answer(**what) -> None:
+    """What the persistent cache said inside this thread's open backend
+    phase, kept for the phase's record."""
+    cache = getattr(_requesting, "cache", None)
+    if cache is None:
+        cache = _requesting.cache = {}
+    cache.update(what)
+
+
+def _on_program_enter(event: str, _value: float, **_kw) -> None:
+    """`record_scalar` at a phase's entry: how deep this thread's
+    requests nest."""
+    if event in _PHASES:
+        try:
+            _requesting.open.append(event)
+        except AttributeError:
+            _requesting.open = [event]
+
+
+def _on_program_phase(event: str, secs: float, **kw) -> None:
+    """A phase of a request ended on this thread: one ledger record and,
+    while tracing is on, one `xla_compile` span."""
+    phase = _PHASES.get(event)
+    if phase is None:
+        if event == _CACHE_RETRIEVAL_EVENT:
+            _note_cache_answer(retrieval_ns=int(secs * 1e9))
         return
+    t1 = time.perf_counter_ns()
     ns = int(secs * 1e9)
+    still_open = getattr(_requesting, "open", None)
+    if still_open:
+        # an entry without its end (a listener registered mid-phase) sits
+        # above ours: drop it with ours
+        while still_open and still_open.pop() != event:
+            pass
+    depth = len(still_open) if still_open else 0
+    name = loaded_program_name(str(kw.get("fun_name", "")))
+    kind, kernel = program_kind(name)
+    record = {"program": name, "phase": phase, "t0_ns": t1 - ns, "t1_ns": t1,
+              "tid": threading.get_ident(), "depth": depth, "kind": kind,
+              "site": _call_site(sys._getframe(1))}
+    if phase == "backend":
+        cache = getattr(_requesting, "cache", None) or {}
+        _requesting.cache = None
+        record["cache_hit"] = bool(cache.get("hit"))
+        if cache.get("hit"):
+            record["retrieval_ns"] = cache.get("retrieval_ns", 0)
     with _lock:
-        _backend["backend_compiles"] += 1
-        _backend["backend_compile_ns"] += ns
+        if phase == "backend":
+            _backend["backend_compiles"] += 1
+            _backend["backend_compile_ns"] += ns
+        _program_loads.append(record)
+        over = len(_program_loads) - _PROGRAM_LOADS_CAP
+        if over > 0:
+            del _program_loads[:over]
+            _backend["program_loads_trimmed"] += over
     from blaze_tpu.bridge import tracing
-    tracing.instant("xla_compile", ns=ns, source="backend")
+    if tracing.enabled():
+        attrs = {"program": name, "phase": phase, "site": record["site"],
+                 "ns": ns, "source": "backend"}
+        if phase == "backend":
+            attrs["cache_hit"] = record["cache_hit"]
+        if kernel is not None:
+            attrs["kernel"] = kernel
+        tracing.emit_span("xla_compile", ns, **attrs)
 
 
-def _on_cache_hit(event: str, **_kw) -> None:
+def _on_cache_event(event: str, **_kw) -> None:
     if event == _CACHE_HIT_EVENT:
         with _lock:
             _backend["compile_cache_hits"] += 1
+        _note_cache_answer(hit=True)
 
 
 def listen_backend_compiles() -> None:
-    """Register the two jax.monitoring listeners once per process
-    (called from `import blaze_tpu`; listeners cannot be removed, so
-    `reset()` zeroes the counters and leaves them registered)."""
+    """Register the three jax.monitoring listeners once per process
+    (called from `import blaze_tpu`; `reset()` zeroes the counters and
+    the ledger and leaves them registered)."""
     global _backend_listening
     with _lock:
         if _backend_listening:
             return
         _backend_listening = True
     import jax
-    jax.monitoring.register_event_duration_secs_listener(
-        _on_backend_compile)
-    jax.monitoring.register_event_listener(_on_cache_hit)
+    jax.monitoring.register_scalar_listener(_on_program_enter)
+    jax.monitoring.register_event_duration_secs_listener(_on_program_phase)
+    jax.monitoring.register_event_listener(_on_cache_event)
+
+
+def program_loads(since_ns: Optional[int] = None,
+                  until_ns: Optional[int] = None) -> List[Dict[str, Any]]:
+    """The ledger's records (copies, oldest first) that ended after
+    `since_ns` and at or before `until_ns`, both on
+    `time.perf_counter_ns`: one a phase (`trace`, `lower`, `backend`) of
+    every program this process asked JAX for, with `program` (as the
+    device trace prints it), `t0_ns` / `t1_ns`, `tid`, `depth` (0: a
+    top-level request of its thread), `kind` (`metered` / `eager`), `site`
+    (`module:function:line` of the caller in `blaze_tpu/`) and, for a
+    backend phase, `cache_hit` with `retrieval_ns`."""
+    with _lock:
+        return [dict(r) for r in _program_loads
+                if (since_ns is None or r["t1_ns"] > since_ns)
+                and (until_ns is None or r["t1_ns"] <= until_ns)]
+
+
+def program_load_summary(until_ns: Optional[int] = None,
+                         since_ns: Optional[int] = None,
+                         top: int = 20) -> Dict[str, Any]:
+    """What the records of `program_loads(since_ns, until_ns)` add up to:
+    thread-seconds by phase and the persistent cache's retrieval seconds
+    (top-level requests only, so nothing is counted twice), `wall_s` the
+    union over threads and phases (seconds in which at least one thread
+    was getting a program), requests by kind and how many the cache
+    answered (every backend phase, nested or not), the `top` (program,
+    call site) pairs with most thread-seconds (`seconds`, and of them
+    `trace_s` / `lower_s` / `backend_s`; `requests`), and `trimmed`: records the
+    cap has dropped since the last `reset()` (above 0, the oldest are
+    missing here)."""
+    records = program_loads(since_ns, until_ns)
+    out: Dict[str, Any] = {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                           "cache_retrieval_s": 0.0, "requests_eager": 0,
+                           "requests_metered": 0, "cache_hits": 0}
+    pairs: Dict[tuple, Dict[str, Any]] = {}
+    intervals = []
+    for r in records:
+        if r["phase"] == "backend":
+            out[f"requests_{r['kind']}"] += 1
+            out["cache_hits"] += r["cache_hit"]
+        if r["depth"]:
+            continue
+        secs = (r["t1_ns"] - r["t0_ns"]) / 1e9
+        out[f"{r['phase']}_s"] += secs
+        out["cache_retrieval_s"] += r.get("retrieval_ns", 0) / 1e9
+        intervals.append((r["t0_ns"], r["t1_ns"]))
+        pair = pairs.setdefault((r["program"], r["site"]), {
+            "seconds": 0.0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+            "requests": 0})
+        pair["seconds"] += secs
+        pair[f"{r['phase']}_s"] += secs
+        pair["requests"] += r["phase"] == "backend"
+    from blaze_tpu.bridge.history import _merged_busy_ns
+    out["wall_s"] = _merged_busy_ns(intervals) / 1e9
+    out["top"] = [{"program": p, "site": s, **v} for (p, s), v in sorted(
+        pairs.items(), key=lambda kv: -kv[1]["seconds"])[:top]]
+    with _lock:
+        out["trimmed"] = _backend["program_loads_trimmed"]
+    return out
 
 
 def meter_jit(fun: Callable, *, name: Optional[str] = None,
@@ -473,9 +667,6 @@ def meter_jit(fun: Callable, *, name: Optional[str] = None,
                 entry["compile_ns"] += dt
             else:
                 entry["cache_hits"] += 1
-        if compiled:
-            from blaze_tpu.bridge import tracing
-            tracing.instant("xla_compile", kernel=kname, ns=dt)
         return out
 
     wrapper._blaze_metered_jit = kname  # introspection / tests
@@ -1469,6 +1660,7 @@ def reset() -> None:
             _fleet[k] = 0
         for k in _backend:
             _backend[k] = 0
+        _program_loads.clear()
         _fallback_errors.clear()
         _stage_loop_fallback_reasons.clear()
         _task_duration_ns.clear()

@@ -285,7 +285,8 @@ class WorkerPool:
                         # mid-task child spans stream back in heartbeat
                         # frames; rebase the child clock onto ours
                         tracing.ingest(msg["spans"], worker=slot.id,
-                                       clock_ns=msg.get("mono_ns"))
+                                       clock_ns=msg.get("mono_ns"),
+                                       dropped=msg.get("spans_dropped", 0))
                 else:
                     slot.last_heartbeat = time.monotonic()
                     inbox.put(msg)
@@ -659,7 +660,8 @@ class WorkerPool:
             # final child spans ride the result frame — including an
             # abandoned speculation loser's (the drainer lands here too)
             tracing.ingest(res["spans"], worker=slot.id,
-                           clock_ns=res.get("mono_ns"))
+                           clock_ns=res.get("mono_ns"),
+                           dropped=res.get("spans_dropped", 0))
         cpu_ns = res.get("cpu_ns")
         if cpu_ns:
             # actual worker-process CPU (user+sys from os.times in the
@@ -900,6 +902,16 @@ def _resolve_fn(spec: str) -> Callable:
     return fn
 
 
+def _ship_spans(frame: Dict[str, Any]) -> None:
+    """The child's buffered spans onto a heartbeat or result frame, with
+    the child's clock at send time and what the buffer's cap trimmed."""
+    frame["spans"] = tracing.take_buffered()
+    frame["mono_ns"] = time.perf_counter_ns()
+    dropped = tracing.take_child_dropped()
+    if dropped:
+        frame["spans_dropped"] = dropped
+
+
 def _run_child_task(msg: Dict[str, Any], out, out_lock) -> Dict[str, Any]:
     from blaze_tpu import config
     config.conf.replace(msg.get("conf") or {})
@@ -926,8 +938,7 @@ def _run_child_task(msg: Dict[str, Any], out, out_lock) -> Dict[str, Any]:
             beat: Dict[str, Any] = {"kind": "heartbeat"}
             if trace:
                 tracing.instant("worker_heartbeat", pid=os.getpid())
-                beat["spans"] = tracing.take_buffered()
-                beat["mono_ns"] = time.perf_counter_ns()
+                _ship_spans(beat)
             try:
                 _send_msg(out, beat, out_lock)
             except Exception:
@@ -971,8 +982,7 @@ def _run_child_task(msg: Dict[str, Any], out, out_lock) -> Dict[str, Any]:
         reply = {"kind": "result", "task_id": msg["task_id"], "ok": True,
                  "value": value, "cpu_ns": _cpu_ns()}
         if trace:
-            reply["spans"] = tracing.take_buffered()
-            reply["mono_ns"] = time.perf_counter_ns()
+            _ship_spans(reply)
         return reply
     except BaseException as e:
         if kill_timer is not None:
@@ -985,8 +995,7 @@ def _run_child_task(msg: Dict[str, Any], out, out_lock) -> Dict[str, Any]:
                  "classify": classify_exception(e), "fetch": fetch,
                  "cpu_ns": _cpu_ns()}
         if trace:
-            reply["spans"] = tracing.take_buffered()
-            reply["mono_ns"] = time.perf_counter_ns()
+            _ship_spans(reply)
         return reply
     finally:
         stop_beat.set()

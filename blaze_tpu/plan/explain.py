@@ -201,6 +201,10 @@ class QueryProfile:
     exec_mode: str
     xla: Dict[str, int] = field(default_factory=dict)
     kernels: Dict[str, dict] = field(default_factory=dict)
+    # programs JAX was asked for while the query ran, by (program, call
+    # site), the five with most seconds (xla_stats.program_load_summary);
+    # empty for a warm query
+    programs: List[dict] = field(default_factory=list)
     placement: str = ""
     output_rows: int = 0
     # critical-path category attribution (bridge/critical_path.py
@@ -221,6 +225,8 @@ class QueryProfile:
             "placement": self.placement,
             "output_rows": self.output_rows,
         }
+        if self.programs:
+            d["programs"] = [dict(p) for p in self.programs]
         if self.bottleneck is not None:
             d["bottleneck"] = self.bottleneck
         return d
@@ -235,7 +241,10 @@ class QueryProfile:
         lines.append(
             f"XLA: compiles={x.get('total_compiles', 0)} "
             f"cache_hits={x.get('total_cache_hits', 0)} "
-            f"compile_time={_fmt_ns(x.get('total_compile_ns', 0))}")
+            f"compile_time={_fmt_ns(x.get('total_compile_ns', 0))}"
+            + "".join(f" {p['program']}@{p['site']}"
+                      f"({_fmt_ns(int(p['seconds'] * 1e9))})"
+                      for p in self.programs))
         churny = [f"{k} ({v['distinct_signatures']} signatures)"
                   for k, v in sorted(self.kernels.items())
                   if v.get("shape_churn")]
@@ -518,6 +527,8 @@ def explain_analyze(plan: Union[Dict[str, Any], Any], *,
         query_id=qid, wall_ns=wall_ns, tree=tree, partitions=partitions,
         exec_mode=mode, xla=xla_stats.delta(xla_before),
         kernels=xla_stats.compile_report()["kernels"],
+        programs=xla_stats.program_load_summary(
+            since_ns=t0, until_ns=t0 + wall_ns, top=5)["top"],
         placement="host" if host_resident() else "device",
         output_rows=rows, bottleneck=bottleneck,
         result=table if keep_result else None)

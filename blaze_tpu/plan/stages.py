@@ -941,7 +941,8 @@ class DagScheduler:
                 # groups are drained into the exchange's columns
                 carry = device_loop.run_partition(
                     prog, m, ctx=str(stage.sid), table=table)
-                out = device_loop.drain_device(prog, carry)
+                with tracing.span("agg_drain", table="loop"):
+                    out = device_loop.drain_device(prog, carry)
         except (KeyboardInterrupt, SystemExit, FetchFailedError):
             raise
         except Exception as e:

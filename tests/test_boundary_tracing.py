@@ -285,13 +285,14 @@ def test_new_counters_reach_every_surface():
     MemManager.init(4 << 30)
     fams = xla_stats.counter_families()
     assert {"h2d_ns", "d2h_wait_ns"} <= set(fams["transfers"])
+    ledger = ("program_loads_trimmed",)
     assert set(fams["backend"]) == {"backend_compiles",
                                     "backend_compile_ns",
-                                    "compile_cache_hits"}
+                                    "compile_cache_hits", *ledger}
     snap = xla_stats.snapshot()
     text = profiling.prometheus_text()
     for k in ("h2d_ns", "d2h_wait_ns", "backend_compiles",
-              "backend_compile_ns", "compile_cache_hits"):
+              "backend_compile_ns", "compile_cache_hits", *ledger):
         assert k in snap
         assert f"blaze_{k}_total" in text
 
@@ -550,9 +551,15 @@ def test_backend_compiles_see_what_meter_jit_does_not(traced):
     (task,) = _named("task")
     mine = [s for s in _named("xla_compile")
             if s["attrs"].get("source") == "backend"]
-    assert len(mine) == d["backend_compiles"]
+    # one span a phase of a request; the backend's are the compiles
+    assert len([s for s in mine if s["attrs"]["phase"] == "backend"]) \
+        == d["backend_compiles"]
+    assert {s["attrs"]["phase"] for s in mine} == {"trace", "lower", "backend"}
     for s in mine:
-        assert s["attrs"]["ns"] > 0
+        assert s["attrs"]["ns"] == s["dur_ns"] > 0
+        assert s["attrs"]["site"].startswith(
+            "test_boundary_tracing.py:"
+            "test_backend_compiles_see_what_meter_jit_does_not:")
         assert s["ctx"] == {"query": "q-compile", "stage": 5}
         assert s["parent"] == task["sid"]
 

@@ -186,24 +186,29 @@ def test_meter_jit_flags_shape_churn():
     assert e["compiles"] == xla_stats.SHAPE_CHURN_THRESHOLD + 1
 
 
-def test_meter_jit_emits_compile_instants():
+def test_a_metered_compile_is_one_span_a_phase_and_meter_jit_adds_none():
     import jax.numpy as jnp
 
     xla_stats.reset()
     f = xla_stats.meter_jit(lambda x: x + 1, name="traced.kernel")
     tracing.start_tracing()
     try:
-        f(jnp.arange(4))   # compile -> instant
+        f(jnp.arange(4))   # compile -> one interval a phase
         f(jnp.arange(4))   # cache hit -> nothing
     finally:
         spans = tracing.stop_tracing()
     compiles = [s for s in spans if s["name"] == "xla_compile"]
     metered = [s for s in compiles if "kernel" in s["attrs"]]
-    assert len(metered) == 1
-    assert metered[0]["attrs"]["kernel"] == "traced.kernel"
-    # the rest come from JAX's own compile events (backend_compiles)
-    assert all(s["attrs"]["source"] == "backend"
-               for s in compiles if s not in metered)
+    assert [s["attrs"]["phase"] for s in metered] == \
+        ["trace", "lower", "backend"]
+    for s in metered:
+        assert s["attrs"]["kernel"] == "traced_kernel"
+        assert s["attrs"]["program"] == "jit__lambda__traced_kernel"
+        assert s["dur_ns"] > 0
+    # every one comes from JAX's own compile events, the glue's too
+    assert all(s["attrs"]["source"] == "backend" for s in compiles)
+    assert xla_stats.compile_report()["kernels"]["traced.kernel"][
+        "compiles"] == 1
 
 
 def test_transfer_accounting_from_batch_layer():

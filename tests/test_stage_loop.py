@@ -6,6 +6,7 @@ chunk of a cancellation with a clean leak report."""
 
 import logging
 import threading
+import time
 
 import numpy as np
 import pyarrow as pa
@@ -309,6 +310,7 @@ def test_cancel_noticed_at_chunk_boundary(tmp_path, loop_on):
 def test_cancelled_query_leaves_no_leaks(tmp_path, staged_path, loop_on):
     plan = _two_stage_plan(tmp_path, n=100_000, tag="leak")
     ctx = QueryContext("q-leak")
+    threads_before = set(threading.enumerate())
     timer = threading.Timer(0.05, ctx.cancel, args=("bored",))
     sched = DagScheduler(work_dir=str(tmp_path / "dag-leak"),
                          query_ctx=ctx)
@@ -320,6 +322,12 @@ def test_cancelled_query_leaves_no_leaks(tmp_path, staged_path, loop_on):
         timer.cancel()
     report = sched.leak_report()
     assert all(v == [] for v in report.values()), report
+    # an attempt that was mid-chunk when the token fired ends on its own
+    # time (`run_tasks` shuts its pool down without waiting): wait for it
+    # here, or what it counts lands in the next test's window
+    deadline = time.monotonic() + 20
+    for t in set(threading.enumerate()) - threads_before:
+        t.join(timeout=max(0.0, deadline - time.monotonic()))
 
 
 # -- table sizing: reserve before fold, in every mode (ISSUE 25) -------------

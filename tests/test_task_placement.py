@@ -177,6 +177,34 @@ def test_q06_on_four_devices_every_device_works_and_no_row_strays(
     assert by_name["device_exchange"] == {0}
 
 
+def test_a_loop_tasks_drain_runs_under_an_agg_drain_span(
+        on_devices, cases, tmp_path):
+    """`drain_device` of a `mode=loop` map task used to run under `task`
+    alone, so the chip's idle during it read as no operator's."""
+    on_devices(4)
+    query, paths, tables, _want = cases("q06")
+    tracing.start_tracing()
+    try:
+        _run("q06", query, paths, tables, tmp_path)
+    finally:
+        spans = tracing.stop_tracing()
+    loops = {s["sid"]: s for s in spans
+             if s["name"] == "task" and s["attrs"]["mode"] == "loop"}
+    drains = [s for s in spans if s["name"] == "agg_drain"
+              and s["attrs"]["table"] == "loop"]
+    assert len(loops) >= 4 and len(drains) == len(loops)
+    assert {s["parent"] for s in drains} == set(loops)
+    for s in drains:
+        task = loops[s["parent"]]
+        assert task["t0_ns"] <= s["t0_ns"] < s["t1_ns"] <= task["t1_ns"]
+        assert s["tid"] == task["tid"]
+    # and the family table gives the span to the aggregations
+    import json
+    with open(os.path.join(ROOT, "benchmark", "sources",
+                           "op_families.json")) as f:
+        assert "agg_drain" in json.load(f)["families"]["agg"]
+
+
 # -- the mapping ---------------------------------------------------------------
 
 @pytest.mark.parametrize("attempt", ["first", "retry", "speculative"])
