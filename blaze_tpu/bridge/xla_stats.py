@@ -122,7 +122,16 @@ _shuffle = {"shuffle_device_bytes": 0, "shuffle_host_bytes": 0,
             # (raw size - wire size, summed; 0 when the codec is raw
             # or compression grew the payload and was skipped)
             "worker_frame_compressed_bytes_saved": 0,
-            "rss_put_compressed_bytes_saved": 0}
+            "rss_put_compressed_bytes_saved": 0,
+            # a map task's committed output by the tier it took
+            # (shuffle/writer.py): rows that stayed on the chip and their
+            # bytes as columns and validity lanes, rows written to
+            # `.data` files and those files' bytes, and of the resident
+            # ones those a spill wrote to files later (a subset: they
+            # were resident first)
+            "shuffle_resident_rows": 0, "shuffle_resident_bytes": 0,
+            "shuffle_file_rows": 0, "shuffle_file_bytes": 0,
+            "shuffle_spilled_rows": 0, "shuffle_spilled_bytes": 0}
 
 # Device-resident stage-loop accounting (runtime/loop.py,
 # plan/stage_compiler.py): stage programs built vs served from the
@@ -1176,6 +1185,15 @@ def note_host_exchange(nbytes: int) -> None:
     (`nbytes` = total .data bytes across its map tasks)."""
     with _lock:
         _shuffle["shuffle_host_bytes"] += int(nbytes)
+
+
+def note_exchange_tier(tier: str, rows: int, nbytes: int) -> None:
+    """One map task committed `rows` rows through `tier`: `resident`
+    (on the chip), `file` (its `.data` file), or `spilled` (a resident
+    output written to files under memory pressure)."""
+    with _lock:
+        _shuffle[f"shuffle_{tier}_rows"] += int(rows)
+        _shuffle[f"shuffle_{tier}_bytes"] += int(nbytes)
 
 
 def note_device_shuffle_fallback() -> None:

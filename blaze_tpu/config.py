@@ -644,11 +644,21 @@ SHUFFLE_DEVICE = str_conf(
     "repartitions (fixed-width row schema, column-reference keys) over "
     "mesh collectives when compute is device-resident (bridge/"
     "placement) and >1 device is visible, 'on' forces the attempt "
-    "regardless of placement, 'off' always writes host shuffle files.  "
+    "regardless of placement, 'off' never takes the collective.  "
     "Any device-lane "
     "failure — injected fault, capacity overflow, unsupported shape — "
     "falls back to the file shuffle for that stage (counted as "
-    "shuffle_device_fallbacks), so lineage recovery keeps working.",
+    "shuffle_device_fallbacks), so lineage recovery keeps working.  "
+    "This key speaks of the mesh collective alone.  Where it declines "
+    "and compute is device-resident on ONE device, the scheduler keeps "
+    "a map task's output on the chip for the reduce tasks of the same "
+    "process (the resident tier, plan/stages.py _resident_tier); no key "
+    "selects that tier: it is taken where writer and reader share a "
+    "chip and a process and nothing else needs the output as files "
+    "(worker pool, shuffle service, speculation, the subplan cache, "
+    "adaptive re-planning, a broadcast reader), for the columns the "
+    "chip carries, and it spills to the file shuffle's own files under "
+    "memory pressure.",
     category="scale-out")
 SHUFFLE_DEVICE_MAX_BYTES = int_conf(
     "auron.tpu.shuffle.device.maxBytes", 1 << 30,

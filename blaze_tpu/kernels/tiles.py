@@ -6,7 +6,10 @@ program's signature is its tiles' widths and column types and nothing else.
 `SortExec`'s resident lane lays a whole partition with `_assemble_tiles`
 (kernels/sort.py `assemble_tiles`); `CoalesceStream` lays the few batches
 it holds behind the rows left of the last tile with `lay_tile` and cuts a
-tile of one batch size off the front (ops/base.py).
+tile of one batch size off the front (ops/base.py).  The exchange's
+resident tier lays a map task's batch partition-major where it lies
+(`partition_tile`, shuffle/writer.py) and a reduce task's runs of such
+batches end to end (`lay_runs`, shuffle/reader.py).
 """
 
 from __future__ import annotations
@@ -79,3 +82,57 @@ def _narrow_tile(tile, lanes: int):
 
 narrow_tile = meter_jit(_narrow_tile, name="coalesce.tail",
                         static_argnames=("lanes",))
+
+
+def _lay_runs(held, parts, starts, rows, tile: int, lanes: int):
+    """`_lay_tile` over runs that lie anywhere in their tiles: part k's
+    rows are its lanes from `starts[k]` on (a reduce partition's run of a
+    batch laid partition-major).  A copy brings each run to its tile's
+    front; what lies behind a run's rows is overwritten or masked as any
+    tile's padding is."""
+    def front(a, at):
+        return jax.lax.dynamic_slice(
+            jnp.pad(a, (0, a.shape[0])), (at,), (a.shape[0],))
+
+    parts = tuple(jax.tree_util.tree_map(lambda a, at=at: front(a, at), part)
+                  for part, at in zip(parts, starts))
+    return _lay_tile(held, parts, rows, tile, lanes)
+
+
+lay_runs = meter_jit(_lay_runs, name="exchange.lay",
+                     static_argnames=("tile", "lanes"))
+
+
+def _partition_tile(cols, pids, selection, rows, n_parts: int):
+    """A batch's live rows laid partition-major: (columns, counts).  `cols`
+    is ((data, validity), ...) over one capacity, `pids` a lane's partition
+    id; the first `rows` lanes are rows, those of them that `selection`
+    (None: all) keeps are live.  Rows of partition 0 come first, then
+    partition 1's, each partition in arrival order (a stable order by
+    partition id); `counts[p]` says how many partition p has.  A row's
+    place is its partition's start plus its rank among the partition's
+    rows, by counting, one prefix sum a partition; no sort.  ONE int32
+    scatter turns places into sources and every lane is gathered once.
+    Lanes behind the rows read 0 and are not valid."""
+    cap = pids.shape[0]
+    lane = jnp.arange(cap, dtype=jnp.int32)
+    mask = lane < rows
+    if selection is not None:
+        mask = mask & selection
+    hot = (mask[None, :]
+           & (pids[None, :] == jnp.arange(n_parts, dtype=pids.dtype)[:, None])
+           ).astype(jnp.int32)
+    rank = jnp.cumsum(hot, axis=1)
+    counts = rank[:, -1]
+    starts = jnp.cumsum(counts) - counts
+    place = jnp.sum(hot * (starts[:, None] + rank - 1), axis=0)
+    # a dead lane's place is past the end, and dropped
+    src = jnp.zeros((cap,), jnp.int32).at[
+        jnp.where(mask, place, cap)].set(lane, mode="drop")
+    live = lane < jnp.sum(counts)
+    return tuple((jnp.where(live, d[src], jnp.zeros_like(d)), v[src] & live)
+                 for d, v in cols), counts
+
+
+partition_tile = meter_jit(_partition_tile, name="exchange.partition",
+                           static_argnames=("n_parts",))

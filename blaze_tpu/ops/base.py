@@ -14,6 +14,7 @@ enqueued ahead while the host iterates.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import queue
 import threading
@@ -29,7 +30,7 @@ from blaze_tpu.batch import (ColumnBatch, DeviceColumn, bucket_capacity,
 from blaze_tpu.bridge import tracing, xla_stats
 from blaze_tpu.bridge.context import current_task
 from blaze_tpu.bridge.metrics import BASELINE_METRICS, MetricNode
-from blaze_tpu.kernels.tiles import lay_tile, narrow_tile
+from blaze_tpu.kernels.tiles import lay_runs, lay_tile, narrow_tile
 from blaze_tpu.schema import Schema
 
 BatchIterator = Iterator[ColumnBatch]
@@ -548,9 +549,16 @@ class _TileLane:
     a tile of exactly the batch size leaves, at that capacity and with no
     selection; the rest stays as the head of the next.  No per-column op,
     and fewer dispatches than batches.  Row order and values are
-    `ColumnBatch.concat`'s."""
+    `ColumnBatch.concat`'s.
 
-    def __init__(self):
+    `runs`: a batch's rows lie from a lane `start` on (`lay`'s) and not at
+    its front, a reduce partition's run of a map task's batch that the
+    exchange laid partition-major; the program is kernels/tiles.py
+    `lay_runs` and no `coalesce` span is written (shuffle/reader.py)."""
+
+    def __init__(self, runs: bool = False):
+        self._runs = runs
+        self._starts = []     # where each waiting batch's rows begin
         self.rows = 0         # held: laid and waiting
         self._tile = 0        # the batch size, and its capacity
         self._lanes = 0
@@ -569,13 +577,21 @@ class _TileLane:
         parts, counts = self._parts or [self._last], self._counts or [0]
         spare = _LAY_PARTS - len(parts)
         laid = [] if self._held is None else [self.rows - sum(counts)]
-        with tracing.span("coalesce", batches=len(self._parts),
-                          rows=sum(counts), lane="tile"):
-            self._head, self._held = lay_tile(
-                self._held, tuple(parts) + (parts[-1],) * spare,
-                np.array(laid + counts + [0] * spare, np.int32),
+        parts = tuple(parts) + (parts[-1],) * spare
+        rows = np.array(laid + counts + [0] * spare, np.int32)
+        if self._runs:
+            starts = np.array((self._starts or [0]) + [0] * spare, np.int32)
+            self._head, self._held = lay_runs(
+                self._held, parts, starts, rows,
                 tile=self._tile, lanes=self._lanes)
-        self._last, self._parts, self._counts = parts[-1], [], []
+        else:
+            with tracing.span("coalesce", batches=len(self._parts),
+                              rows=sum(counts), lane="tile"):
+                self._head, self._held = lay_tile(
+                    self._held, parts, rows,
+                    tile=self._tile, lanes=self._lanes)
+        self._last = parts[len(counts) - 1]
+        self._parts, self._counts, self._starts = [], [], []
 
     def _batch(self, cols, n: int) -> ColumnBatch:
         return ColumnBatch(
@@ -584,12 +600,15 @@ class _TileLane:
              for f, (d, v), codes in zip(self._schema, cols, self._dicts)],
             n, None)
 
-    def lay(self, batch: ColumnBatch, target: int) -> List[ColumnBatch]:
-        """`batch`'s rows behind the rows held; the full tiles that makes
+    def lay(self, batch: ColumnBatch, target: int, start: int = 0,
+            rows: Optional[int] = None) -> List[ColumnBatch]:
+        """`batch`'s rows (`rows` of them from lane `start` on, where the
+        lane takes runs) behind the rows held; the full tiles that makes
         (and first, where the batch size or a column's dictionary changed
         under the rows held, those rows as they are: `lay_tile`'s room is
         one tile's, and its lanes are one dictionary's)."""
         out = []
+        rows = batch.num_rows if rows is None else rows
         dicts = _dictionaries(batch)
         if self.rows and not all(same_dictionary(a, b)
                                  for a, b in zip(dicts, self._dicts)):
@@ -602,8 +621,9 @@ class _TileLane:
             self._tile, self._lanes = target, bucket_capacity(target)
         self._schema, self._dicts = batch.schema, dicts
         self._parts.append(tuple((c.data, c.validity) for c in batch.columns))
-        self._counts.append(batch.num_rows)
-        self.rows += batch.num_rows
+        self._counts.append(rows)
+        self._starts.append(start)
+        self.rows += rows
         if self.rows >= self._tile or len(self._parts) == _LAY_PARTS:
             self._lay()
         while self.rows >= self._tile:
@@ -624,7 +644,8 @@ class _TileLane:
         head, n = self._head, self.rows
         lanes = self._lanes if self._cut else bucket_capacity(n)
         if lanes < self._lanes:
-            with tracing.span("coalesce", batches=0, rows=n, lane="tile"):
+            with (contextlib.nullcontext() if self._runs else
+                  tracing.span("coalesce", batches=0, rows=n, lane="tile")):
                 head = narrow_tile(head, lanes=lanes)
         self.rows, self._head = 0, None
         return self._batch(head, n)

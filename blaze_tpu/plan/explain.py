@@ -328,6 +328,17 @@ class QueryProfile:
         enc_line = format_encodings_footer(x)
         if enc_line is not None:
             lines.append(enc_line)
+        if any(x.get(k) for k in ("shuffle_resident_rows",
+                                  "shuffle_file_rows")):
+            # a map task's committed rows by the tier they took; `spilled`
+            # are resident rows a spill wrote to files later
+            lines.append(
+                f"exchange tiers: resident={x.get('shuffle_resident_rows', 0)}"
+                f" rows ({_fmt_bytes(x.get('shuffle_resident_bytes', 0))}) "
+                f"file={x.get('shuffle_file_rows', 0)} rows "
+                f"({_fmt_bytes(x.get('shuffle_file_bytes', 0))}) "
+                f"spilled={x.get('shuffle_spilled_rows', 0)} rows "
+                f"({_fmt_bytes(x.get('shuffle_spilled_bytes', 0))})")
         if any(x.get(k) for k in ("shuffle_device_bytes",
                                   "shuffle_host_bytes",
                                   "shuffle_device_fallbacks")):

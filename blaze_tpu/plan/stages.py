@@ -27,6 +27,7 @@ Cutting rules:
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import tempfile
@@ -191,7 +192,15 @@ class DagScheduler:
         # analog.  blocks_for closures read THIS dict at call time, so a
         # recovered map task's fresh output is what the retried reduce
         # task fetches — never a stale snapshot of the poisoned one.
-        self._stage_outputs: Dict[int, Dict[int, tuple]] = {}
+        # On the resident tier an entry is the `ResidentMapOutput` a map
+        # task committed in place of files (shuffle/writer.py).
+        self._stage_outputs: Dict[int, Dict[int, Any]] = {}
+        # every resident output a map task of this run committed, to be
+        # let go with the run whatever became of its table entry
+        self._resident_outputs: List[Any] = []
+        # resource ids of the readers under a broadcast build side
+        # (split() finds them): those exchanges stay on files
+        self._broadcast_rids: set = set()
         # (sid, map_id) -> pool worker id that produced the committed
         # output (None on the in-process path).  A worker crash
         # re-validates exactly these entries; validation failure marks
@@ -297,6 +306,7 @@ class DagScheduler:
         demoted: set = set()
         for st in self.stages:
             demoted |= _broadcast_reader_rids(st.plan)
+        self._broadcast_rids = demoted
         for st in self.stages:
             if st.partitioning is None or st.resource_id in demoted:
                 continue
@@ -556,8 +566,12 @@ class DagScheduler:
             except ValueError:
                 return None
             outputs = dict(self._stage_outputs.get(up_sid) or {})
-            if not outputs:
-                return None  # device/RSS tier: blocks live in-process
+            if not outputs or not all(
+                    e is None or isinstance(e, tuple)
+                    for e in outputs.values()):
+                # device/RSS tier, or an output resident on the chip:
+                # blocks live in-process
+                return None
             n_out = None
             for entry in outputs.values():
                 if entry is not None:
@@ -806,12 +820,14 @@ class DagScheduler:
 
     def _run_producer(self, stage: Stage) -> None:
         """One exchange boundary: device-resident collective when the
-        planner marked it eligible; else the elastic shuffle service
+        planner marked it eligible; else the resident tier when writer
+        and reader share one chip and one process (`_resident_tier`: the
+        map output stays on the chip); else the elastic shuffle service
         (auron.tpu.shuffle.service) when configured, so concurrent
         queries don't contend on local disk; host shuffle files
         otherwise — and the file path is ALSO the fallback for any
-        device- or service-tier failure.  The higher tiers are
-        optimizations, never a new failure mode."""
+        device-, resident- or service-tier failure.  The higher tiers
+        are optimizations, never a new failure mode."""
         if self._try_cached_producer(stage):
             return
         if stage.device_spec is not None:
@@ -834,6 +850,19 @@ class DagScheduler:
                 tracing.instant("device_shuffle_fallback",
                                 stage=stage.sid, error=type(e).__name__)
         rss_root = self._rss_root()
+        if self._resident_tier(stage):
+            try:
+                self._run_producer_file(stage, resident=True)
+                return
+            except (KeyboardInterrupt, SystemExit):
+                raise
+            except FetchFailedError:
+                raise
+            except Exception as e:
+                if self._is_cancellation(e):
+                    raise
+                self._note_undeclared_fallback("resident_shuffle", e,
+                                               stage=stage.sid)
         if rss_root is not None:
             try:
                 self._run_producer_rss(stage, rss_root)
@@ -850,6 +879,48 @@ class DagScheduler:
                                 error=type(e).__name__)
         self._run_producer_file(stage)
         self._maybe_store_subplan(stage)
+
+    def _resident_tier(self, stage: Stage) -> bool:
+        """Whether this exchange's map output may stay on the chip
+        (shuffle/writer.py's resident lane), from what the scheduler can
+        see; no key selects it.  It is the tier of the case the mesh tier
+        declines: compute placed on the device and ONE device in the mesh,
+        so writer and reader share a chip.  They have to share a process
+        too (no worker pool, no shuffle service root), and whoever else
+        needs the output as files keeps it on
+        files: speculation (attempt-suffixed files are its commit
+        protocol), the subplan cache about to store this stage's segments,
+        adaptive re-planning (it splits a skewed partition by file
+        segment), a reader under a broadcast build (it replays every
+        partition once a task, which files stream through the page cache).
+        A single-partition exchange keeps the streaming Arrow writer: its
+        rows are few and its consumers read Arrow.  What a map task's
+        batches hold decides the rest, task by task, in the writer."""
+        from blaze_tpu import config
+        from blaze_tpu.bridge.placement import host_resident
+        from blaze_tpu.parallel.mesh import current_mesh
+        from blaze_tpu.plan import adaptive
+        part = self._part_of(stage)
+        return (int(part.get("num_partitions", 1)) > 1
+                and not host_resident()
+                and current_mesh().devices.size == 1
+                and self._map_remote(stage, part) is None
+                and self._rss_root() is None
+                and not config.SPECULATION_ENABLE.get()
+                and stage.sid not in self._pending_subplan
+                and not adaptive.enabled()
+                and stage.resource_id not in self._broadcast_rids)
+
+    def _commit_resident(self, outputs: Dict[int, Any], m: int,
+                         output) -> bool:
+        """The sink a map task of a resident wave commits to: the first
+        output committed for map task `m` is the one readers see, as the
+        first file commit is."""
+        with self._metrics_lock:
+            if outputs.setdefault(m, output) is not output:
+                return False
+            self._resident_outputs.append(output)
+        return True
 
     @staticmethod
     def _note_undeclared_fallback(site: str, e: Exception,
@@ -1369,8 +1440,17 @@ class DagScheduler:
         if stage.resource_id not in self._resources:
             self._resources.append(stage.resource_id)
 
-    def _run_producer_file(self, stage: Stage) -> None:
+    def _run_producer_file(self, stage: Stage,
+                           resident: bool = False) -> None:
+        """The map wave of one exchange, each task's output committed as
+        a `.data` / `.index` pair.  `resident` (`_resident_tier`): each
+        task finds a sink in the resource map, under its own `.data` path,
+        and commits its output there as it lies on the chip where its
+        batches allow (shuffle/writer.py); a task that wrote files all the
+        same, a spilled output and a recovered one are file segments among
+        the resident blocks."""
         from blaze_tpu.shuffle.reader import FileSegmentBlock
+        from blaze_tpu.shuffle.writer import RESIDENT_SINK, ResidentMapOutput
 
         os.makedirs(self._dir, exist_ok=True)
         part = self._part_of(stage)
@@ -1382,38 +1462,63 @@ class DagScheduler:
                 if p not in self._files:
                     self._files.append(p)
 
+        committed: Dict[int, Any] = {}   # resident commits, by map task
+
+        def run_map(m: int) -> None:
+            if not resident:
+                return self._run_map_task(stage, part, m)
+            # handed through the resource map, as the shuffle service's
+            # partition writer is: the TaskDefinition still crosses the
+            # plan-serde boundary with nothing but its paths
+            rid = RESIDENT_SINK + self._map_data_path(stage.sid, m)
+            put_resource(rid, functools.partial(self._commit_resident,
+                                                committed, m))
+            try:
+                return self._run_map_task(stage, part, m)
+            finally:
+                remove_resource(rid)
+
         from blaze_tpu.bridge import tracing, xla_stats
         loop_before = xla_stats.stage_loop_stats()["stage_loop_tasks"]
         with tracing.span("shuffle_exchange", stage=stage.sid,
                           tasks=stage.num_tasks,
-                          partitioning=part["kind"]):
+                          partitioning=part["kind"]) as attrs:
             try:
                 results = self._run_tasks(
-                    lambda m: self._run_map_task(stage, part, m),
+                    run_map,
                     stage.num_tasks, f"stage {stage.sid} (shuffle write)",
                     remote=self._map_remote(stage, part),
                     sid=stage.sid)
+                outputs = {m: committed.get(m)
+                           or self._read_map_output(stage, m, n_out)
+                           for m in range(stage.num_tasks)}
+            except BaseException:
+                for out in committed.values():
+                    out.release()
+                raise
             finally:
                 # attempt-suffixed outputs, claim files and a late
                 # loser's leftovers all join the cleanup list even when
                 # the wave itself failed
                 self._register_stage_files(stage.sid)
+            attrs["tier"] = ("file" if not committed else "resident"
+                             if len(committed) == stage.num_tasks
+                             else "mixed")
         self._absorb_remote_results(stage, results)
-        self._note_placement(stage.sid, "file", loop_before)
+        self._note_placement(stage.sid, attrs["tier"], loop_before)
 
-        self._stage_outputs[stage.sid] = {
-            m: self._read_map_output(stage, m, n_out)
-            for m in range(stage.num_tasks)}
-        from blaze_tpu.bridge import xla_stats
-        xla_stats.note_host_exchange(sum(
-            int(off[-1])
-            for _, off in self._stage_outputs[stage.sid].values()))
+        self._stage_outputs[stage.sid] = outputs
+        files = [e[1] for e in outputs.values() if isinstance(e, tuple)]
+        # bytes that reached files alone: a resident output counts when
+        # (and if) it spills
+        xla_stats.note_host_exchange(sum(int(off[-1]) for off in files))
         from blaze_tpu.plan import adaptive, statstore
         if statstore.enabled() or adaptive.enabled():
             self._note_boundary(stage, [
-                sum(int(off[r + 1] - off[r])
-                    for _, off in self._stage_outputs[stage.sid].values())
-                for r in range(n_out)], "file")
+                sum(int(off[r + 1] - off[r]) for off in files)
+                + sum(int(out.partition_rows[r]) * out.row_bytes
+                      for out in committed.values())
+                for r in range(n_out)], attrs["tier"])
 
         sid = stage.sid
 
@@ -1430,6 +1535,11 @@ class DagScheduler:
                     raise FetchFailedError(
                         sid, map_id,
                         "map output invalidated after worker crash")
+                if isinstance(entry, ResidentMapOutput):
+                    block = entry.block(reduce_id, sid, map_id)
+                    if block is not None:
+                        yield block
+                    continue
                 data, offsets = entry
                 length = offsets[reduce_id + 1] - offsets[reduce_id]
                 if length:
@@ -1836,6 +1946,7 @@ class DagScheduler:
             resources, self._resources = self._resources, []
             files, self._files = self._files, []
             rss_clients, self._rss_clients = self._rss_clients, []
+            resident, self._resident_outputs = self._resident_outputs, []
             self._stage_outputs = {}
             self._map_worker = {}
             self._map_attempt = {}
@@ -1855,6 +1966,9 @@ class DagScheduler:
                 client.cleanup()
             except Exception:
                 pass
+        for output in resident:
+            # the rows on the chip and their memory-manager charge
+            output.release()
         if self._owns_dir:
             import shutil
             # recreated lazily by the next _run_producer if reused
@@ -1862,16 +1976,22 @@ class DagScheduler:
 
     def leak_report(self) -> Dict[str, List[str]]:
         """What this scheduler still holds: shuffle temp files on disk,
-        resource-map entries, RSS shuffle roots, and the owned scratch
+        resource-map entries, RSS shuffle roots, map outputs resident on
+        the chip (or their memory-manager charge), and the owned scratch
         dir.  Empty lists everywhere == nothing leaked; tests assert
         exactly that after failed/cancelled queries."""
         from blaze_tpu.bridge.resource import get_resource
         report: Dict[str, List[str]] = {
-            "files": [], "resources": [], "rss_roots": [], "dirs": []}
+            "files": [], "resources": [], "rss_roots": [], "dirs": [],
+            "resident": []}
         with self._cleanup_lock:
             files = list(self._files)
             resources = list(self._resources)
             rss_clients = list(self._rss_clients)
+            resident = list(self._resident_outputs)
+        report["resident"] = [
+            f"{out.name}: {out.rows} rows, {out.mem_used} B charged"
+            for out in resident if out.on_chip or out.mem_used]
         for path in files:
             if os.path.exists(path):
                 report["files"].append(path)

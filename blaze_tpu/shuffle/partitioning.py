@@ -23,6 +23,7 @@ import pyarrow as pa
 
 from blaze_tpu.batch import ColumnBatch
 from blaze_tpu.bridge.context import current_task
+from blaze_tpu.bridge.xla_stats import meter_jit
 from blaze_tpu.exprs import PhysicalExpr
 from blaze_tpu.kernels import hashing as H
 
@@ -33,6 +34,14 @@ class Partitioning:
     def partition_ids(self, batch: ColumnBatch) -> np.ndarray:
         """int32 partition id per (selected) row; batch must be compact."""
         raise NotImplementedError
+
+    def device_partition_ids(self, batch: ColumnBatch):
+        """int32 partition id a lane of `batch`'s capacity, left on the
+        chip, for the exchange's resident lane (shuffle/writer.py): the
+        batch comes with its selection, nothing is compacted and nothing
+        read back, and a lane that is no live row may hold any id.  None
+        where this partitioning computes its ids on the host."""
+        return None
 
 
 @dataclass
@@ -55,7 +64,6 @@ def _hash_pmod_jit(tids: Tuple[str, ...], n_parts: int):
         # the ONE shared pid definition (normalization included) —
         # identical to the device collective lane and the host path
         return H.spark_partition_ids(flat_cols, tids, n_parts, xp=jnp)
-    from blaze_tpu.bridge.xla_stats import meter_jit
     return meter_jit(f, name="shuffle.hash_pmod")
 
 
@@ -157,15 +165,21 @@ class HashPartitioning(Partitioning):
         # chip): the newest dictionary's alone, let go with the plan
         self._placed: dict = {}
 
-    def partition_ids(self, batch: ColumnBatch) -> np.ndarray:
+    def partition_ids(self, batch: ColumnBatch, keep_on_chip: bool = False):
+        """`keep_on_chip`: the device program's ids over every lane of
+        the batch's capacity as it left them, or None where the ids are
+        computed on the host (host placement, one partition, a key that is
+        a host column)."""
         from blaze_tpu.batch import dict_info
         from blaze_tpu.bridge.placement import host_resident
         from blaze_tpu.xputil import asnp, to_device
         n = batch.num_rows
+        on_host = host_resident()
+        if keep_on_chip and (on_host or self.num_partitions == 1):
+            return None
         if self.num_partitions == 1:
             # pmod(h, 1) == 0 for every row: skip the hash chain
             return np.zeros(n, dtype=np.int32)
-        on_host = host_resident()
         # host batches are unpadded; hashing in numpy avoids one jit
         # compile per distinct tail-batch length
         cap = n if on_host else batch.capacity
@@ -197,6 +211,8 @@ class HashPartitioning(Partitioning):
                 else:
                     flat_cols.append((v.data, v.validity))
                 tids.append(v.dtype.id.value)
+            elif keep_on_chip:
+                return None
             else:
                 # host (string) columns are exact-length; pad the byte
                 # matrix to the batch capacity so mixed string+fixed key
@@ -230,7 +246,30 @@ class HashPartitioning(Partitioning):
                                          self.num_partitions, xp=np)
             return np.asarray(pids)[:n].astype(np.int32)
         pids = _hash_pmod_jit(tuple(tids), self.num_partitions)(flat_cols)
+        if keep_on_chip:
+            return pids
         return asnp(pids)[:n].astype(np.int32)
+
+    def device_partition_ids(self, batch: ColumnBatch):
+        # a row's id is its keys' alone: deselected lanes are hashed with
+        # the rest and never looked at
+        return self.partition_ids(batch, keep_on_chip=True)
+
+
+def _round_robin_ids(selection, rows, cursor, cap: int, n_parts: int):
+    """(ids, the cursor behind them): live row k of the batch (one of its
+    first `rows` lanes that `selection`, where there is one, keeps) takes
+    partition (cursor + k) mod n."""
+    mask = jnp.arange(cap, dtype=jnp.int32) < rows
+    if selection is not None:
+        mask = mask & selection
+    rank = jnp.cumsum(mask.astype(jnp.int32))
+    cursor = jnp.asarray(cursor, jnp.int32)
+    return (rank - 1 + cursor) % n_parts, (cursor + rank[-1]) % n_parts
+
+
+_round_robin = meter_jit(_round_robin_ids, name="shuffle.round_robin",
+                         static_argnames=("cap", "n_parts"))
 
 
 class RoundRobinPartitioning(Partitioning):
@@ -242,9 +281,32 @@ class RoundRobinPartitioning(Partitioning):
         n = batch.num_rows
         # Spark RoundRobin starts at a per-task position; keep a running
         # cursor so rows spread evenly across batches
-        ids = (np.arange(n, dtype=np.int64) + self._next) % self.num_partitions
-        self._next = int((self._next + n) % self.num_partitions)
+        ids = (np.arange(n, dtype=np.int64) + self._cursor()) \
+            % self.num_partitions
+        self._next = int((self._cursor() + n) % self.num_partitions)
         return ids.astype(np.int32)
+
+    def _cursor(self) -> int:
+        """The running cursor on the host (read back once, if the resident
+        lane left it on the chip)."""
+        if isinstance(self._next, jax.Array):
+            from blaze_tpu.xputil import asnp
+            self._next = int(asnp(self._next))
+        return self._next
+
+    def device_partition_ids(self, batch: ColumnBatch):
+        # the k-th LIVE row of the task takes partition k mod n: a row's
+        # rank among the live lanes, behind a cursor that stays on the chip
+        # (the count it advances by is never read back)
+        from blaze_tpu.bridge.placement import host_resident
+        if host_resident():
+            return None
+        pids, self._next = _round_robin(
+            batch.selection, np.int32(batch.num_rows),
+            np.int32(self._next) if isinstance(self._next, int)
+            else self._next,
+            cap=batch.capacity, n_parts=self.num_partitions)
+        return pids
 
 
 class RangePartitioning(Partitioning):

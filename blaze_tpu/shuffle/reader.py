@@ -10,18 +10,21 @@ in-process analog imports an iterator of Arrow batches).
 from __future__ import annotations
 
 import io
+import itertools
 from dataclasses import dataclass
-from typing import BinaryIO, Callable, Iterable, Iterator, List, Union
+from typing import (Any, BinaryIO, Callable, Iterable, Iterator, List,
+                    NamedTuple, Optional, Union)
 
 import pyarrow as pa
 
 from blaze_tpu import faults
-from blaze_tpu.batch import ColumnBatch, DictStream, plain_columns
+from blaze_tpu.batch import (ColumnBatch, DictStream, one_schema,
+                             plain_columns)
 from blaze_tpu.bridge import xla_stats
 from blaze_tpu.bridge.resource import get_resource
 from blaze_tpu.faults import (FetchFailedError, InjectedFault,
                               ShuffleChecksumError)
-from blaze_tpu.ops.base import (BatchIterator, ExecutionPlan,
+from blaze_tpu.ops.base import (BatchIterator, ExecutionPlan, _TileLane,
                                 effective_batch_size)
 from blaze_tpu.schema import Schema
 from blaze_tpu.shuffle.ipc import IpcCompressionReader, IpcCompressionWriter
@@ -41,27 +44,65 @@ class FileSegmentBlock:
     map_id: int = -1
 
 
-Block = Union[FileSegmentBlock, bytes, BinaryIO]
+class ResidentRun(NamedTuple):
+    """`rows` rows of a batch that lies on the chip, from lane `start` on:
+    one reduce partition's share of one map batch (`batch` is the writer's
+    `ResidentBatch`)."""
+
+    batch: Any
+    start: int
+    rows: int
+
+    def to_arrow(self) -> pa.RecordBatch:
+        return self.batch.to_arrow().slice(self.start, self.rows)
 
 
-def read_block(block: Block) -> Iterator[pa.RecordBatch]:
-    if isinstance(block, FileSegmentBlock):
-        if block.length == 0:
+@dataclass
+class ResidentBlock:
+    """Reduce partition `partition`'s rows of ONE map task's output where
+    it lies on the chip (shuffle/writer.py `ResidentMapOutput`): a run of
+    every batch the task committed, in the task's batch order.  `batches`
+    is None where the output was let go: reading that names the map task,
+    as a lost file does."""
+
+    batches: Optional[list]
+    partition: int
+    stage_id: int = -1
+    map_id: int = -1
+
+    def runs(self) -> Iterator[ResidentRun]:
+        if self.batches is None:
+            raise EOFError("the resident map output was released")
+        for b in self.batches:
+            start, end = (int(b.offsets[self.partition]),
+                          int(b.offsets[self.partition + 1]))
+            if end > start:
+                yield ResidentRun(b, start, end - start)
+
+
+Block = Union[FileSegmentBlock, ResidentBlock, bytes, BinaryIO]
+
+
+def read_block(block: Block) -> Iterator[Union[pa.RecordBatch, ResidentRun]]:
+    if isinstance(block, (FileSegmentBlock, ResidentBlock)):
+        resident = isinstance(block, ResidentBlock)
+        where = (f"resident:{block.stage_id}/{block.map_id}"
+                 f"#{block.partition}" if resident else
+                 f"{block.path}@{block.offset}+{block.length}")
+        if not resident and block.length == 0:
             return
         try:
-            faults.maybe_fail("shuffle-read", path=block.path)
-            yield from _read_segment(block)
+            faults.maybe_fail("shuffle-read", path=where)
+            yield from block.runs() if resident else _read_segment(block)
         except (ShuffleChecksumError, EOFError, OSError,
                 InjectedFault) as e:
             # the Spark FetchFailed contract: a block that cannot be
             # read back intact (bit rot, truncation, lost file, injected
             # fetch failure) names its producer so the DAG scheduler can
             # re-run just that map task instead of failing the query
-            from blaze_tpu.bridge import xla_stats
             xla_stats.note_fetch_failure()
-            raise FetchFailedError(
-                block.stage_id, block.map_id,
-                f"{block.path}@{block.offset}+{block.length}: {e}") from e
+            raise FetchFailedError(block.stage_id, block.map_id,
+                                   f"{where}: {e}") from e
     elif isinstance(block, (bytes, bytearray, memoryview)):
         yield from IpcCompressionReader(io.BytesIO(block)).read_batches()
     else:  # file-like channel
@@ -130,7 +171,10 @@ def _tiles(pieces: Iterable[pa.RecordBatch]) -> Iterator[pa.RecordBatch]:
 
 
 def _join(staged: List[pa.RecordBatch]) -> pa.RecordBatch:
-    return staged[0] if len(staged) == 1 else pa.concat_batches(staged)
+    # one map task's pieces may carry a column as codes and the next
+    # one's as plain strings (its scan's encoder hit its cap)
+    return staged[0] if len(staged) == 1 \
+        else pa.concat_batches(one_schema(staged))
 
 
 class IpcReaderExec(ExecutionPlan):
@@ -157,18 +201,42 @@ class IpcReaderExec(ExecutionPlan):
         return self._num_partitions
 
     def execute(self, partition: int) -> BatchIterator:
-        # re-tiled in Arrow, on the host, BEFORE anything is placed: a
-        # reduce task's batches reach the chip at the tile's capacity and
-        # no device program joins them
         # blocks of several map tasks bring each its own dictionaries: the
         # task's batches leave under one a column (`batch.DictStream`)
         stream = DictStream()
-        for rb in _tiles(self._block_batches(partition)):
-            batch = stream.under_one_dictionary(ColumnBatch.from_arrow(rb))
+        for batch in self._tile_batches(partition):
+            batch = stream.under_one_dictionary(batch)
             if stream.dicts:
                 xla_stats.note_dict(
                     dict_rows_coded=batch.num_rows * len(stream.dicts))
             yield batch
+
+    def _tile_batches(self, partition: int) -> BatchIterator:
+        """The blocks' rows, in block order, as batches of exactly
+        `effective_batch_size()` rows and a tail.  Record batches (a file
+        segment's, a channel's) are re-tiled in Arrow, on the host, BEFORE
+        anything is placed, so they reach the chip at the tile's capacity
+        and no device program joins them.  Runs that lie on the chip (a
+        resident block's) are laid end to end there by the copy-only tile
+        program (ops/base.py `_TileLane`, kernels/tiles.py `lay_runs`) and
+        never leave it.  Where a list mixes the kinds (a recovered or
+        spilled map output among resident ones) each stretch of one kind
+        ends in its own tail."""
+        pieces = itertools.groupby(
+            self._block_batches(partition),
+            key=lambda piece: isinstance(piece, ResidentRun))
+        for resident, stretch in pieces:
+            if not resident:
+                for rb in _tiles(stretch):
+                    yield ColumnBatch.from_arrow(rb)
+                continue
+            lane = _TileLane(runs=True)
+            for run in stretch:
+                # asked anew for every run, as `_tiles` asks
+                yield from lane.lay(run.batch.batch, effective_batch_size(),
+                                    start=run.start, rows=run.rows)
+            if lane.rows:
+                yield lane.tail()
 
     def arrow_batches(self, partition: int):
         """Arrow-resident read: decoded IPC frames go straight to
@@ -178,6 +246,8 @@ class IpcReaderExec(ExecutionPlan):
         the prefetch worker so reduce-side compute overlaps them
         (kill-switch auron.tpu.io.prefetch)."""
         for rb in self._block_batches(partition):
+            if isinstance(rb, ResidentRun):
+                rb = rb.to_arrow()   # same rows, same order, read back
             if any(pa.types.is_dictionary(f.type) for f in rb.schema):
                 rb = pa.RecordBatch.from_arrays(plain_columns(rb.columns),
                                                 names=rb.schema.names)
@@ -199,7 +269,8 @@ class IpcReaderExec(ExecutionPlan):
             # fetching mid-shuffle instead of draining every segment
             ctx.check_running()
             for rb in read_block(block):
-                self.metrics.add("io_bytes", rb.nbytes)
+                if not isinstance(rb, ResidentRun):
+                    self.metrics.add("io_bytes", rb.nbytes)
                 yield rb
 
 

@@ -13,6 +13,13 @@ stage), then rows group by pid via the same device sort-by-key machinery as
 aggregation; the host writes per-partition frames.  Spill files hold the
 same per-partition framed layout with an in-memory offset table, so the
 final merge is pure sequential IO per partition (no decode).
+
+The resident lane: where the scheduler hands the task a sink
+(`RESIDENT_SINK`, plan/stages.py chooses the tier) and every column of
+every batch is carried on the chip, a batch is laid partition-major where
+it lies (kernels/tiles.py `partition_tile`) and the task commits a
+`ResidentMapOutput` in place of files.  The files stay its spill target
+and the lane of every other case.
 """
 
 from __future__ import annotations
@@ -22,21 +29,37 @@ import os
 import re
 import struct
 import tempfile
-from dataclasses import dataclass, field
+import threading
+from dataclasses import dataclass, field, replace
 from typing import BinaryIO, Callable, Iterator, List, Optional, Sequence, \
     Tuple
 
+import jax
 import numpy as np
 import pyarrow as pa
 
 from blaze_tpu import config
-from blaze_tpu.batch import ColumnBatch, one_schema
+from blaze_tpu.batch import ColumnBatch, DeviceColumn, one_schema
+from blaze_tpu.bridge import xla_stats
 from blaze_tpu.bridge.context import current_task
+from blaze_tpu.bridge.resource import get_resource
+from blaze_tpu.kernels.tiles import partition_tile
 from blaze_tpu.memory import MemConsumer, MemManager
 from blaze_tpu.ops.base import BatchIterator, ExecutionPlan
-from blaze_tpu.schema import Schema
+from blaze_tpu.schema import Schema, TypeId
 from blaze_tpu.shuffle.ipc import IpcCompressionWriter
 from blaze_tpu.shuffle.partitioning import Partitioning
+from blaze_tpu.shuffle.reader import FileSegmentBlock, ResidentBlock
+from blaze_tpu.xputil import to_host
+
+#: resource-map key, before a map task's `.data` path, of the sink the task
+#: commits a resident output to: `sink(output) -> bool`, first wins.  The
+#: TaskDefinition carries the path and nothing else
+RESIDENT_SINK = "exchange-sink://"
+
+# the most reduce partitions the resident lane takes: `partition_tile` ranks
+# a row inside its partition by counting, one prefix sum a partition
+_RESIDENT_PARTS = 256
 
 
 #: attempt-suffixed index sidecar: `<base>.a<N>.index` — the speculative
@@ -123,6 +146,124 @@ def resolve_attempt_data(data_file: str) -> Tuple[str, int]:
     return f"{base}.a{attempt}.data", attempt
 
 
+def _offsets(counts) -> np.ndarray:
+    """Cumulative offsets (n + 1, int64) of per-partition counts."""
+    return np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
+
+
+def _with_pids(rb: pa.RecordBatch, pids: np.ndarray) -> pa.RecordBatch:
+    """A batch as `ShuffleRepartitioner` stages it: `__pid` first."""
+    return pa.RecordBatch.from_arrays(
+        [pa.array(pids, type=pa.int32())] + list(rb.columns),
+        names=["__pid"] + list(rb.schema.names))
+
+
+@dataclass
+class ResidentBatch:
+    """One batch of a map task's output on the chip, laid partition-major:
+    reduce partition p's rows are lanes [offsets[p], offsets[p + 1]) of
+    every column of `batch`, in arrival order."""
+
+    batch: ColumnBatch
+    offsets: np.ndarray
+    _arrow: Optional[pa.RecordBatch] = None
+    _lock: threading.Lock = field(default_factory=threading.Lock)
+
+    def to_arrow(self) -> pa.RecordBatch:
+        """The rows, read back ONCE whoever asks and kept: an Arrow
+        consumer's reduce tasks each take a slice (a dictionary column as
+        its codes)."""
+        with self._lock:
+            if self._arrow is None:
+                self._arrow = self.batch.to_arrow(keep_dict=True)
+            return self._arrow
+
+    def staged(self) -> pa.RecordBatch:
+        """As the file lane stages a batch, its partition ids in front."""
+        return _with_pids(self.to_arrow(), np.repeat(
+            np.arange(len(self.offsets) - 1, dtype=np.int32),
+            np.diff(self.offsets)))
+
+
+class ResidentMapOutput(MemConsumer):
+    """A map task's committed output that stays on the chip: the exchange's
+    resident tier.  It charges its bytes to the memory manager of its
+    task's chip; `spill()` writes exactly the `.data` / `.index` pair the
+    file lane would have written (through `ShuffleRepartitioner.write`:
+    the same rows in the same order, frame for frame) and from then on
+    hands out file segments, so under memory pressure the exchange is the
+    file tier's.  Readers may ask from any thread."""
+
+    def __init__(self, partitioning: Partitioning, schema: Schema,
+                 batches: List[ResidentBatch], data_file: str,
+                 index_file: str):
+        super().__init__("shuffle_resident")
+        self.partitioning = partitioning
+        self.schema = schema
+        self._batches: Optional[List[ResidentBatch]] = batches
+        self._paths = (data_file, index_file)
+        self._segments: Optional[tuple] = None   # (data file, offsets)
+        self._lock = threading.Lock()
+        self.partition_rows = sum(
+            (np.diff(b.offsets) for b in batches),
+            np.zeros(partitioning.num_partitions, np.int64))
+        self.nbytes = sum(b.batch.nbytes_device() for b in batches)
+        # bytes of one row as the chip carries it: every column's value
+        # and its validity byte
+        self.row_bytes = sum(c.data.dtype.itemsize + 1
+                             for c in batches[0].batch.columns) \
+            if batches else 0
+
+    @property
+    def rows(self) -> int:
+        return int(self.partition_rows.sum())
+
+    @property
+    def on_chip(self) -> bool:
+        return self._batches is not None
+
+    def block(self, partition: int, stage_id: int, map_id: int):
+        """Reduce partition `partition`'s rows of this output: the runs on
+        the chip, the file segment they were spilled to, or None where the
+        partition has no row."""
+        with self._lock:
+            batches, segments = self._batches, self._segments
+        if segments is not None:
+            data, offsets = segments
+            length = int(offsets[partition + 1] - offsets[partition])
+            return FileSegmentBlock(data, int(offsets[partition]), length,
+                                    stage_id=stage_id, map_id=map_id) \
+                if length else None
+        if batches is not None and not self.partition_rows[partition]:
+            return None
+        # a released output (`batches` None) is a lost block: reading it
+        # names the map task to run again
+        return ResidentBlock(batches, partition, stage_id, map_id)
+
+    def spill(self) -> int:
+        with self._lock:
+            batches = self._batches
+            if batches is None:
+                return 0
+            rep = ShuffleRepartitioner(self.partitioning, self.schema)
+            for b in batches:
+                rep._stage(b.staged())
+            offsets = _offsets(rep.write(*self._paths))
+            self._segments = (self._paths[0], offsets)
+            self._batches = None
+            released, self._mem_used = self._mem_used, 0
+        xla_stats.note_exchange_tier("spilled", self.rows, int(offsets[-1]))
+        xla_stats.note_host_exchange(int(offsets[-1]))
+        return released
+
+    def release(self) -> None:
+        """Let the rows go (the scheduler's cleanup, a commit that lost)."""
+        with self._lock:
+            self._batches = None
+        self.update_mem_used(0)
+        self.unregister()
+
+
 class _PartitionedSpill:
     """Spill file laid out partition-major with an offset table."""
 
@@ -156,6 +297,100 @@ class ShuffleRepartitioner(MemConsumer):
         self._stream_writer: Optional[IpcCompressionWriter] = None
         self._stream_file: Optional[str] = None
         self._stream_tmp: Optional[str] = None
+        # the resident lane, while it is open: (laid batch, its partition
+        # counts on the chip) a batch; None once the task writes files.
+        # While it is open nothing is staged and nothing spilled
+        self._resident: Optional[List[tuple]] = None
+        self._resident_bytes = 0
+        self._resident_lock = threading.Lock()
+        self.file_rows = 0    # rows the file lane took, staged or streamed
+
+    # -- resident lane -------------------------------------------------------
+    def open_resident(self) -> bool:
+        """Keep the task's output on the chip for as long as its batches
+        are carried there.  Only valid before the first insert."""
+        if (1 < self.partitioning.num_partitions <= _RESIDENT_PARTS
+                and not self._staged and not self._spills
+                and self._stream_sink is None):
+            self._resident = []
+        return self._resident is not None
+
+    def _insert_resident(self, batch: ColumnBatch) -> bool:
+        """Lay `batch` partition-major where it lies and hold it; False
+        where the batch has to go through the file lane (the lane is then
+        closed for the rest of the task, what it held staged first, in
+        arrival order)."""
+        carried = bool(batch.columns) and all(
+            isinstance(c, DeviceColumn) and isinstance(c.data, jax.Array)
+            for c in batch.columns)
+        pids = self.partitioning.device_partition_ids(batch) \
+            if carried else None
+        if pids is None:
+            self._stage_resident()
+            return False
+        laid, counts = partition_tile(
+            tuple((c.data, c.validity) for c in batch.columns), pids,
+            batch.selection, np.int32(batch.num_rows),
+            n_parts=self.partitioning.num_partitions)
+        held = ColumnBatch(
+            batch.schema,
+            [replace(c, data=d, validity=v)
+             for c, (d, v) in zip(batch.columns, laid)], 0)
+        with self._resident_lock:
+            if self._resident is None:   # spilled from another thread
+                return False
+            self._resident.append((held, counts))
+            self._resident_bytes += held.nbytes_device()
+        self._charge()
+        return True
+
+    def _take_resident(self) -> Optional[List[ResidentBatch]]:
+        """Close the lane: what it held, with the partition counts read
+        back in ONE transfer; None where it was closed before."""
+        with self._resident_lock:
+            held, self._resident = self._resident, None
+            self._resident_bytes = 0
+        if held is None:
+            return None
+        counts = to_host([c for _b, c in held]) if held else []
+        out = []
+        for (batch, _c), n in zip(held, counts):
+            offsets = _offsets(n)
+            out.append(ResidentBatch(
+                replace(batch, num_rows=int(offsets[-1])), offsets))
+        return out
+
+    def _stage_resident(self) -> None:
+        """What the lane holds joins the staged rows, read back, and the
+        lane is closed (no charge is declared here: `spill` runs under the
+        memory manager's own lock)."""
+        for b in self._take_resident() or ():
+            staged = b.staged()
+            self._staged.append(staged)
+            self._staged_bytes += staged.nbytes
+            self.file_rows += staged.num_rows
+
+    def commit_resident(self, data_file: str, index_file: str
+                        ) -> Optional[ResidentMapOutput]:
+        """The task's whole output as it lies on the chip, charged to the
+        memory manager under its own name; None where the task left the
+        lane (`write` then commits files)."""
+        batches = self._take_resident()
+        if batches is None:
+            return None
+        out = ResidentMapOutput(self.partitioning, self.schema, batches,
+                                data_file, index_file)
+        coded = sum(f.data_type.id == TypeId.UTF8 for f in self.schema)
+        if coded:
+            # every utf8 column the lane carries is a dictionary's codes
+            xla_stats.note_dict(dict_rows_coded=out.rows * coded)
+        self._charge()
+        out.set_spillable(MemManager.get())
+        out.update_mem_used(out.nbytes)
+        return out
+
+    def _charge(self) -> None:
+        self.update_mem_used(self._staged_bytes + self._resident_bytes)
 
     # -- streaming single-partition mode -----------------------------------
     def open_stream(self, data_file: str) -> bool:
@@ -182,6 +417,7 @@ class ShuffleRepartitioner(MemConsumer):
             self._stream_writer = IpcCompressionWriter(
                 self._stream_sink,
                 codec_name=config.SHUFFLE_FILE_CODEC.get())
+        self.file_rows += rb.num_rows
         if isinstance(rb, pa.Table):
             for piece in rb.to_batches():
                 if piece.num_rows:
@@ -194,6 +430,8 @@ class ShuffleRepartitioner(MemConsumer):
         stream temp file is removed, the final path never existed, and
         any spill files are released — a query cancelled between spill
         and write() must not leak them."""
+        with self._resident_lock:
+            self._resident, self._resident_bytes = None, 0
         if self._stream_sink is not None:
             try:
                 self._stream_sink.close()
@@ -215,6 +453,12 @@ class ShuffleRepartitioner(MemConsumer):
 
     # -- insert (ref ShuffleRepartitioner::insert_batch, shuffle/mod.rs:55)
     def insert_batch(self, batch: ColumnBatch) -> None:
+        if self._resident is not None:
+            # no `compact()`, so no count read back: the program takes
+            # the selection as it is
+            current_task().check_running()
+            if batch.num_rows == 0 or self._insert_resident(batch):
+                return
         batch = batch.compact()
         if batch.num_rows == 0:
             return
@@ -233,10 +477,7 @@ class ShuffleRepartitioner(MemConsumer):
         if coded:
             from blaze_tpu.bridge import xla_stats
             xla_stats.note_dict(dict_rows_coded=rb.num_rows * coded)
-        arrays = [pa.array(pids, type=pa.int32())] + list(rb.columns)
-        staged = pa.RecordBatch.from_arrays(
-            arrays, names=["__pid"] + list(rb.schema.names))
-        self._stage(staged)
+        self._stage(_with_pids(rb, pids))
 
     def insert_arrow(self, rb) -> None:
         """Arrow-resident insert: with ONE reduce partition no partition
@@ -263,10 +504,14 @@ class ShuffleRepartitioner(MemConsumer):
     def _stage(self, staged) -> None:
         self._staged.append(staged)
         self._staged_bytes += staged.nbytes
-        self.update_mem_used(self._staged_bytes)
+        self.file_rows += staged.num_rows
+        self._charge()
 
     # -- spill (MemConsumer) -----------------------------------------------
     def spill(self) -> int:
+        # an open resident lane is shed first: its rows join the staged
+        # ones and the task writes files from here on, as before this lane
+        self._stage_resident()
         if not self._staged:
             return 0
         # spills keep the wire codec (not the local-file codec): spilled
@@ -498,10 +743,15 @@ class ShuffleWriterExec(ExecutionPlan):
         arrow_native = (self.partitioning.num_partitions == 1
                         and type(child).arrow_batches
                         is not ExecutionPlan.arrow_batches)
+        # the tier is the scheduler's choice (plan/stages.py): where it
+        # left a sink under this task's .data path, the output stays on
+        # the chip for as long as the batches are carried there
+        sink = get_resource(RESIDENT_SINK + self.data_file)
         try:
             # single-reduce local writes stream frames to disk as
             # they arrive (compute/IO overlap, no staging hump)
-            rep.open_stream(self.data_file)
+            if sink is None or not rep.open_resident():
+                rep.open_stream(self.data_file)
             # sinks yield nothing, so the stream meter never sees rows;
             # count what is written (rows in == rows shuffled out).
             # the child stream pulls on a prefetch worker so upstream
@@ -519,8 +769,24 @@ class ShuffleWriterExec(ExecutionPlan):
                     self.metrics.add("output_rows", batch.num_rows)
                     self.metrics.add("output_batches")
                     rep.insert_batch(batch)
+            output = rep.commit_resident(self.data_file, self.index_file)
+            if output is not None:
+                # first wins, as a file commit: a loser's rows are let go
+                if sink(output):
+                    xla_stats.note_exchange_tier(
+                        "resident", output.rows,
+                        output.rows * output.row_bytes)
+                else:
+                    output.release()
+                self.partition_lengths = [
+                    int(n) * output.row_bytes
+                    for n in output.partition_rows]
+                self.metrics.add("data_size", sum(self.partition_lengths))
+                return iter(())
             self.partition_lengths = rep.write(self.data_file,
                                                self.index_file)
+            xla_stats.note_exchange_tier("file", rep.file_rows,
+                                         sum(self.partition_lengths))
             self.metrics.add("data_size", sum(self.partition_lengths))
             self.metrics.add("io_bytes", sum(self.partition_lengths))
         finally:

@@ -9,7 +9,11 @@ one more line on stdout, `LEDGER <json>`: `xla_stats.program_load_summary`
 of the records that ended before the first query of the window (the
 twenty (program, call site) pairs with most seconds among it, requests
 by kind, `trimmed`), and `loads_in_window`: the ledger's records since
-that query began, which has to be empty for a run that is `correct`.
+that query began, which has to be empty for a run that is `correct`; and
+`exchange_tiers`: the rows and bytes the process's map tasks committed by
+exchange tier since it started (warm-up included: `resident` on the chip,
+`file`, `spilled` from resident to files) with `resident_rows_share`, the
+resident tier's share of those rows in %.
 The first query's start is the newest `trace_events.json`'s (a traced
 run); an untraced run reports the whole ledger and `loads_in_window`
 null.  The exit code is `run.py`'s.
@@ -38,8 +42,25 @@ def main(argv) -> int:
         for r in xla_stats.program_loads(since_ns=start)]
     print("LEDGER " + json.dumps({
         "summary": xla_stats.program_load_summary(until_ns=start),
-        "loads_in_window": in_window}), flush=True)
+        "loads_in_window": in_window,
+        "exchange_tiers": exchange_tiers(xla_stats.shuffle_stats())}),
+        flush=True)
     return rc
+
+
+def exchange_tiers(shuffle: dict) -> dict:
+    """Rows and bytes by exchange tier out of `xla_stats.shuffle_stats()`;
+    a program without the tier counters reports nothing."""
+    tiers = {t: {"rows": shuffle[f"shuffle_{t}_rows"],
+                 "bytes": shuffle[f"shuffle_{t}_bytes"]}
+             for t in ("resident", "file", "spilled")
+             if f"shuffle_{t}_rows" in shuffle}
+    if not tiers:
+        return {}
+    rows = tiers["resident"]["rows"] + tiers["file"]["rows"]
+    tiers["resident_rows_share"] = \
+        100.0 * tiers["resident"]["rows"] / rows if rows else None
+    return tiers
 
 
 if __name__ == "__main__":
